@@ -45,6 +45,10 @@ def serve(sock_path: str) -> None:
     # worker touches before its first task — the worker module chain,
     # the protobuf wire codec (google.protobuf is ~0.3s cold), pickle
     # machinery — is imported ONCE here; forks inherit the warm modules.
+    # None of it may start a JAX backend: the template owns no chip, and a
+    # fork of a process that had claimed one could never use it.  Each fork
+    # gets its device environment from the node (``req["env"]`` below:
+    # held to the CPU unless it was granted chips).
     import ray_tpu._private.worker as worker_mod
     import ray_tpu._private.wire  # noqa: F401  (pulls google.protobuf)
     import cloudpickle  # noqa: F401

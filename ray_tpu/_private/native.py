@@ -10,6 +10,8 @@ is an accelerator, never a dependency.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,7 +24,7 @@ _SRC_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "src", "store_core",
 )
-_LIB_NAME = "libray_tpu_store.so"
+_LIB_STEM = "libray_tpu_store"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -48,14 +50,19 @@ def _compiler_is_clang(cxx: str) -> bool:
 
 
 def _build() -> Optional[str]:
-    """Compile the .so next to its source (cached across sessions)."""
+    """Compile the .so next to its source (cached across sessions).  The
+    file name carries a hash of the source, so the library that loads is
+    always the one this source builds: a copied tree may hold a binary
+    whose mtime says nothing about what it was built from."""
     tsan = _tsan_enabled()
-    lib_name = _LIB_NAME.replace(".so", "_tsan.so") if tsan else _LIB_NAME
-    out = os.path.join(_SRC_DIR, lib_name)
     src = os.path.join(_SRC_DIR, "store_core.cc")
     if not os.path.exists(src):
         return None
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    suffix = "_tsan.so" if tsan else ".so"
+    out = os.path.join(_SRC_DIR, f"{_LIB_STEM}-{digest}{suffix}")
+    if os.path.exists(out):
         return out
     cxx = os.environ.get("CXX", "g++")
     cmd = [cxx, "-O2", "-fPIC", "-std=c++17"]
@@ -64,9 +71,17 @@ def _build() -> Optional[str]:
                "-fsanitize=thread", "-fno-omit-frame-pointer"]
         if _compiler_is_clang(cxx):
             cmd.append("-Wthread-safety")  # g++ has no such warning
+    tmp = f"{out}.{os.getpid()}.tmp"  # several processes may build at once
     try:
-        subprocess.run(cmd + ["-shared", "-o", out, src],
+        subprocess.run(cmd + ["-shared", "-o", tmp, src],
                        check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)
+        for stale in glob.glob(os.path.join(_SRC_DIR, f"{_LIB_STEM}*{suffix}")):
+            if stale != out and stale.endswith("_tsan.so") == tsan:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
         return out
     except (OSError, subprocess.SubprocessError) as e:
         if tsan:
@@ -77,7 +92,9 @@ def _build() -> Optional[str]:
                 "RAY_TPU_STORE_TSAN=1 but the TSan build failed (%s) — "
                 "the store is NOT sanitizer-instrumented", e)
         else:
-            logger.info("native store core unavailable (build failed: %s)", e)
+            logger.warning(
+                "native store core unavailable, falling back to the Python "
+                "store (build failed: %s)", e)
         return None
 
 

@@ -56,6 +56,7 @@ from ray_tpu._private.gcs import (
     TaskInfo,
 )
 from ray_tpu._private.object_store import ObjectLocation, ObjectRegistry
+from ray_tpu._private.resource_spec import chip_env
 
 logger = logging_utils.get_logger(__name__)
 
@@ -121,6 +122,15 @@ def _runtime_env_key(runtime_env: Optional[dict]) -> Optional[str]:
     import json
 
     return json.dumps(runtime_env, sort_keys=True)
+
+
+def _pool_key(runtime_env: Optional[dict], holds_chips: bool) -> Optional[str]:
+    """Which pool of workers serves a task: its runtime_env's, and for a task
+    that was granted chips a pool of its own — those workers are spawned
+    free to claim a device (every other worker is held to the CPU,
+    ``resource_spec.chip_env``) and retire after the one task they run."""
+    key = _runtime_env_key(runtime_env)
+    return "tpu|" + (key or "") if holds_chips else key
 
 
 def _apply_runtime_env(env: Dict[str, str], runtime_env: Optional[dict]) -> Optional[str]:
@@ -339,6 +349,11 @@ class WorkerHandle:
     # by Node._flush_sends — pickling+write syscalls must not extend lock
     # hold times (they were the head's main source of lock contention)
     outbox: deque = field(default_factory=deque)
+    # a pooled worker that ran a chip-holding task exits after it (a chip
+    # belongs to one process and JAX cannot hand it back): the finished
+    # task's running-table entry parks here, and its chips and resources
+    # return to the node when the process is seen to be gone
+    retiring: Optional[dict] = None
 
     def send(self, msg: dict) -> None:
         with self.send_lock:
@@ -904,10 +919,13 @@ class Node:
             "pid": os.getpid(),
             "dashboard": list(self.dashboard.address) if self.dashboard else None,
         }
-        fd = os.open(path + ".tmp", os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o600)
+        # a temp name of our own: two heads starting at once shared one
+        # ".tmp", and the slower one's rename found it already gone
+        tmp = f"{path}.{os.getpid()}.tmp"
+        fd = os.open(tmp, os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o600)
         with os.fdopen(fd, "w") as f:
             json.dump(payload, f)
-        os.replace(path + ".tmp", path)
+        os.replace(tmp, path)
 
     # ------------------------------------------------------------------
     # topology
@@ -1883,10 +1901,18 @@ class Node:
         worker_id: bytes,
         runtime_env: Optional[dict],
         extra_env: Optional[Dict[str, str]] = None,
+        holds_chips: bool = False,
     ) -> Optional[subprocess.Popen]:
         """Spawn a worker locally or delegate to the node's agent.  Returns
         the Popen for local spawns, None for remote ones.  Raises OSError
-        when the spawn cannot happen on either path."""
+        when the spawn cannot happen on either path.
+
+        One process for each chip is decided HERE, not by user code: a
+        worker that will hold no chip is born held to the CPU, whatever it
+        imports later.  ``holds_chips`` workers are not; they learn which
+        chips with their first task (``worker._execute_task``)."""
+        if not holds_chips:
+            extra_env = {**(extra_env or {}), **chip_env(None)}
         if ns.agent_conn is not None:
             env, cwd = self._remote_env_overrides(worker_id, runtime_env, extra_env)
             ns.agent_send({"type": "spawn_worker", "worker_id": worker_id.hex(),
@@ -1918,17 +1944,20 @@ class Node:
             env.update(extra_env)
         return env, cwd
 
-    def _spawn_worker(self, ns: NodeState, runtime_env: Optional[dict] = None) -> None:
+    def _spawn_worker(self, ns: NodeState, runtime_env: Optional[dict] = None,
+                      holds_chips: bool = False) -> None:
         """Fork/exec a language worker (WorkerPool::StartWorkerProcess analog).
 
         With a runtime_env, the worker is spawned inside that environment
         (env_vars + working_dir) and only ever serves tasks declaring the
-        identical env.  On a remote node the spawn is delegated to its
+        identical env; ``holds_chips`` workers serve one chip-holding task
+        (``_pool_key``).  On a remote node the spawn is delegated to its
         agent (the worker still connects straight back to the head)."""
         worker_id = os.urandom(8)  # raylint: disable=R3 (per spawn, not per task)
-        key = _runtime_env_key(runtime_env)
+        key = _pool_key(runtime_env, holds_chips)
         try:
-            proc = self._spawn_on_node(ns, worker_id, runtime_env)
+            proc = self._spawn_on_node(ns, worker_id, runtime_env,
+                                       holds_chips=holds_chips)
         except (OSError, ValueError) as e:
             logger.warning("worker spawn failed for env %r: %s", key, e)
             if ns.agent_conn is None and key is not None:
@@ -2000,6 +2029,9 @@ class Node:
             h.current_task = None
             pipelined = list(h.pipeline)
             h.pipeline.clear()
+            if h.retiring is not None:
+                self._release_task_resources_locked(h.retiring)
+                h.retiring = None
         if self._shutdown:
             return
         events_mod.emit(
@@ -3108,7 +3140,7 @@ class Node:
                     staged = list(ns.ready_queue)
                     ns.ready_queue.clear()
                 for spec, tpu_ids, bundle in staged:
-                    key = _runtime_env_key(spec.get("runtime_env"))
+                    key = _pool_key(spec.get("runtime_env"), bool(tpu_ids))
                     w = next((c for c in ns.idle if c.runtime_env_key == key), None)
                     if w is None:
                         deferred.append((spec, tpu_ids, bundle, key))
@@ -3140,10 +3172,12 @@ class Node:
                     # Spawn only what the queues need; python startup is
                     # expensive, so never boot more than 2 at a time per env.
                     need_by_key: Dict[Optional[str], int] = {}
-                    env_by_key: Dict[Optional[str], Optional[dict]] = {}
-                    for spec, _, _, key in deferred:
+                    # pool key -> (runtime_env, holds_chips) of its workers
+                    spawn_args: Dict[Optional[str], tuple] = {}
+                    for spec, tpu_ids, _, key in deferred:
                         need_by_key[key] = need_by_key.get(key, 0) + 1
-                        env_by_key.setdefault(key, spec.get("runtime_env"))
+                        spawn_args.setdefault(
+                            key, (spec.get("runtime_env"), bool(tpu_ids)))
                     for key, need in need_by_key.items():
                         if ns.spawn_failures.get(key, 0) >= 3:
                             continue  # boot-looping env; failed below
@@ -3153,7 +3187,7 @@ class Node:
                             and starting < self.cfg.maximum_startup_concurrency
                             and n_workers + ns.starting < max(1, cap)
                         ):
-                            self._spawn_worker(ns, runtime_env=env_by_key[key])
+                            self._spawn_worker(ns, *spawn_args[key])
                             starting += 1
                             n_workers += 1
                         if need > starting and n_workers + ns.starting >= max(1, cap):
@@ -3167,7 +3201,7 @@ class Node:
                             if victim is not None:
                                 self._kill_worker(victim, reason="evicted for new runtime_env")
                                 n_workers -= 1
-                                self._spawn_worker(ns, runtime_env=env_by_key[key])
+                                self._spawn_worker(ns, *spawn_args[key])
                                 n_workers += 1
                     for spec, tpu_ids, bundle, key in deferred:
                         if ns.spawn_failures.get(key, 0) >= 3:
@@ -3271,7 +3305,13 @@ class Node:
             # first and re-acquiring in a separate critical section lets a
             # concurrent dispatch take the freed CPUs and the promotion's
             # "identical shape always fits" invariant would oversubscribe
-            if rt is not None and not is_creation:
+            if rt is not None and rt["tpu_ids"] and not w.is_actor_worker:
+                # the worker is on its way out (worker.main retires after a
+                # chip-holding task); releasing now would let the next
+                # grant race the old process for the device
+                w.retiring = rt
+                w.state = "retiring"
+            elif rt is not None and not is_creation:
                 self._release_task_resources_locked(rt)
             if w.state == "busy" and not w.is_actor_worker:
                 ns = self.nodes.get(w.node_id)
@@ -3581,9 +3621,6 @@ class Node:
                     # dedicated worker for the actor
                     worker_id = os.urandom(8)  # raylint: disable=R3 (per actor)
                     extra_env: Dict[str, str] = {}
-                    if art.tpu_ids:
-                        extra_env["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in art.tpu_ids)
-                        extra_env["RAY_TPU_ASSIGNED_TPUS"] = extra_env["TPU_VISIBLE_CHIPS"]
                     if art.max_concurrency > 1:
                         extra_env["RAY_TPU_MAX_CONCURRENCY"] = str(art.max_concurrency)
                     if art.groups_env:
@@ -3592,7 +3629,8 @@ class Node:
                         extra_env["RAY_TPU_CONCURRENCY_GROUPS"] = art.groups_env
                     try:
                         proc = self._spawn_on_node(
-                            ns, worker_id, spec.get("runtime_env"), extra_env
+                            ns, worker_id, spec.get("runtime_env"), extra_env,
+                            holds_chips=bool(art.tpu_ids),
                         )
                     except (OSError, ValueError) as e:
                         # cannot even fork (bad working_dir, fd/memory
@@ -5272,6 +5310,7 @@ class Node:
                 except Exception:
                     pass
         deadline = time.time() + 2.0
+        killed = []
         for w in workers:
             if w.proc is not None:
                 try:
@@ -5279,8 +5318,19 @@ class Node:
                 except Exception:
                     try:
                         w.proc.kill()
+                        killed.append(w.proc)
                     except Exception:
                         pass
+        # a killed worker is not gone yet: one that held chips spends
+        # seconds unmapping tens of GB of device memory, and until it is
+        # done the chips are busy.  shutdown() returning means they are
+        # free — the next init() in this process may grant them at once.
+        deadline = time.time() + 30.0
+        for proc in killed:
+            try:
+                proc.wait(timeout=max(0.05, deadline - time.time()))
+            except Exception:
+                pass
         with self.lock:
             agents = [ns for ns in self.nodes.values() if ns.agent_conn is not None]
         for ns in agents:

@@ -2,41 +2,75 @@
 
 Analog of ``python/ray/_private/resource_spec.py`` — its
 ``_autodetect_num_gpus`` (``resource_spec.py:268``) counts GPUs; here we
-autodetect **TPU chips** instead, per SURVEY §2.1's TPU-port note: probe
-``/dev/accel*`` (TPU VM PCI devices) and ``/dev/vfio``, honor the
-``TPU_VISIBLE_CHIPS`` restriction the way the reference honors
-``CUDA_VISIBLE_DEVICES``, and allow an explicit override via
-``RAY_TPU_NUM_TPUS`` (tunneled/remote-attached chips are invisible in /dev).
+autodetect **TPU chips** instead, per SURVEY §2.1's TPU-port note.  A v5e
+host hands each chip to user space as one VFIO group, ``/dev/vfio/<n>``
+(one on the one-chip machine, four on the 2x2 host), so counting those
+finds the chips without loading libtpu — the head must never touch the
+device.  A ``TPU_VISIBLE_CHIPS`` restriction on the node's own
+environment is honored the way the reference honors
+``CUDA_VISIBLE_DEVICES``.
+
+This module is also the one seat of the per-process device environment
+(:func:`chip_env`): which variables make a worker own exactly the chips it
+was granted, and which keep a worker that was granted none off the device.
 """
 
 from __future__ import annotations
 
 import glob
 import os
+import sys
 from typing import Dict, List, Optional, Tuple
 
 
 def autodetect_tpus() -> Tuple[int, List[int]]:
     """(chip count, chip ids) from one consistent source — the count and the
     id list must never disagree (the ids become TPU_VISIBLE_CHIPS grants)."""
-    if "RAY_TPU_NUM_TPUS" in os.environ:
-        n = int(os.environ["RAY_TPU_NUM_TPUS"])
-        return n, list(range(n))
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible:
         ids = [int(c) for c in visible.split(",") if c.strip()]
         return len(ids), ids
-    accel = glob.glob("/dev/accel*")
-    if accel:
-        return len(accel), list(range(len(accel)))
-    vfio = glob.glob("/dev/vfio/[0-9]*")
-    if vfio:
-        return len(vfio), list(range(len(vfio)))
-    return 0, []
+    # libtpu numbers the host's chips 0..n-1 whatever the group numbers are
+    # (the one-chip machine exposes /dev/vfio/1 and calls its chip 0)
+    n = len(glob.glob("/dev/vfio/[0-9]*"))
+    return n, list(range(n))
 
 
-def autodetect_num_tpus() -> int:
-    return autodetect_tpus()[0]
+# libtpu's view of the host, for a process that owns only part of it: the
+# chips form a (x, y, z) block.  Without these a process given one chip of
+# a 2x2 host still claims the whole host and the second one fails on
+# libtpu's lockfile.  Shapes are the ones a v5e host can be cut into.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
+def chip_env(tpu_ids: Optional[List[int]]) -> Dict[str, str]:
+    """Environment that decides which device a worker process may claim.
+
+    ``None`` — the worker was granted no chip: JAX is held to the CPU, so
+    nothing it runs (a rollout worker importing jax, a ``map_batches`` UDF)
+    can take the chip from the worker that was granted it.  A list of chip
+    ids — the process owns exactly those chips: the visible-chips list plus
+    the bounds that tell libtpu the process is a slice of that shape."""
+    if tpu_ids is None:
+        return {"JAX_PLATFORMS": "cpu"}
+    ids = ",".join(str(i) for i in sorted(tpu_ids))
+    env = {"TPU_VISIBLE_CHIPS": ids, "RAY_TPU_ASSIGNED_TPUS": ids}
+    bounds = _CHIP_BOUNDS.get(len(tpu_ids))
+    if bounds is not None:
+        env["TPU_CHIPS_PER_HOST_BOUNDS"] = bounds
+        env["TPU_HOST_BOUNDS"] = "1,1,1"
+    return env
+
+
+def jax_backend_initialized() -> bool:
+    """Has THIS process started a JAX backend (and so, on a chip host,
+    claimed the device)?  Importing jax does not; the first array does.
+    Drivers and other chipless processes assert this stays False."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 def autodetect_resources(

@@ -690,16 +690,17 @@ def _execute_task(msg: dict) -> None:
         return
     dep_locs = msg.get("dep_locs", {})
     tpu_ids = msg.get("tpu_ids", [])
-    # Overwrite (not setdefault): a pooled worker may be reused for a task
-    # holding different chips than its previous one.  (jax/libtpu read the
-    # env at first init, so chip isolation is only airtight for dedicated
-    # actor workers — same caveat as CUDA_VISIBLE_DEVICES in the reference.)
-    if tpu_ids:
-        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in tpu_ids)
-        os.environ["RAY_TPU_ASSIGNED_TPUS"] = os.environ["TPU_VISIBLE_CHIPS"]
-    elif "RAY_TPU_ASSIGNED_TPUS" in os.environ and spec.get("actor_id") is None:
-        os.environ.pop("TPU_VISIBLE_CHIPS", None)
-        os.environ.pop("RAY_TPU_ASSIGNED_TPUS", None)
+    if tpu_ids and "RAY_TPU_ASSIGNED_TPUS" not in os.environ:
+        # first (and only) grant: this process owns these chips, so say so
+        # before anything here imports jax — libtpu reads the environment
+        # once, at backend start.  An actor's worker holds its chips for
+        # life; a pooled worker runs ONE chip-holding task and then retires
+        # (main loop), so a grant never changes under a live backend
+        from ray_tpu._private.resource_spec import chip_env
+        from ray_tpu.util import compile_cache
+
+        os.environ.update(chip_env(tpu_ids))
+        compile_cache.configure()
     w.current_task_id = spec["task_id"]
     # tenant context: nested submissions and get_runtime_context() inside
     # this task see the submitting job/namespace (set even when absent so
@@ -1121,6 +1122,13 @@ def main() -> None:
                     target.submit(_execute_task, msg)
                 else:
                     _execute_task(msg)
+                    if msg.get("tpu_ids") and spec.get("actor_id") is None:
+                        # a chip belongs to one process at a time and JAX
+                        # cannot hand one back: retire, as the reference
+                        # does after an accelerator task (max_calls=1), so
+                        # the next grant of this chip finds it free.  The
+                        # head returns the chip to the pool on our exit.
+                        break
         except KeyboardInterrupt:
             # a cancel's interrupt_main landed outside user code — either
             # between tasks (harmless) or in the tiny window between the

@@ -125,13 +125,8 @@ def blockwise_attention(
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 
-try:  # pallas import is deferred-safe: CPU-only envs may lack the TPU bits
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl  # noqa: E402
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 
 def _masked_scores(q_ref, k_ref, qi, ki, *, scale, causal, block_q, block_k,
@@ -463,8 +458,7 @@ def attention(
             return causal_skip_attention(q, k, v, scale=scale, block=256)
         return full_attention(q, k, v, causal=causal, scale=scale)
     if (
-        _HAS_PALLAS
-        and q.ndim == 4
+        q.ndim == 4
         and t_k >= 8192  # measured crossover vs the XLA paths on v5e
         and t_q % block_q == 0
         and t_k % block_k == 0

@@ -317,8 +317,8 @@ def train_one_step(
     t_wall = time.perf_counter()
     if hasattr(policy, "train_on_batch"):
         # server-resident learner (policy_server.py): the batch crosses
-        # the wire once and every SGD update runs device-side — per-
-        # minibatch round trips would dominate on a remote-attached chip
+        # the wire once and every SGD update runs device-side, with no
+        # readback between minibatches
         out = policy.train_on_batch(
             batch, num_sgd_iter=num_sgd_iter,
             sgd_minibatch_size=sgd_minibatch_size,

@@ -85,6 +85,8 @@ class ServeController:
         # actor to run with max_concurrency > #parked listeners)
         self._changed = threading.Condition(self._lock)
         self._stopped = threading.Event()
+        # live drain threads (_stop_replica); graceful_shutdown joins them
+        self._drains: List[threading.Thread] = []
         self._http_config = http_config or {}
         self._health_thread = threading.Thread(
             target=self._health_loop, daemon=True, name="serve-health"
@@ -301,8 +303,11 @@ class ServeController:
         return getattr(self, "_goal_config", None)
 
     def graceful_shutdown(self) -> bool:
-        """Kill every replica; the controller actor itself is killed by
-        serve.shutdown() afterwards."""
+        """Drain and kill every replica, and return once they are gone; the
+        controller actor itself is killed by serve.shutdown() afterwards —
+        which would take unfinished drains with it and leave their replicas
+        alive, holding their resources (a chip, on a TPU replica) until the
+        session ends."""
         self._stopped.set()
         with self._lock:
             for state in self._deployments.values():
@@ -310,6 +315,9 @@ class ServeController:
                     self._stop_replica(state, r)
             self._deployments.clear()
             self._changed.notify_all()  # release parked long-poll listeners
+            drains = list(self._drains)
+        for t in drains:  # each is bounded by its deployment's grace window
+            t.join()
         return True
 
     def ping(self) -> str:
@@ -608,7 +616,9 @@ class ServeController:
                 if replica in state.draining:
                     state.draining.remove(replica)
 
-        threading.Thread(target=drain, daemon=True, name=f"drain-{replica.tag}").start()
+        t = threading.Thread(target=drain, daemon=True, name=f"drain-{replica.tag}")
+        self._drains = [d for d in self._drains if d.is_alive()] + [t]
+        t.start()
 
     # ------------------------------------------------------------------
     # health loop (GcsHealthCheckManager-style active probing of replicas)

@@ -384,7 +384,9 @@ def shutdown() -> None:
     if _client is None:
         return
     try:
-        ray_tpu.get(_client.controller.graceful_shutdown.remote(), timeout=30)
+        # returns when every replica is drained and dead (each drain is
+        # bounded by its deployment's graceful_shutdown_timeout_s)
+        ray_tpu.get(_client.controller.graceful_shutdown.remote(), timeout=60)
     except Exception:
         pass
     for h in (_client.proxy, _client.controller):

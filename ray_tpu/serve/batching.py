@@ -46,11 +46,10 @@ class _Batcher:
     never pickled; built lazily on first call).
 
     ``max_concurrent_batches > 1`` lets the collector hand batch N+1 to a
-    worker thread while batch N is still executing.  On a TPU whose host
-    round trip dominates (remote-attached chips: a sync readback costs
-    ~100 ms regardless of size), overlapping batches is the difference
-    between ``batch/rtt`` and ``batch*K/rtt`` throughput — the device
-    serializes the actual compute either way."""
+    worker thread while batch N is still executing: one batch's host work
+    and readback overlap the next one's dispatch, and the device
+    serializes the actual compute either way.  What K is worth on a
+    directly attached chip has not been measured."""
 
     def __init__(self, run_fn: Callable[[List], List], max_batch_size: int,
                  timeout_s: float, max_concurrent_batches: int = 1):
@@ -175,8 +174,8 @@ def batch(_func: Optional[Callable] = None, *, max_batch_size: int = 8,
 
     ``max_concurrent_batches=K`` (default 1) overlaps up to K batch
     executions on concurrent threads — use when per-batch latency is
-    dominated by device round trips rather than compute (remote-attached
-    TPUs), and only if the decorated function is thread-safe.
+    dominated by host work and readback rather than device compute, and
+    only if the decorated function is thread-safe.
     """
     if max_batch_size < 1:
         raise ValueError("max_batch_size must be >= 1")

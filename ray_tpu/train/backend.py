@@ -9,7 +9,14 @@ rendezvous (``torch/config.py:69`` ``dist.init_process_group``):
 - with ``use_jax_distributed=True`` (real multi-host pods) rank 0
   publishes a coordinator address through the GCS KV and every worker
   calls ``jax.distributed.initialize`` so all hosts enter one SPMD
-  program over ICI/DCN.
+  program over ICI/DCN.  One worker per HOST, each owning all of its
+  host's chips.  It is NOT a way to join several one-chip workers on one
+  host: the runtime makes each of those an isolated 1x1x1 slice
+  (``resource_spec.chip_env``), and libtpu would need the opposite
+  description (one process grid over the host) to connect them.  For the
+  chips of one host use one worker that holds them all and a mesh over
+  its devices (``chip_smoke.py --chips 4``).  Tested on CPU processes
+  only (``tests/test_multihost.py``); never run on a chip.
 """
 
 from __future__ import annotations

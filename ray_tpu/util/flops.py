@@ -13,32 +13,36 @@ Conventions (unchanged from bench.py's originals):
   matmul fwd+bwd plus the ``12·L·D·T`` attention term; remat
   recomputation is never credited.
 - ``peak_flops`` is the bf16 peak of the chip generation, keyed by
-  substring of ``device.device_kind``; unknown kinds (CPU dev boxes
-  included) fall back to the v5e number so ratios stay comparable
-  across environments.
+  substring of ``device.device_kind``.  A kind that is not in the table
+  is an error: an MFU against somebody else's peak (a CPU run divided by
+  the v5e number) is a made-up ratio, not a diagnostic.
 """
 
 from __future__ import annotations
 
 from typing import Any, Optional
 
-# bf16 peak FLOP/s per chip generation (marketing peaks; MFU denominators)
+# bf16 peak FLOP/s per chip, by generation (Google Cloud TPU documentation,
+# the per-generation "System architecture" pages: v4 275, v5e 197, v5p 459,
+# v6e 918 TFLOP/s).  MFU denominators.
 PEAK_FLOPS_BF16 = {
     "v5 lite": 197e12, "v5litepod": 197e12, "v5e": 197e12,
     "v4": 275e12, "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12,
 }
 
-DEFAULT_PEAK_FLOPS = 197e12
-
 
 def peak_flops(device_kind: str) -> float:
     """bf16 peak FLOP/s for a device kind string (``jax.devices()[0]
-    .device_kind``); unknown kinds fall back to the v5e peak."""
+    .device_kind``).  Raises ``KeyError`` for a kind the table does not
+    hold — callers that only want a live diagnostic catch it and report
+    no MFU (``StepProfiler``); a benchmark lets it fail."""
     kind = (device_kind or "").lower()
     for k, v in PEAK_FLOPS_BF16.items():
         if k in kind:
             return v
-    return DEFAULT_PEAK_FLOPS
+    raise KeyError(
+        f"no bf16 peak for device kind {device_kind!r}: add it to "
+        f"PEAK_FLOPS_BF16 with its source, or pass peak= explicitly")
 
 
 def transformer_flops_per_token(n_params: int, n_layers: int,
@@ -70,11 +74,10 @@ def decode_flops_per_token(n_params: int) -> float:
 def mfu(tokens_per_sec: float, flops_per_token: float,
         device_kind: str = "", peak: Optional[float] = None) -> float:
     """Model FLOPs utilization: achieved model FLOP/s over the chip's
-    bf16 peak.  ``peak`` overrides the device-kind lookup (tests, CPU
-    dev boxes with a synthetic denominator)."""
+    bf16 peak.  ``peak`` overrides the device-kind lookup (tests, or a
+    synthetic denominator asked for by name); without it an unknown
+    ``device_kind`` raises (``peak_flops``)."""
     denom = peak if peak else peak_flops(device_kind)
-    if denom <= 0:
-        return 0.0
     return tokens_per_sec * flops_per_token / denom
 
 
@@ -82,18 +85,13 @@ def xla_cost_analysis_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
     """XLA's own FLOP count for one call of a jitted function, via
     ``lower(...).compile().cost_analysis()`` — the cross-check that keeps
     the analytical model honest (the two should agree within the remat /
-    non-matmul-op noise).  Returns None wherever the backend doesn't
-    expose cost analysis (never raises: this is a diagnostic, and a
-    backend quirk must not take down a bench or doctor run)."""
+    non-matmul-op noise).  Returns None where the function cannot be
+    lowered or the backend reports no FLOPs (never raises: this is a
+    diagnostic, and a backend quirk must not take down a bench or doctor
+    run)."""
     try:
-        lowered = jitted_fn.lower(*args, **kwargs)
-        compiled = lowered.compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
-        if not ca:
-            return None
-        f = ca.get("flops")
-        return float(f) if f else None
+        ca = jitted_fn.lower(*args, **kwargs).compile().cost_analysis()
     except Exception:
         return None
+    f = (ca or {}).get("flops")
+    return float(f) if f else None

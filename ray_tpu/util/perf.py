@@ -120,9 +120,8 @@ def sample_device_memory(device: Any = None) -> Optional[dict]:
             import jax
 
             device = jax.devices()[0]
-        dev_label = str(getattr(device, "id", 0))
-        ms = device.memory_stats() if hasattr(device, "memory_stats") \
-            else None
+        dev_label = str(device.id)
+        ms = device.memory_stats()  # None on the CPU backend
         if ms:
             return {
                 "device": dev_label, "kind": "hbm",
@@ -416,11 +415,7 @@ class StepProfiler:
         phases["other"] = wall - covered
         tokens = self._cur_tokens if self._cur_tokens is not None \
             else self.tokens_per_step
-        mfu = None
-        if tokens and self.flops_per_token and wall > 0:
-            mfu = flops_mod.mfu(
-                tokens / wall, self.flops_per_token,
-                self._resolve_device_kind(), peak=self._peak)
+        mfu = self._mfu(tokens, wall)
         with self._lock:
             self._n_steps += 1
             n = self._n_steps
@@ -452,16 +447,28 @@ class StepProfiler:
             **({"mfu": round(mfu, 5)} if mfu is not None else {}),
             **({"tokens": int(tokens)} if tokens else {}))
 
-    def _resolve_device_kind(self) -> str:
-        if self._device_kind is None:
-            try:
+    def _mfu(self, tokens: Optional[int], wall: float) -> Optional[float]:
+        """Live MFU of ``tokens`` in ``wall`` seconds, or None: without a
+        FLOPs model, and on a device the peak table does not know (the
+        CPU) — the gauge stays empty rather than showing a ratio against
+        another chip's peak.  The denominator is the ``peak=`` given, else
+        the table's entry for the device this process computes on."""
+        if not (tokens and self.flops_per_token and wall > 0):
+            return None
+        if self._peak is None:
+            if self._device_kind is None:
                 import jax
 
                 dev = self._device or jax.devices()[0]
-                self._device_kind = getattr(dev, "device_kind", "")
-            except Exception:
-                self._device_kind = ""
-        return self._device_kind
+                self._device_kind = dev.device_kind
+            try:
+                self._peak = flops_mod.peak_flops(self._device_kind)
+            except KeyError:
+                self._peak = 0.0  # looked up once: no peak, no MFU
+        if not self._peak:
+            return None
+        return flops_mod.mfu(tokens / wall, self.flops_per_token,
+                             peak=self._peak)
 
     # -- aggregate -----------------------------------------------------
     def summary(self) -> dict:
@@ -480,11 +487,7 @@ class StepProfiler:
                 "frac": round(v / wall, 4) if wall > 0 else 0.0}
             for k, v in sorted(phase_totals.items(),
                                key=lambda kv: -kv[1])}
-        mean_mfu = None
-        if tokens_total and self.flops_per_token and wall > 0:
-            mean_mfu = flops_mod.mfu(
-                tokens_total / wall, self.flops_per_token,
-                self._resolve_device_kind(), peak=self._peak)
+        mean_mfu = self._mfu(tokens_total, wall)
         return {
             "rank": self.rank,
             "steps": n_steps,

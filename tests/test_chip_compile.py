@@ -1,0 +1,169 @@
+"""The main path's programs, compiled for the v5e at their real widths.
+
+No chip is needed: the TPU compiler is installed and compiles for a chip
+that is described (``v5e:2x2``), not attached.  A compile that passes is
+not a chip run — it says the chip's compiler accepts the program and that
+it fits the device's memory, nothing about results or speed.  What it
+catches is what interpret mode and the CPU backend cannot: a Pallas slice
+the tiling refuses, too much VMEM, a step that does not fit 16 GB.
+
+Shapes only — nothing is allocated or run (``jax.eval_shape`` for the
+models, ``ShapeDtypeStruct`` pinned to the described chip for arguments).
+Skipped only where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+from concurrent.futures import ThreadPoolExecutor
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A sharding that pins arguments to one described v5e chip.  The
+    persistent compile cache is off meanwhile: a compile for a described
+    chip is written to it but cannot be read back without a chip (the next
+    one warns and recompiles)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(chip, tree):
+    """The shapes of ``tree`` as arguments living on the described chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert need < HBM_BYTES, f"program needs {need / 2**30:.1f} GiB"
+    return need
+
+
+def _lower_train_step(chip):
+    """The train phase of chip_smoke.py and bench.py: GPT-2 small unchanged
+    (12 layers, d_model 768, vocab 50304), B=6, T=1024, bf16, dots remat."""
+    from ray_tpu.models import gpt2
+
+    cfg = gpt2.GPT2Config.gpt2_small()
+    assert (cfg.remat, cfg.remat_policy, cfg.dtype) == (True, "dots", jnp.bfloat16)
+    optimizer = gpt2.make_optimizer(lr=3e-4)
+    state = jax.eval_shape(
+        lambda k: gpt2.init_state(cfg, k, optimizer), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((6, cfg.max_seq_len), jnp.int32)
+    step = jax.jit(gpt2.make_train_step(cfg, optimizer), donate_argnums=(0,))
+    return [step.lower(
+        _on(chip, state), _on(chip, {"inputs": tokens, "targets": tokens}))]
+
+
+def _lower_serve_engine(chip, family):
+    """What a ``num_tpus=1`` LLM replica runs (bench.run_decode_bench's
+    shape): prefill of bucket 128 at the fixed admission width, and one
+    64-step decode chunk over 16 slots plus the scratch slot."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config(family, "small")
+    n_slots, bucket, chunk, max_new = 16, 128, 64, 128
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(cfg.dtype) if x.dtype == jnp.float32 else x,
+        llm._default_init(cfg, 0)))
+    cache = jax.eval_shape(
+        lambda: gen.init_cache(cfg, n_slots + 1, bucket + max_new + chunk))
+    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    return [
+        prefill.lower(
+            _on(chip, params), _on(chip, i32(n_slots, bucket)),
+            _on(chip, i32(n_slots)), _on(chip, cache), _on(chip, i32(n_slots))),
+        decode.lower(
+            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
+            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
+            _on(chip, key)),
+    ]
+
+
+def _lower_bert(chip):
+    """The classifier bench.run_serve_bench serves: BERT-base, one static
+    batch of 16 x 128 tokens."""
+    from ray_tpu.models import bert
+
+    cfg = bert.BertConfig.base()
+    params = jax.eval_shape(lambda: bert.init(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((16, 128), jnp.int32)
+    fwd = jax.jit(lambda p, t: bert.apply(p, t, cfg))
+    return [fwd.lower(_on(chip, params), _on(chip, tokens))]
+
+
+def _lower_flash(chip, direction):
+    """The Pallas kernels the attention dispatcher picks on TPU from T=8192:
+    12 heads of 64, blocks of 128, causal."""
+    from ray_tpu.ops.attention import flash_attention_tpu
+
+    qkv = _on(chip, jax.ShapeDtypeStruct((1, 12, 8192, 64), jnp.bfloat16))
+
+    def attend(q, k, v):
+        return flash_attention_tpu(q, k, v, True, None, 128, 128, False)
+
+    if direction == "backward":
+        attend = jax.grad(
+            lambda q, k, v, f=attend: f(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))
+    return [jax.jit(attend).lower(qkv, qkv, qkv)]
+
+
+PROGRAMS = {
+    "gpt2_125m_train_step": _lower_train_step,
+    "serve_engine_gpt2": lambda chip: _lower_serve_engine(chip, "gpt2"),
+    "serve_engine_llama": lambda chip: _lower_serve_engine(chip, "llama"),
+    "bert_base_forward": _lower_bert,
+    "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
+    "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
+}
+
+
+@pytest.fixture(scope="module")
+def compiled(chip):
+    """Every program, lowered here and compiled side by side (the compiler
+    runs outside the GIL; one after another they cost twice the wall)."""
+    lowered = {name: lower(chip) for name, lower in PROGRAMS.items()}
+    with ThreadPoolExecutor(max_workers=8) as pool:
+        futures = {name: [pool.submit(low.compile) for low in lows]
+                   for name, lows in lowered.items()}
+    return futures  # .result() re-raises what the chip's compiler raised
+
+
+@pytest.mark.parametrize("name", list(PROGRAMS))
+def test_program_compiles_for_v5e(compiled, name):
+    programs = [f.result() for f in compiled[name]]
+    needs = [_fits(c) for c in programs]
+    if name == "gpt2_125m_train_step":
+        assert needs[0] > 1 * 2**30  # params + adam moments alone are 1.5 GB
+    if name.startswith("flash_attention"):
+        # must reach the chip's compiler as kernels, not as an XLA fallback:
+        # one forward; dq + dk/dv + the forward they differentiate
+        want = 1 if name.endswith("forward") else 3
+        assert programs[0].as_text().count("tpu_custom_call") >= want

@@ -41,7 +41,7 @@ def _wait_for(pred, timeout=30.0, interval=0.1):
 
 def test_flops_model_shared_with_bench():
     """util/flops.py carries the exact bench formulas: 6N + 12·L·D·T and
-    the per-generation peak table with a v5e fallback."""
+    the per-generation peak table; an unknown kind is an error."""
     from ray_tpu.models import gpt2
 
     cfg = gpt2.GPT2Config.tiny()
@@ -63,8 +63,10 @@ def test_flops_model_shared_with_bench():
     assert bench.peak_flops is flops_mod.peak_flops
     assert flops_mod.peak_flops("TPU v4") == 275e12
     assert flops_mod.peak_flops("TPU v5p") == 459e12
-    assert flops_mod.peak_flops("weird accelerator") == \
-        flops_mod.DEFAULT_PEAK_FLOPS  # fallback, never 0
+    with pytest.raises(KeyError):  # no made-up peak for an unknown kind
+        flops_mod.peak_flops("weird accelerator")
+    with pytest.raises(KeyError):
+        flops_mod.mfu(1000.0, 1e9, "cpu")
     assert flops_mod.mfu(1000.0, 1e9, peak=4e12) == pytest.approx(0.25)
     assert flops_mod.mfu(1000.0, 1e9, "TPU v4") == \
         pytest.approx(1e12 / 275e12)
