@@ -712,8 +712,13 @@ def _execute_task(msg: dict) -> None:
     # None — a pooled worker must not leak the previous task's context.
     from ray_tpu.util import tracing
 
-    tracing._current.set(spec.get("trace_ctx"))
+    trace_ctx = spec.get("trace_ctx")
     exec_start = time.time()  # profile event (core_worker profiling.h:30)
+    if trace_ctx is not None:
+        # `.remote()` -> here is the core runtime's share of a traced call
+        # (a `task.dispatch` span); nothing for a spec without a context
+        trace_ctx = tracing.task_arrived(trace_ctx, exec_start)
+    tracing._current.set(trace_ctx)
     failed = False
     error_str = None
     on_main = threading.current_thread() is threading.main_thread()

@@ -15,6 +15,7 @@ from typing import Any, Dict, Optional, Tuple
 import cloudpickle
 
 from ray_tpu.serve.exceptions import ReplicaDrainingError
+from ray_tpu.util import tracing
 
 
 class ServeReplica:
@@ -71,6 +72,13 @@ class ServeReplica:
                 raise ReplicaDrainingError(self.replica_tag)
             self._num_requests += 1
             self._inflight += 1
+        ctx = tracing.current_context()
+        if ctx is not None and "t_root" in ctx:
+            # ingress (the root's start) -> the router submitted this call:
+            # both stamps are the proxy's, read here so that the stage
+            # lands in the aggregate of the process that reports it.  The
+            # tree already has the router's admission span over it
+            tracing.fold("serve.route", max(0.0, ctx["t"] - ctx["t_root"]))
         try:
             return self._run_request(method_name, args, kwargs)
         finally:
@@ -120,13 +128,26 @@ class ServeReplica:
         from ray_tpu.serve._private.http_util import encode_chunk
 
         sid = uuid.uuid4().hex
+        # the request's trace context: the generator's body first runs on
+        # the stream thread, which adopts it (an engine request made there
+        # chains under this replica task), and the first ``next_chunks``
+        # reply with data closes the request's last stages under it
+        # (``stage_ctx``, cleared then)
+        ctx = tracing.current_context()
         state = {"q": queue_mod.Queue(maxsize=64), "done": False,
-                 "error": None, "stop": threading.Event()}
+                 "error": None, "stop": threading.Event(),
+                 "stage_ctx": ctx if ctx and "t_root" in ctx else None,
+                 "first_put_t": None}
 
         def drain(it=iter(result.iterable)):
+            token = tracing.adopt(ctx)
             try:
                 for chunk in it:
                     data = encode_chunk(chunk)
+                    if state["first_put_t"] is None:
+                        # stamped BEFORE the put: a poll that finds the
+                        # chunk must find its time too
+                        state["first_put_t"] = time.perf_counter()
                     while not state["stop"].is_set():
                         try:
                             state["q"].put(data, timeout=0.2)
@@ -141,6 +162,7 @@ class ServeReplica:
                 state["error"] = f"{type(e).__name__}: {e}"
             finally:
                 state["done"] = True
+                tracing.restore(token)
 
         threading.Thread(target=drain, daemon=True,
                          name=f"serve-stream-{sid[:8]}").start()
@@ -166,6 +188,18 @@ class ServeReplica:
         finished = state["done"] and state["q"].empty()
         if finished:
             self.cancel_stream(sid)
+        ctx = state["stage_ctx"]
+        if chunks and ctx is not None:
+            # the first reply that carries data: how long the first chunk
+            # lay in the queue waiting for this poll, and, on its own two
+            # clock reads, the whole way from ingress to here (the stage
+            # the others must add up to)
+            state["stage_ctx"] = None
+            tracing.emit_stage(
+                "serve.pickup",
+                time.perf_counter() - state["first_put_t"], ctx)
+            tracing.emit_stage(
+                "serve.first_reply", tracing.since(ctx["t_root"]), ctx)
         return {"chunks": chunks, "done": finished,
                 "error": state["error"] if finished else None}
 

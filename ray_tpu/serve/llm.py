@@ -29,6 +29,14 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private import events as _events
+from ray_tpu.util import compile_cache, tracing
+
+# the stages of one served request, ingress to first reply, as phases of
+# the span aggregate (``perf_stats()["stages"]``); emitted here, in the
+# worker's task entry and in ``serve/_private/replica.py``
+STAGES = ("serve.route", "task.dispatch", "serve.submit", "engine.queue",
+          "engine.first_token", "engine.stream_yield", "serve.pickup",
+          "serve.first_reply")
 
 # Lazy engine metric singletons.
 _LLM_METRICS = None
@@ -199,7 +207,7 @@ class _TickMeter:
 class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
                  "submitted_at", "trace_ctx", "first_token_t",
-                 "last_emit_t")
+                 "last_emit_t", "dispatched_at", "first_host_t")
 
     def __init__(self, tokens: List[int], max_new: int):
         self.tokens = list(tokens)
@@ -216,14 +224,20 @@ class _Request:
         # on the engine thread at drain time
         self.first_token_t: Optional[float] = None
         self.last_emit_t: Optional[float] = None
+        # stage boundaries (perf_counter): prefill dispatched, and the
+        # first token really on the host (``first_token_t`` is stamped
+        # before the drain waits for it)
+        self.dispatched_at: Optional[float] = None
+        self.first_host_t: Optional[float] = None
         # the submitter's trace context (a traced serve replica): the
         # engine loop runs on its own thread, so the context is captured
-        # HERE and the completion span is tagged with it at drain time
+        # HERE and the engine's spans are tagged with it.  A caller that
+        # brought none (a plain DeploymentHandle, bench.py) gets a root of
+        # the engine's own, so engine.* stages never depend on the ingress
         self.trace_ctx = None
         if _events.ENABLED:
-            from ray_tpu.util import tracing
-
-            self.trace_ctx = tracing.child_context("llm.generate")
+            self.trace_ctx = (tracing.child_context("llm.generate")
+                              or tracing.root_context("llm.generate"))
 
 
 class _PendingChunk:
@@ -265,6 +279,14 @@ class GenerationEngine:
         import jax
         from ray_tpu.models import generate as gen
 
+        # before the first program is built: every executable this process
+        # compiles or loads from now on is counted (perf_stats()["compiles"])
+        compile_cache.listen()
+        # host phases of the engine thread on the profiler's clock: they
+        # land in the same trace as the device's ops, so an idle gap there
+        # can be named by what this thread was doing (free when no trace
+        # is being taken)
+        self._annotate = jax.profiler.TraceAnnotation
         self._gen = gen
         self.cfg = cfg
         if params is None:
@@ -297,13 +319,19 @@ class GenerationEngine:
         self._prefill_jit, self._decode_jit = engine_programs(
             cfg, decode_chunk_steps=decode_chunk_steps,
             temperature=temperature, top_k=top_k, eos_id=eos_id)
-        self._sample_jit = jax.jit(
-            lambda logits, key: gen.sample_logits(
-                logits, key, temperature=temperature, top_k=top_k))
+        # named functions, not lambdas: a device trace lists each program
+        # under its function's name (jit_llm_sample_first, ...)
+        def llm_sample_first(logits, key):
+            return gen.sample_logits(
+                logits, key, temperature=temperature, top_k=top_k)
+
         # prefill's sampled first tokens fold into the device-resident
         # last-token row without a host round trip
-        self._merge_jit = jax.jit(
-            lambda last, slots, firsts: last.at[slots].set(firsts))
+        def llm_merge_first(last, slots, firsts):
+            return last.at[slots].set(firsts)
+
+        self._sample_jit = jax.jit(llm_sample_first)
+        self._merge_jit = jax.jit(llm_merge_first)
 
         self._slots: List[Optional[_Request]] = [None] * n_slots
         # device-resident last token per slot: decode chunk N+1 chains off
@@ -345,6 +373,13 @@ class GenerationEngine:
                 f"bucket {self.buckets[-1]}")
         req = _Request(tokens, min(max_new or self.max_new_tokens,
                                    self.max_new_tokens))
+        ctx = tracing.current_context()
+        if ctx is not None and "t_exec" in ctx:
+            # from where the worker's task.dispatch ended to the request
+            # existing: argument decoding, handle_request, and for a
+            # streamed request the stream thread's start
+            tracing.emit_stage("serve.submit", tracing.since(ctx["t_exec"]),
+                               ctx)
         with self._lock:
             self._queue.append(req)
             self.total_requests += 1
@@ -366,14 +401,21 @@ class GenerationEngine:
         n = 0
         deadline = time.perf_counter() + timeout
         while True:
+            # read BEFORE the snapshot: the engine resolves the future
+            # after its last append, so a done request's tokens are all in
+            done = req.future.done()
             emitted = req.emitted  # list append is atomic; len-snapshot safe
             m = len(emitted)
             while n < m:
+                if n == 0 and req.first_host_t is not None:
+                    # what this loop's poll adds to the first token
+                    tracing.emit_stage(
+                        "engine.stream_yield",
+                        time.perf_counter() - req.first_host_t,
+                        req.trace_ctx)
                 yield emitted[n]
                 n += 1
-            if req.future.done():
-                for t in req.emitted[n:]:
-                    yield t
+            if done:
                 req.future.result()  # surface engine errors
                 return
             if time.perf_counter() > deadline:
@@ -441,10 +483,20 @@ class GenerationEngine:
             # the same lock, so sorted() never sees a mutating deque
             ttft = list(self._ttft_samples)
             itl = list(self._itl_samples)
+        stages: Dict[str, Any] = {}
+        if _events.ENABLED:
+            stages = tracing.span_stats(STAGES)
+            stages["clock_skew"] = tracing.clock_skew()
         return {
             "ttft": self._pctiles(ttft),
             "itl": self._pctiles(itl),
             **self._ticks.snapshot(),
+            # the request's stages from ingress to the first reply, as this
+            # process closed them (cumulative: difference two calls for a
+            # window); empty with the observability layer off
+            "stages": stages,
+            "compiles": compile_cache.counts(),
+            "device": _device_facts(),
         }
 
     # -- engine loop ---------------------------------------------------
@@ -481,7 +533,8 @@ class GenerationEngine:
                 self._last_tok_dev = jnp.zeros((self.n_slots + 1,), jnp.int32)
                 worked = False
             if not worked:
-                self._work.wait(timeout=0.05)
+                with self._annotate("engine.wait_work"):
+                    self._work.wait(timeout=0.05)
                 self._work.clear()
 
     def _bucket(self, n: int) -> int:
@@ -520,6 +573,13 @@ class GenerationEngine:
             toks[j, :len(req.tokens)] = req.tokens
             lens[j] = len(req.tokens)
             slots[j] = slot
+        if _events.ENABLED:
+            t_dispatch = time.perf_counter()
+            for _, req in batch:
+                req.dispatched_at = t_dispatch
+                tracing.emit_stage(
+                    "engine.queue", t_dispatch - req.submitted_at,
+                    req.trace_ctx)
         last_logits, self.cache = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
             self.cache, jnp.asarray(slots))
@@ -569,7 +629,8 @@ class GenerationEngine:
 
         meter = self._ticks if _events.ENABLED else None
         t_tick0 = time.perf_counter() if meter else 0.0
-        admissions, firsts_dev = self._admit()
+        with self._annotate("engine.admit"):
+            admissions, firsts_dev = self._admit()
         admit_s = (time.perf_counter() - t_tick0) if meter else 0.0
         with self._lock:
             rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
@@ -582,13 +643,14 @@ class GenerationEngine:
                 decoding=len(rows) - len(admissions))
         dispatched = None
         if rows:
-            active = np.zeros((self.n_slots + 1,), bool)  # scratch inactive
-            active[[i for i, _ in rows]] = True
-            chunk_dev, self.cache, self._last_tok_dev, self._key = (
-                self._decode_jit(
-                    self.params, self.cache, self._last_tok_dev,
-                    jnp.asarray(active), self._key))
-            chunk_dev.copy_to_host_async()
+            with self._annotate("engine.decode_dispatch"):
+                active = np.zeros((self.n_slots + 1,), bool)  # scratch inactive
+                active[[i for i, _ in rows]] = True
+                chunk_dev, self.cache, self._last_tok_dev, self._key = (
+                    self._decode_jit(
+                        self.params, self.cache, self._last_tok_dev,
+                        jnp.asarray(active), self._key))
+                chunk_dev.copy_to_host_async()
             dispatched = _PendingChunk(chunk_dev, rows, admissions, firsts_dev)
             # cap-based predicted completion: these slots are free for the
             # NEXT admission even though their token values haven't landed
@@ -602,7 +664,8 @@ class GenerationEngine:
         prev, self._pending = self._pending, dispatched
         if prev is not None:
             self._draining = prev  # visible to _loop's error recovery
-            self._drain(prev)
+            with self._annotate("engine.drain"):
+                self._drain(prev)
             self._draining = None
         if meter is not None:
             # the tick wall covers admit + decode dispatch + the drain
@@ -619,12 +682,14 @@ class GenerationEngine:
         instrumented = _events.ENABLED
         drain_t = time.perf_counter()
         if pending.firsts_dev is not None:
-            firsts = np.asarray(pending.firsts_dev)
+            firsts = np.asarray(pending.firsts_dev)  # waits for the prefill
+            first_host_t = time.perf_counter()
             for j, slot, req in pending.admissions:
-                req.emitted.append(int(firsts[j]))
                 if instrumented and req.first_token_t is None:
-                    # TTFT: submit -> first token MATERIALIZED on the
-                    # host (the moment a streaming client could see it)
+                    # TTFT: submit -> this drain's START (drain_t): the
+                    # wait for the prefill just above is not in it, so it
+                    # reads early by about a prefill.  The stage below ends
+                    # where the token really is on the host
                     req.first_token_t = drain_t
                     req.last_emit_t = drain_t
                     ttft = drain_t - req.submitted_at
@@ -633,6 +698,14 @@ class GenerationEngine:
                         # an unlocked concurrent append would raise
                         # "deque mutated during iteration" there
                         self._ttft_samples.append(ttft)
+                    if req.dispatched_at is not None:
+                        req.first_host_t = first_host_t
+                        tracing.emit_stage(
+                            "engine.first_token",
+                            first_host_t - req.dispatched_at, req.trace_ctx)
+                # after the stamps: the stream thread reads them the
+                # moment it sees the token
+                req.emitted.append(int(firsts[j]))
         chunk = np.asarray(pending.chunk_dev)  # transfer already in flight
         for i, req in pending.rows:
             if req.future.done():
@@ -708,11 +781,15 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
 
     from ray_tpu.models import generate as gen
 
+    def llm_prefill(params, toks, lens, cache, slots):
+        return gen.prefill_at(params, cfg, toks, lens, cache, slots)
+
     prefill = jax.jit(
-        lambda params, toks, lens, cache, slots: gen.prefill_at(
-            params, cfg, toks, lens, cache, slots),
+        llm_prefill,
         donate_argnums=(3,),  # scatter into the cache in place
     )
+    # the decode program keeps the name XLA gives a jitted partial
+    # (jit__unknown): the benchmark's cell files find it by that name
     decode = jax.jit(
         partial(
             _decode_chunk_wrapper, gen, cfg,
@@ -733,6 +810,20 @@ def _decode_chunk_wrapper(gen, cfg, params, cache, tokens, active, key, *,
     # round trip (inactive slots carry their input token through, so
     # emitted[:, -1] is correct for every slot)
     return emitted, cache, emitted[:, -1], key
+
+
+def _device_facts() -> Dict[str, Any]:
+    """What this process's JAX runs on, and the most device memory it has
+    held, read now (None where the backend keeps no such count)."""
+    import jax
+
+    from ray_tpu.util import perf as _perf
+
+    devs = jax.devices()
+    sample = _perf.sample_device_memory(devs[0]) or {}
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "count": len(devs),
+            "memory_peak_bytes": sample.get("peak_bytes_in_use")}
 
 
 def _default_init(cfg, seed: int):
@@ -938,5 +1029,15 @@ def llm_deployment(
 
         def perf_stats(self):
             return self.engine.perf_stats()
+
+        def trace(self, logdir: str, seconds: float) -> str:
+            """Take a device trace of this replica for ``seconds`` into
+            ``logdir`` (on the replica's host) while it keeps serving;
+            the engine thread's phases are in it by name."""
+            from ray_tpu.util import profiling
+
+            with profiling.profile_trace(logdir):
+                time.sleep(seconds)
+            return logdir
 
     return LLMServer

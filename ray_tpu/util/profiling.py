@@ -1,19 +1,17 @@
-"""Profiling helpers: XLA device traces + optional OpenTelemetry spans.
+"""Profiling helpers: XLA device traces.
 
-The reference's tracing layer (``python/ray/util/tracing/tracing_helper.py``
-— lazily imported opentelemetry, span contexts injected into task
-metadata) and its on-demand profiling endpoints
-(``dashboard/modules/reporter/profile_manager.py``).  TPU additions:
-``profile_trace`` captures an XLA/jax device trace viewable in
-TensorBoard or Perfetto — the device-side half the reference never had.
+The reference's on-demand profiling endpoints
+(``dashboard/modules/reporter/profile_manager.py``), with the device-side
+half it never had: ``profile_trace`` captures an XLA/jax device trace
+viewable in TensorBoard or Perfetto.  Spans of a request are
+``ray_tpu.util.tracing``'s.
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
-import time
-from typing import Iterator, Optional
+from typing import Iterator
 
 # one device trace at a time per process: jax.profiler.start_trace raises
 # out of XLA on a second concurrent start, and a nested profile scope
@@ -36,6 +34,12 @@ def profile_trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
     Re-entrant by degrading: when a trace is already running in this
     process the inner scope is a no-op (the outer trace still covers it)
     rather than an XLA "profiler already started" crash.
+
+    The profiler's python tracer is OFF: it hooks every Python call of the
+    process and slows a host-bound thread severalfold (a serve engine read
+    48-52 % device idle under it and 0 % without).  ``host_tracer_level``
+    is the profiler's own (2 keeps ``TraceAnnotation`` spans and JAX's
+    launches, 0 leaves the device planes alone).
     """
     global _trace_active
     import jax
@@ -49,7 +53,10 @@ def profile_trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
         yield
         return
     try:
-        jax.profiler.start_trace(logdir, create_perfetto_trace=False)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = host_tracer_level
+        jax.profiler.start_trace(logdir, profiler_options=options)
     except Exception:
         # a start failure (e.g. a foreign profiler session already owns
         # the backend) must not take the step down with it
@@ -83,40 +90,3 @@ def profile_step(logdir: str) -> bool:
         return False
     prof.arm_trace(logdir)
     return True
-
-
-@contextlib.contextmanager
-def span(name: str, attributes: Optional[dict] = None) -> Iterator[None]:
-    """OpenTelemetry span when the SDK is importable, no-op otherwise
-    (the reference's lazy-import pattern, ``tracing_helper.py:53-59``)."""
-    try:
-        from opentelemetry import trace  # type: ignore
-    except ImportError:
-        yield
-        return
-    tracer = trace.get_tracer("ray_tpu")
-    with tracer.start_as_current_span(name, attributes=attributes or {}):
-        yield
-
-
-class timed:
-    """Tiny wall-clock scope, recorded into ray_tpu.util.metrics::
-
-        with profiling.timed("ingest_batch"):
-            ...
-    """
-
-    def __init__(self, name: str):
-        self.name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        from ray_tpu.util.metrics import Histogram
-
-        Histogram(f"ray_tpu_timed_{self.name}_seconds",
-                  f"wall time of {self.name} scopes").observe(
-            time.perf_counter() - self._t0)
-        return False

@@ -25,6 +25,13 @@ per-trace :class:`~ray_tpu._private.events.TraceTable` served by
 Presence of a context IS the enable signal: outside any ``trace()`` block
 nothing is recorded and task specs stay clean, so the disabled path costs
 one contextvar read per submission.
+
+Contexts carry wall-clock start times (``t``, and the root's as ``t_root``),
+so a stage that crosses a process boundary is computed by the RECEIVER from
+the context alone (:func:`since`).  Every closed span is also folded, by
+``phase``, into a per-process aggregate (:func:`span_stats`): the one
+emission feeds both the per-request tree and the numbers a process reports
+about itself (``GenerationEngine.perf_stats()["stages"]``).
 """
 
 from __future__ import annotations
@@ -34,7 +41,8 @@ import contextvars
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional
+from collections import deque
+from typing import Any, Dict, Iterable, Iterator, Optional
 
 from ray_tpu._private import events as _events
 from ray_tpu._private import log_plane as _log_plane
@@ -70,14 +78,29 @@ _id_lock = threading.Lock()
 _id_prefix = ""
 _id_n = 0
 
+# --- per-process span aggregate -------------------------------------------
+# phase -> [count, sum_s, reservoir of the last durations]: count and sum
+# are cumulative, so after-minus-before is exact for a window; the
+# reservoir (bounded like the engine's TTFT deque) backs the percentiles
+STATS_RESERVOIR = 4096
+_stats_lock = threading.Lock()
+_stats: Dict[str, list] = {}
+# cross-process stages that came out negative (the sender's clock ahead of
+# the receiver's) and were clamped to 0
+_clock_skew = 0
+
 
 def _reseed_ids() -> None:
     # fresh lock too: the fork may have happened while another thread of
     # the parent held _id_lock — the child inherits it locked forever
-    global _id_lock, _id_prefix, _id_n
+    global _id_lock, _id_prefix, _id_n, _stats_lock, _clock_skew
     _id_lock = threading.Lock()
     _id_prefix = ""
     _id_n = 0
+    # the span aggregate is per process: a forked child starts its own
+    _stats_lock = threading.Lock()
+    _stats.clear()
+    _clock_skew = 0
 
 
 os.register_at_fork(after_in_child=_reseed_ids)
@@ -113,6 +136,28 @@ def current_context() -> Optional[Dict[str, str]]:
     return _current.get()
 
 
+def _make_context(name: str, parent: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    """A fresh span context under ``parent`` (a root without one), stamped
+    with the wall-clock time of its creation (``t``) and of its root's
+    (``t_root``): what a receiving process computes cross-process stages
+    from."""
+    now = time.time()
+    ctx = {
+        "trace_id": parent["trace_id"] if parent else new_trace_id(),
+        "span_id": new_span_id(),
+        "parent_span_id": parent["span_id"] if parent else "",
+        "name": name,
+        "t": now,
+        "t_root": parent.get("t_root", now) if parent else now,
+    }
+    job = parent.get("job") if parent else _current_job()
+    if job:
+        # tenant identity rides the context: every span of the trace can
+        # be attributed to the submitting job (multi-tenant trace audit)
+        ctx["job"] = job
+    return ctx
+
+
 @contextlib.contextmanager
 def trace(name: str, attributes: Optional[dict] = None,
           phase: str = "span") -> Iterator[Dict[str, str]]:
@@ -120,18 +165,7 @@ def trace(name: str, attributes: Optional[dict] = None,
     their workers continue the same trace.  On exit the timed span is
     emitted into the flight recorder (``trace`` source), which is what
     the head's TraceTable assembles per-trace span trees from."""
-    parent = _current.get()
-    ctx = {
-        "trace_id": parent["trace_id"] if parent else new_trace_id(),
-        "span_id": new_span_id(),
-        "parent_span_id": parent["span_id"] if parent else "",
-        "name": name,
-    }
-    job = parent.get("job") if parent else _current_job()
-    if job:
-        # tenant identity rides the context: every span of the trace can
-        # be attributed to the submitting job (multi-tenant trace audit)
-        ctx["job"] = job
+    ctx = _make_context(name, _current.get())
     token = _ctx_set(ctx)
     otel_cm = _otel_span(name, attributes)
     t0 = time.perf_counter()
@@ -166,7 +200,7 @@ def _otel_span(name: str, attributes: Optional[dict]):
     return tracer.start_as_current_span(name, attributes=attributes or {})
 
 
-def child_context(name: str) -> Optional[Dict[str, str]]:
+def child_context(name: str) -> Optional[Dict[str, Any]]:
     """A fresh span context chained under the caller's (None when tracing
     is off).  Used for outgoing task specs, router admissions, compiled
     ``execute()`` payloads — anything that continues the trace in another
@@ -174,15 +208,14 @@ def child_context(name: str) -> Optional[Dict[str, str]]:
     parent = current_context()
     if parent is None:
         return None
-    ctx = {
-        "trace_id": parent["trace_id"],
-        "span_id": new_span_id(),
-        "parent_span_id": parent["span_id"],
-        "name": name,
-    }
-    if parent.get("job"):
-        ctx["job"] = parent["job"]
-    return ctx
+    return _make_context(name, parent)
+
+
+def root_context(name: str) -> Dict[str, Any]:
+    """A root context that is NOT made current: for work that must be
+    traceable though its caller brought no context (the LLM engine's
+    requests from a plain ``DeploymentHandle`` caller)."""
+    return _make_context(name, None)
 
 
 # outgoing-task alias kept for the original call sites (worker.py)
@@ -247,6 +280,86 @@ def emit_span(name: str, dur_s: float, ctx: Optional[Dict[str, str]],
         TRACE_SOURCE, name, severity=severity, entity_id=ctx["trace_id"],
         span_dur=dur_s, trace_id=ctx["trace_id"], span_id=ctx["span_id"],
         parent_span_id=ctx.get("parent_span_id", ""), phase=phase, **safe)
+    fold(phase, dur_s)
+
+
+def fold(phase: str, dur_s: float) -> None:
+    """Add one closed span to this process's aggregate without drawing it
+    in any tree (``emit_span`` does both).  Nothing with the observability
+    layer disabled."""
+    if not _events.ENABLED:
+        return
+    with _stats_lock:
+        row = _stats.get(phase)
+        if row is None:
+            row = _stats[phase] = [0, 0.0, deque(maxlen=STATS_RESERVOIR)]
+        row[0] += 1
+        row[1] += dur_s
+        row[2].append(dur_s)
+
+
+def span_stats(phases: Optional[Iterable[str]] = None) -> Dict[str, dict]:
+    """``{phase: {count, sum_s, p50_s, p95_s}}`` of the spans this process
+    closed (every phase seen, or those of ``phases`` that were).  ``count``
+    and ``sum_s`` are cumulative since the process started; the
+    percentiles are over the last ``STATS_RESERVOIR`` spans of the phase."""
+    wanted = None if phases is None else set(phases)
+    with _stats_lock:
+        rows = {p: (r[0], r[1], list(r[2])) for p, r in _stats.items()
+                if wanted is None or p in wanted}
+    out = {}
+    for p, (count, sum_s, samples) in rows.items():
+        samples.sort()
+        n = len(samples)
+        out[p] = {"count": count, "sum_s": sum_s,
+                  "p50_s": samples[n // 2],
+                  "p95_s": samples[min(n - 1, int(n * 0.95))]}
+    return out
+
+
+def since(t: float, now: Optional[float] = None) -> float:
+    """Seconds from a context's wall-clock stamp ``t`` (another process's
+    ``time.time()``) to ``now`` on this process's clock: how the receiver
+    times a stage that crossed a process boundary.  A negative reading is
+    clock skew, not time: clamped to 0 and counted (:func:`clock_skew`)."""
+    global _clock_skew
+    d = (time.time() if now is None else now) - t
+    if d < 0:
+        with _stats_lock:
+            _clock_skew += 1
+        return 0.0
+    return d
+
+
+def clock_skew() -> int:
+    """How many cross-process stages this process clamped to 0."""
+    return _clock_skew
+
+
+def emit_stage(phase: str, dur_s: float,
+               parent: Optional[Dict[str, Any]], **data) -> None:
+    """One closed stage [now - dur_s, now], named by its phase, as a fresh
+    child span of ``parent``.  No-op without a parent context."""
+    if parent is None or not _events.ENABLED:
+        return
+    ctx = {"trace_id": parent["trace_id"], "span_id": new_span_id(),
+           "parent_span_id": parent["span_id"]}
+    if parent.get("job"):
+        ctx["job"] = parent["job"]
+    emit_span(phase, dur_s, ctx, phase=phase, **data)
+
+
+def task_arrived(ctx: Dict[str, Any], now: float) -> Dict[str, Any]:
+    """The executing worker's half of a task hop.  Emits ``task.dispatch``
+    (``.remote()``, the spec context's ``t``, to ``now``, when the worker
+    reaches the task) and returns the context to adopt: the same one
+    carrying ``now`` as ``t_exec``, so that whatever stage comes next
+    starts exactly where this one ended."""
+    t = ctx.get("t")
+    if t is None:  # a hand-made context carries no clock
+        return ctx
+    emit_stage("task.dispatch", since(t, now), ctx)
+    return dict(ctx, t_exec=now)
 
 
 @contextlib.contextmanager

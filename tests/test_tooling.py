@@ -256,22 +256,6 @@ def test_timeline_exec_slices(ray_start_regular, tmp_path):
     assert len(queued) == 2
 
 
-def test_profiling_timed_scope(ray_start_regular):
-    from ray_tpu.util import profiling
-    from ray_tpu.util.metrics import registry
-
-    with profiling.timed("unit_scope"):
-        time.sleep(0.01)
-    snap = registry().snapshot()
-    assert "ray_tpu_timed_unit_scope_seconds" in snap
-    vals = list(snap["ray_tpu_timed_unit_scope_seconds"]["values"].values())
-    assert vals[0]["count"] >= 1 and vals[0]["sum"] >= 0.01
-
-    # span() is a no-op without opentelemetry installed
-    with profiling.span("noop-span"):
-        pass
-
-
 def test_usage_report_written(tmp_path):
     """Opt-out usage stats: a session report lands in the session dir
     (local-only; the reference posts the same schema to a collector)."""
