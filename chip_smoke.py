@@ -491,11 +491,21 @@ class Smoke:
                  for d, b in m["param_bytes_per_device"].items()}
         if len(share) != 4 or not all(0.2 <= s <= 0.3 for s in share.values()):
             raise RuntimeError(f"parameters are not spread over four devices: {share}")
+        # what the compiled step moves between chips (kind -> place ->
+        # [count, bytes of the largest operand]): under fsdp the layer loop
+        # gathers weights, so an all-gather in it says the mechanism engaged
+        collectives = {
+            kind: {place: [e["count"], e["max_operand_bytes"]]
+                   for place, e in places.items() if e["count"]}
+            for kind, places in m["collectives"].items()}
+        if "in_loop" not in collectives["all-gather"]:
+            raise RuntimeError(f"no per-layer weight gather in the fsdp2 x tp2 "
+                               f"step: {collectives}")
         self.device = m["device"]
         return {"mesh": m["mesh"], "loss": m["loss"],
                 "single_device_loss": m["ref_loss"],
                 "param_share_per_device": {d: round(s, 4) for d, s in share.items()},
-                "device": m["device"]}
+                "collectives": collectives, "device": m["device"]}
 
     # -- run -----------------------------------------------------------
     def run(self) -> bool:

@@ -22,6 +22,7 @@ from ray_tpu.models.transformer import (
     init_block_params,
 )
 from ray_tpu.ops.layers import layernorm
+from ray_tpu.parallel.sharding import ShardingRules
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +73,7 @@ def logical_axes(cfg: Optional["BertConfig"] = None) -> Dict[str, Any]:
         "wtype": (None, "embed"),
         "ln_emb_w": ("embed",), "ln_emb_b": ("embed",),
         "blocks": block_logical_axes(cfg.n_experts if cfg else 0),
-        "pool_w": ("embed", "embed"),
+        "pool_w": ("embed", None),  # one mesh axis shards one dimension
         "pool_b": ("embed",),
         "cls_w": ("embed", None),
         "cls_b": (None,),
@@ -82,14 +83,16 @@ def logical_axes(cfg: Optional["BertConfig"] = None) -> Dict[str, Any]:
 def apply(
     params: Dict[str, Any], tokens: jax.Array, cfg: BertConfig,
     token_types: Optional[jax.Array] = None, mesh: Optional[Mesh] = None,
+    rules: Optional[ShardingRules] = None,
 ) -> jax.Array:
-    """tokens [B, T] -> class logits [B, num_classes]."""
+    """tokens [B, T] -> class logits [B, num_classes].  ``rules``: the table
+    the parameters were placed with, when it is not ``rules_for_mesh(mesh)``."""
     B, T = tokens.shape
     x = params["wte"][tokens] + params["wpe"][:T]
     if token_types is not None:
         x = x + params["wtype"][token_types]
     x = layernorm(x, params["ln_emb_w"], params["ln_emb_b"]).astype(cfg.dtype)
-    x, _ = apply_stack(x, params["blocks"], cfg, mesh)
+    x, _ = apply_stack(x, params["blocks"], cfg, mesh, rules)
     cls = jnp.tanh(x[:, 0].astype(jnp.float32) @ params["pool_w"] + params["pool_b"])
     return cls @ params["cls_w"] + params["cls_b"]
 

@@ -23,8 +23,14 @@ from ray_tpu.models.transformer import (
     block_logical_axes,
     init_block_params,
 )
-from ray_tpu.ops.layers import cross_entropy_loss, layernorm
-from ray_tpu.parallel.sharding import ShardingRules, logical_to_sharding
+from ray_tpu.ops.layers import cross_entropy_loss, dense, layernorm
+from ray_tpu.parallel.sharding import (
+    ShardingRules,
+    fsdp_engaged,
+    gather_for_compute,
+    logical_to_sharding,
+    shard_activations,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,24 +82,37 @@ def param_shardings(mesh: Mesh, rules: ShardingRules, cfg: Optional[GPT2Config] 
 def apply(
     params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config,
     mesh: Optional[Mesh] = None, *, return_aux: bool = False,
+    rules: Optional[ShardingRules] = None,
 ):
     """tokens [B, T] int32 -> logits [B, T, V] (f32).
 
     With ``return_aux=True`` returns ``(logits, aux)`` where aux is the
-    MoE load-balance loss (0 for dense configs)."""
+    MoE load-balance loss (0 for dense configs).  ``rules``: the table the
+    parameters were placed with, when it is not ``rules_for_mesh(mesh)``."""
     B, T = tokens.shape
-    x = params["wte"][tokens] + params["wpe"][:T]
-    x = x.astype(cfg.dtype)
-    x, aux = apply_stack(x, params["blocks"], cfg, mesh)
-    x = layernorm(x, params["lnf_w"].astype(cfg.dtype), params["lnf_b"].astype(cfg.dtype))
+    # under an fsdp mesh axis each parameter comes whole along fsdp in the
+    # dtype it is used in, activations and logits stay on the batch and
+    # parameter gradients are summed in float32; else these do nothing
+    axes = logical_axes(cfg)
+    whole = lambda name, dtype: gather_for_compute(  # noqa: E731
+        params[name], axes[name], mesh, rules, dtype)
+    x = params["wte"][tokens] + whole("wpe", params["wpe"].dtype)[:T]
+    x = shard_activations(x.astype(cfg.dtype), mesh, rules)
+    x, aux = apply_stack(x, params["blocks"], cfg, mesh, rules)
+    f32g = fsdp_engaged(mesh, x)
+    x = layernorm(x, whole("lnf_w", cfg.dtype), whole("lnf_b", cfg.dtype),
+                  f32_param_grads=f32g)
     # tied embeddings for the LM head
-    logits = (x @ params["wte"].T.astype(cfg.dtype)).astype(jnp.float32)
+    head = gather_for_compute(params["wte"].T, axes["wte"][::-1], mesh, rules,
+                              cfg.dtype)
+    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
+    logits = shard_activations(logits, mesh, rules, "vocab")
     return (logits, aux) if return_aux else logits
 
 
 def loss_fn(
     params: Dict[str, Any], batch: Dict[str, jax.Array], cfg: GPT2Config,
-    mesh: Optional[Mesh] = None,
+    mesh: Optional[Mesh] = None, rules: Optional[ShardingRules] = None,
 ) -> jax.Array:
     """Next-token cross entropy. batch: {"tokens": [B, T+1]} or
     {"inputs": [B,T], "targets": [B,T]}."""
@@ -101,7 +120,7 @@ def loss_fn(
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    logits, aux = apply(params, inputs, cfg, mesh, return_aux=True)
+    logits, aux = apply(params, inputs, cfg, mesh, return_aux=True, rules=rules)
     loss = cross_entropy_loss(logits, targets)
     if cfg.n_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux
@@ -120,12 +139,13 @@ def make_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
     )
 
 
-def make_train_step(cfg: GPT2Config, optimizer, mesh: Optional[Mesh] = None):
+def make_train_step(cfg: GPT2Config, optimizer, mesh: Optional[Mesh] = None,
+                    rules: Optional[ShardingRules] = None):
     """Returns train_step(state, batch) -> (state, metrics); jit/pjit-able,
     donate state for in-place updates."""
     from ray_tpu.models.transformer import make_train_step_from_loss
 
-    return make_train_step_from_loss(loss_fn, cfg, optimizer, mesh)
+    return make_train_step_from_loss(loss_fn, cfg, optimizer, mesh, rules)
 
 
 def init_state(cfg: GPT2Config, key: jax.Array, optimizer) -> Dict[str, Any]:

@@ -15,6 +15,7 @@ factor).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -23,8 +24,14 @@ from jax.sharding import Mesh
 
 from ray_tpu.models.gpt2 import make_optimizer  # same AdamW recipe
 from ray_tpu.models.transformer import make_train_step_from_loss
-from ray_tpu.ops.layers import cross_entropy_loss, rmsnorm, rope
-from ray_tpu.parallel.sharding import ShardingRules, logical_to_sharding
+from ray_tpu.ops.layers import cross_entropy_loss, dense, rmsnorm, rope
+from ray_tpu.parallel.sharding import (
+    ShardingRules,
+    fsdp_engaged,
+    gather_for_compute,
+    logical_to_sharding,
+    shard_activations,
+)
 
 __all__ = [
     "LlamaConfig", "init", "apply", "loss_fn", "make_train_step",
@@ -132,12 +139,16 @@ def param_shardings(mesh: Mesh, rules: ShardingRules, cfg: Optional[LlamaConfig]
 def _block(x, p, cfg: LlamaConfig, mesh: Optional[Mesh], positions):
     B, T, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    dt = cfg.dtype
+    # under fsdp the batch is spread over chips: sum the parameters'
+    # gradients over it in float32
+    f32g = fsdp_engaged(mesh, x)
+    lin = partial(dense, f32_param_grads=f32g)
+    norm = partial(rmsnorm, eps=cfg.rms_eps, f32_param_grads=f32g)
 
-    h = rmsnorm(x, p["attn_norm"].astype(dt), eps=cfg.rms_eps)
-    q = (h @ p["wq"].astype(dt)).reshape(B, T, H, hd)
-    k = (h @ p["wk"].astype(dt)).reshape(B, T, KV, hd)
-    v = (h @ p["wv"].astype(dt)).reshape(B, T, KV, hd)
+    h = norm(x, p["attn_norm"])
+    q = lin(h, p["wq"]).reshape(B, T, H, hd)
+    k = lin(h, p["wk"]).reshape(B, T, KV, hd)
+    v = lin(h, p["wv"]).reshape(B, T, KV, hd)
     q = rope(q.transpose(0, 2, 1, 3), positions, base=cfg.rope_base)  # [B,H,T,hd]
     k = rope(k.transpose(0, 2, 1, 3), positions, base=cfg.rope_base)  # [B,KV,T,hd]
     v = v.transpose(0, 2, 1, 3)
@@ -151,21 +162,33 @@ def _block(x, p, cfg: LlamaConfig, mesh: Optional[Mesh], positions):
 
     o = _attend(q, k, v, causal=True, mesh=mesh)  # [B, H, T, hd]
     o = o.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
-    x = x + o @ p["wo"].astype(dt)
+    x = x + lin(o, p["wo"])
 
-    h = rmsnorm(x, p["ffn_norm"].astype(dt), eps=cfg.rms_eps)
-    gated = jax.nn.silu(h @ p["w_gate"].astype(dt)) * (h @ p["w_up"].astype(dt))
-    return x + gated @ p["w_down"].astype(dt)
+    h = norm(x, p["ffn_norm"])
+    gated = jax.nn.silu(lin(h, p["w_gate"])) * lin(h, p["w_up"])
+    return x + lin(gated, p["w_down"])
 
 
 def apply(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
-          mesh: Optional[Mesh] = None) -> jax.Array:
-    """tokens [B, T] int32 -> logits [B, T, V] f32 (tied embeddings)."""
+          mesh: Optional[Mesh] = None,
+          rules: Optional[ShardingRules] = None) -> jax.Array:
+    """tokens [B, T] int32 -> logits [B, T, V] f32 (tied embeddings).
+    ``rules``: the table the parameters were placed with, when it is not
+    ``rules_for_mesh(mesh)``."""
     B, T = tokens.shape
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    # under an fsdp mesh axis (as in transformer.apply_stack): each layer's
+    # weights whole along fsdp, moved in cfg.dtype, activations and logits on the
+    # batch, parameter gradients summed in float32; else these do nothing
+    axes = logical_axes(cfg)
+    whole = lambda w, axes: gather_for_compute(  # noqa: E731
+        w, axes, mesh, rules, cfg.dtype)
+    x = shard_activations(params["tok_emb"][tokens].astype(cfg.dtype), mesh, rules)
     positions = jnp.arange(T)
 
     def body(h, layer_params):
+        h = shard_activations(h, mesh, rules)
+        layer_params = {k: whole(w, axes["blocks"][k][1:])
+                        for k, w in layer_params.items()}
         return _block(h, layer_params, cfg, mesh, positions), None
 
     if cfg.remat:
@@ -181,20 +204,26 @@ def apply(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
                 f"unknown remat_policy {cfg.remat_policy!r} (use 'dots' or 'full')"
             )
     x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = rmsnorm(x, params["final_norm"].astype(cfg.dtype), eps=cfg.rms_eps)
-    return (x @ params["tok_emb"].T.astype(cfg.dtype)).astype(jnp.float32)
+    f32g = fsdp_engaged(mesh, x)
+    x = rmsnorm(x, whole(params["final_norm"], axes["final_norm"]),
+                eps=cfg.rms_eps, f32_param_grads=f32g)
+    head = whole(params["tok_emb"].T, axes["tok_emb"][::-1])
+    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
+    return shard_activations(logits, mesh, rules, "vocab")
 
 
-def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
+def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None):
     if "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    return cross_entropy_loss(apply(params, inputs, cfg, mesh), targets)
+    return cross_entropy_loss(apply(params, inputs, cfg, mesh, rules), targets)
 
 
-def make_train_step(cfg: LlamaConfig, optimizer, mesh: Optional[Mesh] = None):
-    return make_train_step_from_loss(loss_fn, cfg, optimizer, mesh)
+def make_train_step(cfg: LlamaConfig, optimizer, mesh: Optional[Mesh] = None,
+                    rules: Optional[ShardingRules] = None):
+    return make_train_step_from_loss(loss_fn, cfg, optimizer, mesh, rules)
 
 
 def init_state(cfg: LlamaConfig, key: jax.Array, optimizer) -> Dict[str, Any]:

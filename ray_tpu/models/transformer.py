@@ -14,6 +14,11 @@ Design choices, all TPU-motivated:
 - **Logical axes**: a parallel pytree of axis-name tuples feeds
   :mod:`ray_tpu.parallel.sharding` — ``embed``→fsdp, ``heads``/``mlp``→tp,
   sequence→sp (ring attention when the mesh has an ``sp`` axis).
+- **FSDP**: at rest ``embed``→fsdp shards every weight; under an ``fsdp``
+  mesh axis the scanned body gathers ITS layer's weights in ``config.dtype``
+  and pins the activations to the batch, so chips exchange weights, not
+  activations, and the layer ops sum parameter gradients over the batch
+  (so across chips) in float32 (see :mod:`ray_tpu.parallel.sharding`).
 """
 
 from __future__ import annotations
@@ -28,9 +33,15 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.ops.layers import layernorm
+from ray_tpu.ops.layers import dense, layernorm
 from ray_tpu.ops.moe import init_moe_params, moe_ffn, moe_logical_axes
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.parallel.sharding import (
+    ShardingRules,
+    fsdp_engaged,
+    gather_for_compute,
+    shard_activations,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,11 +127,17 @@ def block_logical_axes(n_experts: int = 0) -> Dict[str, Tuple]:
     return axes
 
 
-def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = None):
+def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = None,
+                              rules: Optional[ShardingRules] = None):
     """Shared train-step recipe for every model family: value_and_grad of
     ``loss_fn(params, batch, cfg, mesh)`` + optimizer update.  One place to
-    fix donation/metrics for all models."""
+    fix donation/metrics for all models.  ``rules``: the table the caller
+    placed the parameters with, when it is not ``rules_for_mesh(mesh)``
+    (handed on as ``loss_fn(..., rules=rules)``)."""
     import optax
+
+    if rules is not None:
+        loss_fn = partial(loss_fn, rules=rules)
 
     def train_step(state, batch):
         params, opt_state, step = state["params"], state["opt_state"], state["step"]
@@ -150,6 +167,11 @@ def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh]) -> jax.Array:
     return attention(q, k, v, causal=causal)
 
 
+# block parameters used in the dtype they are stored in; every other one is
+# cast to cfg.dtype at use (by the layer ops)
+_USED_AS_STORED = ("router",)
+
+
 def apply_block(
     x: jax.Array, p: Dict[str, jax.Array], cfg: TransformerConfig,
     mesh: Optional[Mesh] = None,
@@ -158,42 +180,47 @@ def apply_block(
     Returns ``(x, aux)`` — aux is the MoE load-balance loss (0 when dense)."""
     B, T, D = x.shape
     H, dh = cfg.n_heads, cfg.head_dim
-    c = lambda w: w.astype(cfg.dtype)
     aux = jnp.zeros((), jnp.float32)
+    # under fsdp the batch is spread over chips: sum the parameters'
+    # gradients over it in float32
+    f32g = fsdp_engaged(mesh, x)
+    lin = partial(dense, f32_param_grads=f32g)
+    norm = partial(layernorm, f32_param_grads=f32g)
 
     def attn(h):
-        qkv = h @ c(p["wqkv"]) + c(p["bqkv"])
+        qkv = lin(h, p["wqkv"], p["bqkv"])
         q, k, v = jnp.split(qkv, 3, axis=-1)
         to_heads = lambda t: t.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
         out = _attend(to_heads(q), to_heads(k), to_heads(v), causal=cfg.causal, mesh=mesh)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
-        return out @ c(p["wo"]) + c(p["bo"])
+        return lin(out, p["wo"], p["bo"])
 
     if cfg.n_experts > 0:
         def ffn(h):
             nonlocal aux
-            y, a = moe_ffn(h, p["router"], c(p["ew1"]), c(p["eb1"]),
-                           c(p["ew2"]), c(p["eb2"]),
-                           capacity_factor=cfg.capacity_factor, mesh=mesh)
+            y, a = moe_ffn(h, p["router"], p["ew1"], p["eb1"],
+                           p["ew2"], p["eb2"],
+                           capacity_factor=cfg.capacity_factor, mesh=mesh,
+                           f32_param_grads=f32g)
             aux = aux + a
             return y
     else:
         def ffn(h):
-            h = jax.nn.gelu(h @ c(p["w1"]) + c(p["b1"]), approximate=True)
-            return h @ c(p["w2"]) + c(p["b2"])
+            h = jax.nn.gelu(lin(h, p["w1"], p["b1"]), approximate=True)
+            return lin(h, p["w2"], p["b2"])
 
     if cfg.post_ln:  # original-BERT residual->norm order
-        x = layernorm(x + attn(x), c(p["ln1_w"]), c(p["ln1_b"]))
-        x = layernorm(x + ffn(x), c(p["ln2_w"]), c(p["ln2_b"]))
+        x = norm(x + attn(x), p["ln1_w"], p["ln1_b"])
+        x = norm(x + ffn(x), p["ln2_w"], p["ln2_b"])
     else:  # GPT-2 pre-LN
-        x = x + attn(layernorm(x, c(p["ln1_w"]), c(p["ln1_b"])))
-        x = x + ffn(layernorm(x, c(p["ln2_w"]), c(p["ln2_b"])))
+        x = x + attn(norm(x, p["ln1_w"], p["ln1_b"]))
+        x = x + ffn(norm(x, p["ln2_w"], p["ln2_b"]))
     return x, aux
 
 
 def apply_stack(
     x: jax.Array, blocks: Dict[str, jax.Array], cfg: TransformerConfig,
-    mesh: Optional[Mesh] = None,
+    mesh: Optional[Mesh] = None, rules: Optional[ShardingRules] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Run the stacked layers; returns ``(x, aux)``.
 
@@ -203,7 +230,20 @@ def apply_stack(
     (:func:`ray_tpu.parallel.pipeline.gpipe`) — same math, microbatched.
     """
 
+    axes = block_logical_axes(cfg.n_experts)
+
     def body(x, layer_params):
+        # FSDP proper (both helpers do nothing without an fsdp mesh axis):
+        # the batch stays where it is and THIS layer's weights come to it,
+        # moved in the dtype apply_block uses them in.  Inside the remat'd
+        # body, so the gather is per layer and is recomputed in the
+        # backward pass, not saved.
+        x = shard_activations(x, mesh, rules)
+        layer_params = {
+            k: gather_for_compute(
+                w, axes[k][1:], mesh, rules,
+                w.dtype if k in _USED_AS_STORED else cfg.dtype)
+            for k, w in layer_params.items()}
         return apply_block(x, layer_params, cfg, mesh)
 
     if cfg.remat:

@@ -14,24 +14,105 @@ import jax
 import jax.numpy as jnp
 
 
-def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6) -> jax.Array:
+def _sum_to(g: jax.Array, shape) -> jax.Array:
+    """Sum ``g`` over the dimensions that broadcasting ``shape`` to it added."""
+    lead = g.ndim - len(shape)
+    g = g.sum(tuple(range(lead)))
+    kept = tuple(i for i, n in enumerate(shape) if n == 1 and g.shape[i] != 1)
+    return g.sum(kept, keepdims=True) if kept else g
+
+
+# The three ways a block uses a parameter: x @ w, y + b, y * w, the parameter
+# cast to the activation's dtype at use (float32 masters, bf16 compute).
+# Each is the plain operation forward.  Backward, the PARAMETER's gradient
+# (a sum over every sequence and token) is accumulated in float32 and comes
+# back in the parameter's own dtype, so a float32 master's gradient is never
+# rounded to bf16 on the way.  Where the batch is spread over chips that
+# sum is the cross-chip one: the partitioner then reduces float32 partials
+# (under plain bf16 autodiff it reduces the bf16 outputs of per-chip sums).
+# Asked for with ``f32_param_grads`` (the models do under an ``fsdp`` mesh
+# axis); without it every op below is the plain expression it always was.
+
+@jax.custom_vjp
+def _matmul_f32_grad(x, w):
+    return x @ w.astype(x.dtype)
+
+
+def _matmul_f32_grad_bwd(res, g):
+    x, w = res
+    sum_over = "...k,...n->kn" if w.ndim == 2 else "...ck,...cn->...kn"
+    dw = jnp.einsum(sum_over, x, g, preferred_element_type=jnp.float32)
+    return g @ jnp.swapaxes(w.astype(g.dtype), -1, -2), dw.astype(w.dtype)
+
+
+_matmul_f32_grad.defvjp(lambda x, w: (x @ w.astype(x.dtype), (x, w)),
+                        _matmul_f32_grad_bwd)
+
+
+@jax.custom_vjp
+def _add_f32_grad(y, b):
+    return y + b.astype(y.dtype)
+
+
+_add_f32_grad.defvjp(
+    lambda y, b: (y + b.astype(y.dtype), b),
+    lambda b, g: (g, _sum_to(g.astype(jnp.float32), b.shape).astype(b.dtype)))
+
+
+@jax.custom_vjp
+def _mul_f32_grad(y, w):
+    return y * w.astype(y.dtype)
+
+
+def _mul_f32_grad_bwd(res, g):
+    y, w = res
+    dw = _sum_to(g.astype(jnp.float32) * y.astype(jnp.float32), w.shape)
+    return g * w.astype(g.dtype), dw.astype(w.dtype)
+
+
+_mul_f32_grad.defvjp(lambda y, w: (y * w.astype(y.dtype), (y, w)),
+                     _mul_f32_grad_bwd)
+
+
+def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
+          *, f32_param_grads: bool = False) -> jax.Array:
+    """``x @ w (+ b)``, ``w`` and ``b`` cast to ``x``'s dtype at use.  With
+    ``f32_param_grads`` their gradients are accumulated in float32 (see
+    above); ``w`` may carry leading batch dimensions (``[E, K, N]`` against
+    ``x [E, C, K]``)."""
+    if f32_param_grads:
+        y = _matmul_f32_grad(x, w)
+        return y if b is None else _add_f32_grad(y, b)
+    y = x @ w.astype(x.dtype)
+    return y if b is None else y + b.astype(y.dtype)
+
+
+def _affine(out, weight, bias, f32_param_grads):
+    """``out * weight (+ bias)``, both cast to ``out``'s dtype."""
+    if f32_param_grads:
+        out = _mul_f32_grad(out, weight)
+        return out if bias is None else _add_f32_grad(out, bias)
+    out = out * weight.astype(out.dtype)
+    return out if bias is None else out + bias.astype(out.dtype)
+
+
+def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
+            f32_param_grads: bool = False) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight
+    out = (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)
+    return _affine(out, weight, None, f32_param_grads)
 
 
 def layernorm(
     x: jax.Array, weight: jax.Array, bias: Optional[jax.Array] = None,
-    *, eps: float = 1e-5,
+    *, eps: float = 1e-5, f32_param_grads: bool = False,
 ) -> jax.Array:
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
     out = (xf - mu) * jax.lax.rsqrt(var + eps)
-    out = out.astype(x.dtype) * weight
-    if bias is not None:
-        out = out + bias
-    return out
+    return _affine(out.astype(x.dtype), weight, bias, f32_param_grads)
 
 
 def rope(

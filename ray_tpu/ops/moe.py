@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops.layers import dense
+
 
 def _constrain(x: jax.Array, mesh: Optional[Mesh], spec: P) -> jax.Array:
     if mesh is None:
@@ -52,6 +54,7 @@ def moe_ffn(
     *,
     capacity_factor: float = 2.0,
     mesh: Optional[Mesh] = None,
+    f32_param_grads: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Top-1 (Switch) MoE feed-forward.
 
@@ -62,6 +65,8 @@ def moe_ffn(
         w2, b2: ``[E, F, D]``, ``[E, D]`` expert down-projections.
         capacity_factor: per-expert buffer = ``cf * tokens / E``.
         mesh: optional mesh; expert dims get an ``ep`` sharding constraint.
+        f32_param_grads: accumulate the expert weights' gradients in float32
+            (:func:`ray_tpu.ops.layers.dense`).
 
     Returns:
         ``(y, aux)`` — ``[B, T, D]`` output and the scalar load-balance
@@ -93,9 +98,10 @@ def moe_ffn(
     expert_in = jnp.einsum("sec,sd->ecd", disp.astype(x.dtype), xf)
     expert_in = _constrain(expert_in, mesh, P("ep", None, None))
 
-    h = jnp.einsum("ecd,edf->ecf", expert_in, w1) + b1[:, None, :]
+    # per expert: [C, D] @ [D, F], then [C, F] @ [F, D]
+    h = dense(expert_in, w1, b1[:, None, :], f32_param_grads=f32_param_grads)
     h = jax.nn.gelu(h, approximate=True)
-    out = jnp.einsum("ecf,efd->ecd", h, w2) + b2[:, None, :]
+    out = dense(h, w2, b2[:, None, :], f32_param_grads=f32_param_grads)
     out = _constrain(out, mesh, P("ep", None, None))
 
     # combine: per-expert buffers -> tokens, weighted by the gate (the

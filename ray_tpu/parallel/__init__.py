@@ -8,7 +8,9 @@ ICI axes inside a slice, DCN axes across slices (SURVEY §5.8).
 - :mod:`ray_tpu.parallel.mesh` — ``MeshSpec`` / mesh construction with
   named axes (``dp``/``fsdp``/``tp``/``sp``/``ep``/``pp``).
 - :mod:`ray_tpu.parallel.sharding` — sharding-rule tables mapping pytree
-  paths to ``PartitionSpec``s (the ``prepare_model`` analog for jax).
+  paths to ``PartitionSpec``s (the ``prepare_model`` analog for jax), the
+  two FSDP helpers the models call (weights gathered per layer, activations
+  on the batch) and ``collective_profile`` of a compiled step.
 - :mod:`ray_tpu.parallel.collective` — group-based collective API with the
   surface of ``ray.util.collective`` backed by ``jax.lax`` collectives.
 """
@@ -22,8 +24,11 @@ from ray_tpu.parallel.mesh import (
 from ray_tpu.parallel.pipeline import gpipe, pp_size
 from ray_tpu.parallel.sharding import (
     ShardingRules,
+    collective_profile,
+    gather_for_compute,
     infer_sharding,
     logical_to_sharding,
+    shard_activations,
     with_sharding_constraint,
 )
 
@@ -35,6 +40,9 @@ __all__ = [
     "local_mesh",
     "get_abstract_mesh",
     "ShardingRules",
+    "collective_profile",
+    "gather_for_compute",
+    "shard_activations",
     "infer_sharding",
     "logical_to_sharding",
     "with_sharding_constraint",

@@ -4,12 +4,35 @@ The reference wraps a torch module in DDP/FSDP for the user
 (``python/ray/train/torch/train_loop_utils.py:51,71-74`` ``prepare_model``).
 The TPU-native equivalent is declarative: parameters carry *logical* axis
 names (e.g. ``("embed", "mlp")``) and a rule table maps logical axes to
-mesh axes, producing ``NamedSharding``s that pjit consumes.  This is the
-GSPMD recipe — annotate, let XLA insert collectives.
+mesh axes, producing ``NamedSharding``s that pjit consumes.
+
+Annotating the parameters alone is not FSDP.  ``embed -> fsdp`` puts the
+matmuls' CONTRACTION dimension and the batch on the same mesh axis, and a
+partitioner that is only asked keeps the weights where they are, computes
+a quarter of every contraction for the WHOLE batch on every chip and
+all-reduces the activations.  So under a mesh whose ``fsdp`` axis is
+larger than 1 the models tell it, with the two helpers here:
+
+- :func:`gather_for_compute` — a layer's weights are cast to the compute
+  dtype on the shard and all-gathered along ``fsdp`` for the computation
+  that uses them (per layer, inside the remat'd scan body, so the gather
+  is recomputed in the backward pass and never saved); their gradients
+  are summed over the batch, and so across the chips, in float32 (the
+  layers produce them with ``ops.layers``' ``f32_param_grads``) and land
+  back in the at-rest sharding (a reduce-scatter).
+- :func:`shard_activations` — activations and logits stay on the batch.
+
+At rest nothing changes: parameters and optimizer state keep
+``rules.spec(logical_axes)``, a 1/fsdp share a chip.  ``tp``/``ep``/``sp``
+axes keep their annotations and the partitioner still inserts THOSE
+collectives.  :func:`collective_profile` reads from a compiled step which
+collectives it holds, inside and outside the layer loop: the mechanism is
+decided at compile time, so that is its counter.
 """
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -123,3 +146,184 @@ def infer_sharding(params: Any, mesh: Mesh, rules: ShardingRules) -> Any:
 def with_sharding_constraint(x: Any, mesh: Mesh, spec: P) -> Any:
     """``lax.with_sharding_constraint`` under an explicit mesh."""
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+# ---------------------------------------------------------------------------
+# FSDP: what GSPMD is told, not asked
+# ---------------------------------------------------------------------------
+
+FSDP_AXIS = "fsdp"
+
+
+def fsdp_engaged(mesh: Optional[Mesh], x: Any = None) -> bool:
+    """Whether the two helpers below do anything: the mesh has an ``fsdp``
+    axis larger than 1 and ``x`` (when given) is not inside a manual region
+    (the pipeline engine's ``shard_map`` over ``pp``, where a constraint on
+    the whole mesh is rejected: the stage keeps what propagation gives it).
+    The helpers test it themselves; a model reads it only to choose how its
+    layers accumulate parameter gradients (``ops.layers.dense``)."""
+    if mesh is None or mesh.shape.get(FSDP_AXIS, 1) <= 1:
+        return False
+    return x is None or not getattr(jax.typeof(x), "vma", None)
+
+
+def _without(entry: MeshAxis, axis: str) -> MeshAxis:
+    if isinstance(entry, tuple):
+        kept = tuple(a for a in entry if a != axis)
+        return kept[0] if len(kept) == 1 else (kept or None)
+    return None if entry == axis else entry
+
+
+def gather_for_compute(
+    w: jax.Array, logical_axes: Sequence[Optional[str]], mesh: Optional[Mesh],
+    rules: Optional[ShardingRules], dtype: Any,
+) -> jax.Array:
+    """``w`` whole along ``fsdp`` for the computation that uses it, moved
+    between chips in ``dtype``.
+
+    ``w`` is a parameter, or the scanned per-layer slice of one, that rests
+    sharded by ``rules.spec(logical_axes)`` (``rules``: the table the caller
+    placed the parameters with; None means :func:`rules_for_mesh`).
+    Forward: cast to ``dtype`` on the shard, THEN all-gather along ``fsdp``
+    (bf16 crosses the ICI when the compute dtype is bf16); any ``tp``/``ep``
+    axis stays.  The result holds those ``dtype`` values in ``w.dtype``
+    again (exact, and the compiler drops the pair of casts around the
+    layer's own cast at use), so that its cotangent is float32 like the
+    master: backward, that cotangent is constrained to the at-rest sharding
+    and the cross-chip gradient sum is a float32 reduce-scatter, never an
+    all-reduce of whole weights and never a sum of bf16 partials.  (The
+    partitioner sums across chips at the operation that PRODUCED the
+    cotangent, in that operation's output dtype: the layers produce it in
+    float32, ``ops.layers.dense(..., f32_param_grads=True)`` and the
+    norms.)  Called inside a remat'd scan body the gather is per layer and
+    is recomputed, never saved.  Without an ``fsdp`` axis larger than 1 (or
+    inside a manual region) ``w`` comes back as it is.
+    """
+    if not fsdp_engaged(mesh, w):
+        return w
+    rules = rules or rules_for_mesh(mesh)
+    at_rest = rules.sharding(mesh, logical_axes)
+    whole = NamedSharding(
+        mesh, P(*(_without(entry, FSDP_AXIS) for entry in at_rest.spec)))
+    constrain = jax.lax.with_sharding_constraint
+
+    def cast_then_gather(w):
+        moved = constrain(constrain(w.astype(dtype), at_rest), whole)
+        return moved.astype(w.dtype)
+
+    gather = jax.custom_vjp(cast_then_gather)
+    gather.defvjp(lambda w: (cast_then_gather(w), None),
+                  lambda _, ct: (constrain(ct, at_rest),))
+    return gather(w)
+
+
+def shard_activations(
+    x: jax.Array, mesh: Optional[Mesh], rules: Optional[ShardingRules],
+    *trailing: Optional[str],
+) -> jax.Array:
+    """Constrain ``[B, T, ...]`` to the batch (and ``seq``) sharding, the
+    other dimensions by their ``trailing`` logical axes (default: whole).
+    The counterpart of :func:`gather_for_compute`: activations stay where
+    their sequences are, weights come to them."""
+    if not fsdp_engaged(mesh, x):
+        return x
+    rules = rules or rules_for_mesh(mesh)
+    trailing = trailing or (None,) * (x.ndim - 2)
+    return jax.lax.with_sharding_constraint(
+        x, rules.sharding(mesh, ("batch", "seq") + tuple(trailing)))
+
+
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                "collective-permute")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8}
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\((.*)\)\s*->.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_HLO_CALLEES = re.compile(
+    r"\b(calls|to_apply|body|condition|branch_computations)="
+    r"(?:\{([^}]*)\}|(%?[\w.\-]+))")
+_HLO_COLLECTIVE = re.compile(
+    r"^(.*?)\s(%s)(?:-start)?\((.*?)\)(?:,|$)(.*)$" % "|".join(_COLLECTIVES))
+_HLO_SHAPE = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
+
+
+def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """What a compiled step moves between chips, read from its HLO.
+
+    ``compiled`` is a ``jax.stages.Compiled`` (``jit(f).lower(...).compile()``)
+    or its ``as_text()``.  Returns, per collective kind (``all-reduce``,
+    ``all-gather``, ``reduce-scatter``, ``all-to-all``,
+    ``collective-permute``) and per place (``"in_loop"``: in a ``while``
+    body or anything it calls, i.e. the scanned layers; ``"outside"``),
+    ``{"count", "max_operand_bytes", "shapes"}``.  ``shapes`` lists every
+    distinct per-device array the collectives of that kind take or give,
+    as ``"f32[4,256,768]"``.  The FSDP mechanism above is decided at
+    compile time, so this is the counter that says it engaged.
+    """
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    # computation -> {instruction or parameter name: the text of its shape}
+    comps: Dict[str, Dict[str, str]] = {}
+    calls: Dict[str, set] = {}
+    loop_bodies: set = set()
+    found = []  # (computation, kind, result shape, operand names, attributes)
+    name = None
+    for line in text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = dict(
+                re.findall(r"([\w.\-]+):\s*(\(.*?\)|[^,()]+\[[\d,]*\])", m.group(2)))
+            calls[name] = set()
+            continue
+        m = name and _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        instr, rest = m.groups()
+        for how, several, one in _HLO_CALLEES.findall(rest):
+            callees = {c.strip().lstrip("%") for c in (several or one).split(",")}
+            calls[name] |= callees
+            if how == "body":
+                loop_bodies |= callees
+        c = _HLO_COLLECTIVE.match(rest)
+        comps[name][instr] = c.group(1) if c else rest.split(" ", 1)[0]
+        if c:
+            found.append((name, c.group(2), c.group(1),
+                          re.findall(r"%([\w.\-]+)", c.group(3)),
+                          instr + c.group(4)))
+    in_loop, stack = set(), list(loop_bodies)
+    while stack:
+        n = stack.pop()
+        if n not in in_loop:
+            in_loop.add(n)
+            stack.extend(calls.get(n, ()))
+
+    profile = {kind: {place: {"count": 0, "max_operand_bytes": 0, "shapes": []}
+                      for place in ("in_loop", "outside")}
+               for kind in _COLLECTIVES}
+    seen = set()
+    for comp, kind, result, operands, attrs in found:
+        # the TPU compiler writes a reduce-scatter as a fusion of an
+        # all-reduce and a slice, and clones an asynchronous collective
+        # into each fusion that continues it (same channel)
+        if kind == "all-reduce" and comp.startswith("all-reduce-scatter"):
+            kind = "reduce-scatter"
+        channel = re.search(r"channel_id=(\d+)", attrs)
+        key = (kind, channel.group(1) if channel else (comp, attrs))
+        if key in seen:
+            continue
+        seen.add(key)
+        entry = profile[kind]["in_loop" if comp in in_loop else "outside"]
+        entry["count"] += 1
+        taken = [s for o in operands
+                 for s in _HLO_SHAPE.findall(comps[comp].get(o, ""))]
+        for dt, dims in taken + _HLO_SHAPE.findall(result):
+            label = f"{dt}[{dims}]"
+            if label not in entry["shapes"]:
+                entry["shapes"].append(label)
+        for dt, dims in taken or _HLO_SHAPE.findall(result):
+            size = _DTYPE_BYTES.get(dt, 4)
+            for d in filter(None, dims.split(",")):
+                size *= int(d)
+            entry["max_operand_bytes"] = max(entry["max_operand_bytes"], size)
+    return profile

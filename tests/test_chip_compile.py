@@ -27,11 +27,10 @@ HBM_BYTES = 16 * 1024**3  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """A sharding that pins arguments to one described v5e chip.  The
-    persistent compile cache is off meanwhile: a compile for a described
-    chip is written to it but cannot be read back without a chip (the next
-    one warns and recompiles)."""
+def topo():
+    """The described 2x2 of v5e chips.  The persistent compile cache is off
+    meanwhile: a compile for a described chip is written to it but cannot
+    be read back without a chip (the next one warns and recompiles)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,9 +42,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """A sharding that pins arguments to one described v5e chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(chip, tree):
@@ -154,6 +159,69 @@ def compiled(chip):
         futures = {name: [pool.submit(low.compile) for low in lows]
                    for name, lows in lowered.items()}
     return futures  # .result() re-raises what the chip's compiler raised
+
+
+def test_xl_fsdp4_step_gathers_bf16_weights(topo):
+    """The four-chip cell's step (GPT-2 XL, mesh fsdp=4, B=16, full remat,
+    compiled as benchmark/drivers/train.py does) for the described 2x2: in
+    the layer loop bf16 weight shards are gathered, nothing with the 16
+    sequences of the whole batch crosses chips, every gradient is summed
+    across the chips in float32, and a chip's share fits."""
+    import re
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.sharding import collective_profile, rules_for_mesh
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("fsdp",))
+    cfg = gpt2.GPT2Config.gpt2_small(
+        n_layers=48, n_heads=25, d_model=1600, d_ff=6400, remat_policy="full")
+    optimizer = gpt2.make_optimizer(lr=3e-4, warmup=20)
+    rules = rules_for_mesh(mesh)
+    replicated = NamedSharding(mesh, P())
+    p_shard = gpt2.param_shardings(mesh, rules, cfg)
+    shapes = jax.eval_shape(
+        lambda k: gpt2.init_state(cfg, k, optimizer), jax.random.PRNGKey(0))
+    o_shard = optax.tree_map_params(
+        optimizer, lambda _, s: s, shapes["opt_state"], p_shard,
+        transform_non_params=lambda _: replicated)
+    s_shard = {"params": p_shard, "opt_state": o_shard, "step": replicated}
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        shapes, s_shard)
+    tokens = jax.ShapeDtypeStruct(
+        (16, cfg.max_seq_len), jnp.int32, sharding=NamedSharding(mesh, P("fsdp")))
+    compiled = jax.jit(
+        gpt2.make_train_step(cfg, optimizer, mesh), donate_argnums=(0,),
+        out_shardings=(s_shard, None),
+    ).lower(state, {"inputs": tokens, "targets": tokens}).compile()
+    assert _fits(compiled) < 12 * 2**30  # 14.6 GB when 16 sequences lived on a chip
+    in_loop = {k: v["in_loop"] for k, v in collective_profile(compiled).items()}
+    gathered = in_loop["all-gather"]["shapes"]
+    assert gathered and all(s.startswith("bf16[") for s in gathered), gathered
+    assert "bf16[1600,6400]" in gathered  # w1, whole, from its [400,6400] shard
+    for kind, entry in in_loop.items():
+        for label in entry["shapes"]:
+            dims = label[label.index("[") + 1:-1].split(",")
+            assert "16" not in dims and "1024" not in dims, (kind, label)
+    # the sums fsdp introduces: the layers' matrices are reduce-scattered
+    # as float32 ...
+    scattered = in_loop["reduce-scatter"]["shapes"]
+    assert len(scattered) >= 4 and all(s.startswith("f32[") for s in scattered)
+    # ... and no collective of the BACKWARD pass (vectors' and the LM head's
+    # all-reduces included) gives a bf16 sum.  (Forward, small bf16 vectors
+    # are gathered as an all-reduce of zero-padded shards: no sum.)
+    backward = [
+        line for line in compiled.as_text().splitlines()
+        if re.search(r"\b(all-reduce|reduce-scatter)(-start)?\(", line)
+        and "transpose(jvp" in line]
+    assert backward
+    for line in backward:
+        result = line.split("=", 1)[1].split("all-reduce")[0].split("reduce-scatter")[0]
+        assert "bf16[" not in result and "f16[" not in result, line[:300]
 
 
 @pytest.mark.parametrize("name", list(PROGRAMS))

@@ -1,9 +1,12 @@
 """Model zoo tests: shapes, training progress, sharded end-to-end step."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from ray_tpu.models import bert, gpt2, mlp
 
@@ -189,3 +192,147 @@ def test_llama_sequence_parallel_matches_single():
         lambda p, t: llama.apply(p, t, cfg, mesh)
     )(params, toks_sp))
     np.testing.assert_allclose(single, out, atol=3e-2, rtol=3e-2)
+
+
+def _fsdp_parity_case(family):
+    """(model module, config, loss(params, batch, cfg, mesh), batch) for the
+    fsdp parity test: float32 tiny configs, as dryrun_multichip uses."""
+    from ray_tpu.models import bert, gpt2, llama
+    from ray_tpu.ops.layers import cross_entropy_loss
+
+    rng = np.random.default_rng(0)
+    f32 = jnp.float32
+    if family == "bert":
+        cfg = dataclasses.replace(
+            bert.BertConfig.tiny(dtype=f32), remat=True, remat_policy="full")
+        batch = {"tokens": rng.integers(0, cfg.vocab_size, (8, 32), np.int32),
+                 "labels": rng.integers(0, cfg.num_classes, (8,), np.int32)}
+
+        def loss(params, batch, cfg, mesh=None):
+            logits = bert.apply(params, batch["tokens"], cfg, mesh=mesh)
+            return cross_entropy_loss(logits, batch["labels"])
+
+        return bert, cfg, loss, batch
+    model, cfg = {
+        "gpt2": (gpt2, gpt2.GPT2Config.tiny(
+            dtype=f32, remat=True, remat_policy="full")),
+        "llama": (llama, llama.LlamaConfig.tiny(
+            dtype=f32, remat=True, remat_policy="full")),
+    }[family]
+    batch = {"tokens": rng.integers(0, cfg.vocab_size, (8, 33), np.int32)}
+    return model, cfg, model.loss_fn, batch
+
+
+@pytest.mark.parametrize("family", ["gpt2", "bert", "llama"])
+def test_fsdp4_matches_unsharded(family):
+    """Three optimizer steps on mesh fsdp=4 (weights gathered per layer,
+    gradients landing in the at-rest sharding) against the same steps with
+    ``mesh=None``: losses, first gradients and final parameters agree within
+    the tolerance ``__graft_entry__._dryrun_one`` uses for its parity."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.models.transformer import make_train_step_from_loss
+    from ray_tpu.parallel import create_mesh
+    from ray_tpu.parallel.sharding import logical_to_sharding, rules_for_mesh
+
+    atol = 2e-3
+    model, cfg, loss, batch = _fsdp_parity_case(family)
+    optimizer = gpt2.make_optimizer(lr=1e-3, warmup=1, total_steps=50)
+    mesh = create_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    p_shard = logical_to_sharding(
+        model.logical_axes(cfg), mesh, rules_for_mesh(mesh))
+    on_batch = NamedSharding(mesh, P("fsdp"))
+
+    def run(mesh):
+        params = model.init(cfg, jax.random.PRNGKey(0))
+        data = batch
+        if mesh is not None:
+            params = jax.device_put(params, p_shard)
+            data = jax.device_put(batch, on_batch)
+        grads = jax.jit(jax.grad(loss), static_argnums=(2, 3))(
+            params, data, cfg, mesh)
+        state = {"params": params, "opt_state": optimizer.init(params),
+                 "step": jnp.zeros((), jnp.int32)}
+        step = jax.jit(make_train_step_from_loss(loss, cfg, optimizer, mesh))
+        losses = []
+        for _ in range(3):
+            state, metrics = step(state, data)
+            losses.append(float(metrics["loss"]))
+        return losses, grads, state["params"]
+
+    losses, grads, params = run(mesh)
+    ref_losses, ref_grads, ref_params = run(None)
+    np.testing.assert_allclose(losses, ref_losses, atol=atol)
+    assert ref_losses[-1] < ref_losses[0]
+    # in float32 the two programs differ by the order of their sums alone: a
+    # gradient off by a factor, or one bias's sum dropped, cannot hide here
+    # (Adam's update is scale-free, so the parameters alone would hide it)
+    for got, want, tol in ((grads, ref_grads, dict(rtol=1e-4, atol=1e-6)),
+                           (params, ref_params, dict(atol=atol))):
+        for path, g in jax.tree_util.tree_leaves_with_path(got):
+            w = want
+            for k in path:
+                w = w[k.key]
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), **tol,
+                err_msg=jax.tree_util.keystr(path))
+    # the layers' gradients landed where their parameters rest
+    for g, s in zip(jax.tree.leaves(grads["blocks"]),
+                    jax.tree.leaves(p_shard["blocks"])):
+        assert g.sharding.is_equivalent_to(s, g.ndim), (g.sharding, s)
+
+
+def test_fsdp4_bf16_gradients_no_worse_than_unsharded():
+    """bf16 compute, float32 masters: every parameter's gradient of the
+    fsdp4 step is as near the float32 program's as the one-chip bf16
+    program's is (the sums over the batch that fsdp spreads over chips are
+    float32; summing bf16 per-chip partials would put the matrices' further
+    off)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel import create_mesh
+    from ray_tpu.parallel.sharding import logical_to_sharding, rules_for_mesh
+
+    def config(dtype):
+        return gpt2.GPT2Config.tiny(dtype=dtype, remat=True, remat_policy="full")
+
+    rng = np.random.default_rng(0)
+    batch = {"tokens": rng.integers(
+        0, config(jnp.float32).vocab_size, (32, 33), np.int32)}
+    mesh = create_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+
+    def grads(dtype, mesh):
+        cfg = config(dtype)
+        params, data = gpt2.init(cfg, jax.random.PRNGKey(0)), batch
+        if mesh is not None:
+            params = jax.device_put(params, logical_to_sharding(
+                gpt2.logical_axes(cfg), mesh, rules_for_mesh(mesh)))
+            data = jax.device_put(batch, NamedSharding(mesh, P("fsdp")))
+        got = jax.jit(jax.grad(gpt2.loss_fn), static_argnums=(2, 3))(
+            params, data, cfg, mesh)
+        return {jax.tree_util.keystr(k): np.asarray(g, np.float64)
+                for k, g in jax.tree_util.tree_leaves_with_path(got)}
+
+    want = grads(jnp.float32, None)
+    one_chip, fsdp4 = grads(jnp.bfloat16, None), grads(jnp.bfloat16, mesh)
+    for name, w in want.items():
+        off = lambda g: np.linalg.norm(g[name] - w) / np.linalg.norm(w)  # noqa: E731
+        assert off(fsdp4) <= 1.02 * off(one_chip), (name, off(fsdp4), off(one_chip))
+
+
+def test_unsharded_step_has_no_fsdp_machinery():
+    """Without a mesh the step is the program it was: no sharding constraint
+    and no custom_vjp (the weight gather's) in its jaxpr."""
+    from ray_tpu.models import gpt2, llama
+
+    tokens = jnp.zeros((2, 17), jnp.int32)
+    for model, cfg in ((gpt2, gpt2.GPT2Config.tiny(remat=True)),
+                       (llama, llama.LlamaConfig.tiny(remat=True))):
+        optimizer = model.make_optimizer(lr=1e-3)
+        state = jax.eval_shape(
+            lambda k: model.init_state(cfg, k, optimizer), jax.random.PRNGKey(0))
+        jaxpr = str(jax.make_jaxpr(model.make_train_step(cfg, optimizer))(
+            state, {"tokens": tokens}))
+        assert "sharding_constraint" not in jaxpr and "custom_vjp" not in jaxpr

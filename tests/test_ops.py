@@ -183,6 +183,53 @@ def test_rmsnorm_layernorm():
     np.testing.assert_allclose(np.mean(np.asarray(out), -1), np.zeros(4), atol=1e-5)
 
 
+@pytest.mark.parametrize("op", ["dense", "dense_batched", "layernorm", "rmsnorm"])
+def test_f32_param_grads(op):
+    """``f32_param_grads`` changes no value forward and, in float32, no
+    gradient; with bf16 activations and float32 parameters the parameters'
+    gradients come back float32 (summed over the batch in float32, never
+    rounded to bf16) and agree with the float32 computation as closely as
+    bf16 activations allow."""
+    from ray_tpu.ops.layers import dense, layernorm, rmsnorm
+
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    if op == "dense":
+        x, params = jax.random.normal(k[0], (64, 33, 16)), (
+            jax.random.normal(k[1], (16, 24)), jax.random.normal(k[2], (24,)))
+        fn = dense
+    elif op == "dense_batched":  # the MoE experts' [E, C, D] @ [E, D, F]
+        x, params = jax.random.normal(k[0], (4, 257, 16)), (
+            jax.random.normal(k[1], (4, 16, 24)), jax.random.normal(k[2], (4, 1, 24)))
+        fn = dense
+    elif op == "layernorm":
+        x, params = jax.random.normal(k[0], (64, 33, 16)), (
+            jax.random.normal(k[1], (16,)), jax.random.normal(k[2], (16,)))
+        fn = layernorm
+    else:
+        x, params = jax.random.normal(k[0], (64, 33, 16)), (
+            jax.random.normal(k[1], (16,)),)
+        fn = rmsnorm
+
+    def grads(x, flag):
+        loss = lambda x, *p: jnp.sum(  # noqa: E731
+            jnp.sin(fn(x, *p, f32_param_grads=flag).astype(jnp.float32)))
+        return jax.grad(loss, argnums=tuple(range(1 + len(params))))(x, *params)
+
+    np.testing.assert_array_equal(
+        fn(x, *params, f32_param_grads=True), fn(x, *params))
+    want = grads(x, False)
+    for g, w in zip(grads(x, True), want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    # bf16 activations, float32 masters
+    xb = x.astype(jnp.bfloat16)
+    wide = grads(xb, True)
+    want = grads(xb.astype(jnp.float32), False)
+    assert wide[0].dtype == jnp.bfloat16
+    for g, w in zip(wide[1:], want[1:]):
+        assert g.dtype == jnp.float32
+        assert np.abs(g - w).max() <= 3e-2 * np.abs(w).max()
+
+
 def test_rope_preserves_norm_and_relative_phase():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 64))
     pos = jnp.arange(8)

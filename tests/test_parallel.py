@@ -122,3 +122,98 @@ def test_grad_sync_pmean():
     out = f(grads)
     np.testing.assert_allclose(out["w"], np.full(8, 3.5))
     np.testing.assert_allclose(out["b"], np.ones(8))
+
+
+@pytest.mark.parametrize(
+    "sizes,own_rules",
+    [({"fsdp": 4}, None), ({"fsdp": 2, "tp": 2}, None), ({"dp": 2, "fsdp": 2}, None),
+     # the caller's own table (fsdp shards the OTHER dimension of each
+     # matrix), handed to the step as well as to param_shardings
+     ({"fsdp": 4}, dict(embed=None, heads="fsdp", mlp="fsdp"))],
+    ids=["fsdp4", "fsdp2xtp2", "dp2xfsdp2", "fsdp4-own-rules"])
+def test_fsdp_step_collectives(sizes, own_rules):
+    """Under an fsdp axis the compiled train step gathers WEIGHTS per layer
+    and keeps activations on the batch: no collective inside the layer loop
+    touches an array with the batch in its shape.  The step is compiled the
+    way benchmark/drivers/train.py compiles the fsdp4 cell's.  (The dtypes
+    that cross chips are the TPU compiler's to show: the CPU's widens every
+    bf16 collective.  tests/test_chip_compile.py reads them.)"""
+    import optax
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.parallel.sharding import collective_profile
+
+    # sizes chosen so that no weight dimension (or shard of one) equals the
+    # global batch 20, a chip's share of it, or the sequence length 48
+    B, T = 20, 48
+    cfg = gpt2.GPT2Config.gpt2_small(
+        n_layers=3, n_heads=4, d_model=128, d_ff=512, vocab_size=1024,
+        max_seq_len=T, remat_policy="full")
+    optimizer = gpt2.make_optimizer(lr=3e-4, warmup=20)
+    devices = jax.devices()[:4]
+    mesh = create_mesh(dict(sizes), devices=devices)
+    rules = rules_for_mesh(mesh)
+    step_rules = ()
+    if own_rules:
+        rules = rules.update(**own_rules)
+        step_rules = (rules,)
+    replicated = NamedSharding(mesh, P())
+    p_shard = gpt2.param_shardings(mesh, rules, cfg)
+    make_state = lambda k: gpt2.init_state(cfg, k, optimizer)  # noqa: E731
+    shapes = jax.eval_shape(make_state, jax.random.PRNGKey(0))
+    o_shard = optax.tree_map_params(
+        optimizer, lambda _, s: s, shapes["opt_state"], p_shard,
+        transform_non_params=lambda _: replicated)
+    s_shard = {"params": p_shard, "opt_state": o_shard, "step": replicated}
+    step = jax.jit(gpt2.make_train_step(cfg, optimizer, mesh, *step_rules),
+                   donate_argnums=(0,), out_shardings=(s_shard, None))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        shapes, s_shard)
+    tokens = jax.ShapeDtypeStruct(
+        (B, T), jnp.int32,
+        sharding=NamedSharding(mesh, P(rules.rules["batch"], None)))
+    compiled = step.lower(state, {"inputs": tokens, "targets": tokens}).compile()
+    profile = collective_profile(compiled)
+
+    def dims(label):
+        return [int(d) for d in label[label.index("[") + 1:-1].split(",") if d]
+
+    fsdp, tp = mesh.shape["fsdp"], mesh.shape.get("tp", 1)
+    n_batch = fsdp * mesh.shape.get("dp", 1)
+    in_loop = {kind: places["in_loop"] for kind, places in profile.items()}
+    assert in_loop["all-gather"]["count"] >= 4, profile  # wqkv, wo, w1, w2
+    for kind, entry in in_loop.items():
+        for label in entry["shapes"]:
+            # (tp legitimately all-reduces a chip's OWN sequences)
+            assert B not in dims(label), (
+                f"{kind} of the whole batch in the layer loop: {label}")
+            assert tp > 1 or T not in dims(label), (
+                f"{kind} of an activation in the layer loop: {label}")
+    # what the layer loop gathers is a layer's parameter, from the shard it
+    # rests in to whole along fsdp (tp kept) -- with the caller's own table
+    # too: pinned to another table's shards they would be re-sharded first
+    def per_layer(x, spec):
+        return tuple(NamedSharding(mesh, P(*spec)).shard_shape(x.shape)[1:])
+
+    at_rest, whole = set(), set()
+    for x, s in zip(jax.tree.leaves(shapes["params"]["blocks"]),
+                    jax.tree.leaves(p_shard["blocks"])):
+        spec = tuple(s.spec) + (None,) * (x.ndim - len(s.spec))
+        at_rest.add(per_layer(x, spec))
+        whole.add(per_layer(x, [None if e == "fsdp" else e for e in spec]))
+    for label in in_loop["all-gather"]["shapes"]:
+        shape = tuple(d for d in dims(label) if d != 1)
+        assert shape in at_rest | whole, (label, at_rest, whole)
+    assert not any({B, B // n_batch, T} & set(shape) for shape in at_rest | whole)
+
+    # at rest nothing changed: a chip holds its share of the training state
+    state_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    held = compiled.memory_analysis().argument_size_in_bytes
+    if own_rules is None:
+        assert state_bytes / (fsdp * tp) <= held <= 1.03 * state_bytes / fsdp, (
+            held, state_bytes)
+    placed = sum(
+        int(np.prod(s.shard_shape(x.shape))) * x.dtype.itemsize
+        for x, s in zip(jax.tree.leaves(shapes), jax.tree.leaves(s_shard)))
+    assert held <= 1.03 * placed, (held, placed)
