@@ -5,25 +5,38 @@ plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
 
-- **Static shapes everywhere**: the cache is a fixed ``[L, B, KV, S, dh]``
-  buffer; positions are dynamic *values*, never dynamic shapes, so the
-  decode step compiles once and runs for every token.
-- **In-place cache**: the decode layer loop is a ``fori_loop`` carrying
-  the full cache; each layer writes only its new K/V column with one
-  scatter, and XLA's while-loop buffer aliasing keeps the cache in place
-  (a scan that re-emits the cache per step measured ~1.3 ms/step of pure
-  rewrite traffic at GPT-2 125M on v5e).
+- **Static shapes everywhere**: the cache is a fixed ``[L, B, KV, dh, S]``
+  buffer (positions last: the decode step's scores come out with S on the
+  lanes, and the chip stores the cache unpadded); positions are dynamic
+  *values*, never dynamic shapes, so the decode chunk compiles once and
+  runs for every token.
 - **Per-slot positions**: each batch slot sits at its own offset (``pos``
   vector), which is what iteration-level continuous batching needs
   (Orca-style; see :mod:`ray_tpu.serve.llm`).
 - **Chunked decode**: ``decode_chunk`` runs N decode+sample steps inside
   one device computation (``lax.scan``) so the host syncs once per chunk,
   not per token.
+- **No step writes the cache**: a chunk's new K/V columns live in a
+  chunk-local buffer ``[L, steps, B, KV, dh]``.  Layer ``l`` of step ``i``
+  writes there with one ``dynamic_update_slice`` at ``(l, i)`` — the same
+  index for every slot, a contiguous block — and attends the cache below
+  ``pos0`` (the slot's position when the chunk began) together with the
+  buffer up to ``i``, under one softmax.  After the last step each slot's
+  columns go into the cache at ``pos0[b]``, once, in place (XLA aliases
+  the donated cache through the flush loop).  A scatter of every slot's
+  column at its own position per layer per step cost 7.5 ms of the 19.7 ms
+  GPT-2 XL decode step on the v5e (PERF.md, PR 28).
 
-Cache columns of finished/idle slots keep being written at their frozen
-position, which is harmless: a slot's attention mask never reaches an
-index its own ``pos`` hasn't covered, and prefill overwrites ``[0, len)``
-when a slot is reused.
+The flush invariant: after a chunk, every position ``j < pos[b]`` of slot
+``b`` holds a column that prefill or an ACTIVE step wrote.  The flush
+writes all ``steps`` columns at ``pos0[b] ..``, so those of a slot that was
+idle, or that met EOS mid-chunk, land at or beyond its frozen ``pos`` —
+harmless: a slot never attends an index its own ``pos`` hasn't covered, the
+next flush starts at ``pos`` again, and prefill overwrites ``[0, Tp)`` and
+resets ``pos`` when the slot is reused.  A slot that decodes needs
+``pos0 + steps <= S`` (the engine sizes the cache ``bucket + max_new +
+chunk``); where an IDLE slot's frozen ``pos`` is nearer the end than that,
+the slice update clamps and lands on the slot's own dead columns.
 """
 
 from __future__ import annotations
@@ -52,8 +65,10 @@ def kv_heads(cfg) -> int:
 
 
 def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
-    """Fixed-size KV cache: k/v ``[L, B, KV, S, dh]`` plus per-slot ``pos``."""
-    shape = (cfg.n_layers, n_slots, kv_heads(cfg), max_len, cfg.head_dim)
+    """Fixed-size KV cache: k/v ``[L, B, KV, dh, S]`` (positions LAST, so the
+    scores of a decode step come out with S on the lanes and the chip
+    stores the cache unpadded) plus per-slot ``pos``."""
+    shape = (cfg.n_layers, n_slots, kv_heads(cfg), cfg.head_dim, max_len)
     return {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
@@ -61,28 +76,39 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     }
 
 
-def _decode_attend(q, k_cache, v_cache, pos) -> jax.Array:
-    """q ``[B, H, 1, dh]`` against the full cache ``[B, KV, S, dh]`` with a
-    per-slot length mask ``j <= pos``.  GQA folds the query heads onto
-    their KV head by reshape (no materialized repeat)."""
+def _decode_attend(q, k_cache, v_cache, k_new, v_new, pos0, i) -> jax.Array:
+    """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
+    the cache ``[B, KV, dh, S]`` at positions ``j < pos0`` (where the slot
+    stood when the chunk began) and the chunk's own columns ``[steps, B,
+    KV, dh]`` at ``t <= i``.  ONE softmax over both score sets (shared max
+    and denominator).  GQA folds the query heads onto their KV head by
+    reshape (no materialized repeat)."""
     B, H, _, dh = q.shape
-    KV = k_cache.shape[1]
-    S = k_cache.shape[2]
+    KV, S, steps = k_cache.shape[1], k_cache.shape[3], k_new.shape[0]
     q = q.reshape(B, KV, H // KV, dh)
-    # keep the cache reads in bf16 (f32 accumulation via
-    # preferred_element_type) — upcasting the whole cache each step would
-    # double the dominant HBM traffic of decode
-    scores = jnp.einsum(
-        "bkgd,bksd->bkgs", q, k_cache.astype(q.dtype),
-        preferred_element_type=jnp.float32,
-    ) / (dh ** 0.5)
-    mask = jnp.arange(S)[None, None, None, :] <= pos[:, None, None, None]
-    scores = jnp.where(mask, scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bkgs,bksd->bkgd", w.astype(v_cache.dtype), v_cache,
-        preferred_element_type=jnp.float32,
-    )
+
+    def scores(spec, k, mask):
+        # keep the cache reads in bf16 (f32 accumulation via
+        # preferred_element_type) — upcasting the whole cache each step
+        # would double the dominant HBM traffic of decode
+        s = jnp.einsum(spec, q, k.astype(q.dtype),
+                       preferred_element_type=jnp.float32) / (dh ** 0.5)
+        return jnp.where(mask, s, -1e30)
+
+    s_old = scores("bkgd,bkds->bkgs", k_cache,
+                   jnp.arange(S)[None, None, None, :] < pos0[:, None, None, None])
+    s_new = scores("bkgd,tbkd->bkgt", k_new, jnp.arange(steps) <= i)
+    # column t = 0 is never masked, so the max is a real score
+    m = jnp.maximum(s_old.max(-1, keepdims=True), s_new.max(-1, keepdims=True))
+    e_old, e_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
+    denom = e_old.sum(-1, keepdims=True) + e_new.sum(-1, keepdims=True)
+
+    def weighted(spec, e, v):
+        return jnp.einsum(spec, (e / denom).astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    out = (weighted("bkgs,bkds->bkgd", e_old, v_cache)
+           + weighted("bkgt,tbkd->bkgd", e_new, v_new))
     return out.reshape(B, H, 1, dh)
 
 
@@ -209,8 +235,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
     # single advanced index keeps its axis position: one scatter per tensor
-    cache_k = cache["k"].at[:, slots, :, :Tp, :].set(ks.astype(cache["k"].dtype))
-    cache_v = cache["v"].at[:, slots, :, :Tp, :].set(vs.astype(cache["v"].dtype))
+    # (over whole slots, once a prompt; decode never scatters)
+    to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
+        jnp.swapaxes(t, 3, 4).astype(c.dtype))
+    cache_k, cache_v = to_cache(ks, cache["k"]), to_cache(vs, cache["v"])
     pos = cache["pos"].at[slots].set(lengths.astype(jnp.int32))
     last = _unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
@@ -223,62 +251,6 @@ def prefill(params, cfg, tokens: jax.Array, lengths: jax.Array,
     B = tokens.shape[0]
     return prefill_at(params, cfg, tokens, lengths, cache,
                       slot + jnp.arange(B, dtype=jnp.int32))
-
-
-def decode_step(params, cfg, cache: Dict[str, jax.Array], tokens: jax.Array,
-                active: jax.Array) -> Tuple[jax.Array, Dict]:
-    """One token for every slot.  ``tokens [B]`` are each slot's last
-    emitted token, written at ``pos`` then attended; ``active [B]`` bool
-    gates the position advance.  Returns ``(logits [B, V], cache)``.
-
-    The layer loop is a ``fori_loop`` carrying the FULL cache and writing
-    each layer's new K/V column with one scatter — XLA's while-loop buffer
-    aliasing keeps the cache in place.  (The earlier scan-with-outputs
-    version rebuilt the whole cache every step: measured ~1.3 ms/step of
-    pure rewrite traffic on v5e at GPT-2 125M, on top of the ~1.2 ms
-    weight-streaming floor.)"""
-    fam = family_of(cfg)
-    pos = cache["pos"]
-    B = tokens.shape[0]
-    H, dh = cfg.n_heads, cfg.head_dim
-    KV = kv_heads(cfg)
-    x = _embed(params, tokens[:, None], cfg, pos[:, None])  # [B, 1, D]
-    blocks = params["blocks"]
-    iota_b = jnp.arange(B)[:, None]
-    iota_kv = jnp.arange(KV)[None, :]
-    positions = pos[:, None]  # [B, 1] per-slot offsets (rope)
-
-    def layer(l, carry):
-        x, k_all, v_all = carry  # x [B, 1, D]
-        p = jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
-            blocks)
-        if fam == "gpt2":
-            q, k, v = _gpt2_qkv(x, p, cfg)  # [B, heads, 1, dh]
-        else:
-            q, k, v = _llama_qkv(x, p, cfg, positions)
-        # ONE scatter per tensor writes only the new column (l, b, :, pos_b)
-        k_all = k_all.at[l, iota_b, iota_kv, positions, :].set(
-            k[:, :, 0, :].astype(k_all.dtype))
-        v_all = v_all.at[l, iota_b, iota_kv, positions, :].set(
-            v[:, :, 0, :].astype(v_all.dtype))
-        k_c = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
-        v_c = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
-        out = _decode_attend(q, k_c, v_c, pos)  # [B, H, 1, dh]
-        out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * dh).astype(cfg.dtype)
-        if fam == "gpt2":
-            x = _gpt2_post_attn(x, out, p, cfg)
-        else:
-            x = _llama_post_attn(x, out, p, cfg)
-        return x, k_all, v_all
-
-    x, k_all, v_all = lax.fori_loop(
-        0, cfg.n_layers, layer, (x, cache["k"], cache["v"]))
-    logits = _unembed(params, x, cfg)[:, 0, :]
-    return logits, {
-        "k": k_all, "v": v_all,
-        "pos": pos + active.astype(jnp.int32),
-    }
 
 
 def sample_logits(logits: jax.Array, key: jax.Array, *, temperature: float = 0.0,
@@ -297,32 +269,92 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                  temperature: float = 0.0, top_k: int = 0,
                  eos_id: Optional[int] = None):
     """Run ``steps`` decode+sample iterations in one device computation.
-    Returns ``(emitted [B, steps], cache, active, key)``.  A slot that
-    emits ``eos_id`` flips inactive mid-chunk (its pos freezes)."""
+    ``tokens [B]`` are each slot's last emitted token, ``active [B]`` bool
+    gates the position advance.  Returns ``(emitted [B, steps], cache,
+    active, key)``.  A slot that emits ``eos_id`` flips inactive mid-chunk
+    (its pos freezes).
 
-    def step(carry, _):
-        cache, toks, act, k = carry
-        k, sub = jax.random.split(k)
-        logits, cache = decode_step(params, cfg, cache, toks, act)
+    No step writes into the cache (module docstring): the new K/V columns
+    go to a chunk-local buffer ``[L, steps, B, KV, dh]``, attention is over
+    the cache below ``pos0`` plus that buffer (:func:`_decode_attend`), and
+    after the last step each slot's columns are flushed to ``pos0[b]`` with
+    one ``dynamic_update_slice`` per tensor, in place.  (On the v5e the
+    GPT-2 XL step, 17 rows over 896 positions, fell from 19.74 to 13.16 ms:
+    ``serve-gpt2-xl-chat`` ``model.decode_step_ms``, ledger, PRs 25 and
+    28.)"""
+    fam = family_of(cfg)
+    B = tokens.shape[0]
+    H, dh, KV = cfg.n_heads, cfg.head_dim, kv_heads(cfg)
+    S = cache["k"].shape[-1]
+    # a dynamic_update_slice clamps silently: the flush of a slot at pos0
+    # needs pos0 + steps <= S (the engine's bucket + max_new + chunk)
+    assert steps <= S, (steps, S)
+    if steps == 0:
+        return jnp.zeros((B, 0), jnp.int32), cache, active, key
+    blocks = params["blocks"]
+    k_old, v_old, pos0 = cache["k"], cache["v"], cache["pos"]
+    local = jnp.zeros((cfg.n_layers, steps, B, KV, dh), k_old.dtype)
+
+    def step(carry, i):
+        k_loc, v_loc, pos, toks, act, rng = carry
+        rng, sub = jax.random.split(rng)
+        positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
+        x = _embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
+
+        def layer(l, carry):
+            x, k_loc, v_loc = carry
+            at_l = lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+            p = jax.tree.map(at_l, blocks)
+            if fam == "gpt2":
+                q, k, v = _gpt2_qkv(x, p, cfg)  # [B, heads, 1, dh]
+            else:
+                q, k, v = _llama_qkv(x, p, cfg, positions)
+            put = lambda buf, t: lax.dynamic_update_slice(
+                buf, t[None, None, :, :, 0, :].astype(buf.dtype),
+                (l, i, 0, 0, 0))
+            k_loc, v_loc = put(k_loc, k), put(v_loc, v)
+            out = _decode_attend(q, at_l(k_old), at_l(v_old),
+                                 at_l(k_loc), at_l(v_loc), pos0, i)
+            out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * dh).astype(cfg.dtype)
+            post = _gpt2_post_attn if fam == "gpt2" else _llama_post_attn
+            return post(x, out, p, cfg), k_loc, v_loc
+
+        x, k_loc, v_loc = lax.fori_loop(
+            0, cfg.n_layers, layer, (x, k_loc, v_loc))
+        logits = _unembed(params, x, cfg)[:, 0, :]
         nxt = sample_logits(logits, sub, temperature=temperature, top_k=top_k)
         nxt = jnp.where(act, nxt, toks)
+        pos = pos + act.astype(jnp.int32)
         if eos_id is not None:
             act = act & (nxt != eos_id)
-        return (cache, nxt, act, k), nxt
+        return (k_loc, v_loc, pos, nxt, act, rng), nxt
 
-    (cache, _, active, key), emitted = lax.scan(
-        step, (cache, tokens, active, key), None, length=steps)
-    return emitted.T, cache, active, key  # [B, steps]
+    (k_loc, v_loc, pos, _, active, key), emitted = lax.scan(
+        step, (local, local, pos0, tokens, active, key), jnp.arange(steps))
+
+    def flush(b, kv):
+        # slot b's columns of the chunk, as [L, 1, KV, dh, steps], to pos0[b]
+        col = lambda loc: jnp.transpose(
+            lax.dynamic_index_in_dim(loc, b, 2, keepdims=False),
+            (0, 2, 3, 1))[:, None]
+        return tuple(
+            lax.dynamic_update_slice(big, col(loc), (0, b, 0, 0, pos0[b]))
+            for big, loc in zip(kv, (k_loc, v_loc)))
+
+    k_all, v_all = lax.fori_loop(0, B, flush, (k_old, v_old))
+    return emitted.T, {"k": k_all, "v": v_all, "pos": pos}, active, key
 
 
 def generate(params, cfg, prompts: jax.Array, lengths: jax.Array, *,
              max_new_tokens: int, key: Optional[jax.Array] = None,
              temperature: float = 0.0, top_k: int = 0,
              eos_id: Optional[int] = None) -> jax.Array:
-    """One-shot batched generation (prefill + fused decode loop).  Returns
-    ``[B, max_new_tokens]`` generated tokens (post-EOS positions repeat the
-    EOS token).  For the serving path use :mod:`ray_tpu.serve.llm`, which
-    runs the same kernels under iteration-level continuous batching."""
+    """One-shot batched generation (prefill + ONE decode chunk of
+    ``max_new_tokens - 1`` steps, so the chunk-local K/V buffer is as wide
+    as the answer).  Returns ``[B, max_new_tokens]`` generated tokens
+    (post-EOS positions repeat the EOS token).  For the serving path use
+    :mod:`ray_tpu.serve.llm`, which runs the same kernels in fixed chunks
+    under iteration-level continuous batching."""
     B, Tp = prompts.shape
     if key is None:
         key = jax.random.PRNGKey(0)

@@ -16,6 +16,7 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
+import re
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -83,15 +84,22 @@ def _lower_train_step(chip):
         _on(chip, state), _on(chip, {"inputs": tokens, "targets": tokens}))]
 
 
-def _lower_serve_engine(chip, family):
-    """What a ``num_tpus=1`` LLM replica runs (bench.run_decode_bench's
-    shape): prefill of bucket 128 at the fixed admission width, and one
-    64-step decode chunk over 16 slots plus the scratch slot."""
+# GPT-2 XL's widths (huggingface.co/openai-community/gpt2-xl), the
+# benchmark's gpt2-xl configuration
+XL = dict(n_layers=48, n_heads=25, d_model=1600, d_ff=6400)
+
+
+def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
+                        **config_kwargs):
+    """What a ``num_tpus=1`` LLM replica runs: prefill of one bucket at the
+    fixed admission width, and one decode chunk over 16 slots plus the
+    scratch slot.  The defaults are bench.run_decode_bench's shape (320
+    cache positions: not a multiple of 128)."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
-    cfg = llm.make_config(family, "small")
-    n_slots, bucket, chunk, max_new = 16, 128, 64, 128
+    cfg = llm.make_config(family, "small", **config_kwargs)
+    n_slots = 16
     params = jax.eval_shape(lambda: jax.tree.map(
         lambda x: x.astype(cfg.dtype) if x.dtype == jnp.float32 else x,
         llm._default_init(cfg, 0)))
@@ -144,6 +152,9 @@ PROGRAMS = {
     "gpt2_125m_train_step": _lower_train_step,
     "serve_engine_gpt2": lambda chip: _lower_serve_engine(chip, "gpt2"),
     "serve_engine_llama": lambda chip: _lower_serve_engine(chip, "llama"),
+    # the serve-gpt2-xl-chat cell: 17 rows, 512 + 368 + 16 = 896 positions
+    "serve_engine_gpt2_xl_cell": lambda chip: _lower_serve_engine(
+        chip, "gpt2", bucket=512, chunk=16, max_new=368, **XL),
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -167,8 +178,6 @@ def test_xl_fsdp4_step_gathers_bf16_weights(topo):
     the layer loop bf16 weight shards are gathered, nothing with the 16
     sequences of the whole batch crosses chips, every gradient is summed
     across the chips in float32, and a chip's share fits."""
-    import re
-
     import numpy as np
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -177,8 +186,7 @@ def test_xl_fsdp4_step_gathers_bf16_weights(topo):
     from ray_tpu.parallel.sharding import collective_profile, rules_for_mesh
 
     mesh = Mesh(np.array(topo.devices).reshape(4), ("fsdp",))
-    cfg = gpt2.GPT2Config.gpt2_small(
-        n_layers=48, n_heads=25, d_model=1600, d_ff=6400, remat_policy="full")
+    cfg = gpt2.GPT2Config.gpt2_small(remat_policy="full", **XL)
     optimizer = gpt2.make_optimizer(lr=3e-4, warmup=20)
     rules = rules_for_mesh(mesh)
     replicated = NamedSharding(mesh, P())
@@ -230,6 +238,23 @@ def test_program_compiles_for_v5e(compiled, name):
     needs = [_fits(c) for c in programs]
     if name == "gpt2_125m_train_step":
         assert needs[0] > 1 * 2**30  # params + adam moments alone are 1.5 GB
+    if name.startswith("serve_engine"):
+        # the decode chunk writes the big cache once, at its end, in place:
+        # no scatter anywhere in it, and the cache's three leaves (k, pos,
+        # v: the arguments after the parameters, outputs 1-3) come back in
+        # the buffers they arrived in
+        decode = programs[1]
+        text = decode.as_text()
+        assert not re.search(r"\bscatter\(", text)
+        n_params = len(jax.tree.leaves(decode.args_info[0][0]))
+        aliased = re.search(r"input_output_alias=\{(.*?) \}, entry", text).group(1)
+        for out_index, arg in enumerate(range(n_params, n_params + 3), start=1):
+            assert f"{{{out_index}}}: ({arg}, {{}}, may-alias)" in aliased, aliased
+    if name == "serve_engine_gpt2_xl_cell":
+        # the layout cliff (ISSUE 28; the cell's `assumed` has the same one
+        # at 784 positions): a cache the compiler re-lays-out costs 8-12 GB
+        # of temporaries in converted copies.  In place it needs 1.31 GiB.
+        assert programs[1].memory_analysis().temp_size_in_bytes < 1.5 * 2**30
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; dq + dk/dv + the forward they differentiate

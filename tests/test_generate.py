@@ -25,17 +25,23 @@ def _greedy_reference(apply_fn, params, cfg, prompt, n_new):
     return toks[len(prompt):]
 
 
+def _model(family, seed=0, block_scale=1, **cfg_kw):
+    """A tiny f32 model.  As initialised it repeats its last token whatever
+    it attends (tied embeddings, small blocks); ``block_scale=8`` makes the
+    layers' matrices large enough that the answer depends on the context,
+    so that a wrong or stale K/V column changes a token."""
+    mod = gpt2 if family == "gpt2" else llama
+    cfg_cls = gpt2.GPT2Config if family == "gpt2" else llama.LlamaConfig
+    cfg = cfg_cls.tiny(dtype=jnp.float32, **cfg_kw)
+    params = mod.init(cfg, jax.random.PRNGKey(seed))
+    params["blocks"] = jax.tree.map(
+        lambda w: w * block_scale if w.ndim >= 3 else w, params["blocks"])
+    return cfg, params, mod.apply
+
+
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_cached_decode_matches_full_forward(family):
-    if family == "gpt2":
-        cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
-        params = gpt2.init(cfg, jax.random.PRNGKey(0))
-        apply_fn = lambda p, t, c: gpt2.apply(p, t, c)
-    else:
-        cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-        params = llama.init(cfg, jax.random.PRNGKey(0))
-        apply_fn = lambda p, t, c: llama.apply(p, t, c)
-
+    cfg, params, apply_fn = _model(family)
     prompt = [3, 17, 5, 9, 2, 11]
     want = _greedy_reference(apply_fn, params, cfg, prompt, 8)
     out = gen.generate(
@@ -103,3 +109,176 @@ def test_prefill_then_chunked_decode_equals_one_shot():
         emitted.extend(int(t) for t in np.asarray(chunk[0]))
         tok = chunk[:, -1]
     assert emitted == [int(t) for t in one[0]]
+
+
+# ---------------------------------------------------------------------------
+# the chunk-local K/V buffer and its once-a-chunk flush (PR 28): inside a
+# chunk no step writes the cache; what the NEXT chunk reads is the flush
+# ---------------------------------------------------------------------------
+
+class _Slots:
+    """The engine's use of the programs, on the host: a cache of ``n`` slots
+    (the last one the scratch slot), prompts admitted into any of them, all
+    decoded together ``steps`` tokens a chunk."""
+
+    def __init__(self, family, n, max_len, *, eos_id=None, **cfg_kw):
+        self.cfg, self.params, self.apply = _model(
+            family, seed=4, block_scale=8, **cfg_kw)
+        self.n, self.eos_id = n, eos_id
+        self.cache = gen.init_cache(self.cfg, n, max_len)
+        self.tok = jnp.zeros((n,), jnp.int32)
+        self.active = np.zeros((n,), bool)
+        self.key = jax.random.PRNGKey(0)
+        self.out, self._prompts = {}, {}
+
+    def admit(self, slot, prompt, bucket):
+        """Prefill ``prompt`` padded to ``bucket`` into ``slot``; the padding
+        row of a two-row admission parks in the scratch slot, as the engine's
+        does."""
+        toks = np.zeros((2, bucket), np.int32)
+        toks[0, :len(prompt)] = prompt
+        toks[1] = 1
+        last, self.cache = gen.prefill_at(
+            self.params, self.cfg, jnp.asarray(toks),
+            jnp.asarray([len(prompt), bucket]), self.cache,
+            jnp.asarray([slot, self.n - 1]))
+        first = int(jnp.argmax(last[0]))
+        self.tok = self.tok.at[slot].set(first)
+        self.active[slot] = first != self.eos_id
+        self.out[slot], self._prompts[slot] = [first], prompt
+
+    def decode(self, steps):
+        was = self.active.copy()
+        emitted, self.cache, active, self.key = gen.decode_chunk(
+            self.params, self.cfg, self.cache, self.tok,
+            jnp.asarray(self.active), self.key, steps=steps,
+            eos_id=self.eos_id)
+        emitted = np.asarray(emitted)
+        self.tok = jnp.asarray(emitted[:, -1])
+        self.active = np.array(active)
+        for slot in np.flatnonzero(was):
+            row = [int(t) for t in emitted[slot]]
+            if self.eos_id in row:  # what follows an EOS repeats it
+                row = row[:row.index(self.eos_id) + 1]
+            self.out[slot] += row
+        return emitted
+
+    def assert_greedy(self, slot, n_new):
+        """The slot's answer is the full forward's greedy one, by teacher
+        forcing: ONE forward over prompt + answer; by induction the answer
+        is greedy iff every token is the argmax after the tokens before."""
+        prompt, out = self._prompts[slot], self.out[slot]
+        assert len(out) == n_new
+        logits = self.apply(
+            self.params, jnp.asarray([prompt + out[:-1]]), self.cfg)
+        assert out == [
+            int(t) for t in jnp.argmax(logits[0, len(prompt) - 1:], -1)], slot
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+@pytest.mark.parametrize("chunks", [2, 3])
+def test_chunked_slots_at_different_positions(family, chunks):
+    """Slots at different positions in one batch, an idle slot and the
+    scratch slot beside them, over two and three consecutive chunks: every
+    slot's tokens equal the full forward's (the flush of chunk n is what
+    chunk n+1 attends)."""
+    eng = _Slots(family, 5, 8 + 3 * 4)
+    prompts = {0: [3, 17, 5], 1: [9, 4, 7, 2, 5, 11, 6, 8], 3: [12, 1, 6, 3, 9]}
+    for slot, prompt in prompts.items():
+        eng.admit(slot, prompt, 8)
+    for _ in range(chunks):
+        eng.decode(4)
+    for slot in prompts:
+        eng.assert_greedy(slot, 1 + 4 * chunks)
+    assert [int(p) for p in eng.cache["pos"]] == [
+        3 + 4 * chunks, 8 + 4 * chunks, 0, 5 + 4 * chunks, 8]
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_slot_admitted_between_chunks(family):
+    """A slot that joins while another is mid-answer (the engine admits
+    between chunks): the newcomer's prefill does not disturb the columns
+    the other slot flushed, and both match the full forward."""
+    eng = _Slots(family, 3, 8 + 12)
+    a, b = [5, 9, 2, 14], [7, 1, 4, 8, 3, 6]
+    eng.admit(0, a, 8)
+    eng.decode(4)
+    eng.admit(1, b, 8)
+    eng.decode(4)
+    eng.decode(4)
+    eng.assert_greedy(0, 13)
+    eng.assert_greedy(1, 9)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_eos_mid_chunk_then_slot_reused(family):
+    """EOS in the middle of a chunk freezes the slot's ``pos``; the columns
+    it flushed after that lie beyond ``pos``.  Re-prefilled with a SHORTER
+    prompt and decoded again, the slot must not attend them."""
+    probe = _Slots(family, 2, 8 + 12)
+    first = [3, 17, 5, 9, 2, 11, 4]
+    probe.admit(0, first, 8)
+    free_run = probe.decode(6)[0]
+    eos = int(free_run[2])  # the 4th token of the answer ends it
+    assert eos not in [probe.out[0][0]] + [int(t) for t in free_run[:2]]
+
+    eng = _Slots(family, 2, 8 + 12, eos_id=eos)
+    eng.admit(0, first, 8)
+    row = eng.decode(6)[0]
+    assert [int(t) for t in row] == [int(t) for t in free_run[:3]] + [eos] * 3
+    assert int(eng.cache["pos"][0]) == len(first) + 3 and not eng.active[0]
+    eng.decode(6)  # an idle chunk: the frozen slot flushes garbage again
+    assert int(eng.cache["pos"][0]) == len(first) + 3
+
+    second = [6, 2]
+    eng.eos_id = None
+    eng.admit(0, second, 4)  # bucket 4: columns 4.. keep the old request's
+    eng.decode(6)
+    eng.decode(6)
+    eng.assert_greedy(0, 13)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_chunk_straddles_a_128_position_boundary(family):
+    """A chunk whose ``pos0 .. pos0 + steps`` crosses position 128 (a lane
+    tile of the S-minor cache on the chip), next to a slot that ends its
+    chunk exactly on the boundary."""
+    eng = _Slots(family, 3, 160, max_seq_len=160)
+    rng = np.random.default_rng(0)
+    long_a = [int(t) for t in rng.integers(1, 200, size=123)]
+    long_b = [int(t) for t in rng.integers(1, 200, size=120)]
+    eng.admit(0, long_a, 128)
+    eng.admit(1, long_b, 128)
+    eng.decode(8)   # 123..131 crosses; 120..128 ends on the boundary
+    eng.decode(8)   # 128..136 starts on it
+    eng.assert_greedy(0, 17)
+    eng.assert_greedy(1, 17)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_chunk_of_one_and_of_none(family):
+    """``steps=1`` is the one-step form; ``steps=0`` returns the cache as
+    it came (``generate(max_new_tokens=1)`` asks for it)."""
+    eng = _Slots(family, 2, 8 + 4)
+    prompt = [9, 4, 7, 2, 5]
+    eng.admit(0, prompt, 8)
+    for _ in range(3):
+        eng.decode(1)
+    eng.assert_greedy(0, 4)
+    none, cache, _, _ = gen.decode_chunk(
+        eng.params, eng.cfg, eng.cache, eng.tok, jnp.asarray(eng.active),
+        eng.key, steps=0)
+    assert none.shape == (2, 0) and cache is eng.cache
+    one = gen.generate(eng.params, eng.cfg, jnp.asarray([prompt]),
+                       jnp.asarray([len(prompt)]), max_new_tokens=1)
+    assert [int(t) for t in one[0]] == eng.out[0][:1]
+
+
+def test_flush_that_would_not_fit_is_refused():
+    """A dynamic_update_slice clamps silently, so a chunk longer than the
+    cache is refused where the sizes are static."""
+    eng = _Slots("gpt2", 1, 8)
+    eng.admit(0, [1, 2, 3], 4)
+    with pytest.raises(AssertionError):
+        gen.decode_chunk(eng.params, eng.cfg, eng.cache, eng.tok,
+                         jnp.asarray(eng.active), eng.key, steps=9)
