@@ -908,8 +908,7 @@ class Node:
         reference's /tmp/ray/ray_current_cluster analog)."""
         import json
 
-        path = "/tmp/ray_tpu/last_session.json"
-        os.makedirs(os.path.dirname(path), exist_ok=True)
+        os.makedirs("/tmp/ray_tpu", exist_ok=True)
         host, port = self.tcp_address
         payload = {
             "address": f"tcp://{host}:{port}",
@@ -919,13 +918,17 @@ class Node:
             "pid": os.getpid(),
             "dashboard": list(self.dashboard.address) if self.dashboard else None,
         }
-        # a temp name of our own: two heads starting at once shared one
-        # ".tmp", and the slower one's rename found it already gone
-        tmp = f"{path}.{os.getpid()}.tmp"
-        fd = os.open(tmp, os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o600)
-        with os.fdopen(fd, "w") as f:
-            json.dump(payload, f)
-        os.replace(tmp, path)
+        # in the session's own directory too: the box-wide record belongs to
+        # whichever head started last (`ray_tpu up` reads its own head's)
+        for path in (os.path.join(self.session_dir, "session.json"),
+                     "/tmp/ray_tpu/last_session.json"):
+            # a temp name of our own: two heads starting at once shared one
+            # ".tmp", and the slower one's rename found it already gone
+            tmp = f"{path}.{os.getpid()}.tmp"
+            fd = os.open(tmp, os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o600)
+            with os.fdopen(fd, "w") as f:
+                json.dump(payload, f)
+            os.replace(tmp, path)
 
     # ------------------------------------------------------------------
     # topology

@@ -119,7 +119,7 @@ def load_cluster_config(path: str) -> Dict[str, Any]:
 def up(config: Dict[str, Any], runner: Optional[CommandRunner] = None,
        python: str = sys.executable) -> Dict[str, Any]:
     """Start the head, read its session record, join every worker host.
-    Returns {"address", "authkey", "workers": [...]} for status/down."""
+    Returns {"address", "authkey", "head_pid", "workers": [...]}."""
     runner = runner or _runner_for(config)
     head = config["head_node"]
     head_addr = head.get("address", "127.0.0.1")
@@ -130,22 +130,22 @@ def up(config: Dict[str, Any], runner: Optional[CommandRunner] = None,
     # remote workers' dials are refused (the default bind is loopback)
     env_prefix = "RAY_TPU_HOST=0.0.0.0 " if remote_head else ""
     head_cmd = (
-        # clear any stale session record first — the poll below must see
-        # THIS head's record, not a dead predecessor's
-        f"rm -f /tmp/ray_tpu/last_session.json; "
         f"{env_prefix}nohup {shlex.quote(python)} -m ray_tpu start --head "
         f"{_node_flags(head)} {config.get('head_start_extra', '')} "
-        f"> /tmp/ray_tpu_{name}_head.log 2>&1 & echo started"
+        f"> /tmp/ray_tpu_{name}_head.log 2>&1 & echo $!"
     )
-    runner.run(head_addr, head_cmd)
+    head_pid = int(runner.run(head_addr, head_cmd).split()[-1])
 
-    # the head writes its tcp:// address + authkey to the session record
+    # the head writes its tcp:// address + authkey to the record in its own
+    # session directory (named by its pid): THIS head's, whatever other
+    # head has written the box-wide last_session.json meanwhile
     session = None
     deadline = time.time() + float(config.get("start_timeout_s", 120))
     while time.time() < deadline:
         try:
             out = runner.run(
-                head_addr, "cat /tmp/ray_tpu/last_session.json", timeout=30)
+                head_addr, f"cat /tmp/ray_tpu/session_{head_pid}_*/session.json",
+                timeout=30)
             session = json.loads(out)
             break
         except Exception:
@@ -173,7 +173,8 @@ def up(config: Dict[str, Any], runner: Optional[CommandRunner] = None,
         runner.run(addr, join_cmd)
         joined.append({"address": addr, "node_id": f"node-{name}-{i}"})
     return {"address": address, "authkey": session["authkey"],
-            "workers": joined, "head_address": head_addr}
+            "head_pid": head_pid, "workers": joined,
+            "head_address": head_addr}
 
 
 def down(config: Dict[str, Any], runner: Optional[CommandRunner] = None) -> None:

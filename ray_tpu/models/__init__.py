@@ -11,6 +11,11 @@ rules in :mod:`ray_tpu.parallel.sharding` apply mechanically.  Families:
 - :mod:`ray_tpu.models.llama` — Llama-family decoder (RMSNorm/RoPE/
   SwiGLU/grouped-query attention; long-context + GQA KV savings).
 - :mod:`ray_tpu.models.mlp` — MNIST-class MLP (BASELINE config 2).
+
+A transformer family writes its block once; training, prefill and decode
+share it through one seam, the attention middle the block takes as an
+argument (:mod:`ray_tpu.models.transformer`).  Which families can generate
+is the table ``FAMILIES`` of :mod:`ray_tpu.models.generate`.
 """
 
 from ray_tpu.models import bert, gpt2, llama, mlp  # noqa: F401

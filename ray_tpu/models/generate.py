@@ -5,6 +5,12 @@ plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
 
+- **One block per family**: prefill and decode run the block training
+  runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
+  (:mod:`ray_tpu.models.transformer`): prefill the full causal attention,
+  keeping the layer's k, v for the cache; decode the write into the
+  chunk-local buffer and :func:`_decode_attend`.  ``FAMILIES`` below is
+  the one place that knows the families: a new one is one module.
 - **Static shapes everywhere**: the cache is a fixed ``[L, B, KV, dh, S]``
   buffer (positions last: the decode step's scores come out with S on the
   lanes, and the chip stores the cache unpadded); positions are dynamic
@@ -47,21 +53,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.gpt2 import GPT2Config
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops.layers import layernorm, rmsnorm, rope
+from ray_tpu.models import gpt2, llama
+from ray_tpu.models.transformer import _attend
+
+# The one table that knows the families: name -> the family's module.  A
+# module here names its config class (``Config``) and presets (``SIZES``)
+# and has ``init``, ``kv_heads``, ``embed``, ``block`` and ``unembed``.
+FAMILIES = {"gpt2": gpt2, "llama": llama}
 
 
-def family_of(cfg) -> str:
-    if isinstance(cfg, LlamaConfig):
-        return "llama"
-    if isinstance(cfg, GPT2Config):
-        return "gpt2"
+def family_of(cfg):
+    """The module of the family ``cfg`` is a config of."""
+    for fam in FAMILIES.values():
+        if type(cfg) is fam.Config:
+            return fam
     raise TypeError(f"no generation support for config {type(cfg).__name__}")
 
 
 def kv_heads(cfg) -> int:
-    return cfg.n_kv_heads if isinstance(cfg, LlamaConfig) else cfg.n_heads
+    return family_of(cfg).kv_heads(cfg)
 
 
 def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
@@ -112,106 +122,6 @@ def _decode_attend(q, k_cache, v_cache, k_new, v_new, pos0, i) -> jax.Array:
     return out.reshape(B, H, 1, dh)
 
 
-# ---------------------------------------------------------------------------
-# per-family block math — ONE implementation serves prefill and decode:
-# _qkv projects (post-rope, [B, heads, T, dh]), _post_attn applies the
-# output projection + FFN residuals; only the attention middle differs
-# (full causal for prefill, cache-masked for decode)
-# ---------------------------------------------------------------------------
-
-def _gpt2_qkv(x, p, cfg: GPT2Config):
-    """x [B, T, D] -> q, k, v [B, H, T, dh]."""
-    B, T, _ = x.shape
-    H, dh = cfg.n_heads, cfg.head_dim
-    c = lambda w: w.astype(cfg.dtype)
-    h = layernorm(x, c(p["ln1_w"]), c(p["ln1_b"]))
-    qkv = h @ c(p["wqkv"]) + c(p["bqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    to_heads = lambda t: t.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-    return to_heads(q), to_heads(k), to_heads(v)
-
-
-def _gpt2_post_attn(x, out, p, cfg: GPT2Config):
-    """out [B, T, D] (attention result, head-merged) -> next x."""
-    c = lambda w: w.astype(cfg.dtype)
-    x = x + out @ c(p["wo"]) + c(p["bo"])
-    h = layernorm(x, c(p["ln2_w"]), c(p["ln2_b"]))
-    h = jax.nn.gelu(h @ c(p["w1"]) + c(p["b1"]), approximate=True)
-    return x + h @ c(p["w2"]) + c(p["b2"])
-
-
-def _llama_qkv(x, p, cfg: LlamaConfig, positions):
-    """x [B, T, D] -> post-rope q [B, H, T, dh], k/v [B, KV, T, dh] (the
-    GQA KV-head layout the cache stores)."""
-    B, T, _ = x.shape
-    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    dt = cfg.dtype
-    h = rmsnorm(x, p["attn_norm"].astype(dt), eps=cfg.rms_eps)
-    q = (h @ p["wq"].astype(dt)).reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-    k = (h @ p["wk"].astype(dt)).reshape(B, T, KV, dh).transpose(0, 2, 1, 3)
-    v = (h @ p["wv"].astype(dt)).reshape(B, T, KV, dh).transpose(0, 2, 1, 3)
-    return (rope(q, positions, base=cfg.rope_base),
-            rope(k, positions, base=cfg.rope_base), v)
-
-
-def _llama_post_attn(x, out, p, cfg: LlamaConfig):
-    dt = cfg.dtype
-    x = x + out @ p["wo"].astype(dt)
-    h = rmsnorm(x, p["ffn_norm"].astype(dt), eps=cfg.rms_eps)
-    gated = jax.nn.silu(h @ p["w_gate"].astype(dt)) * (h @ p["w_up"].astype(dt))
-    return x + gated @ p["w_down"].astype(dt)
-
-
-def _gpt2_block(x, p, cfg: GPT2Config):
-    """One GPT-2 prefill block: full causal self-attention over
-    ``x [B, T, D]``; returns ``(x, (k, v))`` for the cache."""
-    B, T, D = x.shape
-    q, k, v = _gpt2_qkv(x, p, cfg)
-    from ray_tpu.ops.attention import attention
-
-    out = attention(q, k, v, causal=True)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, D).astype(cfg.dtype)
-    return _gpt2_post_attn(x, out, p, cfg), (k, v)
-
-
-def _llama_block(x, p, cfg: LlamaConfig, positions):
-    """One Llama prefill block (RMSNorm/RoPE/GQA/SwiGLU); the cache stores
-    post-RoPE keys in the KV-head layout (the GQA memory saving)."""
-    B, T, _ = x.shape
-    H, dh = cfg.n_heads, cfg.head_dim
-    q, k, v = _llama_qkv(x, p, cfg, positions)
-    kr = jnp.repeat(k, cfg.q_per_kv, axis=1)
-    vr = jnp.repeat(v, cfg.q_per_kv, axis=1)
-    from ray_tpu.ops.attention import attention
-
-    out = attention(q, kr, vr, causal=True)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, H * dh).astype(cfg.dtype)
-    return _llama_post_attn(x, out, p, cfg), (k, v)
-
-
-# ---------------------------------------------------------------------------
-# prefill / decode over the stacked layers
-# ---------------------------------------------------------------------------
-
-def _embed(params, tokens, cfg, positions):
-    if family_of(cfg) == "gpt2":
-        x = params["wte"][tokens] + jnp.take(params["wpe"], positions, axis=0)
-    else:
-        x = params["tok_emb"][tokens]
-    return x.astype(cfg.dtype)
-
-
-def _unembed(params, x, cfg):
-    if family_of(cfg) == "gpt2":
-        x = layernorm(x, params["lnf_w"].astype(cfg.dtype),
-                      params["lnf_b"].astype(cfg.dtype))
-        w = params["wte"]
-    else:
-        x = rmsnorm(x, params["final_norm"].astype(cfg.dtype), eps=cfg.rms_eps)
-        w = params["tok_emb"]
-    return (x @ w.T.astype(cfg.dtype)).astype(jnp.float32)
-
-
 def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                cache: Dict[str, jax.Array], slots: jax.Array) -> Tuple[jax.Array, Dict]:
     """Run the prompts ``tokens [B, Tp]`` (right-padded; true lengths
@@ -222,16 +132,15 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     fam = family_of(cfg)
     B, Tp = tokens.shape
     positions = jnp.arange(Tp)
-    x = _embed(params, tokens, cfg, positions)
+    x = fam.embed(params, tokens, cfg, positions)
 
-    if fam == "gpt2":
-        def body(h, p):
-            h, kv = _gpt2_block(h, p, cfg)
-            return h, kv
-    else:
-        def body(h, p):
-            h, kv = _llama_block(h, p, cfg, positions)
-            return h, kv
+    def attend(q, k, v):
+        # the full causal attention of training, this layer's k, v kept
+        return _attend(q, k, v, causal=True, mesh=None)[0], (k, v)
+
+    def body(h, p):
+        h, _, kv = fam.block(h, p, cfg, attend, positions)
+        return h, kv
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
     # single advanced index keeps its axis position: one scatter per tensor
@@ -240,7 +149,7 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         jnp.swapaxes(t, 3, 4).astype(c.dtype))
     cache_k, cache_v = to_cache(ks, cache["k"]), to_cache(vs, cache["v"])
     pos = cache["pos"].at[slots].set(lengths.astype(jnp.int32))
-    last = _unembed(params, jnp.take_along_axis(
+    last = fam.unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
     return last[:, 0, :], {"k": cache_k, "v": cache_v, "pos": pos}
 
@@ -284,7 +193,6 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     28.)"""
     fam = family_of(cfg)
     B = tokens.shape[0]
-    H, dh, KV = cfg.n_heads, cfg.head_dim, kv_heads(cfg)
     S = cache["k"].shape[-1]
     # a dynamic_update_slice clamps silently: the flush of a slot at pos0
     # needs pos0 + steps <= S (the engine's bucket + max_new + chunk)
@@ -293,35 +201,35 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
     blocks = params["blocks"]
     k_old, v_old, pos0 = cache["k"], cache["v"], cache["pos"]
-    local = jnp.zeros((cfg.n_layers, steps, B, KV, dh), k_old.dtype)
+    local = jnp.zeros(  # [L, steps, B, KV, dh]
+        (cfg.n_layers, steps, B, *k_old.shape[2:4]), k_old.dtype)
 
     def step(carry, i):
         k_loc, v_loc, pos, toks, act, rng = carry
         rng, sub = jax.random.split(rng)
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
-        x = _embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
+        x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
 
         def layer(l, carry):
             x, k_loc, v_loc = carry
             at_l = lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-            p = jax.tree.map(at_l, blocks)
-            if fam == "gpt2":
-                q, k, v = _gpt2_qkv(x, p, cfg)  # [B, heads, 1, dh]
-            else:
-                q, k, v = _llama_qkv(x, p, cfg, positions)
-            put = lambda buf, t: lax.dynamic_update_slice(
-                buf, t[None, None, :, :, 0, :].astype(buf.dtype),
-                (l, i, 0, 0, 0))
-            k_loc, v_loc = put(k_loc, k), put(v_loc, v)
-            out = _decode_attend(q, at_l(k_old), at_l(v_old),
-                                 at_l(k_loc), at_l(v_loc), pos0, i)
-            out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * dh).astype(cfg.dtype)
-            post = _gpt2_post_attn if fam == "gpt2" else _llama_post_attn
-            return post(x, out, p, cfg), k_loc, v_loc
+
+            def attend(q, k, v):  # [B, heads, 1, dh]
+                put = lambda buf, t: lax.dynamic_update_slice(
+                    buf, t[None, None, :, :, 0, :].astype(buf.dtype),
+                    (l, i, 0, 0, 0))
+                k_new, v_new = put(k_loc, k), put(v_loc, v)
+                out = _decode_attend(q, at_l(k_old), at_l(v_old),
+                                     at_l(k_new), at_l(v_new), pos0, i)
+                return out.astype(cfg.dtype), (k_new, v_new)
+
+            x, _, (k_loc, v_loc) = fam.block(
+                x, jax.tree.map(at_l, blocks), cfg, attend, positions)
+            return x, k_loc, v_loc
 
         x, k_loc, v_loc = lax.fori_loop(
             0, cfg.n_layers, layer, (x, k_loc, v_loc))
-        logits = _unembed(params, x, cfg)[:, 0, :]
+        logits = fam.unembed(params, x, cfg)[:, 0, :]
         nxt = sample_logits(logits, sub, temperature=temperature, top_k=top_k)
         nxt = jnp.where(act, nxt, toks)
         pos = pos + act.astype(jnp.int32)

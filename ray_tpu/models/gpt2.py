@@ -19,6 +19,7 @@ from jax.sharding import Mesh
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
+    apply_block as block,  # noqa: F401 — this family's block, by the name generate.py reads
     apply_stack,
     block_logical_axes,
     init_block_params,
@@ -54,6 +55,13 @@ class GPT2Config(TransformerConfig):
         return GPT2Config(**base)
 
 
+# the family table (ray_tpu.models.generate.FAMILIES) reads these two: the
+# config class, and the presets ``size`` names
+Config = GPT2Config
+SIZES = {"small": GPT2Config.gpt2_small, "125m": GPT2Config.gpt2_small,
+         "tiny": GPT2Config.tiny}
+
+
 def init(cfg: GPT2Config, key: jax.Array) -> Dict[str, Any]:
     k_emb, k_pos, k_blocks = jax.random.split(key, 3)
     return {
@@ -79,6 +87,44 @@ def param_shardings(mesh: Mesh, rules: ShardingRules, cfg: Optional[GPT2Config] 
     return logical_to_sharding(logical_axes(cfg), mesh, rules)
 
 
+def kv_heads(cfg: GPT2Config) -> int:
+    """K/V heads a cache holds for a position: one for each query head."""
+    return cfg.n_heads
+
+
+def embed(params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config,
+          positions: Optional[jax.Array] = None,
+          mesh: Optional[Mesh] = None,
+          rules: Optional[ShardingRules] = None) -> jax.Array:
+    """tokens [B, T] -> x [B, T, D] in cfg.dtype.  ``positions``: [T], or
+    [B, 1] (a decode step's per-slot offsets); None: 0..T-1."""
+    # under an fsdp mesh axis each parameter comes whole along fsdp in the
+    # dtype it is used in and activations stay on the batch; else the two
+    # helpers do nothing
+    wpe = gather_for_compute(params["wpe"], logical_axes(cfg)["wpe"], mesh,
+                             rules, params["wpe"].dtype)
+    x = params["wte"][tokens] + (
+        wpe[:tokens.shape[1]] if positions is None
+        else jnp.take(wpe, positions, axis=0))
+    return shard_activations(x.astype(cfg.dtype), mesh, rules)
+
+
+def unembed(params: Dict[str, Any], x: jax.Array, cfg: GPT2Config,
+            mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None) -> jax.Array:
+    """Final norm and the tied LM head: x [B, T, D] -> logits [B, T, V] f32
+    (on the batch under fsdp, parameter gradients summed in float32)."""
+    axes = logical_axes(cfg)
+    whole = lambda w, axes: gather_for_compute(  # noqa: E731
+        w, axes, mesh, rules, cfg.dtype)
+    f32g = fsdp_engaged(mesh, x)
+    x = layernorm(x, whole(params["lnf_w"], axes["lnf_w"]),
+                  whole(params["lnf_b"], axes["lnf_b"]), f32_param_grads=f32g)
+    head = whole(params["wte"].T, axes["wte"][::-1])
+    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
+    return shard_activations(logits, mesh, rules, "vocab")
+
+
 def apply(
     params: Dict[str, Any], tokens: jax.Array, cfg: GPT2Config,
     mesh: Optional[Mesh] = None, *, return_aux: bool = False,
@@ -89,24 +135,9 @@ def apply(
     With ``return_aux=True`` returns ``(logits, aux)`` where aux is the
     MoE load-balance loss (0 for dense configs).  ``rules``: the table the
     parameters were placed with, when it is not ``rules_for_mesh(mesh)``."""
-    B, T = tokens.shape
-    # under an fsdp mesh axis each parameter comes whole along fsdp in the
-    # dtype it is used in, activations and logits stay on the batch and
-    # parameter gradients are summed in float32; else these do nothing
-    axes = logical_axes(cfg)
-    whole = lambda name, dtype: gather_for_compute(  # noqa: E731
-        params[name], axes[name], mesh, rules, dtype)
-    x = params["wte"][tokens] + whole("wpe", params["wpe"].dtype)[:T]
-    x = shard_activations(x.astype(cfg.dtype), mesh, rules)
+    x = embed(params, tokens, cfg, mesh=mesh, rules=rules)
     x, aux = apply_stack(x, params["blocks"], cfg, mesh, rules)
-    f32g = fsdp_engaged(mesh, x)
-    x = layernorm(x, whole("lnf_w", cfg.dtype), whole("lnf_b", cfg.dtype),
-                  f32_param_grads=f32g)
-    # tied embeddings for the LM head
-    head = gather_for_compute(params["wte"].T, axes["wte"][::-1], mesh, rules,
-                              cfg.dtype)
-    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
-    logits = shard_activations(logits, mesh, rules, "vocab")
+    logits = unembed(params, x, cfg, mesh, rules)
     return (logits, aux) if return_aux else logits
 
 

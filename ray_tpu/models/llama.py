@@ -23,7 +23,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.models.gpt2 import make_optimizer  # same AdamW recipe
-from ray_tpu.models.transformer import make_train_step_from_loss
+from ray_tpu.models.transformer import (
+    _attend,
+    apply_stack,
+    make_train_step_from_loss,
+)
 from ray_tpu.ops.layers import cross_entropy_loss, dense, rmsnorm, rope
 from ray_tpu.parallel.sharding import (
     ShardingRules,
@@ -34,7 +38,8 @@ from ray_tpu.parallel.sharding import (
 )
 
 __all__ = [
-    "LlamaConfig", "init", "apply", "loss_fn", "make_train_step",
+    "LlamaConfig", "init", "apply", "block", "embed", "unembed", "kv_heads",
+    "loss_fn", "make_train_step",
     "init_state", "num_params", "logical_axes", "param_shardings",
     "make_optimizer",
 ]
@@ -53,9 +58,9 @@ class LlamaConfig:
     rms_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # "dots" saves matmul outputs and recomputes elementwise (measured
-    # +3-6% over full remat at these shapes on v5e — same policy the
-    # shared transformer core uses); "full" recomputes everything
+    # "dots" saves matmul outputs and recomputes elementwise (the same
+    # policy the shared transformer core uses); "full" recomputes
+    # everything.  No benchmark cell runs this family.
     remat_policy: str = "dots"
 
     @property
@@ -78,6 +83,13 @@ class LlamaConfig:
                     d_model=64, d_ff=128, max_seq_len=128, remat=False)
         base.update(kw)
         return LlamaConfig(**base)
+
+
+# the family table (ray_tpu.models.generate.FAMILIES) reads these two: the
+# config class, and the presets ``size`` names
+Config = LlamaConfig
+SIZES = {"small": LlamaConfig.llama_125m, "125m": LlamaConfig.llama_125m,
+         "tiny": LlamaConfig.tiny}
 
 
 def _dense(key, n_in, n_out, scale=1.0):
@@ -136,9 +148,22 @@ def param_shardings(mesh: Mesh, rules: ShardingRules, cfg: Optional[LlamaConfig]
     return logical_to_sharding(logical_axes(cfg), mesh, rules)
 
 
-def _block(x, p, cfg: LlamaConfig, mesh: Optional[Mesh], positions):
+def kv_heads(cfg: LlamaConfig) -> int:
+    """K/V heads a cache holds for a position (the GQA saving)."""
+    return cfg.n_kv_heads
+
+
+def block(x, p, cfg: LlamaConfig, attend=None, positions=None,
+          mesh: Optional[Mesh] = None):
+    """One Llama block (RMSNorm/RoPE/GQA/SwiGLU).  x: [B, T, D] in cfg.dtype;
+    ``positions`` [T] or [B, T] for the rotary embedding (None: 0..T-1).
+    ``attend``: the attention middle (:mod:`ray_tpu.models.transformer`),
+    given post-rope q and k, v in the KV-head layout a cache stores.
+    Returns ``(x, aux, carried)``; aux is 0 (no experts in this family)."""
     B, T, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    attend = attend or partial(_attend, causal=True, mesh=mesh)
+    positions = jnp.arange(T) if positions is None else positions
     # under fsdp the batch is spread over chips: sum the parameters'
     # gradients over it in float32
     f32g = fsdp_engaged(mesh, x)
@@ -151,22 +176,41 @@ def _block(x, p, cfg: LlamaConfig, mesh: Optional[Mesh], positions):
     v = lin(h, p["wv"]).reshape(B, T, KV, hd)
     q = rope(q.transpose(0, 2, 1, 3), positions, base=cfg.rope_base)  # [B,H,T,hd]
     k = rope(k.transpose(0, 2, 1, 3), positions, base=cfg.rope_base)  # [B,KV,T,hd]
-    v = v.transpose(0, 2, 1, 3)
-    # GQA: each KV head serves q_per_kv query heads
-    if KV != H:
-        k = jnp.repeat(k, cfg.q_per_kv, axis=1)
-        v = jnp.repeat(v, cfg.q_per_kv, axis=1)
-    # the shared transformer-core seam shard_maps ring attention when the
-    # mesh has sp > 1
-    from ray_tpu.models.transformer import _attend
-
-    o = _attend(q, k, v, causal=True, mesh=mesh)  # [B, H, T, hd]
+    o, carried = attend(q, k, v.transpose(0, 2, 1, 3))  # [B, H, T, hd]
     o = o.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
     x = x + lin(o, p["wo"])
 
     h = norm(x, p["ffn_norm"])
     gated = jax.nn.silu(lin(h, p["w_gate"])) * lin(h, p["w_up"])
-    return x + lin(gated, p["w_down"])
+    return x + lin(gated, p["w_down"]), jnp.zeros((), jnp.float32), carried
+
+
+def embed(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
+          positions: Optional[jax.Array] = None,
+          mesh: Optional[Mesh] = None,
+          rules: Optional[ShardingRules] = None) -> jax.Array:
+    """tokens [B, T] -> x [B, T, D] in cfg.dtype (``positions`` is not used:
+    this family's positions are the blocks' rotary embedding)."""
+    return shard_activations(params["tok_emb"][tokens].astype(cfg.dtype),
+                             mesh, rules)
+
+
+def unembed(params: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
+            mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None) -> jax.Array:
+    """Final norm and the tied LM head: x [B, T, D] -> logits [B, T, V] f32
+    (under an fsdp mesh axis, as in transformer.apply_stack: parameters
+    whole along fsdp in cfg.dtype, logits on the batch, parameter gradients
+    summed in float32; else the helpers do nothing)."""
+    axes = logical_axes(cfg)
+    whole = lambda w, axes: gather_for_compute(  # noqa: E731
+        w, axes, mesh, rules, cfg.dtype)
+    f32g = fsdp_engaged(mesh, x)
+    x = rmsnorm(x, whole(params["final_norm"], axes["final_norm"]),
+                eps=cfg.rms_eps, f32_param_grads=f32g)
+    head = whole(params["tok_emb"].T, axes["tok_emb"][::-1])
+    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
+    return shard_activations(logits, mesh, rules, "vocab")
 
 
 def apply(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
@@ -175,41 +219,10 @@ def apply(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     """tokens [B, T] int32 -> logits [B, T, V] f32 (tied embeddings).
     ``rules``: the table the parameters were placed with, when it is not
     ``rules_for_mesh(mesh)``."""
-    B, T = tokens.shape
-    # under an fsdp mesh axis (as in transformer.apply_stack): each layer's
-    # weights whole along fsdp, moved in cfg.dtype, activations and logits on the
-    # batch, parameter gradients summed in float32; else these do nothing
-    axes = logical_axes(cfg)
-    whole = lambda w, axes: gather_for_compute(  # noqa: E731
-        w, axes, mesh, rules, cfg.dtype)
-    x = shard_activations(params["tok_emb"][tokens].astype(cfg.dtype), mesh, rules)
-    positions = jnp.arange(T)
-
-    def body(h, layer_params):
-        h = shard_activations(h, mesh, rules)
-        layer_params = {k: whole(w, axes["blocks"][k][1:])
-                        for k, w in layer_params.items()}
-        return _block(h, layer_params, cfg, mesh, positions), None
-
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            )
-        elif cfg.remat_policy == "full":
-            body = jax.checkpoint(body)
-        else:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r} (use 'dots' or 'full')"
-            )
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    f32g = fsdp_engaged(mesh, x)
-    x = rmsnorm(x, whole(params["final_norm"], axes["final_norm"]),
-                eps=cfg.rms_eps, f32_param_grads=f32g)
-    head = whole(params["tok_emb"].T, axes["tok_emb"][::-1])
-    logits = dense(x, head, f32_param_grads=f32g).astype(jnp.float32)
-    return shard_activations(logits, mesh, rules, "vocab")
+    x = embed(params, tokens, cfg, mesh=mesh, rules=rules)
+    x, _ = apply_stack(x, params["blocks"], cfg, mesh, rules, block=block,
+                       axes=logical_axes(cfg)["blocks"])
+    return unembed(params, x, cfg, mesh, rules)
 
 
 def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None,

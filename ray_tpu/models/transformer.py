@@ -19,6 +19,14 @@ Design choices, all TPU-motivated:
   and pins the activations to the batch, so chips exchange weights, not
   activations, and the layer ops sum parameter gradients over the batch
   (so across chips) in float32 (see :mod:`ray_tpu.parallel.sharding`).
+- **One block per family, one seam for attention**: a family's block
+  (:func:`apply_block` here, ``llama.block``) is the only place its
+  projections, norms, residuals and FFN are written.  Training, prefill and
+  decode differ in the attention middle alone, so the block takes it as an
+  argument: ``attend(q [B, H, T, dh], k, v [B, KV, T, dh])`` returns the
+  attention output ``[B, H, T, dh]`` and whatever its caller wants carried
+  out of the layer (:func:`_attend`: nothing; prefill: the layer's k, v;
+  decode: the chunk's K/V buffers — :mod:`ray_tpu.models.generate`).
 """
 
 from __future__ import annotations
@@ -55,9 +63,10 @@ class TransformerConfig:
     causal: bool = True
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # "dots": save matmul outputs, recompute elementwise (measured ~+6%
-    # over full remat at GPT-2 shapes on v5e — the backward re-reads saved
-    # MXU outputs instead of re-running them); "full": recompute all.
+    # "dots": save matmul outputs, recompute elementwise (the backward
+    # re-reads saved MXU outputs instead of re-running them); "full":
+    # recompute all.  train-gpt2-medium-1k runs "dots" and
+    # train-gpt2-xl-fsdp4 "full" (what fits); no cell compares the two.
     remat_policy: str = "dots"
     # pre-LN (GPT-2 style) by default; post-LN matches original BERT so
     # HF checkpoints load faithfully.
@@ -150,8 +159,12 @@ def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = No
     return train_step
 
 
-def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh]) -> jax.Array:
-    """Pick the sequence-parallel path when the mesh has an sp axis."""
+def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh]):
+    """The attention middle of training and ``apply``: the whole sequence,
+    each KV head serving ``H // KV`` query heads, sequence-parallel when the
+    mesh has an sp axis.  Carries nothing out of the layer."""
+    if k.shape[1] != q.shape[1]:  # GQA
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
     if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
         batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
         heads = "tp" if "tp" in mesh.axis_names else None
@@ -163,8 +176,8 @@ def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh]) -> jax.Array:
             out_specs=spec,
             check_vma=False,
         )
-        return sm(q, k, v)
-    return attention(q, k, v, causal=causal)
+        return sm(q, k, v), None
+    return attention(q, k, v, causal=causal), None
 
 
 # block parameters used in the dtype they are stored in; every other one is
@@ -174,13 +187,18 @@ _USED_AS_STORED = ("router",)
 
 def apply_block(
     x: jax.Array, p: Dict[str, jax.Array], cfg: TransformerConfig,
+    attend=None, positions: Optional[jax.Array] = None,
     mesh: Optional[Mesh] = None,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Any]:
     """One transformer block, pre-LN or post-LN.  x: [B, T, D] in cfg.dtype.
-    Returns ``(x, aux)`` — aux is the MoE load-balance loss (0 when dense)."""
+    ``attend``: the attention middle (module docstring; default
+    :func:`_attend`).  ``positions`` is not used: this family's are a table
+    added at the embedding.  Returns ``(x, aux, carried)`` — aux is the MoE
+    load-balance loss (0 when dense), carried what ``attend`` handed back."""
     B, T, D = x.shape
     H, dh = cfg.n_heads, cfg.head_dim
-    aux = jnp.zeros((), jnp.float32)
+    attend = attend or partial(_attend, causal=cfg.causal, mesh=mesh)
+    aux, carried = jnp.zeros((), jnp.float32), None
     # under fsdp the batch is spread over chips: sum the parameters'
     # gradients over it in float32
     f32g = fsdp_engaged(mesh, x)
@@ -188,10 +206,11 @@ def apply_block(
     norm = partial(layernorm, f32_param_grads=f32g)
 
     def attn(h):
+        nonlocal carried
         qkv = lin(h, p["wqkv"], p["bqkv"])
         q, k, v = jnp.split(qkv, 3, axis=-1)
         to_heads = lambda t: t.reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-        out = _attend(to_heads(q), to_heads(k), to_heads(v), causal=cfg.causal, mesh=mesh)
+        out, carried = attend(to_heads(q), to_heads(k), to_heads(v))
         out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
         return lin(out, p["wo"], p["bo"])
 
@@ -215,14 +234,16 @@ def apply_block(
     else:  # GPT-2 pre-LN
         x = x + attn(norm(x, p["ln1_w"], p["ln1_b"]))
         x = x + ffn(norm(x, p["ln2_w"], p["ln2_b"]))
-    return x, aux
+    return x, aux, carried
 
 
 def apply_stack(
-    x: jax.Array, blocks: Dict[str, jax.Array], cfg: TransformerConfig,
+    x: jax.Array, blocks: Dict[str, jax.Array], cfg: Any,
     mesh: Optional[Mesh] = None, rules: Optional[ShardingRules] = None,
+    *, block=apply_block, axes: Optional[Dict[str, Tuple]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run the stacked layers; returns ``(x, aux)``.
+    """Run the stacked layers of a family's ``block`` (``axes``: the logical
+    axes of ``blocks``; default: this module's); returns ``(x, aux)``.
 
     Without ``pp`` the stack is one remat'd ``lax.scan`` over the layer
     axis.  With a ``pp > 1`` mesh axis, the layer axis is sharded into
@@ -230,12 +251,12 @@ def apply_stack(
     (:func:`ray_tpu.parallel.pipeline.gpipe`) — same math, microbatched.
     """
 
-    axes = block_logical_axes(cfg.n_experts)
+    axes = axes or block_logical_axes(cfg.n_experts)
 
     def body(x, layer_params):
         # FSDP proper (both helpers do nothing without an fsdp mesh axis):
         # the batch stays where it is and THIS layer's weights come to it,
-        # moved in the dtype apply_block uses them in.  Inside the remat'd
+        # moved in the dtype the block uses them in.  Inside the remat'd
         # body, so the gather is per layer and is recomputed in the
         # backward pass, not saved.
         x = shard_activations(x, mesh, rules)
@@ -244,7 +265,8 @@ def apply_stack(
                 w, axes[k][1:], mesh, rules,
                 w.dtype if k in _USED_AS_STORED else cfg.dtype)
             for k, w in layer_params.items()}
-        return apply_block(x, layer_params, cfg, mesh)
+        x, aux, _ = block(x, layer_params, cfg, mesh=mesh)
+        return x, aux
 
     if cfg.remat:
         if cfg.remat_policy == "dots":

@@ -1,11 +1,14 @@
 """Attention implementations with one contract — ``[B, H, T, D]`` q/k/v.
 
-:func:`attention` dispatches by shape (measured on v5e, see each impl's
-docstring):
+:func:`attention` dispatches by shape.  Its thresholds predate the chip
+and no benchmark cell sits on either side of any of them (ROADMAP D7): the
+cells run :func:`causal_skip_attention` (training at T=1,024, the 512
+prefill bucket) and :func:`full_attention` (the 64/128/256 buckets); the
+other paths have no cell.
 
-- :func:`causal_skip_attention` — the causal production path at moderate
-  T: unrolled q-blocks contracting only visible keys (~40% FLOPs saved),
-  bf16 matmuls with f32 accumulation.  Fastest measured fwd+bwd.
+- :func:`causal_skip_attention` — the causal path at moderate T: unrolled
+  q-blocks contracting only visible keys (~40% of the FLOPs of masked full
+  attention skipped at T=1024), bf16 matmuls with f32 accumulation.
 - :func:`full_attention` — masked materialized-scores path (non-causal,
   or shapes causal-skip can't take).
 - :func:`blockwise_attention` — online-softmax ``lax.scan`` over k/v
@@ -14,8 +17,7 @@ docstring):
 
 - :func:`flash_attention_tpu` — pallas MXU-tiled kernels for BOTH forward
   and backward (dq/dk/dv rebuilt from the saved logsumexp, recompute-free).
-  Slower than the XLA paths at GPT-2 shapes (d_head=64, T≤4k) but fastest
-  from ~8k tokens — the dispatch selects it for long context on TPU.
+  The dispatch selects it from 8k tokens on TPU.
 
 Not in the dispatch:
 
@@ -442,13 +444,14 @@ def attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
     scale: Optional[float] = None, block_q: int = 128, block_k: int = 128,
 ) -> jax.Array:
-    """Dispatch to the fastest correct implementation for the shape.
-    Single entry point used by the model zoo.
+    """Dispatch to an implementation by shape (module docstring: none of
+    the thresholds has a cell on either side).  Single entry point used by
+    the model zoo.
 
     - causal, square, block-divisible, moderate T → :func:`causal_skip_attention`
     - moderate T → :func:`full_attention` (masked, MXU dtypes)
     - T ≥ 8k on TPU, block-divisible → :func:`flash_attention_tpu`
-      (pallas fwd + recompute-free bwd kernels; measured crossover on v5e)
+      (pallas fwd + recompute-free bwd kernels)
     - other long T → :func:`blockwise_attention` (O(block) memory,
       pads+masks any length; ring attention covers sharded-T)
     """
@@ -459,7 +462,7 @@ def attention(
         return full_attention(q, k, v, causal=causal, scale=scale)
     if (
         q.ndim == 4
-        and t_k >= 8192  # measured crossover vs the XLA paths on v5e
+        and t_k >= 8192  # predates the chip; no cell on either side
         and t_q % block_q == 0
         and t_k % block_k == 0
         and jax.default_backend() == "tpu"
@@ -499,9 +502,7 @@ def full_attention(
     in their dtype (bf16 in the models), scores accumulate in f32
     (``preferred_element_type``), softmax in f32, P@V back in input dtype.
 
-    Measured faster fwd+bwd on v5e at moderate T than our pallas kernel,
-    jax's in-tree pallas flash, and f32 blockwise (XLA fuses the masked
-    softmax; head_dim=64 tiles fine).
+    XLA fuses the masked softmax.
     """
     *_, t_q, d = q.shape
     t_k = k.shape[-2]
@@ -523,13 +524,11 @@ def causal_skip_attention(
     shape static so XLA tiles each branch onto the MXU.  Requires
     ``t_q == t_k`` divisible by ``block``.
 
-    One dot + one full-width masked select per q block, deliberately: an
-    A/B with separate unmasked-prefix/masked-diagonal dots measured ~7%
-    SLOWER end-to-end (XLA fuses the select into the softmax for free, but
-    two dots + concat fuse worse than one).  Measured ~2.5x faster fwd+bwd
-    than both pallas flash kernels (ours and jax's in-tree) at GPT-2
-    shapes on v5e — which is why this, not the pallas path, is the
-    dispatcher's causal default.
+    One dot + one full-width masked select per q block (XLA fuses the
+    select into the softmax; separate unmasked-prefix and masked-diagonal
+    dots would need a concat).  The dispatcher's causal default below 4k
+    tokens: the train cells and the 512 prefill bucket run it; no cell
+    runs the pallas pair against it.
     """
     *_, t, d = q.shape
     scale = scale if scale is not None else d ** -0.5

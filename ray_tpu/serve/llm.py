@@ -61,13 +61,10 @@ def _llm_metrics():
             "tokens": Counter(
                 "ray_tpu_llm_generated_tokens_total",
                 "tokens generated by the continuous-batching engine"),
-            "requests": Counter(
-                "ray_tpu_llm_requests_total",
-                "requests completed by the continuous-batching engine"),
             # decode-tail attribution (the gpt2 p99/p50=1.39x chase):
-            # TTFT + inter-token latency per request, per-tick wall by
-            # mode, and the decode-tick seconds billed to co-scheduled
-            # prefill chunks — all TSDB-exported via the push path
+            # TTFT + inter-token latency per request, and the decode-tick
+            # seconds billed to co-scheduled prefill chunks — all
+            # TSDB-exported via the push path
             "ttft": Histogram(
                 "ray_tpu_llm_ttft_s",
                 "request submit -> first generated token (s)",
@@ -77,12 +74,6 @@ def _llm_metrics():
                 "inter-token latency (per-drain mean per request, s)",
                 boundaries=[0.0005, 0.001, 0.005, 0.01, 0.025, 0.05,
                             0.1, 0.5, 1]),
-            "tick": Histogram(
-                "ray_tpu_llm_decode_tick_s",
-                "engine iteration wall by mode (decode_only / "
-                "interleaved / prefill_only)",
-                boundaries=[0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5],
-                tag_keys=("mode",)),
             "interference": Counter(
                 "ray_tpu_llm_prefill_interference_s_total",
                 "decode-tick seconds billed to co-scheduled prefill "
@@ -129,8 +120,6 @@ class _TickMeter:
             mode = "decode_only"
         self.ticks[mode] += 1
         self.tick_s[mode] += wall
-        m = _llm_metrics()
-        m["tick"].observe(wall, tags={"mode": mode})
         if sum(self.ticks.values()) % 256 == 1:
             # periodic HBM watermark from the serving process (the train
             # profiler samples per step; the engine samples per ~256
@@ -160,7 +149,7 @@ class _TickMeter:
                 interference += max(0.0, wall - admit_s - self.ema_decode_s)
                 self.excess_s += max(0.0, wall - self.ema_decode_s)
             self.interference_s += interference
-            m["interference"].inc(interference)
+            _llm_metrics()["interference"].inc(interference)
             self._since_emit += 1
             if self._since_emit >= self.EMIT_EVERY:
                 self.emit_event()
@@ -634,13 +623,6 @@ class GenerationEngine:
         admit_s = (time.perf_counter() - t_tick0) if meter else 0.0
         with self._lock:
             rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
-        if _events.ENABLED and admissions and len(rows) > len(admissions):
-            # new prefills joined mid-stream: the interleave the continuous
-            # batcher exists for (admission never idles active decodes)
-            _events.emit(
-                "serve_llm", "prefill/decode interleave", severity="DEBUG",
-                admitted=len(admissions),
-                decoding=len(rows) - len(admissions))
         dispatched = None
         if rows:
             with self._annotate("engine.decode_dispatch"):
@@ -750,9 +732,7 @@ class GenerationEngine:
                         self._slots[i] = None  # freed by cap prediction
                 self.total_generated += len(req.emitted)
                 if _events.ENABLED:
-                    m = _llm_metrics()
-                    m["tokens"].inc(len(req.emitted))
-                    m["requests"].inc()
+                    _llm_metrics()["tokens"].inc(len(req.emitted))
                     tf = {}
                     if req.trace_ctx is not None:
                         # the whole submit->completion window as a span of
@@ -831,24 +811,16 @@ def _default_init(cfg, seed: int):
 
     from ray_tpu.models import generate as gen
 
-    fam = gen.family_of(cfg)
-    if fam == "gpt2":
-        from ray_tpu.models import gpt2 as m
-    else:
-        from ray_tpu.models import llama as m
-    return m.init(cfg, jax.random.PRNGKey(seed))
+    return gen.family_of(cfg).init(cfg, jax.random.PRNGKey(seed))
 
 
 def make_config(family: str = "gpt2", size: str = "tiny", **kw):
-    if family == "gpt2":
-        from ray_tpu.models.gpt2 import GPT2Config as C
+    from ray_tpu.models import generate as gen
 
-        return C.gpt2_small(**kw) if size in ("small", "125m") else C.tiny(**kw)
-    if family == "llama":
-        from ray_tpu.models.llama import LlamaConfig as C
-
-        return C.llama_125m(**kw) if size in ("small", "125m") else C.tiny(**kw)
-    raise ValueError(f"unknown model family {family!r}")
+    if family not in gen.FAMILIES:
+        raise ValueError(f"unknown model family {family!r}")
+    sizes = gen.FAMILIES[family].SIZES
+    return sizes.get(size, sizes["tiny"])(**kw)
 
 
 def prefill_decode_graph(

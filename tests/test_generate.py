@@ -282,3 +282,76 @@ def test_flush_that_would_not_fit_is_refused():
     with pytest.raises(AssertionError):
         gen.decode_chunk(eng.params, eng.cfg, eng.cache, eng.tok,
                          jnp.asarray(eng.active), eng.key, steps=9)
+
+
+# -- the seam: a new family is one module -------------------------------------
+
+class _NoPEConfig(gpt2.TransformerConfig):
+    """GPT-2 without its position table (causal attention alone orders it)."""
+
+
+def _nope_family():
+    """A third family from the existing pieces: GPT-2's block, init and head,
+    the position table left out.  Everything generate.py and the engine ask
+    of a family, and its own full forward (``apply``) to compare with."""
+    import types
+
+    from ray_tpu.models.transformer import apply_stack
+
+    def init(cfg, key):
+        return {k: v for k, v in gpt2.init(cfg, key).items() if k != "wpe"}
+
+    def embed(params, tokens, cfg, positions=None, mesh=None, rules=None):
+        return params["wte"][tokens].astype(cfg.dtype)
+
+    def apply(params, tokens, cfg):
+        x, _ = apply_stack(embed(params, tokens, cfg), params["blocks"], cfg)
+        return gpt2.unembed(params, x, cfg)
+
+    tiny = lambda **kw: _NoPEConfig(**{  # noqa: E731
+        **vars(gpt2.GPT2Config.tiny()), "dtype": jnp.float32, **kw})
+    return types.SimpleNamespace(
+        Config=_NoPEConfig, SIZES={"tiny": tiny}, init=init, embed=embed,
+        block=gpt2.block, unembed=gpt2.unembed, kv_heads=gpt2.kv_heads,
+        apply=apply)
+
+
+@pytest.fixture
+def nope():
+    """The family, in the table for the length of one test."""
+    fam = gen.FAMILIES["nope"] = _nope_family()
+    try:
+        yield fam
+    finally:
+        del gen.FAMILIES["nope"]
+
+
+@pytest.mark.parametrize("through", ["generate", "engine"])
+def test_a_new_family_is_one_module(nope, through):
+    """Registered in the table and nowhere else, the family generates: cached
+    decode equals its own full forward token for token, and an engine built
+    on its config alone (make_config, default init) answers a request."""
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("nope", "tiny")
+    assert gen.family_of(cfg) is nope and "wpe" not in llm._default_init(cfg, 0)
+    prompt = [3, 17, 5, 9, 2, 11]
+    if through == "generate":
+        params = nope.init(cfg, jax.random.PRNGKey(0))
+        params["blocks"] = jax.tree.map(  # context-dependent, as in _model
+            lambda w: w * 8 if w.ndim >= 3 else w, params["blocks"])
+        out = gen.generate(
+            params, cfg, jnp.asarray([prompt]), jnp.asarray([len(prompt)]),
+            max_new_tokens=8)
+        assert [int(t) for t in out[0]] == _greedy_reference(
+            nope.apply, params, cfg, prompt, 8)
+    else:
+        eng = llm.GenerationEngine(
+            cfg, n_slots=2, max_new_tokens=6, decode_chunk_steps=3,
+            prefill_buckets=(8,)).start()
+        try:
+            got = eng.generate(prompt, timeout=120)
+        finally:
+            eng.stop()
+        assert got == _greedy_reference(
+            nope.apply, llm._default_init(cfg, 0), cfg, prompt, 6)

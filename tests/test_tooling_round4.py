@@ -22,7 +22,9 @@ import ray_tpu
 
 def test_cluster_up_down_local_provider(tmp_path):
     """`ray_tpu up` from a YAML with the local provider: a real head
-    process + a real worker agent, then `down` reaps both."""
+    process + a real worker agent, then `down` reaps both.  Judged by what
+    `up` returned, never by /tmp/ray_tpu/last_session.json: every head on
+    the box (each xdist worker's tests) overwrites that record."""
     from ray_tpu.autoscaler.commands import down, load_cluster_config, up
 
     cfg_path = tmp_path / "cluster.yaml"
@@ -39,7 +41,8 @@ def test_cluster_up_down_local_provider(tmp_path):
         assert out["address"].startswith("tcp://")
         assert len(out["workers"]) == 1
         # join the launched cluster as a driver and see BOTH nodes
-        ray_tpu.init(address="auto")
+        ray_tpu.init(address=out["address"],
+                     _authkey=bytes.fromhex(out["authkey"]))
         deadline = time.time() + 60
         while time.time() < deadline:
             if len(ray_tpu.nodes()) >= 2:
@@ -58,8 +61,7 @@ def test_cluster_up_down_local_provider(tmp_path):
     # the head process is gone (or a zombie — this container's pid 1 does
     # not reap orphans, and a zombie still answers os.kill(pid, 0))
     time.sleep(1.5)
-    sess = json.loads(open("/tmp/ray_tpu/last_session.json").read())
-    pid = sess["pid"]
+    pid = out["head_pid"]
     try:
         with open(f"/proc/{pid}/stat") as f:
             state = f.read().rsplit(")", 1)[-1].split()[0]
