@@ -15,7 +15,8 @@ first-class TPU path, designed for XLA:
   buffer (positions last: the decode step's scores come out with S on the
   lanes, and the chip stores the cache unpadded); positions are dynamic
   *values*, never dynamic shapes, so the decode chunk compiles once and
-  runs for every token.
+  runs for every token.  The ALLOCATION is padded; what a step reads of it
+  is not (last point below).
 - **Per-slot positions**: each batch slot sits at its own offset (``pos``
   vector), which is what iteration-level continuous batching needs
   (Orca-style; see :mod:`ray_tpu.serve.llm`).
@@ -32,6 +33,21 @@ first-class TPU path, designed for XLA:
   the donated cache through the flush loop).  A scatter of every slot's
   column at its own position per layer per step cost 7.5 ms of the 19.7 ms
   GPT-2 XL decode step on the v5e (PERF.md, PR 28).
+- **A step reads only the live cache**: a slot attends the cache below
+  ``live[b]`` — ``pos0[b]``, or 0 for a slot that sits the chunk out (the
+  scratch row, idle slots, finished requests).  Lowered for a TPU with a
+  cache of whole 128-position tiles (``S % 128 == 0``; the engine rounds
+  its cache up to that), the cache half of the attention is a Pallas kernel
+  that copies in, per slot, only the tiles below ``live[b]`` and returns the
+  softmax un-normalised (:func:`ray_tpu.ops.attention.ragged_decode_attention`;
+  :func:`_decode_attend` merges it with the chunk-local columns under one
+  max and denominator).  Anywhere else — the CPU, a cache of another
+  length such as :func:`generate`'s — the same sums run as masked einsums
+  over layer ``l``'s whole padded slab (:func:`_cache_scores_slab`), which
+  is also the reference the kernel is tested against.  Chosen by
+  ``lax.platform_dependent`` and the cache's shape; there is no flag.
+  Reading the padded slab was 6.2 ms of the 13.16 ms GPT-2 XL step, 17 rows
+  x 896 positions of which 14-20 % were live tiles (ledger, PR 29; ISSUE 30).
 
 The flush invariant: after a chunk, every position ``j < pos[b]`` of slot
 ``b`` holds a column that prefill or an ACTIVE step wrote.  The flush
@@ -55,6 +71,11 @@ from jax import lax
 
 from ray_tpu.models import gpt2, llama
 from ray_tpu.models.transformer import _attend
+from ray_tpu.ops.attention import (
+    DECODE_TILE,
+    ragged_decode_attention,
+    ragged_decode_plan,
+)
 
 # The one table that knows the families: name -> the family's module.  A
 # module here names its config class (``Config``) and presets (``SIZES``)
@@ -86,39 +107,67 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     }
 
 
-def _decode_attend(q, k_cache, v_cache, k_new, v_new, pos0, i) -> jax.Array:
+def _cache_scores_slab(q, k_all, v_all, l, n):
+    """The cache half of :func:`_decode_attend` as masked einsums over layer
+    ``l``'s whole padded slab ``[B, KV, dh, S]``: what every platform can
+    run, and the plain reference the kernel is held to.  Same result as
+    :func:`ray_tpu.ops.attention.ragged_decode_attention`."""
+    k, v = (lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+            for a in (k_all, v_all))
+    S, dh = k.shape[3], k.shape[2]
+    mask = jnp.arange(S)[None, None, None, :] < n[:, None, None, None]
+    # keep the cache reads in bf16 (f32 accumulation via
+    # preferred_element_type) — upcasting the whole cache each step
+    # would double the dominant HBM traffic of decode
+    s = jnp.einsum("bkgd,bkds->bkgs", q, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32) / (dh ** 0.5)
+    s = jnp.where(mask, s, -1e30)
+    m = s.max(-1)
+    e = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)  # n == 0: nothing
+    acc = jnp.einsum("bkgs,bkds->bkgd", e.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return acc, m, e.sum(-1)
+
+
+def _cache_scores(q, k_all, v_all, l, n, plan):
+    """``q [B, KV, G, dh]`` against positions ``j < n[b]`` of layer ``l`` of
+    the whole caches ``[L, B, KV, dh, S]``: ``(acc, m, d)``, the softmax
+    un-normalised.  Lowered for a TPU, with a cache of whole 128-position
+    tiles, the Pallas kernel that copies in only the tiles below ``n[b]``;
+    anywhere else the masked einsums over the slab.  Decided by what the
+    program is lowered for and by the cache's shape, never by a flag."""
+    if plan is None:
+        return _cache_scores_slab(q, k_all, v_all, l, n)
+    return lax.platform_dependent(
+        q, k_all, v_all, l, n, plan,
+        tpu=lambda q, k, v, l, n, plan: ragged_decode_attention(
+            q, k, v, l, plan),
+        default=lambda q, k, v, l, n, plan: _cache_scores_slab(q, k, v, l, n))
+
+
+def _decode_attend(q, k_all, v_all, l, k_new, v_new, n, plan, i) -> jax.Array:
     """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
-    the cache ``[B, KV, dh, S]`` at positions ``j < pos0`` (where the slot
-    stood when the chunk began) and the chunk's own columns ``[steps, B,
-    KV, dh]`` at ``t <= i``.  ONE softmax over both score sets (shared max
-    and denominator).  GQA folds the query heads onto their KV head by
-    reshape (no materialized repeat)."""
+    layer ``l`` of the caches ``[L, B, KV, dh, S]`` at positions ``j < n``
+    (where the slot stood when the chunk began; 0 for a slot that was
+    inactive then) and the chunk's own columns ``[steps, B, KV, dh]`` at
+    ``t <= i``.  ONE softmax over both score sets: the cache half arrives
+    un-normalised (:func:`_cache_scores`) and is merged with the chunk's
+    under the shared max and denominator.  GQA folds the query heads onto
+    their KV head by reshape (no materialized repeat)."""
     B, H, _, dh = q.shape
-    KV, S, steps = k_cache.shape[1], k_cache.shape[3], k_new.shape[0]
+    KV, steps = k_new.shape[2], k_new.shape[0]
     q = q.reshape(B, KV, H // KV, dh)
-
-    def scores(spec, k, mask):
-        # keep the cache reads in bf16 (f32 accumulation via
-        # preferred_element_type) — upcasting the whole cache each step
-        # would double the dominant HBM traffic of decode
-        s = jnp.einsum(spec, q, k.astype(q.dtype),
+    acc_old, m_old, d_old = _cache_scores(q, k_all, v_all, l, n, plan)
+    s_new = jnp.einsum("bkgd,tbkd->bkgt", q, k_new.astype(q.dtype),
                        preferred_element_type=jnp.float32) / (dh ** 0.5)
-        return jnp.where(mask, s, -1e30)
-
-    s_old = scores("bkgd,bkds->bkgs", k_cache,
-                   jnp.arange(S)[None, None, None, :] < pos0[:, None, None, None])
-    s_new = scores("bkgd,tbkd->bkgt", k_new, jnp.arange(steps) <= i)
+    s_new = jnp.where(jnp.arange(steps) <= i, s_new, -1e30)
     # column t = 0 is never masked, so the max is a real score
-    m = jnp.maximum(s_old.max(-1, keepdims=True), s_new.max(-1, keepdims=True))
-    e_old, e_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
-    denom = e_old.sum(-1, keepdims=True) + e_new.sum(-1, keepdims=True)
-
-    def weighted(spec, e, v):
-        return jnp.einsum(spec, (e / denom).astype(v.dtype), v,
-                          preferred_element_type=jnp.float32)
-
-    out = (weighted("bkgs,bkds->bkgd", e_old, v_cache)
-           + weighted("bkgt,tbkd->bkgd", e_new, v_new))
+    m = jnp.maximum(m_old, s_new.max(-1))
+    w_old, e_new = jnp.exp(m_old - m), jnp.exp(s_new - m[..., None])
+    denom = d_old * w_old + e_new.sum(-1)
+    out = acc_old * (w_old / denom)[..., None] + jnp.einsum(
+        "bkgt,tbkd->bkgd", (e_new / denom[..., None]).astype(v_new.dtype),
+        v_new, preferred_element_type=jnp.float32)
     return out.reshape(B, H, 1, dh)
 
 
@@ -190,7 +239,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     one ``dynamic_update_slice`` per tensor, in place.  (On the v5e the
     GPT-2 XL step, 17 rows over 896 positions, fell from 19.74 to 13.16 ms:
     ``serve-gpt2-xl-chat`` ``model.decode_step_ms``, ledger, PRs 25 and
-    28.)"""
+    28.)  What a slot attends of the cache is fixed when the chunk begins
+    (``live``), and so is the kernel's work list, built once here."""
     fam = family_of(cfg)
     B = tokens.shape[0]
     S = cache["k"].shape[-1]
@@ -201,6 +251,12 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
     blocks = params["blocks"]
     k_old, v_old, pos0 = cache["k"], cache["v"], cache["pos"]
+    # what a slot attends of the cache, fixed for the chunk: the positions
+    # below where it stood, nothing for a slot that sits the chunk out
+    live = jnp.where(active, pos0, 0)
+    plan = None
+    if S % DECODE_TILE == 0 and cfg.head_dim % 8 == 0:
+        plan = ragged_decode_plan(live, S // DECODE_TILE)
     local = jnp.zeros(  # [L, steps, B, KV, dh]
         (cfg.n_layers, steps, B, *k_old.shape[2:4]), k_old.dtype)
 
@@ -219,8 +275,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     buf, t[None, None, :, :, 0, :].astype(buf.dtype),
                     (l, i, 0, 0, 0))
                 k_new, v_new = put(k_loc, k), put(v_loc, v)
-                out = _decode_attend(q, at_l(k_old), at_l(v_old),
-                                     at_l(k_new), at_l(v_new), pos0, i)
+                out = _decode_attend(q, k_old, v_old, l, at_l(k_new),
+                                     at_l(v_new), live, plan, i)
                 return out.astype(cfg.dtype), (k_new, v_new)
 
             x, _, (k_loc, v_loc) = fam.block(

@@ -23,6 +23,16 @@ Not in the dispatch:
 
 - :func:`mha_reference` — naive O(T²) f32 attention; numerical ground
   truth for tests.
+- :func:`ragged_decode_attention` — the decode step's contract, not
+  ``[B, H, T, D]``: one query a slot against the S-minor KV cache ``[L, B,
+  KV, dh, S]`` left whole in HBM, a Pallas kernel that copies in only each
+  slot's 128-position tiles below its own live length and returns the
+  softmax un-normalised (acc, max, denominator) for the caller to merge.
+  Arithmetic on the VPU (one query a head is a matrix-VECTOR product).
+  ``models/generate.py`` calls it where a decode program is lowered for a
+  TPU with a cache of whole tiles: the ``serve-gpt2-xl-chat`` cell, whose
+  masked einsums over the padded slab it replaces (6.2 ms of a 13.16 ms
+  step: ledger, PR 29).
 """
 
 from __future__ import annotations
@@ -438,6 +448,218 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 
 
 flash_attention_tpu.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Ragged decode attention: one query a slot against the LIVE tiles of the
+# slot's cache
+# ---------------------------------------------------------------------------
+
+DECODE_TILE = 128  # cache positions a tile; one lane width
+# KV heads a trip of the kernel's rolled head loop, at most: on the v5e one
+# head a trip left the kernel 12 % slower, all 25 of GPT-2 XL unrolled cost
+# a replica 2 s of Python lowering at start-up (PERF.md, PR 30)
+HEAD_UNROLL = 5
+
+
+def ragged_decode_plan(n: jax.Array, n_tiles: int) -> jax.Array:
+    """The kernel's work list for live lengths ``n [B]`` over a cache of
+    ``n_tiles`` tiles a slot, as ONE int32 vector (one copy into scalar
+    memory a call): ``[count, n[0..B), slot[0..W), tile[0..W)]`` with ``W =
+    B * n_tiles``; items ``w < count`` are the ``(slot, tile)`` pairs with
+    ``tile * 128 < n[slot]``, in slot order, and the rest are never read.
+    A function of ``n`` alone: compute it once where ``n`` is fixed (a
+    decode chunk), not once a layer."""
+    n = n.astype(jnp.int32)
+    tiles = (n + DECODE_TILE - 1) // DECODE_TILE
+    ends = jnp.cumsum(tiles)
+    w = jnp.arange(n.shape[0] * n_tiles, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, w, side="right"), n.shape[0] - 1)
+    tile = w - (ends - tiles)[slot]
+    return jnp.concatenate([ends[-1:], n, slot, tile]).astype(jnp.int32)
+
+
+def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
+                          k_buf, v_buf, sem, q_wide, acc, m_run, l_run,
+                          *, scale: float, groups: int):
+    """One invocation walks the work list: item ``w`` is one 128-position
+    tile of one slot, copied from the caches in HBM (double-buffered: item
+    ``w + 1`` is in flight while ``w`` is computed, across slots too) and
+    folded into that slot's running softmax.
+
+    All arithmetic is on the VPU, a head at a time, on ``[dh, 128]`` blocks
+    with positions on the lanes, as the cache stores them.  The softmax
+    runs LANE-WISE: every lane keeps its own running max, denominator and
+    accumulator column over the slot's tiles (no cross-lane operation per
+    tile), and the 128 partial softmaxes are merged when the slot's last
+    tile is done.  The two layout changes a slot needs — q's values from
+    lanes to sublanes, the accumulator's from sublanes to lanes — are
+    128 x 128 transposes at the slot's first and last tile.  The loops over
+    heads are rolled (``HEAD_UNROLL``), so a head's running max and
+    denominator sit on 8 equal rows of ``m_run`` / ``l_run``: a whole
+    sublane tile, which a dynamic index can address.
+
+    ``out_ref [B, 2 * heads_pad + n_blocks, 128]`` (one copy out a call):
+    rows ``[0, heads_pad)`` the max of head ``r`` on every lane, the next
+    ``heads_pad`` its denominator still spread over the lanes, then the
+    accumulator as ``n_blocks`` rows of 128 consecutive ``(head, d)``."""
+    T = DECODE_TILE
+    n_slots = q_ref.shape[0]
+    kv_heads, dh = k_buf.shape[1], k_buf.shape[2]
+    heads, heads_pad = kv_heads * groups, m_run.shape[0] // 8
+    unroll = max(u for u in range(1, HEAD_UNROLL + 1) if kv_heads % u == 0)
+    n_blocks = q_wide.shape[0] // T
+    n_items = (plan_ref.shape[0] - 1 - n_slots) // 2
+    layer, count = layer_ref[0], plan_ref[0]
+    slot_of = lambda w: plan_ref[1 + n_slots + w]  # noqa: E731
+    tile_of = lambda w: plan_ref[1 + n_slots + n_items + w]  # noqa: E731
+
+    # slots the list never visits (nothing live) return the empty softmax
+    out_ref[:, :heads_pad, :] = jnp.full(
+        (n_slots, heads_pad, T), NEG_INF, jnp.float32)
+    out_ref[:, heads_pad:, :] = jnp.zeros(
+        (n_slots, heads_pad + n_blocks, T), jnp.float32)
+
+    def copies(w, buf):
+        start = pl.multiple_of(tile_of(w) * T, T)
+        return [
+            pltpu.make_async_copy(
+                src.at[layer, slot_of(w), :, :, pl.ds(start, T)],
+                dst.at[buf], sem.at[j, buf])
+            for j, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+
+    @pl.when(count > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    def item(w, _):
+        buf = w % 2
+        b, t = slot_of(w), tile_of(w)
+        n = plan_ref[1 + b]
+
+        @pl.when(w + 1 < count)
+        def _():
+            for c in copies(w + 1, 1 - buf):
+                c.start()
+
+        @pl.when(t == 0)
+        def _():  # the slot's first tile: q[r] on every lane of row r
+            for i in range(n_blocks):
+                rows = slice(i * T, (i + 1) * T)
+                q_wide[rows, :] = jnp.broadcast_to(
+                    q_ref[b, :, rows], (T, T)).T
+            acc[...] = jnp.zeros_like(acc)
+            l_run[...] = jnp.zeros_like(l_run)
+            m_run[...] = jnp.full_like(m_run, NEG_INF)
+
+        for c in copies(w, buf):
+            c.wait()
+        live = t * T + lax.broadcasted_iota(jnp.int32, (1, T), 1) < n
+
+        def kv_head(kv):
+            k = k_buf[buf, kv].astype(jnp.float32)  # [dh, T]
+            v = v_buf[buf, kv].astype(jnp.float32)
+            for g in range(groups):
+                r = kv * groups + g
+                rows = pl.ds(pl.multiple_of(r * dh, 8), dh)
+                stat = pl.ds(pl.multiple_of(r * 8, 8), 8)
+                s = jnp.sum(q_wide[rows, :] * k, axis=0, keepdims=True) * scale
+                s = jnp.where(live, s, NEG_INF)
+                m_prev = m_run[stat, :]  # [8, T], the head's 8 equal rows
+                m_new = jnp.maximum(m_prev, s)
+                alpha = jnp.exp(m_prev - m_new)
+                # a lane that has only seen masked positions has s == m_new
+                e = jnp.where(live, jnp.exp(s - m_new), 0.0)
+                m_run[stat, :] = m_new
+                l_run[stat, :] = l_run[stat, :] * alpha + e
+                acc[rows, :] = acc[rows, :] * alpha[:1] + e[:1] * v
+
+        def kv_heads_at(i, _):
+            for u in range(unroll):
+                kv_head(i * unroll + u)
+
+        lax.fori_loop(0, kv_heads // unroll, kv_heads_at, None)
+
+        @pl.when((t + 1) * T >= n)
+        def _():  # the slot's last tile: merge the 128 lane-wise softmaxes
+            every8 = pl.ds(0, heads_pad, stride=8)  # a head's first row
+            m_lane = m_run[every8, :]
+            m_all = jnp.max(m_lane, axis=1, keepdims=True)
+            weight = jnp.exp(m_lane - m_all)  # 0 on a lane that saw nothing
+            out_ref[b, :heads_pad, :] = jnp.broadcast_to(m_all, m_lane.shape)
+            out_ref[b, heads_pad:2 * heads_pad, :] = l_run[every8, :] * weight
+            m_run[every8, :] = weight  # the max is spent: row 0 carries on
+
+            def weigh(r, _):
+                rows = pl.ds(pl.multiple_of(r * dh, 8), dh)
+                w8 = m_run[pl.ds(pl.multiple_of(r * 8, 8), 8), :]
+                acc[rows, :] = acc[rows, :] * w8[:1]
+
+            lax.fori_loop(0, heads, weigh, None)
+            for i in range(n_blocks):
+                out_ref[b, 2 * heads_pad + i:2 * heads_pad + i + 1, :] = (
+                    jnp.sum(acc[i * T:(i + 1) * T, :].T, axis=0, keepdims=True))
+
+    lax.fori_loop(0, count, item, None)
+
+
+def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                            layer: jax.Array, plan: jax.Array, *,
+                            interpret=False):
+    """The cache half of a decode step's attention, reading only what is
+    live: ``q [B, KV, G, dh]`` (one query a head a slot) against layer
+    ``layer`` of the WHOLE caches ``k, v [L, B, KV, dh, S]``, which stay in
+    HBM; slot ``b`` attends positions ``j < n[b]``, and the kernel copies
+    in only its tiles ``t < ceil(n[b] / 128)`` (``plan``:
+    :func:`ragged_decode_plan` of ``n``).  Returns the softmax
+    un-normalised, flash-decoding style, for the caller to merge with its
+    other keys: ``acc [B, KV, G, dh]``, running max ``m`` and denominator
+    ``d [B, KV, G]``, all f32.  A slot with ``n[b] == 0`` gives ``acc = 0,
+    d = 0, m = -1e30`` and moves no byte of cache.  Needs ``S % 128 == 0``
+    and ``dh % 8 == 0``."""
+    B, KV, G, dh = q.shape
+    S, T = k.shape[-1], DECODE_TILE
+    assert S % T == 0 and dh % 8 == 0, (S, dh)
+    heads = KV * G
+    n_blocks = -(-heads * dh // T)  # whole 128-row blocks of (head, d)
+    heads_pad = -(-heads // 8) * 8
+    q_rows = jnp.pad(q.reshape(B, 1, heads * dh).astype(jnp.float32),
+                     ((0, 0), (0, 0), (0, n_blocks * T - heads * dh)))
+    whole = lambda *shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, *_: (0,) * len(shape))
+    out = pl.pallas_call(
+        functools.partial(_ragged_decode_kernel, scale=dh ** -0.5, groups=G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                whole(B, 1, n_blocks * T),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=whole(B, 2 * heads_pad + n_blocks, T),
+            scratch_shapes=[
+                pltpu.VMEM((2, KV, dh, T), k.dtype),
+                pltpu.VMEM((2, KV, dh, T), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_blocks * T, T), jnp.float32),  # q, lane-wide
+                pltpu.VMEM((n_blocks * T, T), jnp.float32),  # accumulator
+                # lane-wise max and denominator, a head's on 8 equal rows
+                # (whole sublane tiles, so a rolled head loop can index them)
+                pltpu.VMEM((heads_pad * 8, T), jnp.float32),
+                pltpu.VMEM((heads_pad * 8, T), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (B, 2 * heads_pad + n_blocks, T), jnp.float32),
+        name="ragged_decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q_rows, k, v)
+    acc = out[:, 2 * heads_pad:, :].reshape(B, n_blocks * T)[:, :heads * dh]
+    return (acc.reshape(B, KV, G, dh),
+            out[:, :heads, 0].reshape(B, KV, G),
+            out[:, heads_pad:heads_pad + heads, :].sum(-1).reshape(B, KV, G))
 
 
 def attention(

@@ -193,6 +193,19 @@ class _TickMeter:
             baseline_s=snap["decode_tick_baseline_s"])
 
 
+def cache_positions(largest_bucket: int, max_new_tokens: int,
+                    chunk_steps: int) -> int:
+    """Cache positions an engine gives a slot: the longest prompt, the
+    longest answer and one chunk of slack for the flush, rounded UP to whole
+    tiles of the decode kernel — a cache of whole tiles is what lets the
+    decode step read only a slot's live tiles
+    (:func:`ray_tpu.ops.attention.ragged_decode_attention`)."""
+    from ray_tpu.ops.attention import DECODE_TILE
+
+    need = largest_bucket + max_new_tokens + chunk_steps
+    return -(-need // DECODE_TILE) * DECODE_TILE
+
+
 class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
                  "submitted_at", "trace_ctx", "first_token_t",
@@ -298,7 +311,14 @@ class GenerationEngine:
         self.top_k = top_k
         self.eos_id = eos_id
 
-        self._max_len = self.buckets[-1] + max_new_tokens + decode_chunk_steps
+        self._max_len = cache_positions(
+            self.buckets[-1], max_new_tokens, decode_chunk_steps)
+        # cumulative: the cache tiles the dispatched decode chunks read a
+        # layer a step, and the tiles of the padded slab they did not have to
+        from ray_tpu.ops.attention import DECODE_TILE
+
+        self._tile = DECODE_TILE
+        self._cache_tiles = {"read": 0, "padded": 0}
         # one extra SCRATCH slot (index n_slots): batched admission pads
         # the prefill batch to a bucketed size and parks the padding rows
         # there, so admitting 1..n_slots requests costs ONE device dispatch
@@ -472,6 +492,7 @@ class GenerationEngine:
             # the same lock, so sorted() never sees a mutating deque
             ttft = list(self._ttft_samples)
             itl = list(self._itl_samples)
+            cache_tiles = dict(self._cache_tiles)
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
             stages = tracing.span_stats(STAGES)
@@ -484,6 +505,9 @@ class GenerationEngine:
             # process closed them (cumulative: difference two calls for a
             # window); empty with the observability layer off
             "stages": stages,
+            # how much of the padded cache the decode steps read (cumulative;
+            # counted at dispatch from prompt lengths and scheduled tokens)
+            "cache_tiles": cache_tiles,
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -639,6 +663,14 @@ class GenerationEngine:
             # (completion timing is deterministic; EOS only finishes a
             # request EARLIER, confirmed at drain)
             with self._lock:
+                # a slot stands at prompt + scheduled - 1 (the last sampled
+                # token is not in the cache yet); EOS, which only the
+                # device has seen, can only leave it lower
+                self._cache_tiles["read"] += sum(
+                    -(-(len(req.tokens) + req.scheduled - 1) // self._tile)
+                    for _, req in rows)
+                self._cache_tiles["padded"] += (
+                    (self.n_slots + 1) * self._max_len // self._tile)
                 for i, req in rows:
                     req.scheduled = min(req.max_new, req.scheduled + self.chunk)
                     if req.scheduled >= req.max_new:

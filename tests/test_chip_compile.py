@@ -93,8 +93,9 @@ def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
                         **config_kwargs):
     """What a ``num_tpus=1`` LLM replica runs: prefill of one bucket at the
     fixed admission width, and one decode chunk over 16 slots plus the
-    scratch slot.  The defaults are bench.run_decode_bench's shape (320
-    cache positions: not a multiple of 128)."""
+    scratch slot.  The defaults are bench.run_decode_bench's shape; the
+    cache's length is the engine's own rounding to whole 128-position tiles
+    (128 + 128 + 64 = 320 -> 384), so this is what a replica really runs."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -104,7 +105,8 @@ def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
         lambda x: x.astype(cfg.dtype) if x.dtype == jnp.float32 else x,
         llm._default_init(cfg, 0)))
     cache = jax.eval_shape(
-        lambda: gen.init_cache(cfg, n_slots + 1, bucket + max_new + chunk))
+        lambda: gen.init_cache(
+            cfg, n_slots + 1, llm.cache_positions(bucket, max_new, chunk)))
     prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
@@ -152,6 +154,10 @@ PROGRAMS = {
     "gpt2_125m_train_step": _lower_train_step,
     "serve_engine_gpt2": lambda chip: _lower_serve_engine(chip, "gpt2"),
     "serve_engine_llama": lambda chip: _lower_serve_engine(chip, "llama"),
+    # GQA with four query heads a KV head, heads of 128: the decode kernel's
+    # G > 1 path at another head size (the preset above has G = 3, dh = 64)
+    "serve_engine_llama_gqa4": lambda chip: _lower_serve_engine(
+        chip, "llama", n_heads=8, n_kv_heads=2, d_model=1024),
     # the serve-gpt2-xl-chat cell: 17 rows, 512 + 368 + 16 = 896 positions
     "serve_engine_gpt2_xl_cell": lambda chip: _lower_serve_engine(
         chip, "gpt2", bucket=512, chunk=16, max_new=368, **XL),
@@ -246,6 +252,10 @@ def test_program_compiles_for_v5e(compiled, name):
         decode = programs[1]
         text = decode.as_text()
         assert not re.search(r"\bscatter\(", text)
+        # every engine's cache is whole 128-position tiles, so the cache
+        # half of decode attention reaches the chip's compiler as the
+        # ragged kernel, for G = 1 (GPT-2) and G > 1 (Llama) alike
+        assert text.count("tpu_custom_call") >= 1
         n_params = len(jax.tree.leaves(decode.args_info[0][0]))
         aliased = re.search(r"input_output_alias=\{(.*?) \}, entry", text).group(1)
         for out_index, arg in enumerate(range(n_params, n_params + 3), start=1):
@@ -253,7 +263,8 @@ def test_program_compiles_for_v5e(compiled, name):
     if name == "serve_engine_gpt2_xl_cell":
         # the layout cliff (ISSUE 28; the cell's `assumed` has the same one
         # at 784 positions): a cache the compiler re-lays-out costs 8-12 GB
-        # of temporaries in converted copies.  In place it needs 1.31 GiB.
+        # of temporaries in converted copies.  In place it needs 1.31 GiB
+        # (a slab-sized copy feeding the kernel would show here too).
         assert programs[1].memory_analysis().temp_size_in_bytes < 1.5 * 2**30
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:

@@ -1,0 +1,127 @@
+"""The ragged decode-attention kernel against the masked einsums it replaces.
+
+``generate._cache_scores`` has two forms held equal here: the Pallas kernel
+that copies in only a slot's 128-position tiles below ``n[b]``
+(``ops.attention.ragged_decode_attention``; what a program lowered for a TPU
+with a cache of whole tiles runs) and the einsums over the whole padded slab
+(``generate._cache_scores_slab``; what the CPU runs, and the plain
+reference).  The kernel runs here in the TPU interpreter.  To walk a whole
+``decode_chunk`` through it the test steers ``lax.platform_dependent`` to
+its ``tpu`` branch; the program has no option for that.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.models import generate as gen
+from test_generate import _Slots
+
+attention = importlib.import_module("ray_tpu.ops.attention")
+
+SHAPES = {  # KV heads, query heads a KV head, head size
+    "gpt2": (5, 1, 64),    # MHA, an odd number of heads
+    "llama": (2, 4, 16),   # GQA
+}
+LIVE = {
+    # (pos, active) a slot; the kernel sees n = pos where active, else 0
+    "edges": ([0, 1, 127, 128, 129, None], [True] * 6),   # None: S
+    "inactive_frozen": ([200, 77, 130, 5], [True, False, True, False]),
+    "all_inactive": ([200, 77, 130], [False] * 3),
+}
+
+
+def _f32_reference(q, k, v, layer, n):
+    """The same softmax, un-normalised, in float32 throughout."""
+    k, v = k[layer].astype(jnp.float32), v[layer].astype(jnp.float32)
+    s = jnp.einsum("bkgd,bkds->bkgs", q.astype(jnp.float32), k)
+    s = s * q.shape[-1] ** -0.5
+    mask = (jnp.arange(k.shape[-1]) < n[:, None])[:, None, None, :]
+    m = jnp.where(mask, s, -1e30).max(-1)
+    e = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
+    return jnp.einsum("bkgs,bkds->bkgd", e, v), m, e.sum(-1)
+
+
+@pytest.mark.parametrize("S", [256, 896])
+@pytest.mark.parametrize("live", list(LIVE))
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_kernel_matches_the_slab(shape, live, S):
+    KV, G, dh = SHAPES[shape]
+    pos, active = LIVE[live]
+    pos = jnp.asarray([S if p is None else p for p in pos], jnp.int32)
+    n = jnp.where(jnp.asarray(active), pos, 0)  # as decode_chunk has it
+    B, L, layer = len(active), 3, 1
+    keys = jax.random.split(jax.random.PRNGKey(S + dh), 3)
+    q = jax.random.normal(keys[0], (B, KV, G, dh), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (L, B, KV, dh, S), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (L, B, KV, dh, S), jnp.bfloat16)
+    plan = attention.ragged_decode_plan(n, S // attention.DECODE_TILE)
+    # the work list is the live tiles, slot by slot, and nothing else
+    count, ns, slot, tile = np.split(
+        np.asarray(plan), [1, 1 + B, 1 + B + B * S // 128])
+    want = [(b, t) for b in range(B) for t in range(-(-int(n[b]) // 128))]
+    assert int(count[0]) == len(want) and list(ns) == list(np.asarray(n))
+    assert list(zip(slot[:len(want)], tile[:len(want)])) == want
+
+    got = attention.ragged_decode_attention(
+        q, k, v, jnp.int32(layer), plan, interpret=pltpu.InterpretParams())
+    exact = _f32_reference(q, k, v, layer, n)
+    slab = gen._cache_scores_slab(q, k, v, jnp.int32(layer), n)
+    for name, g, e, s in zip(("acc", "m", "d"), got, exact, slab):
+        # f32 rounding of the same sums in another order ...
+        np.testing.assert_allclose(g, e, rtol=2e-5, atol=2e-5, err_msg=name)
+        # ... and the slab form, which rounds the weights to bf16 before
+        # the value product as the decode step always has
+        np.testing.assert_allclose(g, s, rtol=2e-2, atol=2e-2, err_msg=name)
+    dead = np.asarray(n) == 0
+    assert (np.asarray(got[0])[dead] == 0).all()
+    assert (np.asarray(got[2])[dead] == 0).all()
+    assert (np.asarray(got[1])[dead] == -1e30).all()
+    assert (np.asarray(slab[2])[dead] == 0).all()
+
+
+@pytest.fixture
+def lowered_for_tpu(monkeypatch):
+    """``lax.platform_dependent`` takes its ``tpu`` branch, and Pallas calls
+    run in the TPU interpreter: the decode program a chip would run, here."""
+    monkeypatch.setattr(
+        gen.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
+    """Slots on both sides of a tile boundary, a short slot, an idle slot
+    and the scratch slot, a slot admitted between chunks, over three chunks
+    of a cache of two tiles: token for token the full forward's greedy
+    answer (which ``test_generate`` holds the slab path to as well)."""
+    eng = _Slots(family, 5, 256, max_seq_len=256)
+    rng = np.random.default_rng(0)
+    eng.admit(0, [int(t) for t in rng.integers(1, 200, size=123)], 128)
+    eng.admit(2, [9, 4, 7, 2, 5], 8)   # one tile, mostly masked
+    eng.decode(8)                      # slot 0 crosses position 128
+    eng.admit(3, [int(t) for t in rng.integers(1, 200, size=128)], 128)
+    eng.decode(8)                      # slot 3 starts on the boundary
+    eng.decode(8)
+    for slot, n_new in ((0, 25), (2, 25), (3, 17)):
+        eng.assert_greedy(slot, n_new)
+    assert [int(p) for p in eng.cache["pos"]] == [147, 0, 29, 144, 128]
+
+
+def test_cache_that_is_not_whole_tiles_takes_the_slab(monkeypatch):
+    """The kernel is chosen by the cache's shape: a cache of 160 positions
+    never reaches ``platform_dependent``."""
+    def refuse(*args, **kw):
+        raise AssertionError("a 160-position cache must take the slab")
+
+    monkeypatch.setattr(gen.lax, "platform_dependent", refuse)
+    eng = _Slots("gpt2", 2, 160, max_seq_len=160)
+    eng.admit(0, [3, 17, 5], 8)
+    eng.decode(4)
+    eng.assert_greedy(0, 5)

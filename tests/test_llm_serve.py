@@ -78,6 +78,41 @@ def test_engine_eos_and_max_new():
     assert out2 == ref[:3]
 
 
+def test_cache_tiles_counts_what_the_decode_chunks_read():
+    """``perf_stats()["cache_tiles"]``: at every decode dispatch a slot
+    standing at ``n`` positions adds ``ceil(n / 128)`` tiles to ``read``
+    and the dispatch adds the whole padded slab to ``padded`` — counted on
+    the engine thread from prompt lengths and scheduled tokens, no device
+    read.  The cache itself is whole tiles."""
+    cfg = GPT2Config.tiny(dtype=jnp.float32, max_seq_len=256)
+    params = gpt2.init(cfg, jax.random.PRNGKey(2))
+    eng = GenerationEngine(  # never started: the test is the engine thread
+        cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
+        prefill_buckets=(8, 160))
+    assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
+    assert eng.perf_stats()["cache_tiles"] == {"read": 0, "padded": 0}
+    prompts = [[1 + i % 50 for i in range(127)], [3, 17, 5],
+               [1 + i % 40 for i in range(130)]]
+    futs = [eng.submit(p, 8) for p in prompts]  # 3 requests, 2 slots
+    seen = [eng.perf_stats()["cache_tiles"]]
+    while not all(f.done() for f in futs):
+        eng.step()
+        seen.append(eng.perf_stats()["cache_tiles"])
+    # a request of 8 tokens is dispatched in three chunks of 3 steps, its
+    # slot standing at len, len + 3 and len + 6 positions when they begin
+    want = sum(-(-(len(p) + 3 * chunk) // 128)
+               for p in prompts for chunk in range(3))
+    assert want == (1 + 2 + 2) + 3 + 6
+    last = seen[-1]
+    assert last["read"] == want and 0 < last["read"] <= last["padded"]
+    dispatches = last["padded"] // (3 * 2)  # 3 rows (one scratch) x 2 tiles
+    assert last["padded"] == dispatches * 6 and dispatches >= 6
+    for a, b in zip(seen, seen[1:]):
+        assert a["read"] <= b["read"] and a["padded"] <= b["padded"]
+    for p, f in zip(prompts, futs):
+        assert f.result() == _one_shot(params, cfg, p, 8)
+
+
 def test_llm_deployment_behind_serve(serve_instance):
     dep = llm_deployment(
         "gpt2", "tiny",
