@@ -63,13 +63,14 @@ the slice update clamps and lands on the slot's own dead columns.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import gpt2, llama
+from ray_tpu.models import exaone_moe, gpt2, llama
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.attention import (
     DECODE_TILE,
@@ -79,8 +80,14 @@ from ray_tpu.ops.attention import (
 
 # The one table that knows the families: name -> the family's module.  A
 # module here names its config class (``Config``) and presets (``SIZES``)
-# and has ``init``, ``kv_heads``, ``embed``, ``block`` and ``unembed``.
-FAMILIES = {"gpt2": gpt2, "llama": llama}
+# and has ``init``, ``kv_heads``, ``embed``, ``block`` and ``unembed``.  A
+# family whose layers are all alike keeps their parameters stacked
+# (``params["blocks"]``, leaves ``[L, ...]``) and the layer loops below run
+# them rolled; one that mixes kinds of layer lists them (``params["layers"]``),
+# its config says which layers attend a window (``sliding_windows``), its
+# ``block`` takes the layer's ``window`` and the ``valid`` tokens, and the
+# loops run unrolled, each kind of layer against its own kind of cache.
+FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe}
 
 
 def family_of(cfg):
@@ -95,27 +102,57 @@ def kv_heads(cfg) -> int:
     return family_of(cfg).kv_heads(cfg)
 
 
+def layer_windows(cfg) -> Tuple[int, ...]:
+    """Per layer, the positions it attends: 0 is every one (a full layer), a
+    window size ``W`` the last ``W`` (a window layer; ``cfg.sliding_windows``
+    of a family that mixes them, with one size)."""
+    windows = tuple(getattr(cfg, "sliding_windows", ()) or (0,) * cfg.n_layers)
+    assert len(windows) == cfg.n_layers and len(set(windows) - {0}) <= 1, windows
+    return windows
+
+
+def ring_positions(window: int) -> int:
+    """Positions a slot's ring holds for a window layer: twice the window
+    (two 128-tiles at the published 128).  A decode chunk needs ``steps <=
+    window + 1``: the flush writes all ``steps`` columns, those of a slot
+    that stopped mid-chunk too, and what they overwrite has to lie outside
+    every window still to come."""
+    return 2 * window
+
+
 def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
-    """Fixed-size KV cache: k/v ``[L, B, KV, dh, S]`` (positions LAST, so the
-    scores of a decode step come out with S on the lanes and the chip
-    stores the cache unpadded) plus per-slot ``pos``."""
-    shape = (cfg.n_layers, n_slots, kv_heads(cfg), cfg.head_dim, max_len)
-    return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-    }
+    """Fixed-size KV cache, by kind of layer, plus per-slot ``pos``.  The
+    full layers: ``k``/``v`` ``[L_full, B, KV, dh, S]`` (positions LAST, so
+    the scores of a decode step come out with S on the lanes and the chip
+    stores the cache unpadded), every position kept.  The window layers, for
+    a family that has them: ``k_ring``/``v_ring`` ``[L_window, B, KV, dh,
+    R]``, a ring: position ``j`` lives at ``j % R`` (``ring_positions``), so
+    a slot costs ``R`` positions however long its context."""
+    windows = layer_windows(cfg)
+    slab = lambda layers, length: jnp.zeros(  # noqa: E731
+        (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
+    n_full = windows.count(0)
+    cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
+             "pos": jnp.zeros((n_slots,), jnp.int32)}
+    if n_full < len(windows):
+        ring = ring_positions(max(windows))
+        cache.update(k_ring=slab(len(windows) - n_full, ring),
+                     v_ring=slab(len(windows) - n_full, ring))
+    return cache
 
 
-def _cache_scores_slab(q, k_all, v_all, l, n):
+def _cache_scores_slab(q, k_all, v_all, l, mask):
     """The cache half of :func:`_decode_attend` as masked einsums over layer
-    ``l``'s whole padded slab ``[B, KV, dh, S]``: what every platform can
-    run, and the plain reference the kernel is held to.  Same result as
-    :func:`ray_tpu.ops.attention.ragged_decode_attention`."""
+    ``l``'s whole padded slab ``[B, KV, dh, S]``, slot ``b`` attending the
+    positions where ``mask [B, S]`` holds: what every platform can run, the
+    plain reference the kernel is held to (``mask``: the positions below
+    ``n[b]``; same result as
+    :func:`ray_tpu.ops.attention.ragged_decode_attention`), and how a window
+    layer's ring is read (:func:`_ring_mask`)."""
     k, v = (lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
             for a in (k_all, v_all))
-    S, dh = k.shape[3], k.shape[2]
-    mask = jnp.arange(S)[None, None, None, :] < n[:, None, None, None]
+    dh = k.shape[2]
+    mask = mask[:, None, None, :]
     # keep the cache reads in bf16 (f32 accumulation via
     # preferred_element_type) — upcasting the whole cache each step
     # would double the dominant HBM traffic of decode
@@ -136,31 +173,55 @@ def _cache_scores(q, k_all, v_all, l, n, plan):
     tiles, the Pallas kernel that copies in only the tiles below ``n[b]``;
     anywhere else the masked einsums over the slab.  Decided by what the
     program is lowered for and by the cache's shape, never by a flag."""
+    below = lambda n: jnp.arange(k_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
     if plan is None:
-        return _cache_scores_slab(q, k_all, v_all, l, n)
+        return _cache_scores_slab(q, k_all, v_all, l, below(n))
     return lax.platform_dependent(
         q, k_all, v_all, l, n, plan,
         tpu=lambda q, k, v, l, n, plan: ragged_decode_attention(
             q, k, v, l, plan),
-        default=lambda q, k, v, l, n, plan: _cache_scores_slab(q, k, v, l, n))
+        default=lambda q, k, v, l, n, plan: _cache_scores_slab(
+            q, k, v, l, below(n)))
 
 
-def _decode_attend(q, k_all, v_all, l, k_new, v_new, n, plan, i) -> jax.Array:
+def _ring_holds(n, ring: int) -> jax.Array:
+    """``[B, ring]``: the position entry ``r`` of slot ``b``'s ring holds once
+    positions ``j < n[b]`` are written, the newest with ``j % ring == r``;
+    negative where no position has filled the entry yet."""
+    last = n.astype(jnp.int32)[:, None] - 1
+    return last - (last - jnp.arange(ring)[None, :]) % ring
+
+
+def _ring_mask(live, pos, window: int, ring: int) -> jax.Array:
+    """``[B, ring]``: the ring entries a window layer's query at position
+    ``pos[b]`` attends: those that hold a position (``live``: where the slot
+    stood when the chunk began, all of it flushed; 0 for a slot that sits the
+    chunk out, which attends nothing here) inside the window, ``j > pos -
+    window``."""
+    j = _ring_holds(live, ring)
+    return (j >= 0) & (j > pos[:, None] - window)
+
+
+def _decode_attend(q, cached, k_new, v_new, i, window: int = 0) -> jax.Array:
     """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
-    layer ``l`` of the caches ``[L, B, KV, dh, S]`` at positions ``j < n``
-    (where the slot stood when the chunk began; 0 for a slot that was
-    inactive then) and the chunk's own columns ``[steps, B, KV, dh]`` at
-    ``t <= i``.  ONE softmax over both score sets: the cache half arrives
-    un-normalised (:func:`_cache_scores`) and is merged with the chunk's
-    under the shared max and denominator.  GQA folds the query heads onto
-    their KV head by reshape (no materialized repeat)."""
+    what its cache holds from before the chunk, and the chunk's own columns
+    ``[steps, B, KV, dh]`` at ``t <= i`` (of a window layer: ``t > i -
+    window`` too).  ``cached(q)`` gives the cache half for ``q [B, KV, G,
+    dh]``, un-normalised (:func:`_cache_scores` over the positions ``j < n``
+    of a full layer, ``n`` where the slot stood when the chunk began and 0
+    for a slot that was inactive then; :func:`_cache_scores_slab` over a
+    window layer's ring).  ONE softmax over both score sets: the halves are
+    merged under the shared max and denominator.  GQA folds the query heads
+    onto their KV head by reshape (no materialized repeat)."""
     B, H, _, dh = q.shape
     KV, steps = k_new.shape[2], k_new.shape[0]
     q = q.reshape(B, KV, H // KV, dh)
-    acc_old, m_old, d_old = _cache_scores(q, k_all, v_all, l, n, plan)
+    acc_old, m_old, d_old = cached(q)
     s_new = jnp.einsum("bkgd,tbkd->bkgt", q, k_new.astype(q.dtype),
                        preferred_element_type=jnp.float32) / (dh ** 0.5)
-    s_new = jnp.where(jnp.arange(steps) <= i, s_new, -1e30)
+    t = jnp.arange(steps)
+    s_new = jnp.where((t <= i) & (t > i - window) if window else t <= i,
+                      s_new, -1e30)
     # column t = 0 is never masked, so the max is a real score
     m = jnp.maximum(m_old, s_new.max(-1))
     w_old, e_new = jnp.exp(m_old - m), jnp.exp(s_new - m[..., None])
@@ -171,36 +232,70 @@ def _decode_attend(q, k_all, v_all, l, k_new, v_new, n, plan, i) -> jax.Array:
     return out.reshape(B, H, 1, dh)
 
 
+def _ring_of(t, lengths, ring: int):
+    """A window layer's prompt keys or values ``[L, B, KV, Tp, dh]`` -> what
+    its ring holds of them, ``[L, B, KV, dh, ring]`` (:func:`_ring_holds`;
+    entries no position fills yet hold position 0's, and nothing reads
+    them)."""
+    j = jnp.maximum(_ring_holds(lengths, ring), 0)
+    kept = jnp.take_along_axis(t, j[None, :, None, :, None], axis=3)
+    return jnp.swapaxes(kept, 3, 4)
+
+
 def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                cache: Dict[str, jax.Array], slots: jax.Array) -> Tuple[jax.Array, Dict]:
     """Run the prompts ``tokens [B, Tp]`` (right-padded; true lengths
     ``lengths [B]``) and write K/V into cache slots ``slots [B]`` (any
     subset — one compiled program admits a whole batch of requests).  Returns
     ``(last_logits [B, V], cache)``.  Positions are 0..Tp-1, so a slot must
-    be prefilled from scratch (pos resets to ``lengths``)."""
+    be prefilled from scratch (pos resets to ``lengths``).  A full layer
+    keeps every position of the prompt, a window layer the last ``ring`` of
+    each row (:func:`_ring_of`).  Where the family's layers count what they
+    routed, the dispatch's counts come back as ``cache["routed"]`` (leaves
+    stacked over the layers that route)."""
     fam = family_of(cfg)
     B, Tp = tokens.shape
     positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
-    def attend(q, k, v):
-        # the full causal attention of training, this layer's k, v kept
-        return _attend(q, k, v, causal=True, mesh=None)[0], (k, v)
+    def attend(q, k, v, window=0):
+        # the causal (or band) attention of training, this layer's k, v kept
+        return _attend(q, k, v, causal=True, mesh=None, window=window)[0], (k, v)
 
-    def body(h, p):
-        h, _, kv = fam.block(h, p, cfg, attend, positions)
-        return h, kv
+    routed = []
+    if "blocks" in params:  # layers alike, stacked: one rolled loop
+        def body(h, p):
+            h, _, kv = fam.block(h, p, cfg, attend, positions)
+            return h, kv
 
-    x, (ks, vs) = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
+        x, full = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
+        ringed = ()
+    else:  # kinds of layer mixed, listed: unrolled, each kind's k, v apart
+        valid = positions[None, :] < lengths[:, None]
+        kept = {}
+        for p, w in zip(params["layers"], layer_windows(cfg)):
+            x, counts, kv = fam.block(x, p, cfg, partial(attend, window=w),
+                                      positions, window=w, valid=valid)
+            kept.setdefault(bool(w), []).append(kv)
+            routed += [] if counts is None else [counts]
+        full, ringed = (
+            tuple(jnp.stack(t) for t in zip(*kept.get(kind, ())))
+            for kind in (False, True))
+    out = {**cache, "pos": cache["pos"].at[slots].set(lengths.astype(jnp.int32))}
     # single advanced index keeps its axis position: one scatter per tensor
     # (over whole slots, once a prompt; decode never scatters)
     to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
         jnp.swapaxes(t, 3, 4).astype(c.dtype))
-    cache_k, cache_v = to_cache(ks, cache["k"]), to_cache(vs, cache["v"])
-    pos = cache["pos"].at[slots].set(lengths.astype(jnp.int32))
+    for t, name in zip(full, "kv"):
+        out[name] = to_cache(t, cache[name])
+    for t, name in zip(ringed, ("k_ring", "v_ring")):
+        out[name] = cache[name].at[:, slots].set(_ring_of(
+            t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
+    if routed:
+        out["routed"] = jax.tree.map(lambda *a: jnp.stack(a), *routed)
     last = fam.unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
-    return last[:, 0, :], {"k": cache_k, "v": cache_v, "pos": pos}
+    return last[:, 0, :], out
 
 
 def prefill(params, cfg, tokens: jax.Array, lengths: jax.Array,
@@ -244,12 +339,14 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     fam = family_of(cfg)
     B = tokens.shape[0]
     S = cache["k"].shape[-1]
+    windows = layer_windows(cfg)
+    window, ring = max(windows), cache.get("k_ring", cache["k"]).shape[-1]
     # a dynamic_update_slice clamps silently: the flush of a slot at pos0
-    # needs pos0 + steps <= S (the engine's bucket + max_new + chunk)
-    assert steps <= S, (steps, S)
+    # needs pos0 + steps <= S (the engine's bucket + max_new + chunk); a
+    # ring's, steps <= window + 1 (ring_positions)
+    assert steps <= S and (not window or steps <= window + 1), (steps, S, window)
     if steps == 0:
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
-    blocks = params["blocks"]
     k_old, v_old, pos0 = cache["k"], cache["v"], cache["pos"]
     # what a slot attends of the cache, fixed for the chunk: the positions
     # below where it stood, nothing for a slot that sits the chunk out
@@ -266,35 +363,66 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
         x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
 
-        def layer(l, carry):
+        def layer(l, w, at, block, carry):
+            """Layer ``l``, the ``at``-th of its kind (``w``: its window, 0
+            a full layer), run as ``block(x, attend)``."""
             x, k_loc, v_loc = carry
             at_l = lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
+            def cached(q):
+                if not w:
+                    return _cache_scores(q, k_old, v_old, at, live, plan)
+                return _cache_scores_slab(
+                    q, cache["k_ring"], cache["v_ring"], at,
+                    _ring_mask(live, pos, w, ring))
 
             def attend(q, k, v):  # [B, heads, 1, dh]
                 put = lambda buf, t: lax.dynamic_update_slice(
                     buf, t[None, None, :, :, 0, :].astype(buf.dtype),
                     (l, i, 0, 0, 0))
                 k_new, v_new = put(k_loc, k), put(v_loc, v)
-                out = _decode_attend(q, k_old, v_old, l, at_l(k_new),
-                                     at_l(v_new), live, plan, i)
+                out = _decode_attend(q, cached, at_l(k_new), at_l(v_new), i, w)
                 return out.astype(cfg.dtype), (k_new, v_new)
 
-            x, _, (k_loc, v_loc) = fam.block(
-                x, jax.tree.map(at_l, blocks), cfg, attend, positions)
-            return x, k_loc, v_loc
+            x, counts, (k_loc, v_loc) = block(x, attend)
+            return (x, k_loc, v_loc), counts
 
-        x, k_loc, v_loc = lax.fori_loop(
-            0, cfg.n_layers, layer, (x, k_loc, v_loc))
+        counted = []  # what the layers that route counted, this step
+        if "blocks" in params:  # layers alike, stacked: one rolled loop
+            def rolled(l, carry):
+                p = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                    a, l, 0, keepdims=False), params["blocks"])
+                return layer(l, 0, l, lambda x, attend: fam.block(
+                    x, p, cfg, attend, positions), carry)[0]
+
+            x, k_loc, v_loc = lax.fori_loop(
+                0, cfg.n_layers, rolled, (x, k_loc, v_loc))
+        else:  # kinds of layer mixed, listed: unrolled, each kind's cache
+            state, seen = (x, k_loc, v_loc), {}
+            for l, (p, w) in enumerate(zip(params["layers"], windows)):
+                at = seen[bool(w)] = seen.get(bool(w), -1) + 1
+                state, counts = layer(l, w, at, lambda x, attend, p=p, w=w: fam.block(
+                    x, p, cfg, attend, positions, window=w, valid=act[:, None]),
+                    state)
+                counted += [] if counts is None else [counts]
+            x, k_loc, v_loc = state
         logits = fam.unembed(params, x, cfg)[:, 0, :]
         nxt = sample_logits(logits, sub, temperature=temperature, top_k=top_k)
         nxt = jnp.where(act, nxt, toks)
         pos = pos + act.astype(jnp.int32)
         if eos_id is not None:
             act = act & (nxt != eos_id)
-        return (k_loc, v_loc, pos, nxt, act, rng), nxt
+        # leaves stacked over the layers that route (None: nothing counted)
+        counted = jax.tree.map(lambda *a: jnp.stack(a), *counted) if counted else None
+        return (k_loc, v_loc, pos, nxt, act, rng), (nxt, counted)
 
-    (k_loc, v_loc, pos, _, active, key), emitted = lax.scan(
+    (k_loc, v_loc, pos, _, active, key), (emitted, routed) = lax.scan(
         step, (local, local, pos0, tokens, active, key), jnp.arange(steps))
+
+    # the chunk's columns of the layers of one kind (a family of one kind: all)
+    of = lambda loc, kind: loc if not window else loc[  # noqa: E731
+        jnp.asarray([l for l, w in enumerate(windows) if bool(w) == kind])]
+    k_new, v_new = of(k_loc, False), of(v_loc, False)
 
     def flush(b, kv):
         # slot b's columns of the chunk, as [L, 1, KV, dh, steps], to pos0[b]
@@ -303,10 +431,24 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
             (0, 2, 3, 1))[:, None]
         return tuple(
             lax.dynamic_update_slice(big, col(loc), (0, b, 0, 0, pos0[b]))
-            for big, loc in zip(kv, (k_loc, v_loc)))
+            for big, loc in zip(kv, (k_new, v_new)))
 
-    k_all, v_all = lax.fori_loop(0, B, flush, (k_old, v_old))
-    return emitted.T, {"k": k_all, "v": v_all, "pos": pos}, active, key
+    out = {**cache, "pos": pos}
+    out["k"], out["v"] = lax.fori_loop(0, B, flush, (k_old, v_old))
+    if window:
+        # the rings, once a chunk and whole: column t of slot b goes to entry
+        # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other
+        # entry stays; no scatter, and a wrap is nothing special
+        hit = ((pos0[:, None, None] + jnp.arange(steps)[None, :, None]) % ring
+               == jnp.arange(ring)[None, None, :])          # [B, steps, ring]
+        for name, loc in (("k_ring", k_loc), ("v_ring", v_loc)):
+            new = jnp.einsum("ltbkd,btr->lbkdr", of(loc, True),
+                             hit.astype(loc.dtype))
+            out[name] = jnp.where(hit.any(1)[None, :, None, None, :],
+                                  new, cache[name])
+    if routed is not None:  # the chunk's routing counts: summed over its steps
+        out["routed"] = jax.tree.map(lambda a: a.sum(0), routed)
+    return emitted.T, out, active, key
 
 
 def generate(params, cfg, prompts: jax.Array, lengths: jax.Array, *,

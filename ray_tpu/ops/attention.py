@@ -665,11 +665,14 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
     scale: Optional[float] = None, block_q: int = 128, block_k: int = 128,
+    window: int = 0,
 ) -> jax.Array:
     """Dispatch to an implementation by shape (module docstring: none of
     the thresholds has a cell on either side).  Single entry point used by
     the model zoo.
 
+    - a window layer (``window > 0``: causal, and position ``i`` attends
+      ``i - window < j <= i``) → :func:`band_attention`
     - causal, square, block-divisible, moderate T → :func:`causal_skip_attention`
     - moderate T → :func:`full_attention` (masked, MXU dtypes)
     - T ≥ 8k on TPU, block-divisible → :func:`flash_attention_tpu`
@@ -678,6 +681,9 @@ def attention(
       pads+masks any length; ring attention covers sharded-T)
     """
     t_q, t_k = q.shape[-2], k.shape[-2]
+    if window:
+        assert causal and t_q == t_k, (causal, t_q, t_k)
+        return band_attention(q, k, v, window=window, scale=scale)
     if t_q <= _MAX_MATERIALIZED_T and t_k <= _MAX_MATERIALIZED_T:
         if causal and t_q == t_k and t_q % 256 == 0 and t_q >= 512:
             return causal_skip_attention(q, k, v, scale=scale, block=256)
@@ -766,6 +772,41 @@ def causal_skip_attention(
         s = jnp.where(mask, _scores(qi, ki, scale), NEG_INF)
         outs.append(_weighted_values(jax.nn.softmax(s, axis=-1), vi))
     return jnp.concatenate(outs, axis=-2)
+
+
+def band_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """A window layer's attention over a whole sequence: position ``i``
+    attends ``i - window < j <= i``.  Where the sequence is whole blocks of
+    ``ceil(window / 128) * 128`` positions and more than one, each block of
+    queries contracts only its own block of keys and the one before it (the
+    band lies inside them): ``2 * block`` keys a query whatever the length,
+    where the masked full scores are ``T``.  Else (a short prompt bucket, a
+    test's odd length) the band is a mask on the materialized scores."""
+    *lead, t, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    block = -(-window // 128) * 128
+    if t % block or t == block:
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        s = jnp.where((j <= i) & (j > i - window), _scores(q, k, scale), NEG_INF)
+        return _weighted_values(jax.nn.softmax(s, axis=-1), v)
+    n = t // block
+    blocks = lambda a: a.reshape(*lead, n, block, d)  # noqa: E731
+    # block b's keys: block b - 1 (zeros before the first, masked) then b
+    before = lambda a: jnp.concatenate(  # noqa: E731
+        [jnp.zeros_like(a[..., :1, :, :]), a[..., :-1, :, :]], axis=-3)
+    kb, vb = blocks(k), blocks(v)
+    k2 = jnp.concatenate([before(kb), kb], axis=-2)  # [.., n, 2 * block, d]
+    v2 = jnp.concatenate([before(vb), vb], axis=-2)
+    i = block + jnp.arange(block)[:, None]           # a query's place in k2
+    j = jnp.arange(2 * block)[None, :]
+    first = (jnp.arange(n) == 0)[:, None, None]      # no block before block 0
+    mask = (j <= i) & (j > i - window) & ~(first & (j < block))
+    s = jnp.where(mask, _scores(blocks(q), k2, scale), NEG_INF)
+    out = _weighted_values(jax.nn.softmax(s, axis=-1), v2)
+    return out.reshape(*lead, t, d)
 
 
 # Above this, materialized scores risk HBM pressure; the O(block) blockwise

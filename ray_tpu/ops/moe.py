@@ -142,3 +142,115 @@ def moe_logical_axes() -> Dict[str, Tuple]:
         "ew2": ("layers", "expert", "mlp", "embed"),
         "eb2": ("layers", "expert", "embed"),
     }
+
+
+# -- top-k routing without drops, over the experts THIS chip holds ------------
+#
+# The second expert layer of this file (the Switch layer above keeps the
+# LayerNorm block's training path).  What a wide expert-parallel deployment
+# asks of one chip: the router scores ALL experts, a token keeps its
+# ``top_k``, and the chip adds up what the experts it holds (``first_expert
+# .. first_expert + n_held``) give for the tokens routed to them.  What the
+# absent experts would add is left out: on one chip the layer runs without
+# its exchange, and nothing stands in for the other chips.
+
+# up to this many (token, expert) pairs go through the grouped matmuls as one
+# block (a decode step, a short prompt); of more, a quarter at a time: an
+# eighth of the pairs are held where eight chips share a layer, so one trip
+# as a rule, and a skewed router costs trips, never tokens
+_ONE_BLOCK_PAIRS = 4096
+
+
+def route_sigmoid_top_k(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+                        top_k: int, scale: float):
+    """``x [N, D]`` -> ``(experts [N, k] int32, gates [N, k] float32)``.
+    DeepSeek-V3-style routing: ``s = sigmoid(x W_r)`` over every expert in
+    float32 (at matmul precision "highest": a bf16 pass over the scores moves
+    the k-th place), the ``k`` largest of ``s + bias`` chosen, and the CHOSEN
+    experts' own scores (without the bias) renormalised over all ``k`` and
+    scaled."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, experts, axis=-1)
+    return (experts.astype(jnp.int32),
+            scale * chosen / chosen.sum(-1, keepdims=True))
+
+
+def held_experts_ffn(
+    x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate: jax.Array,
+    w_up: jax.Array, w_down: jax.Array, *, first_expert: int = 0,
+    valid: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a top-k SwiGLU expert layer, no token dropped.
+
+    Args:
+        x: ``[N, D]`` tokens (compute dtype).
+        experts, gates: ``[N, k]``, each token's chosen experts (numbered over
+            ALL experts) and their weights (:func:`route_sigmoid_top_k`).
+        w_gate, w_up: ``[n_held, D, F]``; w_down: ``[n_held, F, D]``: experts
+            ``first_expert .. first_expert + n_held``, without biases.
+        valid: ``[N]`` bool, the real tokens.  Padding and the rows of slots
+            that sit a step out are routed nowhere: they cost no expert
+            matmul and no expert weight read, and their part of ``y`` is 0.
+
+    The ``N * k`` (token, expert) pairs are sorted by held expert (pairs of
+    absent experts last) and the held ones go through grouped matmuls
+    (``lax.ragged_dot``: on a TPU one kernel over the rows of each group, no
+    capacity, and a group without rows costs nothing, not even the read of
+    its weights).  One block of pairs (``_ONE_BLOCK_PAIRS``) takes one trip
+    and a token's ``k`` rows are gathered back and summed; more pairs take a
+    quarter a trip, as many trips as the held pairs need, each added into the
+    result where its rows' tokens are.
+
+    Returns ``(y [N, D] float32, tokens [n_held] int32)``: the weighted sum,
+    and the valid tokens routed to each held expert.
+    """
+    N, D = x.shape
+    n_held, top_k = w_gate.shape[0], experts.shape[1]
+    M = N * top_k
+    local = experts.reshape(M) - first_expert
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held = held & jnp.repeat(valid, top_k)
+    key = jnp.where(held, local, n_held)
+    order = jnp.argsort(key)                      # held pairs first, by expert
+    # where each held expert's rows begin in the sorted list
+    bounds = jnp.searchsorted(
+        key[order], jnp.arange(n_held + 1)).astype(jnp.int32)
+    tokens = bounds[1:] - bounds[:-1]
+    gate_of = jnp.where(held, gates.reshape(M), 0.0)
+
+    def experts_of(pairs, sizes):
+        rows = x[pairs // top_k]
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate.astype(x.dtype), sizes))
+             * jax.lax.ragged_dot(rows, w_up.astype(x.dtype), sizes))
+        return jax.lax.ragged_dot(h, w_down.astype(x.dtype), sizes,
+                                  preferred_element_type=jnp.float32)
+
+    if M <= _ONE_BLOCK_PAIRS:
+        y = experts_of(order, tokens)             # [M, D], sorted by expert
+        # pair (n, j) sits at row rank[n * k + j]; a row past the held pairs
+        # holds nothing meant to be read, and its gate is 0
+        rank = jnp.argsort(order).reshape(N, top_k)
+        y = jnp.where(held.reshape(N, top_k, 1), y[rank], 0.0)
+        return (y * gate_of.reshape(N, top_k, 1)).sum(1), tokens
+
+    block = -(-M // 4)
+    order = jnp.concatenate([order, jnp.zeros((4 * block - M,), order.dtype)])
+
+    def trip(i, out):
+        lo = i * block
+        pairs = jax.lax.dynamic_slice(order, (lo,), (block,))
+        live = lo + jnp.arange(block) < bounds[n_held]
+        sizes = (jnp.clip(bounds[1:], lo, lo + block)
+                 - jnp.clip(bounds[:-1], lo, lo + block))
+        y = jnp.where(live[:, None],
+                      experts_of(pairs, sizes) * gate_of[pairs][:, None], 0.0)
+        # rows past the held pairs go nowhere
+        return out.at[jnp.where(live, pairs // top_k, N)].add(y, mode="drop")
+
+    trips = -(-bounds[n_held] // block)
+    return jax.lax.fori_loop(
+        0, trips, trip, jnp.zeros((N, D), jnp.float32)), tokens

@@ -247,13 +247,16 @@ class _PendingChunk:
     (tokens already streaming host-ward via ``copy_to_host_async``) plus
     the host bookkeeping needed to route them when they land."""
 
-    __slots__ = ("chunk_dev", "rows", "admissions", "firsts_dev")
+    __slots__ = ("chunk_dev", "rows", "admissions", "firsts_dev", "routed_dev")
 
-    def __init__(self, chunk_dev, rows, admissions, firsts_dev):
+    def __init__(self, chunk_dev, rows, admissions, firsts_dev, routed_dev):
         self.chunk_dev = chunk_dev          # [n_slots+1, steps] device
         self.rows = rows                    # [(slot, _Request)] active in chunk
         self.admissions = admissions        # [(row_j, slot, _Request)] this iter
-        self.firsts_dev = firsts_dev        # [n_slots] device or None
+        self.firsts_dev = firsts_dev        # [rows] device or None
+        # (prefill's, chunk's) routing counts of this iteration (device; None
+        # where nothing was counted): they land with the tokens
+        self.routed_dev = routed_dev
 
 
 class GenerationEngine:
@@ -273,6 +276,7 @@ class GenerationEngine:
         max_new_tokens: int = 128,
         decode_chunk_steps: int = 16,
         prefill_buckets: tuple = (32, 64, 128, 256),
+        prefill_token_budget: Optional[int] = None,
         temperature: float = 0.0,
         top_k: int = 0,
         eos_id: Optional[int] = None,
@@ -296,7 +300,9 @@ class GenerationEngine:
         # inference-only params: pre-cast master f32 weights to the compute
         # dtype ONCE — the per-step .astype inside the blocks otherwise
         # re-reads the f32 copy every decode step (2x the HBM traffic of
-        # the weights, which is the whole cost of a decode step)
+        # the weights, which is the whole cost of a decode step).  A family
+        # whose init makes its leaves in cfg.dtype, a layer at a time, never
+        # has masters (exaone_moe: they would not fit the chip)
         import jax.numpy as jnp
 
         self.params = jax.tree.map(
@@ -307,6 +313,14 @@ class GenerationEngine:
         self.max_new_tokens = max_new_tokens
         self.chunk = decode_chunk_steps
         self.buckets = tuple(sorted(prefill_buckets))
+        # rows a prefill of each bucket is wide: as many as fit a budget of
+        # tokens a call, at most the slots, at least one.  One compiled
+        # program a bucket still.  The default budget (every slot at the
+        # largest bucket) makes every bucket n_slots wide; an engine whose
+        # largest prompts are thousands of tokens sets one (32 rows of 4,096
+        # would be 131 k tokens a call)
+        budget = prefill_token_budget or n_slots * self.buckets[-1]
+        self._rows = {b: max(1, min(n_slots, budget // b)) for b in self.buckets}
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -318,7 +332,23 @@ class GenerationEngine:
         from ray_tpu.ops.attention import DECODE_TILE
 
         self._tile = DECODE_TILE
-        self._cache_tiles = {"read": 0, "padded": 0}
+        windows = gen.layer_windows(cfg)
+        self._layers = {"full": windows.count(0),
+                        "window": len(windows) - windows.count(0)}
+        # per layer of the kind: a full layer reads a slot's live tiles, a
+        # window layer every row's whole ring (two tiles at the published
+        # window), whatever the context
+        self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
+        self._cache_tiles = {"read_full": 0, "read_window": 0, "padded": 0}
+        # cumulative, by prefill bucket: calls, the rows and tokens they were
+        # wide, and the prompts and prompt tokens among those
+        self._prefill = {b: {"calls": 0, "rows": 0, "padded_tokens": 0,
+                             "prompts": 0, "live_tokens": 0}
+                         for b in self.buckets}
+        # cumulative routing counts of the dispatches drained so far, where
+        # the family's layers count (leaves as the programs return them:
+        # stacked over the layers that route); None until a first arrives
+        self._routed = {"prefill": None, "decode": None, "decode_steps": 0}
         # one extra SCRATCH slot (index n_slots): batched admission pads
         # the prefill batch to a bucketed size and parks the padding rows
         # there, so admitting 1..n_slots requests costs ONE device dispatch
@@ -488,11 +518,15 @@ class GenerationEngine:
         ``excess_billed_to_prefill`` is the share of all decode-tick
         time above the decode-only baseline that co-scheduled prefill
         chunks account for."""
+        import jax
+
         with self._lock:  # snapshot: the engine thread appends under
             # the same lock, so sorted() never sees a mutating deque
             ttft = list(self._ttft_samples)
             itl = list(self._itl_samples)
-            cache_tiles = dict(self._cache_tiles)
+            cache_tiles = {**self._cache_tiles, "layers": dict(self._layers)}
+            prefill = {str(b): dict(v) for b, v in self._prefill.items()}
+            moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
             stages = tracing.span_stats(STAGES)
@@ -505,9 +539,20 @@ class GenerationEngine:
             # process closed them (cumulative: difference two calls for a
             # window); empty with the observability layer off
             "stages": stages,
-            # how much of the padded cache the decode steps read (cumulative;
-            # counted at dispatch from prompt lengths and scheduled tokens)
+            # how much of the padded cache the decode steps read, in tiles a
+            # layer of each kind (cumulative; counted at dispatch from prompt
+            # lengths and scheduled tokens), and how many layers of each kind
             "cache_tiles": cache_tiles,
+            # what the prefill calls were wide and what of it was prompt
+            # (cumulative, by bucket)
+            "prefill": prefill,
+            # a family with expert layers: what its layers counted of the
+            # routing in the dispatches drained so far, prefills and decode
+            # chunks apart (``tokens [layer][held expert]``, ``touched
+            # [layer]``: held experts with any token, summed over calls and
+            # steps; ``decode_steps``: the chunk steps among them).
+            # Cumulative, like cache_tiles
+            "moe": moe,
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -557,27 +602,41 @@ class GenerationEngine:
         return self.buckets[-1]
 
     def _admit(self):
-        """Prefill queued prompts into ALL free slots with one device call
-        (batch padded to a fixed n_slots width; padding rows target the
-        scratch slot).  Returns ``(admissions, firsts_dev)`` — the sampled
-        first tokens stay ON DEVICE (merged into the last-token row there);
-        their values reach the host with the next chunk drain."""
+        """Prefill queued prompts into free slots with one device call: the
+        queue's head, in order, for as long as the next request still fits
+        the rows its bucket allows (``_rows``: the widest prompt taken
+        decides the bucket) and a slot is free; the batch is padded to that
+        fixed width, padding rows target the scratch slot.  Returns
+        ``(admissions, firsts_dev, routed_dev)`` — the sampled first tokens
+        stay ON DEVICE (merged into the last-token row there); their values,
+        and the prefill's routing counts, reach the host with the next chunk
+        drain."""
         import jax
         import jax.numpy as jnp
 
         with self._lock:
             free = [i for i, s in enumerate(self._slots) if s is None]
-            take = min(len(free), len(self._queue))
+            take = b = 0
+            while take < min(len(free), len(self._queue)):
+                wider = max(b, self._bucket(len(self._queue[take].tokens)))
+                if take + 1 > self._rows[wider]:
+                    break
+                take, b = take + 1, wider
             if take == 0:
-                return [], None
+                return [], None, None
             batch = [(free[j], self._queue.pop(0)) for j in range(take)]
             for slot, req in batch:
                 self._slots[slot] = req
-        b = self._bucket(max(len(r.tokens) for _, r in batch))
-        # fixed admission width = n_slots: ONE compiled prefill program per
+            tally = self._prefill[b]
+            tally["calls"] += 1
+            tally["rows"] += self._rows[b]
+            tally["padded_tokens"] += self._rows[b] * b
+            tally["prompts"] += take
+            tally["live_tokens"] += sum(len(r.tokens) for _, r in batch)
+        # a fixed admission width a bucket: ONE compiled prefill program per
         # prompt bucket (variable widths recompiled mid-serving, which cost
         # far more than the padded rows' wasted FLOPs)
-        n = self.n_slots
+        n = self._rows[b]
         toks = np.zeros((n, b), np.int32)
         toks[:, 0] = 1  # padding rows: 1-token dummy prompt
         lens = np.ones((n,), np.int32)
@@ -593,7 +652,7 @@ class GenerationEngine:
                 tracing.emit_stage(
                     "engine.queue", t_dispatch - req.submitted_at,
                     req.trace_ctx)
-        last_logits, self.cache = self._prefill_jit(
+        last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
             self.cache, jnp.asarray(slots))
         self._key, sub = jax.random.split(self._key)
@@ -601,6 +660,7 @@ class GenerationEngine:
         self._last_tok_dev = self._merge_jit(
             self._last_tok_dev, jnp.asarray(slots), firsts_dev)
         firsts_dev.copy_to_host_async()
+        _to_host_async(routed_dev)
         for _, req in batch:
             req.scheduled = 1  # the prefill's sampled first token
         if _events.ENABLED:
@@ -620,7 +680,7 @@ class GenerationEngine:
                 span_dur=worst, slots=[s for s, _ in batch],
                 bucket=b, queued_left=queued_left)
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
-        return admissions, firsts_dev
+        return admissions, firsts_dev, routed_dev
 
     def step(self) -> bool:
         """One engine iteration, software-pipelined against the device:
@@ -643,7 +703,7 @@ class GenerationEngine:
         meter = self._ticks if _events.ENABLED else None
         t_tick0 = time.perf_counter() if meter else 0.0
         with self._annotate("engine.admit"):
-            admissions, firsts_dev = self._admit()
+            admissions, firsts_dev, routed_dev = self._admit()
         admit_s = (time.perf_counter() - t_tick0) if meter else 0.0
         with self._lock:
             rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
@@ -652,12 +712,14 @@ class GenerationEngine:
             with self._annotate("engine.decode_dispatch"):
                 active = np.zeros((self.n_slots + 1,), bool)  # scratch inactive
                 active[[i for i, _ in rows]] = True
-                chunk_dev, self.cache, self._last_tok_dev, self._key = (
-                    self._decode_jit(
-                        self.params, self.cache, self._last_tok_dev,
-                        jnp.asarray(active), self._key))
+                (chunk_dev, self.cache, self._last_tok_dev, self._key,
+                 chunk_routed) = self._decode_jit(
+                    self.params, self.cache, self._last_tok_dev,
+                    jnp.asarray(active), self._key)
                 chunk_dev.copy_to_host_async()
-            dispatched = _PendingChunk(chunk_dev, rows, admissions, firsts_dev)
+                _to_host_async(chunk_routed)
+            dispatched = _PendingChunk(chunk_dev, rows, admissions, firsts_dev,
+                                       (routed_dev, chunk_routed))
             # cap-based predicted completion: these slots are free for the
             # NEXT admission even though their token values haven't landed
             # (completion timing is deterministic; EOS only finishes a
@@ -666,9 +728,12 @@ class GenerationEngine:
                 # a slot stands at prompt + scheduled - 1 (the last sampled
                 # token is not in the cache yet); EOS, which only the
                 # device has seen, can only leave it lower
-                self._cache_tiles["read"] += sum(
+                self._cache_tiles["read_full"] += sum(
                     -(-(len(req.tokens) + req.scheduled - 1) // self._tile)
                     for _, req in rows)
+                if self._layers["window"]:
+                    self._cache_tiles["read_window"] += (
+                        (self.n_slots + 1) * self._ring_tiles)
                 self._cache_tiles["padded"] += (
                     (self.n_slots + 1) * self._max_len // self._tile)
                 for i, req in rows:
@@ -721,6 +786,15 @@ class GenerationEngine:
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
         chunk = np.asarray(pending.chunk_dev)  # transfer already in flight
+        for phase, counts in zip(("prefill", "decode"), pending.routed_dev):
+            if counts is not None:
+                counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
+                with self._lock:
+                    had = self._routed[phase]
+                    self._routed[phase] = counts if had is None else {
+                        k: had[k] + v for k, v in counts.items()}
+                    if phase == "decode":
+                        self._routed["decode_steps"] += self.chunk
         for i, req in pending.rows:
             if req.future.done():
                 continue
@@ -794,7 +868,10 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
     from ray_tpu.models import generate as gen
 
     def llm_prefill(params, toks, lens, cache, slots):
-        return gen.prefill_at(params, cfg, toks, lens, cache, slots)
+        logits, cache = gen.prefill_at(params, cfg, toks, lens, cache, slots)
+        # the dispatch's routing counts come out beside the donated cache, so
+        # that the host can read them (None: the family counts nothing)
+        return logits, cache, cache.pop("routed", None)
 
     prefill = jax.jit(
         llm_prefill,
@@ -820,8 +897,16 @@ def _decode_chunk_wrapper(gen, cfg, params, cache, tokens, active, key, *,
         temperature=temperature, top_k=top_k, eos_id=eos_id)
     # chain the NEXT chunk off this one's final tokens without a host
     # round trip (inactive slots carry their input token through, so
-    # emitted[:, -1] is correct for every slot)
-    return emitted, cache, emitted[:, -1], key
+    # emitted[:, -1] is correct for every slot); the chunk's routing counts
+    # as in llm_prefill
+    return emitted, cache, emitted[:, -1], key, cache.pop("routed", None)
+
+
+def _to_host_async(tree) -> None:
+    import jax
+
+    for leaf in jax.tree.leaves(tree):
+        leaf.copy_to_host_async()
 
 
 def _device_facts() -> Dict[str, Any]:
@@ -902,6 +987,7 @@ def prefill_decode_graph(
             self.max_len = prefill_bucket + max_new_tokens + 1
 
         def prefill(self, request):
+            import jax
             import jax.numpy as jnp
 
             if not isinstance(request, dict):
@@ -925,7 +1011,7 @@ def prefill_decode_graph(
             # KV state ships host-side through the channel (the
             # disaggregation transfer)
             return {
-                "cache": {k: np.asarray(v) for k, v in cache.items()},
+                "cache": jax.tree.map(np.asarray, cache),
                 "first": first,
                 "max_new": int(request.get("max_new_tokens")
                                or max_new_tokens),
@@ -954,7 +1040,7 @@ def prefill_decode_graph(
                     f"{max_new_tokens}")
             out = [state["first"]]
             if m > 1:
-                cache = {k: jnp.asarray(v) for k, v in state["cache"].items()}
+                cache = jax.tree.map(jnp.asarray, state["cache"])
                 emitted, _cache, _active, _key = self.gen.decode_chunk(
                     self.params, self.cfg, cache,
                     jnp.asarray(np.array([state["first"]], np.int32)),

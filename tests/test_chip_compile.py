@@ -121,6 +121,45 @@ def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
     ]
 
 
+# K-EXAONE-236B-A23B as the benchmark's k-exaone-236b-a23b-ep8 holds it: the
+# published widths (the config's defaults), 5 layers, experts 0-15 of 128,
+# an eighth of the vocabulary
+K_EXAONE = dict(vocab_size=19_200, n_layers=5, experts_held=(0, 16))
+
+
+def _lower_exaone_cell(chip):
+    """The serve-k-exaone-236b-ep8-mixed cell's programs: window and full
+    layers in one cache (33 rows: a 4,736-position slab for the full layer,
+    256-position rings for the four window layers), 16 held experts a
+    sparse layer through the grouped matmul, prefill rows from a 4,096-token
+    budget: the widest bucket one row, the narrowest 32."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("exaone_moe", "236b", **K_EXAONE)
+    n_slots, chunk = 32, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(4096, 512, chunk)))
+    assert cache["k"].shape == (1, 33, 8, 128, 4736)
+    assert cache["k_ring"].shape == (4, 33, 8, 128, 256)
+    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    rows = lambda n, bucket: prefill.lower(  # noqa: E731
+        _on(chip, params), _on(chip, i32(n, bucket)), _on(chip, i32(n)),
+        _on(chip, cache), _on(chip, i32(n)))
+    return [
+        rows(1, 4096),
+        decode.lower(
+            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
+            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
+            _on(chip, key)),
+        rows(32, 128),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -161,6 +200,7 @@ PROGRAMS = {
     # the serve-gpt2-xl-chat cell: 17 rows, 512 + 368 + 16 = 896 positions
     "serve_engine_gpt2_xl_cell": lambda chip: _lower_serve_engine(
         chip, "gpt2", bucket=512, chunk=16, max_new=368, **XL),
+    "serve_engine_exaone_cell": _lower_exaone_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -246,9 +286,9 @@ def test_program_compiles_for_v5e(compiled, name):
         assert needs[0] > 1 * 2**30  # params + adam moments alone are 1.5 GB
     if name.startswith("serve_engine"):
         # the decode chunk writes the big cache once, at its end, in place:
-        # no scatter anywhere in it, and the cache's three leaves (k, pos,
-        # v: the arguments after the parameters, outputs 1-3) come back in
-        # the buffers they arrived in
+        # no scatter anywhere in it, and the cache's leaves (k, pos, v, and
+        # a family's rings: the arguments after the parameters, outputs 1..)
+        # come back in the buffers they arrived in
         decode = programs[1]
         text = decode.as_text()
         assert not re.search(r"\bscatter\(", text)
@@ -257,8 +297,9 @@ def test_program_compiles_for_v5e(compiled, name):
         # ragged kernel, for G = 1 (GPT-2) and G > 1 (Llama) alike
         assert text.count("tpu_custom_call") >= 1
         n_params = len(jax.tree.leaves(decode.args_info[0][0]))
+        n_cache = len(jax.tree.leaves(decode.args_info[0][1]))
         aliased = re.search(r"input_output_alias=\{(.*?) \}, entry", text).group(1)
-        for out_index, arg in enumerate(range(n_params, n_params + 3), start=1):
+        for out_index, arg in enumerate(range(n_params, n_params + n_cache), start=1):
             assert f"{{{out_index}}}: ({arg}, {{}}, may-alias)" in aliased, aliased
     if name == "serve_engine_gpt2_xl_cell":
         # the layout cliff (ISSUE 28; the cell's `assumed` has the same one
@@ -266,6 +307,12 @@ def test_program_compiles_for_v5e(compiled, name):
         # of temporaries in converted copies.  In place it needs 1.31 GiB
         # (a slab-sized copy feeding the kernel would show here too).
         assert programs[1].memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+    if name == "serve_engine_exaone_cell":
+        # the ragged kernel on the full layer, the grouped matmuls of four
+        # expert layers (three each and their metadata); 7.42 GB of weights
+        # and 0.78 GB of cache resident, well over a quarter of the chip
+        assert programs[1].as_text().count("tpu_custom_call") >= 1 + 4 * 3
+        assert all(8.0e9 < need < 10.5e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; dq + dk/dv + the forward they differentiate

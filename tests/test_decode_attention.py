@@ -70,7 +70,8 @@ def test_kernel_matches_the_slab(shape, live, S):
     got = attention.ragged_decode_attention(
         q, k, v, jnp.int32(layer), plan, interpret=pltpu.InterpretParams())
     exact = _f32_reference(q, k, v, layer, n)
-    slab = gen._cache_scores_slab(q, k, v, jnp.int32(layer), n)
+    slab = gen._cache_scores_slab(
+        q, k, v, jnp.int32(layer), jnp.arange(S)[None, :] < n[:, None])
     for name, g, e, s in zip(("acc", "m", "d"), got, exact, slab):
         # f32 rounding of the same sums in another order ...
         np.testing.assert_allclose(g, e, rtol=2e-5, atol=2e-5, err_msg=name)
@@ -95,7 +96,7 @@ def lowered_for_tpu(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe"])
 def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
     """Slots on both sides of a tile boundary, a short slot, an idle slot
     and the scratch slot, a slot admitted between chunks, over three chunks

@@ -1,0 +1,269 @@
+"""The EXAONE-MoE family (window and full attention layers in one cache,
+sigmoid-routed experts of which this chip holds a block, beside a shared one)
+against its plain reference (``benchmark/reference/k_exaone_ref.py``), at a
+small size on the CPU.
+
+Tolerances.  With ``dtype=float32`` the program and the reference do the
+same arithmetic in another order (grouped matmuls against a masked loop, a
+blocked band against a masked square, one softmax merged from a ring and a
+chunk's columns), so logits of size ~1 agree to a few 1e-6; the limit is
+``F32_TOL = 2e-4``, far under what any departure makes: a ring entry read
+one position off (>1e-2), a dropped token (>1e-1), a bf16 router pass (1e-1
+where the k-th expert changes).
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.reference import k_exaone_ref as ref  # noqa: E402
+from ray_tpu.models import exaone_moe as em  # noqa: E402
+from ray_tpu.models import generate as gen  # noqa: E402
+from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.ops.attention import attention  # noqa: E402
+from ray_tpu.serve.llm import GenerationEngine, make_config  # noqa: E402
+
+F32_TOL = 2e-4
+
+
+def sizes_of(cfg):
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim, "sliding_windows": cfg.sliding_windows,
+            "top_k": cfg.experts_per_token, "routed_scale": cfg.routed_scale,
+            "first_expert": cfg.experts_held[0], "rope_theta": cfg.rope_base,
+            "rms_eps": cfg.rms_eps}
+
+
+@pytest.fixture(scope="module")
+def model():
+    # window 8 (ring 16), 16 experts of which 4..11 are held, top-4
+    cfg = em.ExaoneMoeConfig.tiny(dtype=jnp.float32, experts_held=(4, 8))
+    return cfg, em.init(cfg, jax.random.PRNGKey(0))
+
+
+def ref_logits(model, seq):
+    cfg, params = model
+    return np.asarray(ref.logits(params, jnp.asarray([seq]), sizes_of(cfg))[0])
+
+
+def test_config_reads_the_published_lists_up_to_its_depth():
+    cfg = make_config(
+        "exaone_moe", "tiny", n_layers=5,
+        layer_types=[em.WINDOW] * 3 + [em.FULL] + [em.WINDOW] * 4,
+        mlp_layer_types=[em.DENSE] + [em.SPARSE] * 7, experts_held=[0, 16])
+    assert cfg.sliding_windows == (8, 8, 8, 0, 8)
+    assert cfg.mlp_layer_types == (em.DENSE,) + (em.SPARSE,) * 4
+    hash(cfg)  # jit closes over it
+    # the default pattern is the published one: LLLG, the first layer dense
+    assert em.ExaoneMoeConfig.tiny(n_layers=8).sliding_windows == (
+        8, 8, 8, 0, 8, 8, 8, 0)
+    with pytest.raises(AssertionError):
+        em.ExaoneMoeConfig.tiny(experts_held=(12, 8))  # past the router
+
+
+@pytest.mark.parametrize("layer", [0, 1, 3], ids=[
+    "window_dense", "window_sparse", "full_sparse"])
+def test_one_block_of_each_kind_against_the_reference(model, layer):
+    cfg, params = model
+    x = jax.random.normal(jax.random.PRNGKey(layer), (2, 21, cfg.d_model))
+    p, window = params["layers"][layer], cfg.sliding_windows[layer]
+    got, routed, _ = em.block(x, p, cfg, window=window)
+    sizes = {k: v for k, v in sizes_of(cfg).items() if k != "sliding_windows"}
+    with jax.default_matmul_precision("highest"):
+        want = ref._layer(x, p, window=window, lower=None, **sizes)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
+    assert (routed is None) == (layer == 0)
+    if routed is not None:  # 42 tokens x 4 choices, half of the experts held
+        assert 0 < int(routed["tokens"].sum()) <= 42 * 4
+        assert int(routed["touched"]) == int((routed["tokens"] > 0).sum())
+
+
+def test_forward_against_the_reference(model):
+    cfg, params = model
+    seq = list(np.random.RandomState(1).randint(0, cfg.vocab_size, 40))
+    got = np.asarray(em.apply(params, jnp.asarray([seq]), cfg)[0])
+    assert np.abs(got - ref_logits(model, seq)).max() < F32_TOL
+
+
+@pytest.mark.parametrize("n_prompt", [3, 8, 37], ids=[
+    "shorter_than_window", "equal_to_window", "several_windows"])
+def test_prefill_then_decode_through_the_cache_against_the_reference(
+        model, n_prompt):
+    """Prefill, then three decode chunks of 5 steps through the cache (ring
+    16: the 37-token prompt has wrapped it twice, and every chunk's flush
+    wraps again somewhere): each served token is the reference's best at its
+    position in one full forward over prompt + served tokens."""
+    cfg, params = model
+    rng = np.random.RandomState(n_prompt)
+    prompts = [list(rng.randint(0, cfg.vocab_size, n_prompt)),
+               list(rng.randint(0, cfg.vocab_size, max(1, n_prompt - 2)))]
+    bucket, n_new, steps = 40, 16, 5
+    toks = np.zeros((2, bucket), np.int32)
+    for r, p in enumerate(prompts):
+        toks[r, :len(p)] = p
+    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    cache = gen.init_cache(cfg, 3, 64)  # a third slot sits idle
+    last, cache = gen.prefill_at(params, cfg, jnp.asarray(toks), lengths,
+                                 cache, jnp.asarray([2, 0]))
+    assert cache["k"].shape[0] == 1 and cache["k_ring"].shape == (
+        4, 3, cfg.n_kv_heads, cfg.head_dim, 16)
+    first = jnp.argmax(last, -1).astype(jnp.int32)
+    served = [[int(first[0])], [int(first[1])]]
+    tokens = jnp.zeros((3,), jnp.int32).at[jnp.asarray([2, 0])].set(first)
+    active, key = jnp.asarray([True, False, True]), jax.random.PRNGKey(0)
+    for _ in range(3):
+        cache.pop("routed")
+        emitted, cache, active, key = gen.decode_chunk(
+            params, cfg, cache, tokens, active, key, steps=steps)
+        tokens = emitted[:, -1]
+        served[0] += [int(t) for t in emitted[2]]
+        served[1] += [int(t) for t in emitted[0]]
+    assert int(cache["pos"][2]) == len(prompts[0]) + 15
+    for p, out in zip(prompts, served):
+        logits = ref_logits(model, p + out)[len(p) - 1:len(p) - 1 + n_new]
+        gap = logits.max(-1) - logits[np.arange(n_new), out]
+        assert gap.max() < F32_TOL, (gap, out)
+
+
+def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """16 experts over 8 chips, 2 a chip: the parts the shares give, the
+    shared expert counted once, are the uncut layer (the reference, given
+    every expert)."""
+    whole = em.ExaoneMoeConfig.tiny(dtype=jnp.float32)
+    p = em.init_layer(whole, jax.random.PRNGKey(3), 1)
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, whole.d_model))
+    flat = h.reshape(18, -1)
+    experts, gates = moe.route_sigmoid_top_k(
+        flat, p["router"], p["router_bias"], whole.experts_per_token,
+        whole.routed_scale)
+    parts, counted = 0.0, 0
+    for chip in range(8):
+        held = slice(2 * chip, 2 * chip + 2)
+        y, tokens = moe.held_experts_ffn(
+            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
+            p["ew_down"][held], first_expert=2 * chip)
+        parts, counted = parts + y, counted + int(tokens.sum())
+    assert counted == 18 * whole.experts_per_token  # every choice, once
+    shared = em._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"])
+    sizes = {k: v for k, v in sizes_of(whole).items() if k != "sliding_windows"}
+    # the reference's whole layer on a residual of zero attention: feed the
+    # FFN half by hand (x = 0 would zero the norm), so compare FFN outputs
+    f = lambda a: a  # noqa: E731
+    s = jax.nn.sigmoid(h @ p["router"])
+    _, sel = jax.lax.top_k(s + p["router_bias"], sizes["top_k"])
+    chosen = jnp.take_along_axis(s, sel, -1)
+    g_all = sizes["routed_scale"] * chosen / chosen.sum(-1, keepdims=True)
+    want = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
+    for e in range(whole.n_experts):
+        g = jnp.where(sel == e, g_all, 0.0).sum(-1)
+        want = want + g[..., None] * ref._swiglu(
+            h, p["ew_gate"][e], p["ew_up"][e], p["ew_down"][e], f)
+    got = parts.reshape(h.shape) + shared
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
+
+
+@pytest.mark.parametrize("one_block", [True, False], ids=["one_trip", "trips"])
+def test_no_token_is_dropped_when_every_token_takes_the_same_experts(
+        monkeypatch, one_block):
+    """All-to-one routing: 64 tokens, each choosing experts 0..3, all four
+    held.  Every (token, expert) pair is computed, through one block of
+    pairs or (a block made small) through several trips."""
+    if not one_block:
+        monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", 100)
+    N, D, F, E = 64, 16, 8, 6
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(keys[0], (N, D))
+    w_gate, w_up = (jax.random.normal(k, (E, D, F)) / 4 for k in keys[1:3])
+    w_down = jax.random.normal(keys[3], (E, F, D)) / 3
+    experts = jnp.tile(jnp.arange(4, dtype=jnp.int32), (N, 1))
+    gates = jnp.full((N, 4), 0.25)
+    valid = jnp.arange(N) % 7 != 0  # and some rows hold no token
+    y, tokens = moe.held_experts_ffn(
+        x, experts, gates, w_gate, w_up, w_down, valid=valid)
+    assert tokens.tolist() == [int(valid.sum())] * 4 + [0, 0]
+    want = sum(0.25 * (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+               for e in range(4)) * valid[:, None]
+    assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-5
+
+
+@pytest.mark.parametrize("t,window", [(256, 128), (384, 100), (48, 8), (128, 128)])
+def test_band_attention_is_the_masked_square(t, window):
+    q, k, v = (jax.random.normal(key, (2, 3, t, 16))
+               for key in jax.random.split(jax.random.PRNGKey(t), 3))
+    got = attention(q, k, v, causal=True, window=window)
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) / 4.0
+    s = np.where((j <= i) & (j > i - window), s, -np.inf)
+    w = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", w / w.sum(-1, keepdims=True), v)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+def _one_shot(params, cfg, prompt, n):
+    out = gen.generate(params, cfg, jnp.asarray([prompt]),
+                       jnp.asarray([len(prompt)]), max_new_tokens=n)
+    return [int(t) for t in out[0]]
+
+
+def test_engine_admits_under_a_token_budget_in_order(model):
+    """rows(bucket) = max(1, budget // bucket), at most the slots; the queue's
+    head is taken in order while the next request still fits: a long prompt
+    is neither overtaken nor padded 4 rows wide.  The engine's answers are
+    the one-shot path's, and its counters say what was dispatched."""
+    cfg, params = model
+    eng = GenerationEngine(  # never started: the test is the engine thread
+        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
+        prefill_buckets=(8, 16, 32), prefill_token_budget=32)
+    assert eng._rows == {8: 4, 16: 2, 32: 1}
+    rng = np.random.RandomState(7)
+    prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (5, 6, 20, 4, 12, 3)]
+    futs = [eng.submit(p, 6) for p in prompts]
+    taken = []
+    while not all(f.done() for f in futs):
+        before = eng.stats()["queued"]
+        eng.step()
+        if before - eng.stats()["queued"]:
+            taken.append(before - eng.stats()["queued"])
+    # [5, 6] fit bucket 8; 20 needs bucket 32, one row: alone; then 4 with 12
+    # (bucket 16, two rows), 3 would be a third: next call
+    assert taken == [2, 1, 2, 1]
+    stats = eng.perf_stats()
+    assert stats["prefill"]["8"] == {
+        "calls": 2, "rows": 8, "padded_tokens": 64, "prompts": 3,
+        "live_tokens": 5 + 6 + 3}
+    assert stats["prefill"]["16"]["prompts"] == 2
+    assert stats["prefill"]["32"] == {
+        "calls": 1, "rows": 1, "padded_tokens": 32, "prompts": 1,
+        "live_tokens": 20}
+    for p, f in zip(prompts, futs):
+        assert f.result() == _one_shot(params, cfg, p, 6)
+    # what the decode chunks read: a full layer a slot's live tiles, the four
+    # window layers every row's ring (one tile here), whatever the context
+    tiles = stats["cache_tiles"]
+    assert tiles["layers"] == {"full": 1, "window": 4}
+    dispatches = tiles["padded"] // 5  # 5 rows x 1 tile of 128
+    assert tiles["read_window"] == dispatches * 5 and tiles["read_full"] > 0
+    # the routing counts of every drained dispatch, prefills and chunks apart
+    routed = stats["moe"]
+    held = cfg.experts_held[1]
+    for phase in ("prefill", "decode"):
+        tokens = np.asarray(routed[phase]["tokens"])
+        assert tokens.shape == (4, held) and tokens.sum() > 0
+        assert (np.asarray(routed[phase]["touched"]) <= tokens.sum(1)).all()
+    assert routed["decode_steps"] == 3 * dispatches
+    # only real prompt tokens were routed: 4 choices each, over 16 experts
+    assert np.asarray(routed["prefill"]["tokens"]).sum(1).max() <= 4 * sum(
+        len(p) for p in prompts)
+
+
+def test_default_budget_keeps_every_bucket_n_slots_wide(model):
+    cfg, params = model
+    eng = GenerationEngine(cfg, params, n_slots=3, max_new_tokens=4,
+                           decode_chunk_steps=2, prefill_buckets=(8, 32))
+    assert eng._rows == {8: 3, 32: 3}

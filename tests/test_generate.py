@@ -30,12 +30,12 @@ def _model(family, seed=0, block_scale=1, **cfg_kw):
     it attends (tied embeddings, small blocks); ``block_scale=8`` makes the
     layers' matrices large enough that the answer depends on the context,
     so that a wrong or stale K/V column changes a token."""
-    mod = gpt2 if family == "gpt2" else llama
-    cfg_cls = gpt2.GPT2Config if family == "gpt2" else llama.LlamaConfig
-    cfg = cfg_cls.tiny(dtype=jnp.float32, **cfg_kw)
+    mod = gen.FAMILIES[family]
+    cfg = mod.Config.tiny(dtype=jnp.float32, **cfg_kw)
     params = mod.init(cfg, jax.random.PRNGKey(seed))
-    params["blocks"] = jax.tree.map(
-        lambda w: w * block_scale if w.ndim >= 3 else w, params["blocks"])
+    if "blocks" in params:  # a family that lists its layers scales them itself
+        params["blocks"] = jax.tree.map(
+            lambda w: w * block_scale if w.ndim >= 3 else w, params["blocks"])
     return cfg, params, mod.apply
 
 
@@ -175,7 +175,7 @@ class _Slots:
             int(t) for t in jnp.argmax(logits[0, len(prompt) - 1:], -1)], slot
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe"])
 @pytest.mark.parametrize("chunks", [2, 3])
 def test_chunked_slots_at_different_positions(family, chunks):
     """Slots at different positions in one batch, an idle slot and the

@@ -80,7 +80,7 @@ def test_engine_eos_and_max_new():
 
 def test_cache_tiles_counts_what_the_decode_chunks_read():
     """``perf_stats()["cache_tiles"]``: at every decode dispatch a slot
-    standing at ``n`` positions adds ``ceil(n / 128)`` tiles to ``read``
+    standing at ``n`` positions adds ``ceil(n / 128)`` tiles to ``read_full``
     and the dispatch adds the whole padded slab to ``padded`` — counted on
     the engine thread from prompt lengths and scheduled tokens, no device
     read.  The cache itself is whole tiles."""
@@ -90,7 +90,9 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
         cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
         prefill_buckets=(8, 160))
     assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
-    assert eng.perf_stats()["cache_tiles"] == {"read": 0, "padded": 0}
+    assert eng.perf_stats()["cache_tiles"] == {
+        "read_full": 0, "read_window": 0, "padded": 0,
+        "layers": {"full": cfg.n_layers, "window": 0}}
     prompts = [[1 + i % 50 for i in range(127)], [3, 17, 5],
                [1 + i % 40 for i in range(130)]]
     futs = [eng.submit(p, 8) for p in prompts]  # 3 requests, 2 slots
@@ -104,11 +106,12 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
                for p in prompts for chunk in range(3))
     assert want == (1 + 2 + 2) + 3 + 6
     last = seen[-1]
-    assert last["read"] == want and 0 < last["read"] <= last["padded"]
+    assert last["read_full"] == want and 0 < want <= last["padded"]
+    assert last["read_window"] == 0  # no window layer in this family
     dispatches = last["padded"] // (3 * 2)  # 3 rows (one scratch) x 2 tiles
     assert last["padded"] == dispatches * 6 and dispatches >= 6
     for a, b in zip(seen, seen[1:]):
-        assert a["read"] <= b["read"] and a["padded"] <= b["padded"]
+        assert a["read_full"] <= b["read_full"] and a["padded"] <= b["padded"]
     for p, f in zip(prompts, futs):
         assert f.result() == _one_shot(params, cfg, p, 8)
 
