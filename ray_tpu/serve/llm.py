@@ -10,8 +10,9 @@ is part of the framework here:
   of cache slots, prompt prefills admitted into free slots, one fused
   ``decode_chunk`` advancing every active slot per iteration.  New requests
   join between chunks; finished slots free mid-stream.  All device
-  computations have static shapes (prompt buckets, fixed chunk length), so
-  everything compiles exactly once per bucket.
+  computations have static shapes (a prompt bucket at its fixed narrow width,
+  :data:`CALL_TOKENS`; a fixed chunk length), so everything compiles exactly
+  once per bucket, and a burst is several such calls in one iteration.
 - :func:`llm_deployment` — wraps the engine in a Serve deployment on a
   ``num_tpus`` replica; requests block on a future the engine thread
   resolves, so Serve's threaded replica concurrency (not the engine)
@@ -46,6 +47,19 @@ _LLM_METRICS = None
 import itertools as _itertools  # noqa: E402
 
 _ENGINE_SEQ = _itertools.count()
+
+# Padded tokens one prefill call is wide (rows x bucket; a bucket above it
+# runs one row): about the chip's ridge.  A bf16 weight matmul on a v5e is
+# compute-bound from ~240 tokens a call, so a wider call is not more
+# efficient, only later, and all of it is padding when one prompt arrives;
+# a narrower one still costs a whole read of the weights.
+CALL_TOKENS = 256
+
+
+def call_rows(bucket: int, n_slots: int) -> int:
+    """Rows a prefill call of ``bucket`` is wide: :data:`CALL_TOKENS`' worth,
+    at most the slots, at least one."""
+    return max(1, min(n_slots, CALL_TOKENS // bucket))
 
 
 def _llm_metrics():
@@ -247,15 +261,16 @@ class _PendingChunk:
     (tokens already streaming host-ward via ``copy_to_host_async``) plus
     the host bookkeeping needed to route them when they land."""
 
-    __slots__ = ("chunk_dev", "rows", "admissions", "firsts_dev", "routed_dev")
+    __slots__ = ("chunk_dev", "rows", "prefills", "routed_dev")
 
-    def __init__(self, chunk_dev, rows, admissions, firsts_dev, routed_dev):
+    def __init__(self, chunk_dev, rows, prefills, routed_dev):
         self.chunk_dev = chunk_dev          # [n_slots+1, steps] device
         self.rows = rows                    # [(slot, _Request)] active in chunk
-        self.admissions = admissions        # [(row_j, slot, _Request)] this iter
-        self.firsts_dev = firsts_dev        # [rows] device or None
-        # (prefill's, chunk's) routing counts of this iteration (device; None
-        # where nothing was counted): they land with the tokens
+        # this iteration's prefill calls, in dispatch order: (admissions
+        # [(row_j, slot, _Request)], first tokens [rows] device, the call's
+        # routing counts: device, None where the family counts nothing)
+        self.prefills = prefills
+        # the chunk's routing counts (device or None): they land with the tokens
         self.routed_dev = routed_dev
 
 
@@ -313,14 +328,16 @@ class GenerationEngine:
         self.max_new_tokens = max_new_tokens
         self.chunk = decode_chunk_steps
         self.buckets = tuple(sorted(prefill_buckets))
-        # rows a prefill of each bucket is wide: as many as fit a budget of
-        # tokens a call, at most the slots, at least one.  One compiled
-        # program a bucket still.  The default budget (every slot at the
-        # largest bucket) makes every bucket n_slots wide; an engine whose
-        # largest prompts are thousands of tokens sets one (32 rows of 4,096
-        # would be 131 k tokens a call)
-        budget = prefill_token_budget or n_slots * self.buckets[-1]
-        self._rows = {b: max(1, min(n_slots, budget // b)) for b in self.buckets}
+        # rows a prefill of each bucket is wide.  One compiled program a
+        # bucket, and a narrow one: a lone prompt is not padded to every
+        # slot's rows, and a burst becomes several calls in one tick (_admit)
+        self._rows = {b: call_rows(b, n_slots) for b in self.buckets}
+        # the most padded prefill tokens between two decode chunks: what
+        # every live request's next gap may wait for.  Every slot at the
+        # largest bucket unless set (an engine whose largest prompts are
+        # thousands of tokens sets one); a tick's first call goes whatever
+        # it is wide, so no budget can starve the queue
+        self._tick_tokens = prefill_token_budget or n_slots * self.buckets[-1]
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -349,28 +366,26 @@ class GenerationEngine:
         # the family's layers count (leaves as the programs return them:
         # stacked over the layers that route); None until a first arrives
         self._routed = {"prefill": None, "decode": None, "decode_steps": 0}
-        # one extra SCRATCH slot (index n_slots): batched admission pads
-        # the prefill batch to a bucketed size and parks the padding rows
-        # there, so admitting 1..n_slots requests costs ONE device dispatch
+        # one extra SCRATCH slot (index n_slots): a prefill call is its
+        # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
         self._key = jax.random.PRNGKey(seed)
 
         self._prefill_jit, self._decode_jit = engine_programs(
             cfg, decode_chunk_steps=decode_chunk_steps,
             temperature=temperature, top_k=top_k, eos_id=eos_id)
-        # named functions, not lambdas: a device trace lists each program
-        # under its function's name (jit_llm_sample_first, ...)
-        def llm_sample_first(logits, key):
-            return gen.sample_logits(
-                logits, key, temperature=temperature, top_k=top_k)
+        # a named function, not a lambda: a device trace lists each program
+        # under its function's name (jit_llm_first_tokens).  One program a
+        # prefill width: it draws the call's key, samples the first tokens
+        # and folds them into the device-resident last-token row, so neither
+        # needs a host round trip
+        def llm_first_tokens(logits, key, last, slots):
+            key, sub = jax.random.split(key)
+            firsts = gen.sample_logits(
+                logits, sub, temperature=temperature, top_k=top_k)
+            return firsts, last.at[slots].set(firsts), key
 
-        # prefill's sampled first tokens fold into the device-resident
-        # last-token row without a host round trip
-        def llm_merge_first(last, slots, firsts):
-            return last.at[slots].set(firsts)
-
-        self._sample_jit = jax.jit(llm_sample_first)
-        self._merge_jit = jax.jit(llm_merge_first)
+        self._first_jit = jax.jit(llm_first_tokens)
 
         self._slots: List[Optional[_Request]] = [None] * n_slots
         # device-resident last token per slot: decode chunk N+1 chains off
@@ -602,40 +617,50 @@ class GenerationEngine:
         return self.buckets[-1]
 
     def _admit(self):
-        """Prefill queued prompts into free slots with one device call: the
-        queue's head, in order, for as long as the next request still fits
-        the rows its bucket allows (``_rows``: the widest prompt taken
-        decides the bucket) and a slot is free; the batch is padded to that
-        fixed width, padding rows target the scratch slot.  Returns
-        ``(admissions, firsts_dev, routed_dev)`` — the sampled first tokens
-        stay ON DEVICE (merged into the last-token row there); their values,
-        and the prefill's routing counts, reach the host with the next chunk
-        drain."""
-        import jax
-        import jax.numpy as jnp
-
+        """Prefill queued prompts into free slots: the queue's head, strictly
+        in order, formed into calls greedily.  Consecutive requests of one
+        bucket share a call up to the rows that bucket is wide (``_rows``);
+        a request of another bucket, or a full call, starts the next, so a
+        prompt is padded only to ITS bucket.  Admission stops when the free
+        slots, the queue or the tick's budget of padded tokens
+        (``_tick_tokens``; a tick's first call always goes) are used up.
+        Every call is dispatched here, none is read back: returns the calls
+        as ``_PendingChunk.prefills`` holds them, their first tokens ON
+        DEVICE (merged into the last-token row there); the values, and the
+        calls' routing counts, reach the host with the next chunk drain."""
         with self._lock:
             free = [i for i, s in enumerate(self._slots) if s is None]
-            take = b = 0
-            while take < min(len(free), len(self._queue)):
-                wider = max(b, self._bucket(len(self._queue[take].tokens)))
-                if take + 1 > self._rows[wider]:
+            calls: List[tuple] = []  # (bucket, [(slot, _Request)])
+            spent = 0
+            for slot in free:
+                if not self._queue:
                     break
-                take, b = take + 1, wider
-            if take == 0:
-                return [], None, None
-            batch = [(free[j], self._queue.pop(0)) for j in range(take)]
-            for slot, req in batch:
+                b = self._bucket(len(self._queue[0].tokens))
+                if not (calls and calls[-1][0] == b
+                        and len(calls[-1][1]) < self._rows[b]):
+                    spent += self._rows[b] * b
+                    if calls and spent > self._tick_tokens:
+                        break
+                    calls.append((b, []))
+                req = self._queue.pop(0)
                 self._slots[slot] = req
-            tally = self._prefill[b]
-            tally["calls"] += 1
-            tally["rows"] += self._rows[b]
-            tally["padded_tokens"] += self._rows[b] * b
-            tally["prompts"] += take
-            tally["live_tokens"] += sum(len(r.tokens) for _, r in batch)
-        # a fixed admission width a bucket: ONE compiled prefill program per
-        # prompt bucket (variable widths recompiled mid-serving, which cost
-        # far more than the padded rows' wasted FLOPs)
+                calls[-1][1].append((slot, req))
+            for b, batch in calls:
+                tally = self._prefill[b]
+                tally["calls"] += 1
+                tally["rows"] += self._rows[b]
+                tally["padded_tokens"] += self._rows[b] * b
+                tally["prompts"] += len(batch)
+                tally["live_tokens"] += sum(len(r.tokens) for _, r in batch)
+        return [self._prefill_call(b, batch) for b, batch in calls]
+
+    def _prefill_call(self, b: int, batch):
+        """Dispatch one prefill call of bucket ``b`` for ``batch`` [(slot,
+        _Request)]: the bucket's fixed rows wide (ONE compiled program a
+        bucket: a width that varies recompiles mid-serving), the rows no
+        prompt fills a 1-token dummy aimed at the scratch slot."""
+        import jax.numpy as jnp
+
         n = self._rows[b]
         toks = np.zeros((n, b), np.int32)
         toks[:, 0] = 1  # padding rows: 1-token dummy prompt
@@ -652,13 +677,12 @@ class GenerationEngine:
                 tracing.emit_stage(
                     "engine.queue", t_dispatch - req.submitted_at,
                     req.trace_ctx)
+        slots_dev = jnp.asarray(slots)
         last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
-            self.cache, jnp.asarray(slots))
-        self._key, sub = jax.random.split(self._key)
-        firsts_dev = self._sample_jit(last_logits, sub)
-        self._last_tok_dev = self._merge_jit(
-            self._last_tok_dev, jnp.asarray(slots), firsts_dev)
+            self.cache, slots_dev)
+        firsts_dev, self._last_tok_dev, self._key = self._first_jit(
+            last_logits, self._key, self._last_tok_dev, slots_dev)
         firsts_dev.copy_to_host_async()
         _to_host_async(routed_dev)
         for _, req in batch:
@@ -676,16 +700,19 @@ class GenerationEngine:
             # slot-admission span: submit -> prefill dispatch (the decode
             # tail the VERDICT's p99/p50 chase starts from)
             _events.emit(
-                "serve_llm", f"admitted {take} prompt(s)", severity="DEBUG",
-                span_dur=worst, slots=[s for s, _ in batch],
-                bucket=b, queued_left=queued_left)
+                "serve_llm", f"admitted {len(batch)} prompt(s)",
+                severity="DEBUG", span_dur=worst,
+                slots=[s for s, _ in batch], bucket=b,
+                queued_left=queued_left)
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
         return admissions, firsts_dev, routed_dev
 
     def step(self) -> bool:
         """One engine iteration, software-pipelined against the device:
 
-        1. admit queued prompts into free slots (prefill, no readback)
+        1. admit queued prompts into free slots (as many narrow prefill
+           calls as the queue's head, the free slots and the tick's token
+           budget allow; no readback)
         2. dispatch decode chunk N (chains off device-side last tokens)
         3. free slots whose request deterministically finishes in chunk N
            (cap-based — the HOST knows completion timing without seeing
@@ -703,7 +730,8 @@ class GenerationEngine:
         meter = self._ticks if _events.ENABLED else None
         t_tick0 = time.perf_counter() if meter else 0.0
         with self._annotate("engine.admit"):
-            admissions, firsts_dev, routed_dev = self._admit()
+            prefills = self._admit()
+        n_admitted = sum(len(admissions) for admissions, _, _ in prefills)
         admit_s = (time.perf_counter() - t_tick0) if meter else 0.0
         with self._lock:
             rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
@@ -718,8 +746,7 @@ class GenerationEngine:
                     jnp.asarray(active), self._key)
                 chunk_dev.copy_to_host_async()
                 _to_host_async(chunk_routed)
-            dispatched = _PendingChunk(chunk_dev, rows, admissions, firsts_dev,
-                                       (routed_dev, chunk_routed))
+            dispatched = _PendingChunk(chunk_dev, rows, prefills, chunk_routed)
             # cap-based predicted completion: these slots are free for the
             # NEXT admission even though their token values haven't landed
             # (completion timing is deterministic; EOS only finishes a
@@ -751,19 +778,32 @@ class GenerationEngine:
             # of chunk N-1 (the device sync) — what a request actually
             # waits per iteration
             meter.record(time.perf_counter() - t_tick0,
-                         admit_s if admissions else 0.0,
-                         len(admissions), len(rows))
+                         admit_s if n_admitted else 0.0,
+                         n_admitted, len(rows))
         return dispatched is not None or prev is not None
+
+    def _count_routed(self, phase: str, counts) -> None:
+        """Add one landed dispatch's routing counts (a prefill call's or a
+        chunk's; None where the family counts nothing) to the totals."""
+        if counts is None:
+            return
+        counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
+        with self._lock:
+            had = self._routed[phase]
+            self._routed[phase] = counts if had is None else {
+                k: had[k] + v for k, v in counts.items()}
+            if phase == "decode":
+                self._routed["decode_steps"] += self.chunk
 
     def _drain(self, pending: _PendingChunk) -> None:
         """Materialize one landed chunk: route first tokens + chunk rows to
         their requests, resolve futures, confirm EOS slot frees."""
         instrumented = _events.ENABLED
         drain_t = time.perf_counter()
-        if pending.firsts_dev is not None:
-            firsts = np.asarray(pending.firsts_dev)  # waits for the prefill
+        for admissions, firsts_dev, routed_dev in pending.prefills:
+            firsts = np.asarray(firsts_dev)  # waits for this prefill call
             first_host_t = time.perf_counter()
-            for j, slot, req in pending.admissions:
+            for j, slot, req in admissions:
                 if instrumented and req.first_token_t is None:
                     # TTFT: submit -> this drain's START (drain_t): the
                     # wait for the prefill just above is not in it, so it
@@ -785,16 +825,9 @@ class GenerationEngine:
                 # after the stamps: the stream thread reads them the
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
+            self._count_routed("prefill", routed_dev)
         chunk = np.asarray(pending.chunk_dev)  # transfer already in flight
-        for phase, counts in zip(("prefill", "decode"), pending.routed_dev):
-            if counts is not None:
-                counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
-                with self._lock:
-                    had = self._routed[phase]
-                    self._routed[phase] = counts if had is None else {
-                        k: had[k] + v for k, v in counts.items()}
-                    if phase == "decode":
-                        self._routed["decode_steps"] += self.chunk
+        self._count_routed("decode", pending.routed_dev)
         for i, req in pending.rows:
             if req.future.done():
                 continue

@@ -18,6 +18,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
 import re
 from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -89,13 +90,25 @@ def _lower_train_step(chip):
 XL = dict(n_layers=48, n_heads=25, d_model=1600, d_ff=6400)
 
 
-def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
+def _lower_prefill(chip, prefill, params, cache, n_slots, bucket):
+    """``llm_prefill`` of one bucket at the engine's width for it."""
+    from ray_tpu.serve import llm
+
+    n = llm.call_rows(bucket, n_slots)
+    i32 = lambda *shape: _on(chip, jax.ShapeDtypeStruct(shape, jnp.int32))  # noqa: E731
+    return prefill.lower(
+        _on(chip, params), i32(n, bucket), i32(n), _on(chip, cache), i32(n))
+
+
+def _lower_serve_engine(chip, family, *, buckets=(128,), chunk=64, max_new=128,
                         **config_kwargs):
-    """What a ``num_tpus=1`` LLM replica runs: prefill of one bucket at the
-    fixed admission width, and one decode chunk over 16 slots plus the
-    scratch slot.  The defaults are bench.run_decode_bench's shape; the
-    cache's length is the engine's own rounding to whole 128-position tiles
-    (128 + 128 + 64 = 320 -> 384), so this is what a replica really runs."""
+    """What a ``num_tpus=1`` LLM replica runs: the prefill of each bucket at
+    the engine's width for it (``llm.call_rows``: the largest bucket first,
+    the others after the decode program), and one decode chunk over 16 slots
+    plus the scratch slot.  The defaults are bench.run_decode_bench's shape;
+    the cache's length is the engine's own rounding to whole 128-position
+    tiles (128 + 128 + 64 = 320 -> 384), so this is what a replica really
+    runs."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -106,18 +119,19 @@ def _lower_serve_engine(chip, family, *, bucket=128, chunk=64, max_new=128,
         llm._default_init(cfg, 0)))
     cache = jax.eval_shape(
         lambda: gen.init_cache(
-            cfg, n_slots + 1, llm.cache_positions(bucket, max_new, chunk)))
+            cfg, n_slots + 1, llm.cache_positions(max(buckets), max_new, chunk)))
     prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
+    widest, *narrower = sorted(buckets, reverse=True)
     return [
-        prefill.lower(
-            _on(chip, params), _on(chip, i32(n_slots, bucket)),
-            _on(chip, i32(n_slots)), _on(chip, cache), _on(chip, i32(n_slots))),
+        prefill_of(widest),
         decode.lower(
             _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
             _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
             _on(chip, key)),
+        *map(prefill_of, narrower),
     ]
 
 
@@ -131,8 +145,9 @@ def _lower_exaone_cell(chip):
     """The serve-k-exaone-236b-ep8-mixed cell's programs: window and full
     layers in one cache (33 rows: a 4,736-position slab for the full layer,
     256-position rings for the four window layers), 16 held experts a
-    sparse layer through the grouped matmul, prefill rows from a 4,096-token
-    budget: the widest bucket one row, the narrowest 32."""
+    sparse layer through the grouped matmul, every prefill bucket at the
+    engine's width for it (``llm.call_rows``: two rows of 128, one of each
+    wider bucket)."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -147,16 +162,15 @@ def _lower_exaone_cell(chip):
     prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    rows = lambda n, bucket: prefill.lower(  # noqa: E731
-        _on(chip, params), _on(chip, i32(n, bucket)), _on(chip, i32(n)),
-        _on(chip, cache), _on(chip, i32(n)))
+    prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
+    assert [llm.call_rows(b, n_slots) for b in (128, 256, 4096)] == [2, 1, 1]
     return [
-        rows(1, 4096),
+        prefill_of(4096),
         decode.lower(
             _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
             _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
             _on(chip, key)),
-        rows(32, 128),
+        *map(prefill_of, (2048, 1024, 512, 256, 128)),
     ]
 
 
@@ -197,9 +211,10 @@ PROGRAMS = {
     # G > 1 path at another head size (the preset above has G = 3, dh = 64)
     "serve_engine_llama_gqa4": lambda chip: _lower_serve_engine(
         chip, "llama", n_heads=8, n_kv_heads=2, d_model=1024),
-    # the serve-gpt2-xl-chat cell: 17 rows, 512 + 368 + 16 = 896 positions
+    # the serve-gpt2-xl-chat cell: 17 rows, 512 + 368 + 16 = 896 positions,
+    # prefill calls of 1 x 512, 1 x 256, 2 x 128, 4 x 64
     "serve_engine_gpt2_xl_cell": lambda chip: _lower_serve_engine(
-        chip, "gpt2", bucket=512, chunk=16, max_new=368, **XL),
+        chip, "gpt2", buckets=(64, 128, 256, 512), chunk=16, max_new=368, **XL),
     "serve_engine_exaone_cell": _lower_exaone_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
@@ -307,6 +322,10 @@ def test_program_compiles_for_v5e(compiled, name):
         # of temporaries in converted copies.  In place it needs 1.31 GiB
         # (a slab-sized copy feeding the kernel would show here too).
         assert programs[1].memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+        # a prefill call is at most 512 padded tokens wide: its temporaries
+        # are a small fraction of the 16-row calls' (1.9 GiB at 16 x 512)
+        for prefill in programs[:1] + programs[2:]:
+            assert prefill.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
     if name == "serve_engine_exaone_cell":
         # the ragged kernel on the full layer, the grouped matmuls of four
         # expert layers (three each and their metadata); 7.42 GB of weights

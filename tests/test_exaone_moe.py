@@ -211,36 +211,89 @@ def _one_shot(params, cfg, prompt, n):
     return [int(t) for t in out[0]]
 
 
-def test_engine_admits_under_a_token_budget_in_order(model):
-    """rows(bucket) = max(1, budget // bucket), at most the slots; the queue's
-    head is taken in order while the next request still fits: a long prompt
-    is neither overtaken nor padded 4 rows wide.  The engine's answers are
-    the one-shot path's, and its counters say what was dispatched."""
-    cfg, params = model
-    eng = GenerationEngine(  # never started: the test is the engine thread
-        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
-        prefill_buckets=(8, 16, 32), prefill_token_budget=32)
-    assert eng._rows == {8: 4, 16: 2, 32: 1}
-    rng = np.random.RandomState(7)
-    prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (5, 6, 20, 4, 12, 3)]
-    futs = [eng.submit(p, 6) for p in prompts]
-    taken = []
+def _ticks(eng, futs):
+    """Step a never-started engine until ``futs`` are done: per tick that
+    admitted anything, the prompt lengths of each prefill call it made."""
+    ticks = []
     while not all(f.done() for f in futs):
         before = eng.stats()["queued"]
         eng.step()
         if before - eng.stats()["queued"]:
-            taken.append(before - eng.stats()["queued"])
-    # [5, 6] fit bucket 8; 20 needs bucket 32, one row: alone; then 4 with 12
-    # (bucket 16, two rows), 3 would be a third: next call
-    assert taken == [2, 1, 2, 1]
+            ticks.append([[len(req.tokens) for _, _, req in admissions]
+                          for admissions, _, _ in eng._pending.prefills])
+    return ticks
+
+
+# buckets (8, 16, 32) at CALL_TOKENS 16: rows 2 / 1 / 1; 4 slots; an answer
+# of 6 tokens is a prefill and two chunks of 3 steps, so a slot admitted in
+# tick t is free again for tick t + 2
+ADMISSIONS = {
+    # a burst of one bucket is several calls in ONE tick, each rows(b) wide
+    "burst_of_one_bucket": dict(
+        lens=(5, 6, 3, 7), budget=None,
+        ticks=[[[5, 6], [3, 7]]],
+        prefill={8: dict(calls=2, rows=4, padded_tokens=32, prompts=4,
+                         live_tokens=21)}),
+    # FIFO: no request overtakes another, a prompt is padded to ITS bucket
+    # only (6 and 4 do not join 5's call past the 20 between them, and 20
+    # does not widen anyone), and admission stops at the free slots
+    "mixed_buckets_keep_fifo": dict(
+        lens=(5, 20, 6, 4, 12, 3), budget=None,
+        ticks=[[[5], [20], [6, 4]], [[12], [3]]],
+        prefill={8: dict(calls=3, rows=6, padded_tokens=48, prompts=4,
+                         live_tokens=18),
+                 16: dict(calls=1, rows=1, padded_tokens=16, prompts=1,
+                          live_tokens=12),
+                 32: dict(calls=1, rows=1, padded_tokens=32, prompts=1,
+                          live_tokens=20)}),
+    # the tick's budget of PADDED tokens stops admission with slots still
+    # free; the rest goes next tick, in order
+    "tick_budget_stops_admission": dict(
+        lens=(5, 6, 12, 4, 20), budget=32,
+        ticks=[[[5, 6], [12]], [[4]], [[20]]],
+        prefill={8: dict(calls=2, rows=4, padded_tokens=32, prompts=3,
+                         live_tokens=15),
+                 16: dict(calls=1, rows=1, padded_tokens=16, prompts=1,
+                          live_tokens=12),
+                 32: dict(calls=1, rows=1, padded_tokens=32, prompts=1,
+                          live_tokens=20)}),
+    # a tick's first call goes whatever it is wide: a budget under a call's
+    # width admits one call a tick and starves nobody
+    "a_ticks_first_call_always_goes": dict(
+        lens=(20, 3, 30), budget=8,
+        ticks=[[[20]], [[3]], [[30]]],
+        prefill={8: dict(calls=1, rows=2, padded_tokens=16, prompts=1,
+                         live_tokens=3),
+                 32: dict(calls=2, rows=2, padded_tokens=64, prompts=2,
+                          live_tokens=50)}),
+}
+
+
+@pytest.mark.parametrize("case", list(ADMISSIONS))
+def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
+    """rows(bucket) = max(1, CALL_TOKENS // bucket), at most the slots; the
+    queue's head is formed into calls greedily and in order, as many a tick
+    as the free slots and the tick's budget of padded tokens allow.  The
+    engine's answers are the one-shot path's whatever call a prompt rode
+    in, and its counters say exactly what was dispatched."""
+    from ray_tpu.serve import llm
+
+    cfg, params = model
+    want = ADMISSIONS[case]
+    monkeypatch.setattr(llm, "CALL_TOKENS", 16)
+    eng = GenerationEngine(  # never started: the test is the engine thread
+        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
+        prefill_buckets=(8, 16, 32), prefill_token_budget=want["budget"])
+    assert eng._rows == {8: 2, 16: 1, 32: 1}
+    assert eng._tick_tokens == (want["budget"] or 4 * 32)
+    rng = np.random.RandomState(7)
+    prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in want["lens"]]
+    futs = [eng.submit(p, 6) for p in prompts]
+    assert _ticks(eng, futs) == want["ticks"]
     stats = eng.perf_stats()
-    assert stats["prefill"]["8"] == {
-        "calls": 2, "rows": 8, "padded_tokens": 64, "prompts": 3,
-        "live_tokens": 5 + 6 + 3}
-    assert stats["prefill"]["16"]["prompts"] == 2
-    assert stats["prefill"]["32"] == {
-        "calls": 1, "rows": 1, "padded_tokens": 32, "prompts": 1,
-        "live_tokens": 20}
+    empty = dict(calls=0, rows=0, padded_tokens=0, prompts=0, live_tokens=0)
+    assert stats["prefill"] == {
+        str(b): want["prefill"].get(b, empty) for b in (8, 16, 32)}
     for p, f in zip(prompts, futs):
         assert f.result() == _one_shot(params, cfg, p, 6)
     # what the decode chunks read: a full layer a slot's live tiles, the four
@@ -249,7 +302,8 @@ def test_engine_admits_under_a_token_budget_in_order(model):
     assert tiles["layers"] == {"full": 1, "window": 4}
     dispatches = tiles["padded"] // 5  # 5 rows x 1 tile of 128
     assert tiles["read_window"] == dispatches * 5 and tiles["read_full"] > 0
-    # the routing counts of every drained dispatch, prefills and chunks apart
+    # the routing counts of every drained dispatch, prefill calls (several a
+    # tick) and chunks apart
     routed = stats["moe"]
     held = cfg.experts_held[1]
     for phase in ("prefill", "decode"):
@@ -262,8 +316,21 @@ def test_engine_admits_under_a_token_budget_in_order(model):
         len(p) for p in prompts)
 
 
-def test_default_budget_keeps_every_bucket_n_slots_wide(model):
-    cfg, params = model
-    eng = GenerationEngine(cfg, params, n_slots=3, max_new_tokens=4,
-                           decode_chunk_steps=2, prefill_buckets=(8, 32))
-    assert eng._rows == {8: 3, 32: 3}
+@pytest.mark.parametrize("n_slots,buckets,rows", [
+    # serve-gpt2-xl-chat and serve-k-exaone-236b-ep8-mixed, as their files
+    # size the engine
+    (16, (64, 128, 256, 512), (4, 2, 1, 1)),
+    (32, (128, 256, 512, 1024, 2048, 4096), (2, 1, 1, 1, 1, 1)),
+    # a bucket under CALL_TOKENS / n_slots is as wide as the slots
+    (3, (8, 32), (3, 3)),
+])
+def test_a_bucket_is_call_tokens_wide(n_slots, buckets, rows):
+    from ray_tpu.serve import llm
+
+    assert llm.CALL_TOKENS == 256
+    eng = GenerationEngine(
+        em.ExaoneMoeConfig.tiny(dtype=jnp.float32, experts_held=(4, 8)),
+        {}, n_slots=n_slots, max_new_tokens=4, decode_chunk_steps=2,
+        prefill_buckets=buckets)
+    assert eng._rows == dict(zip(buckets, rows))
+    assert eng._tick_tokens == n_slots * buckets[-1]

@@ -116,6 +116,44 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
         assert f.result() == _one_shot(params, cfg, p, 8)
 
 
+def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
+    """16 queued prompts, 4 slots, the default budget (every slot at the
+    largest bucket): each tick takes the queue's head into as many calls as
+    it has free slots for, consecutive prompts of one bucket sharing a call
+    up to its rows (2 / 1 at CALL_TOKENS 16), no prompt overtaking another or
+    padded past its own bucket; the tallies are exact and every answer is
+    the one-shot path's."""
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(llm, "CALL_TOKENS", 16)
+    cfg = GPT2Config.tiny(dtype=jnp.float32)
+    params = gpt2.init(cfg, jax.random.PRNGKey(3))
+    eng = GenerationEngine(  # never started: the test is the engine thread
+        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
+        prefill_buckets=(8, 16))
+    assert eng._rows == {8: 2, 16: 1} and eng._tick_tokens == 4 * 16
+    lens = (3, 5, 12, 4, 9, 10, 2, 6, 7, 1, 11, 13, 8, 8, 8, 8)
+    prompts = [[1 + (7 * i + j) % 90 for j in range(n)]
+               for i, n in enumerate(lens)]
+    futs = [eng.submit(p, 6) for p in prompts]
+    ticks = []
+    while not all(f.done() for f in futs):
+        before = eng.stats()["queued"]
+        eng.step()
+        if before - eng.stats()["queued"]:
+            ticks.append([[len(req.tokens) for _, _, req in admissions]
+                          for admissions, _, _ in eng._pending.prefills])
+    assert ticks == [[[3, 5], [12], [4]], [[9], [10], [2, 6]],
+                     [[7, 1], [11], [13]], [[8, 8], [8, 8]]]
+    assert eng.perf_stats()["prefill"] == {
+        "8": {"calls": 6, "rows": 12, "padded_tokens": 96, "prompts": 11,
+              "live_tokens": 60},
+        "16": {"calls": 5, "rows": 5, "padded_tokens": 80, "prompts": 5,
+               "live_tokens": 55}}
+    for p, f in zip(prompts, futs):
+        assert f.result() == _one_shot(params, cfg, p, 6)
+
+
 def test_llm_deployment_behind_serve(serve_instance):
     dep = llm_deployment(
         "gpt2", "tiny",
