@@ -172,8 +172,8 @@ def train_loop(config: dict) -> None:
     ref_cfg = dataclasses.replace(cfg, dtype=jnp.float32, remat=False)
     dispatcher = transformer.attention
     transformer.attention = (
-        lambda q, k, v, causal=False, window=0: full_attention(
-            q, k, v, causal=causal))
+        lambda q, k, v, causal=False, window=0, scale=None: full_attention(
+            q, k, v, causal=causal, scale=scale))
     try:
         with jax.default_matmul_precision("highest"):
             ref_loss = float(jax.jit(

@@ -1,9 +1,31 @@
-"""KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama).
+"""KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
+EXAONE-MoE, Kimi-K2).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
+
+- **Three kinds of cache** (:func:`init_cache`), chosen by what the family's
+  config says of its layers, never by its name:
+
+  1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
+     KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
+     kept.  Prefill writes a prompt's columns whole; a decode chunk flushes
+     its ``steps`` columns to ``pos0[b]`` once, both tensors.
+  2. a RING for a window layer (``cfg.sliding_windows``): ``k_ring``,
+     ``v_ring`` ``[L_window, B, KV, dh, 2 x window]``, position ``j`` at ``j %
+     ring``, so a slot costs the ring however long its context.  Prefill
+     keeps each row's last ring; the chunk's flush writes modulo the ring by
+     a 0/1 matrix, both tensors.
+  3. a LATENT slab (``cfg.latent_cache``; multi-head latent attention): ONE
+     tensor ``c`` ``[L, B, 1, row, S]``, a position holds one row (the normed
+     latent, then the rotated key all heads share: 512 + 64 values as
+     published) and there is NO ``v``: the row is every head's key and its
+     first values are the position's value vector (the absorbed decode form
+     of :mod:`ray_tpu.models.kimi_k2`).  Prefill (un-absorbed, k and v a head
+     for the call only) keeps the rows its block hands over; the chunk's
+     flush writes ONE tensor.
 
 - **One block per family**: prefill and decode run the block training
   runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
@@ -28,7 +50,9 @@ first-class TPU path, designed for XLA:
   writes there with one ``dynamic_update_slice`` at ``(l, i)`` — the same
   index for every slot, a contiguous block — and attends the cache below
   ``pos0`` (the slot's position when the chunk began) together with the
-  buffer up to ``i``, under one softmax.  After the last step each slot's
+  buffer up to ``i``, under one softmax.  (A latent layer's buffer holds the
+  chunk's rows, ``[L, steps, B, 1, row]``, one tensor again.)  After the
+  last step each slot's
   columns go into the cache at ``pos0[b]``, once, in place (XLA aliases
   the donated cache through the flush loop).  A scatter of every slot's
   column at its own position per layer per step cost 7.5 ms of the 19.7 ms
@@ -41,7 +65,10 @@ first-class TPU path, designed for XLA:
   that copies in, per slot, only the tiles below ``live[b]`` and returns the
   softmax un-normalised (:func:`ray_tpu.ops.attention.ragged_decode_attention`;
   :func:`_decode_attend` merges it with the chunk-local columns under one
-  max and denominator).  Anywhere else — the CPU, a cache of another
+  max and denominator; a latent layer walks the same list with
+  :func:`ray_tpu.ops.attention.ragged_latent_decode_attention`, two MXU
+  matmuls over a tile copied in once).  Anywhere else — the CPU, a cache of
+  another
   length such as :func:`generate`'s — the same sums run as masked einsums
   over layer ``l``'s whole padded slab (:func:`_cache_scores_slab`), which
   is also the reference the kernel is tested against.  Chosen by
@@ -70,12 +97,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import exaone_moe, gpt2, llama
+from ray_tpu.models import exaone_moe, gpt2, kimi_k2, llama
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.attention import (
     DECODE_TILE,
+    latent_slab_attention,
     ragged_decode_attention,
     ragged_decode_plan,
+    ragged_latent_decode_attention,
 )
 
 # The one table that knows the families: name -> the family's module.  A
@@ -86,8 +115,13 @@ from ray_tpu.ops.attention import (
 # them rolled; one that mixes kinds of layer lists them (``params["layers"]``),
 # its config says which layers attend a window (``sliding_windows``), its
 # ``block`` takes the layer's ``window`` and the ``valid`` tokens, and the
-# loops run unrolled, each kind of layer against its own kind of cache.
-FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe}
+# loops run unrolled, each kind of layer against its own kind of cache.  A
+# family whose layers cache ONE latent row a position instead of K and V per
+# head says so in its config (``latent_cache``, with its own
+# ``attention_scale``), and its ``block`` hands the attention middle that row
+# as a fourth argument.
+FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
+            "kimi_k2": kimi_k2}
 
 
 def family_of(cfg):
@@ -111,6 +145,20 @@ def layer_windows(cfg) -> Tuple[int, ...]:
     return windows
 
 
+def latent_cache(cfg) -> Optional[Tuple[int, int]]:
+    """For a family whose layers cache one latent row a position: ``(values a
+    row holds, of which the first are the position's value vector)``, the
+    row's other use being the position's key for every head
+    (``cfg.latent_cache``).  None: K and V per KV head."""
+    return getattr(cfg, "latent_cache", None)
+
+
+def cached_tensors(cfg) -> Tuple[str, ...]:
+    """The names of what :func:`init_cache` holds a position of a full
+    layer: K and V per head, or the one latent row."""
+    return ("c",) if latent_cache(cfg) else ("k", "v")
+
+
 def ring_positions(window: int) -> int:
     """Positions a slot's ring holds for a window layer: twice the window
     (two 128-tiles at the published 128).  A decode chunk needs ``steps <=
@@ -127,8 +175,15 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     stores the cache unpadded), every position kept.  The window layers, for
     a family that has them: ``k_ring``/``v_ring`` ``[L_window, B, KV, dh,
     R]``, a ring: position ``j`` lives at ``j % R`` (``ring_positions``), so
-    a slot costs ``R`` positions however long its context."""
+    a slot costs ``R`` positions however long its context.  A family of
+    latent layers (:func:`latent_cache`): ``c`` ``[L, B, 1, row, S]``, one row
+    a position and NO second tensor."""
     windows = layer_windows(cfg)
+    if latent_cache(cfg):
+        assert not any(windows), windows
+        return {"c": jnp.zeros((cfg.n_layers, n_slots, 1, latent_cache(cfg)[0],
+                                max_len), cfg.dtype),
+                "pos": jnp.zeros((n_slots,), jnp.int32)}
     slab = lambda layers, length: jnp.zeros(  # noqa: E731
         (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
     n_full = windows.count(0)
@@ -184,6 +239,26 @@ def _cache_scores(q, k_all, v_all, l, n, plan):
             q, k, v, l, below(n)))
 
 
+def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int):
+    """:func:`_cache_scores` for a latent layer: ``q [B, 1, H, row]``, every
+    head against the ONE row a position of layer ``l`` of ``c_all [L, B, 1,
+    row, S]``, whose first ``dv`` values are the position's value vector.
+    The kernel (:func:`ray_tpu.ops.attention.ragged_latent_decode_attention`)
+    and the masked einsums over the slab are chosen as there."""
+    below = lambda n: jnp.arange(c_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
+    slab = lambda q, c, l, n, plan=None: latent_slab_attention(  # noqa: E731
+        q, c, l, below(n), scale=scale, dv=dv)
+    if plan is None:
+        out = slab(q[:, 0], c_all, l, n)
+    else:
+        out = lax.platform_dependent(
+            q[:, 0], c_all, l, n, plan,
+            tpu=lambda q, c, l, n, plan: ragged_latent_decode_attention(
+                q, c, l, plan, scale=scale, dv=dv),
+            default=slab)
+    return tuple(a[:, None] for a in out)
+
+
 def _ring_holds(n, ring: int) -> jax.Array:
     """``[B, ring]``: the position entry ``r`` of slot ``b``'s ring holds once
     positions ``j < n[b]`` are written, the newest with ``j % ring == r``;
@@ -202,7 +277,8 @@ def _ring_mask(live, pos, window: int, ring: int) -> jax.Array:
     return (j >= 0) & (j > pos[:, None] - window)
 
 
-def _decode_attend(q, cached, k_new, v_new, i, window: int = 0) -> jax.Array:
+def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
+                   scale: Optional[float] = None) -> jax.Array:
     """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
     what its cache holds from before the chunk, and the chunk's own columns
     ``[steps, B, KV, dh]`` at ``t <= i`` (of a window layer: ``t > i -
@@ -212,13 +288,17 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0) -> jax.Array:
     for a slot that was inactive then; :func:`_cache_scores_slab` over a
     window layer's ring).  ONE softmax over both score sets: the halves are
     merged under the shared max and denominator.  GQA folds the query heads
-    onto their KV head by reshape (no materialized repeat)."""
+    onto their KV head by reshape (no materialized repeat).  A latent layer
+    is the same sums with one KV head, a ``scale`` of its own (None: ``dh **
+    -0.5``) and values narrower than keys (``v_new``: the first values of
+    ``k_new``)."""
     B, H, _, dh = q.shape
     KV, steps = k_new.shape[2], k_new.shape[0]
     q = q.reshape(B, KV, H // KV, dh)
     acc_old, m_old, d_old = cached(q)
     s_new = jnp.einsum("bkgd,tbkd->bkgt", q, k_new.astype(q.dtype),
-                       preferred_element_type=jnp.float32) / (dh ** 0.5)
+                       preferred_element_type=jnp.float32)
+    s_new = s_new / (dh ** 0.5) if scale is None else s_new * scale
     t = jnp.arange(steps)
     s_new = jnp.where((t <= i) & (t > i - window) if window else t <= i,
                       s_new, -1e30)
@@ -229,7 +309,7 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0) -> jax.Array:
     out = acc_old * (w_old / denom)[..., None] + jnp.einsum(
         "bkgt,tbkd->bkgd", (e_new / denom[..., None]).astype(v_new.dtype),
         v_new, preferred_element_type=jnp.float32)
-    return out.reshape(B, H, 1, dh)
+    return out.reshape(B, H, 1, v_new.shape[-1])
 
 
 def _ring_of(t, lengths, ring: int):
@@ -249,8 +329,9 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     subset — one compiled program admits a whole batch of requests).  Returns
     ``(last_logits [B, V], cache)``.  Positions are 0..Tp-1, so a slot must
     be prefilled from scratch (pos resets to ``lengths``).  A full layer
-    keeps every position of the prompt, a window layer the last ``ring`` of
-    each row (:func:`_ring_of`).  Where the family's layers count what they
+    keeps every position of the prompt (its k, v; a latent layer its rows),
+    a window layer the last ``ring`` of each row (:func:`_ring_of`).  Where
+    the family's layers count what they
     routed, the dispatch's counts come back as ``cache["routed"]`` (leaves
     stacked over the layers that route)."""
     fam = family_of(cfg)
@@ -258,9 +339,12 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
-    def attend(q, k, v, window=0):
-        # the causal (or band) attention of training, this layer's k, v kept
-        return _attend(q, k, v, causal=True, mesh=None, window=window)[0], (k, v)
+    def attend(q, k, v, row=None, window=0):
+        # the causal (or band) attention of training; kept: this layer's k,
+        # v, or the cache row a latent family's block hands over
+        out = _attend(q, k, v, causal=True, mesh=None, window=window,
+                      scale=getattr(cfg, "attention_scale", None))[0]
+        return out, ((k, v) if row is None else (row,))
 
     routed = []
     if "blocks" in params:  # layers alike, stacked: one rolled loop
@@ -286,7 +370,7 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     # (over whole slots, once a prompt; decode never scatters)
     to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
         jnp.swapaxes(t, 3, 4).astype(c.dtype))
-    for t, name in zip(full, "kv"):
+    for t, name in zip(full, cached_tensors(cfg)):
         out[name] = to_cache(t, cache[name])
     for t, name in zip(ringed, ("k_ring", "v_ring")):
         out[name] = cache[name].at[:, slots].set(_ring_of(
@@ -338,27 +422,34 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     (``live``), and so is the kernel's work list, built once here."""
     fam = family_of(cfg)
     B = tokens.shape[0]
-    S = cache["k"].shape[-1]
+    # what a full layer caches a position: k and v, or one latent row whose
+    # first values are the position's value vector (absorbed attention)
+    names, latent = cached_tensors(cfg), latent_cache(cfg)
+    scale = getattr(cfg, "attention_scale", None)  # None: dh ** -0.5
+    old = tuple(cache[name] for name in names)
+    S = old[0].shape[-1]
     windows = layer_windows(cfg)
-    window, ring = max(windows), cache.get("k_ring", cache["k"]).shape[-1]
+    window, ring = max(windows), cache.get("k_ring", old[0]).shape[-1]
     # a dynamic_update_slice clamps silently: the flush of a slot at pos0
     # needs pos0 + steps <= S (the engine's bucket + max_new + chunk); a
     # ring's, steps <= window + 1 (ring_positions)
     assert steps <= S and (not window or steps <= window + 1), (steps, S, window)
     if steps == 0:
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
-    k_old, v_old, pos0 = cache["k"], cache["v"], cache["pos"]
+    pos0 = cache["pos"]
     # what a slot attends of the cache, fixed for the chunk: the positions
     # below where it stood, nothing for a slot that sits the chunk out
     live = jnp.where(active, pos0, 0)
     plan = None
-    if S % DECODE_TILE == 0 and cfg.head_dim % 8 == 0:
+    if S % DECODE_TILE == 0 and old[0].shape[3] % 8 == 0:
         plan = ragged_decode_plan(live, S // DECODE_TILE)
     local = jnp.zeros(  # [L, steps, B, KV, dh]
-        (cfg.n_layers, steps, B, *k_old.shape[2:4]), k_old.dtype)
+        (cfg.n_layers, steps, B, *old[0].shape[2:4]), old[0].dtype)
+    # a latent family's block in its decode form (the family's docstring)
+    form = {"absorbed": True} if latent else {}
 
     def step(carry, i):
-        k_loc, v_loc, pos, toks, act, rng = carry
+        locs, pos, toks, act, rng = carry
         rng, sub = jax.random.split(rng)
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
         x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
@@ -366,26 +457,32 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         def layer(l, w, at, block, carry):
             """Layer ``l``, the ``at``-th of its kind (``w``: its window, 0
             a full layer), run as ``block(x, attend)``."""
-            x, k_loc, v_loc = carry
+            x, *locs = carry
             at_l = lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
 
             def cached(q):
+                if latent:
+                    return _latent_cache_scores(
+                        q, old[0], at, live, plan, scale=scale, dv=latent[1])
                 if not w:
-                    return _cache_scores(q, k_old, v_old, at, live, plan)
+                    return _cache_scores(q, *old, at, live, plan)
                 return _cache_scores_slab(
                     q, cache["k_ring"], cache["v_ring"], at,
                     _ring_mask(live, pos, w, ring))
 
-            def attend(q, k, v):  # [B, heads, 1, dh]
+            def attend(q, k, v, row=None):  # [B, heads, 1, dh]
                 put = lambda buf, t: lax.dynamic_update_slice(
                     buf, t[None, None, :, :, 0, :].astype(buf.dtype),
                     (l, i, 0, 0, 0))
-                k_new, v_new = put(k_loc, k), put(v_loc, v)
-                out = _decode_attend(q, cached, at_l(k_new), at_l(v_new), i, w)
-                return out.astype(cfg.dtype), (k_new, v_new)
+                new = tuple(put(buf, t) for buf, t in zip(
+                    locs, (k, v) if row is None else (row,)))
+                k_new = at_l(new[0])
+                v_new = at_l(new[1]) if row is None else k_new[..., :v.shape[-1]]
+                out = _decode_attend(q, cached, k_new, v_new, i, w, scale)
+                return out.astype(cfg.dtype), new
 
-            x, counts, (k_loc, v_loc) = block(x, attend)
-            return (x, k_loc, v_loc), counts
+            x, counts, locs = block(x, attend)
+            return (x, *locs), counts
 
         counted = []  # what the layers that route counted, this step
         if "blocks" in params:  # layers alike, stacked: one rolled loop
@@ -395,17 +492,16 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                 return layer(l, 0, l, lambda x, attend: fam.block(
                     x, p, cfg, attend, positions), carry)[0]
 
-            x, k_loc, v_loc = lax.fori_loop(
-                0, cfg.n_layers, rolled, (x, k_loc, v_loc))
+            x, *locs = lax.fori_loop(0, cfg.n_layers, rolled, (x, *locs))
         else:  # kinds of layer mixed, listed: unrolled, each kind's cache
-            state, seen = (x, k_loc, v_loc), {}
+            state, seen = (x, *locs), {}
             for l, (p, w) in enumerate(zip(params["layers"], windows)):
                 at = seen[bool(w)] = seen.get(bool(w), -1) + 1
                 state, counts = layer(l, w, at, lambda x, attend, p=p, w=w: fam.block(
-                    x, p, cfg, attend, positions, window=w, valid=act[:, None]),
-                    state)
+                    x, p, cfg, attend, positions, window=w, valid=act[:, None],
+                    **form), state)
                 counted += [] if counts is None else [counts]
-            x, k_loc, v_loc = state
+            x, *locs = state
         logits = fam.unembed(params, x, cfg)[:, 0, :]
         nxt = sample_logits(logits, sub, temperature=temperature, top_k=top_k)
         nxt = jnp.where(act, nxt, toks)
@@ -414,34 +510,35 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
             act = act & (nxt != eos_id)
         # leaves stacked over the layers that route (None: nothing counted)
         counted = jax.tree.map(lambda *a: jnp.stack(a), *counted) if counted else None
-        return (k_loc, v_loc, pos, nxt, act, rng), (nxt, counted)
+        return (tuple(locs), pos, nxt, act, rng), (nxt, counted)
 
-    (k_loc, v_loc, pos, _, active, key), (emitted, routed) = lax.scan(
-        step, (local, local, pos0, tokens, active, key), jnp.arange(steps))
+    (locs, pos, _, active, key), (emitted, routed) = lax.scan(
+        step, ((local,) * len(names), pos0, tokens, active, key),
+        jnp.arange(steps))
 
     # the chunk's columns of the layers of one kind (a family of one kind: all)
     of = lambda loc, kind: loc if not window else loc[  # noqa: E731
         jnp.asarray([l for l, w in enumerate(windows) if bool(w) == kind])]
-    k_new, v_new = of(k_loc, False), of(v_loc, False)
+    news = tuple(of(loc, False) for loc in locs)
 
-    def flush(b, kv):
+    def flush(b, bigs):
         # slot b's columns of the chunk, as [L, 1, KV, dh, steps], to pos0[b]
         col = lambda loc: jnp.transpose(
             lax.dynamic_index_in_dim(loc, b, 2, keepdims=False),
             (0, 2, 3, 1))[:, None]
         return tuple(
             lax.dynamic_update_slice(big, col(loc), (0, b, 0, 0, pos0[b]))
-            for big, loc in zip(kv, (k_new, v_new)))
+            for big, loc in zip(bigs, news))
 
     out = {**cache, "pos": pos}
-    out["k"], out["v"] = lax.fori_loop(0, B, flush, (k_old, v_old))
+    out.update(zip(names, lax.fori_loop(0, B, flush, old)))
     if window:
         # the rings, once a chunk and whole: column t of slot b goes to entry
         # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other
         # entry stays; no scatter, and a wrap is nothing special
         hit = ((pos0[:, None, None] + jnp.arange(steps)[None, :, None]) % ring
                == jnp.arange(ring)[None, None, :])          # [B, steps, ring]
-        for name, loc in (("k_ring", k_loc), ("v_ring", v_loc)):
+        for name, loc in zip(("k_ring", "v_ring"), locs):
             new = jnp.einsum("ltbkd,btr->lbkdr", of(loc, True),
                              hit.astype(loc.dtype))
             out[name] = jnp.where(hit.any(1)[None, :, None, None, :],

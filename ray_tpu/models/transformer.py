@@ -159,15 +159,17 @@ def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = No
     return train_step
 
 
-def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh], window: int = 0):
+def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh], window: int = 0,
+            scale: Optional[float] = None):
     """The attention middle of training and ``apply``: the whole sequence,
     each KV head serving ``H // KV`` query heads, sequence-parallel when the
     mesh has an sp axis; ``window``: a window layer's band (0: none; not
-    under sp).  Carries nothing out of the layer."""
+    under sp); ``scale``: a family's own (None: ``dh ** -0.5``; not under
+    sp).  Carries nothing out of the layer."""
     if k.shape[1] != q.shape[1]:  # GQA
         k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
     if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
-        assert not window, "no band under sequence parallelism"
+        assert not window and scale is None, "no band, no scale of its own under sp"
         batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
         heads = "tp" if "tp" in mesh.axis_names else None
         spec = P(batch, heads, "sp", None)
@@ -179,7 +181,7 @@ def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh], window: int = 0):
             check_vma=False,
         )
         return sm(q, k, v), None
-    return attention(q, k, v, causal=causal, window=window), None
+    return attention(q, k, v, causal=causal, window=window, scale=scale), None
 
 
 # block parameters used in the dtype they are stored in; every other one is

@@ -33,6 +33,20 @@ Not in the dispatch:
   TPU with a cache of whole tiles: the ``serve-gpt2-xl-chat`` cell, whose
   masked einsums over the padded slab it replaces (6.2 ms of a 13.16 ms
   step: ledger, PR 29).
+- :func:`ragged_latent_decode_attention` — the decode step of multi-head
+  latent attention: 64 absorbed queries a slot against a cache of ONE latent
+  row a position, ``[L, B, 1, 576, S]``.  The tile walk is
+  :func:`ragged_decode_attention`'s (the same plan of (slot, tile) items, the
+  same double buffer across slots, the same un-normalised result), but a
+  tile ``[576, 128]`` is copied in ONCE and serves as the keys of all heads
+  and, its first 512 rows, as their values.  Why it is not that kernel: there
+  a head's query meets its own K and V tile, a matrix-vector product on the
+  VPU; here every head meets the same tile, 139,264 FLOPs a position against
+  1,152 bytes (121 FLOP a byte, half way to the v5e's ridge of 240), so
+  scores and values are two MXU matmuls with float32 accumulation and an
+  online, row-wise softmax.  Off the TPU and for a cache that is not whole
+  tiles: :func:`latent_slab_attention`, the masked einsums over the slab,
+  which the tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -89,7 +103,7 @@ def blockwise_attention(
     (used by ring attention, where k/v rotate around the ``sp`` ring).
     """
     *_, t_q, d = q.shape
-    t_k = k.shape[-2]
+    t_k, dv = k.shape[-2], v.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     block_k = min(block_k, t_k)
     # Lengths that don't divide block_k are padded (padded keys masked out
@@ -104,7 +118,7 @@ def blockwise_attention(
 
     qf = q.astype(jnp.float32) * scale
     k_blocks = k.reshape(*k.shape[:-2], n_blocks, block_k, d)
-    v_blocks = v.reshape(*v.shape[:-2], n_blocks, block_k, d)
+    v_blocks = v.reshape(*v.shape[:-2], n_blocks, block_k, dv)
     # scan over the block axis: move it to front
     k_blocks = jnp.moveaxis(k_blocks, -3, 0)
     v_blocks = jnp.moveaxis(v_blocks, -3, 0)
@@ -124,7 +138,7 @@ def blockwise_attention(
             s = jnp.where((k_pos < t_k)[None, :], s, NEG_INF)
         return _block_update(carry, s, v_blk), None
 
-    o0 = jnp.zeros((*q.shape[:-1], d), jnp.float32)
+    o0 = jnp.zeros((*q.shape[:-1], dv), jnp.float32)
     m0 = jnp.full(q.shape[:-1], NEG_INF, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1], jnp.float32)
     (o, m, l), _ = lax.scan(
@@ -214,15 +228,16 @@ def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool,
 ):
-    """Returns (out [B,H,Tq,D], lse [B,H,Tq] f32)."""
+    """Returns (out [B,H,Tq,Dv], lse [B,H,Tq] f32); ``v`` may be another
+    width than ``q`` and ``k`` (latent attention's 128 against 192)."""
     b, h, t_q, d = q.shape
-    t_k = k.shape[-2]
+    t_k, dv = k.shape[-2], v.shape[-1]
     bq, bk = min(block_q, t_q), min(block_k, t_k)
     if t_q % bq or t_k % bk:
         raise ValueError(f"seq lens ({t_q},{t_k}) not divisible by blocks ({bq},{bk})")
     qr = q.reshape(b * h, t_q, d)
     kr = k.reshape(b * h, t_k, d)
-    vr = v.reshape(b * h, t_k, d)
+    vr = v.reshape(b * h, t_k, dv)
     grid = (b * h, t_q // bq, t_k // bk)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
@@ -234,24 +249,24 @@ def _flash_forward(
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, dv), lambda bh, qi, ki: (bh, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, t_q, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
-            pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(b, h, t_q, d), lse.reshape(b, h, t_q)
+    return out.reshape(b, h, t_q, dv), lse.reshape(b, h, t_q)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -346,12 +361,12 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_backward(q, k, v, out, lse, g, *, causal, scale,
                     block_q, block_k, interpret):
     b, h, t_q, d = q.shape
-    t_k = k.shape[-2]
+    t_k, dv = k.shape[-2], v.shape[-1]
     bq, bk = min(block_q, t_q), min(block_k, t_k)
     qr = q.reshape(b * h, t_q, d)
     kr = k.reshape(b * h, t_k, d)
-    vr = v.reshape(b * h, t_k, d)
-    dor = g.reshape(b * h, t_q, d)
+    vr = v.reshape(b * h, t_k, dv)
+    dor = g.reshape(b * h, t_q, dv)
     lser = lse.reshape(b * h, t_q, 1)
     # Δ = rowsum(dO ⊙ O): one fused elementwise reduce, cheap in XLA
     delta = jnp.sum(
@@ -359,6 +374,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, scale,
     ).reshape(b * h, t_q, 1)
 
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, a, b2: (bh, a, 0))
+    do_spec = pl.BlockSpec((1, bq, dv), lambda bh, a, b2: (bh, a, 0))
     row_spec = pl.BlockSpec((1, bq, 1), lambda bh, a, b2: (bh, a, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
@@ -367,8 +383,8 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, scale,
         in_specs=[
             q_spec,                                                # q by qi
             pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            q_spec,                                                # dO by qi
+            pl.BlockSpec((1, bk, dv), lambda bh, qi, ki: (bh, ki, 0)),
+            do_spec,                                               # dO by qi
             row_spec,                                              # lse
             row_spec,                                              # delta
         ],
@@ -379,33 +395,34 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, scale,
     )(qr, kr, vr, dor, lser, delta)
 
     k_spec = pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0))
-    dk, dv = pl.pallas_call(
+    v_spec = pl.BlockSpec((1, bk, dv), lambda bh, ki, qi: (bh, ki, 0))
+    dk, dv_ = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, q_offset=t_k - t_q),
         grid=(b * h, t_k // bk, t_q // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),  # q
             k_spec,                                                    # k
-            k_spec,                                                    # v
-            pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),  # dO
+            v_spec,                                                    # v
+            pl.BlockSpec((1, bq, dv), lambda bh, ki, qi: (bh, qi, 0)),  # dO
             pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # lse
             pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # delta
         ],
-        out_specs=[k_spec, k_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, t_k, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, delta)
     return (
         dq.reshape(b, h, t_q, d),
         dk.reshape(b, h, t_k, d),
-        dv.reshape(b, h, t_k, d),
+        dv_.reshape(b, h, t_k, dv),
     )
 
 
@@ -662,6 +679,171 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             out[:, heads_pad:heads_pad + heads, :].sum(-1).reshape(B, KV, G))
 
 
+# ---------------------------------------------------------------------------
+# Ragged LATENT decode attention: 64 heads against ONE latent row a position
+# ---------------------------------------------------------------------------
+
+
+def latent_slab_attention(q: jax.Array, c: jax.Array, layer: jax.Array,
+                          mask: jax.Array, *, scale: float, dv: int):
+    """:func:`ragged_latent_decode_attention`'s sums as masked einsums over
+    layer ``layer``'s whole padded slab ``[B, 1, dk, S]``, slot ``b`` attending
+    the positions where ``mask [B, S]`` holds: what every platform can run
+    (the CPU, a cache that is not whole tiles), and the plain reference the
+    kernel is tested against."""
+    c = lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)[:, 0]  # [B, dk, S]
+    mask = mask[:, None, :]
+    s = jnp.einsum("bhd,bds->bhs", q.astype(c.dtype), c,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask, s, NEG_INF)
+    m = s.max(-1)
+    e = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)  # n == 0: nothing
+    acc = jnp.einsum("bhs,bds->bhd", e.astype(c.dtype), c[:, :dv],
+                     preferred_element_type=jnp.float32)
+    return acc, m, e.sum(-1)
+
+
+def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, acc_ref, m_ref,
+                          d_ref, c_buf, sem, acc, m_run, l_run,
+                          *, scale: float, dv: int):
+    """One invocation walks :func:`ragged_decode_plan`'s work list, as
+    :func:`_ragged_decode_kernel` does: item ``w`` is one 128-position tile of
+    one slot, ``[dk, 128]`` with positions on the lanes as the cache stores
+    them, copied in ONCE (double-buffered: item ``w + 1`` is in flight while
+    ``w`` is computed, across slots too) and used twice, as the keys of the
+    slot's ``heads`` queries and, its first ``dv`` rows, as their values.
+
+    Unlike that kernel the arithmetic is two MXU matmuls a tile in the
+    cache's dtype with float32 accumulation, scores ``[heads, dk] x [dk,
+    128]`` and values ``[heads, 128] x [dv, 128]^T``: every head attends the
+    SAME rows, so a tile is a matrix-matrix product (139,264 FLOPs a cached
+    position against 1,152 bytes), where K and V per head make a head's
+    query a matrix-VECTOR product.  The softmax runs row-wise, online: a
+    head's running max and denominator sit on all 128 lanes of its row of
+    ``m_run`` / ``l_run``, so that every update is elementwise.  A slot's
+    accumulator, max and denominator are written when its last tile is done."""
+    T = DECODE_TILE
+    n_slots = q_ref.shape[0]
+    n_items = (plan_ref.shape[0] - 1 - n_slots) // 2
+    layer, count = layer_ref[0], plan_ref[0]
+    slot_of = lambda w: plan_ref[1 + n_slots + w]  # noqa: E731
+    tile_of = lambda w: plan_ref[1 + n_slots + n_items + w]  # noqa: E731
+
+    # slots the list never visits (nothing live) return the empty softmax
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    d_ref[...] = jnp.zeros_like(d_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+    def copy(w, buf):
+        start = pl.multiple_of(tile_of(w) * T, T)
+        return pltpu.make_async_copy(
+            c_hbm.at[layer, slot_of(w), 0, :, pl.ds(start, T)],
+            c_buf.at[buf], sem.at[buf])
+
+    @pl.when(count > 0)
+    def _():
+        copy(0, 0).start()
+
+    def item(w, _):
+        buf = w % 2
+        b, t = slot_of(w), tile_of(w)
+        n = plan_ref[1 + b]
+
+        @pl.when(w + 1 < count)
+        def _():
+            copy(w + 1, 1 - buf).start()
+
+        @pl.when(t == 0)
+        def _():  # the slot's first tile
+            acc[...] = jnp.zeros_like(acc)
+            l_run[...] = jnp.zeros_like(l_run)
+            m_run[...] = jnp.full_like(m_run, NEG_INF)
+
+        copy(w, buf).wait()
+        tile = c_buf[buf]                                       # [dk, T]
+        s = lax.dot_general(
+            q_ref[b], tile, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [heads, T]
+        live = t * T + lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_run[...]                       # a head's max on every lane
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # the slot's first tile holds position 0, so m_new is a real score
+        e = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        m_run[...] = m_new
+        l_run[...] = l_run[...] * alpha + jnp.sum(e, axis=1, keepdims=True)
+        acc[...] = acc[...] * alpha[:, :1] + lax.dot_general(
+            e.astype(tile.dtype), tile[:dv], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [heads, dv]
+
+        @pl.when((t + 1) * T >= n)
+        def _():  # the slot's last tile
+            acc_ref[b] = acc[...]
+            m_ref[b] = m_run[...]
+            d_ref[b] = l_run[...]
+
+    lax.fori_loop(0, count, item, None)
+
+
+def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
+                                   layer: jax.Array, plan: jax.Array, *,
+                                   scale: float, dv: int, interpret=False):
+    """The cache half of a decode step's LATENT attention, reading only what
+    is live: ``q [B, H, dk]`` (one absorbed query a head a slot) against layer
+    ``layer`` of the WHOLE latent cache ``c [L, B, 1, dk, S]`` (one row a
+    position: its ``dk`` values are the position's key for every head, the
+    first ``dv`` of them its value), which stays in HBM; slot ``b`` attends
+    positions ``j < n[b]``, and the kernel copies in only its tiles ``t <
+    ceil(n[b] / 128)``, each ONCE for scores and values (``plan``:
+    :func:`ragged_decode_plan` of ``n``, the list
+    :func:`ragged_decode_attention` walks).  Returns the softmax
+    un-normalised for the caller to merge with its other keys: ``acc [B, H,
+    dv]``, running max ``m`` and denominator ``d [B, H]``, all f32; a slot
+    with ``n[b] == 0`` gives ``acc = 0, d = 0, m = -1e30`` and moves no byte
+    of cache.  Needs ``S % 128 == 0`` and ``dk, dv % 8 == 0``.
+
+    What it shares with :func:`ragged_decode_attention`: the plan, the walk,
+    the double buffer, the un-normalised result.  Why it is not that kernel:
+    that one multiplies on the VPU a head at a time over separate ``k`` and
+    ``v`` tiles of one width; here every head reads the same tile, so the
+    work is two MXU matmuls over a tile that is copied in once
+    (:func:`_ragged_latent_kernel`)."""
+    B, H, dk = q.shape
+    S, T = c.shape[-1], DECODE_TILE
+    assert S % T == 0 and dk % 8 == 0 and dv % 8 == 0, (S, dk, dv)
+    assert c.shape[2:4] == (1, dk) and dv <= dk, (c.shape, dk, dv)
+    whole = lambda *shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, *_: (0,) * len(shape))
+    acc, m, d = pl.pallas_call(
+        functools.partial(_ragged_latent_kernel, scale=scale, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole(B, H, dk), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[whole(B, H, dv), whole(B, H, T), whole(B, H, T)],
+            scratch_shapes=[
+                pltpu.VMEM((2, dk, T), c.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, dv), jnp.float32),  # accumulator
+                # max and denominator, a head's on every lane of its row
+                pltpu.VMEM((H, T), jnp.float32),
+                pltpu.VMEM((H, T), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, T), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, T), jnp.float32)],
+        # q and the three results live in VMEM whole, double-buffered by the
+        # pipeline: 33 slots x 64 heads x (576 bf16 + 768 f32) is 18 MB
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="ragged_latent_decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q.astype(c.dtype), c)
+    return acc, m[..., 0], d[..., 0]
+
+
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
     scale: Optional[float] = None, block_q: int = 128, block_k: int = 128,
@@ -675,7 +857,7 @@ def attention(
       ``i - window < j <= i``) → :func:`band_attention`
     - causal, square, block-divisible, moderate T → :func:`causal_skip_attention`
     - moderate T → :func:`full_attention` (masked, MXU dtypes)
-    - T ≥ 8k on TPU, block-divisible → :func:`flash_attention_tpu`
+    - T ≥ 8k lowered for a TPU, block-divisible → :func:`flash_attention_tpu`
       (pallas fwd + recompute-free bwd kernels)
     - other long T → :func:`blockwise_attention` (O(block) memory,
       pads+masks any length; ring attention covers sharded-T)
@@ -688,17 +870,19 @@ def attention(
         if causal and t_q == t_k and t_q % 256 == 0 and t_q >= 512:
             return causal_skip_attention(q, k, v, scale=scale, block=256)
         return full_attention(q, k, v, causal=causal, scale=scale)
-    if (
-        q.ndim == 4
-        and t_k >= 8192  # predates the chip; no cell on either side
-        and t_q % block_q == 0
-        and t_k % block_k == 0
-        and jax.default_backend() == "tpu"
-    ):
-        # long context: the pallas kernel pair (fwd + recompute-free bwd)
-        return flash_attention_tpu(
-            q, k, v, causal, scale, block_q, block_k, False
-        )
+    if q.ndim == 4 and t_k >= 8192 and t_q % block_q == 0 and t_k % block_k == 0:
+        # long context (predates the chip; one prefill bucket of one cell
+        # sits here): where the program is lowered for a TPU the pallas
+        # kernel pair (fwd + recompute-free bwd), in blocks of 512 where the
+        # lengths allow (a grid step costs ~0.35 us whatever it computes)
+        wide = lambda b, t: max(b, 512) if t % 512 == 0 else b  # noqa: E731
+        return lax.platform_dependent(
+            q, k, v,
+            tpu=lambda q, k, v: flash_attention_tpu(
+                q, k, v, causal, scale, wide(block_q, t_q), wide(block_k, t_k),
+                False),
+            default=lambda q, k, v: blockwise_attention(
+                q, k, v, causal=causal, scale=scale, block_k=block_k))
     return blockwise_attention(
         q, k, v, causal=causal, scale=scale, block_k=block_k
     )

@@ -21,6 +21,7 @@ is part of the framework here:
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from concurrent.futures import Future
@@ -369,6 +370,15 @@ class GenerationEngine:
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
+        # bytes of one tile of a layer of each kind, from the cache's own
+        # shapes (k and v per KV head, or one latent row a position), so that
+        # no reader of the counters guesses a row's width
+        row_bytes = lambda *names: sum(  # noqa: E731
+            math.prod(self.cache[n].shape[2:4]) * self.cache[n].dtype.itemsize
+            for n in names if n in self.cache)
+        self._tile_bytes = {
+            "full": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg)),
+            "window": DECODE_TILE * row_bytes("k_ring", "v_ring")}
         self._key = jax.random.PRNGKey(seed)
 
         self._prefill_jit, self._decode_jit = engine_programs(
@@ -539,7 +549,8 @@ class GenerationEngine:
             # the same lock, so sorted() never sees a mutating deque
             ttft = list(self._ttft_samples)
             itl = list(self._itl_samples)
-            cache_tiles = {**self._cache_tiles, "layers": dict(self._layers)}
+            cache_tiles = {**self._cache_tiles, "layers": dict(self._layers),
+                           "tile_bytes": dict(self._tile_bytes)}
             prefill = {str(b): dict(v) for b, v in self._prefill.items()}
             moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
         stages: Dict[str, Any] = {}
@@ -556,7 +567,8 @@ class GenerationEngine:
             "stages": stages,
             # how much of the padded cache the decode steps read, in tiles a
             # layer of each kind (cumulative; counted at dispatch from prompt
-            # lengths and scheduled tokens), and how many layers of each kind
+            # lengths and scheduled tokens), how many layers of each kind (a
+            # latent layer is a full one) and the bytes of one tile of each
             "cache_tiles": cache_tiles,
             # what the prefill calls were wide and what of it was prompt
             # (cumulative, by bucket)

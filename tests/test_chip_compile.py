@@ -174,6 +174,45 @@ def _lower_exaone_cell(chip):
     ]
 
 
+# Kimi-K2.7-Code as the benchmark's kimi-k2.7-code-ep32 holds it: the
+# published widths (the config's defaults), 6 layers, experts 0-11 of 384, an
+# eighth of the vocabulary
+KIMI_K2 = dict(vocab_size=20_480, n_layers=6, experts_held=(0, 12))
+
+
+def _lower_kimi_cell(chip):
+    """The serve-kimi-k2.7-code-ep32-code cell's programs: six latent layers
+    over ONE cache tensor (33 rows x 9,344 positions of one 576-value row),
+    12 held experts a sparse layer through the grouped matmul, every prefill
+    bucket one row wide up to 8,192 tokens (the widest through the Pallas
+    flash kernel with 192-wide keys and 128-wide values)."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("kimi_k2", "k2.7-code", **KIMI_K2)
+    n_slots, chunk = 32, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_173_177_728
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(8192, 1024, chunk)))
+    assert set(cache) == {"c", "pos"}
+    assert cache["c"].shape == (6, 33, 1, 576, 9344)
+    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
+    assert [llm.call_rows(b, n_slots) for b in (256, 8192)] == [1, 1]
+    return [
+        prefill_of(8192),
+        decode.lower(
+            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
+            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
+            _on(chip, key)),
+        *map(prefill_of, (4096, 2048, 1024, 512, 256)),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -216,6 +255,7 @@ PROGRAMS = {
     "serve_engine_gpt2_xl_cell": lambda chip: _lower_serve_engine(
         chip, "gpt2", buckets=(64, 128, 256, 512), chunk=16, max_new=368, **XL),
     "serve_engine_exaone_cell": _lower_exaone_cell,
+    "serve_engine_kimi_cell": _lower_kimi_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -332,6 +372,14 @@ def test_program_compiles_for_v5e(compiled, name):
         # and 0.78 GB of cache resident, well over a quarter of the chip
         assert programs[1].as_text().count("tpu_custom_call") >= 1 + 4 * 3
         assert all(8.0e9 < need < 10.5e9 for need in needs), needs
+    if name == "serve_engine_kimi_cell":
+        # the latent kernel once a layer, the grouped matmuls of five expert
+        # layers; the 8,192-token prefill attends through the flash kernel
+        # (six layers); 8.35 GB of weights and 2.13 GB of cache resident
+        assert programs[1].as_text().count("tpu_custom_call") >= 6 + 5 * 3
+        assert "ragged_latent_decode_attention" in programs[1].as_text()
+        assert programs[0].as_text().count("tpu_custom_call") >= 6 + 5 * 3
+        assert all(10.4e9 < need < 15.5e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; dq + dk/dv + the forward they differentiate

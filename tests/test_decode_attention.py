@@ -96,12 +96,14 @@ def lowered_for_tpu(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe", "kimi_k2"])
 def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
     """Slots on both sides of a tile boundary, a short slot, an idle slot
     and the scratch slot, a slot admitted between chunks, over three chunks
     of a cache of two tiles: token for token the full forward's greedy
-    answer (which ``test_generate`` holds the slab path to as well)."""
+    answer (which ``test_generate`` holds the slab path to as well).  A
+    latent family (``kimi_k2``) walks the same list through its own kernel,
+    ``ragged_latent_decode_attention``."""
     eng = _Slots(family, 5, 256, max_seq_len=256)
     rng = np.random.default_rng(0)
     eng.admit(0, [int(t) for t in rng.integers(1, 200, size=123)], 128)

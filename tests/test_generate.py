@@ -175,7 +175,7 @@ class _Slots:
             int(t) for t in jnp.argmax(logits[0, len(prompt) - 1:], -1)], slot
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe", "kimi_k2"])
 @pytest.mark.parametrize("chunks", [2, 3])
 def test_chunked_slots_at_different_positions(family, chunks):
     """Slots at different positions in one batch, an idle slot and the

@@ -92,7 +92,9 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
     assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
     assert eng.perf_stats()["cache_tiles"] == {
         "read_full": 0, "read_window": 0, "padded": 0,
-        "layers": {"full": cfg.n_layers, "window": 0}}
+        "layers": {"full": cfg.n_layers, "window": 0},
+        # k and v of every head, 128 positions, float32 here
+        "tile_bytes": {"full": 2 * cfg.d_model * 128 * 4, "window": 0}}
     prompts = [[1 + i % 50 for i in range(127)], [3, 17, 5],
                [1 + i % 40 for i in range(130)]]
     futs = [eng.submit(p, 8) for p in prompts]  # 3 requests, 2 slots

@@ -123,9 +123,14 @@ def test_sixteen_host_slice_chaos_recovery(slice_fleet, tmp_path):
     th = threading.Thread(target=run, daemon=True)
     th.start()
 
-    # mid-train: rank 0 has taken (and checkpointed) a few steps
-    _wait(lambda: progress.exists() and int(progress.read_text() or 0) >= 3,
-          240, "training to reach step 3")
+    # mid-train: rank 0 has taken (and checkpointed) a few steps.  The
+    # progress file is written BEFORE the step's report, and the driver books
+    # a checkpoint only once all 16 ranks' reports are in, so on a loaded box
+    # step 3 can be reached with nothing booked yet: wait for the third
+    # checkpoint (step 2) itself, which is what ``resumed_from >= 3`` needs
+    _wait(lambda: progress.exists() and int(progress.read_text() or 0) >= 3
+          and (tmp_path / "slice-chaos" / "checkpoint_000002").exists(),
+          240, "training to reach step 3 with step 2's checkpoint booked")
 
     # the gang leased STRICT_PACK *within the slice*: one bundle per host
     with node.lock:
