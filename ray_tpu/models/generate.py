@@ -52,11 +52,24 @@ first-class TPU path, designed for XLA:
   ``pos0`` (the slot's position when the chunk began) together with the
   buffer up to ``i``, under one softmax.  (A latent layer's buffer holds the
   chunk's rows, ``[L, steps, B, 1, row]``, one tensor again.)  After the
-  last step each slot's
-  columns go into the cache at ``pos0[b]``, once, in place (XLA aliases
-  the donated cache through the flush loop).  A scatter of every slot's
-  column at its own position per layer per step cost 7.5 ms of the 19.7 ms
-  GPT-2 XL decode step on the v5e (PERF.md, PR 28).
+  last step the columns go into the cache at ``pos0[b]``, once, in place:
+  the FLUSH (:func:`_flush`), a cached tensor at a time.
+- **The flush touches only what it changes**: positions are on the lanes,
+  so ``steps`` columns at an arbitrary ``pos0[b]`` are a partial store into
+  every row of a 128-lane tile.  Lowered for a TPU with a cache of whole
+  tiles, the flush is a Pallas kernel over the slots that were ``active``
+  when the chunk began (:func:`ray_tpu.ops.attention.cache_flush`): it
+  copies in the one tile ``[KV, dh, 128]`` of a layer that holds
+  ``pos0[b]`` (and the next where the columns cross into it), places the
+  columns at their lanes, keeps every other lane and copies the tile back;
+  the slab stays in HBM, aliased input to output, so the donated cache is
+  merged where it lies.  A slot that sat the chunk out is not visited.
+  Anywhere else one ``dynamic_update_slice`` a slot, every slot's
+  (:func:`_flush_slices`, the reference the kernel is tested against).  The
+  rings keep their 0/1-matrix flush.  On the chip the kernel is the
+  ``cache_flush`` row of a traced run's ``breakdown.device_ops``; how much of
+  the slab it touches is ``perf_stats()["cache_tiles"]``: ``flushed``
+  against ``padded`` (numbers: PERF.md section 6, PRs 28 and 38).
 - **A step reads only the live cache**: a slot attends the cache below
   ``live[b]`` — ``pos0[b]``, or 0 for a slot that sits the chunk out (the
   scratch row, idle slots, finished requests).  Lowered for a TPU with a
@@ -73,19 +86,20 @@ first-class TPU path, designed for XLA:
   over layer ``l``'s whole padded slab (:func:`_cache_scores_slab`), which
   is also the reference the kernel is tested against.  Chosen by
   ``lax.platform_dependent`` and the cache's shape; there is no flag.
-  Reading the padded slab was 6.2 ms of the 13.16 ms GPT-2 XL step, 17 rows
-  x 896 positions of which 14-20 % were live tiles (ledger, PR 29; ISSUE 30).
+  (What reading the padded slab cost: PERF.md section 6, PR 30.)
 
 The flush invariant: after a chunk, every position ``j < pos[b]`` of slot
-``b`` holds a column that prefill or an ACTIVE step wrote.  The flush
-writes all ``steps`` columns at ``pos0[b] ..``, so those of a slot that was
-idle, or that met EOS mid-chunk, land at or beyond its frozen ``pos`` —
-harmless: a slot never attends an index its own ``pos`` hasn't covered, the
-next flush starts at ``pos`` again, and prefill overwrites ``[0, Tp)`` and
-resets ``pos`` when the slot is reused.  A slot that decodes needs
-``pos0 + steps <= S`` (the engine sizes the cache ``bucket + max_new +
-chunk``); where an IDLE slot's frozen ``pos`` is nearer the end than that,
-the slice update clamps and lands on the slot's own dead columns.
+``b`` holds a column that prefill or an ACTIVE step wrote.  For a slot that
+was active when the chunk began the flush writes all ``steps`` columns at
+``pos0[b] ..``, so those after a mid-chunk EOS land at or beyond its frozen
+``pos`` — harmless: a slot never attends an index its own ``pos`` hasn't
+covered, the next flush starts at ``pos`` again, and prefill overwrites
+``[0, Tp)`` and resets ``pos`` when the slot is reused.  A slot that
+decodes needs ``pos0 + steps <= S`` (the engine sizes the cache ``bucket +
+max_new + chunk``).  For a slot that sat the chunk out the kernel writes
+NOTHING; the slice updates write its ``steps`` columns at its frozen
+``pos`` too (where that is nearer the end than ``steps`` the update clamps),
+onto the slot's own dead columns, which is as harmless.
 """
 
 from __future__ import annotations
@@ -101,6 +115,8 @@ from ray_tpu.models import exaone_moe, gpt2, kimi_k2, llama
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.attention import (
     DECODE_TILE,
+    cache_flush,
+    cache_flush_plan,
     latent_slab_attention,
     ragged_decode_attention,
     ragged_decode_plan,
@@ -312,6 +328,38 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
     return out.reshape(B, H, 1, v_new.shape[-1])
 
 
+def _flush_slices(slab, new, pos0):
+    """The flush as one ``dynamic_update_slice`` a slot: ``new [L, steps, B,
+    KV, dh]``, slot ``b``'s ``steps`` columns of every layer, into ``slab [L,
+    B, KV, dh, S]`` at ``pos0[b] ..`` (held to ``S - steps``: the update
+    clamps), EVERY slot's, in place.  What every platform can run, and the
+    plain reference :func:`ray_tpu.ops.attention.cache_flush` is held to on
+    the slots that decoded."""
+    def one(b, big):
+        # slot b's columns of the chunk, as [L, 1, KV, dh, steps]
+        col = jnp.transpose(
+            lax.dynamic_index_in_dim(new, b, 2, keepdims=False),
+            (0, 2, 3, 1))[:, None]
+        return lax.dynamic_update_slice(big, col, (0, b, 0, 0, pos0[b]))
+
+    return lax.fori_loop(0, slab.shape[1], one, slab)
+
+
+def _flush(slab, new, pos0, plan):
+    """A chunk's columns ``new [L, steps, B, KV, dh]`` into ``slab``, in
+    place.  Lowered for a TPU, with a cache of whole 128-position tiles
+    (``plan``: :func:`ray_tpu.ops.attention.cache_flush_plan`), the Pallas
+    kernel that visits only the slots that decoded and only the tiles their
+    columns fall in; anywhere else the slice update of every slot.  Decided
+    as :func:`_cache_scores` is, never by a flag."""
+    if plan is None:
+        return _flush_slices(slab, new, pos0)
+    return lax.platform_dependent(
+        slab, new, pos0, plan,
+        tpu=lambda slab, new, pos0, plan: cache_flush(slab, new, plan),
+        default=lambda slab, new, pos0, plan: _flush_slices(slab, new, pos0))
+
+
 def _ring_of(t, lengths, ring: int):
     """A window layer's prompt keys or values ``[L, B, KV, Tp, dh]`` -> what
     its ring holds of them, ``[L, B, KV, dh, ring]`` (:func:`_ring_holds`;
@@ -414,12 +462,15 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     No step writes into the cache (module docstring): the new K/V columns
     go to a chunk-local buffer ``[L, steps, B, KV, dh]``, attention is over
     the cache below ``pos0`` plus that buffer (:func:`_decode_attend`), and
-    after the last step each slot's columns are flushed to ``pos0[b]`` with
-    one ``dynamic_update_slice`` per tensor, in place.  (On the v5e the
-    GPT-2 XL step, 17 rows over 896 positions, fell from 19.74 to 13.16 ms:
-    ``serve-gpt2-xl-chat`` ``model.decode_step_ms``, ledger, PRs 25 and
-    28.)  What a slot attends of the cache is fixed when the chunk begins
-    (``live``), and so is the kernel's work list, built once here."""
+    after the last step the columns are flushed to ``pos0[b]``, in place, a
+    tensor at a time (:func:`_flush`): lowered for a TPU with a cache of
+    whole tiles by the kernel that merges them into the one or two tiles
+    they fall in, of the slots that were active when the chunk began only;
+    elsewhere by a slice update a slot.  What a slot attends of the cache is
+    fixed when the chunk begins (``live``), and so are both kernels' work
+    lists, built once here.  The step is ``serve-gpt2-xl-chat``'s
+    ``model.decode_step_ms``, the flush the ``cache_flush`` row of its
+    ``breakdown.device_ops``."""
     fam = family_of(cfg)
     B = tokens.shape[0]
     # what a full layer caches a position: k and v, or one latent row whose
@@ -430,9 +481,10 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     S = old[0].shape[-1]
     windows = layer_windows(cfg)
     window, ring = max(windows), cache.get("k_ring", old[0]).shape[-1]
-    # a dynamic_update_slice clamps silently: the flush of a slot at pos0
-    # needs pos0 + steps <= S (the engine's bucket + max_new + chunk); a
-    # ring's, steps <= window + 1 (ring_positions)
+    # the flush holds a start to S - steps silently (a dynamic_update_slice
+    # clamps, and the kernel's plan does as it does): a slot at pos0 needs
+    # pos0 + steps <= S (the engine's bucket + max_new + chunk); a ring's
+    # flush, steps <= window + 1 (ring_positions)
     assert steps <= S and (not window or steps <= window + 1), (steps, S, window)
     if steps == 0:
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
@@ -440,9 +492,13 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # what a slot attends of the cache, fixed for the chunk: the positions
     # below where it stood, nothing for a slot that sits the chunk out
     live = jnp.where(active, pos0, 0)
-    plan = None
+    # the kernels' work lists, functions of who is active and where alone:
+    # built once here, for every layer, step and tensor
+    plan = to_flush = None
     if S % DECODE_TILE == 0 and old[0].shape[3] % 8 == 0:
         plan = ragged_decode_plan(live, S // DECODE_TILE)
+        if steps <= DECODE_TILE:
+            to_flush = cache_flush_plan(active, pos0, steps, S)
     local = jnp.zeros(  # [L, steps, B, KV, dh]
         (cfg.n_layers, steps, B, *old[0].shape[2:4]), old[0].dtype)
     # a latent family's block in its decode form (the family's docstring)
@@ -519,19 +575,9 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # the chunk's columns of the layers of one kind (a family of one kind: all)
     of = lambda loc, kind: loc if not window else loc[  # noqa: E731
         jnp.asarray([l for l, w in enumerate(windows) if bool(w) == kind])]
-    news = tuple(of(loc, False) for loc in locs)
-
-    def flush(b, bigs):
-        # slot b's columns of the chunk, as [L, 1, KV, dh, steps], to pos0[b]
-        col = lambda loc: jnp.transpose(
-            lax.dynamic_index_in_dim(loc, b, 2, keepdims=False),
-            (0, 2, 3, 1))[:, None]
-        return tuple(
-            lax.dynamic_update_slice(big, col(loc), (0, b, 0, 0, pos0[b]))
-            for big, loc in zip(bigs, news))
-
     out = {**cache, "pos": pos}
-    out.update(zip(names, lax.fori_loop(0, B, flush, old)))
+    for name, big, loc in zip(names, old, locs):
+        out[name] = _flush(big, of(loc, False), pos0, to_flush)
     if window:
         # the rings, once a chunk and whole: column t of slot b goes to entry
         # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other

@@ -47,6 +47,13 @@ Not in the dispatch:
   online, row-wise softmax.  Off the TPU and for a cache that is not whole
   tiles: :func:`latent_slab_attention`, the masked einsums over the slab,
   which the tests hold the kernel to.
+- :func:`cache_flush` — not attention, but the other kernel over the same
+  cache: a decode chunk's new columns merged into the one or two
+  128-position tiles they fall in, of the slots that decoded only, the slab
+  left in HBM and updated in place.  ``models/generate.py`` ends a chunk
+  with it where the decode program is lowered for a TPU with a cache of
+  whole tiles; elsewhere a ``dynamic_update_slice`` a slot
+  (``generate._flush_slices``), which the tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -842,6 +849,172 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q.astype(c.dtype), c)
     return acc, m[..., 0], d[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# The decode chunk's flush: a chunk's new columns merged into the tiles of the
+# slots that decoded
+# ---------------------------------------------------------------------------
+
+# tiles in flight: while one is merged the next is on its way in and the one
+# before on its way back
+FLUSH_BUFFERS = 3
+
+
+def cache_flush_plan(active: jax.Array, pos0: jax.Array, steps: int,
+                     n_positions: int) -> jax.Array:
+    """:func:`cache_flush`'s work list as ONE int32 vector: ``[count,
+    start[0..B), slot[0..W), tile[0..W)]`` with ``W = 2 * B``.  ``start[b]``
+    is where slot ``b``'s ``steps`` columns go, ``pos0[b]`` (held to
+    ``n_positions - steps``, as a ``dynamic_update_slice`` holds it); items
+    ``w < count`` are the ``(slot, tile)`` pairs that the columns of an
+    ``active`` slot fall in, one tile or, where they cross a 128-position
+    boundary, two, in slot order; the rest are never read.  A function of
+    ``active`` and ``pos0`` alone: built once a chunk for every tensor."""
+    B, T = pos0.shape[0], DECODE_TILE
+    assert steps <= T, steps
+    start = jnp.clip(pos0.astype(jnp.int32), 0, n_positions - steps)
+    tiles = active.astype(jnp.int32) * (1 + (start % T > T - steps))
+    ends = jnp.cumsum(tiles)
+    w = jnp.arange(2 * B, dtype=jnp.int32)
+    # item w is of the first slot whose items end beyond it
+    slot = jnp.minimum((w[:, None] >= ends[None, :]).sum(1), B - 1)
+    tile = start[slot] // T + w - (ends - tiles)[slot]
+    return jnp.concatenate([ends[-1:], start, slot, tile]).astype(jnp.int32)
+
+
+def _cache_flush_kernel(plan_ref, cols_hbm, slab_hbm, out_hbm, tile_buf,
+                        cols_buf, sem, *, steps: int):
+    """One invocation walks :func:`cache_flush_plan`'s list, and under every
+    item the layers: a unit of work is one 128-position tile ``[KV, dh, 128]``
+    of one slot of one layer.  It is copied in with the slot's ``steps`` new
+    columns of that layer ``[steps, KV * dh]``, the columns are placed at
+    their lanes by a 0/1 matrix ``[steps, 128]`` on the MXU (exact: one term a
+    sum, and the contraction over the columns' leading axis is the transpose
+    the cache's layout asks for), every other lane keeps what it held, and the
+    tile is copied back to where it came from: ``slab_hbm`` and ``out_hbm``
+    are one buffer.  ``FLUSH_BUFFERS`` units are in flight: the next one's
+    copies in and the last one's copy back run under this one's merge.  Both
+    loops are rolled.  No unit is visited twice, so no copy waits for another
+    unit's."""
+    T, N = DECODE_TILE, FLUSH_BUFFERS
+    n_layers, n_slots = slab_hbm.shape[:2]
+    kv_heads, dh = tile_buf.shape[1:3]
+    count = plan_ref[0]
+    total = count * n_layers
+
+    def unit_of(j):
+        """Unit ``j``: ``(layer, slot, tile)``, the layers under an item."""
+        w = j // n_layers
+        return (j % n_layers, plan_ref[1 + n_slots + w],
+                plan_ref[1 + 3 * n_slots + w])
+
+    def copies(j, back: bool):
+        """Unit ``j``'s copies: in (its tile, its columns), or back."""
+        (layer, b, t), buf = unit_of(j), j % N
+        at = (layer, b, slice(None), slice(None),
+              pl.ds(pl.multiple_of(t * T, T), T))
+        if back:
+            return [pltpu.make_async_copy(
+                tile_buf.at[buf], out_hbm.at[at], sem.at[2, buf])]
+        return [
+            pltpu.make_async_copy(slab_hbm.at[at], tile_buf.at[buf],
+                                  sem.at[0, buf]),
+            pltpu.make_async_copy(cols_hbm.at[layer, b], cols_buf.at[buf],
+                                  sem.at[1, buf])]
+
+    @pl.when(total > 0)
+    def _():
+        for c in copies(0, False):
+            c.start()
+
+    def unit(j, _):
+        buf = j % N
+
+        @pl.when(j + 1 < total)
+        def _():
+            @pl.when(j + 1 >= N)
+            def _():  # the buffer's last tenant has to be back in the cache
+                for c in copies(j + 1 - N, True):
+                    c.wait()
+
+            for c in copies(j + 1, False):
+                c.start()
+
+        for c in copies(j, False):
+            c.wait()
+        _, b, t = unit_of(j)
+        # column i of the chunk lands on lane start + i of this tile, if there
+        first = plan_ref[1 + b] - t * T
+        lane = lax.broadcasted_iota(jnp.int32, (steps, T), 1) - first
+        step = lax.broadcasted_iota(jnp.int32, (steps, T), 0)
+        cols = cols_buf[buf]                # [steps, KV * dh and padding]
+        placed = lax.dot_general(
+            cols, (lane == step).astype(cols.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=(lax.Precision.HIGHEST if cols.dtype == jnp.float32
+                       else None))[:kv_heads * dh]          # [KV * dh, 128]
+        new = (lane[:1] >= 0) & (lane[:1] < steps)          # [1, 128]
+        tile_buf[buf] = jnp.where(
+            new[None], placed.reshape(kv_heads, dh, T).astype(tile_buf.dtype),
+            tile_buf[buf])
+        for c in copies(j, True):
+            c.start()
+
+    lax.fori_loop(0, total, unit, None)
+
+    def settle(j, _):  # the last units' copies back
+        for c in copies(j, True):
+            c.wait()
+
+    lax.fori_loop(jnp.maximum(total - N, 0), total, settle, None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def cache_flush(slab: jax.Array, new: jax.Array, plan: jax.Array, *,
+                interpret=False) -> jax.Array:
+    """A decode chunk's columns into the cache, touching only what they
+    change: ``new [L, steps, B, KV, dh]`` (the chunk-local buffer of one
+    cached tensor) into ``slab [L, B, KV, dh, S]``, slot ``b``'s ``steps``
+    columns at positions ``start[b] ..`` for the slots ``plan`` lists
+    (:func:`cache_flush_plan`: those active when the chunk began).  The slab
+    stays in HBM and is updated IN PLACE (the result aliases it): the kernel
+    copies in the one or two 128-position tiles a listed slot's columns fall
+    in, a layer at a time, merges the columns at their lanes and copies the
+    tile back.  A slot that is not listed is not visited: no byte of its row
+    moves.  Positions are on the lanes, so what a ``dynamic_update_slice`` of
+    ``steps`` columns at an arbitrary lane pays in masked partial stores, a
+    row of the slab at a time, is here two copies of a tile (the
+    ``cache_flush`` row of a traced run's ``breakdown.device_ops``; PERF.md
+    section 6, PR 38).  Needs ``S % 128 == 0``, ``dh % 8 == 0`` and ``steps
+    <= 128``."""
+    L, steps, B, KV, dh = new.shape
+    S, T = slab.shape[-1], DECODE_TILE
+    assert slab.shape == (L, B, KV, dh, S) and new.dtype == slab.dtype
+    assert S % T == 0 and dh % 8 == 0 and steps <= min(S, T), (S, dh, steps)
+    # a slot's columns of a layer as one block, values on whole lane tiles
+    cols = jnp.transpose(new, (0, 2, 1, 3, 4)).reshape(L, B, steps, KV * dh)
+    cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, -(KV * dh) % T),))
+    return pl.pallas_call(
+        functools.partial(_cache_flush_kernel, steps=steps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((FLUSH_BUFFERS, KV, dh, T), slab.dtype),
+                pltpu.VMEM((FLUSH_BUFFERS, *cols.shape[2:]), new.dtype),
+                pltpu.SemaphoreType.DMA((3, FLUSH_BUFFERS)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(slab.shape, slab.dtype),
+        # operands: plan, cols, slab -> the slab is the result
+        input_output_aliases={2: 0},
+        name="cache_flush",
+        interpret=interpret,
+    )(plan, cols, slab)
 
 
 def attention(

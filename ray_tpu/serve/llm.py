@@ -346,7 +346,8 @@ class GenerationEngine:
         self._max_len = cache_positions(
             self.buckets[-1], max_new_tokens, decode_chunk_steps)
         # cumulative: the cache tiles the dispatched decode chunks read a
-        # layer a step, and the tiles of the padded slab they did not have to
+        # layer a step and the tiles their flushes wrote a layer a tensor,
+        # and the tiles of the padded slab they did not have to
         from ray_tpu.ops.attention import DECODE_TILE
 
         self._tile = DECODE_TILE
@@ -357,7 +358,8 @@ class GenerationEngine:
         # window layer every row's whole ring (two tiles at the published
         # window), whatever the context
         self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
-        self._cache_tiles = {"read_full": 0, "read_window": 0, "padded": 0}
+        self._cache_tiles = {"read_full": 0, "read_window": 0, "padded": 0,
+                             "flushed": 0}
         # cumulative, by prefill bucket: calls, the rows and tokens they were
         # wide, and the prompts and prompt tokens among those
         self._prefill = {b: {"calls": 0, "rows": 0, "padded_tokens": 0,
@@ -567,8 +569,10 @@ class GenerationEngine:
             "stages": stages,
             # how much of the padded cache the decode steps read, in tiles a
             # layer of each kind (cumulative; counted at dispatch from prompt
-            # lengths and scheduled tokens), how many layers of each kind (a
-            # latent layer is a full one) and the bytes of one tile of each
+            # lengths and scheduled tokens), and how much of it a chunk's
+            # flush writes (``flushed``: tiles a full layer a tensor, against
+            # ``padded``); how many layers of each kind (a latent layer is a
+            # full one) and the bytes of one tile of each
             "cache_tiles": cache_tiles,
             # what the prefill calls were wide and what of it was prompt
             # (cumulative, by bucket)
@@ -767,9 +771,15 @@ class GenerationEngine:
                 # a slot stands at prompt + scheduled - 1 (the last sampled
                 # token is not in the cache yet); EOS, which only the
                 # device has seen, can only leave it lower
+                stands = [len(req.tokens) + req.scheduled - 1 for _, req in rows]
                 self._cache_tiles["read_full"] += sum(
-                    -(-(len(req.tokens) + req.scheduled - 1) // self._tile)
-                    for _, req in rows)
+                    -(-n // self._tile) for n in stands)
+                # the chunk's flush writes, a full layer a tensor, the tile
+                # a dispatched row's columns fall in, and the next where
+                # they cross into it; a row that sits the chunk out, none
+                self._cache_tiles["flushed"] += sum(
+                    1 + (n % self._tile > self._tile - self.chunk)
+                    for n in stands)
                 if self._layers["window"]:
                     self._cache_tiles["read_window"] += (
                         (self.n_slots + 1) * self._ring_tiles)

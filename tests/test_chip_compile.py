@@ -262,6 +262,16 @@ PROGRAMS = {
 }
 
 
+# the three serve cells' decode programs: cached tensors a full layer (one
+# flush kernel each), the slab's type, and the temporaries the PARENT of
+# PR 38 planned for the program (the flush as slice updates)
+FLUSHED_IN_PLACE = {
+    "serve_engine_gpt2_xl_cell": (2, "bf16[48,17,25,64,896]", 1_408_124_416),
+    "serve_engine_exaone_cell": (2, "bf16[1,33,8,128,4736]", 668_006_400),
+    "serve_engine_kimi_cell": (1, "bf16[6,33,1,576,9344]", 258_276_352),
+}
+
+
 @pytest.fixture(scope="module")
 def compiled(chip):
     """Every program, lowered here and compiled side by side (the compiler
@@ -356,6 +366,24 @@ def test_program_compiles_for_v5e(compiled, name):
         aliased = re.search(r"input_output_alias=\{(.*?) \}, entry", text).group(1)
         for out_index, arg in enumerate(range(n_params, n_params + n_cache), start=1):
             assert f"{{{out_index}}}: ({arg}, {{}}, may-alias)" in aliased, aliased
+    if name in FLUSHED_IN_PLACE:
+        # the chunk's flush reaches the chip's compiler as the kernel, by
+        # name, once a cached tensor; no update of slab size is left beside
+        # it; and the slab is merged where it lies: a copy of it (2.34 GB,
+        # 0.32 GB, 2.13 GB) would show in the temporaries, which stay at
+        # what the slice updates planned (sandbox compiles of PR 38's parent)
+        tensors, slab, parent_temp = FLUSHED_IN_PLACE[name]
+        text = programs[1].as_text()
+        flushes = [line for line in text.splitlines()
+                   if re.search(r"%cache_flush[.\d]* = ", line)]
+        assert len(flushes) == tensors, flushes
+        for line in flushes:
+            assert f"= {slab}{{" in line and "tpu_custom_call" in line
+            assert "output_to_operand_aliasing={{}: (2, {})}" in line
+        for line in text.splitlines():
+            result = line.split("dynamic-update-slice(")[0]
+            assert result == line or f" {slab}{{" not in result, line[:300]
+        assert programs[1].memory_analysis().temp_size_in_bytes < parent_temp + 2**20
     if name == "serve_engine_gpt2_xl_cell":
         # the layout cliff (ISSUE 28; the cell's `assumed` has the same one
         # at 784 positions): a cache the compiler re-lays-out costs 8-12 GB

@@ -1,13 +1,19 @@
-"""The ragged decode-attention kernel against the masked einsums it replaces.
+"""The decode chunk's kernels against what they replace: the ragged
+decode-attention kernel against the masked einsums, the flush kernel against
+the slice updates.
 
 ``generate._cache_scores`` has two forms held equal here: the Pallas kernel
 that copies in only a slot's 128-position tiles below ``n[b]``
 (``ops.attention.ragged_decode_attention``; what a program lowered for a TPU
 with a cache of whole tiles runs) and the einsums over the whole padded slab
 (``generate._cache_scores_slab``; what the CPU runs, and the plain
-reference).  The kernel runs here in the TPU interpreter.  To walk a whole
-``decode_chunk`` through it the test steers ``lax.platform_dependent`` to
-its ``tpu`` branch; the program has no option for that.
+reference).  ``generate._flush`` likewise: ``ops.attention.cache_flush``,
+which merges a chunk's columns into the one or two tiles they fall in, of
+the slots that decoded only, and ``generate._flush_slices``, a
+``dynamic_update_slice`` a slot.  The kernels run here in the TPU
+interpreter.  To walk a whole ``decode_chunk`` through them the test steers
+``lax.platform_dependent`` to its ``tpu`` branch; the program has no option
+for that.
 """
 
 import importlib
@@ -19,7 +25,7 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.models import generate as gen
-from test_generate import _Slots
+from test_generate import _Slots, lowered_for_tpu  # noqa: F401 (a fixture)
 
 attention = importlib.import_module("ray_tpu.ops.attention")
 
@@ -85,15 +91,57 @@ def test_kernel_matches_the_slab(shape, live, S):
     assert (np.asarray(slab[2])[dead] == 0).all()
 
 
-@pytest.fixture
-def lowered_for_tpu(monkeypatch):
-    """``lax.platform_dependent`` takes its ``tpu`` branch, and Pallas calls
-    run in the TPU interpreter: the decode program a chip would run, here."""
-    monkeypatch.setattr(
-        gen.lax, "platform_dependent",
-        lambda *args, tpu, default: tpu(*args))
-    with pltpu.force_tpu_interpret_mode():
-        yield
+SLABS = {  # KV heads, values a head: what a position of a full layer holds
+    "mha_heads_x_64": (5, 64),    # GPT-2 XL's 25 x 64, fewer heads
+    "gqa_8_x_128": (8, 128),      # K-EXAONE
+    "latent_1_x_576": (1, 576),   # Kimi-K2's one row a position
+}
+STEPS = 16
+FLUSHES = {
+    # (pos0, active, steps taken before an EOS) a slot; S = 384
+    "straddles_a_tile": ([120, 250, 5], [True, True, False], [16, 16, 0]),
+    "first_lane": ([128, 0, 256], [True, True, True], [16, 16, 16]),
+    "last_lane": ([127, 255, 40], [True, True, False], [16, 16, 0]),
+    "all_idle": ([120, 128, 300], [False] * 3, [0] * 3),
+    "one_live": ([77, 113, 300], [False, True, False], [0, 16, 0]),
+    "every_slot_live": ([3, 112, 113, 368], [True] * 4, [16] * 4),
+    "eos_mid_chunk": ([121, 60, 200], [True, True, False], [5, 16, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(FLUSHES))
+@pytest.mark.parametrize("slab", list(SLABS))
+def test_flush_kernel_matches_the_slice_updates(slab, case):
+    """Bit for bit: every position below ``pos`` of every slot that decoded
+    holds what the slice updates put there (all ``steps`` columns do, those
+    after a mid-chunk EOS too), and a slot that sat the chunk out keeps its
+    row as it was, every byte; the work list is the tiles the columns fall
+    in and nothing else."""
+    KV, dh = SLABS[slab]
+    pos0, active, taken = (np.asarray(a) for a in FLUSHES[case])
+    L, B, S = 2, len(pos0), 384
+    keys = jax.random.split(jax.random.PRNGKey(KV + dh), 2)
+    old = jax.random.normal(keys[0], (L, B, KV, dh, S), jnp.bfloat16)
+    new = jax.random.normal(keys[1], (L, STEPS, B, KV, dh), jnp.bfloat16)
+    plan = attention.cache_flush_plan(
+        jnp.asarray(active), jnp.asarray(pos0, jnp.int32), STEPS, S)
+    count, start, slot, tile = np.split(np.asarray(plan), [1, 1 + B, 1 + 3 * B])
+    want = [(b, t) for b in range(B) if active[b]
+            for t in range(pos0[b] // 128, (pos0[b] + STEPS - 1) // 128 + 1)]
+    assert int(count[0]) == len(want) and list(start) == list(pos0)
+    assert list(zip(slot[:len(want)], tile[:len(want)])) == want
+
+    bits = lambda a: np.asarray(a).view(np.uint16)  # noqa: E731
+    got = bits(attention.cache_flush(
+        old, new, plan, interpret=pltpu.InterpretParams()))
+    ref = bits(gen._flush_slices(old, new, jnp.asarray(pos0, jnp.int32)))
+    for b in range(B):
+        if active[b]:
+            pos = pos0[b] + taken[b]
+            assert (got[:, b, ..., :pos] == ref[:, b, ..., :pos]).all(), b
+            assert (got[:, b] == ref[:, b]).all(), b  # the columns beyond too
+        else:
+            assert (got[:, b] == bits(old)[:, b]).all(), b
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe", "kimi_k2"])
@@ -103,12 +151,18 @@ def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
     of a cache of two tiles: token for token the full forward's greedy
     answer (which ``test_generate`` holds the slab path to as well).  A
     latent family (``kimi_k2``) walks the same list through its own kernel,
-    ``ragged_latent_decode_attention``."""
+    ``ragged_latent_decode_attention``.  Every chunk ends in the flush
+    kernel, which leaves the rows of the slots that sat it out as they
+    were."""
     eng = _Slots(family, 5, 256, max_seq_len=256)
     rng = np.random.default_rng(0)
     eng.admit(0, [int(t) for t in rng.integers(1, 200, size=123)], 128)
     eng.admit(2, [9, 4, 7, 2, 5], 8)   # one tile, mostly masked
+    names = gen.cached_tensors(eng.cfg)
+    idle = {n: np.asarray(eng.cache[n][:, [1, 3, 4]]) for n in names}
     eng.decode(8)                      # slot 0 crosses position 128
+    for n in names:  # the idle slots' and the scratch slot's rows: untouched
+        assert (np.asarray(eng.cache[n][:, [1, 3, 4]]) == idle[n]).all(), n
     eng.admit(3, [int(t) for t in rng.integers(1, 200, size=128)], 128)
     eng.decode(8)                      # slot 3 starts on the boundary
     eng.decode(8)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.models import generate as gen
 from ray_tpu.models import gpt2, llama
@@ -175,14 +176,38 @@ class _Slots:
             int(t) for t in jnp.argmax(logits[0, len(prompt) - 1:], -1)], slot
 
 
+@pytest.fixture
+def lowered_for_tpu(monkeypatch):
+    """``lax.platform_dependent`` takes its ``tpu`` branch, and Pallas calls
+    run in the TPU interpreter: the decode program a chip would run, here."""
+    monkeypatch.setattr(
+        gen.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture(params=["cpu", "lowered_for_tpu"])
+def positions(request):
+    """The chunk cases below run twice: as the CPU runs them (masked einsums
+    over the slab, a slice update a slot) and as a chip does (the ragged read
+    and the flush kernel, through ``lowered_for_tpu``).  Gives the cache
+    length a case asks for as the path needs it: the kernels are chosen for
+    a cache of whole 128-position tiles, as the engine's always is."""
+    if request.param == "cpu":
+        return lambda n: n
+    request.getfixturevalue("lowered_for_tpu")
+    return lambda n: -(-n // 128) * 128
+
+
 @pytest.mark.parametrize("family", ["gpt2", "llama", "exaone_moe", "kimi_k2"])
 @pytest.mark.parametrize("chunks", [2, 3])
-def test_chunked_slots_at_different_positions(family, chunks):
+def test_chunked_slots_at_different_positions(family, chunks, positions):
     """Slots at different positions in one batch, an idle slot and the
     scratch slot beside them, over two and three consecutive chunks: every
     slot's tokens equal the full forward's (the flush of chunk n is what
     chunk n+1 attends)."""
-    eng = _Slots(family, 5, 8 + 3 * 4)
+    eng = _Slots(family, 5, positions(8 + 3 * 4))
     prompts = {0: [3, 17, 5], 1: [9, 4, 7, 2, 5, 11, 6, 8], 3: [12, 1, 6, 3, 9]}
     for slot, prompt in prompts.items():
         eng.admit(slot, prompt, 8)
@@ -195,11 +220,11 @@ def test_chunked_slots_at_different_positions(family, chunks):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
-def test_slot_admitted_between_chunks(family):
+def test_slot_admitted_between_chunks(family, positions):
     """A slot that joins while another is mid-answer (the engine admits
     between chunks): the newcomer's prefill does not disturb the columns
     the other slot flushed, and both match the full forward."""
-    eng = _Slots(family, 3, 8 + 12)
+    eng = _Slots(family, 3, positions(8 + 12))
     a, b = [5, 9, 2, 14], [7, 1, 4, 8, 3, 6]
     eng.admit(0, a, 8)
     eng.decode(4)
@@ -211,23 +236,25 @@ def test_slot_admitted_between_chunks(family):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
-def test_eos_mid_chunk_then_slot_reused(family):
+def test_eos_mid_chunk_then_slot_reused(family, positions):
     """EOS in the middle of a chunk freezes the slot's ``pos``; the columns
     it flushed after that lie beyond ``pos``.  Re-prefilled with a SHORTER
     prompt and decoded again, the slot must not attend them."""
-    probe = _Slots(family, 2, 8 + 12)
+    probe = _Slots(family, 2, positions(8 + 12))
     first = [3, 17, 5, 9, 2, 11, 4]
     probe.admit(0, first, 8)
     free_run = probe.decode(6)[0]
     eos = int(free_run[2])  # the 4th token of the answer ends it
     assert eos not in [probe.out[0][0]] + [int(t) for t in free_run[:2]]
 
-    eng = _Slots(family, 2, 8 + 12, eos_id=eos)
+    eng = _Slots(family, 2, positions(8 + 12), eos_id=eos)
     eng.admit(0, first, 8)
     row = eng.decode(6)[0]
     assert [int(t) for t in row] == [int(t) for t in free_run[:3]] + [eos] * 3
     assert int(eng.cache["pos"][0]) == len(first) + 3 and not eng.active[0]
-    eng.decode(6)  # an idle chunk: the frozen slot flushes garbage again
+    # an idle chunk: on the CPU the frozen slot flushes garbage again, at
+    # its frozen pos; the flush kernel does not visit it
+    eng.decode(6)
     assert int(eng.cache["pos"][0]) == len(first) + 3
 
     second = [6, 2]
@@ -239,11 +266,11 @@ def test_eos_mid_chunk_then_slot_reused(family):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
-def test_chunk_straddles_a_128_position_boundary(family):
+def test_chunk_straddles_a_128_position_boundary(family, positions):
     """A chunk whose ``pos0 .. pos0 + steps`` crosses position 128 (a lane
-    tile of the S-minor cache on the chip), next to a slot that ends its
-    chunk exactly on the boundary."""
-    eng = _Slots(family, 3, 160, max_seq_len=160)
+    tile of the S-minor cache on the chip: the flush kernel merges two
+    tiles), next to a slot that ends its chunk exactly on the boundary."""
+    eng = _Slots(family, 3, positions(160), max_seq_len=160)
     rng = np.random.default_rng(0)
     long_a = [int(t) for t in rng.integers(1, 200, size=123)]
     long_b = [int(t) for t in rng.integers(1, 200, size=120)]

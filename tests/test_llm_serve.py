@@ -91,7 +91,7 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
         prefill_buckets=(8, 160))
     assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
     assert eng.perf_stats()["cache_tiles"] == {
-        "read_full": 0, "read_window": 0, "padded": 0,
+        "read_full": 0, "read_window": 0, "padded": 0, "flushed": 0,
         "layers": {"full": cfg.n_layers, "window": 0},
         # k and v of every head, 128 positions, float32 here
         "tile_bytes": {"full": 2 * cfg.d_model * 128 * 4, "window": 0}}
@@ -116,6 +116,41 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
         assert a["read_full"] <= b["read_full"] and a["padded"] <= b["padded"]
     for p, f in zip(prompts, futs):
         assert f.result() == _one_shot(params, cfg, p, 8)
+
+
+@pytest.mark.parametrize("length,flushed", [
+    # chunks of 3 steps; the slot stands at length, length + 3, length + 6
+    pytest.param(3, [1, 1, 1], id="one_tile_a_dispatch"),
+    pytest.param(126, [2, 1, 1], id="first_chunk_crosses_128"),  # 126..128
+    pytest.param(125, [1, 1, 1], id="chunk_ends_on_the_boundary"),
+    pytest.param(123, [1, 2, 1], id="second_chunk_crosses_128"),
+    pytest.param(0, [], id="nothing_dispatched"),
+])
+def test_cache_tiles_counts_what_the_flushes_write(length, flushed):
+    """``perf_stats()["cache_tiles"]["flushed"]``: a dispatched row adds the
+    tile its chunk's columns fall in and, where they cross a 128-position
+    boundary, the next (what the flush kernel writes a full layer a tensor,
+    against ``padded``); a slot that sits the chunk out and a tick that
+    dispatches nothing add none."""
+    cfg = GPT2Config.tiny(dtype=jnp.float32, max_seq_len=256)
+    params = gpt2.init(cfg, jax.random.PRNGKey(2))
+    eng = GenerationEngine(  # never started: the test is the engine thread
+        cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
+        prefill_buckets=(8, 160))
+    seen, prompt = [], [1 + i % 50 for i in range(length)]
+    if not prompt:
+        assert eng.step() is False
+    else:
+        fut = eng.submit(prompt, 8)
+        while not fut.done():
+            before = eng.perf_stats()["cache_tiles"]
+            eng.step()
+            after = eng.perf_stats()["cache_tiles"]
+            if after["padded"] > before["padded"]:  # a chunk was dispatched
+                seen.append(after["flushed"] - before["flushed"])
+        assert fut.result() == _one_shot(params, cfg, prompt, 8)
+    assert seen == flushed
+    assert eng.perf_stats()["cache_tiles"]["flushed"] == sum(flushed)
 
 
 def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
