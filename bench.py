@@ -1633,7 +1633,12 @@ def _perf_observability_overhead() -> dict:
     M = 20000
     t0 = time.perf_counter()
     for i in range(M):
-        meter.record(0.01, 0.001 if i % 3 == 0 else 0.0, i % 3, 3)
+        # what a drain feeds it: a tick in three holds a prefill call
+        meter.begin(True)
+        if i % 3 == 0:
+            meter.call_landed(0.01 * i + 0.001)
+        meter.chunk_landed(0.01 * (i + 1), i % 3 == 0, 3)
+        meter.tick_host(0.001, 0.001, 0.001)
     meter_cost_s = (time.perf_counter() - t0) / M
     tick_pct = (100.0 * meter_cost_s / tick_wall_s) if tick_wall_s else 0.0
 

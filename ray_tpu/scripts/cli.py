@@ -797,14 +797,21 @@ def cmd_perf(args) -> None:
                 f"p99{_pct(itl, 'p99_est_s', 2)}")
         for eid, m in interference.items():
             billed = m.get("excess_billed_to_prefill")
-            billed_s = (f"{billed * 100:.0f}% of tick excess billed to "
-                        f"prefill" if billed is not None
+            billed_s = (f"{billed * 100:.0f}% of what interleaved ticks "
+                        f"took above decode-only ones" if billed is not None
                         else "excess share n/a: no decode-only baseline")
             out.append(
-                f"  {eid}: interference {m.get('interference_s', 0):.3f}s "
-                f"({(m.get('interference_frac') or 0) * 100:.1f}% of "
-                f"decode tick time; {billed_s}) over "
-                f"{m.get('interleaved_ticks')} interleaved ticks")
+                f"  {eid}: prefill calls took {m.get('interference_s', 0):.3f}s "
+                f"({(m.get('interference_frac') or 0) * 100:.1f}% of the "
+                f"device time of ticks with a request decoding; {billed_s}) "
+                f"over {m.get('interleaved_ticks')} interleaved ticks")
+            if m.get("tpot_p95_s") is not None:
+                # per request (last - first token on the host) / (tokens - 1)
+                out.append(
+                    f"  {eid}: engine tpot "
+                    f"p50 {m['tpot_p50_s'] * 1e3:.2f}ms "
+                    f"p95 {m['tpot_p95_s'] * 1e3:.2f}ms over a decode tick "
+                    f"of {(m.get('baseline_s') or 0) * 1e3:.1f}ms")
     if not (st["count"] or comp or hbm or ttft or itl or interference):
         out.append("(no perf data recorded — run a StepProfiler-"
                    "instrumented train loop or serve LLM traffic; see "

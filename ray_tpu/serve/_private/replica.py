@@ -130,14 +130,18 @@ class ServeReplica:
         sid = uuid.uuid4().hex
         # the request's trace context: the generator's body first runs on
         # the stream thread, which adopts it (an engine request made there
-        # chains under this replica task), and the first ``next_chunks``
-        # reply with data closes the request's last stages under it
-        # (``stage_ctx``, cleared then)
+        # chains under this replica task); the first ``next_chunks`` reply
+        # with data closes the stages up to the first reply under it, the
+        # finishing one those of the stream (``stage_ctx``, cleared then).
+        # Clock reads: one at the first put, one when the producer ends
+        # (``ended_t``: the last put has just returned), one a reply that
+        # carries data; none a chunk
         ctx = tracing.current_context()
         state = {"q": queue_mod.Queue(maxsize=64), "done": False,
                  "error": None, "stop": threading.Event(),
                  "stage_ctx": ctx if ctx and "t_root" in ctx else None,
-                 "first_put_t": None}
+                 "first_put_t": None, "ended_t": None,
+                 "first_data_t": None, "last_data_t": None, "n_chunks": 0}
 
         def drain(it=iter(result.iterable)):
             token = tracing.adopt(ctx)
@@ -161,6 +165,7 @@ class ServeReplica:
             except Exception as e:  # noqa: BLE001 — surfaced to the proxy
                 state["error"] = f"{type(e).__name__}: {e}"
             finally:
+                state["ended_t"] = time.perf_counter()  # before ``done``
                 state["done"] = True
                 tracing.restore(token)
 
@@ -189,19 +194,46 @@ class ServeReplica:
         if finished:
             self.cancel_stream(sid)
         ctx = state["stage_ctx"]
-        if chunks and ctx is not None:
-            # the first reply that carries data: how long the first chunk
-            # lay in the queue waiting for this poll, and, on its own two
-            # clock reads, the whole way from ingress to here (the stage
-            # the others must add up to)
+        if ctx is not None and chunks:
+            now = time.perf_counter()
+            if state["first_data_t"] is None:
+                # the first reply that carries data: how long the first
+                # chunk lay in the queue waiting for this poll, and, on its
+                # own two clock reads, the whole way from ingress to here
+                # (the stage the others must add up to)
+                state["first_data_t"] = now
+                tracing.emit_stage(
+                    "serve.pickup", now - state["first_put_t"], ctx)
+                tracing.emit_stage(
+                    "serve.first_reply", tracing.since(ctx["t_root"]), ctx)
+            state["last_data_t"] = now
+            state["n_chunks"] += len(chunks)
+        if ctx is not None and finished:
             state["stage_ctx"] = None
-            tracing.emit_stage(
-                "serve.pickup",
-                time.perf_counter() - state["first_put_t"], ctx)
-            tracing.emit_stage(
-                "serve.first_reply", tracing.since(ctx["t_root"]), ctx)
+            if state["first_data_t"] is not None:
+                self._emit_stream_stages(state, ctx)
         return {"chunks": chunks, "done": finished,
                 "error": state["error"] if finished else None}
+
+    @staticmethod
+    def _emit_stream_stages(state: Dict[str, Any], ctx) -> None:
+        """The finishing reply of a stream that delivered data.  Both stages
+        end at the LAST reply that carried data (this one may be empty, a
+        poll later): how long the last chunk lay in the queue, and, on its
+        own two clock reads, first data reply -> last data reply, which the
+        request's other decode stages must add up to (``serve.llm.STAGES``);
+        the same over the gaps between the chunks delivered is folded as
+        the pace this replica gave the request."""
+        last, n = state["last_data_t"], state["n_chunks"]
+        ended = time.time() - (time.perf_counter() - last)
+        tracing.emit_stage(
+            "serve.last_pickup", max(0.0, last - state["ended_t"]), ctx,
+            ts=ended)
+        streamed = last - state["first_data_t"]
+        tracing.emit_stage(
+            "serve.stream", streamed, ctx, ts=ended, stream_chunks=n)
+        if n > 1:
+            tracing.fold("serve.stream_per_chunk", streamed / (n - 1))
 
     def cancel_stream(self, sid: str) -> bool:
         with self._streams_lock:

@@ -33,12 +33,26 @@ import numpy as np
 from ray_tpu._private import events as _events
 from ray_tpu.util import compile_cache, tracing
 
-# the stages of one served request, ingress to first reply, as phases of
-# the span aggregate (``perf_stats()["stages"]``); emitted here, in the
-# worker's task entry and in ``serve/_private/replica.py``
-STAGES = ("serve.route", "task.dispatch", "serve.submit", "engine.queue",
-          "engine.first_token", "engine.stream_yield", "serve.pickup",
-          "serve.first_reply")
+# the stages of one served request as phases of the span aggregate
+# (``perf_stats()["stages"]``); emitted here, in the worker's task entry and
+# in ``serve/_private/replica.py``.  Ingress to the first reply, which the
+# seven before it tile ...
+FIRST_REPLY_STAGES = (
+    "serve.route", "task.dispatch", "serve.submit", "engine.queue",
+    "engine.first_token", "engine.stream_yield", "serve.pickup",
+    "serve.first_reply")
+# ... and from the first token to the last reply, emitted once, when the
+# request ends: ``serve.stream`` = ``engine.decode`` + (``engine.last_yield``
+# + ``serve.last_pickup``) - (``engine.stream_yield`` + ``serve.pickup``), up
+# to the encode-and-put between a yield and its put
+DECODE_STAGES = ("engine.decode", "engine.last_yield", "serve.last_pickup",
+                 "serve.stream")
+STAGES = FIRST_REPLY_STAGES + DECODE_STAGES
+# folded only (no span in any tree): a request's ``engine.decode`` over its
+# tokens - 1 and its ``serve.stream`` over its chunks - 1, the client's
+# per-request pace after the first token taken at two depths inside the
+# program; a request of one token has no gap and folds nothing
+PER_GAP = ("engine.decode_per_token", "serve.stream_per_chunk")
 
 # Lazy engine metric singletons.
 _LLM_METRICS = None
@@ -91,26 +105,33 @@ def _llm_metrics():
                             0.1, 0.5, 1]),
             "interference": Counter(
                 "ray_tpu_llm_prefill_interference_s_total",
-                "decode-tick seconds billed to co-scheduled prefill "
-                "chunks (admission dispatch + tick excess over the "
-                "decode-only baseline)"),
+                "seconds of the periods between two chunk landings that "
+                "went to prefill calls while other slots were decoding"),
         }
     return _LLM_METRICS
 
 
 class _TickMeter:
-    """Prefill-interference meter: classifies every engine iteration as
-    ``decode_only`` / ``interleaved`` (a prefill admitted while other
-    slots were mid-decode) / ``prefill_only``, keeps an EMA of the
-    decode-only tick wall as the no-interference baseline, and bills
-    each interleaved tick's admission dispatch plus its excess over
-    that baseline to *prefill interference* — the number that says how
-    much of the decode tail the co-scheduled prefills explain.
+    """The engine's ticks on the clock the device keeps.  A tick's record is
+    made when it is DRAINED: between the previous landing (``np.asarray`` of
+    a chunk or a prefill call's first tokens returned) and its own the device
+    ran exactly that tick's prefill calls and its chunk, as long as the
+    look-ahead kept the device queue non-empty; a tick dispatched behind no
+    undrained one (the first after an idle engine) has no previous landing
+    and its period is left out.  The instant a tick's last prefill call
+    landed splits its period into a prefill part and a chunk part; where the
+    host looks late the call had landed earlier, so the prefill part is an
+    upper bound.  Periods are classed ``decode_only`` / ``interleaved`` (a
+    prefill call while other slots were mid-decode) / ``prefill_only``; an
+    interleaved period's prefill part is *prefill interference*: what the
+    requests that were decoding waited for other requests' prompts.
+
+    Also summed here: the engine thread's own time a tick (``host_s``) and,
+    over finished requests, what their decode spans held (``decode``).
 
     Owned by the engine thread (no locking needed on the hot path);
     ``snapshot()`` reads are torn-tolerant counters."""
 
-    EMA_ALPHA = 0.1
     EMIT_EVERY = 32  # interleaved ticks per flight-recorder event
 
     def __init__(self, entity_id: str):
@@ -118,23 +139,54 @@ class _TickMeter:
         self.ticks = {"decode_only": 0, "interleaved": 0, "prefill_only": 0}
         self.tick_s = {"decode_only": 0.0, "interleaved": 0.0,
                        "prefill_only": 0.0}
-        self.ema_decode_s: Optional[float] = None
         self.interference_s = 0.0
-        self.excess_s = 0.0  # total tick wall above the decode baseline
+        # seconds and count of EVERY prefill call that landed behind another
+        # landing (a left-out tick's second call too): a request's decode
+        # span differences them between its first and its last token
+        self.prefill_s = 0.0
+        self.prefill_calls = 0
+        self.host_s = {"admit": 0.0, "dispatch": 0.0, "drain_book": 0.0}
+        self.ticks_live = 0
+        self.decode = {"requests": 0, "gaps": 0, "chunk_steps_paid": 0,
+                       "span_s": 0.0, "prefill_s": 0.0}
+        self._landed: Optional[float] = None  # the previous landing
+        self._period_s = 0.0    # of the tick being drained, so far
+        self._counted = False   # it has a previous landing to start from
         self._since_emit = 0
 
-    def record(self, wall: float, admit_s: float, n_admitted: int,
-               n_rows: int) -> None:
-        if n_rows == 0 and n_admitted == 0:
-            return  # drain-only / idle iteration: nothing to attribute
-        if n_admitted and n_rows > n_admitted:
-            mode = "interleaved"
-        elif n_admitted:
-            mode = "prefill_only"
-        else:
+    def begin(self, chained: bool) -> None:
+        """The drain of one tick begins; ``chained``: it was dispatched
+        behind a tick not yet drained, whose landing its period starts at."""
+        self._counted = chained and self._landed is not None
+        if not self._counted:
+            self._landed = None
+        self._period_s = 0.0
+
+    def call_landed(self, t: float) -> None:
+        """One of the tick's prefill calls landed at ``t``."""
+        if self._landed is not None:
+            self._period_s += t - self._landed
+            self.prefill_s += t - self._landed
+            self.prefill_calls += 1
+        self._landed = t
+
+    def chunk_landed(self, t: float, n_admitted: int, n_rows: int) -> None:
+        """The tick's chunk landed at ``t``: its record is made
+        (``n_admitted`` of the ``n_rows`` it decoded were prefilled in it)."""
+        prefill_part = self._period_s
+        if self._landed is not None:
+            self._period_s += t - self._landed
+        self._landed = t
+        if not self._counted:
+            return
+        if not n_admitted:
             mode = "decode_only"
+        elif n_rows > n_admitted:
+            mode = "interleaved"
+        else:
+            mode = "prefill_only"
         self.ticks[mode] += 1
-        self.tick_s[mode] += wall
+        self.tick_s[mode] += self._period_s
         if sum(self.ticks.values()) % 256 == 1:
             # periodic HBM watermark from the serving process (the train
             # profiler samples per step; the engine samples per ~256
@@ -142,54 +194,68 @@ class _TickMeter:
             from ray_tpu.util import perf as _perf
 
             _perf.publish_device_memory()
-        if mode == "decode_only":
-            if self.ema_decode_s is None:
-                self.ema_decode_s = wall
-            else:
-                if self.ema_decode_s > 0:
-                    self.excess_s += max(0.0, wall - self.ema_decode_s)
-                self.ema_decode_s += self.EMA_ALPHA * (
-                    wall - self.ema_decode_s)
-        elif mode == "interleaved":
-            # the admission dispatch stalls every active decode slot for
-            # its whole span; any further excess over the decode-only
-            # baseline is the prefill compute crowding the chunk.  With
-            # NO baseline yet (a saturated engine can interleave every
-            # tick), only the admission span is billed and excess_s is
-            # left alone — otherwise excess would equal interference by
-            # construction and the billed share would read 100% as an
-            # artifact, in exactly the high-load regime that matters
-            interference = admit_s
-            if self.ema_decode_s is not None:
-                interference += max(0.0, wall - admit_s - self.ema_decode_s)
-                self.excess_s += max(0.0, wall - self.ema_decode_s)
-            self.interference_s += interference
-            _llm_metrics()["interference"].inc(interference)
+        if mode == "interleaved":
+            self.interference_s += prefill_part
+            _llm_metrics()["interference"].inc(prefill_part)
             self._since_emit += 1
             if self._since_emit >= self.EMIT_EVERY:
                 self.emit_event()
 
+    def tick_host(self, admit: float, dispatch: float,
+                  drain_book: float) -> None:
+        """What one tick that dispatched or drained anything cost the
+        engine thread, apart from blocking in the drain's reads."""
+        host = self.host_s
+        host["admit"] += admit
+        host["dispatch"] += dispatch
+        host["drain_book"] += drain_book
+        self.ticks_live += 1
+
+    def request_done(self, tokens: int, chunks: int, chunk_steps: int,
+                     span_s: float, prefill_s: float) -> None:
+        """A request ended: ``span_s`` from its first to its last token on
+        the host, ``chunks`` decode chunks gave it one, and ``prefill_s`` of
+        the span the device spent on other requests' prefill calls."""
+        tally = self.decode
+        tally["requests"] += 1
+        tally["gaps"] += tokens - 1
+        tally["chunk_steps_paid"] += chunks * chunk_steps
+        tally["span_s"] += span_s
+        tally["prefill_s"] += prefill_s
+
     def snapshot(self) -> Dict[str, Any]:
         decode_wall = self.tick_s["decode_only"] + self.tick_s["interleaved"]
+        n_decode = self.ticks["decode_only"]
+        baseline = self.tick_s["decode_only"] / n_decode if n_decode else None
+        # what the interleaved periods took above as many decode-only ones
+        excess = 0.0 if baseline is None else max(
+            0.0, self.tick_s["interleaved"]
+            - self.ticks["interleaved"] * baseline)
         return {
             "ticks": dict(self.ticks),
             "tick_s": {k: round(v, 6) for k, v in self.tick_s.items()},
+            # the mean decode-only period: over the chunk's steps, the
+            # decode step on the host's clock
             "decode_tick_baseline_s":
-                round(self.ema_decode_s, 6)
-                if self.ema_decode_s is not None else None,
+                round(baseline, 6) if baseline is not None else None,
             "interference_s": round(self.interference_s, 6),
             "interference_frac":
                 round(self.interference_s / decode_wall, 4)
                 if decode_wall > 0 else 0.0,
-            "tick_excess_s": round(self.excess_s, 6),
-            # the p99-excess attribution: what share of all decode-tick
-            # time above the baseline the co-scheduled prefills explain.
-            # None (not 0, not 1) until a decode-only baseline exists —
-            # an unmeasurable share must not masquerade as a measured one
+            "tick_excess_s": round(excess, 6),
+            # of what the interleaved periods took above the decode-only
+            # baseline, the share that was their prefill calls (all of it
+            # where they took no longer than their calls).  None (not 0,
+            # not 1) until a decode-only baseline exists — an unmeasurable
+            # share must not masquerade as a measured one
             "excess_billed_to_prefill":
-                round(min(1.0, self.interference_s / self.excess_s), 4)
-                if self.excess_s > 0 and self.ema_decode_s is not None
+                round(self.interference_s / excess, 4)
+                if excess > self.interference_s
+                else 1.0 if baseline is not None and self.interference_s > 0
                 else None,
+            "host_s": dict(self.host_s),
+            "ticks_live": self.ticks_live,
+            "decode": dict(self.decode),
         }
 
     def emit_event(self) -> None:
@@ -197,6 +263,9 @@ class _TickMeter:
         if not _events.ENABLED or self.ticks["interleaved"] == 0:
             return
         snap = self.snapshot()
+        # the engine's own reading of the judged pace, for `ray_tpu perf`
+        tpot = tracing.span_stats(("engine.decode_per_token",)).get(
+            "engine.decode_per_token") or {}
         _events.emit(
             "perf", "prefill interference", severity="DEBUG",
             entity_id=self.entity_id,
@@ -205,7 +274,8 @@ class _TickMeter:
             excess_billed_to_prefill=snap["excess_billed_to_prefill"],
             interleaved_ticks=self.ticks["interleaved"],
             decode_only_ticks=self.ticks["decode_only"],
-            baseline_s=snap["decode_tick_baseline_s"])
+            baseline_s=snap["decode_tick_baseline_s"],
+            tpot_p50_s=tpot.get("p50_s"), tpot_p95_s=tpot.get("p95_s"))
 
 
 def cache_positions(largest_bucket: int, max_new_tokens: int,
@@ -223,8 +293,8 @@ def cache_positions(largest_bucket: int, max_new_tokens: int,
 
 class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
-                 "submitted_at", "trace_ctx", "first_token_t",
-                 "last_emit_t", "dispatched_at", "first_host_t")
+                 "submitted_at", "trace_ctx", "dispatched_at",
+                 "first_host_t", "last_host_t", "chunks", "prefill_mark")
 
     def __init__(self, tokens: List[int], max_new: int):
         self.tokens = list(tokens)
@@ -236,16 +306,17 @@ class _Request:
         # so completion prediction must count scheduled, not emitted
         self.scheduled = 0
         self.submitted_at = time.perf_counter()
-        # decode-tail attribution: first-token landing time (TTFT) and
-        # the previous drain's timestamp (inter-token latency), both set
-        # on the engine thread at drain time
-        self.first_token_t: Optional[float] = None
-        self.last_emit_t: Optional[float] = None
-        # stage boundaries (perf_counter): prefill dispatched, and the
-        # first token really on the host (``first_token_t`` is stamped
-        # before the drain waits for it)
+        # stage boundaries (perf_counter), set on the engine thread with the
+        # observability layer on: prefill dispatched; the first token on the
+        # host (TTFT, and where ``engine.decode`` starts); the newest token
+        # on the host (inter-token latency; at the end, where it stops)
         self.dispatched_at: Optional[float] = None
         self.first_host_t: Optional[float] = None
+        self.last_host_t: Optional[float] = None
+        # decode chunks that gave it a token, and the tick meter's prefill
+        # seconds and calls when its first token landed
+        self.chunks = 0
+        self.prefill_mark = (0.0, 0)
         # the submitter's trace context (a traced serve replica): the
         # engine loop runs on its own thread, so the context is captured
         # HERE and the engine's spans are tagged with it.  A caller that
@@ -262,9 +333,9 @@ class _PendingChunk:
     (tokens already streaming host-ward via ``copy_to_host_async``) plus
     the host bookkeeping needed to route them when they land."""
 
-    __slots__ = ("chunk_dev", "rows", "prefills", "routed_dev")
+    __slots__ = ("chunk_dev", "rows", "prefills", "routed_dev", "chained")
 
-    def __init__(self, chunk_dev, rows, prefills, routed_dev):
+    def __init__(self, chunk_dev, rows, prefills, routed_dev, chained):
         self.chunk_dev = chunk_dev          # [n_slots+1, steps] device
         self.rows = rows                    # [(slot, _Request)] active in chunk
         # this iteration's prefill calls, in dispatch order: (admissions
@@ -273,6 +344,9 @@ class _PendingChunk:
         self.prefills = prefills
         # the chunk's routing counts (device or None): they land with the tokens
         self.routed_dev = routed_dev
+        # for the tick meter: the tick before this one was still undrained
+        # at dispatch (the device then runs this one straight after it)
+        self.chained = chained
 
 
 class GenerationEngine:
@@ -465,6 +539,7 @@ class GenerationEngine:
         serve's chunked responses).  Raises the request's error, if any."""
         req = self._submit_req(tokens, max_new)
         n = 0
+        yielded_t = None  # when the newest token of a poll was handed over
         deadline = time.perf_counter() + timeout
         while True:
             # read BEFORE the snapshot: the engine resolves the future
@@ -479,9 +554,20 @@ class GenerationEngine:
                         "engine.stream_yield",
                         time.perf_counter() - req.first_host_t,
                         req.trace_ctx)
+                if n == m - 1 and req.first_host_t is not None:
+                    yielded_t = time.perf_counter()  # one read a poll
                 yield emitted[n]
                 n += 1
             if done:
+                if yielded_t is not None:
+                    # ... and what it added to the last: emitted here, where
+                    # the request's stamps are final, whichever poll saw the
+                    # last token (the engine resolves the future a little
+                    # after appending it)
+                    tracing.emit_stage(
+                        "engine.last_yield", yielded_t - req.last_host_t,
+                        req.trace_ctx,
+                        ts=time.time() - (time.perf_counter() - yielded_t))
                 req.future.result()  # surface engine errors
                 return
             if time.perf_counter() > deadline:
@@ -540,11 +626,11 @@ class GenerationEngine:
 
     def perf_stats(self) -> Dict[str, Any]:
         """Decode-tail attribution: TTFT/ITL percentiles over the recent
-        sample reservoirs plus the prefill-interference meter — the
-        numbers that explain (rather than just report) the decode p99.
-        ``excess_billed_to_prefill`` is the share of all decode-tick
-        time above the decode-only baseline that co-scheduled prefill
-        chunks account for."""
+        sample reservoirs (both stamped where the tokens landed on the
+        host) plus the tick meter (:class:`_TickMeter`: the periods
+        between landings by class, the engine thread's time a tick, what
+        the finished requests' decode spans held) — the numbers that
+        explain (rather than just report) the decode tail."""
         import jax
 
         with self._lock:  # snapshot: the engine thread appends under
@@ -557,15 +643,16 @@ class GenerationEngine:
             moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
-            stages = tracing.span_stats(STAGES)
+            stages = tracing.span_stats(STAGES + PER_GAP)
             stages["clock_skew"] = tracing.clock_skew()
         return {
             "ttft": self._pctiles(ttft),
             "itl": self._pctiles(itl),
             **self._ticks.snapshot(),
-            # the request's stages from ingress to the first reply, as this
+            # the request's stages from ingress to the last reply, as this
             # process closed them (cumulative: difference two calls for a
-            # window); empty with the observability layer off
+            # window, and take that many off the end of ``recent`` for the
+            # window's own durations); empty with the observability layer off
             "stages": stages,
             # how much of the padded cache the decode steps read, in tiles a
             # layer of each kind (cumulative; counted at dispatch from prompt
@@ -622,8 +709,11 @@ class GenerationEngine:
                 self._last_tok_dev = jnp.zeros((self.n_slots + 1,), jnp.int32)
                 worked = False
             if not worked:
+                # ONE annotation an idle period, so that an idle gap of any
+                # length in a device trace is covered by one host event;
+                # ``submit`` and ``stop`` both set the event
                 with self._annotate("engine.wait_work"):
-                    self._work.wait(timeout=0.05)
+                    self._work.wait()
                 self._work.clear()
 
     def _bucket(self, n: int) -> int:
@@ -688,11 +778,12 @@ class GenerationEngine:
             slots[j] = slot
         if _events.ENABLED:
             t_dispatch = time.perf_counter()
+            hist = _llm_metrics()["admission"]
             for _, req in batch:
                 req.dispatched_at = t_dispatch
-                tracing.emit_stage(
-                    "engine.queue", t_dispatch - req.submitted_at,
-                    req.trace_ctx)
+                waited = t_dispatch - req.submitted_at
+                hist.observe(waited)
+                tracing.emit_stage("engine.queue", waited, req.trace_ctx)
         slots_dev = jnp.asarray(slots)
         last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
@@ -703,23 +794,6 @@ class GenerationEngine:
         _to_host_async(routed_dev)
         for _, req in batch:
             req.scheduled = 1  # the prefill's sampled first token
-        if _events.ENABLED:
-            now = time.perf_counter()
-            hist = _llm_metrics()["admission"]
-            worst = 0.0
-            for slot, req in batch:
-                waited = now - req.submitted_at
-                hist.observe(waited)
-                worst = max(worst, waited)
-            with self._lock:
-                queued_left = len(self._queue)
-            # slot-admission span: submit -> prefill dispatch (the decode
-            # tail the VERDICT's p99/p50 chase starts from)
-            _events.emit(
-                "serve_llm", f"admitted {len(batch)} prompt(s)",
-                severity="DEBUG", span_dur=worst,
-                slots=[s for s, _ in batch], bucket=b,
-                queued_left=queued_left)
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
         return admissions, firsts_dev, routed_dev
 
@@ -737,18 +811,19 @@ class GenerationEngine:
         4. drain chunk N-1 (its ``copy_to_host_async`` transfer has been
            streaming since last iteration), resolve finished futures
 
-        The drain of N-1 thus overlaps chunk N's device compute: steady
-        state pays max(compute, transfer) per chunk instead of their sum.
-        (The chunk length this was tuned with predates the directly
-        attached chip; it has not been re-measured.)"""
+        The drain of N-1 thus overlaps chunk N's device compute.  With the
+        observability layer on, the tick meter gets what this iteration
+        cost the engine thread (admit, dispatch, the drain's bookkeeping:
+        not the time blocked in its reads) and, from the drain, the record of
+        tick N-1: the period between two landings is that tick's device
+        time (:class:`_TickMeter`)."""
         import jax.numpy as jnp
 
         meter = self._ticks if _events.ENABLED else None
         t_tick0 = time.perf_counter() if meter else 0.0
         with self._annotate("engine.admit"):
             prefills = self._admit()
-        n_admitted = sum(len(admissions) for admissions, _, _ in prefills)
-        admit_s = (time.perf_counter() - t_tick0) if meter else 0.0
+        t_admitted = time.perf_counter() if meter else 0.0
         with self._lock:
             rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         dispatched = None
@@ -762,7 +837,9 @@ class GenerationEngine:
                     jnp.asarray(active), self._key)
                 chunk_dev.copy_to_host_async()
                 _to_host_async(chunk_routed)
-            dispatched = _PendingChunk(chunk_dev, rows, prefills, chunk_routed)
+            dispatched = _PendingChunk(
+                chunk_dev, rows, prefills, chunk_routed,
+                chained=self._pending is not None)
             # cap-based predicted completion: these slots are free for the
             # NEXT admission even though their token values haven't landed
             # (completion timing is deterministic; EOS only finishes a
@@ -789,20 +866,20 @@ class GenerationEngine:
                     req.scheduled = min(req.max_new, req.scheduled + self.chunk)
                     if req.scheduled >= req.max_new:
                         self._slots[i] = None
+        t_dispatched = time.perf_counter() if meter else 0.0
         prev, self._pending = self._pending, dispatched
+        waited = 0.0
         if prev is not None:
             self._draining = prev  # visible to _loop's error recovery
             with self._annotate("engine.drain"):
-                self._drain(prev)
+                waited = self._drain(prev, meter)
             self._draining = None
-        if meter is not None:
-            # the tick wall covers admit + decode dispatch + the drain
-            # of chunk N-1 (the device sync) — what a request actually
-            # waits per iteration
-            meter.record(time.perf_counter() - t_tick0,
-                         admit_s if n_admitted else 0.0,
-                         n_admitted, len(rows))
-        return dispatched is not None or prev is not None
+        worked = dispatched is not None or prev is not None
+        if meter is not None and worked:
+            meter.tick_host(
+                t_admitted - t_tick0, t_dispatched - t_admitted,
+                time.perf_counter() - t_dispatched - waited)
+        return worked
 
     def _count_routed(self, phase: str, counts) -> None:
         """Add one landed dispatch's routing counts (a prefill call's or a
@@ -817,38 +894,57 @@ class GenerationEngine:
             if phase == "decode":
                 self._routed["decode_steps"] += self.chunk
 
-    def _drain(self, pending: _PendingChunk) -> None:
+    def _read_back(self, dev) -> tuple:
+        """``np.asarray(dev)`` (blocks until the device has produced it), the
+        instant it returned, and the seconds it blocked; the wait carries
+        its own annotation, so that in a device trace a gap under
+        ``engine.drain`` and not under it is the drain's bookkeeping."""
+        t = time.perf_counter()
+        with self._annotate("engine.drain_wait"):
+            host = np.asarray(dev)
+        landed = time.perf_counter()
+        return host, landed, landed - t
+
+    def _drain(self, pending: _PendingChunk,
+               meter: Optional[_TickMeter] = None) -> float:
         """Materialize one landed chunk: route first tokens + chunk rows to
-        their requests, resolve futures, confirm EOS slot frees."""
-        instrumented = _events.ENABLED
-        drain_t = time.perf_counter()
+        their requests, resolve futures, confirm EOS slot frees.  With a
+        ``meter`` (the observability layer is on) every stamp is the instant
+        the tokens it times were on the host, and the tick's record is made;
+        returns the seconds blocked in the reads."""
+        waited = 0.0
+        if meter is not None:
+            meter.begin(pending.chained)
         for admissions, firsts_dev, routed_dev in pending.prefills:
-            firsts = np.asarray(firsts_dev)  # waits for this prefill call
-            first_host_t = time.perf_counter()
+            firsts, landed, blocked = self._read_back(firsts_dev)
+            waited += blocked
+            if meter is not None:
+                meter.call_landed(landed)
             for j, slot, req in admissions:
-                if instrumented and req.first_token_t is None:
-                    # TTFT: submit -> this drain's START (drain_t): the
-                    # wait for the prefill just above is not in it, so it
-                    # reads early by about a prefill.  The stage below ends
-                    # where the token really is on the host
-                    req.first_token_t = drain_t
-                    req.last_emit_t = drain_t
-                    ttft = drain_t - req.submitted_at
+                if meter is not None:
+                    req.first_host_t = req.last_host_t = landed
+                    req.prefill_mark = (meter.prefill_s, meter.prefill_calls)
+                    ttft = landed - req.submitted_at
                     _llm_metrics()["ttft"].observe(ttft)
                     with self._lock:  # perf_stats sorts a snapshot;
                         # an unlocked concurrent append would raise
                         # "deque mutated during iteration" there
                         self._ttft_samples.append(ttft)
                     if req.dispatched_at is not None:
-                        req.first_host_t = first_host_t
                         tracing.emit_stage(
                             "engine.first_token",
-                            first_host_t - req.dispatched_at, req.trace_ctx)
+                            landed - req.dispatched_at, req.trace_ctx)
                 # after the stamps: the stream thread reads them the
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
             self._count_routed("prefill", routed_dev)
-        chunk = np.asarray(pending.chunk_dev)  # transfer already in flight
+        # the transfer is already in flight
+        chunk, landed, blocked = self._read_back(pending.chunk_dev)
+        waited += blocked
+        if meter is not None:
+            meter.chunk_landed(
+                landed, sum(len(adm) for adm, _, _ in pending.prefills),
+                len(pending.rows))
         self._count_routed("decode", pending.routed_dev)
         for i, req in pending.rows:
             if req.future.done():
@@ -862,28 +958,17 @@ class GenerationEngine:
                         and req.emitted[-1] == self.eos_id):
                     break
                 req.emitted.append(int(t))
-            if instrumented:
-                k = len(req.emitted) - n_before
-                if k > 0:
-                    if req.first_token_t is None:
-                        req.first_token_t = drain_t
-                        ttft = drain_t - req.submitted_at
-                        _llm_metrics()["ttft"].observe(ttft)
-                        with self._lock:
-                            self._ttft_samples.append(ttft)
-                        k -= 1  # the first token is TTFT, not ITL
-                    if k > 0 and req.last_emit_t is not None \
-                            and req.last_emit_t != drain_t:
-                        # per-drain mean gap: chunked decode lands tokens
-                        # in bursts, so the steady-state per-token rate
-                        # is the burst interval over the burst size (a
-                        # same-drain first token never yields a 0s gap —
-                        # the equality guard skips it)
-                        itl = (drain_t - req.last_emit_t) / k
-                        _llm_metrics()["itl"].observe(itl)
-                        with self._lock:
-                            self._itl_samples.append(itl)
-                    req.last_emit_t = drain_t
+            k = len(req.emitted) - n_before
+            if k > 0 and req.last_host_t is not None:
+                # per-drain mean gap: chunked decode lands tokens in
+                # bursts, so the steady-state per-token rate is the burst
+                # interval (landing to landing) over the burst size
+                itl = (landed - req.last_host_t) / k
+                _llm_metrics()["itl"].observe(itl)
+                with self._lock:
+                    self._itl_samples.append(itl)
+                req.last_host_t = landed
+                req.chunks += 1
             done = len(req.emitted) >= req.max_new or (
                 self.eos_id is not None and req.emitted
                 and req.emitted[-1] == self.eos_id)
@@ -893,22 +978,41 @@ class GenerationEngine:
                         self._slots[i] = None  # freed by cap prediction
                 self.total_generated += len(req.emitted)
                 if _events.ENABLED:
-                    _llm_metrics()["tokens"].inc(len(req.emitted))
-                    tf = {}
-                    if req.trace_ctx is not None:
-                        # the whole submit->completion window as a span of
-                        # the submitter's trace
-                        tf = {"trace_id": req.trace_ctx["trace_id"],
-                              "span_id": req.trace_ctx["span_id"],
-                              "parent_span_id":
-                                  req.trace_ctx["parent_span_id"],
-                              "phase": "llm_generate"}
-                    _events.emit(
-                        "serve_llm", "request complete", severity="DEBUG",
-                        entity_id=str(i), tokens=len(req.emitted),
-                        span_dur=time.perf_counter() - req.submitted_at,
-                        **tf)
+                    self._emit_done(i, req, meter)
                 req.future.set_result(req.emitted)
+        return waited
+
+    def _emit_done(self, slot: int, req: _Request,
+                   meter: Optional[_TickMeter]) -> None:
+        """A request's once-only emissions when it ends: the ``request
+        complete`` event (the whole submit->completion window as a span of
+        the submitter's trace) and, under it, ``engine.decode``: first token
+        on the host -> last token on the host."""
+        n = len(req.emitted)
+        _llm_metrics()["tokens"].inc(n)
+        now = time.perf_counter()
+        if meter is not None and req.first_host_t is not None:
+            span = req.last_host_t - req.first_host_t
+            prefill_s = meter.prefill_s - req.prefill_mark[0]
+            meter.request_done(n, req.chunks, self.chunk, span, prefill_s)
+            tracing.emit_stage(
+                "engine.decode", span, req.trace_ctx,
+                ts=time.time() - (now - req.last_host_t), tokens=n,
+                chunks=req.chunks, chunk_steps=self.chunk,
+                prefill_calls=meter.prefill_calls - req.prefill_mark[1],
+                prefill_s=round(prefill_s, 6))
+            if n > 1:
+                tracing.fold("engine.decode_per_token", span / (n - 1))
+        tf = {}
+        if req.trace_ctx is not None:
+            tf = {"trace_id": req.trace_ctx["trace_id"],
+                  "span_id": req.trace_ctx["span_id"],
+                  "parent_span_id": req.trace_ctx["parent_span_id"],
+                  "phase": "llm_generate"}
+        _events.emit(
+            "serve_llm", "request complete", severity="DEBUG",
+            entity_id=str(slot), tokens=n,
+            span_dur=now - req.submitted_at, **tf)
 
 
 def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,

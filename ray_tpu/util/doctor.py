@@ -579,10 +579,11 @@ def _rule_ingest_bound(events, tasks):
 
 
 def _rule_prefill_interference(events, tasks):
-    """Decode ticks co-scheduled with prefill chunks run long — the
-    serve engine's tick meter bills that excess to the prefills.  A high
-    billed share IS the decode-tail explanation (gpt2 p99/p50=1.39x):
-    bound it with chunked prefill or an interleave budget."""
+    """Requests that are decoding wait for other requests' prefill calls
+    — the serve engine's tick meter bills the prefill part of every
+    interleaved period (landing to landing, the device's clock).  A high
+    share IS the decode-tail explanation: bound it with chunked prefill
+    or an interleave budget."""
     rows = _rows(events, "perf", "prefill interference")
     # latest meter state per (origin, engine): engine ids are per-process
     # (pids collide across hosts), so the shipping origin must qualify

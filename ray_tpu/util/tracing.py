@@ -83,6 +83,9 @@ _id_n = 0
 # are cumulative, so after-minus-before is exact for a window; the
 # reservoir (bounded like the engine's TTFT deque) backs the percentiles
 STATS_RESERVOIR = 4096
+# how many of the reservoir's newest durations a row hands out as they
+# closed (``recent``): what a reader needs for a percentile over a window
+STATS_RECENT = 512
 _stats_lock = threading.Lock()
 _stats: Dict[str, list] = {}
 # cross-process stages that came out negative (the sender's clock ahead of
@@ -261,13 +264,15 @@ def span_fields(ctx: Optional[Dict[str, str]], phase: str,
 
 def emit_span(name: str, dur_s: float, ctx: Optional[Dict[str, str]],
               phase: str = "span", severity: str = "DEBUG",
-              attributes: Optional[dict] = None, **data) -> None:
-    """Record one closed span [now - dur_s, now] in the flight recorder,
-    tagged with its trace lineage so the head's TraceTable can assemble
-    the tree.  No-op without a context or with the observability layer
-    disabled — callers can invoke it unconditionally.  User attribute
-    keys shadowing span/emit fields are prefixed ``attr_`` instead of
-    crashing or clobbering the lineage."""
+              attributes: Optional[dict] = None, ts: Optional[float] = None,
+              **data) -> None:
+    """Record one closed span [now - dur_s, now] in the flight recorder
+    (``[ts - dur_s, ts]`` for a span emitted after the fact: ``ts`` is its
+    wall-clock end), tagged with its trace lineage so the head's TraceTable
+    can assemble the tree.  No-op without a context or with the
+    observability layer disabled — callers can invoke it unconditionally.
+    User attribute keys shadowing span/emit fields are prefixed ``attr_``
+    instead of crashing or clobbering the lineage."""
     if ctx is None or not _events.ENABLED:
         return
     merged = dict(attributes or ())
@@ -278,7 +283,8 @@ def emit_span(name: str, dur_s: float, ctx: Optional[Dict[str, str]],
             for k, v in merged.items()}
     _events.emit(
         TRACE_SOURCE, name, severity=severity, entity_id=ctx["trace_id"],
-        span_dur=dur_s, trace_id=ctx["trace_id"], span_id=ctx["span_id"],
+        span_dur=dur_s, ts=ts, trace_id=ctx["trace_id"],
+        span_id=ctx["span_id"],
         parent_span_id=ctx.get("parent_span_id", ""), phase=phase, **safe)
     fold(phase, dur_s)
 
@@ -299,21 +305,26 @@ def fold(phase: str, dur_s: float) -> None:
 
 
 def span_stats(phases: Optional[Iterable[str]] = None) -> Dict[str, dict]:
-    """``{phase: {count, sum_s, p50_s, p95_s}}`` of the spans this process
-    closed (every phase seen, or those of ``phases`` that were).  ``count``
-    and ``sum_s`` are cumulative since the process started; the
-    percentiles are over the last ``STATS_RESERVOIR`` spans of the phase."""
+    """``{phase: {count, sum_s, p50_s, p95_s, recent}}`` of the spans this
+    process closed (every phase seen, or those of ``phases`` that were).
+    ``count`` and ``sum_s`` are cumulative since the process started; the
+    percentiles are over the last ``STATS_RESERVOIR`` spans of the phase;
+    ``recent`` is the newest ``STATS_RECENT`` of those durations in the
+    order they closed, so a reader that differences ``count`` over a window
+    takes that many off its end and has the window's own durations."""
     wanted = None if phases is None else set(phases)
     with _stats_lock:
         rows = {p: (r[0], r[1], list(r[2])) for p, r in _stats.items()
                 if wanted is None or p in wanted}
     out = {}
     for p, (count, sum_s, samples) in rows.items():
+        recent = samples[-STATS_RECENT:]
         samples.sort()
         n = len(samples)
         out[p] = {"count": count, "sum_s": sum_s,
                   "p50_s": samples[n // 2],
-                  "p95_s": samples[min(n - 1, int(n * 0.95))]}
+                  "p95_s": samples[min(n - 1, int(n * 0.95))],
+                  "recent": recent}
     return out
 
 
@@ -337,16 +348,18 @@ def clock_skew() -> int:
 
 
 def emit_stage(phase: str, dur_s: float,
-               parent: Optional[Dict[str, Any]], **data) -> None:
-    """One closed stage [now - dur_s, now], named by its phase, as a fresh
-    child span of ``parent``.  No-op without a parent context."""
+               parent: Optional[Dict[str, Any]], ts: Optional[float] = None,
+               **data) -> None:
+    """One closed stage [now - dur_s, now] (or ending at wall-clock ``ts``),
+    named by its phase, as a fresh child span of ``parent``.  No-op without
+    a parent context."""
     if parent is None or not _events.ENABLED:
         return
     ctx = {"trace_id": parent["trace_id"], "span_id": new_span_id(),
            "parent_span_id": parent["span_id"]}
     if parent.get("job"):
         ctx["job"] = parent["job"]
-    emit_span(phase, dur_s, ctx, phase=phase, **data)
+    emit_span(phase, dur_s, ctx, phase=phase, ts=ts, **data)
 
 
 def task_arrived(ctx: Dict[str, Any], now: float) -> Dict[str, Any]:

@@ -306,18 +306,26 @@ def test_events_flow_end_to_end(obs_cluster):
 
 
 def test_llm_engine_emits_slot_admission_events():
-    """The continuous-batching engine's slot admissions, interleave, and
-    completions land in the flight recorder (no cluster needed — the
-    engine runs in-process)."""
+    """The continuous-batching engine's slot admissions and completions
+    land in the flight recorder (no cluster needed — the engine runs
+    in-process): an ``engine.queue`` stage a request (submit -> its
+    prefill's dispatch), a ``request complete`` span a request."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt2
     from ray_tpu.serve.llm import GenerationEngine
+    from ray_tpu.util import metrics as mm
+
+    def admitted():
+        vals = mm.registry().snapshot().get(
+            "ray_tpu_llm_slot_admission_latency_s", {}).get("values", {})
+        return sum(h["count"] for h in vals.values())
 
     cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
     params = gpt2.init(cfg, jax.random.PRNGKey(0))
     before = events_mod.buffer().last_seq()
+    had = admitted()
     eng = GenerationEngine(cfg, params, n_slots=2, max_new_tokens=6,
                            decode_chunk_steps=3,
                            prefill_buckets=(8, 16)).start()
@@ -328,18 +336,22 @@ def test_llm_engine_emits_slot_admission_events():
             f.result(timeout=120)
     finally:
         eng.stop()
-    rows = [r for r in events_mod.local_events()
-            if r["seq"] > before and r["source"] == "serve_llm"]
-    assert any("admitted" in r["message"] for r in rows)
-    done = [r for r in rows if r["message"] == "request complete"]
+    rows = [r for r in events_mod.local_events() if r["seq"] > before]
+    queued = [r for r in rows if r["source"] == "trace"
+              and r["data"]["phase"] == "engine.queue"]
+    assert len(queued) == 3 and all(r["span_dur"] >= 0 for r in queued)
+    # three prompts on two slots: the last waited for a slot
+    assert max(r["span_dur"] for r in queued) > min(
+        r["span_dur"] for r in queued)
+    done = [r for r in rows if r["source"] == "serve_llm"
+            and r["message"] == "request complete"]
     assert len(done) == 3
     assert all(r["span_dur"] > 0 for r in done)
+    # each engine.queue stage is a child of its request's span
+    assert {r["data"]["parent_span_id"] for r in queued} == {
+        r["data"]["span_id"] for r in done}
     # admission latency histogram recorded each admitted request
-    from ray_tpu.util import metrics as mm
-
-    vals = mm.registry().snapshot()[
-        "ray_tpu_llm_slot_admission_latency_s"]["values"]
-    assert sum(h["count"] for h in vals.values()) >= 3
+    assert admitted() == had + 3
 
 
 def test_dashboard_events_metrics_grafana_endpoints(obs_cluster):

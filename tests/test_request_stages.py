@@ -1,6 +1,6 @@
-"""One span tree per served request, ingress to first token, and the
-per-process aggregate of its stages that ``perf_stats()`` returns: what the
-benchmark's stage readers rest on."""
+"""One span tree per served request, ingress to the last reply, the
+per-process aggregate of its stages that ``perf_stats()`` returns, and the
+engine's tick meter: what the benchmark's stage and counter readers rest on."""
 
 import http.client
 import json
@@ -15,10 +15,17 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu._private import events as events_mod
-from ray_tpu.serve.llm import STAGES, GenerationEngine, make_config
+from ray_tpu.serve.llm import (
+    FIRST_REPLY_STAGES,
+    PER_GAP,
+    STAGES,
+    GenerationEngine,
+    _TickMeter,
+    make_config,
+)
 from ray_tpu.util import compile_cache, tracing
 
-TILE = [p for p in STAGES if p != "serve.first_reply"]
+TILE = [p for p in FIRST_REPLY_STAGES if p != "serve.first_reply"]
 
 
 def tiny_engine(**kw):
@@ -70,10 +77,11 @@ def stages_of(handle):
 
 def test_streamed_request_is_one_span_tree(llm_http):
     """root -> router admission -> replica task -> task.dispatch,
-    serve.submit, the engine's request (queue, first token, stream yield),
-    serve.pickup and serve.first_reply, under ONE trace id.  Before the
-    stream thread adopted the request's context the engine's span of a
-    streamed request was dropped."""
+    serve.submit, the engine's request (queue, first token, stream yield,
+    decode, last yield), serve.pickup, serve.first_reply, serve.last_pickup
+    and serve.stream, under ONE trace id, ONE of each however many tokens
+    the request got.  Before the stream thread adopted the request's context
+    the engine's span of a streamed request was dropped."""
     from ray_tpu.experimental.state import api as state
 
     post, _ = llm_http
@@ -114,12 +122,35 @@ def test_streamed_request_is_one_span_tree(llm_http):
 
     assert {s["trace_id"] for s in tr["spans"]} == {tr["trace_id"]}
     for phase in ("task.dispatch", "serve.submit", "serve.pickup",
-                  "serve.first_reply"):
+                  "serve.first_reply", "serve.last_pickup", "serve.stream"):
         assert lineage(one(phase)) == [
             phase, "task", "router_admission", "http"], phase
-    for phase in ("engine.queue", "engine.first_token", "engine.stream_yield"):
+    for phase in ("engine.queue", "engine.first_token", "engine.stream_yield",
+                  "engine.decode", "engine.last_yield"):
         assert lineage(one(phase)) == [
             phase, "llm_generate", "task", "router_admission", "http"], phase
+    # 24 tokens in chunks of 4: nothing of it is emitted per token or chunk
+    # (every traced task has a task.dispatch: the proxy's polls too)
+    mine = [s["phase"] for s in tr["spans"]
+            if s["phase"] in STAGES and s["phase"] != "task.dispatch"]
+    assert sorted(mine) == sorted(
+        set(STAGES) - {"serve.route", "task.dispatch"})
+    assert not any(s["phase"] in PER_GAP for s in tr["spans"])  # folded only
+    decode = one("engine.decode")
+    assert (decode["data"]["tokens"], decode["data"]["chunks"],
+            decode["data"]["chunk_steps"]) == (24, 6, 4)
+    assert one("serve.stream")["data"]["stream_chunks"] == 24  # one a token
+    # the decode stages are drawn where they happened, not where they were
+    # emitted: decode from the first token's landing, the stream from the
+    # first reply to the last reply that carried data
+    assert decode["start"] == pytest.approx(
+        one("engine.first_token")["end"], abs=0.02)
+    assert one("serve.stream")["start"] == pytest.approx(
+        one("serve.first_reply")["end"], abs=0.02)
+    assert one("serve.stream")["end"] == pytest.approx(
+        one("serve.last_pickup")["end"], abs=1e-6)
+    assert decode["end"] <= one("engine.last_yield")["end"] <= (
+        one("serve.stream")["end"] + 0.005)
     # in time order, each stage starts where the one before it ended
     order = [one(p) for p in TILE[1:]]
     for a, b in zip(order, order[1:]):
@@ -139,15 +170,21 @@ def window(llm_http, n):
     after = stages_of(handle)
     assert after["clock_skew"] == 0
     return {p: {k: after[p][k] - before.get(p, {}).get(k, 0)
-                for k in ("count", "sum_s")} for p in STAGES}, after
+                for k in ("count", "sum_s")} for p in STAGES + PER_GAP}, after
 
 
 def test_stage_counts_are_exact_over_a_window(llm_http):
-    """N requests between two snapshots: every request phase counts N."""
+    """N requests between two snapshots: every request phase counts N, the
+    decode stages and the two per-gap folds too (one a FINISHED request,
+    none a token)."""
     diff, after = window(llm_http, 8)
-    assert {p: d["count"] for p, d in diff.items()} == {p: 8 for p in STAGES}
-    for p in STAGES:
+    assert {p: d["count"] for p, d in diff.items()} == {
+        p: 8 for p in STAGES + PER_GAP}
+    for p in STAGES + PER_GAP:
         assert 0 <= after[p]["p50_s"] <= after[p]["p95_s"]
+        # the window's own durations: the last 8 of ``recent``
+        assert sum(after[p]["recent"][-8:]) == pytest.approx(
+            diff[p]["sum_s"], rel=1e-6, abs=1e-9)
 
 
 def test_the_seven_stages_tile_the_first_reply(llm_http):
@@ -157,6 +194,138 @@ def test_the_seven_stages_tile_the_first_reply(llm_http):
     whole = diff["serve.first_reply"]["sum_s"]
     parts = sum(diff[p]["sum_s"] for p in TILE)
     assert parts == pytest.approx(whole, rel=0.05), diff
+
+
+def test_the_decode_stages_tile_the_stream(llm_http):
+    """serve.stream (first data reply -> last data reply, its own two clock
+    reads) = engine.decode + (engine.last_yield + serve.last_pickup) -
+    (engine.stream_yield + serve.pickup), up to the encode-and-put between a
+    yield and its put; and a request's pace is the same statistic at both
+    depths, 23 gaps a request here."""
+    diff, _ = window(llm_http, 8)
+    sums = {p: d["sum_s"] for p, d in diff.items()}
+    parts = (sums["engine.decode"]
+             + sums["engine.last_yield"] + sums["serve.last_pickup"]
+             - sums["engine.stream_yield"] - sums["serve.pickup"])
+    assert parts == pytest.approx(sums["serve.stream"], abs=8 * 0.004), diff
+    assert sums["engine.decode_per_token"] == pytest.approx(
+        sums["engine.decode"] / 23, rel=1e-6)
+    assert sums["serve.stream_per_chunk"] == pytest.approx(
+        sums["serve.stream"] / 23, rel=1e-6)
+
+
+def test_recent_is_in_closing_order_and_a_window_is_its_tail():
+    """``recent``: the reservoir's newest durations as they closed, at most
+    STATS_RECENT; a reader that differences ``count`` takes that many off
+    the end and never sees what closed before its window."""
+    phase = "test.recent"
+    for d in (9.0, 8.0, 7.0):  # before the window: slow, must not be read
+        tracing.fold(phase, d)
+    before = tracing.span_stats([phase])[phase]
+    assert before["recent"] == [9.0, 8.0, 7.0]
+    for d in (0.3, 0.1, 0.2):
+        tracing.fold(phase, d)
+    after = tracing.span_stats([phase])[phase]
+    assert after["recent"] == [9.0, 8.0, 7.0, 0.3, 0.1, 0.2]  # not sorted
+    assert after["recent"][-(after["count"] - before["count"]):] == [
+        0.3, 0.1, 0.2]
+    for i in range(tracing.STATS_RECENT):
+        tracing.fold(phase, float(i))
+    row = tracing.span_stats([phase])[phase]
+    assert row["count"] == 6 + tracing.STATS_RECENT
+    assert row["recent"] == [float(i) for i in range(tracing.STATS_RECENT)]
+
+
+def test_tick_meter_bills_the_period_to_the_tick_that_held_the_prefill():
+    """Decode-only, a tick with a prefill call, decode-only, on a synthetic
+    clock: a period runs from the previous landing to the tick's own, so the
+    prefill's 0.25 s are in the tick that held the call, not in the next;
+    the first tick after an idle engine has no previous landing and is left
+    out, though the second call of such a tick has one."""
+    m = _TickMeter("test")
+    m.begin(chained=False)            # the first tick after idle: left out
+    m.chunk_landed(10.0, 0, 2)
+    m.begin(chained=True)             # decode-only, 0.1 s
+    m.chunk_landed(10.1, 0, 2)
+    m.begin(chained=True)             # a 0.25 s call, then its 0.1 s chunk
+    m.call_landed(10.35)
+    mark = (m.prefill_s, m.prefill_calls)  # the admitted request's own call
+    m.chunk_landed(10.45, 1, 3)
+    m.begin(chained=True)             # decode-only again, 0.1 s
+    m.chunk_landed(10.55, 0, 3)
+    m.tick_host(0.001, 0.002, 0.003)
+    m.request_done(tokens=18, chunks=2, chunk_steps=16, span_s=0.2,
+                   prefill_s=m.prefill_s - mark[0])
+    snap = m.snapshot()
+    assert snap["ticks"] == {"decode_only": 2, "interleaved": 1,
+                             "prefill_only": 0}
+    assert snap["tick_s"] == pytest.approx(
+        {"decode_only": 0.2, "interleaved": 0.35, "prefill_only": 0.0})
+    assert "periods" not in snap  # the class sums above hold them
+    assert snap["decode_tick_baseline_s"] == pytest.approx(0.1)
+    assert snap["interference_s"] == pytest.approx(0.25)
+    assert snap["interference_frac"] == pytest.approx(0.25 / 0.55, abs=1e-4)
+    assert snap["tick_excess_s"] == pytest.approx(0.25)
+    assert snap["excess_billed_to_prefill"] == pytest.approx(1.0)
+    assert snap["host_s"] == {"admit": 0.001, "dispatch": 0.002,
+                              "drain_book": 0.003}
+    assert snap["ticks_live"] == 1
+    # the request's decode span saw none of its OWN call's seconds
+    assert snap["decode"] == {"requests": 1, "gaps": 17,
+                              "chunk_steps_paid": 32, "span_s": 0.2,
+                              "prefill_s": 0.0}
+    # an idle engine, then a burst of two calls in one tick: the tick is
+    # left out, but a request of the first call waits for the second
+    m.begin(chained=False)
+    m.call_landed(20.0)
+    m.call_landed(20.3)
+    m.chunk_landed(20.4, 2, 2)
+    assert m.snapshot()["ticks"] == snap["ticks"]
+    assert (m.prefill_s, m.prefill_calls) == pytest.approx((0.55, 2))
+    # a tick that holds nothing but admissions is no interference
+    m.begin(chained=True)
+    m.call_landed(20.6)
+    m.chunk_landed(20.7, 1, 1)
+    snap = m.snapshot()
+    assert snap["ticks"]["prefill_only"] == 1
+    assert snap["interference_s"] == pytest.approx(0.25)
+    assert snap["tick_s"]["prefill_only"] == pytest.approx(0.3)
+
+
+def test_engine_meter_and_decode_counters_over_real_requests():
+    """The engine feeds the meter from its drains: a request of 6 tokens in
+    chunks of 3 pays two chunks for five gaps, its ``engine.decode`` span
+    runs landing to landing, and ``itl``/``ttft`` are stamped there too."""
+    eng = tiny_engine()
+    try:
+        eng.generate([3, 5, 7], 6)  # build the programs
+        before = eng.perf_stats()
+        seq = events_mod.buffer().last_seq()
+        futs = [eng.submit([3, 5, 7], 6) for _ in range(3)]  # 3 on 2 slots
+        for f in futs:
+            assert len(f.result(timeout=120)) == 6
+    finally:
+        eng.stop()
+    after = eng.perf_stats()
+    d = {k: after["decode"][k] - before["decode"][k] for k in after["decode"]}
+    assert (d["requests"], d["gaps"], d["chunk_steps_paid"]) == (3, 15, 18)
+    assert 0 <= d["prefill_s"] <= d["span_s"]
+    spans = [r for r in events_mod.buffer().since(seq)
+             if (r.get("data") or {}).get("phase") == "engine.decode"]
+    assert len(spans) == 3
+    assert sum(r["span_dur"] for r in spans) == pytest.approx(d["span_s"])
+    assert all(r["data"]["tokens"] == 6 and r["data"]["chunks"] == 2
+               for r in spans)
+    assert after["ticks_live"] > before["ticks_live"]
+    host = {k: after["host_s"][k] - before["host_s"][k]
+            for k in after["host_s"]}
+    assert sorted(host) == ["admit", "dispatch", "drain_book"]
+    assert all(v >= 0 for v in host.values()) and host["dispatch"] > 0
+    # the third request was admitted while the others decoded or right
+    # after them: its tick was dispatched behind an undrained one
+    assert sum(after["ticks"].values()) > sum(before["ticks"].values())
+    assert after["itl"]["count"] >= before["itl"]["count"] + 6
+    assert after["ttft"]["count"] == before["ttft"]["count"] + 3
 
 
 def test_engine_spans_do_not_depend_on_the_ingress():
@@ -181,15 +350,51 @@ def test_engine_spans_do_not_depend_on_the_ingress():
         assert root["parent_span_id"] == ""
         stages = {d["phase"] for d in spans
                   if d["parent_span_id"] == root["span_id"]}
-        assert {"engine.queue", "engine.first_token"} <= stages
-    assert any(d["phase"] == "engine.stream_yield" for d in rows
-               for d in [d["data"]])
+        assert {"engine.queue", "engine.first_token",
+                "engine.decode"} <= stages
+    for phase in ("engine.stream_yield", "engine.last_yield"):
+        # the streamed request's alone
+        assert [r["data"]["phase"] for r in rows].count(phase) == 1
     # no task carried them here, so the upstream stages are absent
     assert not any(r["data"]["phase"] in ("task.dispatch", "serve.submit")
                    for r in rows)
 
 
+def test_last_yield_is_emitted_when_the_future_resolves_a_poll_late(
+        monkeypatch):
+    """The engine appends a request's last token, emits what it emits once a
+    request, and only then resolves the future: a poll of ``stream`` that
+    lands in between yields every token and sees the request done a poll
+    later.  The stage is still emitted, once, timed at the yield."""
+    eng = tiny_engine()
+    emit_done = eng._emit_done
+
+    def slow_emit_done(*a):
+        time.sleep(0.08)  # four polls of the stream thread
+        emit_done(*a)
+
+    try:
+        eng.generate([3, 5, 7], 6)  # build the programs
+        monkeypatch.setattr(eng, "_emit_done", slow_emit_done)
+        seq = events_mod.buffer().last_seq()
+        t0 = time.perf_counter()
+        for n, _ in enumerate(eng.stream([3, 5, 7], 6), 1):
+            if n == 6:
+                got_last = time.perf_counter() - t0
+        took = time.perf_counter() - t0
+    finally:
+        eng.stop()
+    assert took - got_last > 0.03  # the last token came before ``done``
+    found = [r for r in events_mod.buffer().since(seq)
+             if (r.get("data") or {}).get("phase") == "engine.last_yield"]
+    assert len(found) == 1
+    assert 0 <= found[0]["span_dur"] < 0.06  # a poll, not the wait for done
+
+
 def test_events_off_means_no_stage_and_no_span(monkeypatch):
+    folded = lambda: {p: r["count"] for p, r in tracing.span_stats(  # noqa: E731
+        STAGES + PER_GAP).items()}
+    had = folded()
     monkeypatch.setattr(events_mod, "ENABLED", False)
     eng = tiny_engine()
     try:
@@ -201,6 +406,11 @@ def test_events_off_means_no_stage_and_no_span(monkeypatch):
     assert stats["stages"] == {}
     assert events_mod.buffer().last_seq() == seq
     assert "ttft" in stats and "compiles" in stats and "device" in stats
+    # the meter's keys are there and nothing was added to them
+    assert stats["ticks_live"] == 0 and stats["decode"]["requests"] == 0
+    assert sum(stats["ticks"].values()) == 0
+    assert stats["ttft"]["count"] == stats["itl"]["count"] == 0
+    assert folded() == had
 
 
 def test_clock_skew_clamps_and_counts():
@@ -252,7 +462,9 @@ def test_compile_counter_counts_new_shapes_only():
 
 def test_engine_phases_are_in_the_profilers_trace(tmp_path):
     """The engine thread's phases land in the same trace file as the
-    device's ops, under stable names, with the python tracer off."""
+    device's ops, under stable names, with the python tracer off; the
+    drain's blocking reads have a name of their own, and ONE
+    ``engine.wait_work`` event covers an idle period however long."""
     from jax.profiler import ProfileData
 
     from ray_tpu.util import profiling
@@ -262,13 +474,26 @@ def test_engine_phases_are_in_the_profilers_trace(tmp_path):
         eng.generate([3, 5, 7], 6)  # build the programs outside the trace
         with profiling.profile_trace(str(tmp_path)):
             eng.generate([3, 5, 7], 6)
-            time.sleep(0.12)  # an idle loop turn or two
+            time.sleep(0.3)  # one idle period
+            eng.generate([3, 5, 7], 6)
     finally:
         eng.stop()
     files = list(tmp_path.rglob("*.xplane.pb"))
     assert files
-    names = {e.name for plane in ProfileData.from_file(str(files[0])).planes
-             for line in plane.lines for e in line.events}
+    events = [e for plane in ProfileData.from_file(str(files[0])).planes
+              for line in plane.lines for e in line.events]
+    names = {e.name for e in events}
     assert {"engine.admit", "engine.decode_dispatch", "engine.drain",
-            "engine.wait_work"} <= names
+            "engine.drain_wait", "engine.wait_work"} <= names
     assert not any(n.startswith("$") for n in names)  # python frames
+    # the 0.3 s between the two requests is ONE event, not six of 50 ms (a
+    # submit that lands while the engine is busy leaves a wait of no length)
+    waits = [e.duration_ns / 1e9 for e in events
+             if e.name == "engine.wait_work" and e.duration_ns > 60e6]
+    assert len(waits) == 1 and waits[0] >= 0.25, waits
+    drains = [(e.start_ns, e.start_ns + e.duration_ns) for e in events
+              if e.name == "engine.drain"]
+    for e in events:  # every blocking read lies inside a drain
+        if e.name == "engine.drain_wait":
+            assert any(s <= e.start_ns and e.start_ns + e.duration_ns <= t
+                       for s, t in drains)
