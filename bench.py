@@ -1613,7 +1613,10 @@ def _perf_observability_overhead() -> dict:
     step_pct = 100.0 * step_cost_s / step_wall_s
 
     # decode tick: real tick wall from a short engine run, meter cost
-    # timed directly
+    # timed directly.  The wall is a MEAN over whole and cut ticks (an
+    # answer of 32 tokens is its prefill's, three chunks of 8 and a cut of
+    # 7; the engine cuts a chunk where the nearest live request ends), so
+    # it is under eight steps' time
     engine = GenerationEngine(
         make_config("gpt2", "small"),
         n_slots=4, max_new_tokens=32,

@@ -44,7 +44,12 @@ first-class TPU path, designed for XLA:
   (Orca-style; see :mod:`ray_tpu.serve.llm`).
 - **Chunked decode**: ``decode_chunk`` runs N decode+sample steps inside
   one device computation (``lax.scan``) so the host syncs once per chunk,
-  not per token.
+  not per token.  A caller that knows an answer ends ``n < N`` steps into a
+  chunk CUTS it (``n``, a runtime scalar): the same step in a loop with
+  that bound, one further program whatever ``n`` is, so the answer's last
+  token does not wait for steps nobody needs (the serve engine's rule:
+  :meth:`ray_tpu.serve.llm.GenerationEngine.step`).  The chunk-local buffer
+  stays ``N`` wide and is flushed as ever.
 - **No step writes the cache**: a chunk's new K/V columns live in a
   chunk-local buffer ``[L, steps, B, KV, dh]``.  Layer ``l`` of step ``i``
   writes there with one ``dynamic_update_slice`` at ``(l, i)`` — the same
@@ -94,7 +99,10 @@ was active when the chunk began the flush writes all ``steps`` columns at
 ``pos0[b] ..``, so those after a mid-chunk EOS land at or beyond its frozen
 ``pos`` — harmless: a slot never attends an index its own ``pos`` hasn't
 covered, the next flush starts at ``pos`` again, and prefill overwrites
-``[0, Tp)`` and resets ``pos`` when the slot is reused.  A slot that
+``[0, Tp)`` and resets ``pos`` when the slot is reused.  The columns a CUT
+chunk flushes beyond its ``n`` steps (zeros) land past ``pos`` the same way;
+in a ring they overwrite entries that held positions ``2 x window`` back,
+outside every window still to come.  A slot that
 decodes needs ``pos0 + steps <= S`` (the engine sizes the cache ``bucket +
 max_new + chunk``).  For a slot that sat the chunk out the kernel writes
 NOTHING; the slice updates write its ``steps`` columns at its frozen
@@ -451,13 +459,21 @@ def sample_logits(logits: jax.Array, key: jax.Array, *, temperature: float = 0.0
 
 
 def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
-                 temperature: float = 0.0, top_k: int = 0,
-                 eos_id: Optional[int] = None):
+                 n: Optional[jax.Array] = None, temperature: float = 0.0,
+                 top_k: int = 0, eos_id: Optional[int] = None):
     """Run ``steps`` decode+sample iterations in one device computation.
     ``tokens [B]`` are each slot's last emitted token, ``active [B]`` bool
     gates the position advance.  Returns ``(emitted [B, steps], cache,
     active, key)``.  A slot that emits ``eos_id`` flips inactive mid-chunk
     (its pos freezes).
+
+    ``n`` (a traced scalar, ``1 <= n <= steps``) CUTS the chunk: the same
+    step runs ``n`` times, in a loop whose bound is a runtime value, so one
+    compiled program serves every ``n``.  ``emitted[:, n:]`` then repeat each
+    slot's last token (``emitted[:, -1]`` is it either way), ``pos`` advances
+    ``n``, and the flush still writes ``steps`` columns: those from ``n`` on
+    land at or beyond the new ``pos``, as an EOS-frozen slot's do (module
+    docstring).  None: the whole chunk, a ``lax.scan`` over ``steps``.
 
     No step writes into the cache (module docstring): the new K/V columns
     go to a chunk-local buffer ``[L, steps, B, KV, dh]``, attention is over
@@ -498,7 +514,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     if S % DECODE_TILE == 0 and old[0].shape[3] % 8 == 0:
         plan = ragged_decode_plan(live, S // DECODE_TILE)
         if steps <= DECODE_TILE:
-            to_flush = cache_flush_plan(active, pos0, steps, S)
+            to_flush = cache_flush_plan(active, pos0, steps, S, written=n)
     local = jnp.zeros(  # [L, steps, B, KV, dh]
         (cfg.n_layers, steps, B, *old[0].shape[2:4]), old[0].dtype)
     # a latent family's block in its decode form (the family's docstring)
@@ -568,9 +584,25 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         counted = jax.tree.map(lambda *a: jnp.stack(a), *counted) if counted else None
         return (tuple(locs), pos, nxt, act, rng), (nxt, counted)
 
-    (locs, pos, _, active, key), (emitted, routed) = lax.scan(
-        step, ((local,) * len(names), pos0, tokens, active, key),
-        jnp.arange(steps))
+    state = ((local,) * len(names), pos0, tokens, active, key)
+    if n is None:
+        (locs, pos, _, active, key), (emitted, routed) = lax.scan(
+            step, state, jnp.arange(steps))
+    else:
+        # the cut chunk: what the scan stacks a step is carried instead, the
+        # tokens written at their step, the routing counts added up
+        def cut_step(i, carry):
+            state, emitted, routed = carry
+            state, (nxt, counted) = step(state, i)
+            return (state, emitted.at[i].set(nxt),
+                    jax.tree.map(jnp.add, routed, counted))
+
+        counted = jax.eval_shape(step, state, 0)[1][1]
+        (locs, pos, last, active, key), emitted, routed = lax.fori_loop(
+            0, n, cut_step,
+            (state, jnp.zeros((steps, B), jnp.int32),
+             jax.tree.map(jnp.zeros_like, counted)))
+        emitted = jnp.where(jnp.arange(steps)[:, None] < n, emitted, last)
 
     # the chunk's columns of the layers of one kind (a family of one kind: all)
     of = lambda loc, kind: loc if not window else loc[  # noqa: E731
@@ -590,7 +622,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
             out[name] = jnp.where(hit.any(1)[None, :, None, None, :],
                                   new, cache[name])
     if routed is not None:  # the chunk's routing counts: summed over its steps
-        out["routed"] = jax.tree.map(lambda a: a.sum(0), routed)
+        out["routed"] = routed if n is not None else jax.tree.map(
+            lambda a: a.sum(0), routed)
     return emitted.T, out, active, key
 
 
@@ -602,8 +635,8 @@ def generate(params, cfg, prompts: jax.Array, lengths: jax.Array, *,
     ``max_new_tokens - 1`` steps, so the chunk-local K/V buffer is as wide
     as the answer).  Returns ``[B, max_new_tokens]`` generated tokens
     (post-EOS positions repeat the EOS token).  For the serving path use
-    :mod:`ray_tpu.serve.llm`, which runs the same kernels in fixed chunks
-    under iteration-level continuous batching."""
+    :mod:`ray_tpu.serve.llm`, which runs the same kernels in chunks (cut
+    where an answer ends) under iteration-level continuous batching."""
     B, Tp = prompts.shape
     if key is None:
         key = jax.random.PRNGKey(0)

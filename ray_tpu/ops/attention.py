@@ -862,7 +862,7 @@ FLUSH_BUFFERS = 3
 
 
 def cache_flush_plan(active: jax.Array, pos0: jax.Array, steps: int,
-                     n_positions: int) -> jax.Array:
+                     n_positions: int, written=None) -> jax.Array:
     """:func:`cache_flush`'s work list as ONE int32 vector: ``[count,
     start[0..B), slot[0..W), tile[0..W)]`` with ``W = 2 * B``.  ``start[b]``
     is where slot ``b``'s ``steps`` columns go, ``pos0[b]`` (held to
@@ -870,11 +870,16 @@ def cache_flush_plan(active: jax.Array, pos0: jax.Array, steps: int,
     ``w < count`` are the ``(slot, tile)`` pairs that the columns of an
     ``active`` slot fall in, one tile or, where they cross a 128-position
     boundary, two, in slot order; the rest are never read.  A function of
-    ``active`` and ``pos0`` alone: built once a chunk for every tensor."""
+    ``active`` and ``pos0`` alone: built once a chunk for every tensor.
+    ``written`` (a cut chunk's ``n <= steps``, which may be traced; None:
+    ``steps``): only the first ``written`` columns hold anything a later
+    step attends, so the second tile is listed where THEY cross; the columns
+    after them that fall in the first tile are written with it."""
     B, T = pos0.shape[0], DECODE_TILE
     assert steps <= T, steps
     start = jnp.clip(pos0.astype(jnp.int32), 0, n_positions - steps)
-    tiles = active.astype(jnp.int32) * (1 + (start % T > T - steps))
+    tiles = active.astype(jnp.int32) * (
+        1 + (start % T > T - (steps if written is None else written)))
     ends = jnp.cumsum(tiles)
     w = jnp.arange(2 * B, dtype=jnp.int32)
     # item w is of the first slot whose items end beyond it

@@ -11,8 +11,12 @@ is part of the framework here:
   ``decode_chunk`` advancing every active slot per iteration.  New requests
   join between chunks; finished slots free mid-stream.  All device
   computations have static shapes (a prompt bucket at its fixed narrow width,
-  :data:`CALL_TOKENS`; a fixed chunk length), so everything compiles exactly
-  once per bucket, and a burst is several such calls in one iteration.
+  :data:`CALL_TOKENS`; a chunk of ``decode_chunk_steps``), so everything
+  compiles exactly once per bucket, and a burst is several such calls in one
+  iteration.  A chunk ends where the nearest live request ends: when one has
+  fewer tokens left than the chunk is long, the CUT chunk runs just those
+  steps (the same step body under a runtime bound: one further program
+  whatever the bound), so no answer waits for steps nobody needs.
 - :func:`llm_deployment` — wraps the engine in a Serve deployment on a
   ``num_tpus`` replica; requests block on a future the engine thread
   resolves, so Serve's threaded replica concurrency (not the engine)
@@ -24,7 +28,7 @@ from __future__ import annotations
 import math
 import threading
 import time
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 from functools import partial
 from typing import Any, Dict, List, Optional
 
@@ -211,15 +215,16 @@ class _TickMeter:
         host["drain_book"] += drain_book
         self.ticks_live += 1
 
-    def request_done(self, tokens: int, chunks: int, chunk_steps: int,
-                     span_s: float, prefill_s: float) -> None:
+    def request_done(self, tokens: int, chunk_steps: int, span_s: float,
+                     prefill_s: float) -> None:
         """A request ended: ``span_s`` from its first to its last token on
-        the host, ``chunks`` decode chunks gave it one, and ``prefill_s`` of
-        the span the device spent on other requests' prefill calls."""
+        the host, the chunks that gave it one ran ``chunk_steps`` steps, and
+        ``prefill_s`` of the span the device spent on other requests' prefill
+        calls."""
         tally = self.decode
         tally["requests"] += 1
         tally["gaps"] += tokens - 1
-        tally["chunk_steps_paid"] += chunks * chunk_steps
+        tally["chunk_steps_paid"] += chunk_steps
         tally["span_s"] += span_s
         tally["prefill_s"] += prefill_s
 
@@ -234,8 +239,8 @@ class _TickMeter:
         return {
             "ticks": dict(self.ticks),
             "tick_s": {k: round(v, 6) for k, v in self.tick_s.items()},
-            # the mean decode-only period: over the chunk's steps, the
-            # decode step on the host's clock
+            # the mean decode-only period, over whole and cut chunks alike
+            # (a cut chunk ran fewer steps: GenerationEngine.step)
             "decode_tick_baseline_s":
                 round(baseline, 6) if baseline is not None else None,
             "interference_s": round(self.interference_s, 6),
@@ -294,7 +299,8 @@ def cache_positions(largest_bucket: int, max_new_tokens: int,
 class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
                  "submitted_at", "trace_ctx", "dispatched_at",
-                 "first_host_t", "last_host_t", "chunks", "prefill_mark")
+                 "first_host_t", "last_host_t", "chunks", "chunk_steps",
+                 "prefill_mark")
 
     def __init__(self, tokens: List[int], max_new: int):
         self.tokens = list(tokens)
@@ -313,9 +319,10 @@ class _Request:
         self.dispatched_at: Optional[float] = None
         self.first_host_t: Optional[float] = None
         self.last_host_t: Optional[float] = None
-        # decode chunks that gave it a token, and the tick meter's prefill
-        # seconds and calls when its first token landed
+        # decode chunks that gave it a token and the steps they ran, and the
+        # tick meter's prefill seconds and calls when its first token landed
         self.chunks = 0
+        self.chunk_steps = 0
         self.prefill_mark = (0.0, 0)
         # the submitter's trace context (a traced serve replica): the
         # engine loop runs on its own thread, so the context is captured
@@ -333,10 +340,12 @@ class _PendingChunk:
     (tokens already streaming host-ward via ``copy_to_host_async``) plus
     the host bookkeeping needed to route them when they land."""
 
-    __slots__ = ("chunk_dev", "rows", "prefills", "routed_dev", "chained")
+    __slots__ = ("chunk_dev", "steps", "rows", "prefills", "routed_dev",
+                 "chained")
 
-    def __init__(self, chunk_dev, rows, prefills, routed_dev, chained):
-        self.chunk_dev = chunk_dev          # [n_slots+1, steps] device
+    def __init__(self, chunk_dev, steps, rows, prefills, routed_dev, chained):
+        self.chunk_dev = chunk_dev          # [n_slots+1, chunk] device
+        self.steps = steps                  # the steps it ran: chunk, or its cut
         self.rows = rows                    # [(slot, _Request)] active in chunk
         # this iteration's prefill calls, in dispatch order: (admissions
         # [(row_j, slot, _Request)], first tokens [rows] device, the call's
@@ -457,7 +466,7 @@ class GenerationEngine:
             "window": DECODE_TILE * row_bytes("k_ring", "v_ring")}
         self._key = jax.random.PRNGKey(seed)
 
-        self._prefill_jit, self._decode_jit = engine_programs(
+        self._prefill_jit, self._decode_jit, decode_cut_jit = engine_programs(
             cfg, decode_chunk_steps=decode_chunk_steps,
             temperature=temperature, top_k=top_k, eos_id=eos_id)
         # a named function, not a lambda: a device trace lists each program
@@ -478,6 +487,22 @@ class GenerationEngine:
         # chunk N's output ON DEVICE, so dispatching N+1 never waits for
         # N's tokens to reach the host
         self._last_tok_dev = jnp.zeros((n_slots + 1,), jnp.int32)
+        # the cut chunk is built AHEAD, for the shapes it will be called
+        # with, on a thread of its own: its load is seconds for a family
+        # whose layers are unrolled (PERF.md section 6, PR 40), and here it
+        # runs beside the replica's start-up and the loading of the first
+        # prefill and of the whole chunk, where the first cut would else
+        # wait for all of it.  The future holds the compiled program (or
+        # what building it raised)
+        shapes = lambda tree: jax.tree.map(  # noqa: E731
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+        cut_args = shapes((self.params, self.cache, self._last_tok_dev,
+                           jnp.zeros((n_slots + 1,), bool), self._key,
+                           jnp.int32(0)))
+        pool = ThreadPoolExecutor(1, thread_name_prefix="llm-decode-cut")
+        self._decode_cut: Future = pool.submit(
+            lambda: decode_cut_jit.lower(*cut_args).compile())
+        pool.shutdown(wait=False)  # the thread ends with the build
         self._pending: Optional[_PendingChunk] = None
         self._draining: Optional[_PendingChunk] = None  # mid-_drain record
         self._queue: List[_Request] = []
@@ -587,6 +612,7 @@ class GenerationEngine:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        self._decode_cut.exception()  # a build still running ends first
         # flush the final interference numbers so a short engine run
         # still leaves the doctor/`ray_tpu perf` its last meter state
         self._ticks.emit_event()
@@ -803,7 +829,12 @@ class GenerationEngine:
         1. admit queued prompts into free slots (as many narrow prefill
            calls as the queue's head, the free slots and the tick's token
            budget allow; no readback)
-        2. dispatch decode chunk N (chains off device-side last tokens)
+        2. dispatch decode chunk N (chains off device-side last tokens): as
+           long as every live request has tokens left for it, else CUT to the
+           fewest any has left (``max_new - scheduled``, which the host knows
+           without a device read), so that the request that ends in it gets
+           its last token at the step that made it, and every other row its
+           tokens that much sooner; the next chunk is whole again
         3. free slots whose request deterministically finishes in chunk N
            (cap-based — the HOST knows completion timing without seeing
            token values), so the next iteration's admission reuses them
@@ -831,14 +862,21 @@ class GenerationEngine:
             with self._annotate("engine.decode_dispatch"):
                 active = np.zeros((self.n_slots + 1,), bool)  # scratch inactive
                 active[[i for i, _ in rows]] = True
+                # the steps this chunk runs: up to where the nearest live
+                # request ends (one with nothing left, its answer being its
+                # prefill's token, is freed below and counts for nothing)
+                left = [req.max_new - req.scheduled for _, req in rows]
+                n = min([self.chunk] + [m for m in left if m > 0])
+                args = (self.params, self.cache, self._last_tok_dev,
+                        jnp.asarray(active), self._key)
                 (chunk_dev, self.cache, self._last_tok_dev, self._key,
-                 chunk_routed) = self._decode_jit(
-                    self.params, self.cache, self._last_tok_dev,
-                    jnp.asarray(active), self._key)
+                 chunk_routed) = (
+                    self._decode_jit(*args) if n == self.chunk
+                    else self._decode_cut.result()(*args, np.int32(n)))
                 chunk_dev.copy_to_host_async()
                 _to_host_async(chunk_routed)
             dispatched = _PendingChunk(
-                chunk_dev, rows, prefills, chunk_routed,
+                chunk_dev, n, rows, prefills, chunk_routed,
                 chained=self._pending is not None)
             # cap-based predicted completion: these slots are free for the
             # NEXT admission even though their token values haven't landed
@@ -850,20 +888,20 @@ class GenerationEngine:
                 # device has seen, can only leave it lower
                 stands = [len(req.tokens) + req.scheduled - 1 for _, req in rows]
                 self._cache_tiles["read_full"] += sum(
-                    -(-n // self._tile) for n in stands)
+                    -(-at // self._tile) for at in stands)
                 # the chunk's flush writes, a full layer a tensor, the tile
                 # a dispatched row's columns fall in, and the next where
-                # they cross into it; a row that sits the chunk out, none
+                # the ``n`` it ran cross into it; a row that sits the chunk
+                # out, none
                 self._cache_tiles["flushed"] += sum(
-                    1 + (n % self._tile > self._tile - self.chunk)
-                    for n in stands)
+                    1 + (at % self._tile > self._tile - n) for at in stands)
                 if self._layers["window"]:
                     self._cache_tiles["read_window"] += (
                         (self.n_slots + 1) * self._ring_tiles)
                 self._cache_tiles["padded"] += (
                     (self.n_slots + 1) * self._max_len // self._tile)
                 for i, req in rows:
-                    req.scheduled = min(req.max_new, req.scheduled + self.chunk)
+                    req.scheduled = min(req.max_new, req.scheduled + n)
                     if req.scheduled >= req.max_new:
                         self._slots[i] = None
         t_dispatched = time.perf_counter() if meter else 0.0
@@ -881,9 +919,10 @@ class GenerationEngine:
                 time.perf_counter() - t_dispatched - waited)
         return worked
 
-    def _count_routed(self, phase: str, counts) -> None:
-        """Add one landed dispatch's routing counts (a prefill call's or a
-        chunk's; None where the family counts nothing) to the totals."""
+    def _count_routed(self, phase: str, counts, steps: int = 0) -> None:
+        """Add one landed dispatch's routing counts (a prefill call's, or a
+        chunk's with the ``steps`` it ran; None where the family counts
+        nothing) to the totals."""
         if counts is None:
             return
         counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
@@ -891,8 +930,7 @@ class GenerationEngine:
             had = self._routed[phase]
             self._routed[phase] = counts if had is None else {
                 k: had[k] + v for k, v in counts.items()}
-            if phase == "decode":
-                self._routed["decode_steps"] += self.chunk
+            self._routed["decode_steps"] += steps
 
     def _read_back(self, dev) -> tuple:
         """``np.asarray(dev)`` (blocks until the device has produced it), the
@@ -945,12 +983,12 @@ class GenerationEngine:
             meter.chunk_landed(
                 landed, sum(len(adm) for adm, _, _ in pending.prefills),
                 len(pending.rows))
-        self._count_routed("decode", pending.routed_dev)
+        self._count_routed("decode", pending.routed_dev, pending.steps)
         for i, req in pending.rows:
             if req.future.done():
                 continue
             n_before = len(req.emitted)
-            for t in chunk[i]:
+            for t in chunk[i][:pending.steps]:
                 # check BEFORE append: the prefill's first token may already
                 # have satisfied max_new (or been EOS) for this request
                 if len(req.emitted) >= req.max_new or (
@@ -969,6 +1007,7 @@ class GenerationEngine:
                     self._itl_samples.append(itl)
                 req.last_host_t = landed
                 req.chunks += 1
+                req.chunk_steps += pending.steps
             done = len(req.emitted) >= req.max_new or (
                 self.eos_id is not None and req.emitted
                 and req.emitted[-1] == self.eos_id)
@@ -994,11 +1033,11 @@ class GenerationEngine:
         if meter is not None and req.first_host_t is not None:
             span = req.last_host_t - req.first_host_t
             prefill_s = meter.prefill_s - req.prefill_mark[0]
-            meter.request_done(n, req.chunks, self.chunk, span, prefill_s)
+            meter.request_done(n, req.chunk_steps, span, prefill_s)
             tracing.emit_stage(
                 "engine.decode", span, req.trace_ctx,
                 ts=time.time() - (now - req.last_host_t), tokens=n,
-                chunks=req.chunks, chunk_steps=self.chunk,
+                chunks=req.chunks, chunk_steps=req.chunk_steps,
                 prefill_calls=meter.prefill_calls - req.prefill_mark[1],
                 prefill_s=round(prefill_s, 6))
             if n > 1:
@@ -1017,8 +1056,10 @@ class GenerationEngine:
 
 def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
                     top_k: int = 0, eos_id: Optional[int] = None):
-    """The engine's two device programs, ``(prefill, decode_chunk)``: one
-    prefill per prompt bucket (compiled lazily), one chunked decode.  cfg is
+    """The engine's three device programs, ``(prefill, decode_chunk, the cut
+    decode chunk)``: one prefill per prompt bucket (compiled lazily), the
+    whole chunk of ``decode_chunk_steps``, and the same steps under a runtime
+    bound (a sixth argument, ``n``: one program whatever it is).  cfg is
     closed over (hashable frozen dataclass).  A function of the config alone,
     so the compile test (``tests/test_chip_compile.py``) lowers exactly what
     a replica runs."""
@@ -1046,13 +1087,23 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
         ),
         donate_argnums=(1,),  # cache buffers reused in place
     )
-    return prefill, decode
+
+    # a name of its own (jit_llm_decode_cut): a device trace keeps its runs
+    # apart from the whole chunk's, whose time is read as that of
+    # ``decode_chunk_steps`` steps
+    def llm_decode_cut(params, cache, tokens, active, key, n):
+        return _decode_chunk_wrapper(
+            gen, cfg, params, cache, tokens, active, key, n=n,
+            steps=decode_chunk_steps, temperature=temperature, top_k=top_k,
+            eos_id=eos_id)
+
+    return prefill, decode, jax.jit(llm_decode_cut, donate_argnums=(1,))
 
 
 def _decode_chunk_wrapper(gen, cfg, params, cache, tokens, active, key, *,
-                          steps, temperature, top_k, eos_id):
+                          steps, temperature, top_k, eos_id, n=None):
     emitted, cache, _active, key = gen.decode_chunk(
-        params, cfg, cache, tokens, active, key, steps=steps,
+        params, cfg, cache, tokens, active, key, steps=steps, n=n,
         temperature=temperature, top_k=top_k, eos_id=eos_id)
     # chain the NEXT chunk off this one's final tokens without a host
     # round trip (inactive slots carry their input token through, so

@@ -100,12 +100,22 @@ def _lower_prefill(chip, prefill, params, cache, n_slots, bucket):
         _on(chip, params), i32(n, bucket), i32(n), _on(chip, cache), i32(n))
 
 
+def _lower_decodes(chip, decode, cut, params, cache, n_slots):
+    """The engine's two decode programs as it calls them: the whole chunk
+    with five arguments, the cut chunk with its runtime bound as a sixth."""
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    args = (_on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
+            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
+            _on(chip, jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    return [decode.lower(*args), cut.lower(*args, _on(chip, i32()))]
+
+
 def _lower_serve_engine(chip, family, *, buckets=(128,), chunk=64, max_new=128,
                         **config_kwargs):
     """What a ``num_tpus=1`` LLM replica runs: the prefill of each bucket at
     the engine's width for it (``llm.call_rows``: the largest bucket first,
-    the others after the decode program), and one decode chunk over 16 slots
-    plus the scratch slot.  The defaults are bench.run_decode_bench's shape;
+    the others after the two decode programs), and one decode chunk, whole
+    and cut, over 16 slots plus the scratch slot.  The defaults are bench.run_decode_bench's shape;
     the cache's length is the engine's own rounding to whole 128-position
     tiles (128 + 128 + 64 = 320 -> 384), so this is what a replica really
     runs."""
@@ -120,17 +130,12 @@ def _lower_serve_engine(chip, family, *, buckets=(128,), chunk=64, max_new=128,
     cache = jax.eval_shape(
         lambda: gen.init_cache(
             cfg, n_slots + 1, llm.cache_positions(max(buckets), max_new, chunk)))
-    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
     widest, *narrower = sorted(buckets, reverse=True)
     return [
         prefill_of(widest),
-        decode.lower(
-            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
-            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
-            _on(chip, key)),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
         *map(prefill_of, narrower),
     ]
 
@@ -159,17 +164,12 @@ def _lower_exaone_cell(chip):
         cfg, n_slots + 1, llm.cache_positions(4096, 512, chunk)))
     assert cache["k"].shape == (1, 33, 8, 128, 4736)
     assert cache["k_ring"].shape == (4, 33, 8, 128, 256)
-    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
     assert [llm.call_rows(b, n_slots) for b in (128, 256, 4096)] == [2, 1, 1]
     return [
         prefill_of(4096),
-        decode.lower(
-            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
-            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
-            _on(chip, key)),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
         *map(prefill_of, (2048, 1024, 512, 256, 128)),
     ]
 
@@ -198,17 +198,12 @@ def _lower_kimi_cell(chip):
         cfg, n_slots + 1, llm.cache_positions(8192, 1024, chunk)))
     assert set(cache) == {"c", "pos"}
     assert cache["c"].shape == (6, 33, 1, 576, 9344)
-    prefill, decode = llm.engine_programs(cfg, decode_chunk_steps=chunk)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
     assert [llm.call_rows(b, n_slots) for b in (256, 8192)] == [1, 1]
     return [
         prefill_of(8192),
-        decode.lower(
-            _on(chip, params), _on(chip, cache), _on(chip, i32(n_slots + 1)),
-            _on(chip, jax.ShapeDtypeStruct((n_slots + 1,), jnp.bool_)),
-            _on(chip, key)),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
         *map(prefill_of, (4096, 2048, 1024, 512, 256)),
     ]
 
@@ -353,19 +348,26 @@ def test_program_compiles_for_v5e(compiled, name):
         # the decode chunk writes the big cache once, at its end, in place:
         # no scatter anywhere in it, and the cache's leaves (k, pos, v, and
         # a family's rings: the arguments after the parameters, outputs 1..)
-        # come back in the buffers they arrived in
-        decode = programs[1]
-        text = decode.as_text()
-        assert not re.search(r"\bscatter\(", text)
-        # every engine's cache is whole 128-position tiles, so the cache
-        # half of decode attention reaches the chip's compiler as the
-        # ragged kernel, for G = 1 (GPT-2) and G > 1 (Llama) alike
-        assert text.count("tpu_custom_call") >= 1
-        n_params = len(jax.tree.leaves(decode.args_info[0][0]))
-        n_cache = len(jax.tree.leaves(decode.args_info[0][1]))
-        aliased = re.search(r"input_output_alias=\{(.*?) \}, entry", text).group(1)
-        for out_index, arg in enumerate(range(n_params, n_params + n_cache), start=1):
-            assert f"{{{out_index}}}: ({arg}, {{}}, may-alias)" in aliased, aliased
+        # come back in the buffers they arrived in.  The cut chunk (the
+        # same steps under a runtime bound) likewise, under a name of its own
+        assert programs[1].as_text().startswith("HloModule jit__unknown")
+        assert programs[2].as_text().startswith("HloModule jit_llm_decode_cut")
+        kernels = [p.as_text().count("tpu_custom_call") for p in programs[1:3]]
+        assert kernels[0] == kernels[1], kernels
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            assert not re.search(r"\bscatter\(", text)
+            # every engine's cache is whole 128-position tiles, so the cache
+            # half of decode attention reaches the chip's compiler as the
+            # ragged kernel, for G = 1 (GPT-2) and G > 1 (Llama) alike
+            assert text.count("tpu_custom_call") >= 1
+            n_params = len(jax.tree.leaves(decode.args_info[0][0]))
+            n_cache = len(jax.tree.leaves(decode.args_info[0][1]))
+            aliased = re.search(
+                r"input_output_alias=\{(.*?) \}, entry", text).group(1)
+            for out_index, arg in enumerate(
+                    range(n_params, n_params + n_cache), start=1):
+                assert f"{{{out_index}}}: ({arg}, {{}}, may-alias)" in aliased, aliased
     if name in FLUSHED_IN_PLACE:
         # the chunk's flush reaches the chip's compiler as the kernel, by
         # name, once a cached tensor; no update of slab size is left beside
@@ -373,26 +375,28 @@ def test_program_compiles_for_v5e(compiled, name):
         # 0.32 GB, 2.13 GB) would show in the temporaries, which stay at
         # what the slice updates planned (sandbox compiles of PR 38's parent)
         tensors, slab, parent_temp = FLUSHED_IN_PLACE[name]
-        text = programs[1].as_text()
-        flushes = [line for line in text.splitlines()
-                   if re.search(r"%cache_flush[.\d]* = ", line)]
-        assert len(flushes) == tensors, flushes
-        for line in flushes:
-            assert f"= {slab}{{" in line and "tpu_custom_call" in line
-            assert "output_to_operand_aliasing={{}: (2, {})}" in line
-        for line in text.splitlines():
-            result = line.split("dynamic-update-slice(")[0]
-            assert result == line or f" {slab}{{" not in result, line[:300]
-        assert programs[1].memory_analysis().temp_size_in_bytes < parent_temp + 2**20
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            flushes = [line for line in text.splitlines()
+                       if re.search(r"%cache_flush[.\d]* = ", line)]
+            assert len(flushes) == tensors, flushes
+            for line in flushes:
+                assert f"= {slab}{{" in line and "tpu_custom_call" in line
+                assert "output_to_operand_aliasing={{}: (2, {})}" in line
+            for line in text.splitlines():
+                result = line.split("dynamic-update-slice(")[0]
+                assert result == line or f" {slab}{{" not in result, line[:300]
+            assert decode.memory_analysis().temp_size_in_bytes < parent_temp + 2**20
     if name == "serve_engine_gpt2_xl_cell":
         # the layout cliff (ISSUE 28; the cell's `assumed` has the same one
         # at 784 positions): a cache the compiler re-lays-out costs 8-12 GB
         # of temporaries in converted copies.  In place it needs 1.31 GiB
         # (a slab-sized copy feeding the kernel would show here too).
-        assert programs[1].memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+        for decode in programs[1:3]:
+            assert decode.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
         # a prefill call is at most 512 padded tokens wide: its temporaries
         # are a small fraction of the 16-row calls' (1.9 GiB at 16 x 512)
-        for prefill in programs[:1] + programs[2:]:
+        for prefill in programs[:1] + programs[3:]:
             assert prefill.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
     if name == "serve_engine_exaone_cell":
         # the ragged kernel on the full layer, the grouped matmuls of four

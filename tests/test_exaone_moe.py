@@ -213,15 +213,18 @@ def _one_shot(params, cfg, prompt, n):
 
 def _ticks(eng, futs):
     """Step a never-started engine until ``futs`` are done: per tick that
-    admitted anything, the prompt lengths of each prefill call it made."""
-    ticks = []
+    admitted anything, the prompt lengths of each prefill call it made; and
+    the steps every dispatched chunk ran."""
+    ticks, steps = [], []
     while not all(f.done() for f in futs):
-        before = eng.stats()["queued"]
+        before, was = eng.stats()["queued"], eng._pending
         eng.step()
         if before - eng.stats()["queued"]:
             ticks.append([[len(req.tokens) for _, _, req in admissions]
                           for admissions, _, _ in eng._pending.prefills])
-    return ticks
+        if eng._pending is not None and eng._pending is not was:
+            steps.append(eng._pending.steps)
+    return ticks, steps
 
 
 # buckets (8, 16, 32) at CALL_TOKENS 16: rows 2 / 1 / 1; 4 slots; an answer
@@ -289,7 +292,8 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     rng = np.random.RandomState(7)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in want["lens"]]
     futs = [eng.submit(p, 6) for p in prompts]
-    assert _ticks(eng, futs) == want["ticks"]
+    ticks, steps = _ticks(eng, futs)
+    assert ticks == want["ticks"]
     stats = eng.perf_stats()
     empty = dict(calls=0, rows=0, padded_tokens=0, prompts=0, live_tokens=0)
     assert stats["prefill"] == {
@@ -310,7 +314,10 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
         tokens = np.asarray(routed[phase]["tokens"])
         assert tokens.shape == (4, held) and tokens.sum() > 0
         assert (np.asarray(routed[phase]["touched"]) <= tokens.sum(1)).all()
-    assert routed["decode_steps"] == 3 * dispatches
+    # ... by the steps each chunk really ran: an answer of 6 tokens is its
+    # prefill's, a chunk of 3 and a chunk CUT to the 2 it has left
+    assert len(steps) == dispatches and set(steps) <= {1, 2, 3}
+    assert routed["decode_steps"] == sum(steps) < 3 * dispatches
     # only real prompt tokens were routed: 4 choices each, over 16 experts
     assert np.asarray(routed["prefill"]["tokens"]).sum(1).max() <= 4 * sum(
         len(p) for p in prompts)

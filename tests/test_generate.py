@@ -117,6 +117,11 @@ def test_prefill_then_chunked_decode_equals_one_shot():
 # chunk no step writes the cache; what the NEXT chunk reads is the flush
 # ---------------------------------------------------------------------------
 
+# one program a config and chunk length, whatever the bound ``n``
+_CUT_CHUNK = jax.jit(gen.decode_chunk, static_argnums=1,
+                     static_argnames=("steps", "eos_id"))
+
+
 class _Slots:
     """The engine's use of the programs, on the host: a cache of ``n`` slots
     (the last one the scratch slot), prompts admitted into any of them, all
@@ -148,17 +153,21 @@ class _Slots:
         self.active[slot] = first != self.eos_id
         self.out[slot], self._prompts[slot] = [first], prompt
 
-    def decode(self, steps):
+    def decode(self, steps, n=None):
+        """One chunk of ``steps``; ``n``: CUT to ``n`` steps, through a
+        jitted program whose bound is an argument, as the engine's is."""
         was = self.active.copy()
-        emitted, self.cache, active, self.key = gen.decode_chunk(
+        chunk, cut = (gen.decode_chunk, {}) if n is None else (
+            _CUT_CHUNK, {"n": jnp.int32(n)})
+        emitted, self.cache, active, self.key = chunk(
             self.params, self.cfg, self.cache, self.tok,
             jnp.asarray(self.active), self.key, steps=steps,
-            eos_id=self.eos_id)
+            eos_id=self.eos_id, **cut)
         emitted = np.asarray(emitted)
         self.tok = jnp.asarray(emitted[:, -1])
         self.active = np.array(active)
         for slot in np.flatnonzero(was):
-            row = [int(t) for t in emitted[slot]]
+            row = [int(t) for t in emitted[slot, :n]]
             if self.eos_id in row:  # what follows an EOS repeats it
                 row = row[:row.index(self.eos_id) + 1]
             self.out[slot] += row

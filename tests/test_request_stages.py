@@ -129,7 +129,8 @@ def test_streamed_request_is_one_span_tree(llm_http):
                   "engine.decode", "engine.last_yield"):
         assert lineage(one(phase)) == [
             phase, "llm_generate", "task", "router_admission", "http"], phase
-    # 24 tokens in chunks of 4: nothing of it is emitted per token or chunk
+    # 24 tokens in chunks of 4 (five whole, and one CUT to the 3 steps the
+    # answer had left): nothing of it is emitted per token or chunk
     # (every traced task has a task.dispatch: the proxy's polls too)
     mine = [s["phase"] for s in tr["spans"]
             if s["phase"] in STAGES and s["phase"] != "task.dispatch"]
@@ -138,7 +139,7 @@ def test_streamed_request_is_one_span_tree(llm_http):
     assert not any(s["phase"] in PER_GAP for s in tr["spans"])  # folded only
     decode = one("engine.decode")
     assert (decode["data"]["tokens"], decode["data"]["chunks"],
-            decode["data"]["chunk_steps"]) == (24, 6, 4)
+            decode["data"]["chunk_steps"]) == (24, 6, 23)
     assert one("serve.stream")["data"]["stream_chunks"] == 24  # one a token
     # the decode stages are drawn where they happened, not where they were
     # emitted: decode from the first token's landing, the stream from the
@@ -254,7 +255,7 @@ def test_tick_meter_bills_the_period_to_the_tick_that_held_the_prefill():
     m.begin(chained=True)             # decode-only again, 0.1 s
     m.chunk_landed(10.55, 0, 3)
     m.tick_host(0.001, 0.002, 0.003)
-    m.request_done(tokens=18, chunks=2, chunk_steps=16, span_s=0.2,
+    m.request_done(tokens=18, chunk_steps=32, span_s=0.2,  # two whole chunks
                    prefill_s=m.prefill_s - mark[0])
     snap = m.snapshot()
     assert snap["ticks"] == {"decode_only": 2, "interleaved": 1,
@@ -294,7 +295,8 @@ def test_tick_meter_bills_the_period_to_the_tick_that_held_the_prefill():
 
 def test_engine_meter_and_decode_counters_over_real_requests():
     """The engine feeds the meter from its drains: a request of 6 tokens in
-    chunks of 3 pays two chunks for five gaps, its ``engine.decode`` span
+    chunks of 3 rides two chunks, the second CUT to the two steps it has
+    left, so it pays five steps for five gaps; its ``engine.decode`` span
     runs landing to landing, and ``itl``/``ttft`` are stamped there too."""
     eng = tiny_engine()
     try:
@@ -308,14 +310,14 @@ def test_engine_meter_and_decode_counters_over_real_requests():
         eng.stop()
     after = eng.perf_stats()
     d = {k: after["decode"][k] - before["decode"][k] for k in after["decode"]}
-    assert (d["requests"], d["gaps"], d["chunk_steps_paid"]) == (3, 15, 18)
+    assert (d["requests"], d["gaps"], d["chunk_steps_paid"]) == (3, 15, 15)
     assert 0 <= d["prefill_s"] <= d["span_s"]
     spans = [r for r in events_mod.buffer().since(seq)
              if (r.get("data") or {}).get("phase") == "engine.decode"]
     assert len(spans) == 3
     assert sum(r["span_dur"] for r in spans) == pytest.approx(d["span_s"])
     assert all(r["data"]["tokens"] == 6 and r["data"]["chunks"] == 2
-               for r in spans)
+               and r["data"]["chunk_steps"] == 5 for r in spans)
     assert after["ticks_live"] > before["ticks_live"]
     host = {k: after["host_s"][k] - before["host_s"][k]
             for k in after["host_s"]}
