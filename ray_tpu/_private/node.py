@@ -176,6 +176,10 @@ def _set_child_subreaper() -> bool:
         return False
 
 
+# the longest shutdown() waits for a killed worker to be gone
+SHUTDOWN_WAIT_S = 300.0
+
+
 class _ForkedProc:
     """Popen-compatible handle for a forkserver-spawned worker.  The
     worker is not our direct child (double fork) but reparents to us via
@@ -304,9 +308,12 @@ class _ForkServerClient:
                 return None
 
     def close(self) -> None:
+        """Kill the template and wait until it is gone (it holds nothing; a
+        killed child that nobody waits for stays a zombie of ours)."""
         if self._proc is not None:
             try:
                 self._proc.kill()
+                self._proc.wait(timeout=10)
             except Exception:
                 pass
 
@@ -731,6 +738,10 @@ class Node:
             None if os.environ.get("RAY_TPU_DISABLE_FORKSERVER")
             else _ForkServerClient(self.session_dir))
         self._zombie_seen: Dict[int, float] = {}
+        # every worker process this node started that may still be alive,
+        # whatever became of its handle: shutdown() returns only when each
+        # of them is gone (the reaper loop drops the ones that are)
+        self._spawned: List[Any] = []
         # bounded: one entry per service thread, joined at shutdown
         self._threads = []  # raylint: disable=R5
         t = threading.Thread(target=self._reaper_loop, name="reaper", daemon=True)
@@ -1876,11 +1887,13 @@ class Node:
                 or (runtime_env or {}).get("conda")):
             proc = self._forkserver.spawn(env, cwd)
             if proc is not None:
+                self._spawned.append(proc)
                 self._register_worker_log(worker_id, ns.node_id, proc)
                 return proc
         proc = subprocess.Popen(
             _worker_argv(runtime_env), env=env, cwd=cwd
         )
+        self._spawned.append(proc)
         self._register_worker_log(worker_id, ns.node_id, proc)
         return proc
 
@@ -2493,6 +2506,7 @@ class Node:
                 for p in forked:
                     p.poll()  # reaps on exit; handle keeps the status
                     popen_pids.add(p.pid)  # sweep must not steal statuses
+                self._spawned = [p for p in self._spawned if p.poll() is None]
                 self._reap_unknown_zombies(popen_pids)
             except Exception:
                 pass
@@ -5312,28 +5326,36 @@ class Node:
                     w.send({"type": "exit"})
                 except Exception:
                     pass
+        # every process this node started: the handles' (dead ones keep
+        # theirs) and whatever was spawned and never got, or lost, a handle
+        procs = {id(p): p for p in [w.proc for w in workers] + list(self._spawned)
+                 if p is not None}
         deadline = time.time() + 2.0
         killed = []
-        for w in workers:
-            if w.proc is not None:
+        for proc in procs.values():
+            try:
+                proc.wait(timeout=max(0.05, deadline - time.time()))
+            except Exception:
                 try:
-                    w.proc.wait(timeout=max(0.05, deadline - time.time()))
+                    proc.kill()
+                    killed.append(proc)
                 except Exception:
-                    try:
-                        w.proc.kill()
-                        killed.append(w.proc)
-                    except Exception:
-                        pass
+                    pass
         # a killed worker is not gone yet: one that held chips spends
         # seconds unmapping tens of GB of device memory, and until it is
         # done the chips are busy.  shutdown() returning means they are
-        # free — the next init() in this process may grant them at once.
-        deadline = time.time() + 30.0
+        # free — the next init() in this process may grant them at once —
+        # and that NO process of this node is left: a caller that counts
+        # processes when we return (a benchmark between two runs) must find
+        # none, so the wait has no 30 s cap that a 13 GB chip holder on a
+        # loaded host overruns; what ends it is the process being gone (a
+        # SIGKILL cannot be refused; SHUTDOWN_WAIT_S guards a wedged kernel)
+        deadline = time.time() + SHUTDOWN_WAIT_S
         for proc in killed:
             try:
                 proc.wait(timeout=max(0.05, deadline - time.time()))
             except Exception:
-                pass
+                logger.error("worker process %s outlived shutdown()", proc.pid)
         with self.lock:
             agents = [ns for ns in self.nodes.values() if ns.agent_conn is not None]
         for ns in agents:

@@ -1,13 +1,14 @@
 """KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
-EXAONE-MoE, Kimi-K2).
+EXAONE-MoE, Kimi-K2, Granite-hybrid).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
 
-- **Three kinds of cache** (:func:`init_cache`), chosen by what the family's
-  config says of its layers, never by its name:
+- **Four kinds of cache** (:func:`init_cache`), chosen by what the family's
+  config says of its layers, never by its name; three hold something per
+  POSITION, the fourth per REQUEST:
 
   1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
      KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
@@ -26,6 +27,18 @@ first-class TPU path, designed for XLA:
      of :mod:`ray_tpu.models.kimi_k2`).  Prefill (un-absorbed, k and v a head
      for the call only) keeps the rows its block hands over; the chunk's
      flush writes ONE tensor.
+  4. a STATE for a recurrent layer (``cfg.state_cache``; a Mamba-2 mixer,
+     ``RECURRENT`` in ``cfg.sliding_windows``): ``ssm`` ``[L_state, B, tiles,
+     state, heads a tile x head values]`` in float32 and ``conv`` ``[L_state, d_conv - 1, B,
+     width]``, the layer's last inputs.  NOTHING here grows with the
+     position: a slot costs the same at position 10 and at 100,000.  Prefill
+     writes a slot's state WHOLE, as it stands after the prompt's last real
+     token (which is what makes a reused slot clean); a decode step
+     overwrites it in place, and only for the rows that take the step
+     (:func:`ray_tpu.ops.ssm.state_update`: lowered for a TPU a Pallas kernel
+     over the slots that were active when the chunk began); a cut chunk has
+     advanced it ``n`` steps; there is nothing to flush.  The family's other
+     layers keep K and V in the slab (1).
 
 - **One block per family**: prefill and decode run the block training
   runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
@@ -119,8 +132,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import exaone_moe, gpt2, kimi_k2, llama
+from ray_tpu.models import exaone_moe, gpt2, granite_hybrid, kimi_k2, llama
 from ray_tpu.models.transformer import _attend
+from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import (
     DECODE_TILE,
     cache_flush,
@@ -143,9 +157,18 @@ from ray_tpu.ops.attention import (
 # family whose layers cache ONE latent row a position instead of K and V per
 # head says so in its config (``latent_cache``, with its own
 # ``attention_scale``), and its ``block`` hands the attention middle that row
-# as a fourth argument.
+# as a fourth argument.  A family some of whose layers attend NOTHING and
+# carry a per-request state instead says so in ``sliding_windows``
+# (``RECURRENT``) and ``state_cache``; its parameters are a stack of the
+# recurrent layers (``params[kind]``, leaves ``[L_kind, ...]``) beside a list
+# of the others, ``cfg.layer_runs`` says which layers follow each other, its
+# ``block`` takes the layer's ``kind`` and the mixer's middle, and the loops
+# roll each run of recurrent layers.
 FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
-            "kimi_k2": kimi_k2}
+            "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid}
+
+# a layer's entry in :func:`layer_windows` that attends no position at all
+RECURRENT = granite_hybrid.RECURRENT
 
 
 def family_of(cfg):
@@ -163,10 +186,20 @@ def kv_heads(cfg) -> int:
 def layer_windows(cfg) -> Tuple[int, ...]:
     """Per layer, the positions it attends: 0 is every one (a full layer), a
     window size ``W`` the last ``W`` (a window layer; ``cfg.sliding_windows``
-    of a family that mixes them, with one size)."""
+    of a family that mixes them, with one size), ``RECURRENT`` none (a layer
+    that carries a state: :func:`state_cache`)."""
     windows = tuple(getattr(cfg, "sliding_windows", ()) or (0,) * cfg.n_layers)
-    assert len(windows) == cfg.n_layers and len(set(windows) - {0}) <= 1, windows
+    assert len(windows) == cfg.n_layers and len(
+        set(windows) - {0, RECURRENT}) <= 1, windows
     return windows
+
+
+def state_cache(cfg) -> Optional[dict]:
+    """For a family with recurrent layers: ``{"ssm": (shape a slot a layer,
+    dtype), "conv": ((inputs kept, width), dtype)}``, what a slot holds of
+    such a layer whatever its position (``cfg.state_cache``).  None: every
+    layer caches positions."""
+    return getattr(cfg, "state_cache", None)
 
 
 def latent_cache(cfg) -> Optional[Tuple[int, int]]:
@@ -201,7 +234,10 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     R]``, a ring: position ``j`` lives at ``j % R`` (``ring_positions``), so
     a slot costs ``R`` positions however long its context.  A family of
     latent layers (:func:`latent_cache`): ``c`` ``[L, B, 1, row, S]``, one row
-    a position and NO second tensor."""
+    a position and NO second tensor.  The recurrent layers, for a family that
+    has them (:func:`state_cache`): ``ssm`` ``[L_state, B, ...]`` and ``conv``
+    ``[L_state, inputs kept, B, width]`` (the slots beside the width, so that
+    the chip pads neither), no positions at all."""
     windows = layer_windows(cfg)
     if latent_cache(cfg):
         assert not any(windows), windows
@@ -210,24 +246,31 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
                 "pos": jnp.zeros((n_slots,), jnp.int32)}
     slab = lambda layers, length: jnp.zeros(  # noqa: E731
         (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
-    n_full = windows.count(0)
+    n_full, n_state = windows.count(0), windows.count(RECURRENT)
     cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
              "pos": jnp.zeros((n_slots,), jnp.int32)}
-    if n_full < len(windows):
+    if n_full + n_state < len(windows):
         ring = ring_positions(max(windows))
-        cache.update(k_ring=slab(len(windows) - n_full, ring),
-                     v_ring=slab(len(windows) - n_full, ring))
+        cache.update(k_ring=slab(len(windows) - n_full - n_state, ring),
+                     v_ring=slab(len(windows) - n_full - n_state, ring))
+    if n_state:
+        (shape, dtype), ((kept, width), conv_dtype) = (
+            state_cache(cfg)[name] for name in ("ssm", "conv"))
+        cache.update(
+            ssm=jnp.zeros((n_state, n_slots, *shape), dtype),
+            conv=jnp.zeros((n_state, kept, n_slots, width), conv_dtype))
     return cache
 
 
-def _cache_scores_slab(q, k_all, v_all, l, mask):
+def _cache_scores_slab(q, k_all, v_all, l, mask, scale=None):
     """The cache half of :func:`_decode_attend` as masked einsums over layer
     ``l``'s whole padded slab ``[B, KV, dh, S]``, slot ``b`` attending the
     positions where ``mask [B, S]`` holds: what every platform can run, the
     plain reference the kernel is held to (``mask``: the positions below
     ``n[b]``; same result as
     :func:`ray_tpu.ops.attention.ragged_decode_attention`), and how a window
-    layer's ring is read (:func:`_ring_mask`)."""
+    layer's ring is read (:func:`_ring_mask`).  ``scale``: a family's own
+    (None: ``dh ** -0.5``)."""
     k, v = (lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
             for a in (k_all, v_all))
     dh = k.shape[2]
@@ -236,7 +279,8 @@ def _cache_scores_slab(q, k_all, v_all, l, mask):
     # preferred_element_type) — upcasting the whole cache each step
     # would double the dominant HBM traffic of decode
     s = jnp.einsum("bkgd,bkds->bkgs", q, k.astype(q.dtype),
-                   preferred_element_type=jnp.float32) / (dh ** 0.5)
+                   preferred_element_type=jnp.float32)
+    s = s / (dh ** 0.5) if scale is None else s * scale
     s = jnp.where(mask, s, -1e30)
     m = s.max(-1)
     e = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)  # n == 0: nothing
@@ -245,7 +289,7 @@ def _cache_scores_slab(q, k_all, v_all, l, mask):
     return acc, m, e.sum(-1)
 
 
-def _cache_scores(q, k_all, v_all, l, n, plan):
+def _cache_scores(q, k_all, v_all, l, n, plan, scale=None):
     """``q [B, KV, G, dh]`` against positions ``j < n[b]`` of layer ``l`` of
     the whole caches ``[L, B, KV, dh, S]``: ``(acc, m, d)``, the softmax
     un-normalised.  Lowered for a TPU, with a cache of whole 128-position
@@ -254,13 +298,13 @@ def _cache_scores(q, k_all, v_all, l, n, plan):
     program is lowered for and by the cache's shape, never by a flag."""
     below = lambda n: jnp.arange(k_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
     if plan is None:
-        return _cache_scores_slab(q, k_all, v_all, l, below(n))
+        return _cache_scores_slab(q, k_all, v_all, l, below(n), scale)
     return lax.platform_dependent(
         q, k_all, v_all, l, n, plan,
         tpu=lambda q, k, v, l, n, plan: ragged_decode_attention(
-            q, k, v, l, plan),
+            q, k, v, l, plan, scale=scale),
         default=lambda q, k, v, l, n, plan: _cache_scores_slab(
-            q, k, v, l, below(n)))
+            q, k, v, l, below(n), scale))
 
 
 def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int):
@@ -386,8 +430,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     ``(last_logits [B, V], cache)``.  Positions are 0..Tp-1, so a slot must
     be prefilled from scratch (pos resets to ``lengths``).  A full layer
     keeps every position of the prompt (its k, v; a latent layer its rows),
-    a window layer the last ``ring`` of each row (:func:`_ring_of`).  Where
-    the family's layers count what they
+    a window layer the last ``ring`` of each row (:func:`_ring_of`), a
+    recurrent layer its state as it stands after each row's last REAL token
+    and that row's last real inputs, written whole over the slot (a padded
+    position changes neither).  Where the family's layers count what they
     routed, the dispatch's counts come back as ``cache["routed"]`` (leaves
     stacked over the layers that route)."""
     fam = family_of(cfg)
@@ -409,8 +455,13 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
             return h, kv
 
         x, full = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
+        ringed = states = ()
+    elif state_cache(cfg):  # runs of recurrent layers rolled, the others listed
+        x, routed, full, states = _prefill_runs(
+            fam, params, cfg, x, attend, positions, lengths)
         ringed = ()
     else:  # kinds of layer mixed, listed: unrolled, each kind's k, v apart
+        states = ()
         valid = positions[None, :] < lengths[:, None]
         kept = {}
         for p, w in zip(params["layers"], layer_windows(cfg)):
@@ -431,11 +482,52 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     for t, name in zip(ringed, ("k_ring", "v_ring")):
         out[name] = cache[name].at[:, slots].set(_ring_of(
             t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
+    if states:  # [L_state, B, ...] and [L_state, B, kept, width]: whole slots
+        out["ssm"] = cache["ssm"].at[:, slots].set(states[0])
+        out["conv"] = cache["conv"].at[:, :, slots].set(
+            jnp.swapaxes(states[1], 1, 2).astype(cache["conv"].dtype))
     if routed:
-        out["routed"] = jax.tree.map(lambda *a: jnp.stack(a), *routed)
+        out["routed"] = routed if isinstance(routed, dict) else jax.tree.map(
+            lambda *a: jnp.stack(a), *routed)
     last = fam.unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
     return last[:, 0, :], out
+
+
+def _prefill_runs(fam, params, cfg, x, attend, positions, lengths):
+    """:func:`prefill_at`'s layers for a family with recurrent layers: every
+    run of them (``cfg.layer_runs``) one rolled loop over the kind's stacked
+    parameters, the attention layers between them listed.  Returns ``(x, the
+    layers' routing counts with their leaves stacked over the layers, the
+    attention layers' stacked k and v, the recurrent layers' stacked (state,
+    last inputs))``."""
+    valid = positions[None, :] < lengths[:, None]
+    recur = lambda xbc, dt, p: fam.mamba_whole(  # noqa: E731
+        xbc, dt, p, cfg, lengths)
+    routed, kept = [], {False: [], True: []}
+    for kind, first, count, at in cfg.layer_runs:
+        recurrent = layer_windows(cfg)[first] == RECURRENT
+        if recurrent:
+            def body(h, i, kind=kind):
+                h, counts, carried = fam.block(
+                    h, fam.layer_of(params[kind], i), cfg, recur, positions,
+                    kind=kind, valid=valid)
+                return h, (counts, carried)
+
+            x, (counts, carried) = lax.scan(body, x, at + jnp.arange(count))
+        else:
+            outs = []
+            for p in params[kind][at:at + count]:
+                x, *out = fam.block(x, p, cfg, attend, positions, kind=kind,
+                                    valid=valid)
+                outs.append(out)
+            counts, carried = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+        routed.append(counts)
+        kept[recurrent].append(carried)
+    full, states = (tuple(jnp.concatenate(t) for t in zip(*kept[kind]))
+                    for kind in (False, True))
+    # leaves stacked over the layers, in layer order
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *routed), full, states
 
 
 def prefill(params, cfg, tokens: jax.Array, lengths: jax.Array,
@@ -484,7 +576,11 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     they fall in, of the slots that were active when the chunk began only;
     elsewhere by a slice update a slot.  What a slot attends of the cache is
     fixed when the chunk begins (``live``), and so are both kernels' work
-    lists, built once here.  The step is ``serve-gpt2-xl-chat``'s
+    lists, built once here.  A recurrent layer's state is no column: it rides
+    the steps' carry, each step overwriting it in place for the rows that
+    take the step (frozen for a row that is inactive or stopped at EOS), so a
+    cut chunk leaves it ``n`` steps on and there is nothing to flush.  The
+    step is ``serve-gpt2-xl-chat``'s
     ``model.decode_step_ms``, the flush the ``cache_flush`` row of its
     ``breakdown.device_ops``."""
     fam = family_of(cfg)
@@ -515,13 +611,18 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         plan = ragged_decode_plan(live, S // DECODE_TILE)
         if steps <= DECODE_TILE:
             to_flush = cache_flush_plan(active, pos0, steps, S, written=n)
-    local = jnp.zeros(  # [L, steps, B, KV, dh]
-        (cfg.n_layers, steps, B, *old[0].shape[2:4]), old[0].dtype)
+    local = jnp.zeros(  # [L, steps, B, KV, dh], the layers that attend
+        (cfg.n_layers - windows.count(RECURRENT), steps, B,
+         *old[0].shape[2:4]), old[0].dtype)
+    # the recurrent layers' state, and the slots whose state a step moves
+    held = tuple(cache[name] for name in ("ssm", "conv") if name in cache)
+    moved = ssm.state_update_plan(active) if held and ssm.kernel_shapes(
+        held[0]) else None
     # a latent family's block in its decode form (the family's docstring)
     form = {"absorbed": True} if latent else {}
 
     def step(carry, i):
-        locs, pos, toks, act, rng = carry
+        locs, held, pos, toks, act, rng = carry
         rng, sub = jax.random.split(rng)
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
         x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
@@ -537,7 +638,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     return _latent_cache_scores(
                         q, old[0], at, live, plan, scale=scale, dv=latent[1])
                 if not w:
-                    return _cache_scores(q, *old, at, live, plan)
+                    return _cache_scores(q, *old, at, live, plan, scale)
                 return _cache_scores_slab(
                     q, cache["k_ring"], cache["v_ring"], at,
                     _ring_mask(live, pos, w, ring))
@@ -565,6 +666,42 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     x, p, cfg, attend, positions), carry)[0]
 
             x, *locs = lax.fori_loop(0, cfg.n_layers, rolled, (x, *locs))
+        elif held:  # runs of recurrent layers rolled, the others listed
+            for kind, first, count, at in cfg.layer_runs:
+                if windows[first] == RECURRENT:
+                    def recurrent(carry, l, kind=kind):
+                        x, state, tails = carry
+                        tail = lax.dynamic_index_in_dim(tails, l, 0, keepdims=False)
+                        moved_to = []
+
+                        def update(*inputs):
+                            with jax.named_scope("ssm.state_update"):
+                                new, y = ssm.state_update(
+                                    state, l, *inputs, act, moved)
+                            moved_to.append(new)
+                            return y
+
+                        def recur(xbc, dt, p):
+                            y, last = fam.mamba_step(xbc, dt, p, cfg, tail, update)
+                            return y, jnp.where(act[None, :, None], last, tail)
+
+                        x, counts, last = fam.block(
+                            x, fam.layer_of(params[kind], l), cfg, recur,
+                            positions, kind=kind, valid=act[:, None])
+                        return (x, moved_to[0], lax.dynamic_update_index_in_dim(
+                            tails, last, l, 0)), counts
+
+                    (x, *held), counts = lax.scan(
+                        recurrent, (x, *held), at + jnp.arange(count))
+                    counted.append(counts)
+                    continue
+                for j, p in enumerate(params[kind][at:at + count]):
+                    (x, *locs), counts = layer(
+                        at + j, 0, at + j, lambda x, attend, p=p, kind=kind: fam.block(
+                            x, p, cfg, attend, positions, kind=kind,
+                            valid=act[:, None]), (x, *locs))
+                    counted.append(jax.tree.map(lambda a: a[None], counts))
+            counted = jax.tree.map(lambda *a: jnp.concatenate(a), *counted)
         else:  # kinds of layer mixed, listed: unrolled, each kind's cache
             state, seen = (x, *locs), {}
             for l, (p, w) in enumerate(zip(params["layers"], windows)):
@@ -580,13 +717,16 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         pos = pos + act.astype(jnp.int32)
         if eos_id is not None:
             act = act & (nxt != eos_id)
-        # leaves stacked over the layers that route (None: nothing counted)
-        counted = jax.tree.map(lambda *a: jnp.stack(a), *counted) if counted else None
-        return (tuple(locs), pos, nxt, act, rng), (nxt, counted)
+        # leaves stacked over the layers that route (None: nothing counted;
+        # the rolled runs' come stacked)
+        if isinstance(counted, list):
+            counted = jax.tree.map(
+                lambda *a: jnp.stack(a), *counted) if counted else None
+        return (tuple(locs), tuple(held), pos, nxt, act, rng), (nxt, counted)
 
-    state = ((local,) * len(names), pos0, tokens, active, key)
+    state = ((local,) * len(names), held, pos0, tokens, active, key)
     if n is None:
-        (locs, pos, _, active, key), (emitted, routed) = lax.scan(
+        (locs, held, pos, _, active, key), (emitted, routed) = lax.scan(
             step, state, jnp.arange(steps))
     else:
         # the cut chunk: what the scan stacks a step is carried instead, the
@@ -598,7 +738,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     jax.tree.map(jnp.add, routed, counted))
 
         counted = jax.eval_shape(step, state, 0)[1][1]
-        (locs, pos, last, active, key), emitted, routed = lax.fori_loop(
+        (locs, held, pos, last, active, key), emitted, routed = lax.fori_loop(
             0, n, cut_step,
             (state, jnp.zeros((steps, B), jnp.int32),
              jax.tree.map(jnp.zeros_like, counted)))
@@ -607,7 +747,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # the chunk's columns of the layers of one kind (a family of one kind: all)
     of = lambda loc, kind: loc if not window else loc[  # noqa: E731
         jnp.asarray([l for l, w in enumerate(windows) if bool(w) == kind])]
-    out = {**cache, "pos": pos}
+    out = {**cache, "pos": pos, **dict(zip(("ssm", "conv"), held))}
     for name, big, loc in zip(names, old, locs):
         out[name] = _flush(big, of(loc, False), pos0, to_flush)
     if window:

@@ -630,7 +630,7 @@ def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
 
 def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             layer: jax.Array, plan: jax.Array, *,
-                            interpret=False):
+                            scale: Optional[float] = None, interpret=False):
     """The cache half of a decode step's attention, reading only what is
     live: ``q [B, KV, G, dh]`` (one query a head a slot) against layer
     ``layer`` of the WHOLE caches ``k, v [L, B, KV, dh, S]``, which stay in
@@ -640,8 +640,8 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     un-normalised, flash-decoding style, for the caller to merge with its
     other keys: ``acc [B, KV, G, dh]``, running max ``m`` and denominator
     ``d [B, KV, G]``, all f32.  A slot with ``n[b] == 0`` gives ``acc = 0,
-    d = 0, m = -1e30`` and moves no byte of cache.  Needs ``S % 128 == 0``
-    and ``dh % 8 == 0``."""
+    d = 0, m = -1e30`` and moves no byte of cache.  ``scale``: the scores'
+    (None: ``dh ** -0.5``).  Needs ``S % 128 == 0`` and ``dh % 8 == 0``."""
     B, KV, G, dh = q.shape
     S, T = k.shape[-1], DECODE_TILE
     assert S % T == 0 and dh % 8 == 0, (S, dh)
@@ -653,7 +653,8 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     whole = lambda *shape: pl.BlockSpec(  # noqa: E731
         shape, lambda i, *_: (0,) * len(shape))
     out = pl.pallas_call(
-        functools.partial(_ragged_decode_kernel, scale=dh ** -0.5, groups=G),
+        functools.partial(_ragged_decode_kernel, groups=G,
+                          scale=dh ** -0.5 if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),

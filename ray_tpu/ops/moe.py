@@ -178,10 +178,22 @@ def route_sigmoid_top_k(x: jax.Array, router_w: jax.Array, bias: jax.Array,
             scale * chosen / chosen.sum(-1, keepdims=True))
 
 
+def route_softmax_top_k(x: jax.Array, router_w: jax.Array, top_k: int):
+    """``x [N, D]`` -> ``(experts [N, k] int32, gates [N, k] float32)``.
+    Mixtral/Granite-style routing: the ``k`` largest router LOGITS are chosen
+    (no sigmoid, no selection bias, no scale) and the gates are the softmax
+    over those ``k`` alone.  Float32 at matmul precision "highest", for the
+    reason :func:`route_sigmoid_top_k` gives."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    chosen, experts = jax.lax.top_k(logits, top_k)
+    return experts.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+
+
 def held_experts_ffn(
     x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate: jax.Array,
     w_up: jax.Array, w_down: jax.Array, *, first_expert: int = 0,
-    valid: Optional[jax.Array] = None,
+    valid: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of a top-k SwiGLU expert layer, no token dropped.
 
@@ -194,6 +206,13 @@ def held_experts_ffn(
         valid: ``[N]`` bool, the real tokens.  Padding and the rows of slots
             that sit a step out are routed nowhere: they cost no expert
             matmul and no expert weight read, and their part of ``y`` is 0.
+        layer: for weights that hold the experts of SEVERAL layers stacked
+            (``[n_layers, n_held, D, F]``, a family whose layer loop is
+            rolled), the index (it may be traced) of the layer to use.  The
+            grouped matmuls are then given every layer's experts and sizes of
+            0 for all but this layer's: a group without rows costs nothing,
+            where a layer's weights sliced out to feed the kernel would be a
+            copy of them (170 MB a layer a decode step at Granite's widths).
 
     The ``N * k`` (token, expert) pairs are sorted by held expert (pairs of
     absent experts last) and the held ones go through grouped matmuls
@@ -208,7 +227,14 @@ def held_experts_ffn(
     and the valid tokens routed to each held expert.
     """
     N, D = x.shape
-    n_held, top_k = w_gate.shape[0], experts.shape[1]
+    widen = lambda sizes: sizes  # noqa: E731 — the groups' sizes as the kernel takes them
+    if layer is not None:
+        n_layers, n_held = w_gate.shape[:2]
+        w_gate, w_up, w_down = (
+            w.reshape(n_layers * n_held, *w.shape[2:]) for w in (w_gate, w_up, w_down))
+        widen = lambda sizes: jax.lax.dynamic_update_slice(  # noqa: E731
+            jnp.zeros((n_layers * n_held,), sizes.dtype), sizes, (layer * n_held,))
+    n_held, top_k = w_gate.shape[0] if layer is None else n_held, experts.shape[1]
     M = N * top_k
     local = experts.reshape(M) - first_expert
     held = (local >= 0) & (local < n_held)
@@ -223,14 +249,20 @@ def held_experts_ffn(
     gate_of = jnp.where(held, gates.reshape(M), 0.0)
 
     def experts_of(pairs, sizes):
-        rows = x[pairs // top_k]
+        rows, sizes = x[pairs // top_k], widen(sizes)
         h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate.astype(x.dtype), sizes))
              * jax.lax.ragged_dot(rows, w_up.astype(x.dtype), sizes))
         return jax.lax.ragged_dot(h, w_down.astype(x.dtype), sizes,
                                   preferred_element_type=jnp.float32)
 
     if M <= _ONE_BLOCK_PAIRS:
-        y = experts_of(order, tokens)             # [M, D], sorted by expert
+        # the TPU's grouped-matmul kernel takes whole sublane tiles of rows
+        # (a list of another length, 49 rows x 10, is computed densely:
+        # every row against every held expert); rows past the groups' sizes
+        # belong to no group
+        rows = order if M % 8 == 0 else jnp.concatenate(
+            [order, jnp.zeros((-M % 8,), order.dtype)])
+        y = experts_of(rows, tokens)[:M]          # [M, D], sorted by expert
         # pair (n, j) sits at row rank[n * k + j]; a row past the held pairs
         # holds nothing meant to be read, and its gate is 0
         rank = jnp.argsort(order).reshape(N, top_k)

@@ -812,6 +812,23 @@ def cmd_perf(args) -> None:
                     f"p50 {m['tpot_p50_s'] * 1e3:.2f}ms "
                     f"p95 {m['tpot_p95_s'] * 1e3:.2f}ms over a decode tick "
                     f"of {(m.get('baseline_s') or 0) * 1e3:.1f}ms")
+        states = {eid: m["state"] for eid, m in interference.items()
+                  if m.get("state")}
+        if states:
+            # recurrent layers: the rows of per-request state the decode
+            # steps moved against the rows that were live
+            out.append("")
+            out.append(f"{'RECURRENT STATE':<24} {'LAYERS':>6} {'ROW-MB':>7} "
+                       f"{'STEPS':>8} {'CHUNKS':>7} {'LIVE ROWS':>10} "
+                       f"{'UPDATED':>10} {'SHARE':>7}")
+            for eid, st_ in states.items():
+                live = st_["rows_live"]
+                out.append(
+                    f"{eid[:23]:<24} {st_['layers']:>6} "
+                    f"{st_['row_bytes'] / 1e6:>7.2f} {st_['steps']:>8} "
+                    f"{st_['dispatches']:>7} {live:>10} "
+                    f"{st_['rows_updated']:>10} "
+                    f"{100.0 * st_['rows_updated'] / live if live else 0.0:>6.1f}%")
     if not (st["count"] or comp or hbm or ttft or itl or interference):
         out.append("(no perf data recorded — run a StepProfiler-"
                    "instrumented train loop or serve LLM traffic; see "

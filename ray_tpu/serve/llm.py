@@ -157,6 +157,9 @@ class _TickMeter:
         self._period_s = 0.0    # of the tick being drained, so far
         self._counted = False   # it has a previous landing to start from
         self._since_emit = 0
+        # the engine's recurrent-state counters (its own dict, or None): they
+        # ride this meter's event to `ray_tpu perf`
+        self.state: Optional[Dict[str, int]] = None
 
     def begin(self, chained: bool) -> None:
         """The drain of one tick begins; ``chained``: it was dispatched
@@ -280,7 +283,8 @@ class _TickMeter:
             interleaved_ticks=self.ticks["interleaved"],
             decode_only_ticks=self.ticks["decode_only"],
             baseline_s=snap["decode_tick_baseline_s"],
-            tpot_p50_s=tpot.get("p50_s"), tpot_p95_s=tpot.get("p95_s"))
+            tpot_p50_s=tpot.get("p50_s"), tpot_p95_s=tpot.get("p95_s"),
+            **({"state": dict(self.state)} if self.state else {}))
 
 
 def cache_positions(largest_bucket: int, max_new_tokens: int,
@@ -435,8 +439,10 @@ class GenerationEngine:
 
         self._tile = DECODE_TILE
         windows = gen.layer_windows(cfg)
+        n_state = windows.count(gen.RECURRENT)
         self._layers = {"full": windows.count(0),
-                        "window": len(windows) - windows.count(0)}
+                        "window": len(windows) - windows.count(0) - n_state,
+                        **({"state": n_state} if n_state else {})}
         # per layer of the kind: a full layer reads a slot's live tiles, a
         # window layer every row's whole ring (two tiles at the published
         # window), whatever the context
@@ -451,7 +457,8 @@ class GenerationEngine:
         # cumulative routing counts of the dispatches drained so far, where
         # the family's layers count (leaves as the programs return them:
         # stacked over the layers that route); None until a first arrives
-        self._routed = {"prefill": None, "decode": None, "decode_steps": 0}
+        self._routed = {"prefill": None, "decode": None, "decode_steps": 0,
+                        "decode_dispatches": 0}
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
@@ -464,6 +471,26 @@ class GenerationEngine:
         self._tile_bytes = {
             "full": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg)),
             "window": DECODE_TILE * row_bytes("k_ring", "v_ring")}
+        # a family with recurrent layers: cumulative, over the steps the
+        # drained chunks really ran (host arithmetic, no device read): the
+        # rows whose state a step HAD to move (``rows_live``: the chunk's
+        # rows) and those the program's update touched (``rows_updated``: the
+        # same where the decode program was lowered with the kernel that
+        # walks them, every row of the cache where it runs the masked form);
+        # the layers with a state, and the bytes a row holds a layer (state
+        # and last inputs), from the cache's own shapes.  None: no such layer
+        self._state: Optional[Dict[str, int]] = None
+        if n_state:
+            from ray_tpu.ops import ssm
+
+            held, tails = self.cache["ssm"], self.cache["conv"]
+            self._state_kernel = (jax.devices()[0].platform == "tpu"
+                                  and ssm.kernel_shapes(held))
+            self._state = {
+                "rows_updated": 0, "rows_live": 0, "steps": 0, "dispatches": 0,
+                "layers": n_state,
+                "row_bytes": (held.nbytes // math.prod(held.shape[:2])
+                              + tails.nbytes // (n_state * tails.shape[2]))}
         self._key = jax.random.PRNGKey(seed)
 
         self._prefill_jit, self._decode_jit, decode_cut_jit = engine_programs(
@@ -524,6 +551,7 @@ class GenerationEngine:
         # engines inside one replica process
         self._ticks = _TickMeter(
             f"engine-{_os.getpid()}-{next(_ENGINE_SEQ)}")
+        self._ticks.state = self._state
         self._ttft_samples: "_deque[float]" = _deque(maxlen=4096)
         self._itl_samples: "_deque[float]" = _deque(maxlen=4096)
 
@@ -667,6 +695,7 @@ class GenerationEngine:
                            "tile_bytes": dict(self._tile_bytes)}
             prefill = {str(b): dict(v) for b, v in self._prefill.items()}
             moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
+            state = dict(self._state) if self._state else None
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
             stages = tracing.span_stats(STAGES + PER_GAP)
@@ -694,9 +723,13 @@ class GenerationEngine:
             # routing in the dispatches drained so far, prefills and decode
             # chunks apart (``tokens [layer][held expert]``, ``touched
             # [layer]``: held experts with any token, summed over calls and
-            # steps; ``decode_steps``: the chunk steps among them).
+            # steps; ``decode_steps``: the chunk steps among them, in
+            # ``decode_dispatches`` chunks, whole or cut).
             # Cumulative, like cache_tiles
             "moe": moe,
+            # a family with recurrent layers: the rows of state the decode
+            # steps moved against those they had to (``__init__``)
+            **({"state": state} if state else {}),
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -931,6 +964,7 @@ class GenerationEngine:
             self._routed[phase] = counts if had is None else {
                 k: had[k] + v for k, v in counts.items()}
             self._routed["decode_steps"] += steps
+            self._routed["decode_dispatches"] += phase == "decode"
 
     def _read_back(self, dev) -> tuple:
         """``np.asarray(dev)`` (blocks until the device has produced it), the
@@ -984,6 +1018,14 @@ class GenerationEngine:
                 landed, sum(len(adm) for adm, _, _ in pending.prefills),
                 len(pending.rows))
         self._count_routed("decode", pending.routed_dev, pending.steps)
+        if self._state is not None:
+            touched = (len(pending.rows) if self._state_kernel
+                       else self.n_slots + 1)
+            with self._lock:
+                self._state["steps"] += pending.steps
+                self._state["dispatches"] += 1
+                self._state["rows_live"] += len(pending.rows) * pending.steps
+                self._state["rows_updated"] += touched * pending.steps
         for i, req in pending.rows:
             if req.future.done():
                 continue

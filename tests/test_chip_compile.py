@@ -208,6 +208,44 @@ def _lower_kimi_cell(chip):
     ]
 
 
+# Granite-4.0-H-Small as the benchmark's granite-4.0-h-small-ep8 holds it:
+# the published widths (the config's defaults), layers 0-19, experts 0-8 of
+# 72, an eighth of the tied vocabulary
+GRANITE = dict(vocab_size=12_544, n_layers=20, experts_held=(0, 9))
+
+
+def _lower_granite_cell(chip):
+    """The serve-granite-4.0-h-small-ep8-chat cell's programs at 48 slots:
+    18 Mamba-2 layers in three rolled runs over ONE stack of parameters, a
+    float32 state of 4 MB a row a layer updated in place by the kernel that
+    walks the chunk's active slots, two attention layers' K/V slabs of 2,688
+    positions beside it, 9 held experts a layer through the grouped matmul
+    over the whole stack, every prefill bucket at the engine's width for it
+    (four rows of 64, two of 128, one of each wider bucket)."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("granite_hybrid", "4.0-h-small", **GRANITE)
+    n_slots, chunk = 48, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_058_678_528
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(2048, 512, chunk)))
+    assert set(cache) == {"k", "v", "pos", "ssm", "conv"}
+    assert cache["ssm"].shape == (18, 49, 64, 128, 128)
+    assert cache["ssm"].dtype == jnp.float32
+    assert cache["k"].shape == (2, 49, 8, 128, 2688)
+    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
+    assert [llm.call_rows(b, n_slots) for b in (64, 128, 256, 2048)] == [4, 2, 1, 1]
+    return [
+        prefill_of(2048),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
+        *map(prefill_of, (1024, 512, 256, 128, 64)),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -251,6 +289,7 @@ PROGRAMS = {
         chip, "gpt2", buckets=(64, 128, 256, 512), chunk=16, max_new=368, **XL),
     "serve_engine_exaone_cell": _lower_exaone_cell,
     "serve_engine_kimi_cell": _lower_kimi_cell,
+    "serve_engine_granite_cell": _lower_granite_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -264,6 +303,9 @@ FLUSHED_IN_PLACE = {
     "serve_engine_gpt2_xl_cell": (2, "bf16[48,17,25,64,896]", 1_408_124_416),
     "serve_engine_exaone_cell": (2, "bf16[1,33,8,128,4736]", 668_006_400),
     "serve_engine_kimi_cell": (1, "bf16[6,33,1,576,9344]", 258_276_352),
+    # no parent: the decode chunk plans 0.08 GB of temporaries (PR 42); a
+    # copy of the 3.7 GB of state or of a slab would show at once
+    "serve_engine_granite_cell": (2, "bf16[2,49,8,128,2688]", 200_000_000),
 }
 
 
@@ -412,6 +454,27 @@ def test_program_compiles_for_v5e(compiled, name):
         assert "ragged_latent_decode_attention" in programs[1].as_text()
         assert programs[0].as_text().count("tpu_custom_call") >= 6 + 5 * 3
         assert all(10.4e9 < need < 15.5e9 for need in needs), needs
+    if name == "serve_engine_granite_cell":
+        # the state's update reaches the chip's compiler as the kernel, once
+        # a rolled run of Mamba layers (three runs), the whole cache of
+        # states its operand AND its result; the attention layers' ragged
+        # kernel; the grouped matmuls over the WHOLE stack of experts (no
+        # layer's 56 MB sliced out to feed them); 8.12 GB of weights and
+        # 4.82 GB of cache resident: 13.0-13.8 GB a program
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            updates = [line for line in text.splitlines()
+                       if re.search(r"%ssm_state_update[.\d]* = ", line)]
+            assert len(updates) == 3, len(updates)
+            for line in updates:
+                assert "f32[18,49,64,128,128]" in line.split("custom-call(")[0]
+                assert "output_to_operand_aliasing={{0}: (3, {})}" in line
+            assert text.count("ragged_decode_attention") >= 2
+            assert text.count("tpu_custom_call") >= 3 + 2 + 2 + 5 * 3
+            for line in text.splitlines():
+                made = line.split(" fusion(")[0]
+                assert made == line or " bf16[9,4096,768]{" not in made, line[:200]
+        assert all(12.9e9 < need < 14.0e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; dq + dk/dv + the forward they differentiate
