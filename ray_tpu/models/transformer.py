@@ -32,6 +32,7 @@ Design choices, all TPU-motivated:
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import FLASH_RESIDUALS, attention, flash_plan
 from ray_tpu.ops.layers import dense, layernorm
 from ray_tpu.ops.moe import init_moe_params, moe_ffn, moe_logical_axes
 from ray_tpu.ops.ring_attention import ring_attention
@@ -165,23 +166,33 @@ def _attend(q, k, v, *, causal: bool, mesh: Optional[Mesh], window: int = 0,
     each KV head serving ``H // KV`` query heads, sequence-parallel when the
     mesh has an sp axis; ``window``: a window layer's band (0: none; not
     under sp); ``scale``: a family's own (None: ``dh ** -0.5``; not under
-    sp).  Carries nothing out of the layer."""
+    sp).  Carries nothing out of the layer.
+
+    Under a mesh, ring attention and the Pallas pair (``ops.attention.
+    flash_plan``: the shapes that go to it where the step is lowered for a
+    TPU) run under ``jax.shard_map``, each chip on its own sequences (``dp``,
+    ``fsdp``) and heads (``tp``): GSPMD cannot partition a ``pallas_call``,
+    and would gather q, k and v onto every chip to run it whole."""
     if k.shape[1] != q.shape[1]:  # GQA
         k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
-    if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
+    attend = partial(attention, causal=causal, window=window, scale=scale)
+    if mesh is None or getattr(jax.typeof(q), "vma", None):
+        return attend(q, k, v), None  # no mesh, or a manual region already
+    ring = "sp" in mesh.axis_names and mesh.shape["sp"] > 1
+    batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
+    heads = "tp" if "tp" in mesh.axis_names else None
+    if ring:
         assert not window and scale is None, "no band, no scale of its own under sp"
-        batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
-        heads = "tp" if "tp" in mesh.axis_names else None
-        spec = P(batch, heads, "sp", None)
-        sm = jax.shard_map(
-            partial(ring_attention, axis_name="sp", causal=causal),
-            mesh=mesh,
-            in_specs=(spec,) * 3,
-            out_specs=spec,
-            check_vma=False,
-        )
-        return sm(q, k, v), None
-    return attention(q, k, v, causal=causal, window=window, scale=scale), None
+        attend = partial(ring_attention, axis_name="sp", causal=causal)
+    elif (flash_plan(q.shape, k.shape, v.shape, causal=causal,
+                     window=window) is None
+          or q.shape[0] % math.prod(mesh.shape[a] for a in batch or ())
+          or q.shape[1] % (mesh.shape["tp"] if heads else 1)):
+        return attend(q, k, v), None
+    spec = P(batch, heads, "sp" if ring else None, None)
+    sm = jax.shard_map(attend, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                       check_vma=False)
+    return sm(q, k, v), None
 
 
 # block parameters used in the dtype they are stored in; every other one is
@@ -274,9 +285,16 @@ def apply_stack(
 
     if cfg.remat:
         if cfg.remat_policy == "dots":
+            # the weight matmuls' outputs and, where attention ran as the
+            # Pallas pair, its result and logsumexp (17.3 MB a layer at the
+            # medium cell's shape): the backward pass then runs no forward
+            # kernel again
+            policies = jax.checkpoint_policies
             body = jax.checkpoint(
                 body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                policy=policies.save_from_both_policies(
+                    policies.dots_with_no_batch_dims_saveable,
+                    policies.save_only_these_names(*FLASH_RESIDUALS)),
             )
         elif cfg.remat_policy == "full":
             body = jax.checkpoint(body)

@@ -1,23 +1,34 @@
 """Attention implementations with one contract — ``[B, H, T, D]`` q/k/v.
 
-:func:`attention` dispatches by shape.  Its thresholds predate the chip
-and no benchmark cell sits on either side of any of them (ROADMAP D7): the
-cells run :func:`causal_skip_attention` (training at T=1,024, the 512
-prefill bucket) and :func:`full_attention` (the 64/128/256 buckets); the
-other paths have no cell.
+:func:`attention` dispatches by shape and, for the Pallas pair, by the
+platform the program is lowered for (``lax.platform_dependent``; no flag).
+Which benchmark cell runs which path: training at T=1,024 (both train cells)
+and every prefill bucket from 1,024 positions up (K-EXAONE's full layer,
+Kimi-K2's un-absorbed form, Granite's two attention layers) run
+:func:`flash_attention_tpu` on the chip; the 512 bucket (GPT-2 XL's widest)
+runs :func:`causal_skip_attention`, the 64/128/256 buckets
+:func:`full_attention`, window layers :func:`band_attention`.  The crossover
+(``FLASH_MIN_T``) is the chip's: the op-level table of both paths at the
+cells' shapes and the train steps with either path are in PERF.md, section 6,
+PR 43.  Off the TPU every one of these calls takes the XLA paths, which are
+also what the tests hold the kernels to.
 
-- :func:`causal_skip_attention` — the causal path at moderate T: unrolled
-  q-blocks contracting only visible keys (~40% of the FLOPs of masked full
-  attention skipped at T=1024), bf16 matmuls with f32 accumulation.
+- :func:`flash_attention_tpu` — the Pallas pair: an MXU-tiled forward kernel
+  with online softmax (``flash_attention_fwd``) and ONE backward kernel
+  (``flash_attention_bwd``: dq, dk and dv from the saved logsumexp,
+  recompute-free); no score leaves VMEM.  Heads of 64 or 128 are read and
+  written in the projections' own layout ``[B, T, H x d]``, two heads of 64
+  side by side on the 128 lanes (:func:`_flash_pack`), so XLA re-lays nothing
+  out around the kernels.  Its result and logsumexp carry names
+  (``FLASH_RESIDUALS``) so that a remat policy can keep them.
+- :func:`causal_skip_attention` — the causal XLA path at moderate T: unrolled
+  q-blocks contracting only visible keys, scores materialized in float32,
+  bf16 matmuls with f32 accumulation.
 - :func:`full_attention` — masked materialized-scores path (non-causal,
   or shapes causal-skip can't take).
 - :func:`blockwise_attention` — online-softmax ``lax.scan`` over k/v
   blocks; O(block) memory, any length (pads+masks), differentiable; also
   the inner block the ring-attention layer reuses.
-
-- :func:`flash_attention_tpu` — pallas MXU-tiled kernels for BOTH forward
-  and backward (dq/dk/dv rebuilt from the saved logsumexp, recompute-free).
-  The dispatch selects it from 8k tokens on TPU.
 
 Not in the dispatch:
 
@@ -64,6 +75,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
@@ -162,275 +174,303 @@ from jax.experimental import pallas as pl  # noqa: E402
 from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 
-def _masked_scores(q_ref, k_ref, qi, ki, *, scale, causal, block_q, block_k,
-                   q_offset):
-    """scale·QKᵀ for one (q block, k block) cell, causal-masked with the
-    bottom-right-aligned diagonal.  Shared by the forward and both backward
-    kernels so masking semantics can never desynchronize."""
-    s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    if causal:
-        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    return s
+def _nt(a, b):
+    """``a [m, d] x b [n, d]^T -> [m, n]``, float32 accumulation."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
-def _block_visible(qi, ki, *, block_q, block_k, q_offset):
-    """True iff the (qi, ki) cell has any unmasked element — cells fully
-    above the causal diagonal are skipped (≈2x MXU work saved at long T)."""
-    return ki * block_k <= q_offset + (qi + 1) * block_q - 1
+def _nn(a, b):
+    """``a [m, n] x b [n, d] -> [m, d]``, float32 accumulation."""
+    return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                  *, scale: float, causal: bool, block_q: int, block_k: int,
-                  q_offset: int):
-    """Grid = (batch*heads, n_q_blocks, n_k_blocks); the k axis is the
-    innermost (sequential) dimension, so the f32 scratch (acc, m, l)
-    carries the online softmax across k steps of one q block."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _causal_mask(first_q, first_k, shape, *, keys_first: bool = False):
+    """Query position >= key position over a ``[queries, keys]`` cell (``[keys,
+    queries]`` if ``keys_first``) whose first query and key sit at those
+    positions.  One place for both kernels, so their masks can never differ."""
+    q_pos = first_q + lax.broadcasted_iota(jnp.int32, shape, int(keys_first))
+    k_pos = first_k + lax.broadcasted_iota(jnp.int32, shape, int(not keys_first))
+    return q_pos >= k_pos
+
+
+def _own_lanes(x, i: int, heads: int):
+    """``x [rows, heads x d]`` with every lane but head ``i``'s zeroed: a
+    contraction over all the lanes against it is that head's alone.  (With
+    narrow heads side by side on the 128 lanes the MXU pass is as deep as it
+    would be for one of them.)"""
+    if heads == 1:
+        return x
+    d = x.shape[-1] // heads
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= i * d) & (lane < (i + 1) * d), x, jnp.zeros_like(x))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_own, m_ref, l_ref,
+                  acc_ref, *, scale: float, causal: bool, block_q: int,
+                  block_k: int, q_offset: int):
+    """Grid = (batch, lane blocks of heads, n_q_blocks, n_k_blocks); the k
+    axis is the innermost (sequential) dimension, so the f32 scratch (acc, m,
+    l: one of each a head of the lane block) carries the online softmax
+    across k steps of one q block.  A lane block is ``heads`` heads side by
+    side (two of 64 on the 128 lanes, as the projections leave them; one
+    where a head fills them): a head's scores are its queries, the others'
+    lanes zeroed once a q block (``q_own``), against the block's keys, and its
+    ``P V`` keeps its own lanes at the end.  Row statistics stay ``[bq, 1]``
+    columns (a lane broadcast against the scores).
+
+    The causal diagonal (bottom-right aligned: query ``i`` is position
+    ``q_offset + i``) leaves a cell wholly above it out (half the work at long
+    T), and only a cell it crosses pays the iota, compare and select."""
+    qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    heads = q_own.shape[0]
+    first_q, first_k = q_offset + qi * block_q, ki * block_k
 
     @pl.when(ki == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for i in range(heads):
+            q_own[i] = _own_lanes(q_ref[0], i, heads)
 
-    visible = (
-        _block_visible(qi, ki, block_q=block_q, block_k=block_k, q_offset=q_offset)
-        if causal else ki >= 0
-    )
+    def fold(masked: bool):
+        k, v = k_ref[0], v_ref[0]
+        mask = _causal_mask(first_q, first_k, (block_q, block_k)) if masked else None
+        for i in range(heads):
+            s = _nt(q_own[i], k) * scale
+            if masked:
+                s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[i]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[i] = l_ref[i] * alpha + p.sum(axis=-1, keepdims=True)
+            m_ref[i] = m_new
+            acc_ref[i] = acc_ref[i] * alpha + _nn(p.astype(v.dtype), v)
 
-    @pl.when(visible)
-    def _():
-        s = _masked_scores(q_ref, k_ref, qi, ki, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k, q_offset=q_offset)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=-1)
-        m_ref[:, 0] = m_new
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    if causal:
+        whole = first_k + block_k - 1 <= first_q
+        pl.when(whole)(lambda: fold(False))
+        pl.when((first_k <= first_q + block_q - 1) & jnp.logical_not(whole))(
+            lambda: fold(True))
+    else:
+        fold(False)
 
     @pl.when(ki == nk - 1)
     def _():
-        o_ref[0] = (acc_ref[:] / l_ref[:, 0][:, None]).astype(o_ref.dtype)
-        # logsumexp residual: the backward kernels rebuild P = exp(S - LSE)
-        # from it without re-running the online softmax.  Kept as a
-        # [bq, 1] column (TPU blocks want the sublane dim divisible by 8).
-        lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(l_ref[:, 0])
+        out = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+        for i in range(heads):
+            out = out + _own_lanes(acc_ref[i] / l_ref[i], i, heads)
+            # logsumexp residual: the backward kernel rebuilds P = exp(S -
+            # LSE) from it without re-running the online softmax.  It leaves
+            # with positions on the LANES (a whole-tile transpose): a ``[..,
+            # T, 1]`` result is padded to 128 lanes in HBM, 64 MB for 0.5
+            lse = m_ref[i] + jnp.log(l_ref[i])
+            lse_ref[0, 0, i:i + 1, :] = jnp.broadcast_to(lse, (block_q, 128)).T[:1]
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _flash_forward(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
-):
-    """Returns (out [B,H,Tq,Dv], lse [B,H,Tq] f32); ``v`` may be another
-    width than ``q`` and ``k`` (latent attention's 128 against 192)."""
-    b, h, t_q, d = q.shape
-    t_k, dv = k.shape[-2], v.shape[-1]
+# one v5e core has 128 MiB of VMEM; the default scoped limit (16 MiB) is under
+# what 1,024 x 1,024 float32 score tiles and a resident sequence take
+_FLASH_VMEM = 64 * 1024 * 1024
+
+
+def _flash_blocks(t_q: int, t_k: int, block_q: int, block_k: int):
     bq, bk = min(block_q, t_q), min(block_k, t_k)
     if t_q % bq or t_k % bk:
         raise ValueError(f"seq lens ({t_q},{t_k}) not divisible by blocks ({bq},{bk})")
-    qr = q.reshape(b * h, t_q, d)
-    kr = k.reshape(b * h, t_k, d)
-    vr = v.reshape(b * h, t_k, dv)
-    grid = (b * h, t_q // bq, t_k // bk)
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        q_offset=t_k - t_q,
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    return bq, bk
+
+
+def _flash_pack(q, k, v):
+    """The kernels' operands: ``[N, T, lanes]``, walked a lane block of
+    ``heads`` heads at a time.  Heads that tile the 128 lanes (64 | 128 wide,
+    keys and values alike) stay as the projections leave them, ``[B, T, H x
+    d]``: the caller's ``[B, H, T, d]`` is a transpose of that, which XLA
+    cancels against this one, so nothing is re-laid out around the kernels and
+    no lane is padding.  Other widths (latent attention's 192 | 128) go a
+    (batch x head) row each, one lane block.  Returns ``(pack, unpack, (heads,
+    q's and v's lane-block width))``; ``unpack`` gives ``[B, H, T, d]`` back."""
+    b, h, _, d = q.shape
+    dv = v.shape[-1]
+    heads = 128 // d if d == dv and 128 % d == 0 else 0
+    if heads and h % heads == 0:
+        pack = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+            b, x.shape[2], h * x.shape[3])
+        unpack = lambda x: x.reshape(b, x.shape[1], h, -1).transpose(0, 2, 1, 3)  # noqa: E731
+        return pack, unpack, (heads, 128, 128)
+    pack = lambda x: x.reshape(b * h, *x.shape[2:])  # noqa: E731
+    unpack = lambda x: x.reshape(b, h, *x.shape[1:])  # noqa: E731
+    return pack, unpack, (1, d, dv)
+
+
+def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
+                   block_q: int, block_k: int, interpret: bool):
+    """Packed operands (:func:`_flash_pack`) in; returns the packed result
+    ``[N, Tq, lanes]`` and the logsumexp ``[N, lane blocks, heads, Tq]`` f32.
+    ``v`` may be another width than ``q`` and ``k`` (latent attention's 128
+    against 192)."""
+    heads, lw, lwv = layout
+    n, t_q, w = qp.shape
+    t_k, nb = kp.shape[1], w // lw
+    bq, bk = _flash_blocks(t_q, t_k, block_q, block_k)
+    nk, q_offset = t_k // bk, t_k - t_q
+    # a cell above the causal diagonal asks for the key block its neighbour
+    # already holds, and the pipeline fetches nothing for it
+    last_k = lambda qi: jnp.clip(  # noqa: E731
+        (q_offset + (qi + 1) * bq - 1) // bk, 0, nk - 1)
+    by_q = lambda i, hb, qi, ki: (i, qi, hb)  # noqa: E731
+    by_k = lambda i, hb, qi, ki: (  # noqa: E731
+        i, jnp.minimum(ki, last_k(qi)) if causal else ki, hb)
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, q_offset=q_offset),
+        grid=(n, nb, t_q // bq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, dv), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bq, lw), by_q),
+            pl.BlockSpec((1, bk, lw), by_k),
+            pl.BlockSpec((1, bk, lwv), by_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, lwv), by_q),
+            pl.BlockSpec((1, 1, heads, bq), lambda i, hb, qi, ki: (i, hb, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_q, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, t_q, vp.shape[2]), qp.dtype),
+            jax.ShapeDtypeStruct((n, nb, heads, t_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max
-            pltpu.VMEM((bq, 1), jnp.float32),   # running denom
-            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
+            pltpu.VMEM((heads, bq, lw), qp.dtype),      # a head's own lanes of q
+            pltpu.VMEM((heads, bq, 1), jnp.float32),    # running max
+            pltpu.VMEM((heads, bq, 1), jnp.float32),    # running denom
+            pltpu.VMEM((heads, bq, lwv), jnp.float32),  # output accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM),
+        name="flash_attention_fwd",
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(b, h, t_q, dv), lse.reshape(b, h, t_q)
+    )(qp, kp, vp)
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                     dq_acc, *, scale: float, causal: bool,
-                     block_q: int, block_k: int, q_offset: int):
-    """dQ: grid (bh, n_q, n_k), k innermost; one q block accumulates
-    dQ = sum_k dS @ K with dS = P * (dO Vᵀ - Δ) * scale, P = exp(S - LSE)
-    rebuilt from the forward's logsumexp (recompute-free backward,
-    FlashAttention-2 eq. 13-16)."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, heads: int,
+                      scale: float, causal: bool, block_q: int, block_k: int,
+                      q_offset: int):
+    """dQ, dK and dV in ONE kernel (five matmuls and one exp a cell, where a
+    ``dq`` and a ``dk, dv`` kernel took seven and two): grid (batch, lane
+    blocks of heads, n_k_blocks); a row's queries, dO, logsumexp and Δ stay in
+    VMEM while its key blocks go by, and a rolled loop walks the query chunks
+    a key block sees, from the causal diagonal on, masking only the chunks the
+    diagonal crosses.  P = exp(S - LSE) is rebuilt from the forward's
+    logsumexp (recompute-free backward, FlashAttention-2 eq. 13-16): dV = sum
+    Pᵀ dO, dS = P (dO Vᵀ - Δ), dK = scale sum dSᵀ Q, and dQ = scale sum dS K
+    into a float32 scratch of the whole row, written when the row's last key
+    block is done.  A cell is computed KEYS FIRST (Sᵀ = K Qᵀ, ``[bk, bq]``):
+    Pᵀ and dSᵀ are then the left operands of plain matmuls, and LSE and Δ are
+    used as the rows (positions on the lanes) they are stored as.  Of a lane
+    block of several heads, a head's cell contracts ITS lanes of K and V (the
+    others zeroed once a key block), so dS K lands on its own lanes of dQ, and
+    dK and dV keep their own lanes at the end."""
+    ki, nk = pl.program_id(2), pl.num_programs(2)
+    nq = q_ref.shape[1] // block_q
+    first_k = ki * block_k
 
     @pl.when(ki == 0)
     def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    visible = (
-        _block_visible(qi, ki, block_q=block_q, block_k=block_k, q_offset=q_offset)
-        if causal else ki >= 0
-    )
+    whole = 0
+    if causal:
+        # the first chunk of queries whose last row sees this key block, and
+        # the first whose FIRST row sees its last key
+        seen = jnp.clip((first_k - q_offset) // block_q, 0, nq)
+        whole = jnp.clip(-((q_offset - first_k - block_k + 1) // block_q), 0, nq)
+    dk_all = jnp.zeros(k_ref.shape[1:], jnp.float32)
+    dv_all = jnp.zeros(v_ref.shape[1:], jnp.float32)
+    for i in range(heads):
+        k, v = (_own_lanes(ref[0], i, heads) for ref in (k_ref, v_ref))
 
-    @pl.when(visible)
-    def _():
-        s = _masked_scores(q_ref, k_ref, qi, ki, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k, q_offset=q_offset)
-        p = jnp.exp(s - lse_ref[0])               # [bq,1] bcast -> [bq, bk]
-        do = do_ref[0]
-        dp = jax.lax.dot_general(                 # dO @ Vᵀ  [bq, bk]
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0]) * scale
-        k = k_ref[0]
-        dq_acc[:] += jax.lax.dot_general(         # dS @ K  [bq, d]
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def chunk(j, carry, masked: bool, i=i, k=k, v=v):
+            dk, dv = carry
+            rows = pl.ds(pl.multiple_of(j * block_q, block_q), block_q)
+            stat = pl.ds(i * nq + j, 1)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            s = _nt(k, q) * scale                               # [bk, bq]
+            if masked:
+                s = jnp.where(_causal_mask(q_offset + j * block_q, first_k,
+                                           s.shape, keys_first=True), s, NEG_INF)
+            p = jnp.exp(s - lse_ref[0, 0, stat, :])
+            dv = dv + _nn(p.astype(do.dtype), do)
+            ds = (p * (_nt(v, do) - delta_ref[0, 0, stat, :])).astype(q.dtype)
+            dk = dk + _nn(ds, q)
+            dq_acc[rows, :] += lax.dot_general(                 # dS K
+                ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return dk, dv
+
+        carry = (jnp.zeros_like(dk_all), jnp.zeros_like(dv_all))
+        if causal:
+            carry = lax.fori_loop(
+                seen, whole, functools.partial(chunk, masked=True), carry)
+        dk, dv = lax.fori_loop(
+            whole, nq, functools.partial(chunk, masked=False), carry)
+        dk_all = dk_all + _own_lanes(dk, i, heads)
+        dv_all = dv_all + _own_lanes(dv, i, heads)
+    dk_ref[0] = (dk_all * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_all.astype(dv_ref.dtype)
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                      causal: bool, block_q: int, block_k: int,
-                      q_offset: int):
-    """dK/dV: grid (bh, n_k, n_q), q innermost; one k block accumulates
-    dV = sum_q Pᵀ @ dO and dK = sum_q dSᵀ @ Q."""
-    kbi = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(qi == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    visible = (
-        _block_visible(qi, kbi, block_q=block_q, block_k=block_k, q_offset=q_offset)
-        if causal else qi >= 0
-    )
-
-    @pl.when(visible)
-    def _():
-        s = _masked_scores(q_ref, k_ref, qi, kbi, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k, q_offset=q_offset)
-        p = jnp.exp(s - lse_ref[0])               # [bq,1] bcast -> [bq, bk]
-        do = do_ref[0]
-        dv_acc[:] += jax.lax.dot_general(         # Pᵀ @ dO  [bk, d]
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0]) * scale
-        q = q_ref[0]
-        dk_acc[:] += jax.lax.dot_general(         # dSᵀ @ Q  [bk, d]
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(qi == nq - 1)
-    def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _flash_backward(q, k, v, out, lse, g, *, causal, scale,
+def _flash_backward(qp, kp, vp, outp, lse, gp, *, layout, causal, scale,
                     block_q, block_k, interpret):
-    b, h, t_q, d = q.shape
-    t_k, dv = k.shape[-2], v.shape[-1]
-    bq, bk = min(block_q, t_q), min(block_k, t_k)
-    qr = q.reshape(b * h, t_q, d)
-    kr = k.reshape(b * h, t_k, d)
-    vr = v.reshape(b * h, t_k, dv)
-    dor = g.reshape(b * h, t_q, dv)
-    lser = lse.reshape(b * h, t_q, 1)
-    # Δ = rowsum(dO ⊙ O): one fused elementwise reduce, cheap in XLA
+    """Packed operands, result and cotangent in; packed ``(dq, dk, dv)``."""
+    heads, lw, lwv = layout
+    n, t_q, w = qp.shape
+    t_k, nb = kp.shape[1], w // lw
+    bq, bk = _flash_blocks(t_q, t_k, block_q, block_k)
+    # Δ = rowsum(dO ⊙ O) a head: one fused elementwise reduce, cheap in XLA;
+    # like LSE ``[N, lane blocks, heads x chunks of queries, bq]``: a chunk of
+    # a head's queries a ROW, positions on the lanes
+    d_head = lwv // heads
     delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).reshape(b * h, t_q, 1)
-
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh, a, b2: (bh, a, 0))
-    do_spec = pl.BlockSpec((1, bq, dv), lambda bh, a, b2: (bh, a, 0))
-    row_spec = pl.BlockSpec((1, bq, 1), lambda bh, a, b2: (bh, a, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, q_offset=t_k - t_q),
-        grid=(b * h, t_q // bq, t_k // bk),
-        in_specs=[
-            q_spec,                                                # q by qi
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, dv), lambda bh, qi, ki: (bh, ki, 0)),
-            do_spec,                                               # dO by qi
-            row_spec,                                              # lse
-            row_spec,                                              # delta
-        ],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        (gp.astype(jnp.float32) * outp.astype(jnp.float32)).reshape(
+            n, t_q, nb * heads, d_head), axis=-1)                # [N, T, H]
+    stats = lambda x: x.reshape(n, nb, heads * (t_q // bq), bq)  # noqa: E731
+    row_of_q = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, t_q, width), lambda i, hb, ki: (i, 0, hb))
+    by_k = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, bk, width), lambda i, hb, ki: (i, ki, hb))
+    stat = pl.BlockSpec((1, 1, heads * (t_q // bq), bq),
+                        lambda i, hb, ki: (i, hb, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, heads=heads, scale=scale,
+                          causal=causal, block_q=bq, block_k=bk,
+                          q_offset=t_k - t_q),
+        grid=(n, nb, t_k // bk),
+        in_specs=[row_of_q(lw), by_k(lw), by_k(lwv), row_of_q(lwv), stat, stat],
+        out_specs=[row_of_q(lw), by_k(lw), by_k(lwv)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (qp, kp, vp)],
+        scratch_shapes=[pltpu.VMEM((t_q, lw), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM),
+        name="flash_attention_bwd",
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
+    )(qp, kp, vp, gp, stats(lse),
+      stats(delta.transpose(0, 2, 1).reshape(n, nb, heads, t_q)))
 
-    k_spec = pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0))
-    v_spec = pl.BlockSpec((1, bk, dv), lambda bh, ki, qi: (bh, ki, 0))
-    dk, dv_ = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, q_offset=t_k - t_q),
-        grid=(b * h, t_k // bk, t_q // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),  # q
-            k_spec,                                                    # k
-            v_spec,                                                    # v
-            pl.BlockSpec((1, bq, dv), lambda bh, ki, qi: (bh, qi, 0)),  # dO
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # lse
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # delta
-        ],
-        out_specs=[k_spec, v_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_k, dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
-    return (
-        dq.reshape(b, h, t_q, d),
-        dk.reshape(b, h, t_k, d),
-        dv_.reshape(b, h, t_k, dv),
-    )
+
+# what a remat policy may keep of a layer's attention so that the backward
+# pass does not run the forward kernel again (``save_only_these_names``)
+FLASH_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
+# the backward kernel's blocks: at most this (the chip's sweep, PERF.md
+# section 6, PR 43: 512 x 512 cells beat 256 and 1,024 on the v5e)
+_FLASH_BWD_BLOCK = 512
 
 
 @functools.partial(
@@ -441,34 +481,38 @@ def flash_attention_tpu(
     causal: bool = False, scale: Optional[float] = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
 ) -> jax.Array:
-    """Pallas flash attention: MXU-tiled forward AND backward.  The
-    backward is recompute-free — P is rebuilt from the forward's saved
-    logsumexp, never materializing the full score matrix (the standard
-    dq/dk/dv flash backward)."""
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-    out, _ = _flash_forward(
-        q, k, v, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
-    return out
+    """Pallas flash attention: an MXU-tiled forward kernel and ONE backward
+    kernel.  The backward is recompute-free: P is rebuilt from the forward's
+    saved logsumexp, never materializing the full score matrix (the standard
+    flash backward, dq and dk/dv fused); it keeps a row's queries and dO in
+    VMEM (``flash_plan`` bounds the length).  ``block_q``, ``block_k``: the
+    forward's; the backward's are these up to 512.  Heads of 64 or 128 are
+    read and written where the projections leave them (:func:`_flash_pack`)."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    out, lse = _flash_forward(
-        q, k, v, causal=causal, scale=scale,
+    pack, unpack, layout = _flash_pack(q, k, v)
+    qp, kp, vp = pack(q), pack(k), pack(v)
+    outp, lse = _flash_forward(
+        qp, kp, vp, layout=layout, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    return out, (q, k, v, out, lse)
+    outp, lse = map(checkpoint_name, (outp, lse), FLASH_RESIDUALS)
+    return unpack(outp), (q, k, v, outp, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
+    q, k, v, outp, lse = res
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_backward(
-        q, k, v, out, lse, g, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+    pack, unpack, layout = _flash_pack(q, k, v)
+    grads = _flash_backward(
+        pack(q), pack(k), pack(v), outp, lse, pack(g), layout=layout,
+        causal=causal, scale=scale, block_q=min(block_q, _FLASH_BWD_BLOCK),
+        block_k=min(block_k, _FLASH_BWD_BLOCK), interpret=interpret,
     )
+    return tuple(map(unpack, grads))
 
 
 flash_attention_tpu.defvjp(_flash_fwd, _flash_bwd)
@@ -1023,48 +1067,79 @@ def cache_flush(slab: jax.Array, new: jax.Array, plan: jax.Array, *,
     )(plan, cols, slab)
 
 
+# Causal self-attention over a whole sequence goes to the Pallas pair from this
+# many positions up, where it is lowered for a TPU: the measured crossover on
+# the v5e (PERF.md section 6, PR 43: the op-level table).  At 512 the cell that
+# runs it (serve-gpt2-xl-chat) is judged on a tail that would not resolve it.
+FLASH_MIN_T = 1024
+
+
+def flash_plan(q_shape, k_shape, v_shape=None, *, causal: bool, window: int = 0,
+               block_q: int = 128, block_k: int = 128):
+    """What :func:`attention` gives the Pallas pair for these shapes where it
+    is lowered for a TPU: the forward's ``(block_q, block_k)``, or None where
+    the XLA paths take the call on every platform.  By shape alone.  Blocks of
+    1,024, else 512, where the lengths allow (the chip's sweep: a grid step's
+    fixed cost outweighs what a finer causal staircase skips; PERF.md section
+    6, PR 43); no plan where a row's queries, dO and float32 dQ would not sit
+    in the backward kernel's VMEM together (T = 32k with heads of 64)."""
+    t_q, t_k = q_shape[-2], k_shape[-2]
+    square = causal and t_q == t_k and t_q >= FLASH_MIN_T
+    if window or len(q_shape) != 4 or not (square or t_k >= 8192):
+        return None
+    wide = lambda b, t: max(b, next(  # noqa: E731
+        (w for w in (1024, 512) if t % w == 0), b))
+    bq, bk = wide(block_q, t_q), wide(block_k, t_k)
+    lanes = lambda d: -(-d // 128) * 128  # noqa: E731
+    dv = (v_shape or k_shape)[-1]
+    resident = t_q * (12 * lanes(q_shape[-1]) + 4 * lanes(dv))
+    if t_q % bq or t_k % bk or resident > _FLASH_VMEM * 5 // 8:
+        return None
+    return bq, bk
+
+
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
     scale: Optional[float] = None, block_q: int = 128, block_k: int = 128,
     window: int = 0,
 ) -> jax.Array:
-    """Dispatch to an implementation by shape (module docstring: none of
-    the thresholds has a cell on either side).  Single entry point used by
-    the model zoo.
+    """Dispatch to an implementation by shape and, for the Pallas pair, by
+    the platform the program is lowered for (module docstring: which cell
+    runs which).  Single entry point used by the model zoo.
 
     - a window layer (``window > 0``: causal, and position ``i`` attends
       ``i - window < j <= i``) → :func:`band_attention`
-    - causal, square, block-divisible, moderate T → :func:`causal_skip_attention`
-    - moderate T → :func:`full_attention` (masked, MXU dtypes)
-    - T ≥ 8k lowered for a TPU, block-divisible → :func:`flash_attention_tpu`
-      (pallas fwd + recompute-free bwd kernels)
-    - other long T → :func:`blockwise_attention` (O(block) memory,
-      pads+masks any length; ring attention covers sharded-T)
+    - lowered for a TPU, block-divisible: causal self-attention from
+      ``FLASH_MIN_T`` (1,024) positions up, and anything from 8k keys up →
+      :func:`flash_attention_tpu` (pallas fwd + recompute-free bwd kernels;
+      :func:`flash_plan`)
+    - else, causal, square, block-divisible, T ≤ 4k → :func:`causal_skip_attention`
+    - else T ≤ 4k → :func:`full_attention` (masked, MXU dtypes)
+    - else → :func:`blockwise_attention` (O(block) memory, pads+masks any
+      length; ring attention covers sharded-T)
     """
     t_q, t_k = q.shape[-2], k.shape[-2]
     if window:
         assert causal and t_q == t_k, (causal, t_q, t_k)
         return band_attention(q, k, v, window=window, scale=scale)
-    if t_q <= _MAX_MATERIALIZED_T and t_k <= _MAX_MATERIALIZED_T:
-        if causal and t_q == t_k and t_q % 256 == 0 and t_q >= 512:
-            return causal_skip_attention(q, k, v, scale=scale, block=256)
-        return full_attention(q, k, v, causal=causal, scale=scale)
-    if q.ndim == 4 and t_k >= 8192 and t_q % block_q == 0 and t_k % block_k == 0:
-        # long context (predates the chip; one prefill bucket of one cell
-        # sits here): where the program is lowered for a TPU the pallas
-        # kernel pair (fwd + recompute-free bwd), in blocks of 512 where the
-        # lengths allow (a grid step costs ~0.35 us whatever it computes)
-        wide = lambda b, t: max(b, 512) if t % 512 == 0 else b  # noqa: E731
-        return lax.platform_dependent(
-            q, k, v,
-            tpu=lambda q, k, v: flash_attention_tpu(
-                q, k, v, causal, scale, wide(block_q, t_q), wide(block_k, t_k),
-                False),
-            default=lambda q, k, v: blockwise_attention(
-                q, k, v, causal=causal, scale=scale, block_k=block_k))
-    return blockwise_attention(
-        q, k, v, causal=causal, scale=scale, block_k=block_k
-    )
+
+    def xla(q, k, v):
+        if t_q <= _MAX_MATERIALIZED_T and t_k <= _MAX_MATERIALIZED_T:
+            if causal and t_q == t_k and t_q % 256 == 0 and t_q >= 512:
+                return causal_skip_attention(q, k, v, scale=scale, block=256)
+            return full_attention(q, k, v, causal=causal, scale=scale)
+        return blockwise_attention(
+            q, k, v, causal=causal, scale=scale, block_k=block_k)
+
+    plan = flash_plan(q.shape, k.shape, v.shape, causal=causal,
+                      block_q=block_q, block_k=block_k)
+    if plan is None:
+        return xla(q, k, v)
+    return lax.platform_dependent(
+        q, k, v,
+        tpu=lambda q, k, v: flash_attention_tpu(
+            q, k, v, causal, scale, *plan, False),
+        default=xla)
 
 
 def _scores(q, k, scale: float) -> jax.Array:
@@ -1117,9 +1192,11 @@ def causal_skip_attention(
 
     One dot + one full-width masked select per q block (XLA fuses the
     select into the softmax; separate unmasked-prefix and masked-diagonal
-    dots would need a concat).  The dispatcher's causal default below 4k
-    tokens: the train cells and the 512 prefill bucket run it; no cell
-    runs the pallas pair against it.
+    dots would need a concat).  The dispatcher's causal path up to 4k tokens
+    wherever the Pallas pair does not take the call: below ``FLASH_MIN_T``
+    (the 512 prefill bucket) and off the TPU.  Inside a train step its
+    materialized scores were the largest single item of the medium cell's
+    step (PERF.md section 6, PR 43), which is why training left it.
     """
     *_, t, d = q.shape
     scale = scale if scale is not None else d ** -0.5
@@ -1172,6 +1249,6 @@ def band_attention(
     return out.reshape(*lead, t, d)
 
 
-# Above this, materialized scores risk HBM pressure; the O(block) blockwise
-# path takes over.
+# Off the TPU (and for what the Pallas pair does not take): above this,
+# materialized scores risk HBM pressure; the O(block) blockwise path takes over.
 _MAX_MATERIALIZED_T = 4096

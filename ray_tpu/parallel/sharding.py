@@ -248,25 +248,17 @@ _HLO_COLLECTIVE = re.compile(
 _HLO_SHAPE = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
 
 
-def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
-    """What a compiled step moves between chips, read from its HLO.
-
-    ``compiled`` is a ``jax.stages.Compiled`` (``jit(f).lower(...).compile()``)
-    or its ``as_text()``.  Returns, per collective kind (``all-reduce``,
-    ``all-gather``, ``reduce-scatter``, ``all-to-all``,
-    ``collective-permute``) and per place (``"in_loop"``: in a ``while``
-    body or anything it calls, i.e. the scanned layers; ``"outside"``),
-    ``{"count", "max_operand_bytes", "shapes"}``.  ``shapes`` lists every
-    distinct per-device array the collectives of that kind take or give,
-    as ``"f32[4,256,768]"``.  The FSDP mechanism above is decided at
-    compile time, so this is the counter that says it engaged.
-    """
+def _hlo_instructions(compiled: Any):
+    """A compiled program's HLO text by computation: ``(comps, loops, rows)``.
+    ``comps``: computation -> {instruction or parameter: the text of its
+    shape}; ``loops``: computation -> the ``while`` bodies it runs under
+    (itself, if it is one), empty outside every loop; ``rows``: every
+    ``(computation, instruction, the text after "=")``."""
     text = compiled if isinstance(compiled, str) else compiled.as_text()
-    # computation -> {instruction or parameter name: the text of its shape}
     comps: Dict[str, Dict[str, str]] = {}
     calls: Dict[str, set] = {}
     loop_bodies: set = set()
-    found = []  # (computation, kind, result shape, operand names, attributes)
+    rows = []
     name = None
     for line in text.splitlines():
         m = _HLO_COMPUTATION.match(line)
@@ -287,16 +279,59 @@ def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
                 loop_bodies |= callees
         c = _HLO_COLLECTIVE.match(rest)
         comps[name][instr] = c.group(1) if c else rest.split(" ", 1)[0]
+        rows.append((name, instr, rest))
+    loops: Dict[str, set] = {n: set() for n in comps}
+    for body in loop_bodies:
+        stack = [body]
+        while stack:
+            n = stack.pop()
+            if body not in loops.setdefault(n, set()):
+                loops[n].add(body)
+                stack.extend(calls.get(n, ()))
+    return comps, loops, rows
+
+
+def kernel_profile(compiled: Any) -> Dict[str, Dict[str, Any]]:
+    """The Pallas kernels of a compiled program by the name their
+    ``pallas_call`` was given (``flash_attention_fwd``, ...): ``{"count",
+    "loops"}``, ``loops`` the ``while`` bodies a call of that name sits in
+    (the scanned layers of the forward pass are one loop and those of the
+    backward pass another; empty: outside every loop).  Which attention path
+    a step runs is decided where it is lowered, so like
+    :func:`collective_profile` this is the counter that says it engaged."""
+    _, loops, rows = _hlo_instructions(compiled)
+    profile: Dict[str, Dict[str, Any]] = {}
+    for comp, instr, rest in rows:
+        if 'custom_call_target="tpu_custom_call"' in rest:
+            entry = profile.setdefault(
+                re.sub(r"[.\d]*$", "", instr), {"count": 0, "loops": set()})
+            entry["count"] += 1
+            entry["loops"] |= loops[comp]
+    return profile
+
+
+def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """What a compiled step moves between chips, read from its HLO.
+
+    ``compiled`` is a ``jax.stages.Compiled`` (``jit(f).lower(...).compile()``)
+    or its ``as_text()``.  Returns, per collective kind (``all-reduce``,
+    ``all-gather``, ``reduce-scatter``, ``all-to-all``,
+    ``collective-permute``) and per place (``"in_loop"``: in a ``while``
+    body or anything it calls, i.e. the scanned layers; ``"outside"``),
+    ``{"count", "max_operand_bytes", "shapes"}``.  ``shapes`` lists every
+    distinct per-device array the collectives of that kind take or give,
+    as ``"f32[4,256,768]"``.  The FSDP mechanism above is decided at
+    compile time, so this is the counter that says it engaged.
+    """
+    comps, loops, rows = _hlo_instructions(compiled)
+    in_loop = {n for n, bodies in loops.items() if bodies}
+    found = []  # (computation, kind, result shape, operand names, attributes)
+    for comp, instr, rest in rows:
+        c = _HLO_COLLECTIVE.match(rest)
         if c:
-            found.append((name, c.group(2), c.group(1),
+            found.append((comp, c.group(2), c.group(1),
                           re.findall(r"%([\w.\-]+)", c.group(3)),
                           instr + c.group(4)))
-    in_loop, stack = set(), list(loop_bodies)
-    while stack:
-        n = stack.pop()
-        if n not in in_loop:
-            in_loop.add(n)
-            stack.extend(calls.get(n, ()))
 
     profile = {kind: {place: {"count": 0, "max_operand_bytes": 0, "shapes": []}
                       for place in ("in_loop", "outside")}

@@ -86,8 +86,44 @@ def _lower_train_step(chip):
 
 
 # GPT-2 XL's widths (huggingface.co/openai-community/gpt2-xl), the
-# benchmark's gpt2-xl configuration
+# benchmark's gpt2-xl configuration, and GPT-2 medium's (.../gpt2-medium)
 XL = dict(n_layers=48, n_heads=25, d_model=1600, d_ff=6400)
+MEDIUM = dict(n_layers=24, n_heads=16, d_model=1024, d_ff=4096)
+
+
+def _lower_medium_cell(chip):
+    """The train-gpt2-medium-1k cell's step as benchmark/drivers/train.py
+    builds it: GPT-2 medium unchanged, B=8, T=1024, bf16, dots remat."""
+    from ray_tpu.models import gpt2
+
+    cfg = gpt2.GPT2Config.gpt2_small(**MEDIUM)
+    assert (cfg.remat, cfg.remat_policy, cfg.max_seq_len) == (True, "dots", 1024)
+    optimizer = gpt2.make_optimizer(lr=3e-4, warmup=20)
+    state = jax.eval_shape(
+        lambda k: gpt2.init_state(cfg, k, optimizer), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((8, cfg.max_seq_len), jnp.int32)
+    step = jax.jit(gpt2.make_train_step(cfg, optimizer), donate_argnums=(0,))
+    return [step.lower(
+        _on(chip, state), _on(chip, {"inputs": tokens, "targets": tokens}))]
+
+
+def _flash_in_the_layer_loops(compiled, *, forward_again: bool):
+    """The Pallas pair where a train step's attention was: the forward kernel
+    in the forward pass's layer loop, the backward kernel in the backward
+    pass's (with the forward again under full remat; not where the remat
+    policy kept its result and logsumexp), each once a layer body, and no
+    float32 ``[.., 256, kv_len]`` block of scores left anywhere."""
+    from ray_tpu.parallel.sharding import kernel_profile
+
+    kernels = kernel_profile(compiled)
+    fwd, bwd = kernels["flash_attention_fwd"], kernels["flash_attention_bwd"]
+    assert bwd["count"] == 1 and len(bwd["loops"]) == 1, kernels
+    assert fwd["count"] == 1 + forward_again, kernels
+    if forward_again:
+        assert len(fwd["loops"]) == 2 and fwd["loops"] > bwd["loops"], kernels
+    else:
+        assert len(fwd["loops"]) == 1 and not fwd["loops"] & bwd["loops"], kernels
+    assert not re.search(r"f32\[\d+,\d+,256,\d+\]", compiled.as_text())
 
 
 def _lower_prefill(chip, prefill, params, cache, n_slots, bucket):
@@ -259,8 +295,8 @@ def _lower_bert(chip):
 
 
 def _lower_flash(chip, direction):
-    """The Pallas kernels the attention dispatcher picks on TPU from T=8192:
-    12 heads of 64, blocks of 128, causal."""
+    """The Pallas kernels at a long sequence: T=8192, 12 heads of 64, blocks
+    of 128, causal."""
     from ray_tpu.ops.attention import flash_attention_tpu
 
     qkv = _on(chip, jax.ShapeDtypeStruct((1, 12, 8192, 64), jnp.bfloat16))
@@ -275,8 +311,29 @@ def _lower_flash(chip, direction):
     return [jax.jit(attend).lower(qkv, qkv, qkv)]
 
 
+def _lower_attention_dispatch(chip):
+    """``attention()`` itself, forward and backward, at the two train cells'
+    shapes (``[8, 16, 1024, 64]``; a chip's share of fsdp4 ``[4, 25, 1024,
+    64]``) and at the widest prefill shape below the 8,192 bucket (latent
+    attention's 192 | 128 at 4,096): the blocks and heads a step that
+    ``flash_plan`` picks have to fit the chip's VMEM."""
+    from ray_tpu.ops import attention
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: attention(q, k, v, causal=True)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    bf16 = lambda *shape: _on(chip, jax.ShapeDtypeStruct(shape, jnp.bfloat16))  # noqa: E731
+    return [jax.jit(grads).lower(bf16(b, h, t, dk), bf16(b, h, t, dk), bf16(b, h, t, dv))
+            for b, h, t, dk, dv in ((8, 16, 1024, 64, 64), (4, 25, 1024, 64, 64),
+                                    (1, 64, 4096, 192, 128))]
+
+
 PROGRAMS = {
     "gpt2_125m_train_step": _lower_train_step,
+    "gpt2_medium_train_step_cell": _lower_medium_cell,
+    "attention_dispatch_train_and_prefill": _lower_attention_dispatch,
     "serve_engine_gpt2": lambda chip: _lower_serve_engine(chip, "gpt2"),
     "serve_engine_llama": lambda chip: _lower_serve_engine(chip, "llama"),
     # GQA with four query heads a KV head, heads of 128: the decode kernel's
@@ -355,7 +412,15 @@ def test_xl_fsdp4_step_gathers_bf16_weights(topo):
         out_shardings=(s_shard, None),
     ).lower(state, {"inputs": tokens, "targets": tokens}).compile()
     assert _fits(compiled) < 12 * 2**30  # 14.6 GB when 16 sequences lived on a chip
+    # attention runs as the Pallas pair on each chip's own four sequences
+    # (under shard_map: GSPMD would gather q, k and v to run it whole), so
+    # the layer loops move between chips exactly what the parent of PR 43
+    # moved (sandbox compile of that parent: 3 / 8 / 4 / 0 / 3)
+    _flash_in_the_layer_loops(compiled, forward_again=True)
     in_loop = {k: v["in_loop"] for k, v in collective_profile(compiled).items()}
+    assert {k: v["count"] for k, v in in_loop.items()} == {
+        "all-reduce": 3, "all-gather": 8, "reduce-scatter": 4, "all-to-all": 0,
+        "collective-permute": 3}, in_loop
     gathered = in_loop["all-gather"]["shapes"]
     assert gathered and all(s.startswith("bf16[") for s in gathered), gathered
     assert "bf16[1600,6400]" in gathered  # w1, whole, from its [400,6400] shard
@@ -386,6 +451,16 @@ def test_program_compiles_for_v5e(compiled, name):
     needs = [_fits(c) for c in programs]
     if name == "gpt2_125m_train_step":
         assert needs[0] > 1 * 2**30  # params + adam moments alone are 1.5 GB
+        _flash_in_the_layer_loops(programs[0], forward_again=False)
+    if name == "gpt2_medium_train_step_cell":
+        # 4.26 GB of state + 9.63 GB of temporaries (the parent of PR 43
+        # planned 9.22: the kept attention results are 0.41 GB, lane-dense as
+        # the kernel writes them) of the chip's 15.75 GB
+        _flash_in_the_layer_loops(programs[0], forward_again=False)
+        assert 13.5e9 < needs[0] < 14.5e9, needs
+    if name == "attention_dispatch_train_and_prefill":
+        for program in programs:
+            assert program.as_text().count("tpu_custom_call") == 2
     if name.startswith("serve_engine"):
         # the decode chunk writes the big cache once, at its end, in place:
         # no scatter anywhere in it, and the cache's leaves (k, pos, v, and
@@ -477,6 +552,6 @@ def test_program_compiles_for_v5e(compiled, name):
         assert all(12.9e9 < need < 14.0e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
-        # one forward; dq + dk/dv + the forward they differentiate
-        want = 1 if name.endswith("forward") else 3
+        # one forward; the backward kernel + the forward it differentiates
+        want = 1 if name.endswith("forward") else 2
         assert programs[0].as_text().count("tpu_custom_call") >= want

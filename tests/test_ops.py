@@ -1,5 +1,8 @@
 """Attention / layer op correctness on the virtual CPU mesh."""
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +22,9 @@ from ray_tpu.ops import (
 )
 from ray_tpu.ops.ring_attention import ulysses_attention
 from ray_tpu.parallel import MeshSpec, create_mesh
+
+# the package's ``attention`` attribute is the function
+attention_module = importlib.import_module("ray_tpu.ops.attention")
 
 
 def _qkv(b=2, h=2, t=256, d=32, seed=0):
@@ -289,3 +295,107 @@ def test_flash_kernel_backward_rectangular(causal):
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ref, g_fl):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+# (heads, dk, dv, the forward's block_q and block_k): what ``flash_plan`` gives
+# the kernels at the cells' shapes (one forward cell of 1,024, backward cells of
+# 512) in each layout: an even number of heads of 64, two side by side on the
+# lanes; heads of 128, one a lane block; XL's odd number of heads of 64 and
+# latent attention's 192 | 128, a (batch x head) row each; and blocks the
+# diagonal crosses off-centre
+FLASH_PLANS = [(4, 64, 64, 1024, 1024), (2, 128, 128, 1024, 1024),
+               (3, 64, 64, 1024, 1024), (2, 192, 128, 1024, 1024),
+               (2, 64, 64, 256, 512)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,dk,dv,block_q,block_k", FLASH_PLANS)
+def test_flash_pair_at_the_dispatch_blocks(h, dk, dv, block_q, block_k, dtype):
+    """The Pallas pair as training at T=1,024 runs it (interpret mode): the
+    values and all three gradients against the naive float32 reference."""
+    ks = jax.random.split(jax.random.PRNGKey(dk + block_q), 4)
+    q, k = (jax.random.normal(key, (1, h, 1024, dk), jnp.float32).astype(dtype)
+            for key in ks[:2])
+    v, g = (jax.random.normal(key, (1, h, 1024, dv), jnp.float32).astype(dtype)
+            for key in ks[2:])
+    packed = attention_module._flash_pack(q, k, v)[2]
+    assert packed == {(4, 64): (2, 128, 128), (2, 128): (1, 128, 128),
+                      (3, 64): (1, 64, 64), (2, 192): (1, 192, 128),
+                      (2, 64): (2, 128, 128)}[h, dk]
+    assert attention_module.flash_plan(
+        q.shape, k.shape, v.shape, causal=True) == (1024, 1024)
+
+    def flash(q, k, v):
+        return flash_attention_tpu(q, k, v, True, None, block_q, block_k, True)
+
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    want, pull = jax.vjp(
+        lambda q, k, v: mha_reference(q, k, v, causal=True), f32(q), f32(k), f32(v))
+    got, pull_flash = jax.vjp(flash, q, k, v)
+    assert got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(f32(got), want, atol=tol, rtol=tol)
+    for name, a, b in zip("qkv", pull_flash(g), pull(f32(g))):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(
+            f32(a), b, atol=tol * np.abs(b).max(), rtol=tol,
+            err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("t,kernels", [(512, 0), (1024, 2), (2048, 2)])
+def test_attention_takes_the_kernel_by_shape_and_platform(t, kernels):
+    """Causal self-attention, forward and backward: lowered for a TPU the
+    Pallas pair (by its two names) from 1,024 positions up and the XLA path
+    below; lowered for the CPU never the kernel."""
+    x = jax.ShapeDtypeStruct((1, 2, t, 64), jnp.bfloat16)
+    grads = jax.jit(jax.grad(
+        lambda q, k, v: attention(q, k, v, causal=True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).trace(x, x, x)
+    tpu = grads.lower(lowering_platforms=("tpu",)).as_text()
+    assert tpu.count("tpu_custom_call") == kernels
+    for name in ("flash_attention_fwd", "flash_attention_bwd"):
+        assert (name in tpu) == bool(kernels), name
+    assert "tpu_custom_call" not in grads.lower(
+        lowering_platforms=("cpu",)).as_text()
+    plan = functools.partial(attention_module.flash_plan, causal=True)
+    assert (plan(x.shape, x.shape) is not None) == bool(kernels)
+    # not causal, a window layer, queries against a longer cache, a row that
+    # the backward kernel could not hold: the XLA paths on every platform
+    assert attention_module.flash_plan(x.shape, x.shape, causal=False) is None
+    assert plan(x.shape, x.shape, window=128) is None
+    assert plan((1, 2, 128, 64), x.shape) is None
+    assert plan((1, 2, 32768, 64), (1, 2, 32768, 64)) is None
+    assert plan((1, 2, 16384, 64), (1, 2, 16384, 64)) == (1024, 1024)
+    assert plan((1, 64, 8192, 192), (1, 64, 8192, 192), (1, 64, 8192, 128)) == (
+        1024, 1024)
+    assert plan((1, 2, 1536, 64), (1, 2, 1536, 64)) == (512, 512)
+
+
+@pytest.mark.parametrize("axes", [{"fsdp": 4}, {"dp": 2, "tp": 2}],
+                         ids=["fsdp4", "dp2_tp2"])
+def test_attend_under_a_mesh_equals_unsharded(axes):
+    """``_attend`` at a shape the kernel takes runs under ``shard_map`` over
+    the batch axes and ``tp``: same values and gradients as without a mesh
+    (on the CPU both run the XLA path inside), and nothing of q, k or v is
+    gathered: the compiled program holds no collective."""
+    from ray_tpu.models.transformer import _attend
+
+    n = int(np.prod(list(axes.values())))
+    mesh = create_mesh(MeshSpec(**axes), devices=jax.devices()[:n])
+    q, k, v = _qkv(b=4, h=2, t=1024, d=16)
+
+    def loss(mesh):
+        return lambda q, k, v: (
+            _attend(q, k, v, causal=True, mesh=mesh)[0] ** 2).sum()
+
+    want, g_want = jax.value_and_grad(loss(None), argnums=(0, 1, 2))(q, k, v)
+    sharded = jax.jit(jax.value_and_grad(loss(mesh), argnums=(0, 1, 2)))
+    got, g_got = sharded(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4)
+    spec = jax.sharding.NamedSharding(mesh, P(
+        tuple(a for a in ("dp", "fsdp") if a in axes), "tp" if "tp" in axes else None))
+    placed = [jax.device_put(t, spec) for t in (q, k, v)]
+    text = sharded.lower(*placed).compile().as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
