@@ -1,5 +1,5 @@
 """KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
-EXAONE-MoE, Kimi-K2, Granite-hybrid).
+EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
@@ -8,7 +8,9 @@ first-class TPU path, designed for XLA:
 
 - **Four kinds of cache** (:func:`init_cache`), chosen by what the family's
   config says of its layers, never by its name; three hold something per
-  POSITION, the fourth per REQUEST:
+  POSITION (one of them, the latent row, in a slab or in a ring, and with a
+  second tensor beside it where the layer SELECTS what it reads), the fourth
+  per REQUEST:
 
   1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
      KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
@@ -26,7 +28,21 @@ first-class TPU path, designed for XLA:
      first values are the position's value vector (the absorbed decode form
      of :mod:`ray_tpu.models.kimi_k2`).  Prefill (un-absorbed, k and v a head
      for the call only) keeps the rows its block hands over; the chunk's
-     flush writes ONE tensor.
+     flush writes ONE tensor.  Two things a family may add
+     (:mod:`ray_tpu.models.dots3_note`).  (a) A full layer that SELECTS the
+     positions a query attends (``cfg.index_cache``; :mod:`ray_tpu.ops.dsa`)
+     caches an INDEX KEY beside each row: ``idx_k`` ``[L_full, B, 1, key,
+     S]`` (128 values as published), a fifth cached tensor, written by
+     prefill and flushed as ``c`` is.  A decode step scores the slab's index
+     keys below the slot's live length AND the chunk-local ones of the steps
+     up to its own, keeps the ``top-k`` under ONE exact threshold
+     (:func:`_select`), and its attention's softmax runs over those alone:
+     the latent kernel still reads every live tile and is handed the
+     selection as a mask; a cut chunk selects as a whole one does.  (b) Its
+     WINDOW layers keep latent rows of their own width in a RING
+     (``cfg.window_latent_cache``): ``c_ring`` ``[L_window, B, 1, row, 2 x
+     window]`` (1,088 values as published), kind 2's ring holding kind 3's
+     rows, read by masked einsums and flushed by the 0/1 matrix.
   4. a STATE for a recurrent layer (``cfg.state_cache``; a Mamba-2 mixer,
      ``RECURRENT`` in ``cfg.sliding_windows``): ``ssm`` ``[L_state, B, tiles,
      state, heads a tile x head values]`` in float32 and ``conv`` ``[L_state, d_conv - 1, B,
@@ -132,9 +148,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import exaone_moe, gpt2, granite_hybrid, kimi_k2, llama
+from ray_tpu.models import (
+    dots3_note,
+    exaone_moe,
+    gpt2,
+    granite_hybrid,
+    kimi_k2,
+    llama,
+)
 from ray_tpu.models.transformer import _attend
-from ray_tpu.ops import ssm
+from ray_tpu.ops import dsa, ssm
 from ray_tpu.ops.attention import (
     DECODE_TILE,
     cache_flush,
@@ -157,7 +180,11 @@ from ray_tpu.ops.attention import (
 # family whose layers cache ONE latent row a position instead of K and V per
 # head says so in its config (``latent_cache``, with its own
 # ``attention_scale``), and its ``block`` hands the attention middle that row
-# as a fourth argument.  A family some of whose layers attend NOTHING and
+# as a fourth argument; where its full layers select the positions they read
+# (``index_cache``) the block hands the middle the layer's index queries, head
+# weights and index keys as a fifth, and where its window layers cache latent
+# rows too, their width and scale (``window_latent_cache``,
+# ``window_attention_scale``).  A family some of whose layers attend NOTHING and
 # carry a per-request state instead says so in ``sliding_windows``
 # (``RECURRENT``) and ``state_cache``; its parameters are a stack of the
 # recurrent layers (``params[kind]``, leaves ``[L_kind, ...]``) beside a list
@@ -165,7 +192,8 @@ from ray_tpu.ops.attention import (
 # ``block`` takes the layer's ``kind`` and the mixer's middle, and the loops
 # roll each run of recurrent layers.
 FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
-            "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid}
+            "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid,
+            "dots3_note": dots3_note}
 
 # a layer's entry in :func:`layer_windows` that attends no position at all
 RECURRENT = granite_hybrid.RECURRENT
@@ -202,18 +230,40 @@ def state_cache(cfg) -> Optional[dict]:
     return getattr(cfg, "state_cache", None)
 
 
-def latent_cache(cfg) -> Optional[Tuple[int, int]]:
+def latent_cache(cfg, window: bool = False) -> Optional[Tuple[int, int]]:
     """For a family whose layers cache one latent row a position: ``(values a
     row holds, of which the first are the position's value vector)``, the
     row's other use being the position's key for every head
-    (``cfg.latent_cache``).  None: K and V per KV head."""
-    return getattr(cfg, "latent_cache", None)
+    (``cfg.latent_cache``; ``window``: the same of its window layers, whose
+    row may be another width, ``cfg.window_latent_cache``).  None: K and V per
+    KV head."""
+    return getattr(cfg, "window_latent_cache" if window else "latent_cache", None)
 
 
-def cached_tensors(cfg) -> Tuple[str, ...]:
-    """The names of what :func:`init_cache` holds a position of a full
-    layer: K and V per head, or the one latent row."""
-    return ("c",) if latent_cache(cfg) else ("k", "v")
+def index_cache(cfg) -> Optional[Tuple[int, int]]:
+    """For a family whose full layers SELECT the positions a query attends
+    (:mod:`ray_tpu.ops.dsa`): ``(values of the index key a position caches
+    beside its row, positions a query selects)`` (``cfg.index_cache``).  None:
+    a query attends every position."""
+    return getattr(cfg, "index_cache", None)
+
+
+def attention_scale(cfg, window: bool = False) -> Optional[float]:
+    """A family's own score scale for one kind of layer (None: ``dh **
+    -0.5``)."""
+    return getattr(
+        cfg, "window_attention_scale" if window else "attention_scale", None)
+
+
+def cached_tensors(cfg, window: bool = False) -> Tuple[str, ...]:
+    """The names of what :func:`init_cache` holds a position of a full layer
+    (K and V per head; the one latent row; the row and the index key of a
+    layer that selects) or, ``window``, of a window layer's ring."""
+    if window:
+        return ("c_ring",) if latent_cache(cfg, True) else ("k_ring", "v_ring")
+    if not latent_cache(cfg):
+        return ("k", "v")
+    return ("c", "idx_k") if index_cache(cfg) else ("c",)
 
 
 def ring_positions(window: int) -> int:
@@ -233,26 +283,35 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     a family that has them: ``k_ring``/``v_ring`` ``[L_window, B, KV, dh,
     R]``, a ring: position ``j`` lives at ``j % R`` (``ring_positions``), so
     a slot costs ``R`` positions however long its context.  A family of
-    latent layers (:func:`latent_cache`): ``c`` ``[L, B, 1, row, S]``, one row
-    a position and NO second tensor.  The recurrent layers, for a family that
+    latent layers (:func:`latent_cache`): ``c`` ``[L_full, B, 1, row, S]``, one
+    row a position and NO second tensor; where its full layers select
+    (:func:`index_cache`) their index keys beside it, ``idx_k`` ``[L_full, B,
+    1, key, S]``; its window layers a ring of rows of their own width,
+    ``c_ring`` ``[L_window, B, 1, row, R]``.  The recurrent layers, for a family that
     has them (:func:`state_cache`): ``ssm`` ``[L_state, B, ...]`` and ``conv``
     ``[L_state, inputs kept, B, width]`` (the slots beside the width, so that
     the chip pads neither), no positions at all."""
     windows = layer_windows(cfg)
+    n_full, n_state = windows.count(0), windows.count(RECURRENT)
+    n_window = len(windows) - n_full - n_state
     if latent_cache(cfg):
-        assert not any(windows), windows
-        return {"c": jnp.zeros((cfg.n_layers, n_slots, 1, latent_cache(cfg)[0],
-                                max_len), cfg.dtype),
-                "pos": jnp.zeros((n_slots,), jnp.int32)}
+        rows = lambda width, layers, length: jnp.zeros(  # noqa: E731
+            (layers, n_slots, 1, width, length), cfg.dtype)
+        cache = {"c": rows(latent_cache(cfg)[0], n_full, max_len),
+                 "pos": jnp.zeros((n_slots,), jnp.int32)}
+        if index_cache(cfg):
+            cache["idx_k"] = rows(index_cache(cfg)[0], n_full, max_len)
+        if n_window:
+            cache["c_ring"] = rows(latent_cache(cfg, True)[0], n_window,
+                                   ring_positions(max(windows)))
+        return cache
     slab = lambda layers, length: jnp.zeros(  # noqa: E731
         (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
-    n_full, n_state = windows.count(0), windows.count(RECURRENT)
     cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
              "pos": jnp.zeros((n_slots,), jnp.int32)}
-    if n_full + n_state < len(windows):
+    if n_window:
         ring = ring_positions(max(windows))
-        cache.update(k_ring=slab(len(windows) - n_full - n_state, ring),
-                     v_ring=slab(len(windows) - n_full - n_state, ring))
+        cache.update(k_ring=slab(n_window, ring), v_ring=slab(n_window, ring))
     if n_state:
         (shape, dtype), ((kept, width), conv_dtype) = (
             state_cache(cfg)[name] for name in ("ssm", "conv"))
@@ -307,22 +366,29 @@ def _cache_scores(q, k_all, v_all, l, n, plan, scale=None):
             q, k, v, l, below(n), scale))
 
 
-def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int):
+def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int,
+                         keep=None):
     """:func:`_cache_scores` for a latent layer: ``q [B, 1, H, row]``, every
     head against the ONE row a position of layer ``l`` of ``c_all [L, B, 1,
     row, S]``, whose first ``dv`` values are the position's value vector.
     The kernel (:func:`ray_tpu.ops.attention.ragged_latent_decode_attention`)
-    and the masked einsums over the slab are chosen as there."""
+    and the masked einsums over the slab are chosen as there.  ``keep [B, S]``
+    bool (None: all): of the positions ``j < n[b]`` the ones a layer that
+    selects lets slot ``b`` attend; both forms still read what is live and
+    mask."""
     below = lambda n: jnp.arange(c_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
-    slab = lambda q, c, l, n, plan=None: latent_slab_attention(  # noqa: E731
-        q, c, l, below(n), scale=scale, dv=dv)
+
+    def slab(q, c, l, n, plan=None, keep=None):
+        mask = below(n) if keep is None else below(n) & keep
+        return latent_slab_attention(q, c, l, mask, scale=scale, dv=dv)
+
     if plan is None:
-        out = slab(q[:, 0], c_all, l, n)
+        out = slab(q[:, 0], c_all, l, n, keep=keep)
     else:
         out = lax.platform_dependent(
-            q[:, 0], c_all, l, n, plan,
-            tpu=lambda q, c, l, n, plan: ragged_latent_decode_attention(
-                q, c, l, plan, scale=scale, dv=dv),
+            q[:, 0], c_all, l, n, plan, keep,
+            tpu=lambda q, c, l, n, plan, keep: ragged_latent_decode_attention(
+                q, c, l, plan, scale=scale, dv=dv, keep=keep),
             default=slab)
     return tuple(a[:, None] for a in out)
 
@@ -346,7 +412,7 @@ def _ring_mask(live, pos, window: int, ring: int) -> jax.Array:
 
 
 def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
-                   scale: Optional[float] = None) -> jax.Array:
+                   scale: Optional[float] = None, keep_new=None) -> jax.Array:
     """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
     what its cache holds from before the chunk, and the chunk's own columns
     ``[steps, B, KV, dh]`` at ``t <= i`` (of a window layer: ``t > i -
@@ -359,7 +425,9 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
     onto their KV head by reshape (no materialized repeat).  A latent layer
     is the same sums with one KV head, a ``scale`` of its own (None: ``dh **
     -0.5``) and values narrower than keys (``v_new``: the first values of
-    ``k_new``)."""
+    ``k_new``).  ``keep_new [B, steps]`` bool (None: all): of the chunk's own
+    columns the ones a layer that selects lets a slot attend (``cached`` then
+    applies the same selection's other half)."""
     B, H, _, dh = q.shape
     KV, steps = k_new.shape[2], k_new.shape[0]
     q = q.reshape(B, KV, H // KV, dh)
@@ -368,9 +436,12 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
                        preferred_element_type=jnp.float32)
     s_new = s_new / (dh ** 0.5) if scale is None else s_new * scale
     t = jnp.arange(steps)
-    s_new = jnp.where((t <= i) & (t > i - window) if window else t <= i,
-                      s_new, -1e30)
-    # column t = 0 is never masked, so the max is a real score
+    seen = (t <= i) & (t > i - window) if window else t <= i
+    if keep_new is not None:
+        seen = seen & keep_new[:, None, None, :]
+    s_new = jnp.where(seen, s_new, -1e30)
+    # column t = 0 is never masked, so the max is a real score (under a
+    # selection some position is chosen, here or in the cache)
     m = jnp.maximum(m_old, s_new.max(-1))
     w_old, e_new = jnp.exp(m_old - m), jnp.exp(s_new - m[..., None])
     denom = d_old * w_old + e_new.sum(-1)
@@ -378,6 +449,29 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
         "bkgt,tbkd->bkgd", (e_new / denom[..., None]).astype(v_new.dtype),
         v_new, preferred_element_type=jnp.float32)
     return out.reshape(B, H, 1, v_new.shape[-1])
+
+
+def _select(qi, w, idx_k, l, idx_new, live, i, top_k: int):
+    """A decode step's selection on a layer that selects: slot ``b``'s index
+    queries ``qi [B, Hi, d]`` and head weights ``w [B, Hi]`` against the index
+    keys of layer ``l`` of the cache ``idx_k [L, B, 1, d, S]`` below ``live[b]``
+    AND the chunk's own ``idx_new [steps, B, d]`` at ``t <= i``, ONE top-k over
+    both (:func:`ray_tpu.ops.dsa.top_k_mask`).  Returns ``(keep [B, S], keep_new
+    [B, steps])`` bool.  The scores are einsums over the layer's whole slab:
+    the first form (module docstring of :mod:`ray_tpu.ops.dsa`)."""
+    S, steps = idx_k.shape[-1], idx_new.shape[0]
+    with jax.named_scope("attention.index_score"):
+        slab = lax.dynamic_index_in_dim(idx_k, l, 0, keepdims=False)[:, 0]
+        scores = jnp.concatenate([
+            dsa.index_scores(qi, w, slab, "bhd,bds->bhs"),
+            dsa.index_scores(qi, w, idx_new, "bhd,tbd->bht")], axis=-1)
+    with jax.named_scope("attention.index_select"):
+        valid = jnp.concatenate([
+            jnp.arange(S)[None, :] < live[:, None],
+            jnp.broadcast_to(jnp.arange(steps) <= i, (live.shape[0], steps))],
+            axis=-1)
+        keep = dsa.top_k_mask(scores, valid, top_k)
+    return keep[:, :S], keep[:, S:]
 
 
 def _flush_slices(slab, new, pos0):
@@ -441,11 +535,18 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
-    def attend(q, k, v, row=None, window=0):
+    def attend(q, k, v, row=None, index=None, window=0):
         # the causal (or band) attention of training; kept: this layer's k,
-        # v, or the cache row a latent family's block hands over
+        # v, or the cache row a latent family's block hands over; a layer
+        # that selects attends the positions its index puts first, and its
+        # index keys are kept beside the row
+        scale = attention_scale(cfg, bool(window))
+        if index is not None:
+            out = dsa.selected_attention(
+                q, k, v, index, index_cache(cfg)[1], scale=scale)
+            return out, (row, index[2])
         out = _attend(q, k, v, causal=True, mesh=None, window=window,
-                      scale=getattr(cfg, "attention_scale", None))[0]
+                      scale=scale)[0]
         return out, ((k, v) if row is None else (row,))
 
     routed = []
@@ -479,7 +580,7 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         jnp.swapaxes(t, 3, 4).astype(c.dtype))
     for t, name in zip(full, cached_tensors(cfg)):
         out[name] = to_cache(t, cache[name])
-    for t, name in zip(ringed, ("k_ring", "v_ring")):
+    for t, name in zip(ringed, cached_tensors(cfg, True)):
         out[name] = cache[name].at[:, slots].set(_ring_of(
             t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
     if states:  # [L_state, B, ...] and [L_state, B, kept, width]: whole slots
@@ -489,9 +590,27 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     if routed:
         out["routed"] = routed if isinstance(routed, dict) else jax.tree.map(
             lambda *a: jnp.stack(a), *routed)
+    if index_cache(cfg):
+        # what the full layers' selection had to score, chose and read, of
+        # the real rows (the masked kernel reads every causal position)
+        rows = lengths.astype(jnp.int32)
+        pairs = (rows * (rows + 1) // 2).sum()
+        top = jnp.minimum(rows, index_cache(cfg)[1])
+        chosen = (top * (top + 1) // 2 + (rows - top) * top).sum()
+        out["routed"] = {**out.get("routed", {}), **_selection_counts(
+            cfg, scored=pairs, selected=chosen, read=pairs)}
     last = fam.unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
     return last[:, 0, :], out
+
+
+def _selection_counts(cfg, **counts) -> Dict[str, jax.Array]:
+    """A dispatch's selection counters as they ride beside the routing counts
+    (``dsa_*``, one value a full layer: a layer's own count, the same for
+    each, so that a sum over the leaf is a sum over the layers)."""
+    n_full = layer_windows(cfg).count(0)
+    return {"dsa_" + name: jnp.full((n_full,), value, jnp.int32)
+            for name, value in counts.items()}
 
 
 def _prefill_runs(fam, params, cfg, x, attend, positions, lengths):
@@ -587,12 +706,15 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     B = tokens.shape[0]
     # what a full layer caches a position: k and v, or one latent row whose
     # first values are the position's value vector (absorbed attention)
-    names, latent = cached_tensors(cfg), latent_cache(cfg)
-    scale = getattr(cfg, "attention_scale", None)  # None: dh ** -0.5
-    old = tuple(cache[name] for name in names)
-    S = old[0].shape[-1]
     windows = layer_windows(cfg)
-    window, ring = max(windows), cache.get("k_ring", old[0]).shape[-1]
+    window = max(windows)
+    names = cached_tensors(cfg)
+    ring_names = cached_tensors(cfg, True) if window > 0 else ()
+    latent, index = latent_cache(cfg), index_cache(cfg)
+    old = tuple(cache[name] for name in names)
+    rings = tuple(cache[name] for name in ring_names)
+    S = old[0].shape[-1]
+    ring = rings[0].shape[-1] if rings else S
     # the flush holds a start to S - steps silently (a dynamic_update_slice
     # clamps, and the kernel's plan does as it does): a slot at pos0 needs
     # pos0 + steps <= S (the engine's bucket + max_new + chunk); a ring's
@@ -607,13 +729,18 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # the kernels' work lists, functions of who is active and where alone:
     # built once here, for every layer, step and tensor
     plan = to_flush = None
-    if S % DECODE_TILE == 0 and old[0].shape[3] % 8 == 0:
+    if S % DECODE_TILE == 0 and all(t.shape[3] % 8 == 0 for t in old):
         plan = ragged_decode_plan(live, S // DECODE_TILE)
         if steps <= DECODE_TILE:
             to_flush = cache_flush_plan(active, pos0, steps, S, written=n)
-    local = jnp.zeros(  # [L, steps, B, KV, dh], the layers that attend
-        (cfg.n_layers - windows.count(RECURRENT), steps, B,
-         *old[0].shape[2:4]), old[0].dtype)
+    # the chunk-local buffers, one a cached tensor: [layers of its kind,
+    # steps, B, KV, dh], a layer's own at its place among its kind
+    local = tuple(jnp.zeros((t.shape[0], steps, B, *t.shape[2:4]), t.dtype)
+                  for t in old + rings)
+    # the latent rows a selecting layer's attention READS a slot a step, of
+    # the cache: the kernel a slot's live tiles, the slab all of it
+    read_of = ((lambda n: -(-n // DECODE_TILE) * DECODE_TILE)
+               if plan is not None else (lambda n: jnp.full_like(n, S)))
     # the recurrent layers' state, and the slots whose state a step moves
     held = tuple(cache[name] for name in ("ssm", "conv") if name in cache)
     moved = ssm.state_update_plan(active) if held and ssm.kernel_shapes(
@@ -631,28 +758,45 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
             """Layer ``l``, the ``at``-th of its kind (``w``: its window, 0
             a full layer), run as ``block(x, attend)``."""
             x, *locs = carry
-            at_l = lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+            scale = attention_scale(cfg, bool(w))  # None: dh ** -0.5
+            at_l = lambda a: lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
+            # this kind's chunk-local buffers among the carry's
+            mine = slice(len(names), None) if w else slice(0, len(names))
 
-            def cached(q):
+            def cached(q, keep=None):
+                if latent and w:
+                    return tuple(a[:, None] for a in latent_slab_attention(
+                        q[:, 0], rings[0], at, _ring_mask(live, pos, w, ring),
+                        scale=scale, dv=latent_cache(cfg, True)[1]))
                 if latent:
                     return _latent_cache_scores(
-                        q, old[0], at, live, plan, scale=scale, dv=latent[1])
+                        q, old[0], at, live, plan, scale=scale, dv=latent[1],
+                        keep=keep)
                 if not w:
                     return _cache_scores(q, *old, at, live, plan, scale)
                 return _cache_scores_slab(
-                    q, cache["k_ring"], cache["v_ring"], at,
-                    _ring_mask(live, pos, w, ring))
+                    q, *rings, at, _ring_mask(live, pos, w, ring))
 
-            def attend(q, k, v, row=None):  # [B, heads, 1, dh]
+            def attend(q, k, v, row=None, picked=None):  # [B, heads, 1, dh]
                 put = lambda buf, t: lax.dynamic_update_slice(
                     buf, t[None, None, :, :, 0, :].astype(buf.dtype),
-                    (l, i, 0, 0, 0))
-                new = tuple(put(buf, t) for buf, t in zip(
-                    locs, (k, v) if row is None else (row,)))
+                    (at, i, 0, 0, 0))
+                cols = ((k, v) if row is None else (row,) if picked is None
+                        else (row, picked[2]))
+                new = tuple(put(buf, t) for buf, t in zip(locs[mine], cols))
                 k_new = at_l(new[0])
                 v_new = at_l(new[1]) if row is None else k_new[..., :v.shape[-1]]
-                out = _decode_attend(q, cached, k_new, v_new, i, w, scale)
-                return out.astype(cfg.dtype), new
+                keep = keep_new = None
+                if picked is not None:
+                    # the layer's selection for this step: over the slab's
+                    # index keys below ``live`` AND the chunk's own up to i
+                    keep, keep_new = _select(
+                        picked[0][:, :, 0], picked[1][:, 0], old[1], at,
+                        at_l(new[1])[:, :, 0], live, i, index[1])
+                out = _decode_attend(q, partial(cached, keep=keep), k_new,
+                                     v_new, i, w, scale, keep_new)
+                locs[mine] = new
+                return out.astype(cfg.dtype), locs
 
             x, counts, locs = block(x, attend)
             return (x, *locs), counts
@@ -714,6 +858,14 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         logits = fam.unembed(params, x, cfg)[:, 0, :]
         nxt = sample_logits(logits, sub, temperature=temperature, top_k=top_k)
         nxt = jnp.where(act, nxt, toks)
+        # what the step's selection had to score, chose and read, of the rows
+        # that took it: a context is the cache below where the row stood and
+        # the step's own position
+        context = jnp.where(act, pos + 1, 0)
+        selection = _selection_counts(
+            cfg, scored=context.sum(),
+            selected=jnp.minimum(context, index[1]).sum(),
+            read=jnp.where(act, read_of(live) + i + 1, 0).sum()) if index else {}
         pos = pos + act.astype(jnp.int32)
         if eos_id is not None:
             act = act & (nxt != eos_id)
@@ -722,9 +874,11 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         if isinstance(counted, list):
             counted = jax.tree.map(
                 lambda *a: jnp.stack(a), *counted) if counted else None
+        if selection:
+            counted = {**(counted or {}), **selection}
         return (tuple(locs), tuple(held), pos, nxt, act, rng), (nxt, counted)
 
-    state = ((local,) * len(names), held, pos0, tokens, active, key)
+    state = (local, held, pos0, tokens, active, key)
     if n is None:
         (locs, held, pos, _, active, key), (emitted, routed) = lax.scan(
             step, state, jnp.arange(steps))
@@ -744,21 +898,17 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
              jax.tree.map(jnp.zeros_like, counted)))
         emitted = jnp.where(jnp.arange(steps)[:, None] < n, emitted, last)
 
-    # the chunk's columns of the layers of one kind (a family of one kind: all)
-    of = lambda loc, kind: loc if not window else loc[  # noqa: E731
-        jnp.asarray([l for l, w in enumerate(windows) if bool(w) == kind])]
     out = {**cache, "pos": pos, **dict(zip(("ssm", "conv"), held))}
     for name, big, loc in zip(names, old, locs):
-        out[name] = _flush(big, of(loc, False), pos0, to_flush)
-    if window:
+        out[name] = _flush(big, loc, pos0, to_flush)
+    if rings:
         # the rings, once a chunk and whole: column t of slot b goes to entry
         # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other
         # entry stays; no scatter, and a wrap is nothing special
         hit = ((pos0[:, None, None] + jnp.arange(steps)[None, :, None]) % ring
                == jnp.arange(ring)[None, None, :])          # [B, steps, ring]
-        for name, loc in zip(("k_ring", "v_ring"), locs):
-            new = jnp.einsum("ltbkd,btr->lbkdr", of(loc, True),
-                             hit.astype(loc.dtype))
+        for name, loc in zip(ring_names, locs[len(names):]):
+            new = jnp.einsum("ltbkd,btr->lbkdr", loc, hit.astype(loc.dtype))
             out[name] = jnp.where(hit.any(1)[None, :, None, None, :],
                                   new, cache[name])
     if routed is not None:  # the chunk's routing counts: summed over its steps

@@ -72,6 +72,7 @@ from ray_tpu.ops.layers import dense, rmsnorm
 __all__ = [
     "KimiK2Config", "init", "init_layer", "apply", "block", "embed",
     "unembed", "kv_heads", "num_params", "yarn_inv_freq", "yarn_mscale",
+    "latent_projections",
 ]
 
 
@@ -248,6 +249,42 @@ def kv_heads(cfg: KimiK2Config) -> int:
     return 1
 
 
+def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
+                       absorbed: bool, rescale=(1.0, 1.0)):
+    """The projections of one latent-attention layer, ``h [B, T, D]`` (normed)
+    -> ``(q, k, v, row, c_q)``: ``row [B, 1, T, rkv + pe]`` is what a cache
+    holds for the positions (the normed latent, then the rotated shared key),
+    ``c_q [B, T, rq]`` the query's normed latent.  Un-absorbed: ``q, k [B, H,
+    T, nope + pe]``, ``v [B, H, T, dv]``.  ``absorbed``: ``q [B, H, T, rkv +
+    pe]`` against ONE key head, ``k`` the rows themselves and ``v`` their first
+    ``rkv`` values.  ``rope(t)`` rotates ``t [B, heads, T, pe]``; ``rescale``:
+    factors on the two normed latents (a family that has them)."""
+    B, T, _ = h.shape
+    rkv = p["kv_norm"].shape[0]
+    c_q = norm(dense(h, p["w_dq"]), p["q_norm"])
+    ckv = dense(h, p["w_dkv"])[:, None]                        # [B, 1, T, row]
+    c = norm(ckv[..., :rkv], p["kv_norm"])
+    if rescale != (1.0, 1.0):
+        c_q = c_q * jnp.asarray(rescale[0], c_q.dtype)
+        c = c * jnp.asarray(rescale[1], c.dtype)
+    q = dense(c_q, p["w_uq"])
+    q = q.reshape(B, T, heads, -1).transpose(0, 2, 1, 3)
+    pe = q.shape[-1] - nope
+    q_nope, q_pe = q[..., :nope], rope(q[..., nope:])
+    row = jnp.concatenate([c, rope(ckv[..., rkv:])], axis=-1)
+    w_uk, w_uv = p["w_uk"].astype(h.dtype), p["w_uv"].astype(h.dtype)
+    if absorbed:
+        q = jnp.concatenate(
+            [jnp.einsum("bhtd,hdc->bhtc", q_nope, w_uk), q_pe], axis=-1)
+        return q, row, row[..., :rkv], row, c_q
+    c, k_pe = row[:, 0, :, :rkv], row[..., rkv:]
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([
+        jnp.einsum("btc,hdc->bhtd", c, w_uk),
+        jnp.broadcast_to(k_pe, (B, heads, T, pe))], axis=-1)
+    return q, k, jnp.einsum("btc,hcv->bhtv", c, w_uv), row, c_q
+
+
 def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
           mesh: Optional[Mesh] = None, *, window: int = 0, valid=None,
           absorbed: bool = False):
@@ -264,8 +301,7 @@ def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
     [B, 1] bool; None: all): the real tokens, the only ones an expert sees.
     Returns ``(x, routed, carried)``; ``routed`` is None for a dense layer."""
     B, T, D = x.shape
-    H, nope, pe = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    rkv, scale = cfg.kv_lora_rank, cfg.attention_scale
+    H, nope, scale = cfg.n_heads, cfg.qk_nope_head_dim, cfg.attention_scale
     assert not window, window
     attend = attend or (lambda q, k, v, row: _attend(
         q, k, v, causal=True, mesh=mesh, scale=scale))
@@ -274,25 +310,10 @@ def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
 
     h = norm(x, p["attn_norm"])
     with jax.named_scope("attention.mla_proj"):
-        q = dense(norm(dense(h, p["w_dq"]), p["q_norm"]), p["w_uq"])
-        q = q.reshape(B, T, H, nope + pe).transpose(0, 2, 1, 3)
-        q_nope, q_pe = q[..., :nope], rope_yarn(q[..., nope:], positions, cfg)
-        ckv = dense(h, p["w_dkv"])[:, None]                    # [B, 1, T, 576]
-        row = jnp.concatenate([
-            norm(ckv[..., :rkv], p["kv_norm"]),
-            rope_yarn(ckv[..., rkv:], positions, cfg)], axis=-1)
-        w_uk, w_uv = p["w_uk"].astype(x.dtype), p["w_uv"].astype(x.dtype)
-        if absorbed:
-            q = jnp.concatenate(
-                [jnp.einsum("bhtd,hdc->bhtc", q_nope, w_uk), q_pe], axis=-1)
-            k, v = row, row[..., :rkv]
-        else:
-            c, k_pe = row[:, 0, :, :rkv], row[..., rkv:]
-            q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            k = jnp.concatenate([
-                jnp.einsum("btc,hdc->bhtd", c, w_uk),
-                jnp.broadcast_to(k_pe, (B, H, T, pe))], axis=-1)
-            v = jnp.einsum("btc,hcv->bhtv", c, w_uv)
+        q, k, v, row, _ = latent_projections(
+            h, p, heads=H, nope=nope, norm=norm, absorbed=absorbed,
+            rope=lambda t: rope_yarn(t, positions, cfg))
+        w_uv = p["w_uv"].astype(x.dtype)
     with jax.named_scope("attention.latent"):
         o, carried = attend(q, k, v, row)
     with jax.named_scope("attention.mla_proj"):

@@ -70,6 +70,7 @@ Not in the dispatch:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -207,9 +208,9 @@ def _own_lanes(x, i: int, heads: int):
     return jnp.where((lane >= i * d) & (lane < (i + 1) * d), x, jnp.zeros_like(x))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_own, m_ref, l_ref,
-                  acc_ref, *, scale: float, causal: bool, block_q: int,
-                  block_k: int, q_offset: int):
+def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
+                  block_q: int, block_k: int, q_offset: int,
+                  with_keep: bool = False):
     """Grid = (batch, lane blocks of heads, n_q_blocks, n_k_blocks); the k
     axis is the innermost (sequential) dimension, so the f32 scratch (acc, m,
     l: one of each a head of the lane block) carries the online softmax
@@ -222,7 +223,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_own, m_ref, l_ref,
 
     The causal diagonal (bottom-right aligned: query ``i`` is position
     ``q_offset + i``) leaves a cell wholly above it out (half the work at long
-    T), and only a cell it crosses pays the iota, compare and select."""
+    T), and only a cell it crosses pays the iota, compare and select.
+
+    ``with_keep``: a fourth operand ``keep [bq, bk]`` int8, one mask a query ROW
+    that every head shares (a learned selection of positions:
+    :func:`masked_attention`): a score outside it counts for nothing, in every
+    cell, and a cell may hold none of a row's positions, so the
+    exponentials are masked too (a row's running max is then still
+    ``NEG_INF``, and ``exp(s - m)`` of a masked score would be 1)."""
+    keep_ref, rest = (rest[0], rest[1:]) if with_keep else (None, rest)
+    o_ref, lse_ref, q_own, m_ref, l_ref, acc_ref = rest
     qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
     heads = q_own.shape[0]
     first_q, first_k = q_offset + qi * block_q, ki * block_k
@@ -238,14 +248,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_own, m_ref, l_ref,
     def fold(masked: bool):
         k, v = k_ref[0], v_ref[0]
         mask = _causal_mask(first_q, first_k, (block_q, block_k)) if masked else None
+        if keep_ref is not None:
+            chosen = keep_ref[0].astype(jnp.int32) != 0
+            mask = chosen if mask is None else mask & chosen
         for i in range(heads):
             s = _nt(q_own[i], k) * scale
-            if masked:
+            if mask is not None:
                 s = jnp.where(mask, s, NEG_INF)
             m_prev = m_ref[i]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
+            if keep_ref is not None:
+                p = jnp.where(mask, p, 0.0)
             l_ref[i] = l_ref[i] * alpha + p.sum(axis=-1, keepdims=True)
             m_ref[i] = m_new
             acc_ref[i] = acc_ref[i] * alpha + _nn(p.astype(v.dtype), v)
@@ -307,11 +322,13 @@ def _flash_pack(q, k, v):
 
 
 def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
-                   block_q: int, block_k: int, interpret: bool):
+                   block_q: int, block_k: int, interpret: bool, keep=None):
     """Packed operands (:func:`_flash_pack`) in; returns the packed result
     ``[N, Tq, lanes]`` and the logsumexp ``[N, lane blocks, heads, Tq]`` f32.
     ``v`` may be another width than ``q`` and ``k`` (latent attention's 128
-    against 192)."""
+    against 192).  ``keep [B, Tq, Tk]`` int8 (None: no such mask): the
+    positions a query row attends, shared by the ``N / B`` packed rows of its
+    batch row (:func:`masked_attention`)."""
     heads, lw, lwv = layout
     n, t_q, w = qp.shape
     t_k, nb = kp.shape[1], w // lw
@@ -324,14 +341,23 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
     by_q = lambda i, hb, qi, ki: (i, qi, hb)  # noqa: E731
     by_k = lambda i, hb, qi, ki: (  # noqa: E731
         i, jnp.minimum(ki, last_k(qi)) if causal else ki, hb)
+    masks, mask_specs = (), []
+    if keep is not None:
+        per_row = n // keep.shape[0]  # packed rows (heads) of one batch row
+        masks = (keep,)
+        mask_specs = [pl.BlockSpec(
+            (1, bq, bk), lambda i, hb, qi, ki: (
+                i // per_row, qi, jnp.minimum(ki, last_k(qi)) if causal else ki))]
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, q_offset=q_offset),
+                          block_q=bq, block_k=bk, q_offset=q_offset,
+                          with_keep=keep is not None),
         grid=(n, nb, t_q // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, lw), by_q),
             pl.BlockSpec((1, bk, lw), by_k),
             pl.BlockSpec((1, bk, lwv), by_k),
+            *mask_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, lwv), by_q),
@@ -352,7 +378,7 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
             vmem_limit_bytes=_FLASH_VMEM),
         name="flash_attention_fwd",
         interpret=interpret,
-    )(qp, kp, vp)
+    )(qp, kp, vp, *masks)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -755,9 +781,8 @@ def latent_slab_attention(q: jax.Array, c: jax.Array, layer: jax.Array,
     return acc, m, e.sum(-1)
 
 
-def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, acc_ref, m_ref,
-                          d_ref, c_buf, sem, acc, m_run, l_run,
-                          *, scale: float, dv: int):
+def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, *rest,
+                          scale: float, dv: int, with_keep: bool = False):
     """One invocation walks :func:`ragged_decode_plan`'s work list, as
     :func:`_ragged_decode_kernel` does: item ``w`` is one 128-position tile of
     one slot, ``[dk, 128]`` with positions on the lanes as the cache stores
@@ -773,7 +798,15 @@ def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, acc_ref, m_ref,
     query a matrix-VECTOR product.  The softmax runs row-wise, online: a
     head's running max and denominator sit on all 128 lanes of its row of
     ``m_run`` / ``l_run``, so that every update is elementwise.  A slot's
-    accumulator, max and denominator are written when its last tile is done."""
+    accumulator, max and denominator are written when its last tile is done.
+
+    ``with_keep``: a further operand ``keep [B, tiles, 128]`` float32, whole in VMEM:
+    of the live positions a slot attends only those where it is not 0 (a
+    learned selection: :mod:`ray_tpu.ops.dsa`).  Every live tile is still
+    copied in; a tile may hold none of the chosen positions, which the masked
+    exponentials already allow for."""
+    keep_ref, rest = (rest[0], rest[1:]) if with_keep else (None, rest)
+    acc_ref, m_ref, d_ref, c_buf, sem, acc, m_run, l_run = rest
     T = DECODE_TILE
     n_slots = q_ref.shape[0]
     n_items = (plan_ref.shape[0] - 1 - n_slots) // 2
@@ -817,11 +850,21 @@ def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, acc_ref, m_ref,
             q_ref[b], tile, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale         # [heads, T]
         live = t * T + lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
+        if keep_ref is not None:
+            # the slot's mask of tile t: row t of [tiles, T], read as the
+            # aligned group of 8 rows that holds it (a load at an arbitrary
+            # sublane is not one the chip has)
+            t8 = pl.multiple_of((t // 8) * 8, 8)
+            rows = keep_ref[b, pl.ds(t8, 8), :]
+            mine = lax.broadcasted_iota(jnp.int32, rows.shape, 0) == t - t8
+            chosen = jnp.sum(jnp.where(mine, rows, 0.0), axis=0, keepdims=True)
+            live = live & (chosen != 0.0)                       # [1, T] a row
         s = jnp.where(live, s, NEG_INF)
         m_prev = m_run[...]                       # a head's max on every lane
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # the slot's first tile holds position 0, so m_new is a real score
+        # (under a selection it may hold none: e is masked either way)
         e = jnp.where(live, jnp.exp(s - m_new), 0.0)
         m_run[...] = m_new
         l_run[...] = l_run[...] * alpha + jnp.sum(e, axis=1, keepdims=True)
@@ -840,7 +883,8 @@ def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, acc_ref, m_ref,
 
 def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
                                    layer: jax.Array, plan: jax.Array, *,
-                                   scale: float, dv: int, interpret=False):
+                                   scale: float, dv: int, keep=None,
+                                   interpret=False):
     """The cache half of a decode step's LATENT attention, reading only what
     is live: ``q [B, H, dk]`` (one absorbed query a head a slot) against layer
     ``layer`` of the WHOLE latent cache ``c [L, B, 1, dk, S]`` (one row a
@@ -853,7 +897,10 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
     un-normalised for the caller to merge with its other keys: ``acc [B, H,
     dv]``, running max ``m`` and denominator ``d [B, H]``, all f32; a slot
     with ``n[b] == 0`` gives ``acc = 0, d = 0, m = -1e30`` and moves no byte
-    of cache.  Needs ``S % 128 == 0`` and ``dk, dv % 8 == 0``.
+    of cache.  Needs ``S % 128 == 0`` and ``dk, dv % 8 == 0``.  ``keep [B, S]``
+    (None: every live position): the positions a slot's queries attend, where
+    a layer selects them (one mask a slot, shared by its heads); the kernel
+    still reads every live tile and masks.
 
     What it shares with :func:`ragged_decode_attention`: the plan, the walk,
     the double buffer, the un-normalised result.  Why it is not that kernel:
@@ -867,12 +914,19 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
     assert c.shape[2:4] == (1, dk) and dv <= dk, (c.shape, dk, dv)
     whole = lambda *shape: pl.BlockSpec(  # noqa: E731
         shape, lambda i, *_: (0,) * len(shape))
+    masks = ()
+    if keep is not None:  # [B, tiles (whole groups of 8), T]
+        tiles = -(-(S // T) // 8) * 8
+        masks = (jnp.pad(keep.astype(jnp.float32).reshape(B, S // T, T),
+                         ((0, 0), (0, tiles - S // T), (0, 0))),)
     acc, m, d = pl.pallas_call(
-        functools.partial(_ragged_latent_kernel, scale=scale, dv=dv),
+        functools.partial(_ragged_latent_kernel, scale=scale, dv=dv,
+                          with_keep=keep is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[whole(B, H, dk), pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[whole(B, H, dk), pl.BlockSpec(memory_space=pl.ANY),
+                      *(whole(*m.shape) for m in masks)],
             out_specs=[whole(B, H, dv), whole(B, H, T), whole(B, H, T)],
             scratch_shapes=[
                 pltpu.VMEM((2, dk, T), c.dtype),
@@ -892,7 +946,8 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
             vmem_limit_bytes=64 * 1024 * 1024),
         name="ragged_latent_decode_attention",
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q.astype(c.dtype), c)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q.astype(c.dtype), c,
+      *masks)
     return acc, m[..., 0], d[..., 0]
 
 
@@ -1227,13 +1282,18 @@ def band_attention(
     test's odd length) the band is a mask on the materialized scores."""
     *lead, t, d = q.shape
     scale = scale if scale is not None else d ** -0.5
-    block = -(-window // 128) * 128
-    if t % block or t == block:
+    # the smallest whole number of 128-position tiles that holds the window
+    # and divides the sequence (window 513: 1,024 of a 2,048-token bucket)
+    block = next((b for b in range(-(-window // 128) * 128, t, 128)
+                  if t % b == 0), t)
+    if t == block:
         i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
         s = jnp.where((j <= i) & (j > i - window), _scores(q, k, scale), NEG_INF)
         return _weighted_values(jax.nn.softmax(s, axis=-1), v)
     n = t // block
-    blocks = lambda a: a.reshape(*lead, n, block, d)  # noqa: E731
+    if math.prod(lead) * t * 2 * block * 4 > _BAND_SCORES_BYTES:
+        return _band_attention_by_block(q, k, v, window, scale, block)
+    blocks = lambda a: a.reshape(*lead, n, block, a.shape[-1])  # noqa: E731
     # block b's keys: block b - 1 (zeros before the first, masked) then b
     before = lambda a: jnp.concatenate(  # noqa: E731
         [jnp.zeros_like(a[..., :1, :, :]), a[..., :-1, :, :]], axis=-3)
@@ -1246,7 +1306,82 @@ def band_attention(
     mask = (j <= i) & (j > i - window) & ~(first & (j < block))
     s = jnp.where(mask, _scores(blocks(q), k2, scale), NEG_INF)
     out = _weighted_values(jax.nn.softmax(s, axis=-1), v2)
-    return out.reshape(*lead, t, d)
+    return out.reshape(*lead, t, v.shape[-1])
+
+
+# float32 scores of a whole band above which its blocks are walked one at a
+# time: 64 heads x 16,384 queries x 2,048 keys are 8.6 GB at once
+_BAND_SCORES_BYTES = 2 ** 30
+
+
+def _band_attention_by_block(q, k, v, window: int, scale: float, block: int):
+    """:func:`band_attention`'s blocks one after another (``lax.map``): block
+    ``b``'s queries against the keys of blocks ``b - 1`` and ``b`` sliced out
+    of the sequence, so that one block's scores are what is held."""
+    *lead, t, _ = q.shape
+    ax = len(lead)
+    i = block + jnp.arange(block)[:, None]           # a query's place in its keys
+    j = jnp.arange(2 * block)[None, :]
+
+    def one(b):
+        # block 0 reads blocks 0 and 1 and masks the second: no block before it
+        first = jnp.maximum(b - 1, 0) * block
+        qb = lax.dynamic_slice_in_dim(q, b * block, block, ax)
+        kb, vb = (lax.dynamic_slice_in_dim(a, first, 2 * block, ax)
+                  for a in (k, v))
+        at = jnp.where(b == 0, i - block, i)
+        mask = (j <= at) & (j > at - window)
+        s = jnp.where(mask, _scores(qb, kb, scale), NEG_INF)
+        return _weighted_values(jax.nn.softmax(s, axis=-1), vb)
+
+    out = lax.map(one, jnp.arange(t // block))       # [n, *lead, block, dv]
+    return jnp.moveaxis(out, 0, ax).reshape(*lead, t, v.shape[-1])
+
+
+def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     keep: jax.Array, *, scale: Optional[float] = None,
+                     interpret: bool = False) -> jax.Array:
+    """Causal self-attention whose query ROWS each attend a chosen set of
+    positions: ``q, k [B, H, T, dk]``, ``v [B, H, T, dv]``, ``keep [B, T, T]``
+    int8, row ``t`` non-zero where query ``t`` attends (``s <= t`` is applied
+    besides), the same for every head: the softmax runs over the chosen
+    positions alone.  A ``[H, T, T]`` score tensor never exists: lowered for a
+    TPU (whole blocks of 512 from 1,024 positions up) the Pallas forward
+    kernel takes the mask as a fourth operand, a ``[block, block]`` cell at a
+    time; anywhere else a block of queries at a time against all keys.
+    Forward only: the serving path's prefill."""
+    *_, t, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+
+    def xla(q, k, v, keep):
+        block = next(b for b in (256, t) if t % b == 0)
+        positions = jnp.arange(t)
+
+        def rows(first):
+            qb = lax.dynamic_slice_in_dim(q, first, block, 2)
+            kb = lax.dynamic_slice_in_dim(keep, first, block, 1)
+            mask = (kb != 0) & (
+                positions[None, :] <= (first + jnp.arange(block))[:, None])
+            s = jnp.where(mask[:, None], _scores(qb, k, scale), NEG_INF)
+            return _weighted_values(jax.nn.softmax(s, axis=-1), v)
+
+        out = lax.map(rows, jnp.arange(t // block) * block)
+        return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], t, v.shape[-1])
+
+    if t < FLASH_MIN_T or t % 512:
+        return xla(q, k, v, keep)
+
+    def kernel(q, k, v, keep):
+        block = 1024 if t % 1024 == 0 else 512
+        pack, unpack, layout = _flash_pack(q, k, v)
+        out, _ = _flash_forward(
+            pack(q), pack(k), pack(v), layout=layout, causal=True, scale=scale,
+            block_q=block, block_k=block, interpret=interpret, keep=keep)
+        return unpack(out)
+
+    if interpret:
+        return kernel(q, k, v, keep)
+    return lax.platform_dependent(q, k, v, keep, tpu=kernel, default=xla)
 
 
 # Off the TPU (and for what the Pallas pair does not take): above this,

@@ -458,19 +458,20 @@ class GenerationEngine:
         # the family's layers count (leaves as the programs return them:
         # stacked over the layers that route); None until a first arrives
         self._routed = {"prefill": None, "decode": None, "decode_steps": 0,
-                        "decode_dispatches": 0}
+                        "decode_dispatches": 0, "prefill_dispatches": 0}
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
         # bytes of one tile of a layer of each kind, from the cache's own
-        # shapes (k and v per KV head, or one latent row a position), so that
-        # no reader of the counters guesses a row's width
+        # shapes (k and v per KV head, or one latent row a position, with its
+        # index key where the layer selects), so that no reader of the
+        # counters guesses a row's width
         row_bytes = lambda *names: sum(  # noqa: E731
             math.prod(self.cache[n].shape[2:4]) * self.cache[n].dtype.itemsize
             for n in names if n in self.cache)
         self._tile_bytes = {
             "full": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg)),
-            "window": DECODE_TILE * row_bytes("k_ring", "v_ring")}
+            "window": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg, True))}
         # a family with recurrent layers: cumulative, over the steps the
         # drained chunks really ran (host arithmetic, no device read): the
         # rows whose state a step HAD to move (``rows_live``: the chunk's
@@ -696,6 +697,7 @@ class GenerationEngine:
             prefill = {str(b): dict(v) for b, v in self._prefill.items()}
             moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
             state = dict(self._state) if self._state else None
+            dsa = self._selection_stats()
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
             stages = tracing.span_stats(STAGES + PER_GAP)
@@ -730,6 +732,16 @@ class GenerationEngine:
             # a family with recurrent layers: the rows of state the decode
             # steps moved against those they had to (``__init__``)
             **({"state": state} if state else {}),
+            # a family whose full layers select the positions a query reads
+            # (ray_tpu.ops.dsa): prefills and decode chunks apart, summed over
+            # the full layers, the cached rows the selection had to score
+            # (every position of a row's context), the rows it chose (``min(
+            # context, top-k)``) and the latent rows the attention READ for
+            # them (``rows_read / rows_selected`` is 1 for a read that
+            # gathers what was chosen); the decode steps and the dispatches
+            # they came in.  Counted on the device, beside the routing counts
+            # (the same numbers as ``moe[phase]["dsa_*"]``).  Cumulative
+            **({"dsa": dsa} if dsa else {}),
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -964,7 +976,22 @@ class GenerationEngine:
             self._routed[phase] = counts if had is None else {
                 k: had[k] + v for k, v in counts.items()}
             self._routed["decode_steps"] += steps
-            self._routed["decode_dispatches"] += phase == "decode"
+            self._routed[phase + "_dispatches"] += 1
+
+    def _selection_stats(self) -> Optional[Dict[str, Any]]:
+        """``perf_stats()["dsa"]`` from the landed dispatches' counts (call
+        under the lock); None for a family that selects nothing."""
+        if not self._gen.index_cache(self.cfg):
+            return None
+        out = {}
+        for phase in ("prefill", "decode"):
+            had = self._routed[phase] or {}
+            out[phase] = {
+                **{"rows_" + k: int(np.sum(had.get("dsa_" + k, 0)))
+                   for k in ("scored", "selected", "read")},
+                "dispatches": self._routed[phase + "_dispatches"]}
+        out["decode"]["steps"] = self._routed["decode_steps"]
+        return out
 
     def _read_back(self, dev) -> tuple:
         """``np.asarray(dev)`` (blocks until the device has produced it), the

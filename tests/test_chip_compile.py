@@ -282,6 +282,48 @@ def _lower_granite_cell(chip):
     ]
 
 
+# dots3-note-prev as the benchmark's dots3-note-prev-ep32 holds it: the
+# published widths (the config's defaults), layers 0-4, experts 0-7 of 256,
+# an eighth of the vocabulary
+DOTS3_NOTE = dict(vocab_size=19_008, n_layers=5, experts_held=(0, 8))
+
+
+def _lower_dots3_cell(chip):
+    """The serve-dots3-note-prev-ep32-docs cell's programs: two full latent
+    layers that SELECT (a 576-value row and a 128-value index key a position,
+    33 rows x 17,536 positions), three sliding latent layers over rings of
+    1,026 rows of 1,088 values, 8 held experts a sparse layer; the decode
+    chunk hands the latent kernel the selection as a mask; every prefill
+    bucket one row wide up to 16,384 tokens, the full layers through the
+    Pallas forward kernel with the selection as its fourth operand (from
+    4,096 tokens up)."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("dots3_note", "note-prev", **DOTS3_NOTE)
+    n_slots, chunk = 32, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(16384, 1024, chunk)))
+    assert set(cache) == {"c", "idx_k", "c_ring", "pos"}
+    assert cache["c"].shape == (2, 33, 1, 576, 17536)
+    assert cache["idx_k"].shape == (2, 33, 1, 128, 17536)
+    assert cache["c_ring"].shape == (3, 33, 1, 1088, 1026)
+    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
+    assert [llm.call_rows(b, n_slots) for b in (2048, 16384)] == [1, 1]
+    # the widest bucket and the narrowest: the two prefill programs that
+    # differ in kind (the masked kernel; plain causal attention, every
+    # position selected).  8,192 and 4,096 are the widest's program at
+    # other lengths; their sandbox compiles are in the cell's assumed.sizes
+    return [
+        prefill_of(16384),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
+        prefill_of(2048),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -347,6 +389,7 @@ PROGRAMS = {
     "serve_engine_exaone_cell": _lower_exaone_cell,
     "serve_engine_kimi_cell": _lower_kimi_cell,
     "serve_engine_granite_cell": _lower_granite_cell,
+    "serve_engine_dots3_cell": _lower_dots3_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -550,6 +593,32 @@ def test_program_compiles_for_v5e(compiled, name):
                 made = line.split(" fusion(")[0]
                 assert made == line or " bf16[9,4096,768]{" not in made, line[:200]
         assert all(12.9e9 < need < 14.0e9 for need in needs), needs
+    if name == "serve_engine_dots3_cell":
+        # the latent kernel once a full layer, given the step's selection as
+        # a further operand; the sliding layers' rings are read by einsums;
+        # the flush kernel over ``c`` and ``idx_k``; the grouped matmuls of
+        # four expert layers.  Every prefill from 4,096 tokens up runs the
+        # Pallas forward kernel with the selection as its fourth operand on
+        # both full layers (a [128, T, T] score tensor never exists: the
+        # 16,384-token call plans 4.3 GB of temporaries); the 2,048 bucket
+        # selects every position and takes the plain causal kernel.  3.64 GB
+        # of weights and 1.85 GB of cache resident
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            kernels = [line for line in text.splitlines()
+                       if re.search(r"%ragged_latent_decode_attention[.\d]* = ", line)]
+            assert len(kernels) == 2, len(kernels)
+            assert all("f32[33,144,128]" in line for line in kernels)  # the mask
+            flushes = [line for line in text.splitlines()
+                       if re.search(r"%cache_flush[.\d]* = ", line)]
+            assert len(flushes) == 2, flushes
+            assert text.count("tpu_custom_call") >= 2 + 2 + 4 * 3
+            assert decode.memory_analysis().temp_size_in_bytes < 0.6e9
+        for prefill in programs[:1] + programs[3:]:
+            assert prefill.as_text().count("flash_attention_fwd") >= 2
+            assert not re.search(r"f32\[1,128,\d{4,5},\d{4,5}\]", prefill.as_text())
+        assert programs[0].memory_analysis().temp_size_in_bytes < 5.0e9
+        assert all(5.5e9 < need < 10.5e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; the backward kernel + the forward it differentiates
