@@ -316,7 +316,7 @@ def _index(h, c_q, p, cfg: Dots3NoteConfig, positions):
 
 def block(x, p, cfg: Dots3NoteConfig, attend=None, positions=None,
           mesh: Optional[Mesh] = None, *, window: int = 0, valid=None,
-          absorbed: bool = False):
+          absorbed: bool = False, context=None):
     """One layer.  x: [B, T, D] in cfg.dtype; ``window``: the layer's kind (0:
     a full layer; else a sliding one); rotary at ``positions`` ([T] or [B,
     T]; None: 0..T-1); whether its FFN is dense or sparse shows in its
@@ -328,8 +328,10 @@ def block(x, p, cfg: Dots3NoteConfig, attend=None, positions=None,
     weights [B, T, Hi], index keys [B, 1, T, di])``, the keys being what a
     cache holds BESIDE the row: the middle selects the positions a query
     attends from them.  ``valid`` ([B, T] or [B, 1] bool; None: all): the real
-    tokens, the only ones an expert sees.  Returns ``(x, routed, carried)``;
-    ``routed`` is None for a dense layer."""
+    tokens, the only ones an expert sees.  ``context``:
+    :func:`ray_tpu.models.kimi_k2.latent_projections`' (a prompt's part: the
+    keys and values cover what the cache holds before it).  Returns ``(x,
+    routed, carried)``; ``routed`` is None for a dense layer."""
     B, T, D = x.shape
     z = cfg.sizes(bool(window))
     positions = jnp.arange(T) if positions is None else positions
@@ -347,7 +349,7 @@ def block(x, p, cfg: Dots3NoteConfig, attend=None, positions=None,
         q, k, v, row, c_q = latent_projections(
             h, p, heads=z["heads"], nope=z["nope"], norm=norm, absorbed=absorbed,
             rope=lambda t: rope(t, positions, base=z["rope_base"]),
-            rescale=z["rescale"])
+            rescale=z["rescale"], context=context)
         index = None if window else _index(h, c_q, p, cfg, positions)
         gate = jax.nn.sigmoid(dense(h, p["w_g"]).astype(jnp.float32))
     with jax.named_scope(

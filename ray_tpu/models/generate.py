@@ -14,8 +14,9 @@ first-class TPU path, designed for XLA:
 
   1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
      KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
-     kept.  Prefill writes a prompt's columns whole; a decode chunk flushes
-     its ``steps`` columns to ``pos0[b]`` once, both tensors.
+     kept.  Prefill writes a prompt's columns whole (or a PART's at its
+     offset: below); a decode chunk flushes its ``steps`` columns to
+     ``pos0[b]`` once, both tensors.
   2. a RING for a window layer (``cfg.sliding_windows``): ``k_ring``,
      ``v_ring`` ``[L_window, B, KV, dh, 2 x window]``, position ``j`` at ``j %
      ring``, so a slot costs the ring however long its context.  Prefill
@@ -56,6 +57,27 @@ first-class TPU path, designed for XLA:
      advanced it ``n`` steps; there is nothing to flush.  The family's other
      layers keep K and V in the slab (1).
 
+- **A prefill that continues** (:func:`prefill_at`'s ``offsets``): a prompt
+  need not go into its slot in ONE call.  A call's rows may be PARTS: row
+  ``b``'s tokens sit at positions ``offsets[b] ..`` of a slot whose earlier
+  positions an earlier call prefilled, its queries attend what the cache holds
+  of those AND the part's own keys under one softmax, its columns are written
+  at ``offsets[b]``, and ``pos`` becomes ``offsets + lengths``.  The offsets
+  are runtime VALUES (one program whatever they are; the serve engine
+  interleaves the parts of a long prompt with its decode chunks:
+  :mod:`ray_tpu.serve.llm`).  By kind of cache: kinds 1 and 3 read the slot's
+  cached positions up to a static bound with the part's own placed among them,
+  a latent layer's rows up-projected to per-head k and v by the block's own
+  weights as the part's are, and a layer that selects scores cached and own
+  index keys under ONE threshold (the whole prompt's selection); lowered for a
+  TPU the flash kernel takes the key length as a prefetched scalar and
+  neither folds nor fetches a block beyond it, so a prompt's parts add up to
+  the whole call's cells.  Kind 2 reads the positions just ahead of the part
+  by their places in the ring and leaves the last ``ring`` positions of
+  prefix-and-part behind.  Kind 4 cannot: a recurrent layer's prefill starts
+  from a zero state, so such a family keeps whole prompts
+  (:func:`can_continue`).  Without ``offsets`` a call is a whole prompt from
+  position 0, the slot written from scratch.
 - **One block per family**: prefill and decode run the block training
   runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
   (:mod:`ray_tpu.models.transformer`): prefill the full causal attention,
@@ -128,7 +150,8 @@ was active when the chunk began the flush writes all ``steps`` columns at
 ``pos0[b] ..``, so those after a mid-chunk EOS land at or beyond its frozen
 ``pos`` — harmless: a slot never attends an index its own ``pos`` hasn't
 covered, the next flush starts at ``pos`` again, and prefill overwrites
-``[0, Tp)`` and resets ``pos`` when the slot is reused.  The columns a CUT
+``[0, Tp)`` (a prompt in parts: ``[offset, offset + Tp)``, part after part
+from 0) and resets ``pos`` when the slot is reused.  The columns a CUT
 chunk flushes beyond its ``n`` steps (zeros) land past ``pos`` the same way;
 in a ring they overwrite entries that held positions ``2 x window`` back,
 outside every window still to come.  A slot that
@@ -160,8 +183,10 @@ from ray_tpu.models.transformer import _attend
 from ray_tpu.ops import dsa, ssm
 from ray_tpu.ops.attention import (
     DECODE_TILE,
+    band_attention_after,
     cache_flush,
     cache_flush_plan,
+    continued_attention,
     latent_slab_attention,
     ragged_decode_attention,
     ragged_decode_plan,
@@ -184,7 +209,9 @@ from ray_tpu.ops.attention import (
 # (``index_cache``) the block hands the middle the layer's index queries, head
 # weights and index keys as a fifth, and where its window layers cache latent
 # rows too, their width and scale (``window_latent_cache``,
-# ``window_attention_scale``).  A family some of whose layers attend NOTHING and
+# ``window_attention_scale``); its ``block`` also takes ``context``, which maps
+# the call's rows to the rows its keys and values are up-projections of (a
+# prompt's part: what the cache holds ahead of it, then its own).  A family some of whose layers attend NOTHING and
 # carry a per-request state instead says so in ``sliding_windows``
 # (``RECURRENT``) and ``state_cache``; its parameters are a stack of the
 # recurrent layers (``params[kind]``, leaves ``[L_kind, ...]``) beside a list
@@ -516,46 +543,142 @@ def _ring_of(t, lengths, ring: int):
     return jnp.swapaxes(kept, 3, 4)
 
 
+def can_continue(cfg) -> bool:
+    """Whether a prompt of this config can be prefilled in PARTS
+    (:func:`prefill_at`'s ``offsets``): every layer caches positions, which a
+    later part can read back.  A family with recurrent layers
+    (:func:`state_cache`) keeps whole prompts: its prefill starts from a zero
+    state, and a state carried IN is not built."""
+    return state_cache(cfg) is None
+
+
+def _placed(held, new, offsets):
+    """A full layer's keys BY POSITION for a prompt's part: ``held [B, KV, d,
+    bound]``, what the cache holds of the rows' slots (positions last), and
+    the part's own ``new [B, KV, P, d]`` -> ``[B, KV, bound, d]`` with row
+    ``b``'s part at ``offsets[b] ..`` (``offsets[b] + P <= bound``)."""
+    return jax.vmap(lambda h, n, at: lax.dynamic_update_slice(
+        h, n.astype(h.dtype), (0, at, 0)))(
+            jnp.swapaxes(held, 2, 3), new, offsets)
+
+
+def _preceded(ring, new, offsets, window: int):
+    """A window layer's keys for a prompt's part: ``[B, KV, before + P, d]``,
+    the ``before`` positions ahead of ``offsets[b]`` read from the slot's ring
+    ``[B, KV, d, R]`` by their places ``j % R`` (whole 128s that hold ``window
+    - 1`` positions, at most the ring; a place that held no such position yet
+    is masked by its position: :func:`ray_tpu.ops.attention.band_attention_after`),
+    then the part's own ``new [B, KV, P, d]``."""
+    R = ring.shape[-1]
+    before = min(-(-(window - 1) // DECODE_TILE) * DECODE_TILE, R)
+    j = (offsets[:, None] - before + jnp.arange(before)) % R
+    earlier = jnp.take_along_axis(ring, j[:, None, None, :], axis=3)
+    return jnp.concatenate(
+        [jnp.swapaxes(earlier, 2, 3).astype(new.dtype), new], axis=2)
+
+
 def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
-               cache: Dict[str, jax.Array], slots: jax.Array) -> Tuple[jax.Array, Dict]:
+               cache: Dict[str, jax.Array], slots: jax.Array,
+               offsets: Optional[jax.Array] = None,
+               bound: Optional[int] = None) -> Tuple[jax.Array, Dict]:
     """Run the prompts ``tokens [B, Tp]`` (right-padded; true lengths
     ``lengths [B]``) and write K/V into cache slots ``slots [B]`` (any
     subset — one compiled program admits a whole batch of requests).  Returns
-    ``(last_logits [B, V], cache)``.  Positions are 0..Tp-1, so a slot must
-    be prefilled from scratch (pos resets to ``lengths``).  A full layer
+    ``(last_logits [B, V], cache)``.  Positions are 0..Tp-1 and pos resets to
+    ``lengths``: a WHOLE prompt, its slot prefilled from scratch.  A full layer
     keeps every position of the prompt (its k, v; a latent layer its rows),
     a window layer the last ``ring`` of each row (:func:`_ring_of`), a
     recurrent layer its state as it stands after each row's last REAL token
     and that row's last real inputs, written whole over the slot (a padded
     position changes neither).  Where the family's layers count what they
     routed, the dispatch's counts come back as ``cache["routed"]`` (leaves
-    stacked over the layers that route)."""
+    stacked over the layers that route).
+
+    ``offsets [B]`` int32 (None: the above): the rows are PARTS of prompts,
+    row ``b``'s tokens at positions ``offsets[b] + 0..Tp-1`` of a slot whose
+    earlier positions ``[0, offsets[b])`` a call before this one prefilled
+    (:func:`can_continue`; the first part's offset is 0).  The part's queries
+    attend what the cache holds of those positions AND the part's own keys
+    under one softmax, its columns are written at ``offsets[b]``, and ``pos``
+    becomes ``offsets + lengths``; the logits are the last real token's, as
+    ever.  The offsets are runtime VALUES: one program whatever they are.  By
+    kind of cache, as the config declares its layers:
+
+    - a slab of k, v: the slot's first ``bound`` cached positions (static; None:
+      the cache's length; ``offsets + Tp <= bound``) with the part's own placed
+      among them (:func:`_placed`), attended by position
+      (:func:`ray_tpu.ops.attention.continued_attention`: lowered for a TPU
+      the flash kernel with the key length as a prefetched scalar, which
+      neither folds nor fetches a block beyond ``offsets + Tp``);
+    - a ring: the positions just ahead of the part by their places ``j % ring``
+      (:func:`_preceded`), masked by absolute position; the ring then holds the
+      last ``ring`` positions of prefix-and-part;
+    - a latent slab: the cached rows are up-projected to per-head k and v by
+      the block's own weights, as the part's own are (the block's ``context``);
+    - a latent slab that selects: the part's index queries score the cached
+      index keys below ``offsets`` and the part's own, ONE threshold over both
+      (:func:`ray_tpu.ops.dsa.causal_top_k_mask`): the whole prompt's
+      selection."""
     fam = family_of(cfg)
     B, Tp = tokens.shape
-    positions = jnp.arange(Tp)
+    windows = layer_windows(cfg)
+    part = offsets is not None
+    if part:
+        assert can_continue(cfg), "a recurrent layer's prompt is prefilled whole"
+        offsets = offsets.astype(jnp.int32)
+        positions = offsets[:, None] + jnp.arange(Tp)         # [B, Tp]
+        # what the cache holds of the rows' slots, by kind of layer
+        ahead = {False: tuple(cache[n][:, slots, :, :, :bound]
+                              for n in cached_tensors(cfg)),
+                 True: tuple(cache[n][:, slots]
+                             for n in cached_tensors(cfg, True)
+                             ) if max(windows) > 0 else ()}
+    else:
+        positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
-    def attend(q, k, v, row=None, index=None, window=0):
+    def attend(q, k, v, row=None, index=None, window=0, held=None):
         # the causal (or band) attention of training; kept: this layer's k,
         # v, or the cache row a latent family's block hands over; a layer
         # that selects attends the positions its index puts first, and its
-        # index keys are kept beside the row
+        # index keys are kept beside the row.  ``held``: a PART's layer, what
+        # the cache holds ahead of it, a tensor each of ``kept``
         scale = attention_scale(cfg, bool(window))
+        kept = ((k, v) if row is None else (row,) if index is None
+                else (row, index[2]))
+        if held is not None:
+            among = partial(_preceded, window=window) if window else _placed
+            if row is None:  # (a latent layer's k and v cover it already)
+                k, v = (among(h, t, offsets) for h, t in zip(held, (k, v)))
+                k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1)
+                        for t in (k, v))
+            if window:
+                return band_attention_after(
+                    q, k, v, offsets, window=window, scale=scale), kept
+            keep = None if index is None else dsa.causal_top_k_mask(
+                index[0], index[1], _placed(held[1], index[2], offsets)[:, 0],
+                index_cache(cfg)[1], first=offsets)
+            return continued_attention(
+                q, k, v, offsets, keep=keep, scale=scale), kept
         if index is not None:
             out = dsa.selected_attention(
                 q, k, v, index, index_cache(cfg)[1], scale=scale)
-            return out, (row, index[2])
+            return out, kept
         out = _attend(q, k, v, causal=True, mesh=None, window=window,
                       scale=scale)[0]
-        return out, ((k, v) if row is None else (row,))
+        return out, kept
 
     routed = []
     if "blocks" in params:  # layers alike, stacked: one rolled loop
         def body(h, p):
-            h, _, kv = fam.block(h, p, cfg, attend, positions)
+            p, *held = p
+            h, _, kv = fam.block(
+                h, p, cfg, partial(attend, held=held or None), positions)
             return h, kv
 
-        x, full = lax.scan(body, x, params["blocks"])  # ks [L, B, KV, Tp, dh]
+        # ks [L, B, KV, Tp, dh]
+        x, full = lax.scan(body, x, (params["blocks"],
+                                     *(ahead[False] if part else ())))
         ringed = states = ()
     elif state_cache(cfg):  # runs of recurrent layers rolled, the others listed
         x, routed, full, states = _prefill_runs(
@@ -563,26 +686,54 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         ringed = ()
     else:  # kinds of layer mixed, listed: unrolled, each kind's k, v apart
         states = ()
-        valid = positions[None, :] < lengths[:, None]
+        valid = jnp.arange(Tp)[None, :] < lengths[:, None]
         kept = {}
-        for p, w in zip(params["layers"], layer_windows(cfg)):
-            x, counts, kv = fam.block(x, p, cfg, partial(attend, window=w),
-                                      positions, window=w, valid=valid)
+        for p, w in zip(params["layers"], windows):
+            held, rows_of = None, {}
+            if part:  # this layer's among its kind's, and a latent block's rows
+                held = tuple(t[len(kept.get(bool(w), ()))] for t in ahead[bool(w)])
+                if latent_cache(cfg, bool(w)):
+                    rows_of = {"context": partial(
+                        partial(_preceded, window=w) if w else _placed,
+                        held[0], offsets=offsets)}
+            x, counts, kv = fam.block(
+                x, p, cfg, partial(attend, window=w, held=held), positions,
+                window=w, valid=valid, **rows_of)
             kept.setdefault(bool(w), []).append(kv)
             routed += [] if counts is None else [counts]
         full, ringed = (
             tuple(jnp.stack(t) for t in zip(*kept.get(kind, ())))
             for kind in (False, True))
-    out = {**cache, "pos": cache["pos"].at[slots].set(lengths.astype(jnp.int32))}
-    # single advanced index keeps its axis position: one scatter per tensor
-    # (over whole slots, once a prompt; decode never scatters)
-    to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
-        jnp.swapaxes(t, 3, 4).astype(c.dtype))
-    for t, name in zip(full, cached_tensors(cfg)):
-        out[name] = to_cache(t, cache[name])
-    for t, name in zip(ringed, cached_tensors(cfg, True)):
-        out[name] = cache[name].at[:, slots].set(_ring_of(
-            t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
+    rows = lengths.astype(jnp.int32)
+    out = {**cache, "pos": cache["pos"].at[slots].set(
+        rows + offsets if part else rows)}
+    if part:
+        # a row at a time, each at its own offset (a call is a row or a few);
+        # a ring keeps the entries the part did not reach: the newest position
+        # of an entry (_ring_holds over prefix-and-part) is the part's or older
+        for name, t in zip(cached_tensors(cfg), full):
+            cols = jnp.swapaxes(t, 3, 4).astype(cache[name].dtype)
+            for b in range(B):
+                out[name] = lax.dynamic_update_slice(
+                    out[name], cols[:, b:b + 1], (0, slots[b], 0, 0, offsets[b]))
+        for name, t in zip(cached_tensors(cfg, True), ringed):
+            j = _ring_holds(offsets + rows, cache[name].shape[-1]) - offsets[:, None]
+            new = jnp.take_along_axis(
+                t, jnp.clip(j, 0, Tp - 1)[None, :, None, :, None], axis=3)
+            out[name] = cache[name].at[:, slots].set(jnp.where(
+                (j >= 0)[None, :, None, None, :],
+                jnp.swapaxes(new, 3, 4).astype(cache[name].dtype),
+                cache[name][:, slots]))
+    else:
+        # single advanced index keeps its axis position: one scatter per tensor
+        # (over whole slots, once a prompt; decode never scatters)
+        to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
+            jnp.swapaxes(t, 3, 4).astype(c.dtype))
+        for t, name in zip(full, cached_tensors(cfg)):
+            out[name] = to_cache(t, cache[name])
+        for t, name in zip(ringed, cached_tensors(cfg, True)):
+            out[name] = cache[name].at[:, slots].set(_ring_of(
+                t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
     if states:  # [L_state, B, ...] and [L_state, B, kept, width]: whole slots
         out["ssm"] = cache["ssm"].at[:, slots].set(states[0])
         out["conv"] = cache["conv"].at[:, :, slots].set(
@@ -592,11 +743,13 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
             lambda *a: jnp.stack(a), *routed)
     if index_cache(cfg):
         # what the full layers' selection had to score, chose and read, of
-        # the real rows (the masked kernel reads every causal position)
-        rows = lengths.astype(jnp.int32)
-        pairs = (rows * (rows + 1) // 2).sum()
-        top = jnp.minimum(rows, index_cache(cfg)[1])
-        chosen = (top * (top + 1) // 2 + (rows - top) * top).sum()
+        # the real rows (the masked kernel reads every causal position): row
+        # t of a part at ``offsets`` has offsets + t + 1 positions to choose of
+        start = offsets if part else jnp.zeros_like(rows)
+        pairs = (rows * start + rows * (rows + 1) // 2).sum()
+        top = jnp.clip(index_cache(cfg)[1] - start, 0, rows)
+        chosen = (top * start + top * (top + 1) // 2
+                  + (rows - top) * index_cache(cfg)[1]).sum()
         out["routed"] = {**out.get("routed", {}), **_selection_counts(
             cfg, scored=pairs, selected=chosen, read=pairs)}
     last = fam.unembed(params, jnp.take_along_axis(

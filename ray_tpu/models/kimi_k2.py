@@ -250,7 +250,7 @@ def kv_heads(cfg: KimiK2Config) -> int:
 
 
 def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
-                       absorbed: bool, rescale=(1.0, 1.0)):
+                       absorbed: bool, rescale=(1.0, 1.0), context=None):
     """The projections of one latent-attention layer, ``h [B, T, D]`` (normed)
     -> ``(q, k, v, row, c_q)``: ``row [B, 1, T, rkv + pe]`` is what a cache
     holds for the positions (the normed latent, then the rotated shared key),
@@ -258,7 +258,12 @@ def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
     T, nope + pe]``, ``v [B, H, T, dv]``.  ``absorbed``: ``q [B, H, T, rkv +
     pe]`` against ONE key head, ``k`` the rows themselves and ``v`` their first
     ``rkv`` values.  ``rope(t)`` rotates ``t [B, heads, T, pe]``; ``rescale``:
-    factors on the two normed latents (a family that has them)."""
+    factors on the two normed latents (a family that has them).
+    ``context(row) -> [B, 1, Tk, rkv + pe]`` (un-absorbed; None: the rows
+    themselves): the rows the call's keys and values are up-projections of, a
+    prompt's PART handing over what the cache holds before it with its own
+    rows among them (:func:`ray_tpu.models.generate.prefill_at`): ``k`` and
+    ``v`` then have ``Tk`` positions."""
     B, T, _ = h.shape
     rkv = p["kv_norm"].shape[0]
     c_q = norm(dense(h, p["w_dq"]), p["q_norm"])
@@ -277,17 +282,18 @@ def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
         q = jnp.concatenate(
             [jnp.einsum("bhtd,hdc->bhtc", q_nope, w_uk), q_pe], axis=-1)
         return q, row, row[..., :rkv], row, c_q
-    c, k_pe = row[:, 0, :, :rkv], row[..., rkv:]
+    held = row if context is None else context(row)
+    c, k_pe = held[:, 0, :, :rkv], held[..., rkv:]
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([
         jnp.einsum("btc,hdc->bhtd", c, w_uk),
-        jnp.broadcast_to(k_pe, (B, heads, T, pe))], axis=-1)
+        jnp.broadcast_to(k_pe, (B, heads, held.shape[2], pe))], axis=-1)
     return q, k, jnp.einsum("btc,hcv->bhtv", c, w_uv), row, c_q
 
 
 def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
           mesh: Optional[Mesh] = None, *, window: int = 0, valid=None,
-          absorbed: bool = False):
+          absorbed: bool = False, context=None):
     """One layer.  x: [B, T, D] in cfg.dtype; rotary at ``positions`` ([T] or
     [B, T]; None: 0..T-1); whether its FFN is dense or sparse shows in its
     parameters; ``window`` is always 0 (the listed-layers loops of
@@ -299,6 +305,8 @@ def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
     themselves and ``v`` their first 512 values.  ``row [B, 1, T, 576]`` is
     what a cache holds for the positions, either way.  ``valid`` ([B, T] or
     [B, 1] bool; None: all): the real tokens, the only ones an expert sees.
+    ``context``: :func:`latent_projections`' (a prompt's part: the keys and
+    values cover what the cache holds before it).
     Returns ``(x, routed, carried)``; ``routed`` is None for a dense layer."""
     B, T, D = x.shape
     H, nope, scale = cfg.n_heads, cfg.qk_nope_head_dim, cfg.attention_scale
@@ -312,7 +320,7 @@ def block(x, p, cfg: KimiK2Config, attend=None, positions=None,
     with jax.named_scope("attention.mla_proj"):
         q, k, v, row, _ = latent_projections(
             h, p, heads=H, nope=nope, norm=norm, absorbed=absorbed,
-            rope=lambda t: rope_yarn(t, positions, cfg))
+            rope=lambda t: rope_yarn(t, positions, cfg), context=context)
         w_uv = p["w_uv"].astype(x.dtype)
     with jax.named_scope("attention.latent"):
         o, carried = attend(q, k, v, row)

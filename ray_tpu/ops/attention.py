@@ -3,11 +3,14 @@
 :func:`attention` dispatches by shape and, for the Pallas pair, by the
 platform the program is lowered for (``lax.platform_dependent``; no flag).
 Which benchmark cell runs which path: training at T=1,024 (both train cells)
-and every prefill bucket from 1,024 positions up (K-EXAONE's full layer,
-Kimi-K2's un-absorbed form, Granite's two attention layers) run
+and the prefill buckets of 1,024 and 2,048 positions (K-EXAONE's full layer,
+Kimi-K2's and dots3-note's un-absorbed form, Granite's two attention layers) run
 :func:`flash_attention_tpu` on the chip; the 512 bucket (GPT-2 XL's widest)
 runs :func:`causal_skip_attention`, the 64/128/256 buckets
-:func:`full_attention`, window layers :func:`band_attention`.  The crossover
+:func:`full_attention`, window layers :func:`band_attention`.  A prompt above
+2,048 tokens is prefilled in PARTS (``serve/llm.py``), outside the dispatch:
+:func:`continued_attention` (the forward kernel under a runtime key length,
+with dots3-note's selection as its mask) and :func:`band_attention_after`.  The crossover
 (``FLASH_MIN_T``) is the chip's: the op-level table of both paths at the
 cells' shapes and the train steps with either path are in PERF.md, section 6,
 PR 43.  Off the TPU every one of these calls takes the XLA paths, which are
@@ -32,6 +35,14 @@ also what the tests hold the kernels to.
 
 Not in the dispatch:
 
+- :func:`continued_attention` — a prompt's PART against a slot's keys by
+  position, the cached prefix with the part's own among them, up to a static
+  bound: the Pallas forward kernel with the first query's position and the key
+  length as prefetched scalars (one program whatever the offset; no key block
+  at or beyond the length is folded or fetched), optionally under a selection
+  mask; off the TPU the masked scores a block of queries at a time, which the
+  tests hold the kernel to.  :func:`band_attention_after`: the same for a
+  window layer, the ring's positions ahead of the part then its own.
 - :func:`mha_reference` — naive O(T²) f32 attention; numerical ground
   truth for tests.
 - :func:`ragged_decode_attention` — the decode step's contract, not
@@ -208,9 +219,9 @@ def _own_lanes(x, i: int, heads: int):
     return jnp.where((lane >= i * d) & (lane < (i + 1) * d), x, jnp.zeros_like(x))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
-                  block_q: int, block_k: int, q_offset: int,
-                  with_keep: bool = False):
+def _flash_kernel(*refs, scale: float, causal: bool, block_q: int,
+                  block_k: int, q_offset: int, with_keep: bool = False,
+                  rows_per_bound: int = 0, window: int = 0):
     """Grid = (batch, lane blocks of heads, n_q_blocks, n_k_blocks); the k
     axis is the innermost (sequential) dimension, so the f32 scratch (acc, m,
     l: one of each a head of the lane block) carries the online softmax
@@ -230,11 +241,30 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
     :func:`masked_attention`): a score outside it counts for nothing, in every
     cell, and a cell may hold none of a row's positions, so the
     exponentials are masked too (a row's running max is then still
-    ``NEG_INF``, and ``exp(s - m)`` of a masked score would be 1)."""
+    ``NEG_INF``, and ``exp(s - m)`` of a masked score would be 1).
+
+    ``rows_per_bound`` (0: none): the first query's position, the key length
+    and the first key that counts are RUNTIME values, three prefetched scalars
+    for every ``rows_per_bound`` packed rows (``bounds_ref [3 x batch rows]``:
+    a prompt continued at whatever position is one program,
+    :func:`continued_attention`), instead of the static ``q_offset``, the
+    keys' whole length and 0.  A key block at or beyond the key length, or
+    below the first key, is folded no more than one above the diagonal (and the
+    index maps fetch neither); one that either bound crosses is masked as one
+    the diagonal crosses is.  ``window`` (with runtime bounds; 0: none): a
+    query attends the ``window`` keys up to its own position only, and a block
+    wholly below that band is left out like one above the diagonal
+    (:func:`band_attention_after`)."""
+    bounds_ref, refs = (refs[0], refs[1:]) if rows_per_bound else (None, refs)
+    q_ref, k_ref, v_ref, *rest = refs
     keep_ref, rest = (rest[0], rest[1:]) if with_keep else (None, rest)
     o_ref, lse_ref, q_own, m_ref, l_ref, acc_ref = rest
     qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
     heads = q_own.shape[0]
+    k_len = k_min = None
+    if bounds_ref is not None:
+        row = pl.program_id(0) // rows_per_bound
+        q_offset, k_len, k_min = (bounds_ref[3 * row + j] for j in range(3))
     first_q, first_k = q_offset + qi * block_q, ki * block_k
 
     @pl.when(ki == 0)
@@ -248,6 +278,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
     def fold(masked: bool):
         k, v = k_ref[0], v_ref[0]
         mask = _causal_mask(first_q, first_k, (block_q, block_k)) if masked else None
+        if masked and k_len is not None:
+            k_pos = first_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = mask & (k_pos < k_len) & (k_pos >= k_min)
+            if window:
+                mask = mask & (k_pos > first_q - window + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0))
         if keep_ref is not None:
             chosen = keep_ref[0].astype(jnp.int32) != 0
             mask = chosen if mask is None else mask & chosen
@@ -259,17 +296,28 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            if keep_ref is not None:
+            if keep_ref is not None or (window and masked):
+                # (a band's first cell of a row may hold none of its keys too)
                 p = jnp.where(mask, p, 0.0)
             l_ref[i] = l_ref[i] * alpha + p.sum(axis=-1, keepdims=True)
             m_ref[i] = m_new
             acc_ref[i] = acc_ref[i] * alpha + _nn(p.astype(v.dtype), v)
 
     if causal:
+        # (each condition where it is used: under static bounds the kernel
+        # is the training forward's, operation for operation)
         whole = first_k + block_k - 1 <= first_q
+        if k_len is not None:
+            whole = whole & (first_k + block_k <= k_len) & (first_k >= k_min)
+        if window:  # every key in the LAST query's band ...
+            whole = whole & (first_k > first_q + block_q - 1 - window)
         pl.when(whole)(lambda: fold(False))
-        pl.when((first_k <= first_q + block_q - 1) & jnp.logical_not(whole))(
-            lambda: fold(True))
+        seen = first_k <= first_q + block_q - 1
+        if k_len is not None:
+            seen = seen & (first_k < k_len) & (first_k + block_k > k_min)
+        if window:  # ... some key in the first's
+            seen = seen & (first_k + block_k - 1 > first_q - window)
+        pl.when(seen & jnp.logical_not(whole))(lambda: fold(True))
     else:
         fold(False)
 
@@ -322,36 +370,57 @@ def _flash_pack(q, k, v):
 
 
 def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
-                   block_q: int, block_k: int, interpret: bool, keep=None):
+                   block_q: int, block_k: int, interpret: bool, keep=None,
+                   bounds=None, window: int = 0):
     """Packed operands (:func:`_flash_pack`) in; returns the packed result
     ``[N, Tq, lanes]`` and the logsumexp ``[N, lane blocks, heads, Tq]`` f32.
     ``v`` may be another width than ``q`` and ``k`` (latent attention's 128
     against 192).  ``keep [B, Tq, Tk]`` int8 (None: no such mask): the
     positions a query row attends, shared by the ``N / B`` packed rows of its
-    batch row (:func:`masked_attention`)."""
+    batch row (:func:`masked_attention`).  ``bounds [3 x B]`` int32 (None: the
+    static shapes say all three): batch row ``b``'s first query sits at
+    position ``bounds[3 b]`` and attends the keys from ``bounds[3 b + 2]`` to
+    below ``bounds[3 b + 1]``, runtime values that ride into scalar memory
+    ahead of the grid; causal only.  ``window``: the kernel's (with bounds)."""
     heads, lw, lwv = layout
     n, t_q, w = qp.shape
     t_k, nb = kp.shape[1], w // lw
     bq, bk = _flash_blocks(t_q, t_k, block_q, block_k)
     nk, q_offset = t_k // bk, t_k - t_q
-    # a cell above the causal diagonal asks for the key block its neighbour
-    # already holds, and the pipeline fetches nothing for it
-    last_k = lambda qi: jnp.clip(  # noqa: E731
-        (q_offset + (qi + 1) * bq - 1) // bk, 0, nk - 1)
-    by_q = lambda i, hb, qi, ki: (i, qi, hb)  # noqa: E731
-    by_k = lambda i, hb, qi, ki: (  # noqa: E731
-        i, jnp.minimum(ki, last_k(qi)) if causal else ki, hb)
+    per_bound = 0 if bounds is None else n // (bounds.shape[0] // 3)
+    assert bounds is None or causal, "runtime bounds: causal attention only"
+    assert bounds is not None or not window, "a band: with runtime bounds only"
+
+    # a cell above the causal diagonal (or outside the runtime bounds, or
+    # below a band) asks for the key block its neighbour already holds, and
+    # the pipeline fetches nothing for it.  An index map's arguments: the
+    # grid's, then the prefetched bounds where there are any
+    def held(ki, i, qi, prefetched):
+        """The key block step ``ki`` of query block ``qi`` asks for: ``ki``
+        held to the blocks the kernel folds."""
+        if not causal:
+            return ki
+        if not prefetched:
+            return jnp.minimum(ki, jnp.clip(
+                (q_offset + (qi + 1) * bq - 1) // bk, 0, nk - 1))
+        first, k_len, k_min = (
+            prefetched[0][3 * (i // per_bound) + j] for j in range(3))
+        last = jnp.minimum((first + (qi + 1) * bq - 1) // bk, (k_len - 1) // bk)
+        lowest = k_min // bk
+        if window:
+            lowest = jnp.maximum(lowest, (first + qi * bq - window + 1) // bk)
+        return jnp.clip(ki, jnp.clip(lowest, 0, nk - 1), jnp.clip(last, 0, nk - 1))
+
+    by_q = lambda i, hb, qi, ki, *b: (i, qi, hb)  # noqa: E731
+    by_k = lambda i, hb, qi, ki, *b: (i, held(ki, i, qi, b), hb)  # noqa: E731
     masks, mask_specs = (), []
     if keep is not None:
         per_row = n // keep.shape[0]  # packed rows (heads) of one batch row
         masks = (keep,)
         mask_specs = [pl.BlockSpec(
-            (1, bq, bk), lambda i, hb, qi, ki: (
-                i // per_row, qi, jnp.minimum(ki, last_k(qi)) if causal else ki))]
-    return pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, q_offset=q_offset,
-                          with_keep=keep is not None),
+            (1, bq, bk), lambda i, hb, qi, ki, *b: (
+                i // per_row, qi, held(ki, i, qi, b)))]
+    spec = dict(
         grid=(n, nb, t_q // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, lw), by_q),
@@ -361,24 +430,33 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, lwv), by_q),
-            pl.BlockSpec((1, 1, heads, bq), lambda i, hb, qi, ki: (i, hb, 0, qi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, t_q, vp.shape[2]), qp.dtype),
-            jax.ShapeDtypeStruct((n, nb, heads, t_q), jnp.float32),
+            pl.BlockSpec((1, 1, heads, bq), lambda i, hb, qi, ki, *b: (i, hb, 0, qi)),
         ],
         scratch_shapes=[
             pltpu.VMEM((heads, bq, lw), qp.dtype),      # a head's own lanes of q
             pltpu.VMEM((heads, bq, 1), jnp.float32),    # running max
             pltpu.VMEM((heads, bq, 1), jnp.float32),    # running denom
             pltpu.VMEM((heads, bq, lwv), jnp.float32),  # output accumulator
+        ])
+    if bounds is not None:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, q_offset=q_offset,
+                          with_keep=keep is not None, rows_per_bound=per_bound,
+                          window=window),
+        **spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((n, t_q, vp.shape[2]), qp.dtype),
+            jax.ShapeDtypeStruct((n, nb, heads, t_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FLASH_VMEM),
         name="flash_attention_fwd",
         interpret=interpret,
-    )(qp, kp, vp, *masks)
+    )(*(() if bounds is None else (bounds.astype(jnp.int32),)), qp, kp, vp, *masks)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -1269,6 +1347,14 @@ def causal_skip_attention(
     return jnp.concatenate(outs, axis=-2)
 
 
+def band_block(window: int, t: int) -> int:
+    """Queries a block of a band over ``t`` positions: the smallest whole
+    number of 128-position tiles that holds the window and divides the
+    sequence (window 513: 1,024 of a 2,048-token bucket), else all of it."""
+    return next((b for b in range(-(-window // 128) * 128, t, 128)
+                 if t % b == 0), t)
+
+
 def band_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
     scale: Optional[float] = None,
@@ -1284,8 +1370,7 @@ def band_attention(
     scale = scale if scale is not None else d ** -0.5
     # the smallest whole number of 128-position tiles that holds the window
     # and divides the sequence (window 513: 1,024 of a 2,048-token bucket)
-    block = next((b for b in range(-(-window // 128) * 128, t, 128)
-                  if t % b == 0), t)
+    block = band_block(window, t)
     if t == block:
         i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
         s = jnp.where((j <= i) & (j > i - window), _scores(q, k, scale), NEG_INF)
@@ -1338,6 +1423,139 @@ def _band_attention_by_block(q, k, v, window: int, scale: float, block: int):
     return jnp.moveaxis(out, 0, ax).reshape(*lead, t, v.shape[-1])
 
 
+def band_attention_after(q: jax.Array, k: jax.Array, v: jax.Array,
+                         first: jax.Array, *, window: int,
+                         scale: Optional[float] = None,
+                         interpret: bool = False) -> jax.Array:
+    """:func:`band_attention` for a prompt's PART: ``q [B, H, P, d]``, row
+    ``b``'s queries at positions ``first[b] + 0..P-1``; ``k, v [B, H, before +
+    P, *]``: the ``before >= window - 1`` positions that precede the part
+    (whatever a ring still holds of them: one below 0 is masked, one older
+    than the ring lies outside every band), then the part's own.  Lowered
+    for a TPU, whole blocks of 512 on both sides: the Pallas forward kernel
+    with the band as its mask, which folds the two or three key blocks a query
+    block's band crosses and no score leaves VMEM (the masked scores of a
+    window-513 layer's part are 0.4 GB a block of queries in float32).
+    Anywhere else a block of queries (:func:`band_block`) at a time against
+    the ``before + block`` keys its band lies in: the reference the kernel is
+    tested against."""
+    *lead, t, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    before = k.shape[-2] - t
+    assert before >= window - 1, (before, window)
+    first = first.astype(jnp.int32)
+    if len(lead) != 2 or t % 512 or before % 512:
+        return _band_after_by_block(q, k, v, first, window, scale)
+
+    def kernel(q, k, v, first):
+        # positions are the keys' indices here: the first query at ``before``,
+        # and the keys that precede position 0 do not count
+        at = jnp.full_like(first, before)
+        pack, unpack, layout = _flash_pack(q, k, v)
+        out, _ = _flash_forward(
+            pack(q), pack(k), pack(v), layout=layout, causal=True, scale=scale,
+            block_q=512, block_k=512, interpret=interpret, window=window,
+            bounds=jnp.stack([at, at + t, jnp.maximum(before - first, 0)],
+                             axis=1).reshape(-1))
+        return unpack(out)
+
+    if interpret:
+        return kernel(q, k, v, first)
+    return lax.platform_dependent(
+        q, k, v, first, tpu=kernel,
+        default=lambda q, k, v, first: _band_after_by_block(
+            q, k, v, first, window, scale))
+
+
+def _band_after_by_block(q, k, v, first, window: int, scale: float):
+    """:func:`band_attention_after` on every platform."""
+    *lead, t, d = q.shape
+    before = k.shape[-2] - t
+    block, ax = band_block(window, t), len(lead)
+
+    def one(at):
+        qb = lax.dynamic_slice_in_dim(q, at, block, ax)
+        kb, vb = (lax.dynamic_slice_in_dim(a, at, before + block, ax)
+                  for a in (k, v))
+        i = (first[:, None] + at + jnp.arange(block))[:, :, None]
+        j = (first[:, None] + at - before + jnp.arange(before + block))[:, None, :]
+        mask = (j <= i) & (j > i - window) & (j >= 0)
+        s = jnp.where(mask[:, None], _scores(qb, kb, scale), NEG_INF)
+        return _weighted_values(jax.nn.softmax(s, axis=-1), vb)
+
+    out = lax.map(one, jnp.arange(t // block) * block)  # [n, *lead, block, dv]
+    return jnp.moveaxis(out, 0, ax).reshape(*lead, t, v.shape[-1])
+
+
+def _attention_by_query_block(q, k, v, keep, first, scale: float):
+    """The plain form of :func:`masked_attention` and
+    :func:`continued_attention`, on every platform: a block of queries at a
+    time against all the keys, the scores masked (query ``i`` of row ``b``
+    sits at position ``first[b] + i``, 0 where ``first`` is None, and attends
+    the keys at or below it that ``keep``, where there is one, lets it)."""
+    t = q.shape[2]
+    block = next(b for b in (256, t) if t % b == 0)
+    positions = jnp.arange(k.shape[2])
+
+    def rows(at):
+        qb = lax.dynamic_slice_in_dim(q, at, block, 2)
+        at_q = at + jnp.arange(block)
+        if first is not None:
+            at_q = first[:, None] + at_q                     # [B, block]
+        mask = positions <= at_q[..., None]
+        if keep is not None:
+            mask = mask & (lax.dynamic_slice_in_dim(keep, at, block, 1) != 0)
+        s = jnp.where(mask[:, None] if mask.ndim == 3 else mask,
+                      _scores(qb, k, scale), NEG_INF)
+        return _weighted_values(jax.nn.softmax(s, axis=-1), v)
+
+    out = lax.map(rows, jnp.arange(t // block) * block)
+    return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], t, v.shape[-1])
+
+
+def continued_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                        first: jax.Array, *, keep: Optional[jax.Array] = None,
+                        scale: Optional[float] = None,
+                        interpret: bool = False) -> jax.Array:
+    """Causal attention of a prompt's PART over what precedes it and itself:
+    ``q [B, H, P, dk]``, row ``b``'s queries at positions ``first[b] + 0..P-1``
+    (``first [B]`` int32, a RUNTIME value: one program whatever the position);
+    ``k [B, H, Tk, dk]``, ``v [B, H, Tk, dv]`` BY POSITION, index ``j`` the key
+    of position ``j``: a slot's cached prefix with the part's own keys at
+    ``first[b] ..``, ``Tk`` a static bound.  Query ``i`` attends ``j <=
+    first[b] + i``; ``keep [B, P, Tk]`` int8 (None: all of them): of those, the
+    positions a layer that selects lets the row attend, the same for every head
+    (:func:`masked_attention`).
+
+    Lowered for a TPU, whole blocks of 512: the Pallas forward kernel with the
+    first position and the key length ``first[b] + P`` as prefetched scalars,
+    which folds, and fetches, no key block at or beyond the length: a prompt's
+    parts add up to the whole call's cells whatever ``Tk`` is.  Anywhere else
+    the masked scores a block of queries at a time, the reference the kernel is
+    tested against.  Forward only."""
+    t_q, t_k = q.shape[2], k.shape[2]
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    first = first.astype(jnp.int32)
+    xla = lambda q, k, v, first, keep: _attention_by_query_block(  # noqa: E731
+        q, k, v, keep, first, scale)
+    block = next((b for b in (1024, 512) if t_q % b == 0 and t_k % b == 0), None)
+    if block is None:
+        return xla(q, k, v, first, keep)
+
+    def kernel(q, k, v, first, keep):
+        pack, unpack, layout = _flash_pack(q, k, v)
+        out, _ = _flash_forward(
+            pack(q), pack(k), pack(v), layout=layout, causal=True, scale=scale,
+            block_q=block, block_k=block, interpret=interpret, keep=keep,
+            bounds=jnp.stack([first, first + t_q, jnp.zeros_like(first)],
+                             axis=1).reshape(-1))
+        return unpack(out)
+
+    if interpret:
+        return kernel(q, k, v, first, keep)
+    return lax.platform_dependent(q, k, v, first, keep, tpu=kernel, default=xla)
+
+
 def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      keep: jax.Array, *, scale: Optional[float] = None,
                      interpret: bool = False) -> jax.Array:
@@ -1353,21 +1571,8 @@ def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     *_, t, d = q.shape
     scale = scale if scale is not None else d ** -0.5
 
-    def xla(q, k, v, keep):
-        block = next(b for b in (256, t) if t % b == 0)
-        positions = jnp.arange(t)
-
-        def rows(first):
-            qb = lax.dynamic_slice_in_dim(q, first, block, 2)
-            kb = lax.dynamic_slice_in_dim(keep, first, block, 1)
-            mask = (kb != 0) & (
-                positions[None, :] <= (first + jnp.arange(block))[:, None])
-            s = jnp.where(mask[:, None], _scores(qb, k, scale), NEG_INF)
-            return _weighted_values(jax.nn.softmax(s, axis=-1), v)
-
-        out = lax.map(rows, jnp.arange(t // block) * block)
-        return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], t, v.shape[-1])
-
+    xla = lambda q, k, v, keep: _attention_by_query_block(  # noqa: E731
+        q, k, v, keep, None, scale)
     if t < FLASH_MIN_T or t % 512:
         return xla(q, k, v, keep)
 

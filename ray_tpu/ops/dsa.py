@@ -97,31 +97,38 @@ def top_k_mask(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
 
 
 def causal_top_k_mask(q: jax.Array, w: jax.Array, k: jax.Array, top_k: int,
-                      block: int = QUERY_BLOCK) -> jax.Array:
-    """A prompt's selection: ``q [B, H, T, d]``, ``w [B, T, H]``, ``k [B, T,
-    d]`` -> ``keep [B, T, T]`` int8, row ``t`` holding the ``top_k`` positions
+                      block: int = QUERY_BLOCK, first=None) -> jax.Array:
+    """A prompt's selection: ``q [B, H, T, d]``, ``w [B, T, H]``, ``k [B, Tk,
+    d]`` -> ``keep [B, T, Tk]`` int8, row ``t`` holding the ``top_k`` positions
     ``s <= t`` of largest index score (all of them while ``t < top_k``).  A
     block of queries at a time (``lax.map``), so that the float32 products a
-    block are what is held."""
+    block are what is held.  ``first [B]`` int32 (None: 0, and ``Tk == T``): a
+    prompt's PART, whose row ``t`` sits at position ``first[b] + t`` and whose
+    keys are a slot's index keys by position, the cached ones below ``first``
+    and the part's own from there, up to a static bound ``Tk``: ONE threshold
+    over both, the whole prompt's selection for the same scores."""
     B, H, T, d = q.shape
     block = min(block, T)
     assert T % block == 0, (T, block)
     n = T // block
-    positions = jnp.arange(T)
+    positions = jnp.arange(k.shape[1])
 
     def rows(args):
-        qb, wb, first = args                       # [B, H, block, d], [B, block, H]
+        qb, wb, at = args                          # [B, H, block, d], [B, block, H]
         with jax.named_scope("attention.index_score"):
-            scores = index_scores(qb, wb, k)       # [B, block, T]
-        causal = positions[None, :] <= (first + jnp.arange(block))[:, None]
+            scores = index_scores(qb, wb, k)       # [B, block, Tk]
+        at = at + jnp.arange(block)
+        if first is not None:
+            at = first.astype(jnp.int32)[:, None] + at
+        causal = positions <= at[..., None]
         with jax.named_scope("attention.index_select"):
             return top_k_mask(scores, jnp.broadcast_to(causal, scores.shape),
                               top_k).astype(jnp.int8)
 
     qb = jnp.moveaxis(q.reshape(B, H, n, block, d), 2, 0)
     wb = jnp.moveaxis(w.reshape(B, n, block, H), 1, 0)
-    keep = lax.map(rows, (qb, wb, jnp.arange(n) * block))   # [n, B, block, T]
-    return jnp.moveaxis(keep, 0, 1).reshape(B, T, T)
+    keep = lax.map(rows, (qb, wb, jnp.arange(n) * block))   # [n, B, block, Tk]
+    return jnp.moveaxis(keep, 0, 1).reshape(B, T, k.shape[1])
 
 
 def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array, index,

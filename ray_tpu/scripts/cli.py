@@ -812,6 +812,14 @@ def cmd_perf(args) -> None:
                     f"p50 {m['tpot_p50_s'] * 1e3:.2f}ms "
                     f"p95 {m['tpot_p95_s'] * 1e3:.2f}ms over a decode tick "
                     f"of {(m.get('baseline_s') or 0) * 1e3:.1f}ms")
+            split = m.get("parts") or {}
+            if split.get("calls"):
+                # prompts longer than one prefill part went in parts, between
+                # the decode chunks
+                out.append(
+                    f"  {eid}: {split['prompts']} prompts prefilled in "
+                    f"{split['calls']} parts ({split['live_tokens']} prompt "
+                    f"tokens in {split['padded_tokens']} padded)")
         states = {eid: m["state"] for eid, m in interference.items()
                   if m.get("state")}
         if states:

@@ -16,7 +16,14 @@ is part of the framework here:
   iteration.  A chunk ends where the nearest live request ends: when one has
   fewer tokens left than the chunk is long, the CUT chunk runs just those
   steps (the same step body under a runtime bound: one further program
-  whatever the bound), so no answer waits for steps nobody needs.
+  whatever the bound), so no answer waits for steps nobody needs.  A prompt
+  longer than one PART (:data:`PREFILL_PART_TOKENS`) goes into its slot a
+  part at a time, each part attending what the earlier ones left in the cache
+  (``generate.prefill_at``'s ``offsets``: one further program whatever the
+  offset), at most one part between two decode chunks while any row decodes:
+  a pasted document stalls no live stream for longer than one part takes.
+  Buckets above a part are never called, so their programs are never built; a
+  family with recurrent layers keeps whole prompts (``generate.can_continue``).
 - :func:`llm_deployment` — wraps the engine in a Serve deployment on a
   ``num_tpus`` replica; requests block on a future the engine thread
   resolves, so Serve's threaded replica concurrency (not the engine)
@@ -81,6 +88,25 @@ def call_rows(bucket: int, n_slots: int) -> int:
     return max(1, min(n_slots, CALL_TOKENS // bucket))
 
 
+# Tokens of a prompt one prefill call takes: a longer prompt goes into its slot
+# a PART of this many at a time, each part attending what the earlier ones left
+# in the cache (``generate.prefill_at``'s ``offsets``), at most one part between
+# two decode chunks while any row decodes: what a live stream may wait for
+# somebody else's document.  The chip's sweep (PERF.md section 6, PR 45; the
+# serve cells' fixed traces, warm runs): dots3-note's cell (prompts 2,048 -
+# 16,384) at 1,024 / 2,048 / 4,096 reads ``tpot_p95_ms`` 12.0 / 13.0 / 19.7 for
+# the whole prompts' 30.3, but at 1,024 the parts' own queue grows (260.2
+# tokens/s for the 280.3 offered, the first token's p95 5.2 s for 2.6 s at
+# 2,048 and 1.7 s at 4,096): a part pays ~40 ms of its ~100 for the program's
+# static bound whatever it is wide, so halving it halves nothing.  Kimi-K2's
+# cell (prompts 256 - 8,192, half of them split) hardly tells the sizes apart:
+# 9.7 - 10.4 / 9.4 - 9.6 / 9.4 for the whole prompts' 9.15 - 9.20, the first
+# token's p95 1.6 - 2.4 s / 0.95 s / 0.7 s: its whole calls were short enough
+# already, so the size is dots3-note's.  (A prompt's FIRST part runs the 2,048
+# bucket's own program: ``_part_call``.)
+PREFILL_PART_TOKENS = 2048
+
+
 def _llm_metrics():
     global _LLM_METRICS
     if _LLM_METRICS is None:
@@ -126,9 +152,11 @@ class _TickMeter:
     landed splits its period into a prefill part and a chunk part; where the
     host looks late the call had landed earlier, so the prefill part is an
     upper bound.  Periods are classed ``decode_only`` / ``interleaved`` (a
-    prefill call while other slots were mid-decode) / ``prefill_only``; an
-    interleaved period's prefill part is *prefill interference*: what the
-    requests that were decoding waited for other requests' prompts.
+    prefill call while other slots were mid-decode: a whole prompt's, or one
+    PART of a long one, whether or not the tick admitted a row to its chunk) /
+    ``prefill_only``; an interleaved period's prefill part is *prefill
+    interference*: what the requests that were decoding waited for other
+    requests' prompts.
 
     Also summed here: the engine thread's own time a tick (``host_s``) and,
     over finished requests, what their decode spans held (``decode``).
@@ -156,10 +184,13 @@ class _TickMeter:
         self._landed: Optional[float] = None  # the previous landing
         self._period_s = 0.0    # of the tick being drained, so far
         self._counted = False   # it has a previous landing to start from
+        self._calls = 0         # its prefill calls that have landed
         self._since_emit = 0
         # the engine's recurrent-state counters (its own dict, or None): they
-        # ride this meter's event to `ray_tpu perf`
+        # ride this meter's event to `ray_tpu perf`; so does the engine's
+        # tally of the prompts that went in parts (its own dict too)
         self.state: Optional[Dict[str, int]] = None
+        self.parts: Optional[Dict[str, int]] = None
 
     def begin(self, chained: bool) -> None:
         """The drain of one tick begins; ``chained``: it was dispatched
@@ -168,9 +199,11 @@ class _TickMeter:
         if not self._counted:
             self._landed = None
         self._period_s = 0.0
+        self._calls = 0
 
     def call_landed(self, t: float) -> None:
         """One of the tick's prefill calls landed at ``t``."""
+        self._calls += 1
         if self._landed is not None:
             self._period_s += t - self._landed
             self.prefill_s += t - self._landed
@@ -179,14 +212,16 @@ class _TickMeter:
 
     def chunk_landed(self, t: float, n_admitted: int, n_rows: int) -> None:
         """The tick's chunk landed at ``t``: its record is made
-        (``n_admitted`` of the ``n_rows`` it decoded were prefilled in it)."""
+        (``n_admitted`` of the ``n_rows`` it decoded were prefilled in it; a
+        tick whose calls were parts that completed no prompt admitted none, and
+        one that decoded no row has its last call's landing for ``t``)."""
         prefill_part = self._period_s
         if self._landed is not None:
             self._period_s += t - self._landed
         self._landed = t
         if not self._counted:
             return
-        if not n_admitted:
+        if not (self._calls or n_admitted):
             mode = "decode_only"
         elif n_rows > n_admitted:
             mode = "interleaved"
@@ -284,25 +319,36 @@ class _TickMeter:
             decode_only_ticks=self.ticks["decode_only"],
             baseline_s=snap["decode_tick_baseline_s"],
             tpot_p50_s=tpot.get("p50_s"), tpot_p95_s=tpot.get("p95_s"),
-            **({"state": dict(self.state)} if self.state else {}))
+            **({"state": dict(self.state)} if self.state else {}),
+            **({"parts": dict(self.parts)} if self.parts else {}))
 
 
 def cache_positions(largest_bucket: int, max_new_tokens: int,
                     chunk_steps: int) -> int:
-    """Cache positions an engine gives a slot: the longest prompt, the
+    """Cache positions an engine gives a slot: the longest prompt (in whole
+    parts, where it is longer than one: :func:`part_bound`), the
     longest answer and one chunk of slack for the flush, rounded UP to whole
     tiles of the decode kernel — a cache of whole tiles is what lets the
     decode step read only a slot's live tiles
     (:func:`ray_tpu.ops.attention.ragged_decode_attention`)."""
     from ray_tpu.ops.attention import DECODE_TILE
 
-    need = largest_bucket + max_new_tokens + chunk_steps
+    need = part_bound(largest_bucket) + max_new_tokens + chunk_steps
     return -(-need // DECODE_TILE) * DECODE_TILE
+
+
+def part_bound(largest_bucket: int) -> int:
+    """The cache positions a prompt's part may attend, itself included: the
+    largest bucket in whole parts (the part program's static bound on the
+    keys; 16,384 for dots3's cell, where the buckets are whole parts)."""
+    part = PREFILL_PART_TOKENS
+    return largest_bucket if largest_bucket <= part else (
+        -(-largest_bucket // part) * part)
 
 
 class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
-                 "submitted_at", "trace_ctx", "dispatched_at",
+                 "prefilled", "submitted_at", "trace_ctx", "dispatched_at",
                  "first_host_t", "last_host_t", "chunks", "chunk_steps",
                  "prefill_mark")
 
@@ -315,6 +361,10 @@ class _Request:
         # at dispatch time — emitted lags one chunk behind in the pipeline,
         # so completion prediction must count scheduled, not emitted
         self.scheduled = 0
+        # prompt tokens dispatched in PARTS so far (a prompt longer than one
+        # part; a whole prompt's call leaves it 0).  A request that holds a
+        # slot with nothing scheduled is mid-prefill: it sits the chunks out
+        self.prefilled = 0
         self.submitted_at = time.perf_counter()
         # stage boundaries (perf_counter), set on the engine thread with the
         # observability layer on: prefill dispatched; the first token on the
@@ -348,12 +398,16 @@ class _PendingChunk:
                  "chained")
 
     def __init__(self, chunk_dev, steps, rows, prefills, routed_dev, chained):
-        self.chunk_dev = chunk_dev          # [n_slots+1, chunk] device
+        # [n_slots+1, chunk] device; None: a tick whose calls were parts
+        # and no row decoded, so that no chunk was dispatched
+        self.chunk_dev = chunk_dev
         self.steps = steps                  # the steps it ran: chunk, or its cut
         self.rows = rows                    # [(slot, _Request)] active in chunk
         # this iteration's prefill calls, in dispatch order: (admissions
         # [(row_j, slot, _Request)], first tokens [rows] device, the call's
-        # routing counts: device, None where the family counts nothing)
+        # routing counts: device, None where the family counts nothing).  A
+        # part that completes no prompt admits nobody, and in the first
+        # tokens' place has where its row stands now (read for its landing)
         self.prefills = prefills
         # the chunk's routing counts (device or None): they land with the tokens
         self.routed_dev = routed_dev
@@ -426,6 +480,12 @@ class GenerationEngine:
         # thousands of tokens sets one); a tick's first call goes whatever
         # it is wide, so no budget can starve the queue
         self._tick_tokens = prefill_token_budget or n_slots * self.buckets[-1]
+        # a prompt longer than this goes in parts of it, where the family's
+        # caches can be read back by a later part (None: whole prompts only:
+        # recurrent layers, or no bucket above a part)
+        self._part: Optional[int] = PREFILL_PART_TOKENS if (
+            gen.can_continue(cfg)
+            and self.buckets[-1] > PREFILL_PART_TOKENS) else None
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -451,9 +511,12 @@ class GenerationEngine:
                              "flushed": 0}
         # cumulative, by prefill bucket: calls, the rows and tokens they were
         # wide, and the prompts and prompt tokens among those
+        # ... and, under "parts" for an engine that splits prompts, the same
+        # of those that went in PARTS: the prompts split, the part calls (one
+        # row each), and the tokens they took
         self._prefill = {b: {"calls": 0, "rows": 0, "padded_tokens": 0,
                              "prompts": 0, "live_tokens": 0}
-                         for b in self.buckets}
+                         for b in (*self.buckets, *(("parts",) if self._part else ()))}
         # cumulative routing counts of the dispatches drained so far, where
         # the family's layers count (leaves as the programs return them:
         # stacked over the layers that route); None until a first arrives
@@ -494,9 +557,11 @@ class GenerationEngine:
                               + tails.nbytes // (n_state * tails.shape[2]))}
         self._key = jax.random.PRNGKey(seed)
 
-        self._prefill_jit, self._decode_jit, decode_cut_jit = engine_programs(
+        (self._prefill_jit, self._decode_jit, decode_cut_jit,
+         self._part_jit) = engine_programs(
             cfg, decode_chunk_steps=decode_chunk_steps,
-            temperature=temperature, top_k=top_k, eos_id=eos_id)
+            temperature=temperature, top_k=top_k, eos_id=eos_id,
+            part_bound=part_bound(self.buckets[-1]) if self._part else None)
         # a named function, not a lambda: a device trace lists each program
         # under its function's name (jit_llm_first_tokens).  One program a
         # prefill width: it draws the call's key, samples the first tokens
@@ -511,6 +576,8 @@ class GenerationEngine:
         self._first_jit = jax.jit(llm_first_tokens)
 
         self._slots: List[Optional[_Request]] = [None] * n_slots
+        # the slot-holders whose prompt is going in parts, oldest first
+        self._splitting: List[tuple] = []  # (slot, _Request)
         # device-resident last token per slot: decode chunk N+1 chains off
         # chunk N's output ON DEVICE, so dispatching N+1 never waits for
         # N's tokens to reach the host
@@ -553,6 +620,7 @@ class GenerationEngine:
         self._ticks = _TickMeter(
             f"engine-{_os.getpid()}-{next(_ENGINE_SEQ)}")
         self._ticks.state = self._state
+        self._ticks.parts = self._prefill.get("parts")
         self._ttft_samples: "_deque[float]" = _deque(maxlen=4096)
         self._itl_samples: "_deque[float]" = _deque(maxlen=4096)
 
@@ -719,7 +787,10 @@ class GenerationEngine:
             # full one) and the bytes of one tile of each
             "cache_tiles": cache_tiles,
             # what the prefill calls were wide and what of it was prompt
-            # (cumulative, by bucket)
+            # (cumulative, by bucket), and under ``parts``, where the engine
+            # splits prompts, the same of the prompts longer than one part:
+            # the prompts split, their part calls (a row each), the tokens
+            # those took, padded and live
             "prefill": prefill,
             # a family with expert layers: what its layers counted of the
             # routing in the dispatches drained so far, prefills and decode
@@ -767,6 +838,7 @@ class GenerationEngine:
                         if rec is not None:
                             victims += [r for _, r in rec.rows]
                     self._slots = [None] * self.n_slots
+                    self._splitting.clear()  # (they held slots: failed above)
                     self._queue.clear()
                     self._pending = None
                     self._draining = None
@@ -801,17 +873,56 @@ class GenerationEngine:
         prompt is padded only to ITS bucket.  Admission stops when the free
         slots, the queue or the tick's budget of padded tokens
         (``_tick_tokens``; a tick's first call always goes) are used up.
+
+        A prompt longer than one PART (``_part``) takes its slot and stays
+        out of the decode chunks until it is all in (``_splitting``): its
+        parts are calls of this tick and the next ones, the oldest such
+        prompt's first: ONE part a tick while any row decodes, parts back to
+        back under the budget while none does.  Its last part's first token
+        is a whole call's.
+
         Every call is dispatched here, none is read back: returns the calls
         as ``_PendingChunk.prefills`` holds them, their first tokens ON
         DEVICE (merged into the last-token row there); the values, and the
         calls' routing counts, reach the host with the next chunk drain."""
         with self._lock:
             free = [i for i, s in enumerate(self._slots) if s is None]
-            calls: List[tuple] = []  # (bucket, [(slot, _Request)])
+            # in dispatch order: (bucket, [(slot, _Request)]), or (None,
+            # (slot, _Request, first token of the part, its tokens))
+            calls: List[tuple] = []
             spent = 0
+            decoding = any(s is not None and s.scheduled for s in self._slots)
+
+            def parts_of(slot, req) -> None:
+                # this tick's parts of one prompt: the tick's first call
+                # always goes; a further one only while no row decodes, and
+                # under the budget
+                nonlocal spent
+                while req.prefilled < len(req.tokens) and (
+                        not calls or (not decoding and spent + self._part
+                                      <= self._tick_tokens)):
+                    n = min(self._part, len(req.tokens) - req.prefilled)
+                    calls.append((None, (slot, req, req.prefilled, n)))
+                    req.prefilled += n
+                    spent += self._part
+                    for name, more in (("calls", 1), ("rows", 1), ("live_tokens", n),
+                                       ("padded_tokens", self._part)):
+                        self._prefill["parts"][name] += more
+                if req.prefilled >= len(req.tokens):
+                    self._splitting.remove((slot, req))
+
+            for slot, req in list(self._splitting):
+                parts_of(slot, req)
             for slot in free:
                 if not self._queue:
                     break
+                if self._part and len(self._queue[0].tokens) > self._part:
+                    req = self._queue.pop(0)
+                    self._slots[slot] = req
+                    self._splitting.append((slot, req))
+                    self._prefill["parts"]["prompts"] += 1
+                    parts_of(slot, req)  # (none where an older prompt waits)
+                    continue
                 b = self._bucket(len(self._queue[0].tokens))
                 if not (calls and calls[-1][0] == b
                         and len(calls[-1][1]) < self._rows[b]):
@@ -823,19 +934,48 @@ class GenerationEngine:
                 self._slots[slot] = req
                 calls[-1][1].append((slot, req))
             for b, batch in calls:
+                if b is None:
+                    continue  # (a part: tallied where it was planned)
                 tally = self._prefill[b]
                 tally["calls"] += 1
                 tally["rows"] += self._rows[b]
                 tally["padded_tokens"] += self._rows[b] * b
                 tally["prompts"] += len(batch)
                 tally["live_tokens"] += sum(len(r.tokens) for _, r in batch)
-        return [self._prefill_call(b, batch) for b, batch in calls]
+        return [self._part_call(*batch) if b is None
+                else self._prefill_call(b, batch) for b, batch in calls]
 
-    def _prefill_call(self, b: int, batch):
+    def _stamp_dispatch(self, reqs) -> None:
+        """The requests' prompts start going in now: their queue wait ends."""
+        if not _events.ENABLED:
+            return
+        t_dispatch = time.perf_counter()
+        hist = _llm_metrics()["admission"]
+        for req in reqs:
+            req.dispatched_at = t_dispatch
+            waited = t_dispatch - req.submitted_at
+            hist.observe(waited)
+            tracing.emit_stage("engine.queue", waited, req.trace_ctx)
+
+    def _first_tokens(self, last_logits, slots_dev, routed_dev, reqs):
+        """Sample the first tokens of a call that completed its prompts, on
+        the device, into the last-token row there; nothing is read back."""
+        firsts_dev, self._last_tok_dev, self._key = self._first_jit(
+            last_logits, self._key, self._last_tok_dev, slots_dev)
+        firsts_dev.copy_to_host_async()
+        _to_host_async(routed_dev)
+        for req in reqs:
+            req.scheduled = 1  # the prefill's sampled first token
+        return firsts_dev
+
+    def _prefill_call(self, b: int, batch, first_part: bool = False):
         """Dispatch one prefill call of bucket ``b`` for ``batch`` [(slot,
         _Request)]: the bucket's fixed rows wide (ONE compiled program a
         bucket: a width that varies recompiles mid-serving), the rows no
-        prompt fills a 1-token dummy aimed at the scratch slot."""
+        prompt fills a 1-token dummy aimed at the scratch slot.
+        ``first_part``: the prompts are longer than the bucket and this is
+        their first ``b`` tokens (``_part_call``): nobody is admitted, and
+        the drain reads the logits for the call's landing."""
         import jax.numpy as jnp
 
         n = self._rows[b]
@@ -844,36 +984,63 @@ class GenerationEngine:
         lens = np.ones((n,), np.int32)
         slots = np.full((n,), self.n_slots, np.int32)  # scratch slot
         for j, (slot, req) in enumerate(batch):
-            toks[j, :len(req.tokens)] = req.tokens
-            lens[j] = len(req.tokens)
+            own = req.tokens[:b]
+            toks[j, :len(own)] = own
+            lens[j] = len(own)
             slots[j] = slot
-        if _events.ENABLED:
-            t_dispatch = time.perf_counter()
-            hist = _llm_metrics()["admission"]
-            for _, req in batch:
-                req.dispatched_at = t_dispatch
-                waited = t_dispatch - req.submitted_at
-                hist.observe(waited)
-                tracing.emit_stage("engine.queue", waited, req.trace_ctx)
+        self._stamp_dispatch(req for _, req in batch)
         slots_dev = jnp.asarray(slots)
         last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
             self.cache, slots_dev)
-        firsts_dev, self._last_tok_dev, self._key = self._first_jit(
-            last_logits, self._key, self._last_tok_dev, slots_dev)
-        firsts_dev.copy_to_host_async()
-        _to_host_async(routed_dev)
-        for _, req in batch:
-            req.scheduled = 1  # the prefill's sampled first token
+        if first_part:
+            _to_host_async((last_logits, routed_dev))
+            return [], last_logits, routed_dev
+        firsts_dev = self._first_tokens(
+            last_logits, slots_dev, routed_dev, [req for _, req in batch])
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
         return admissions, firsts_dev, routed_dev
+
+    def _part_call(self, slot: int, req: _Request, first: int, n: int):
+        """Dispatch one PART of ``req``'s prompt, its tokens ``[first, first +
+        n)``, into ``slot``: one row, a part wide (ONE compiled program
+        whatever ``first`` is: the offset is a runtime value).  The part that
+        ends the prompt samples its first token as a whole call does; any
+        other admits nobody to the chunk, and hands the drain where its row
+        stands instead of first tokens.  A FIRST part, where a part is one of
+        the buckets, is that bucket's own program over the prompt's first
+        tokens (a slot from scratch: it reads nothing of the cache, where the
+        part program reads its static bound whatever the offset; measured:
+        the constant's comment)."""
+        import jax.numpy as jnp
+
+        if first == 0 and self._part in self._rows:
+            return self._prefill_call(self._part, [(slot, req)], first_part=True)
+        toks = np.zeros((1, self._part), np.int32)
+        toks[0, :n] = req.tokens[first:first + n]
+        if first == 0:
+            self._stamp_dispatch([req])
+        slots_dev = jnp.asarray(np.array([slot], np.int32))
+        last_logits, self.cache, routed_dev, stands_dev = self._part_jit(
+            self.params, jnp.asarray(toks),
+            jnp.asarray(np.array([n], np.int32)), self.cache, slots_dev,
+            jnp.asarray(np.array([first], np.int32)))
+        if first + n < len(req.tokens):
+            stands_dev.copy_to_host_async()
+            _to_host_async(routed_dev)
+            return [], stands_dev, routed_dev
+        firsts_dev = self._first_tokens(
+            last_logits, slots_dev, routed_dev, [req])
+        return [(0, slot, req)], firsts_dev, routed_dev
 
     def step(self) -> bool:
         """One engine iteration, software-pipelined against the device:
 
         1. admit queued prompts into free slots (as many narrow prefill
            calls as the queue's head, the free slots and the tick's token
-           budget allow; no readback)
+           budget allow; no readback).  A prompt longer than one part goes
+           in PARTS, one a tick while any row decodes (``_admit``), and its
+           slot joins the chunks when its last part has gone
         2. dispatch decode chunk N (chains off device-side last tokens): as
            long as every live request has tokens left for it, else CUT to the
            fewest any has left (``max_new - scheduled``, which the host knows
@@ -901,8 +1068,15 @@ class GenerationEngine:
             prefills = self._admit()
         t_admitted = time.perf_counter() if meter else 0.0
         with self._lock:
-            rows = [(i, s) for i, s in enumerate(self._slots) if s is not None]
+            # (a slot-holder with nothing scheduled is mid-prefill)
+            rows = [(i, s) for i, s in enumerate(self._slots)
+                    if s is not None and s.scheduled]
         dispatched = None
+        if prefills and not rows:
+            # parts of a prompt that is not all in yet, and nobody to decode
+            dispatched = _PendingChunk(
+                None, 0, rows, prefills, None,
+                chained=self._pending is not None)
         if rows:
             with self._annotate("engine.decode_dispatch"):
                 active = np.zeros((self.n_slots + 1,), bool)  # scratch inactive
@@ -1037,6 +1211,10 @@ class GenerationEngine:
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
             self._count_routed("prefill", routed_dev)
+        if pending.chunk_dev is None:  # a tick of parts alone: no chunk
+            if meter is not None:
+                meter.chunk_landed(landed, 0, 0)
+            return waited
         # the transfer is already in flight
         chunk, landed, blocked = self._read_back(pending.chunk_dev)
         waited += blocked
@@ -1124,14 +1302,19 @@ class GenerationEngine:
 
 
 def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
-                    top_k: int = 0, eos_id: Optional[int] = None):
-    """The engine's three device programs, ``(prefill, decode_chunk, the cut
-    decode chunk)``: one prefill per prompt bucket (compiled lazily), the
-    whole chunk of ``decode_chunk_steps``, and the same steps under a runtime
-    bound (a sixth argument, ``n``: one program whatever it is).  cfg is
-    closed over (hashable frozen dataclass).  A function of the config alone,
-    so the compile test (``tests/test_chip_compile.py``) lowers exactly what
-    a replica runs."""
+                    top_k: int = 0, eos_id: Optional[int] = None,
+                    part_bound: Optional[int] = None):
+    """The engine's device programs, ``(prefill, decode_chunk, the cut decode
+    chunk, the prefill of a prompt's part)``: one prefill per prompt bucket
+    (compiled lazily), the whole chunk of ``decode_chunk_steps``, the same
+    steps under a runtime bound (a sixth argument, ``n``: one program whatever
+    it is), and ONE program for every part of every long prompt (a sixth
+    argument, the rows' offsets: one program whatever they are; it reads a
+    slot's first ``part_bound`` cached positions at most; None: an engine that
+    splits no prompt has none).  cfg is closed over (hashable frozen
+    dataclass).  A function of the config and the bound alone, so the compile
+    test (``tests/test_chip_compile.py``) lowers exactly what a replica
+    runs."""
     import jax
 
     from ray_tpu.models import generate as gen
@@ -1166,7 +1349,17 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
             steps=decode_chunk_steps, temperature=temperature, top_k=top_k,
             eos_id=eos_id)
 
-    return prefill, decode, jax.jit(llm_decode_cut, donate_argnums=(1,))
+    # ``jit_llm_prefill_part``: a trace's readers sum the prefill programs by
+    # the name they share; beside a whole call's results, where the rows stand
+    # now (what a part that samples no token is read back by)
+    def llm_prefill_part(params, toks, lens, cache, slots, offsets):
+        logits, cache = gen.prefill_at(params, cfg, toks, lens, cache, slots,
+                                       offsets, part_bound)
+        return logits, cache, cache.pop("routed", None), cache["pos"][slots]
+
+    part = None if part_bound is None else jax.jit(
+        llm_prefill_part, donate_argnums=(3,))
+    return prefill, decode, jax.jit(llm_decode_cut, donate_argnums=(1,)), part
 
 
 def _decode_chunk_wrapper(gen, cfg, params, cache, tokens, active, key, *,

@@ -136,6 +136,17 @@ def _lower_prefill(chip, prefill, params, cache, n_slots, bucket):
         _on(chip, params), i32(n, bucket), i32(n), _on(chip, cache), i32(n))
 
 
+def _lower_part(chip, part, params, cache):
+    """``llm_prefill_part`` as the engine calls it: ONE row a part wide, its
+    offset a sixth, runtime argument (one program whatever it is)."""
+    from ray_tpu.serve import llm
+
+    i32 = lambda *shape: _on(chip, jax.ShapeDtypeStruct(shape, jnp.int32))  # noqa: E731
+    return part.lower(
+        _on(chip, params), i32(1, llm.PREFILL_PART_TOKENS), i32(1),
+        _on(chip, cache), i32(1), i32(1))
+
+
 def _lower_decodes(chip, decode, cut, params, cache, n_slots):
     """The engine's two decode programs as it calls them: the whole chunk
     with five arguments, the cut chunk with its runtime bound as a sixth."""
@@ -166,7 +177,8 @@ def _lower_serve_engine(chip, family, *, buckets=(128,), chunk=64, max_new=128,
     cache = jax.eval_shape(
         lambda: gen.init_cache(
             cfg, n_slots + 1, llm.cache_positions(max(buckets), max_new, chunk)))
-    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill, decode, cut, part = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    assert part is None  # no bucket above a part: whole prompts only
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
     widest, *narrower = sorted(buckets, reverse=True)
     return [
@@ -188,7 +200,10 @@ def _lower_exaone_cell(chip):
     256-position rings for the four window layers), 16 held experts a
     sparse layer through the grouped matmul, every prefill bucket at the
     engine's width for it (``llm.call_rows``: two rows of 128, one of each
-    wider bucket)."""
+    wider bucket) up to one part of 2,048 tokens, and the part program in
+    the 4,096 bucket's place (a prompt above a part goes in parts: the full
+    layer's cached k, v through the flash kernel with a runtime key length,
+    the window layers' rings read ahead of the part)."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -200,11 +215,12 @@ def _lower_exaone_cell(chip):
         cfg, n_slots + 1, llm.cache_positions(4096, 512, chunk)))
     assert cache["k"].shape == (1, 33, 8, 128, 4736)
     assert cache["k_ring"].shape == (4, 33, 8, 128, 256)
-    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(4096))
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
-    assert [llm.call_rows(b, n_slots) for b in (128, 256, 4096)] == [2, 1, 1]
+    assert [llm.call_rows(b, n_slots) for b in (128, 256, 2048)] == [2, 1, 1]
     return [
-        prefill_of(4096),
+        _lower_part(chip, part, params, cache),
         *_lower_decodes(chip, decode, cut, params, cache, n_slots),
         *map(prefill_of, (2048, 1024, 512, 256, 128)),
     ]
@@ -220,8 +236,10 @@ def _lower_kimi_cell(chip):
     """The serve-kimi-k2.7-code-ep32-code cell's programs: six latent layers
     over ONE cache tensor (33 rows x 9,344 positions of one 576-value row),
     12 held experts a sparse layer through the grouped matmul, every prefill
-    bucket one row wide up to 8,192 tokens (the widest through the Pallas
-    flash kernel with 192-wide keys and 128-wide values)."""
+    bucket one row wide up to one part of 2,048 tokens, and the part program
+    for every longer prompt (the cached rows up-projected by the layer's own
+    weights, then the Pallas flash kernel with 192-wide keys, 128-wide values
+    and a runtime key length)."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -234,13 +252,14 @@ def _lower_kimi_cell(chip):
         cfg, n_slots + 1, llm.cache_positions(8192, 1024, chunk)))
     assert set(cache) == {"c", "pos"}
     assert cache["c"].shape == (6, 33, 1, 576, 9344)
-    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(8192))
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
-    assert [llm.call_rows(b, n_slots) for b in (256, 8192)] == [1, 1]
+    assert [llm.call_rows(b, n_slots) for b in (256, 2048)] == [1, 1]
     return [
-        prefill_of(8192),
+        _lower_part(chip, part, params, cache),
         *_lower_decodes(chip, decode, cut, params, cache, n_slots),
-        *map(prefill_of, (4096, 2048, 1024, 512, 256)),
+        *map(prefill_of, (2048, 1024, 512, 256)),
     ]
 
 
@@ -272,7 +291,8 @@ def _lower_granite_cell(chip):
     assert cache["ssm"].shape == (18, 49, 64, 128, 128)
     assert cache["ssm"].dtype == jnp.float32
     assert cache["k"].shape == (2, 49, 8, 128, 2688)
-    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill, decode, cut, _ = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    assert not gen.can_continue(cfg)  # a recurrent layer: whole prompts only
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
     assert [llm.call_rows(b, n_slots) for b in (64, 128, 256, 2048)] == [4, 2, 1, 1]
     return [
@@ -293,10 +313,12 @@ def _lower_dots3_cell(chip):
     layers that SELECT (a 576-value row and a 128-value index key a position,
     33 rows x 17,536 positions), three sliding latent layers over rings of
     1,026 rows of 1,088 values, 8 held experts a sparse layer; the decode
-    chunk hands the latent kernel the selection as a mask; every prefill
-    bucket one row wide up to 16,384 tokens, the full layers through the
-    Pallas forward kernel with the selection as its fourth operand (from
-    4,096 tokens up)."""
+    chunk hands the latent kernel the selection as a mask; a prompt of at
+    most one part (2,048 tokens) through its bucket's program, every longer
+    one through the part program: the full layers' cached rows up-projected,
+    the selection over cached and own index keys, and the Pallas forward
+    kernel with the selection as its fourth operand and a runtime key length
+    over a static 16,384 positions."""
     from ray_tpu.models import generate as gen
     from ray_tpu.serve import llm
 
@@ -310,15 +332,15 @@ def _lower_dots3_cell(chip):
     assert cache["c"].shape == (2, 33, 1, 576, 17536)
     assert cache["idx_k"].shape == (2, 33, 1, 128, 17536)
     assert cache["c_ring"].shape == (3, 33, 1, 1088, 1026)
-    prefill, decode, cut = llm.engine_programs(cfg, decode_chunk_steps=chunk)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(16384))
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
-    assert [llm.call_rows(b, n_slots) for b in (2048, 16384)] == [1, 1]
-    # the widest bucket and the narrowest: the two prefill programs that
-    # differ in kind (the masked kernel; plain causal attention, every
-    # position selected).  8,192 and 4,096 are the widest's program at
-    # other lengths; their sandbox compiles are in the cell's assumed.sizes
+    assert llm.call_rows(2048, n_slots) == 1
+    # the engine's two prefill programs: the part program (the masked kernel
+    # under a runtime key length) and the one bucket of at most a part (plain
+    # causal attention, every position selected)
     return [
-        prefill_of(16384),
+        _lower_part(chip, part, params, cache),
         *_lower_decodes(chip, decode, cut, params, cache, n_slots),
         prefill_of(2048),
     ]
@@ -504,6 +526,13 @@ def test_program_compiles_for_v5e(compiled, name):
     if name == "attention_dispatch_train_and_prefill":
         for program in programs:
             assert program.as_text().count("tpu_custom_call") == 2
+    if name in ("serve_engine_exaone_cell", "serve_engine_kimi_cell",
+                "serve_engine_dots3_cell"):
+        # ONE program for every part of every prompt above 2,048 tokens, under
+        # the name a trace's readers sum the prefill programs by; the flash
+        # kernel is in it on every layer that reads a slab
+        assert programs[0].as_text().startswith("HloModule jit_llm_prefill_part")
+        assert "flash_attention_fwd" in programs[0].as_text()
     if name.startswith("serve_engine"):
         # the decode chunk writes the big cache once, at its end, in place:
         # no scatter anywhere in it, and the cache's leaves (k, pos, v, and
@@ -566,8 +595,8 @@ def test_program_compiles_for_v5e(compiled, name):
         assert all(8.0e9 < need < 10.5e9 for need in needs), needs
     if name == "serve_engine_kimi_cell":
         # the latent kernel once a layer, the grouped matmuls of five expert
-        # layers; the 8,192-token prefill attends through the flash kernel
-        # (six layers); 8.35 GB of weights and 2.13 GB of cache resident
+        # layers; a prompt's part attends through the flash kernel (six
+        # layers); 8.35 GB of weights and 2.13 GB of cache resident
         assert programs[1].as_text().count("tpu_custom_call") >= 6 + 5 * 3
         assert "ragged_latent_decode_attention" in programs[1].as_text()
         assert programs[0].as_text().count("tpu_custom_call") >= 6 + 5 * 3
@@ -597,12 +626,14 @@ def test_program_compiles_for_v5e(compiled, name):
         # the latent kernel once a full layer, given the step's selection as
         # a further operand; the sliding layers' rings are read by einsums;
         # the flush kernel over ``c`` and ``idx_k``; the grouped matmuls of
-        # four expert layers.  Every prefill from 4,096 tokens up runs the
-        # Pallas forward kernel with the selection as its fourth operand on
-        # both full layers (a [128, T, T] score tensor never exists: the
-        # 16,384-token call plans 4.3 GB of temporaries); the 2,048 bucket
-        # selects every position and takes the plain causal kernel.  3.64 GB
-        # of weights and 1.85 GB of cache resident
+        # four expert layers.  Every prompt above one part of 2,048 tokens
+        # goes through the PART program (the first of the list), which runs
+        # the Pallas forward kernel with the selection as its fourth operand
+        # and a runtime key length on both full layers (a [128, 2048, 16384]
+        # score tensor never exists: it plans 2.3 GB of temporaries where the
+        # 16,384-token call, which is no longer built, planned 4.3); the
+        # 2,048 bucket selects every position and takes the plain causal
+        # kernel.  3.64 GB of weights and 1.85 GB of cache resident
         for decode in programs[1:3]:
             text = decode.as_text()
             kernels = [line for line in text.splitlines()
@@ -617,8 +648,8 @@ def test_program_compiles_for_v5e(compiled, name):
         for prefill in programs[:1] + programs[3:]:
             assert prefill.as_text().count("flash_attention_fwd") >= 2
             assert not re.search(r"f32\[1,128,\d{4,5},\d{4,5}\]", prefill.as_text())
-        assert programs[0].memory_analysis().temp_size_in_bytes < 5.0e9
-        assert all(5.5e9 < need < 10.5e9 for need in needs), needs
+        assert programs[0].memory_analysis().temp_size_in_bytes < 2.6e9
+        assert all(5.5e9 < need < 8.5e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; the backward kernel + the forward it differentiates
