@@ -155,10 +155,43 @@ def moe_logical_axes() -> Dict[str, Tuple]:
 # its exchange, and nothing stands in for the other chips.
 
 # up to this many (token, expert) pairs go through the grouped matmuls as one
-# block (a decode step, a short prompt); of more, a quarter at a time: an
-# eighth of the pairs are held where eight chips share a layer, so one trip
-# as a rule, and a skewed router costs trips, never tokens
-_ONE_BLOCK_PAIRS = 4096
+# block and a token's rows are gathered back: a decode step (its rows x top-k:
+# 264 - 490 pairs in the serve cells).  A prefill call is 256 tokens or wider
+# (``serve.llm.CALL_TOKENS``: 2,048 pairs and up) and goes in trips.  Lowered
+# from 4,096 on the chip's measurement (PERF.md section 6, PR 46; a whole call
+# of the 256 / 512 bucket, ms): K-EXAONE's 22.9 -> 17.7 / 28.1 -> 23.5,
+# Granite's 31.5 -> 25.3 / 59.6 -> 41.3 (its 512 bucket was in trips already)
+_ONE_BLOCK_PAIRS = 1024
+# of more pairs, the HELD ones go this many rows a trip, as many trips as they
+# need: a skewed router costs trips, never tokens.  The chip's sweep (PERF.md
+# section 6, PR 46; TPU v5e): what a trip costs is its scatter-add, and that
+# neither by its rows nor by its live rows: XLA sorts an update of 512 rows or
+# more and walks the RESULT's rows (3.7 ms into [2,048, 5,120] float32 whether
+# 512 or 4,096 rows are added, live or not), and adds one of up to 256 rows in
+# place (~0.15 ms).  dots3-note's whole 2,048-token call / a part at offset
+# 4,096, ms, by rows a trip: 4,096 (a quarter of all pairs, as it stood) 70.1 /
+# 110.3, 2,048: 68.7 / 107.3, 1,024: 66.5 / 105.4, 512: 77.0 / 112.2,
+# **256: 50.4 / 90.7**; 1,024 and 512 added back in slices of 256 rows: 51.9 /
+# 92.1 and 51.8 / 91.9.  Granite's (20 layers over stacked weights, an eighth
+# held) whole calls of 256 / 512 / 1,024 / 2,048 tokens: 31.5 / 59.6 / 81.7 /
+# 135.9 as it stood, 25.3 / 41.3 / 72.6 / 146.4 at 256 (ten trips a layer at
+# 2,048 tokens, each three grouped-matmul calls over 180 groups: the one shape
+# that lost), 30.6 / 43.7 / 73.1 / 135.9 at 1,024 in slices; K-EXAONE's 128 x
+# 2 / 256 / 512 / 1,024 / 2,048: 21.9 / 22.9 / 28.1 / 42.9 / 62.3 as it
+# stood, 17.1 / 17.7 / 23.5 / 36.7 / 55.2 at 256
+_TRIP_ROWS = 256
+
+
+def dispatch_trips(pairs: int, held):
+    """``(block, trips)`` of one dispatch of ``pairs`` (token, expert) pairs of
+    which ``held`` are this chip's: the rows one trip hands the grouped
+    matmuls, and the trips it takes (``block * trips`` rows computed).
+    ``held`` may be a count, an array of counts (a layer each) or a traced
+    value: the device's loop and the host's counter
+    (``perf_stats()["moe"]["prefill"]["rows_computed"]``) both ask here."""
+    if pairs <= _ONE_BLOCK_PAIRS:
+        return pairs + -pairs % 8, 1
+    return _TRIP_ROWS, -(-held // _TRIP_ROWS)
 
 
 def route_sigmoid_top_k(x: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -218,10 +251,14 @@ def held_experts_ffn(
     absent experts last) and the held ones go through grouped matmuls
     (``lax.ragged_dot``: on a TPU one kernel over the rows of each group, no
     capacity, and a group without rows costs nothing, not even the read of
-    its weights).  One block of pairs (``_ONE_BLOCK_PAIRS``) takes one trip
-    and a token's ``k`` rows are gathered back and summed; more pairs take a
-    quarter a trip, as many trips as the held pairs need, each added into the
-    result where its rows' tokens are.
+    its weights).  Up to one block of pairs (``_ONE_BLOCK_PAIRS``: a decode
+    step) all ``M`` rows take one trip and a token's ``k`` rows are gathered
+    back and summed; of more pairs (a prefill call) the HELD ones take
+    ``_TRIP_ROWS`` rows a trip, as many trips as they need
+    (:func:`dispatch_trips`: a runtime count), each added into the result
+    where its rows' tokens are, in float32: what a trip gathers, multiplies
+    and adds back follows what this chip holds (a 32nd of the pairs where 32
+    chips share a layer), not all pairs.
 
     Returns ``(y [N, D] float32, tokens [n_held] int32)``: the weighted sum,
     and the valid tokens routed to each held expert.
@@ -256,12 +293,13 @@ def held_experts_ffn(
                                   preferred_element_type=jnp.float32)
 
     if M <= _ONE_BLOCK_PAIRS:
+        block, _ = dispatch_trips(M, 0)
         # the TPU's grouped-matmul kernel takes whole sublane tiles of rows
         # (a list of another length, 49 rows x 10, is computed densely:
         # every row against every held expert); rows past the groups' sizes
         # belong to no group
-        rows = order if M % 8 == 0 else jnp.concatenate(
-            [order, jnp.zeros((-M % 8,), order.dtype)])
+        rows = order if block == M else jnp.concatenate(
+            [order, jnp.zeros((block - M,), order.dtype)])
         y = experts_of(rows, tokens)[:M]          # [M, D], sorted by expert
         # pair (n, j) sits at row rank[n * k + j]; a row past the held pairs
         # holds nothing meant to be read, and its gate is 0
@@ -269,8 +307,9 @@ def held_experts_ffn(
         y = jnp.where(held.reshape(N, top_k, 1), y[rank], 0.0)
         return (y * gate_of.reshape(N, top_k, 1)).sum(1), tokens
 
-    block = -(-M // 4)
-    order = jnp.concatenate([order, jnp.zeros((4 * block - M,), order.dtype)])
+    block, trips = dispatch_trips(M, bounds[n_held])
+    if M % block:  # whole trips
+        order = jnp.concatenate([order, jnp.zeros((-M % block,), order.dtype)])
 
     def trip(i, out):
         lo = i * block
@@ -283,6 +322,5 @@ def held_experts_ffn(
         # rows past the held pairs go nowhere
         return out.at[jnp.where(live, pairs // top_k, N)].add(y, mode="drop")
 
-    trips = -(-bounds[n_held] // block)
     return jax.lax.fori_loop(
         0, trips, trip, jnp.zeros((N, D), jnp.float32)), tokens

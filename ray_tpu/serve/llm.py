@@ -97,8 +97,11 @@ def call_rows(bucket: int, n_slots: int) -> int:
 # 16,384) at 1,024 / 2,048 / 4,096 reads ``tpot_p95_ms`` 12.0 / 13.0 / 19.7 for
 # the whole prompts' 30.3, but at 1,024 the parts' own queue grows (260.2
 # tokens/s for the 280.3 offered, the first token's p95 5.2 s for 2.6 s at
-# 2,048 and 1.7 s at 4,096): a part pays ~40 ms of its ~100 for the program's
-# static bound whatever it is wide, so halving it halves nothing.  Kimi-K2's
+# 2,048 and 1.7 s at 4,096): a part costs ~40 ms more than a whole call as
+# wide (since PR 46's expert dispatch 90.7 ms at offset 4,096 against 50.4 for
+# the 2,048 bucket's call; before it 110.3 against 70.1): ~24 for the program's
+# static bound whatever the part is wide, the rest for the cached keys its
+# queries attend, so halving it halves nothing.  Kimi-K2's
 # cell (prompts 256 - 8,192, half of them split) hardly tells the sizes apart:
 # 9.7 - 10.4 / 9.4 - 9.6 / 9.4 for the whole prompts' 9.15 - 9.20, the first
 # token's p95 1.6 - 2.4 s / 0.95 s / 0.7 s: its whole calls were short enough
@@ -405,7 +408,8 @@ class _PendingChunk:
         self.rows = rows                    # [(slot, _Request)] active in chunk
         # this iteration's prefill calls, in dispatch order: (admissions
         # [(row_j, slot, _Request)], first tokens [rows] device, the call's
-        # routing counts: device, None where the family counts nothing).  A
+        # routing counts: device, None where the family counts nothing; the
+        # padded tokens it was wide).  A
         # part that completes no prompt admits nobody, and in the first
         # tokens' place has where its row stands now (read for its landing)
         self.prefills = prefills
@@ -797,7 +801,10 @@ class GenerationEngine:
             # chunks apart (``tokens [layer][held expert]``, ``touched
             # [layer]``: held experts with any token, summed over calls and
             # steps; ``decode_steps``: the chunk steps among them, in
-            # ``decode_dispatches`` chunks, whole or cut).
+            # ``decode_dispatches`` chunks, whole or cut; ``prefill`` also
+            # ``rows_computed``: the rows the calls' grouped matmuls were
+            # handed, summed over the layers, so that ``tokens`` summed over
+            # it is how full the prefill dispatches were).
             # Cumulative, like cache_tiles
             "moe": moe,
             # a family with recurrent layers: the rows of state the decode
@@ -995,11 +1002,11 @@ class GenerationEngine:
             self.cache, slots_dev)
         if first_part:
             _to_host_async((last_logits, routed_dev))
-            return [], last_logits, routed_dev
+            return [], last_logits, routed_dev, n * b
         firsts_dev = self._first_tokens(
             last_logits, slots_dev, routed_dev, [req for _, req in batch])
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
-        return admissions, firsts_dev, routed_dev
+        return admissions, firsts_dev, routed_dev, n * b
 
     def _part_call(self, slot: int, req: _Request, first: int, n: int):
         """Dispatch one PART of ``req``'s prompt, its tokens ``[first, first +
@@ -1028,10 +1035,10 @@ class GenerationEngine:
         if first + n < len(req.tokens):
             stands_dev.copy_to_host_async()
             _to_host_async(routed_dev)
-            return [], stands_dev, routed_dev
+            return [], stands_dev, routed_dev, self._part
         firsts_dev = self._first_tokens(
             last_logits, slots_dev, routed_dev, [req])
-        return [(0, slot, req)], firsts_dev, routed_dev
+        return [(0, slot, req)], firsts_dev, routed_dev, self._part
 
     def step(self) -> bool:
         """One engine iteration, software-pipelined against the device:
@@ -1138,13 +1145,26 @@ class GenerationEngine:
                 time.perf_counter() - t_dispatched - waited)
         return worked
 
-    def _count_routed(self, phase: str, counts, steps: int = 0) -> None:
-        """Add one landed dispatch's routing counts (a prefill call's, or a
-        chunk's with the ``steps`` it ran; None where the family counts
-        nothing) to the totals."""
+    def _count_routed(self, phase: str, counts, steps: int = 0,
+                      padded: int = 0) -> None:
+        """Add one landed dispatch's routing counts (a prefill call's with the
+        ``padded`` tokens it was wide, or a chunk's with the ``steps`` it ran;
+        None where the family counts nothing) to the totals.  A prefill call
+        also counts the rows its grouped matmuls were handed
+        (``rows_computed``; the held pairs, ``tokens``, over it is how full
+        the dispatch was): host arithmetic over what the call returned, by the
+        function the device's loop takes its trips from."""
         if counts is None:
             return
         counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
+        if padded:
+            from ray_tpu.ops.moe import dispatch_trips
+
+            held = counts["tokens"].sum(-1)  # a sparse layer each
+            block, trips = dispatch_trips(
+                padded * self.cfg.experts_per_token, held)
+            counts["rows_computed"] = np.broadcast_to(
+                block * trips, held.shape).sum()
         with self._lock:
             had = self._routed[phase]
             self._routed[phase] = counts if had is None else {
@@ -1188,7 +1208,7 @@ class GenerationEngine:
         waited = 0.0
         if meter is not None:
             meter.begin(pending.chained)
-        for admissions, firsts_dev, routed_dev in pending.prefills:
+        for admissions, firsts_dev, routed_dev, padded in pending.prefills:
             firsts, landed, blocked = self._read_back(firsts_dev)
             waited += blocked
             if meter is not None:
@@ -1210,7 +1230,7 @@ class GenerationEngine:
                 # after the stamps: the stream thread reads them the
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
-            self._count_routed("prefill", routed_dev)
+            self._count_routed("prefill", routed_dev, padded=padded)
         if pending.chunk_dev is None:  # a tick of parts alone: no chunk
             if meter is not None:
                 meter.chunk_landed(landed, 0, 0)
@@ -1220,7 +1240,7 @@ class GenerationEngine:
         waited += blocked
         if meter is not None:
             meter.chunk_landed(
-                landed, sum(len(adm) for adm, _, _ in pending.prefills),
+                landed, sum(len(adm) for adm, *_ in pending.prefills),
                 len(pending.rows))
         self._count_routed("decode", pending.routed_dev, pending.steps)
         if self._state is not None:

@@ -168,28 +168,157 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
 
 
-@pytest.mark.parametrize("one_block", [True, False], ids=["one_trip", "trips"])
+def _to_one_expert(n_tokens, valid=None):
+    """Every token's first choice is held expert 0, its other three absent
+    (experts 6..8 of 9, of which 0..5 are held)."""
+    experts = np.tile(np.array([0, 6, 7, 8], np.int32), (n_tokens, 1))
+    return dict(experts=experts, valid=np.arange(n_tokens) < (
+        n_tokens if valid is None else valid))
+
+
+def _a_32nd_held():
+    """128 tokens x top-4 over 192 experts of which 6 are held: a 32nd of the
+    pairs, one trip of 64 rows with a quarter of it live."""
+    rng = np.random.RandomState(46)
+    experts = np.stack([rng.choice(192, 4, replace=False) for _ in range(128)])
+    return dict(experts=experts.astype(np.int32))
+
+
+# (token, expert) pairs through ``held_experts_ffn``: the routing, the rows of
+# a trip and the pairs of one block (None: the module's), and the trips the
+# dispatch has to take
+DISPATCHES = {
+    # 64 tokens, each choosing experts 0..3, all four held, some rows no token
+    "one_trip": dict(
+        experts=np.tile(np.arange(4, dtype=np.int32), (64, 1)),
+        valid=np.arange(64) % 7 != 0, trips=1),
+    "trips": dict(
+        experts=np.tile(np.arange(4, dtype=np.int32), (64, 1)),
+        valid=np.arange(64) % 7 != 0, one_block=100, trip_rows=64, trips=4),
+    # one trip, an eighth full when a trip was a quarter of ALL pairs
+    "a_32nd_of_the_pairs_held": dict(
+        **_a_32nd_held(), one_block=100, trip_rows=64, trips=1),
+    # several trips, the last one ragged: 150 pairs of one expert, 64 a trip
+    "all_to_one_held_expert": dict(
+        **_to_one_expert(150), one_block=100, trip_rows=64, trips=3),
+    "pairs_exactly_a_block": dict(
+        **_to_one_expert(80, valid=64), one_block=100, trip_rows=64, trips=1),
+    "pairs_one_under_a_block": dict(
+        **_to_one_expert(80, valid=63), one_block=100, trip_rows=64, trips=1),
+    "pairs_one_over_a_block": dict(
+        **_to_one_expert(80, valid=65), one_block=100, trip_rows=64, trips=2),
+    # the experts of four layers stacked, the third's used
+    "stacked_weights_one_layer_used": dict(
+        **_a_32nd_held(), one_block=100, trip_rows=64, trips=1, layer=2),
+    "stacked_weights_several_trips": dict(
+        **_to_one_expert(150), one_block=100, trip_rows=64, trips=3, layer=1),
+    # 100 tokens x held experts (0, 1): token n's rows are n and 100 + n of
+    # the sorted list, in trips 0 - 1 and 1 - 3: its sum crosses trips
+    "a_tokens_two_experts_in_different_trips": dict(
+        experts=np.tile(np.array([0, 1, 7, 8], np.int32), (100, 1)),
+        one_block=100, trip_rows=64, trips=4),
+    # a pair list that is no multiple of 8 rows (a decode step's 49 x 10)
+    "one_block_filled_to_whole_tiles": dict(
+        experts=np.tile(np.array([1, 3, 8], np.int32), (13, 1)), trips=1),
+}
+
+
+@pytest.mark.parametrize("case", list(DISPATCHES))
 def test_no_token_is_dropped_when_every_token_takes_the_same_experts(
-        monkeypatch, one_block):
-    """All-to-one routing: 64 tokens, each choosing experts 0..3, all four
-    held.  Every (token, expert) pair is computed, through one block of
-    pairs or (a block made small) through several trips."""
-    if not one_block:
-        monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", 100)
-    N, D, F, E = 64, 16, 8, 6
-    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+        monkeypatch, case):
+    """Every held (token, expert) pair is computed, whatever the routing puts
+    into a trip: against the dense sum, an expert at a time.  Through one
+    block of pairs, or (the block and the trip made small) through as many
+    trips as the HELD pairs need, which is what ``dispatch_trips`` says and
+    what the device's loop is handed."""
+    want = dict(DISPATCHES[case])
+    if "one_block" in want:
+        monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", want["one_block"])
+        monkeypatch.setattr(moe, "_TRIP_ROWS", want["trip_rows"])
+    experts = jnp.asarray(want["experts"])
+    N, top_k = experts.shape
+    D, F, E, layer = 16, 8, 6, want.get("layer")
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
     x = jax.random.normal(keys[0], (N, D))
-    w_gate, w_up = (jax.random.normal(k, (E, D, F)) / 4 for k in keys[1:3])
-    w_down = jax.random.normal(keys[3], (E, F, D)) / 3
-    experts = jnp.tile(jnp.arange(4, dtype=jnp.int32), (N, 1))
-    gates = jnp.full((N, 4), 0.25)
-    valid = jnp.arange(N) % 7 != 0  # and some rows hold no token
+    stack = () if layer is None else (4,)
+    w_gate, w_up = (jax.random.normal(k, (*stack, E, D, F)) / 4 for k in keys[1:3])
+    w_down = jax.random.normal(keys[3], (*stack, E, F, D)) / 3
+    gates = jax.random.uniform(keys[4], (N, top_k), minval=0.1)
+    valid = jnp.asarray(want.get("valid", np.ones(N, bool)))
+    ran = []
+    loop = jax.lax.fori_loop
+    monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, *a: (
+        ran.append(int(hi)), loop(lo, hi, *a))[1])
     y, tokens = moe.held_experts_ffn(
-        x, experts, gates, w_gate, w_up, w_down, valid=valid)
-    assert tokens.tolist() == [int(valid.sum())] * 4 + [0, 0]
-    want = sum(0.25 * (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
-               for e in range(4)) * valid[:, None]
-    assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-5
+        x, experts, gates, w_gate, w_up, w_down, valid=valid,
+        layer=None if layer is None else jnp.int32(layer))
+    if layer is not None:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
+    took = np.asarray((experts[:, :, None] == jnp.arange(E)) & valid[:, None, None])
+    assert tokens.tolist() == took.sum((0, 1)).tolist()
+    dense = sum(
+        (took[:, :, e] * gates).sum(1)[:, None]
+        * ((jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e])
+        for e in range(E))
+    assert np.abs(np.asarray(y) - np.asarray(dense)).max() < 1e-5
+    block, trips = moe.dispatch_trips(N * top_k, int(took.sum()))
+    assert trips == want["trips"]
+    if N * top_k > moe._ONE_BLOCK_PAIRS:
+        assert ran == [trips] and block == moe._TRIP_ROWS
+    else:
+        assert ran == [] and block == N * top_k + -(N * top_k) % 8
+
+
+def _eqns(jaxpr, name):
+    """Every equation called ``name`` in ``jaxpr`` and the jaxprs inside it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("n,top_k,rows,loops", [
+    # a 2,048-token prefill call with top-8: a trip's rows, not M / 4 = 4,096
+    (2048, 8, 256, 1),
+    # the narrowest prefill calls (256 tokens: top-8, and Granite's top-10)
+    (256, 8, 256, 1), (256, 10, 256, 1),
+    # decode steps (33 rows x 8, 49 x 10): one block of M rows filled to
+    # whole sublane tiles, no loop, no scatter
+    (33, 8, 264, 0), (49, 10, 496, 0), (128, 8, 1024, 0),
+])
+def test_the_grouped_matmuls_are_handed_a_trips_rows(n, top_k, rows, loops):
+    """What lowers: above one block of pairs (every prefill call) the three
+    ``ragged_dot`` calls sit in the loop over trips and take ``_TRIP_ROWS``
+    rows each, whatever ``M`` is; up to one block (a decode step) they take
+    the ``M`` pairs at once and a token's rows are gathered back."""
+    assert (moe._ONE_BLOCK_PAIRS, moe._TRIP_ROWS) == (1024, 256)
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((n, 16), f32), ((n, top_k), i32), ((n, top_k), f32),
+        ((4, 16, 8), f32), ((4, 16, 8), f32), ((4, 8, 16), f32))]
+    jaxpr = jax.make_jaxpr(moe.held_experts_ffn)(*shapes).jaxpr
+    dots = _eqns(jaxpr, "ragged_dot") or _eqns(jaxpr, "ragged_dot_general")
+    assert [eqn.invars[0].aval.shape[0] for eqn in dots] == [rows] * 3
+    assert len(_eqns(jaxpr, "while")) == loops
+    assert len(_eqns(jaxpr, "scatter-add")) == loops
+    assert moe.dispatch_trips(n * top_k, 0)[0] == rows
+
+
+@pytest.mark.parametrize("pairs,held,want", [
+    (264, 7, (264, 1)), (490, 490, (496, 1)), (1024, 0, (1024, 1)),
+    (1025, 0, (256, 0)), (16384, 512, (256, 2)), (16384, 256, (256, 1)),
+    (16384, 257, (256, 2)), (20480, 2560, (256, 10)),
+])
+def test_dispatch_trips(pairs, held, want):
+    assert moe.dispatch_trips(pairs, held) == want
+    # a layer each, as the engine's counter asks; and a traced count
+    block, trips = moe.dispatch_trips(pairs, np.array([held, held]))
+    assert np.broadcast_to(block * trips, (2,)).tolist() == [want[0] * want[1]] * 2
+    assert int(jax.jit(lambda h: jnp.asarray(
+        moe.dispatch_trips(pairs, h)[1]))(held)) == want[1]
 
 
 @pytest.mark.parametrize("t,window", [(256, 128), (384, 100), (48, 8), (128, 128)])
@@ -221,7 +350,7 @@ def _ticks(eng, futs):
         eng.step()
         if before - eng.stats()["queued"]:
             ticks.append([[len(req.tokens) for _, _, req in admissions]
-                          for admissions, _, _ in eng._pending.prefills])
+                          for admissions, *_ in eng._pending.prefills])
         if eng._pending is not None and eng._pending is not was:
             steps.append(eng._pending.steps)
     return ticks, steps
@@ -278,17 +407,26 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     queue's head is formed into calls greedily and in order, as many a tick
     as the free slots and the tick's budget of padded tokens allow.  The
     engine's answers are the one-shot path's whatever call a prompt rode
-    in, and its counters say exactly what was dispatched."""
+    in, and its counters say exactly what was dispatched.  The calls sit on
+    both sides of one block of (token, expert) pairs (made 64 here: buckets 8
+    x 2 rows and 16 are 64 pairs, one block of 64 rows a layer whatever is
+    held; bucket 32 is 128 pairs, trips of 16 rows as its held pairs need)."""
     from ray_tpu.serve import llm
 
     cfg, params = model
     want = ADMISSIONS[case]
     monkeypatch.setattr(llm, "CALL_TOKENS", 16)
+    monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", 64)
+    monkeypatch.setattr(moe, "_TRIP_ROWS", 16)
     eng = GenerationEngine(  # never started: the test is the engine thread
         cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
         prefill_buckets=(8, 16, 32), prefill_token_budget=want["budget"])
     assert eng._rows == {8: 2, 16: 1, 32: 1}
     assert eng._tick_tokens == (want["budget"] or 4 * 32)
+    landed, count = [], eng._count_routed
+    monkeypatch.setattr(eng, "_count_routed", lambda phase, counts, *a, **kw: (
+        landed.append((phase, counts, kw.get("padded"))),
+        count(phase, counts, *a, **kw))[1])
     rng = np.random.RandomState(7)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in want["lens"]]
     futs = [eng.submit(p, 6) for p in prompts]
@@ -321,6 +459,20 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     # only real prompt tokens were routed: 4 choices each, over 16 experts
     assert np.asarray(routed["prefill"]["tokens"]).sum(1).max() <= 4 * sum(
         len(p) for p in prompts)
+    # ``rows_computed``: the rows the landed prefill calls' grouped matmuls
+    # were handed, a sparse layer each (``tokens`` over it is the fill): what
+    # ``dispatch_trips``, which the device's loop asks too, makes of the held
+    # pairs a call returned and the padded tokens it was wide
+    calls = [(np.asarray(c["tokens"]).sum(-1), padded)
+             for phase, c, padded in landed if phase == "prefill"]
+    assert sorted(padded for _, padded in calls) == sorted(
+        max(b, 16) for b, tally in want["prefill"].items()
+        for _ in range(tally["calls"]))
+    assert routed["prefill"]["rows_computed"] == sum(
+        4 * 64 if padded == 16 else int((-(-held // 16) * 16).sum())
+        for held, padded in calls)
+    assert np.sum(routed["prefill"]["tokens"]) <= routed["prefill"]["rows_computed"]
+    assert "rows_computed" not in routed["decode"]
 
 
 @pytest.mark.parametrize("n_slots,buckets,rows", [

@@ -179,7 +179,7 @@ def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
         eng.step()
         if before - eng.stats()["queued"]:
             ticks.append([[len(req.tokens) for _, _, req in admissions]
-                          for admissions, _, _ in eng._pending.prefills])
+                          for admissions, *_ in eng._pending.prefills])
     assert ticks == [[[3, 5], [12], [4]], [[9], [10], [2, 6]],
                      [[7, 1], [11], [13]], [[8, 8], [8, 8]]]
     assert eng.perf_stats()["prefill"] == {
@@ -187,6 +187,8 @@ def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
               "live_tokens": 60},
         "16": {"calls": 5, "rows": 5, "padded_tokens": 80, "prompts": 5,
                "live_tokens": 55}}
+    # a dense family routes nothing: no routing counts, no ``rows_computed``
+    assert eng.perf_stats()["moe"]["prefill"] is None
     for p, f in zip(prompts, futs):
         assert f.result() == _one_shot(params, cfg, p, 6)
 
