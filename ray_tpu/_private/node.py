@@ -1147,6 +1147,14 @@ class Node:
                     msg = conn.recv()
                 except (EOFError, OSError, pickle.UnpicklingError):
                     break
+                except TypeError:
+                    # closed under this loop (a node declared dead, shutdown)
+                    # with a frame on its way: the header came from the
+                    # descriptor the blocked read held, the body is asked of a
+                    # handle that is None by now.  The same end as EOF
+                    if not conn.closed:
+                        raise
+                    break
                 mtype = msg["type"]
                 if mtype == "register_worker":
                     handle = self._on_register_worker(conn, msg)

@@ -462,12 +462,16 @@ def _rule_slice_degraded(events, tasks):
     degraded`` when a member dies unexpectedly (deliberate scale-downs
     mark the slice draining first and stay silent); the autoscaler emits
     ``slice replacement started`` / ``replaced`` / ``failed`` as it
-    heals.  A degraded slice whose LAST degradation has no completed
-    replacement at or after it — and no replacement in flight (a
-    ``started`` not superseded by a later ``failed``) — is an open
-    incident; a FAILED replacement re-opens it (the slice is still
-    degraded; suppressing on 'started' alone would keep doctor silent
-    forever under e.g. persistent quota exhaustion)."""
+    heals.  A degraded slice that was never replaced, with no replacement
+    in flight at or after its LAST degradation (a ``started`` not
+    superseded by a later ``failed``), is an open incident; a FAILED
+    replacement re-opens it (the slice is still degraded; suppressing on
+    'started' alone would keep doctor silent forever under e.g.
+    persistent quota exhaustion).  A slice that ``slice replaced`` names
+    is gone, whole, and cannot degrade: a member's death that is
+    reported after that (on a loaded head the old members' deaths land
+    late, and one that re-registered in between dies unmarked) re-opens
+    nothing."""
     degraded = _rows(events, "node", "slice degraded")
     if not degraded:
         return None
@@ -491,9 +495,9 @@ def _rule_slice_degraded(events, tasks):
             last_degraded[sid] = r
 
     def _open(sid, row):
+        if sid in replaced:
+            return False  # repair landed: the slice no longer exists
         ts = float(row.get("ts") or 0.0)
-        if replaced.get(sid, -1.0) >= ts:
-            return False  # repair landed
         in_flight = (started.get(sid, -1.0) >= ts
                      and failed.get(sid, -1.0) < started.get(sid, -1.0))
         return not in_flight

@@ -76,3 +76,51 @@ def ray_start_cluster():
     cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 2, "num_tpus": 0})
     yield cluster
     cluster.shutdown()
+
+
+# -- the served-model tests' fixtures (tests/family_harness.py) ---------------
+
+@pytest.fixture
+def lowered_for_tpu(monkeypatch):
+    """``lax.platform_dependent`` takes its ``tpu`` branch, and Pallas calls
+    run in the TPU interpreter: the decode program a chip would run, here.
+    The harness keeps the programs traced inside it apart from the CPU's
+    (``family_harness.PATH``)."""
+    import family_harness
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.models import generate as gen
+
+    monkeypatch.setattr(
+        gen.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))
+    monkeypatch.setattr(family_harness, "PATH", "lowered_for_tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture(params=["cpu", "lowered_for_tpu"])
+def positions(request):
+    """A chunk case runs twice: as the CPU runs it (masked einsums over the
+    slab, a slice update a slot) and as a chip does (the ragged read and the
+    flush kernel, through ``lowered_for_tpu``).  Gives the cache length a
+    case asks for as the path needs it: the kernels are chosen for a cache of
+    whole 128-position tiles, as the engine's always is."""
+    if request.param == "cpu":
+        return lambda n: n
+    request.getfixturevalue("lowered_for_tpu")
+    return lambda n: -(-n // 128) * 128
+
+
+@pytest.fixture
+def kept_engine_programs(monkeypatch):
+    """Every ``GenerationEngine`` built under this fixture takes its jitted
+    programs from one memo over ``llm.engine_programs`` (a function of the
+    config and its arguments alone), so that engines of one config and
+    arguments compile once a worker and not once a case.  The served-model
+    files ask for it module-wide (``pytestmark``)."""
+    import family_harness
+
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(llm, "engine_programs", family_harness.kept_programs())

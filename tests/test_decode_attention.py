@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import family_harness
+from family_harness import LONG, Slots
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.models import generate as gen
-from test_generate import _Slots, lowered_for_tpu  # noqa: F401 (a fixture)
 
 attention = importlib.import_module("ray_tpu.ops.attention")
 
@@ -154,7 +155,7 @@ def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
     ``ragged_latent_decode_attention``.  Every chunk ends in the flush
     kernel, which leaves the rows of the slots that sat it out as they
     were."""
-    eng = _Slots(family, 5, 256, max_seq_len=256)
+    eng = Slots(family, 256)
     rng = np.random.default_rng(0)
     eng.admit(0, [int(t) for t in rng.integers(1, 200, size=123)], 128)
     eng.admit(2, [9, 4, 7, 2, 5], 8)   # one tile, mostly masked
@@ -166,19 +167,20 @@ def test_decode_chunk_through_the_kernel(family, lowered_for_tpu):
     eng.admit(3, [int(t) for t in rng.integers(1, 200, size=128)], 128)
     eng.decode(8)                      # slot 3 starts on the boundary
     eng.decode(8)
-    for slot, n_new in ((0, 25), (2, 25), (3, 17)):
-        eng.assert_greedy(slot, n_new)
+    eng.assert_greedy({0: 25, 2: 25, 3: 17})
     assert [int(p) for p in eng.cache["pos"]] == [147, 0, 29, 144, 128]
 
 
 def test_cache_that_is_not_whole_tiles_takes_the_slab(monkeypatch):
-    """The kernel is chosen by the cache's shape: a cache of 160 positions
+    """The kernel is chosen by the cache's shape: a cache of 161 positions
     never reaches ``platform_dependent``."""
     def refuse(*args, **kw):
-        raise AssertionError("a 160-position cache must take the slab")
+        raise AssertionError("a 161-position cache must take the slab")
 
     monkeypatch.setattr(gen.lax, "platform_dependent", refuse)
-    eng = _Slots("gpt2", 2, 160, max_seq_len=160)
+    # a path of its own: its programs are traced here, under the refusal
+    monkeypatch.setattr(family_harness, "PATH", "platform_dependent_refused")
+    eng = Slots("gpt2", LONG)
     eng.admit(0, [3, 17, 5], 8)
     eng.decode(4)
-    eng.assert_greedy(0, 5)
+    eng.assert_greedy({0: 5})

@@ -7,54 +7,63 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_generate import (  # noqa: F401 (two fixtures)
-    _model,
-    _Slots,
-    lowered_for_tpu,
-    positions,
+from family_harness import (
+    LONG,
+    LONG_CHUNK,
+    TINY,
+    Slots,
+    engine,
+    one_shot,
+    run_engine,
+    tiny_model,
 )
 
 from ray_tpu.models import generate as gen
 
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
+
+
+STEPS = 8
+
 
 @pytest.mark.parametrize("family", ["gpt2", "exaone_moe", "kimi_k2"])
-@pytest.mark.parametrize("n", [1, 7, 15])
+@pytest.mark.parametrize("n", [1, 3, 7])
 def test_cut_chunk_then_the_rest_equals_one_whole_chunk(family, n, positions):
-    """A chunk of 16 cut to ``n`` steps, then the ``16 - n`` that complete
-    it, against one whole chunk: the same tokens, the same ``pos``, the same
+    """A chunk of 8 cut to ``n`` steps, then the ``8 - n`` that complete it,
+    against one whole chunk: the same tokens, the same ``pos``, the same
     cache below ``pos`` (the columns a cut chunk flushes beyond its ``n`` lie
     past ``pos`` and the next flush overwrites them), and the same next
     chunk, which attends all of it.  One slot's columns cross position 128
-    (cut at 7 its real columns end short of the boundary, so the flush lists
+    (cut at 3 its real columns end short of the boundary, so the flush lists
     one tile; the rest of the chunk then crosses), one stands where its
-    ring of 32 wraps, one is short, one idle."""
-    kw = {"sliding_window": 16} if family == "exaone_moe" else {}
+    ring of 16 wraps, one is short, one idle.  (8 steps and not the engine's
+    16: a step in the TPU interpreter is a third of a second, and the cut is
+    the same loop whatever the chunk's length.)"""
     prompts = {0: [3, 17, 5], 1: [1 + i % 50 for i in range(121)],
                3: [2 + i % 40 for i in range(60)]}
 
     def run(cuts):
-        eng = _Slots(family, 5, positions(128 + 1 + 32), max_seq_len=256, **kw)
+        eng = Slots(family, positions(LONG))
         for slot, prompt in prompts.items():
             eng.admit(slot, prompt, 128)
         for cut in cuts:
-            eng.decode(16, n=cut)
+            eng.decode(STEPS, n=cut)
         return eng
 
-    whole, cut = run([None]), run([n, 16 - n])
+    whole, cut = run([None]), run([n, STEPS - n])
     assert cut.out == whole.out
     pos = [int(p) for p in whole.cache["pos"]]
     assert [int(p) for p in cut.cache["pos"]] == pos == [
-        3 + 16, 121 + 16, 0, 60 + 16, 128]
+        3 + STEPS, 121 + STEPS, 0, 60 + STEPS, 128]
     for name in gen.cached_tensors(whole.cfg):
         for slot in prompts:
             np.testing.assert_allclose(
                 cut.cache[name][:, slot, ..., :pos[slot]],
                 whole.cache[name][:, slot, ..., :pos[slot]], rtol=1e-4, atol=1e-5)
     for eng in (whole, cut):
-        eng.decode(16)
+        eng.decode(STEPS)
     assert cut.out == whole.out
-    for slot in prompts:
-        cut.assert_greedy(slot, 33)
+    cut.assert_greedy(dict.fromkeys(prompts, 1 + 2 * STEPS))
 
 
 @pytest.mark.parametrize("family", ["gpt2", "exaone_moe", "kimi_k2"])
@@ -64,7 +73,7 @@ def test_whole_chunk_stays_a_scan_and_the_cut_is_one_loop(family):
     (the benchmark reads its device time as that of ``steps`` steps).  The
     cut form is one ``while`` over the same step: its body holds every
     operation of the scan's."""
-    cfg, params, _ = _model(family)
+    cfg, params = tiny_model(family)
     cache = gen.init_cache(cfg, 3, 64)
     args = (params, cache, jnp.zeros((3,), jnp.int32), jnp.ones((3,), bool),
             jax.random.PRNGKey(0))
@@ -90,52 +99,17 @@ def test_whole_chunk_stays_a_scan_and_the_cut_is_one_loop(family):
 # ---------------------------------------------------------------------------
 # the engine: never started, the test is its thread
 
-FAMILY_KW = {"gpt2": {},
-             # window 16: the one-shot reference decodes an answer in ONE chunk
-             "exaone_moe": {"experts_held": (4, 8), "sliding_window": 16},
-             "kimi_k2": {"experts_held": (4, 8)}}
-
-
 def _engine(family, **kw):
-    from ray_tpu.serve.llm import GenerationEngine
-
-    mod = gen.FAMILIES[family]
-    cfg = mod.Config.tiny(dtype=jnp.float32, max_seq_len=256,
-                          **FAMILY_KW[family])
-    params = mod.init(cfg, jax.random.PRNGKey(3))
-    kw = {"n_slots": 2, "max_new_tokens": 40, "prefill_buckets": (8, 128), **kw}
-    return GenerationEngine(cfg, params, **kw), cfg, params
+    """Two slots, buckets of 8 and 128, answers of up to 40 tokens; window 16
+    where a family has one: the one-shot reference decodes an answer in ONE
+    chunk."""
+    return engine(family, seed=3, changed=LONG_CHUNK.get(family, {}), **{
+        "n_slots": 2, "max_new_tokens": 40, "prefill_buckets": (8, 128), **kw})
 
 
-def _one_shot(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray([prompt]),
-                       jnp.asarray([len(prompt)]), max_new_tokens=n)
-    return [int(t) for t in out[0]]
-
-
-def _run(eng, futs):
-    """Step the engine until ``futs`` are done: per tick that dispatched a
-    chunk, the steps it ran and what the counters and the dispatched
-    requests' ``scheduled`` moved by."""
-    seen = []
-    for _ in range(200):
-        if all(f.done() for f in futs):
-            return seen
-        before, was = eng.perf_stats(), eng._pending
-        live = {id(r): (r, r.scheduled) for r in eng._slots if r is not None}
-        eng.step()
-        after, now = eng.perf_stats(), eng._pending
-        if now is not None and now is not was:
-            seen.append({
-                "steps": now.steps,
-                "flushed": (after["cache_tiles"]["flushed"]
-                            - before["cache_tiles"]["flushed"]),
-                # of the rows that were live before the tick (one admitted in
-                # it starts at its prefill's 1)
-                "scheduled": sorted(
-                    r.scheduled - had for r, had in live.values()),
-                "done": [f.done() for f in futs]})
-    raise AssertionError("the engine did not finish")
+def _chunks(eng, futs):
+    """The ticks of ``run_engine`` that dispatched a chunk."""
+    return [s for s in run_engine(eng, futs) if s["steps"] is not None]
 
 
 def test_an_answer_of_18_tokens_pays_17_steps_and_lands_with_its_cut():
@@ -144,11 +118,11 @@ def test_an_answer_of_18_tokens_pays_17_steps_and_lands_with_its_cut():
     and the meter counts the steps the request's chunks really ran."""
     eng, cfg, params = _engine("gpt2", decode_chunk_steps=16)
     fut = eng.submit([3, 5, 7], 18)
-    seen = _run(eng, [fut])
+    seen = _chunks(eng, [fut])
     assert [s["steps"] for s in seen] == [16, 1]
     # the cut chunk was dispatched in the second tick and drained in the
     # third: the answer was whole the moment it landed
-    assert fut.result(timeout=1) == _one_shot(params, cfg, [3, 5, 7], 18)
+    assert [fut.result(timeout=1)] == one_shot(params, cfg, [[3, 5, 7]], 18)
     decode = eng.perf_stats()["decode"]
     assert (decode["requests"], decode["gaps"], decode["chunk_steps_paid"]) == (
         1, 17, 17)
@@ -166,18 +140,18 @@ def test_two_requests_ending_apart_cut_twice_and_the_counters_follow(family):
     # stays inside the tile, where a whole chunk's four would cross 128
     prompts = [[1 + i % 50 for i in range(122)], [9, 4, 7]]
     futs = [eng.submit(p, n) for p, n in zip(prompts, (6, 11))]
-    seen = _run(eng, futs)
+    seen = _chunks(eng, futs)
     assert [s["steps"] for s in seen] == [4, 1, 4, 1]
     assert [s["scheduled"] for s in seen] == [[], [1, 1], [4], [1]]
     # 122 + 1 - 1 and 3: one tile each; 126 % 128 > 128 - 1 is false
     assert [s["flushed"] for s in seen] == [2, 2, 1, 1]
     assert [s["done"] for s in seen] == [
         [False, False], [False, False], [True, False], [True, False]]
-    for p, n, f in zip(prompts, (6, 11), futs):
-        assert f.result(timeout=1) == _one_shot(params, cfg, p, n)
+    assert [f.result(timeout=1) for f in futs] == one_shot(
+        params, cfg, prompts, (6, 11))
     stats = eng.perf_stats()
     assert stats["decode"]["chunk_steps_paid"] == stats["decode"]["gaps"] == 15
-    if FAMILY_KW[family]:  # a family whose layers count what they routed
+    if "experts_held" in TINY[family]:  # its layers count what they routed
         assert stats["moe"]["decode_steps"] == 4 + 1 + 4 + 1
         tokens = np.asarray(stats["moe"]["decode"]["tokens"])
         # only live rows' steps were routed: 5 + 10 tokens, top-4 of 16
@@ -193,17 +167,17 @@ def test_one_cut_program_serves_every_bound_and_one_token_needs_none():
 
     eng, cfg, params = _engine("gpt2", decode_chunk_steps=8, n_slots=3)
     first = eng.submit([5, 9, 2], 1)
-    assert [s["steps"] for s in _run(eng, [first])] == [8]  # today's vehicle
-    assert first.result(timeout=1) == _one_shot(params, cfg, [5, 9, 2], 1)
-    _run(eng, [eng.submit([5, 9, 2], 10)])  # a whole chunk and a cut of 1
+    assert [s["steps"] for s in _chunks(eng, [first])] == [8]  # today's vehicle
+    assert [first.result(timeout=1)] == one_shot(params, cfg, [[5, 9, 2]], 1)
+    _chunks(eng, [eng.submit([5, 9, 2], 10)])  # a whole chunk and a cut of 1
     built = compile_cache.counts()["count"]
     lengths = [12, 3, 9, 1, 7, 11, 2, 5, 10, 4, 8, 6]
     prompts = [[3 + i, 17, 5][:1 + i % 3] for i in range(12)]
     futs = [eng.submit(p, n) for p, n in zip(prompts, lengths)]
-    steps = [s["steps"] for s in _run(eng, futs)]
+    steps = [s["steps"] for s in _chunks(eng, futs)]
     assert len(set(steps)) >= 5, steps
     assert compile_cache.counts()["count"] == built
-    for p, n, f in zip(prompts, lengths, futs):
-        assert f.result(timeout=1) == _one_shot(params, cfg, p, n)
+    assert [f.result(timeout=1) for f in futs] == one_shot(
+        params, cfg, prompts, lengths)
     decode = eng.perf_stats()["decode"]
     assert decode["chunk_steps_paid"] == decode["gaps"]

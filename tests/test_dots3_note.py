@@ -13,6 +13,7 @@ selected SETS are compared exactly.
 
 import dataclasses
 import importlib
+from functools import partial
 import os
 import sys
 
@@ -20,6 +21,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_harness import (
+    TINY,
+    engine,
+    one_shot,
+    padded,
+    run_engine,
+    serve,
+    shares_add_up,
+    sigmoid_top_k_by_hand,
+    tiny_model,
+    worst_gap,
+)
 from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -28,10 +41,11 @@ from benchmark.reference import dots3_note_ref as ref  # noqa: E402
 from ray_tpu.models import dots3_note as dn  # noqa: E402
 from ray_tpu.models import generate as gen  # noqa: E402
 from ray_tpu.ops import dsa, moe  # noqa: E402
-from ray_tpu.serve.llm import GenerationEngine, make_config  # noqa: E402
+from ray_tpu.serve.llm import make_config  # noqa: E402
 
 attention = importlib.import_module("ray_tpu.ops.attention")
 
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
 F32_TOL = 2e-4
 
 
@@ -55,13 +69,19 @@ def model():
     # 5 layers as the cell's: full + dense, full + sparse, three sliding +
     # sparse; 4 | 2 heads, rows of 16 + 8 and 24 + 8 values, index keys of 16,
     # top-8 positions, window 5, 16 experts of which 4..11 are held, top-4
-    cfg = dn.Dots3NoteConfig.tiny(dtype=jnp.float32, experts_held=(4, 8))
-    return cfg, dn.init(cfg, jax.random.PRNGKey(0))
+    return tiny_model("dots3_note")
+
+
+# the five unrolled layers cost more op by op than compiled
+_block = jax.jit(dn.block, static_argnums=2,
+                 static_argnames=("window", "absorbed"))
+_apply = jax.jit(dn.apply, static_argnums=2, static_argnames=("absorbed",))
 
 
 def ref_logits(model, seq, **changed):
     cfg, params = model
-    return ref.logits(params, jnp.asarray([seq]), sizes_of(cfg, **changed))[0]
+    return ref.logits(params, jnp.asarray([padded(seq)]),
+                      sizes_of(cfg, **changed))[0][:len(seq)]
 
 
 def test_config_is_the_published_one_and_says_what_it_caches():
@@ -108,7 +128,7 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(layer), (1, 41, cfg.d_model))
     p = params["layers"][layer]
-    got, routed, _ = dn.block(x, p, cfg, window=cfg.sliding_windows[layer])
+    got, routed, _ = _block(x, p, cfg, window=cfg.sliding_windows[layer])
     with jax.default_matmul_precision("highest"):
         want = ref._layer(x, p, **ref.layer_statics(sizes_of(cfg), layer))
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
@@ -119,7 +139,7 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
 def test_forward_against_the_reference(model, absorbed):
     cfg, params = model
     seq = list(np.random.RandomState(1).randint(0, cfg.vocab_size, 40))
-    got = np.asarray(dn.apply(params, jnp.asarray([seq]), cfg, absorbed=absorbed)[0])
+    got = np.asarray(_apply(params, jnp.asarray([seq]), cfg, absorbed=absorbed)[0])
     assert np.abs(got - ref_logits(model, seq)).max() < F32_TOL
     # and the mechanisms bite: a reference that selects nothing, or whose
     # window is one position wider, is another model
@@ -134,10 +154,10 @@ def test_absorbed_against_unabsorbed(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 33, cfg.d_model))
     p, w = params["layers"][layer], cfg.sliding_windows[layer]
-    plain, _, _ = dn.block(x, p, cfg, window=w)
-    folded, _, _ = dn.block(x, p, cfg, window=w, absorbed=True)
+    plain, _, _ = _block(x, p, cfg, window=w)
+    folded, _, _ = _block(x, p, cfg, window=w, absorbed=True)
     assert np.abs(np.asarray(plain) - np.asarray(folded)).max() < F32_TOL
-    no_rescale, _, _ = dn.block(
+    no_rescale, _, _ = _block(
         x, p, dataclasses.replace(cfg, lora_rescale=False), window=w)
     assert np.abs(np.asarray(plain) - np.asarray(no_rescale)).max() > 1e-2
 
@@ -175,7 +195,7 @@ def test_contexts_up_to_index_topk_are_dense_latent_attention(model):
     cfg = dn.Dots3NoteConfig.tiny(dtype=jnp.float32, experts_held=(4, 8),
                                   index_topk=40)
     seq = list(np.random.RandomState(2).randint(0, cfg.vocab_size, 40))
-    got = np.asarray(dn.apply(params, jnp.asarray([seq]), cfg)[0])
+    got = np.asarray(_apply(params, jnp.asarray([seq]), cfg)[0])
     dense = ref_logits((cfg, params), seq, index_topk=1 << 20)
     assert np.abs(got - dense).max() < F32_TOL
 
@@ -195,65 +215,9 @@ def test_top_k_mask_is_exact_and_takes_the_lower_position_among_equals(ties):
         assert (got[r] == want).all(), r
 
 
-@pytest.fixture
-def lowered_for_tpu(monkeypatch):
-    """``lax.platform_dependent`` takes its ``tpu`` branch, and Pallas calls
-    run in the TPU interpreter: the decode program a chip would run, here."""
-    monkeypatch.setattr(
-        gen.lax, "platform_dependent",
-        lambda *args, tpu, default: tpu(*args))
-    with pltpu.force_tpu_interpret_mode():
-        yield
-
-
-# one program each for the walks below (the tiny model's five layers are
-# unrolled: op by op they cost more than a compile)
-_PREFILL = jax.jit(gen.prefill_at, static_argnums=1)
-_CHUNK = jax.jit(gen.decode_chunk, static_argnums=1, static_argnames=("steps",))
-
-
-def _serve(cfg, params, prompts, chunks, *, cache_len=128, mutate=None,
-           jitted=True):
-    """Prefill two prompts into slots 2 and 0 of a three-slot cache (slot 1
-    sits idle), then decode chunks of 6 steps, each whole (None) or CUT to
-    ``n`` -> the served tokens of each prompt, and the cache."""
-    bucket, steps = 64, 6
-    toks = np.zeros((2, bucket), np.int32)
-    for r, p in enumerate(prompts):
-        toks[r, :len(p)] = p
-    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
-    cache = gen.init_cache(cfg, 3, cache_len)
-    if mutate:
-        cache = mutate(cache)
-    prefill, chunk = ((gen.prefill_at, gen.decode_chunk) if jitted is False
-                      else (_PREFILL, _CHUNK))
-    last, cache = prefill(params, cfg, jnp.asarray(toks), lengths, cache,
-                          jnp.asarray([2, 0]))
-    assert set(cache) == {"c", "idx_k", "c_ring", "pos", "routed"}
-    first = jnp.argmax(last, -1).astype(jnp.int32)
-    served = [[int(first[0])], [int(first[1])]]
-    tokens = jnp.zeros((3,), jnp.int32).at[jnp.asarray([2, 0])].set(first)
-    active, key = jnp.asarray([True, False, True]), jax.random.PRNGKey(0)
-    counted = []
-    for n in chunks:
-        cache.pop("routed")
-        cut = {} if n is None else {"n": jnp.int32(n)}
-        emitted, cache, active, key = chunk(
-            params, cfg, cache, tokens, active, key, steps=steps, **cut)
-        counted.append(cache["routed"])
-        tokens = emitted[:, -1]
-        served[0] += [int(t) for t in emitted[2, :n]]
-        served[1] += [int(t) for t in emitted[0, :n]]
-    return served, cache, counted
-
-
-def _worst_gap(model, prompts, served):
-    worst = 0.0
-    for p, out in zip(prompts, served):
-        logits = ref_logits(model, p + out)[len(p) - 1:len(p) - 1 + len(out)]
-        worst = max(worst, float(
-            (logits.max(-1) - logits[np.arange(len(out)), out]).max()))
-    return worst
+# the cache walks below: prompts into slots 2 and 0 of a three-slot cache of 128
+# positions (slot 1 sits idle), chunks of 6 steps
+WALK = dict(steps=6, bucket=64, cache_len=128)
 
 
 @pytest.mark.parametrize("chunks", [(None, None, None), (4, None, 1, 3)],
@@ -270,8 +234,9 @@ def test_prefill_then_decode_through_the_three_caches(model, chunks):
     rng = np.random.RandomState(5)
     prompts = [list(rng.randint(0, cfg.vocab_size, 37)),
                list(rng.randint(0, cfg.vocab_size, 5))]
-    served, cache, counted = _serve(cfg, params, prompts, chunks)
-    assert _worst_gap(model, prompts, served) < F32_TOL
+    served, cache, counted = serve(cfg, params, prompts, chunks, **WALK)
+    assert set(cache) == {"c", "idx_k", "c_ring", "pos"}
+    assert worst_gap(partial(ref_logits, model), prompts, served) < F32_TOL
     steps = [6 if n is None else n for n in chunks]
     assert int(cache["pos"][2]) == 37 + sum(steps)
     # a step at context c (the cache below and its own position) scores c
@@ -292,9 +257,8 @@ def test_the_chip_path_serves_the_same_tokens(model, lowered_for_tpu):
     rng = np.random.RandomState(5)
     prompts = [list(rng.randint(0, cfg.vocab_size, 37)),
                list(rng.randint(0, cfg.vocab_size, 5))]
-    served, _, counted = _serve(cfg, params, prompts, (None, 2, None),
-                                jitted=False)  # traced under the patch
-    assert _worst_gap(model, prompts, served) < F32_TOL
+    served, _, counted = serve(cfg, params, prompts, (None, 2, None), **WALK)
+    assert worst_gap(partial(ref_logits, model), prompts, served) < F32_TOL
     # the kernel reads a slot's live tiles whole: 128 rows each at these
     # lengths, and the chunk's own columns
     assert counted[0]["dsa_read"].tolist() == [
@@ -311,8 +275,9 @@ def test_a_reused_slot_sees_nothing_of_its_predecessor(model):
                list(rng.randint(0, cfg.vocab_size, 3))]
     dirty = lambda cache: {  # noqa: E731
         k: v if k == "pos" else jnp.full_like(v, 7.0) for k, v in cache.items()}
-    reused, cache, _ = _serve(cfg, params, prompts, (None, 3), mutate=dirty)
-    assert _worst_gap(model, prompts, reused) < F32_TOL
+    reused, cache, _ = serve(cfg, params, prompts, (None, 3), cache=dirty(
+        gen.init_cache(cfg, 3, 128)), **WALK)
+    assert worst_gap(partial(ref_logits, model), prompts, reused) < F32_TOL
     # what the slots hold below their positions is what prefill and the
     # flushes wrote, in all three tensors
     for name in ("c", "idx_k"):
@@ -326,31 +291,13 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     whole = dn.Dots3NoteConfig.tiny(dtype=jnp.float32, n_experts=8)
     p = dn.init_layer(whole, jax.random.PRNGKey(3), 2)
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, whole.d_model))
-    flat = h.reshape(18, -1)
-    experts, gates = moe.route_sigmoid_top_k(
-        flat, p["router"], p["router_bias"], whole.experts_per_token,
-        whole.routed_scale)
-    parts, counted = 0.0, 0
-    for chip in range(4):
-        held = slice(2 * chip, 2 * chip + 2)
-        y, tokens = moe.held_experts_ffn(
-            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
-            p["ew_down"][held], first_expert=2 * chip)
-        parts, counted = parts + y, counted + int(tokens.sum())
-    assert counted == 18 * whole.experts_per_token  # every choice, once
-    f = lambda a: a  # noqa: E731
-    s = jax.nn.sigmoid(h @ p["router"])
-    _, sel = jax.lax.top_k(s + p["router_bias"], whole.experts_per_token)
-    chosen = jnp.take_along_axis(s, sel, -1)
-    g_all = whole.routed_scale * chosen / chosen.sum(-1, keepdims=True)
-    want = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    for e in range(whole.n_experts):
-        g = jnp.where(sel == e, g_all, 0.0).sum(-1)
-        want = want + g[..., None] * ref._swiglu(
-            h, p["ew_gate"][e], p["ew_up"][e], p["ew_down"][e], f)
-    shared = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    got = parts.reshape(h.shape) + shared
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
+    top_k, scale = whole.experts_per_token, whole.routed_scale
+    worst, counted = shares_add_up(
+        p, h, 4, moe.route_sigmoid_top_k(
+            h.reshape(18, -1), p["router"], p["router_bias"], top_k, scale),
+        sigmoid_top_k_by_hand(h, p, top_k, scale), ref._swiglu, whole.n_experts)
+    assert counted == 18 * top_k  # every choice, once
+    assert worst < F32_TOL
 
 
 def test_engine_serves_a_mixed_batch_and_counts_the_selection(model):
@@ -360,23 +307,20 @@ def test_engine_serves_a_mixed_batch_and_counts_the_selection(model):
     ``perf_stats()["dsa"]`` holds ``rows_selected == sum min(context,
     topk)`` over the steps and prompt rows, a full layer."""
     cfg, params = model
-    eng = GenerationEngine(
-        cfg, params, n_slots=3, max_new_tokens=6, decode_chunk_steps=3,
+    eng, _, _ = engine(
+        "dots3_note", n_slots=3, max_new_tokens=6, decode_chunk_steps=3,
         prefill_buckets=(8, 32))
     assert set(eng.cache) == {"c", "idx_k", "c_ring", "pos"}
     rng = np.random.RandomState(11)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (5, 20, 12, 3)]
     new = [6, 5, 6, 2]
     futs = [eng.submit(p, m) for p, m in zip(prompts, new)]
-    for _ in range(200):
-        if all(f.done() for f in futs):
-            break
-        eng.step()
+    run_engine(eng, futs)
     # every answer is the reference's greedy one (teacher-forced: each served
     # token's logit is the reference's best at its position)
     served = [f.result(timeout=1) for f in futs]
     assert [len(out) for out in served] == new
-    assert _worst_gap(model, prompts, served) < F32_TOL
+    assert worst_gap(partial(ref_logits, model), prompts, served) < F32_TOL
     stats = eng.perf_stats()
     tiles = stats["cache_tiles"]
     assert tiles["layers"] == {"full": 2, "window": 3}
@@ -477,7 +421,7 @@ def serve_instance():
     ray_tpu.shutdown()
 
 
-def test_llm_deployment_end_to_end(serve_instance):
+def test_llm_deployment_end_to_end(serve_instance, model):
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.serve.llm import llm_deployment
@@ -486,16 +430,14 @@ def test_llm_deployment_end_to_end(serve_instance):
         "dots3_note", "tiny",
         engine_kwargs=dict(n_slots=2, max_new_tokens=6, decode_chunk_steps=3,
                            prefill_buckets=(16,)),
-        config_kwargs=dict(dtype=jnp.float32))
+        config_kwargs=dict(dtype=jnp.float32, **TINY["dots3_note"]))
     handle = serve.run(dep.bind(), port=0)
     prompt = [3, 5, 7, 11, 2, 9, 4, 8, 1, 6, 12]  # 11 positions: over top-8
     outs = ray_tpu.get(
         [handle.remote({"tokens": prompt, "max_new_tokens": 6})
          for _ in range(3)], timeout=300)
-    cfg = dn.Dots3NoteConfig.tiny(dtype=jnp.float32)
-    one = gen.generate(dn.init(cfg, jax.random.PRNGKey(0)), cfg,
-                       jnp.asarray([prompt]), jnp.asarray([len(prompt)]),
-                       max_new_tokens=6)
-    assert all(o["tokens"] == [int(t) for t in one[0]] for o in outs)
+    cfg, params = model  # the replica's: the same config, initialised alike
+    one, = one_shot(params, cfg, [prompt], 6)
+    assert all(o["tokens"] == one for o in outs)
     stats = ray_tpu.get(handle.perf_stats.remote(), timeout=60)
     assert stats["dsa"]["decode"]["rows_selected"] > 0

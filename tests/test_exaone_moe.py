@@ -14,21 +14,34 @@ where the k-th expert changes).
 
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import family_harness
 import pytest
+from family_harness import (
+    engine,
+    one_shot,
+    padded,
+    run_engine,
+    serve,
+    shares_add_up,
+    sigmoid_top_k_by_hand,
+    tiny_model,
+    worst_gap,
+)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import k_exaone_ref as ref  # noqa: E402
 from ray_tpu.models import exaone_moe as em  # noqa: E402
-from ray_tpu.models import generate as gen  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.ops.attention import attention  # noqa: E402
 from ray_tpu.serve.llm import GenerationEngine, make_config  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
 F32_TOL = 2e-4
 
 
@@ -43,13 +56,13 @@ def sizes_of(cfg):
 @pytest.fixture(scope="module")
 def model():
     # window 8 (ring 16), 16 experts of which 4..11 are held, top-4
-    cfg = em.ExaoneMoeConfig.tiny(dtype=jnp.float32, experts_held=(4, 8))
-    return cfg, em.init(cfg, jax.random.PRNGKey(0))
+    return tiny_model("exaone_moe")
 
 
 def ref_logits(model, seq):
     cfg, params = model
-    return np.asarray(ref.logits(params, jnp.asarray([seq]), sizes_of(cfg))[0])
+    return np.asarray(ref.logits(
+        params, jnp.asarray([padded(seq)]), sizes_of(cfg))[0])[:len(seq)]
 
 
 def test_config_reads_the_published_lists_up_to_its_depth():
@@ -103,32 +116,12 @@ def test_prefill_then_decode_through_the_cache_against_the_reference(
     rng = np.random.RandomState(n_prompt)
     prompts = [list(rng.randint(0, cfg.vocab_size, n_prompt)),
                list(rng.randint(0, cfg.vocab_size, max(1, n_prompt - 2)))]
-    bucket, n_new, steps = 40, 16, 5
-    toks = np.zeros((2, bucket), np.int32)
-    for r, p in enumerate(prompts):
-        toks[r, :len(p)] = p
-    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
-    cache = gen.init_cache(cfg, 3, 64)  # a third slot sits idle
-    last, cache = gen.prefill_at(params, cfg, jnp.asarray(toks), lengths,
-                                 cache, jnp.asarray([2, 0]))
+    served, cache, _ = serve(  # a third slot sits idle
+        cfg, params, prompts, (None,) * 3, steps=5, bucket=40, cache_len=64)
     assert cache["k"].shape[0] == 1 and cache["k_ring"].shape == (
         4, 3, cfg.n_kv_heads, cfg.head_dim, 16)
-    first = jnp.argmax(last, -1).astype(jnp.int32)
-    served = [[int(first[0])], [int(first[1])]]
-    tokens = jnp.zeros((3,), jnp.int32).at[jnp.asarray([2, 0])].set(first)
-    active, key = jnp.asarray([True, False, True]), jax.random.PRNGKey(0)
-    for _ in range(3):
-        cache.pop("routed")
-        emitted, cache, active, key = gen.decode_chunk(
-            params, cfg, cache, tokens, active, key, steps=steps)
-        tokens = emitted[:, -1]
-        served[0] += [int(t) for t in emitted[2]]
-        served[1] += [int(t) for t in emitted[0]]
     assert int(cache["pos"][2]) == len(prompts[0]) + 15
-    for p, out in zip(prompts, served):
-        logits = ref_logits(model, p + out)[len(p) - 1:len(p) - 1 + n_new]
-        gap = logits.max(-1) - logits[np.arange(n_new), out]
-        assert gap.max() < F32_TOL, (gap, out)
+    assert worst_gap(partial(ref_logits, model), prompts, served) < F32_TOL
 
 
 def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
@@ -138,34 +131,13 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     whole = em.ExaoneMoeConfig.tiny(dtype=jnp.float32)
     p = em.init_layer(whole, jax.random.PRNGKey(3), 1)
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, whole.d_model))
-    flat = h.reshape(18, -1)
-    experts, gates = moe.route_sigmoid_top_k(
-        flat, p["router"], p["router_bias"], whole.experts_per_token,
-        whole.routed_scale)
-    parts, counted = 0.0, 0
-    for chip in range(8):
-        held = slice(2 * chip, 2 * chip + 2)
-        y, tokens = moe.held_experts_ffn(
-            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
-            p["ew_down"][held], first_expert=2 * chip)
-        parts, counted = parts + y, counted + int(tokens.sum())
-    assert counted == 18 * whole.experts_per_token  # every choice, once
-    shared = em._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"])
-    sizes = {k: v for k, v in sizes_of(whole).items() if k != "sliding_windows"}
-    # the reference's whole layer on a residual of zero attention: feed the
-    # FFN half by hand (x = 0 would zero the norm), so compare FFN outputs
-    f = lambda a: a  # noqa: E731
-    s = jax.nn.sigmoid(h @ p["router"])
-    _, sel = jax.lax.top_k(s + p["router_bias"], sizes["top_k"])
-    chosen = jnp.take_along_axis(s, sel, -1)
-    g_all = sizes["routed_scale"] * chosen / chosen.sum(-1, keepdims=True)
-    want = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    for e in range(whole.n_experts):
-        g = jnp.where(sel == e, g_all, 0.0).sum(-1)
-        want = want + g[..., None] * ref._swiglu(
-            h, p["ew_gate"][e], p["ew_up"][e], p["ew_down"][e], f)
-    got = parts.reshape(h.shape) + shared
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
+    top_k, scale = whole.experts_per_token, whole.routed_scale
+    worst, counted = shares_add_up(
+        p, h, 8, moe.route_sigmoid_top_k(
+            h.reshape(18, -1), p["router"], p["router_bias"], top_k, scale),
+        sigmoid_top_k_by_hand(h, p, top_k, scale), ref._swiglu, whole.n_experts)
+    assert counted == 18 * top_k  # every choice, once
+    assert worst < F32_TOL
 
 
 def _to_one_expert(n_tokens, valid=None):
@@ -334,28 +306,6 @@ def test_band_attention_is_the_masked_square(t, window):
     assert np.abs(np.asarray(got) - want).max() < 1e-5
 
 
-def _one_shot(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray([prompt]),
-                       jnp.asarray([len(prompt)]), max_new_tokens=n)
-    return [int(t) for t in out[0]]
-
-
-def _ticks(eng, futs):
-    """Step a never-started engine until ``futs`` are done: per tick that
-    admitted anything, the prompt lengths of each prefill call it made; and
-    the steps every dispatched chunk ran."""
-    ticks, steps = [], []
-    while not all(f.done() for f in futs):
-        before, was = eng.stats()["queued"], eng._pending
-        eng.step()
-        if before - eng.stats()["queued"]:
-            ticks.append([[len(req.tokens) for _, _, req in admissions]
-                          for admissions, *_ in eng._pending.prefills])
-        if eng._pending is not None and eng._pending is not was:
-            steps.append(eng._pending.steps)
-    return ticks, steps
-
-
 # buckets (8, 16, 32) at CALL_TOKENS 16: rows 2 / 1 / 1; 4 slots; an answer
 # of 6 tokens is a prefill and two chunks of 3 steps, so a slot admitted in
 # tick t is free again for tick t + 2
@@ -418,8 +368,10 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     monkeypatch.setattr(llm, "CALL_TOKENS", 16)
     monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", 64)
     monkeypatch.setattr(moe, "_TRIP_ROWS", 16)
-    eng = GenerationEngine(  # never started: the test is the engine thread
-        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
+    # the block's size is read at trace time: programs of a path of their own
+    monkeypatch.setattr(family_harness, "PATH", "one_block_is_64_pairs")
+    eng, _, _ = engine(  # never started: the test is the engine thread
+        "exaone_moe", n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
         prefill_buckets=(8, 16, 32), prefill_token_budget=want["budget"])
     assert eng._rows == {8: 2, 16: 1, 32: 1}
     assert eng._tick_tokens == (want["budget"] or 4 * 32)
@@ -430,14 +382,14 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     rng = np.random.RandomState(7)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in want["lens"]]
     futs = [eng.submit(p, 6) for p in prompts]
-    ticks, steps = _ticks(eng, futs)
-    assert ticks == want["ticks"]
+    seen = run_engine(eng, futs)
+    steps = [s["steps"] for s in seen if s["steps"] is not None]
+    assert [s["admitted"] for s in seen if s["admitted"]] == want["ticks"]
     stats = eng.perf_stats()
     empty = dict(calls=0, rows=0, padded_tokens=0, prompts=0, live_tokens=0)
     assert stats["prefill"] == {
         str(b): want["prefill"].get(b, empty) for b in (8, 16, 32)}
-    for p, f in zip(prompts, futs):
-        assert f.result() == _one_shot(params, cfg, p, 6)
+    assert [f.result() for f in futs] == one_shot(params, cfg, prompts, 6)
     # what the decode chunks read: a full layer a slot's live tiles, the four
     # window layers every row's ring (one tile here), whatever the context
     tiles = stats["cache_tiles"]

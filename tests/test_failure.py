@@ -6,6 +6,7 @@ import time
 import pytest
 
 import ray_tpu
+from ray_tpu._private.worker import global_worker
 from ray_tpu.exceptions import RayActorError, WorkerCrashedError
 
 
@@ -67,3 +68,31 @@ def test_node_death_fails_running_tasks(ray_start_cluster):
     cluster.remove_node(nid)
     with pytest.raises(Exception):
         ray_tpu.get(ref, timeout=60)
+    assert _reader_ends_quietly_when_closed_under_it(global_worker.node)
+
+
+def _reader_ends_quietly_when_closed_under_it(node):
+    """The head's reader of a connection that is closed under it (a node
+    declared dead, a shutdown) while a frame is on its way: the frame's
+    header is read from the descriptor the blocked read holds, its body from
+    a handle that is gone.  The loop ends as on EOF: no exception leaves its
+    thread."""
+    import multiprocessing
+    import threading
+
+    from ray_tpu._private import wire
+
+    ours, theirs = multiprocessing.Pipe()
+    conn, raised = wire.wrap(ours), []
+    hook, threading.excepthook = threading.excepthook, raised.append
+    try:
+        reader = threading.Thread(target=node._reader_loop, args=(conn,))
+        reader.start()
+        time.sleep(0.5)  # blocked in recv
+        conn.close()
+        theirs.send_bytes(b"x" * 64)
+        reader.join(timeout=30)
+    finally:
+        threading.excepthook = hook
+        theirs.close()
+    return not reader.is_alive() and not raised

@@ -19,11 +19,24 @@ that moved the state, a scale of ``head_dim ** -0.5`` (each > 1e-2 of it).
 import dataclasses
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_harness import (
+    decode_chunk,
+    engine,
+    one_shot,
+    padded,
+    prefill,
+    run_engine,
+    serve,
+    shares_add_up,
+    tiny_model,
+    worst_gap,
+)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -31,8 +44,9 @@ from benchmark.reference import granite_hybrid_ref as ref  # noqa: E402
 from ray_tpu.models import generate as gen  # noqa: E402
 from ray_tpu.models import granite_hybrid as gh  # noqa: E402
 from ray_tpu.ops import moe, ssm  # noqa: E402
-from ray_tpu.serve.llm import GenerationEngine, make_config  # noqa: E402
+from ray_tpu.serve.llm import make_config  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
 LOGIT_STD = 2.8e-3
 F32_TOL = 2e-4 * LOGIT_STD
 
@@ -54,8 +68,7 @@ def model():
     # 5 layers, attention at index 2 (one period's pattern), 8 Mamba heads of
     # 8 with a state of 16 in chunks of 8, 16 experts of which 4..11 are
     # held, top-4; every multiplier is something other than 1
-    cfg = gh.GraniteHybridConfig.tiny(dtype=jnp.float32, experts_held=(4, 8))
-    return cfg, gh.init(cfg, jax.random.PRNGKey(0))
+    return tiny_model("granite_hybrid")
 
 
 def ref_logits(model, seq):
@@ -63,8 +76,8 @@ def ref_logits(model, seq):
     to one width (every layer is causal: what follows a position does not
     reach it), so that the reference compiles once."""
     cfg, params = model
-    padded = list(seq) + [0] * (64 - len(seq))
-    return ref.logits(params, jnp.asarray([padded]), sizes_of(cfg))[0][:len(seq)]
+    return ref.logits(
+        params, jnp.asarray([padded(seq)]), sizes_of(cfg))[0][:len(seq)]
 
 
 def test_config_is_the_published_one_and_says_what_it_caches():
@@ -175,82 +188,28 @@ def test_chunked_scan_against_the_recurrence(chunk):
     assert (tail[1, 0] == 0).all() and (tail[1, 1:] == np.asarray(raw[1, :2])).all()
 
 
-SLOTS, POSITIONS, BUCKET, ROWS, STEPS = 4, 64, 48, 3, 5
+# every cache test's shapes: 4 slots of 64 positions, prefill calls of 3 rows
+# x 48, chunks of 5 steps (so the harness's programs are built once for them)
+SLOTS, POSITIONS, STEPS = 4, 64, 5
+CALL = {"bucket": 48, "rows": 3}
 
 
-@pytest.fixture(scope="module")
-def programs(model):
-    """The three programs every cache test runs, jitted once for one set of
-    shapes (4 slots of 64 positions, prefill calls of 3 rows x 48, chunks of
-    5 steps): the prefill, the whole chunk (with or without an ``eos_id``)
-    and the cut chunk."""
-    cfg, params = model
-
-    def prefill(toks, lengths, cache, slots):
-        last, cache = gen.prefill_at(params, cfg, toks, lengths, cache, slots)
-        cache.pop("routed")
-        return jnp.argmax(last, -1).astype(jnp.int32), cache
-
-    def chunk(cache, tokens, active, n=None, eos_id=None):
-        emitted, cache, active, _ = gen.decode_chunk(
-            params, cfg, cache, tokens, active, jax.random.PRNGKey(0),
-            steps=STEPS, n=n, eos_id=eos_id)
-        cache.pop("routed")
-        return emitted, cache, active
-
-    return jax.jit(prefill), jax.jit(chunk, static_argnames=("eos_id",))
-
-
-def _prefill(programs, prompts, slots, cache):
-    """``prompts`` right-padded into ONE call of ``ROWS`` rows (those no
-    prompt fills: a 1-token dummy aimed at the last slot, as the engine aims
-    them at its scratch row) -> their first tokens, the cache, and the last
-    tokens and the active flags of every slot."""
-    toks = np.zeros((ROWS, BUCKET), np.int32)
-    lengths, into = np.ones((ROWS,), np.int32), np.full((ROWS,), SLOTS - 1, np.int32)
-    for r, (p, slot) in enumerate(zip(prompts, slots)):
-        toks[r, :len(p)], lengths[r], into[r] = p, len(p), slot
-    first, cache = programs[0](jnp.asarray(toks), jnp.asarray(lengths),
-                               cache, jnp.asarray(into))
-    tokens = jnp.zeros((SLOTS,), jnp.int32).at[jnp.asarray(slots)].set(
-        first[:len(prompts)])
-    active = jnp.zeros((SLOTS,), bool).at[jnp.asarray(slots)].set(True)
-    return first[:len(prompts)], cache, tokens, active
-
-
-def _worst_gap(model, prompts, served):
-    worst = 0.0
-    for p, out in zip(prompts, served):
-        logits = ref_logits(model, p + out)[len(p) - 1:len(p) - 1 + len(out)]
-        worst = max(worst, float(
-            (logits.max(-1) - logits[np.arange(len(out)), out]).max()))
-    return worst
-
-
-def _serve(model, programs, prompts, *, spoil=None):
+def _cut_walk(model, prompts, *, spoil=None):
     """Three prompts of different lengths right-padded into ONE prefill call,
     then a whole chunk of 5 steps, a chunk CUT after 3, and a whole chunk,
     one slot idle throughout -> the served tokens of each prompt."""
-    cfg, _ = model
+    cfg, params = model
     slots = [2, 0, 1]
-    first, cache, tokens, active = _prefill(
-        programs, prompts, slots, gen.init_cache(cfg, SLOTS, POSITIONS))
-    served = [[int(t)] for t in first]
-    for n in (None, 3, None):
-        if spoil:
-            cache = spoil(cache)
-        emitted, cache, active = programs[1](
-            cache, tokens, active, None if n is None else jnp.int32(n))
-        tokens = emitted[:, -1]
-        for r, slot in enumerate(slots):
-            served[r] += [int(t) for t in emitted[slot][:n]]
+    served, cache, _ = serve(
+        cfg, params, prompts, (None, 3, None), steps=STEPS, slots=slots,
+        n_slots=SLOTS, cache_len=POSITIONS, spoil=spoil, **CALL)
     # a cut of 3 advanced the positions (and the state) 3 steps, not 5
     assert [int(cache["pos"][s]) for s in slots] == [len(p) + 13 for p in prompts]
     # what the steps left in the cache against ONE prefill of everything a
     # row has consumed (its prompt and all but the last served token)
-    _, whole, _, _ = _prefill(
-        programs, [p + out[:-1] for p, out in zip(prompts, served)], slots,
-        gen.init_cache(cfg, SLOTS, POSITIONS))
+    _, whole, _, _ = prefill(
+        cfg, params, [p + out[:-1] for p, out in zip(prompts, served)], slots,
+        gen.init_cache(cfg, SLOTS, POSITIONS), **CALL)
     drift = max(
         float(jnp.abs(cache[name] - whole[name]).max() / jnp.abs(whole[name]).max())
         for name in ("ssm", "conv"))
@@ -259,7 +218,7 @@ def _serve(model, programs, prompts, *, spoil=None):
 
 @pytest.mark.parametrize("broken", [None, "bf16_state", "stale_tail"])
 def test_prefill_then_decode_through_the_cache_against_the_reference(
-        model, programs, broken):
+        model, broken):
     """(b) Prefill of three right-padded rows in one call, then whole chunks
     and a cut chunk through the state: each served token's LOGIT is the
     reference's best at its position in one full forward over prompt + served
@@ -277,14 +236,15 @@ def test_prefill_then_decode_through_the_cache_against_the_reference(
             jnp.float32)}
     elif broken == "stale_tail":
         spoil = lambda c: {**c, "conv": jnp.roll(c["conv"], 1, axis=1)}  # noqa: E731
-    served, drift = _serve(model, programs, prompts, spoil=spoil)
+    served, drift = _cut_walk(model, prompts, spoil=spoil)
     if broken is None:
-        assert _worst_gap(model, prompts, served) < F32_TOL and drift < 1e-5
+        gap = worst_gap(partial(ref_logits, model), prompts, served)
+        assert gap < F32_TOL and drift < 1e-5
     else:
         assert drift > 1e-4
 
 
-def test_a_row_stopped_at_eos_is_frozen_and_an_idle_row_untouched(model, programs):
+def test_a_row_stopped_at_eos_is_frozen_and_an_idle_row_untouched(model):
     """(b, d) A row that emits ``eos_id`` at step 2 of a chunk of 5 keeps the
     state of a chunk cut after that step (its later steps moved nothing) and
     repeats the token; a slot that sits the chunk out has its state and tail
@@ -292,18 +252,19 @@ def test_a_row_stopped_at_eos_is_frozen_and_an_idle_row_untouched(model, program
     cfg, params = model
     rng = np.random.RandomState(7)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (11, 6)]
-    _, cache, tokens, active = _prefill(
-        programs, prompts, [0, 2], gen.init_cache(cfg, SLOTS, POSITIONS))
+    _, cache, tokens, active = prefill(
+        cfg, params, prompts, [0, 2], gen.init_cache(cfg, SLOTS, POSITIONS),
+        **CALL)
     # the idle slot holds something, so that "unchanged" is not "zero"
     cache["ssm"] = cache["ssm"].at[:, 1].set(0.5)
     cache["conv"] = cache["conv"].at[:, :, 1].set(0.25)
-    run = lambda *a, **kw: programs[1](  # noqa: E731
-        jax.tree.map(jnp.copy, cache), tokens, active, *a, **kw)
+    run = lambda **kw: decode_chunk(  # noqa: E731
+        params, cfg, cache, tokens, active, steps=STEPS, **kw)[:3]
     free, after, _ = run()
     eos = int(free[2, 2])  # what slot 2 emits at step 2
     assert eos not in [int(t) for t in free[2, :2]]
     stopped, froze, still = run(eos_id=eos)
-    cut, at_cut, _ = run(jnp.int32(3))
+    cut, at_cut, _ = run(n=3)
     assert [int(t) for t in stopped[2]] == [int(t) for t in free[2, :3]] + [eos] * 2
     assert int(froze["pos"][2]) == len(prompts[1]) + 3 == int(at_cut["pos"][2])
     assert not bool(still[2]) and bool(still[0])
@@ -316,7 +277,7 @@ def test_a_row_stopped_at_eos_is_frozen_and_an_idle_row_untouched(model, program
             assert (take(c, 1) == take(cache, 1)).all(), name
 
 
-def test_a_reused_slot_gives_what_a_fresh_cache_gives(model, programs):
+def test_a_reused_slot_gives_what_a_fresh_cache_gives(model):
     """(c) A long prompt is served in slot 0, then a shorter one is prefilled
     into the same slot: its state, tail and tokens are those of a cache that
     never held the first (prefill writes a slot's state whole)."""
@@ -325,8 +286,10 @@ def test_a_reused_slot_gives_what_a_fresh_cache_gives(model, programs):
     long, short = (list(rng.randint(0, cfg.vocab_size, n)) for n in (30, 4))
 
     def serve(prompt, cache):
-        _, cache, tokens, active = _prefill(programs, [prompt], [0], cache)
-        emitted, cache, _ = programs[1](cache, tokens, active)
+        _, cache, tokens, active = prefill(
+            cfg, params, [prompt], [0], cache, **CALL)
+        emitted, cache, *_ = decode_chunk(
+            params, cfg, cache, tokens, active, steps=STEPS)
         return [int(t) for t in emitted[0]], cache
 
     _, used = serve(long, gen.init_cache(cfg, SLOTS, POSITIONS))
@@ -398,30 +361,15 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     whole = gh.GraniteHybridConfig.tiny(dtype=jnp.float32)
     p = gh.init_layer(whole, jax.random.PRNGKey(3), 0)
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, whole.d_model))
-    flat = h.reshape(18, -1)
     experts, gates = moe.route_softmax_top_k(
-        flat, p["router"], whole.experts_per_token)
+        h.reshape(18, -1), p["router"], whole.experts_per_token)
     np.testing.assert_allclose(np.asarray(gates).sum(-1), 1.0, rtol=1e-6)
-    parts, counted = 0.0, 0
-    share = jax.jit(moe.held_experts_ffn, static_argnames=("first_expert",))
-    for chip in range(8):
-        held = slice(2 * chip, 2 * chip + 2)
-        y, tokens = share(
-            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
-            p["ew_down"][held], first_expert=2 * chip)
-        parts, counted = parts + y, counted + int(tokens.sum())
-    assert counted == 18 * whole.experts_per_token  # every choice, once
-    f = lambda a: a  # noqa: E731
     chosen, sel = jax.lax.top_k(h @ p["router"], whole.experts_per_token)
-    g_all = jax.nn.softmax(chosen, -1)
-    want = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    for e in range(whole.n_experts):
-        g = jnp.where(sel == e, g_all, 0.0).sum(-1)
-        want = want + g[..., None] * ref._swiglu(
-            h, p["ew_gate"][e], p["ew_up"][e], p["ew_down"][e], f)
-    got = parts.reshape(h.shape) + ref._swiglu(
-        h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+    worst, counted = shares_add_up(
+        p, h, 8, (experts, gates), (sel, jax.nn.softmax(chosen, -1)),
+        ref._swiglu, whole.n_experts)
+    assert counted == 18 * whole.experts_per_token  # every choice, once
+    assert worst < 2e-5
 
 
 def test_engine_serves_a_mixed_batch_as_generate_does(model):
@@ -431,26 +379,17 @@ def test_engine_serves_a_mixed_batch_as_generate_does(model):
     counters count the rows the steps had to move against those the CPU's
     masked update touched (every row)."""
     cfg, params = model
-    eng = GenerationEngine(
-        cfg, params, n_slots=3, max_new_tokens=7, decode_chunk_steps=3,
+    eng, _, _ = engine(
+        "granite_hybrid", n_slots=3, max_new_tokens=7, decode_chunk_steps=3,
         prefill_buckets=(8, 32))
     assert set(eng.cache) == {"k", "v", "pos", "ssm", "conv"}
     rng = np.random.RandomState(11)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (5, 20, 12, 3)]
     asked = [6, 7, 3, 5]
     futs = [eng.submit(p, n) for p, n in zip(prompts, asked)]
-    for _ in range(200):
-        if all(f.done() for f in futs):
-            break
-        eng.step()
-    batch = np.zeros((len(prompts), 20), np.int32)
-    for r, p in enumerate(prompts):
-        batch[r, :len(p)] = p
-    one = gen.generate(  # the one-shot path: one call, every prompt
-        params, cfg, jnp.asarray(batch),
-        jnp.asarray([len(p) for p in prompts]), max_new_tokens=max(asked))
-    for r, (n, f) in enumerate(zip(asked, futs)):
-        assert f.result(timeout=1) == [int(t) for t in one[r][:n]]
+    run_engine(eng, futs)
+    assert [f.result(timeout=1) for f in futs] == one_shot(
+        params, cfg, prompts, asked)
     stats = eng.perf_stats()
     assert stats["cache_tiles"]["layers"] == {"full": 1, "window": 0, "state": 4}
     state = stats["state"]

@@ -14,6 +14,7 @@ cache rounded through float8 (>1e-2), the scale without YaRN's ``m * m``
 
 import dataclasses
 import importlib
+from functools import partial
 import os
 import sys
 
@@ -21,6 +22,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_harness import (
+    engine,
+    one_shot,
+    padded,
+    run_engine,
+    serve,
+    shares_add_up,
+    sigmoid_top_k_by_hand,
+    tiny_model,
+    worst_gap,
+)
 from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -29,10 +41,11 @@ from benchmark.reference import kimi_k2_ref as ref  # noqa: E402
 from ray_tpu.models import generate as gen  # noqa: E402
 from ray_tpu.models import kimi_k2 as kk  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
-from ray_tpu.serve.llm import GenerationEngine, make_config  # noqa: E402
+from ray_tpu.serve.llm import make_config  # noqa: E402
 
 attention = importlib.import_module("ray_tpu.ops.attention")
 
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
 F32_TOL = 2e-4
 
 
@@ -55,13 +68,13 @@ def model():
     # 3 layers (one dense, two sparse), 4 heads of 8 | 8 | 8, latent rows of
     # 16 + 8 values, 16 experts of which 4..11 are held, top-4; YaRN's ramp
     # lies inside the 4 rotary frequencies (32 original positions)
-    cfg = kk.KimiK2Config.tiny(dtype=jnp.float32, experts_held=(4, 8))
-    return cfg, kk.init(cfg, jax.random.PRNGKey(0))
+    return tiny_model("kimi_k2")
 
 
 def ref_logits(model, seq):
     cfg, params = model
-    return ref.logits(params, jnp.asarray([seq]), sizes_of(cfg))[0]
+    return ref.logits(
+        params, jnp.asarray([padded(seq)]), sizes_of(cfg))[0][:len(seq)]
 
 
 def test_config_is_the_published_one_and_says_what_it_caches():
@@ -150,44 +163,6 @@ def test_absorbed_against_unabsorbed(model):
         assert np.abs(np.asarray(plain) - np.asarray(no_mm)).max() > 1e-2
 
 
-def _serve(cfg, params, prompts, *, spoil=None):
-    """Prefill into a cache of two 128-position tiles, then three decode
-    chunks of 5 steps -> the served tokens of each prompt."""
-    bucket, steps = 128, 5
-    toks = np.zeros((2, bucket), np.int32)
-    for r, p in enumerate(prompts):
-        toks[r, :len(p)] = p
-    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
-    cache = gen.init_cache(cfg, 3, 256)  # a third slot sits idle
-    last, cache = gen.prefill_at(params, cfg, jnp.asarray(toks), lengths,
-                                 cache, jnp.asarray([2, 0]))
-    assert set(cache) == {"c", "pos", "routed"}
-    first = jnp.argmax(last, -1).astype(jnp.int32)
-    served = [[int(first[0])], [int(first[1])]]
-    tokens = jnp.zeros((3,), jnp.int32).at[jnp.asarray([2, 0])].set(first)
-    active, key = jnp.asarray([True, False, True]), jax.random.PRNGKey(0)
-    for _ in range(3):
-        cache.pop("routed")
-        if spoil:
-            cache["c"] = spoil(cache["c"])
-        emitted, cache, active, key = gen.decode_chunk(
-            params, cfg, cache, tokens, active, key, steps=steps)
-        tokens = emitted[:, -1]
-        served[0] += [int(t) for t in emitted[2]]
-        served[1] += [int(t) for t in emitted[0]]
-    assert int(cache["pos"][2]) == len(prompts[0]) + 15
-    return served
-
-
-def _worst_gap(model, prompts, served):
-    worst = 0.0
-    for p, out in zip(prompts, served):
-        logits = ref_logits(model, p + out)[len(p) - 1:len(p) - 1 + len(out)]
-        worst = max(worst, float(
-            (logits.max(-1) - logits[np.arange(len(out)), out]).max()))
-    return worst
-
-
 @pytest.mark.parametrize("broken", [None, "float8_cache", "no_mscale"])
 def test_prefill_then_decode_through_the_latent_cache_against_the_reference(
         model, broken):
@@ -201,16 +176,19 @@ def test_prefill_then_decode_through_the_latent_cache_against_the_reference(
     rng = np.random.RandomState(5)
     prompts = [list(rng.randint(0, cfg.vocab_size, 121)),
                list(rng.randint(0, cfg.vocab_size, 9))]
-    if broken is None:
-        assert _worst_gap(model, prompts, _serve(cfg, params, prompts)) < F32_TOL
-        return
+    spoil = None
     if broken == "float8_cache":
-        served = _serve(cfg, params, prompts, spoil=lambda c: c.astype(
-            jnp.float8_e4m3fn).astype(c.dtype))
-    else:
-        served = _serve(dataclasses.replace(
-            cfg, rope_mscale=0.0, rope_mscale_all_dim=0.0), params, prompts)
-    assert _worst_gap(model, prompts, served) > 10 * F32_TOL
+        spoil = lambda cache: {**cache, "c": cache["c"].astype(  # noqa: E731
+            jnp.float8_e4m3fn).astype(cache["c"].dtype)}
+    elif broken == "no_mscale":
+        cfg = dataclasses.replace(cfg, rope_mscale=0.0, rope_mscale_all_dim=0.0)
+    # a cache of two 128-position tiles, a third slot idle
+    served, cache, _ = serve(cfg, params, prompts, (None,) * 3, steps=5,
+                             bucket=128, cache_len=256, spoil=spoil)
+    assert set(cache) == {"c", "pos"}
+    assert int(cache["pos"][2]) == len(prompts[0]) + 15
+    gap = worst_gap(partial(ref_logits, model), prompts, served)
+    assert gap < F32_TOL if broken is None else gap > 10 * F32_TOL
 
 
 LIVE = {
@@ -259,31 +237,13 @@ def test_the_32_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     whole = kk.KimiK2Config.tiny(dtype=jnp.float32, n_experts=64)
     p = kk.init_layer(whole, jax.random.PRNGKey(3), 1)
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, whole.d_model))
-    flat = h.reshape(18, -1)
-    experts, gates = moe.route_sigmoid_top_k(
-        flat, p["router"], p["router_bias"], whole.experts_per_token,
-        whole.routed_scale)
-    parts, counted = 0.0, 0
-    for chip in range(32):
-        held = slice(2 * chip, 2 * chip + 2)
-        y, tokens = moe.held_experts_ffn(
-            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
-            p["ew_down"][held], first_expert=2 * chip)
-        parts, counted = parts + y, counted + int(tokens.sum())
-    assert counted == 18 * whole.experts_per_token  # every choice, once
-    f = lambda a: a  # noqa: E731
-    s = jax.nn.sigmoid(h @ p["router"])
-    _, sel = jax.lax.top_k(s + p["router_bias"], whole.experts_per_token)
-    chosen = jnp.take_along_axis(s, sel, -1)
-    g_all = whole.routed_scale * chosen / chosen.sum(-1, keepdims=True)
-    want = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    for e in range(whole.n_experts):
-        g = jnp.where(sel == e, g_all, 0.0).sum(-1)
-        want = want + g[..., None] * ref._swiglu(
-            h, p["ew_gate"][e], p["ew_up"][e], p["ew_down"][e], f)
-    shared = ref._swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)
-    got = parts.reshape(h.shape) + shared
-    assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
+    top_k, scale = whole.experts_per_token, whole.routed_scale
+    worst, counted = shares_add_up(
+        p, h, 32, moe.route_sigmoid_top_k(
+            h.reshape(18, -1), p["router"], p["router_bias"], top_k, scale),
+        sigmoid_top_k_by_hand(h, p, top_k, scale), ref._swiglu, whole.n_experts)
+    assert counted == 18 * top_k  # every choice, once
+    assert worst < F32_TOL
 
 
 def test_engine_serves_a_mixed_batch_as_generate_does(model):
@@ -292,21 +252,16 @@ def test_engine_serves_a_mixed_batch_as_generate_does(model):
     path's, and the counters count latent layers as full ones, in the latent
     row's bytes."""
     cfg, params = model
-    eng = GenerationEngine(
-        cfg, params, n_slots=3, max_new_tokens=6, decode_chunk_steps=3,
+    eng, _, _ = engine(
+        "kimi_k2", n_slots=3, max_new_tokens=6, decode_chunk_steps=3,
         prefill_buckets=(8, 16, 32))
     assert set(eng.cache) == {"c", "pos"}
     rng = np.random.RandomState(11)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in (5, 20, 12, 3)]
     futs = [eng.submit(p, 6) for p in prompts]
-    for _ in range(200):
-        if all(f.done() for f in futs):
-            break
-        eng.step()
-    for p, f in zip(prompts, futs):
-        one = gen.generate(params, cfg, jnp.asarray([p]), jnp.asarray([len(p)]),
-                           max_new_tokens=6)
-        assert f.result(timeout=1) == [int(t) for t in one[0]]
+    run_engine(eng, futs)
+    assert [f.result(timeout=1) for f in futs] == one_shot(
+        params, cfg, prompts, 6)
     tiles = eng.perf_stats()["cache_tiles"]
     assert tiles["layers"] == {"full": 3, "window": 0}
     assert tiles["read_window"] == 0 and tiles["read_full"] > 0

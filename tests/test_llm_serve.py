@@ -6,16 +6,18 @@ engine works behind a Serve replica (the decode analog of the reference's
 ``serve/_private/replica.py:250`` request path).
 """
 
-import jax
 import jax.numpy as jnp
 import pytest
+from family_harness import engine, one_shot, run_engine, shared_engine
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import generate as gen
-from ray_tpu.models.gpt2 import GPT2Config
-from ray_tpu.models import gpt2
-from ray_tpu.serve.llm import GenerationEngine, llm_deployment
+from ray_tpu.serve.llm import llm_deployment
+
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
+# the cache-tile tests' engine: two slots of two tiles, chunks of 3
+TILES = dict(seed=2, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
+             prefill_buckets=(8, 160))
 
 
 @pytest.fixture
@@ -27,50 +29,39 @@ def serve_instance():
     ray_tpu.shutdown()
 
 
-def _one_shot(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray([prompt]),
-                       jnp.asarray([len(prompt)]), max_new_tokens=n)
-    return [int(t) for t in out[0]]
-
-
 def test_engine_matches_one_shot_under_continuous_batching():
-    cfg = GPT2Config.tiny(dtype=jnp.float32)
-    params = gpt2.init(cfg, jax.random.PRNGKey(0))
-    eng = GenerationEngine(
-        cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
-        prefill_buckets=(8, 16)).start()
+    eng, cfg, params = engine(
+        "gpt2", n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
+        prefill_buckets=(8, 16))
+    eng.start()
     try:
         prompts = [[3, 17, 5], [9, 2], [11, 4, 7, 1], [6], [8, 8, 3, 2, 1]]
         futs = [eng.submit(p, 8) for p in prompts]  # 5 requests, 2 slots
         got = [f.result(timeout=120) for f in futs]
     finally:
         eng.stop()
-    for p, g in zip(prompts, got):
-        assert g == _one_shot(params, cfg, p, 8), f"prompt {p}"
+    assert got == one_shot(params, cfg, prompts, 8)
     assert eng.stats()["total_requests"] == 5
 
 
 def test_engine_eos_and_max_new():
-    cfg = GPT2Config.tiny(dtype=jnp.float32)
-    params = gpt2.init(cfg, jax.random.PRNGKey(1))
-    ref = _one_shot(params, cfg, [5, 9, 2, 4], 12)
+    kw = dict(seed=1, n_slots=1, decode_chunk_steps=5, prefill_buckets=(8,))
+    eng2, cfg, params = engine("gpt2", max_new_tokens=3, **kw)
+    ref, = one_shot(params, cfg, [[5, 9, 2, 4]], 12)
     # EOS semantics: the stream stops at the FIRST occurrence of the eos
     # value (tiny random models cycle quickly, so derive the expectation
     # from wherever the chosen value first appears)
     eos = ref[-1]
     idx = ref.index(eos)
-    eng = GenerationEngine(
-        cfg, params, n_slots=1, max_new_tokens=12, decode_chunk_steps=5,
-        prefill_buckets=(8,), eos_id=eos).start()
+    eng, _, _ = engine("gpt2", max_new_tokens=12, eos_id=eos, **kw)
+    eng.start()
     try:
         out = eng.generate([5, 9, 2, 4], timeout=120)
     finally:
         eng.stop()
     assert out == ref[:idx + 1]  # stops AT the eos token
     # max_new cutoff
-    eng2 = GenerationEngine(
-        cfg, params, n_slots=1, max_new_tokens=3, decode_chunk_steps=5,
-        prefill_buckets=(8,)).start()
+    eng2.start()
     try:
         out2 = eng2.generate([5, 9, 2, 4], timeout=120)
     finally:
@@ -84,11 +75,7 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
     and the dispatch adds the whole padded slab to ``padded`` — counted on
     the engine thread from prompt lengths and scheduled tokens, no device
     read.  The cache itself is whole tiles."""
-    cfg = GPT2Config.tiny(dtype=jnp.float32, max_seq_len=256)
-    params = gpt2.init(cfg, jax.random.PRNGKey(2))
-    eng = GenerationEngine(  # never started: the test is the engine thread
-        cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
-        prefill_buckets=(8, 160))
+    eng, cfg, params = engine("gpt2", **TILES)  # never started
     assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
     assert eng.perf_stats()["cache_tiles"] == {
         "read_full": 0, "read_window": 0, "padded": 0, "flushed": 0,
@@ -114,8 +101,7 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
     assert last["padded"] == dispatches * 6 and dispatches >= 6
     for a, b in zip(seen, seen[1:]):
         assert a["read_full"] <= b["read_full"] and a["padded"] <= b["padded"]
-    for p, f in zip(prompts, futs):
-        assert f.result() == _one_shot(params, cfg, p, 8)
+    assert [f.result() for f in futs] == one_shot(params, cfg, prompts, 8)
 
 
 @pytest.mark.parametrize("length,flushed", [
@@ -131,26 +117,19 @@ def test_cache_tiles_counts_what_the_flushes_write(length, flushed):
     tile its chunk's columns fall in and, where they cross a 128-position
     boundary, the next (what the flush kernel writes a full layer a tensor,
     against ``padded``); a slot that sits the chunk out and a tick that
-    dispatches nothing add none."""
-    cfg = GPT2Config.tiny(dtype=jnp.float32, max_seq_len=256)
-    params = gpt2.init(cfg, jax.random.PRNGKey(2))
-    eng = GenerationEngine(  # never started: the test is the engine thread
-        cfg, params, n_slots=2, max_new_tokens=8, decode_chunk_steps=3,
-        prefill_buckets=(8, 160))
+    dispatches nothing add none.  (One engine for the five cases: its
+    counters are differenced.)"""
+    eng, cfg, params = shared_engine("gpt2", **TILES)
+    had = eng.perf_stats()["cache_tiles"]["flushed"]
     seen, prompt = [], [1 + i % 50 for i in range(length)]
     if not prompt:
         assert eng.step() is False
     else:
         fut = eng.submit(prompt, 8)
-        while not fut.done():
-            before = eng.perf_stats()["cache_tiles"]
-            eng.step()
-            after = eng.perf_stats()["cache_tiles"]
-            if after["padded"] > before["padded"]:  # a chunk was dispatched
-                seen.append(after["flushed"] - before["flushed"])
-        assert fut.result() == _one_shot(params, cfg, prompt, 8)
+        seen = [s["flushed"] for s in run_engine(eng, [fut]) if s["padded"]]
+        assert [fut.result()] == one_shot(params, cfg, [prompt], 8)
     assert seen == flushed
-    assert eng.perf_stats()["cache_tiles"]["flushed"] == sum(flushed)
+    assert eng.perf_stats()["cache_tiles"]["flushed"] - had == sum(flushed)
 
 
 def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
@@ -163,23 +142,15 @@ def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
     from ray_tpu.serve import llm
 
     monkeypatch.setattr(llm, "CALL_TOKENS", 16)
-    cfg = GPT2Config.tiny(dtype=jnp.float32)
-    params = gpt2.init(cfg, jax.random.PRNGKey(3))
-    eng = GenerationEngine(  # never started: the test is the engine thread
-        cfg, params, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
+    eng, cfg, params = engine(  # never started: the test is the engine thread
+        "gpt2", seed=3, n_slots=4, max_new_tokens=6, decode_chunk_steps=3,
         prefill_buckets=(8, 16))
     assert eng._rows == {8: 2, 16: 1} and eng._tick_tokens == 4 * 16
     lens = (3, 5, 12, 4, 9, 10, 2, 6, 7, 1, 11, 13, 8, 8, 8, 8)
     prompts = [[1 + (7 * i + j) % 90 for j in range(n)]
                for i, n in enumerate(lens)]
     futs = [eng.submit(p, 6) for p in prompts]
-    ticks = []
-    while not all(f.done() for f in futs):
-        before = eng.stats()["queued"]
-        eng.step()
-        if before - eng.stats()["queued"]:
-            ticks.append([[len(req.tokens) for _, _, req in admissions]
-                          for admissions, *_ in eng._pending.prefills])
+    ticks = [s["admitted"] for s in run_engine(eng, futs) if s["admitted"]]
     assert ticks == [[[3, 5], [12], [4]], [[9], [10], [2, 6]],
                      [[7, 1], [11], [13]], [[8, 8], [8, 8]]]
     assert eng.perf_stats()["prefill"] == {
@@ -189,8 +160,7 @@ def test_a_queue_longer_than_the_slots_is_admitted_in_narrow_calls(monkeypatch):
                "live_tokens": 55}}
     # a dense family routes nothing: no routing counts, no ``rows_computed``
     assert eng.perf_stats()["moe"]["prefill"] is None
-    for p, f in zip(prompts, futs):
-        assert f.result() == _one_shot(params, cfg, p, 6)
+    assert [f.result() for f in futs] == one_shot(params, cfg, prompts, 6)
 
 
 def test_llm_deployment_behind_serve(serve_instance):

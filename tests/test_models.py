@@ -84,12 +84,19 @@ def test_mlp_trains():
     assert float(mlp.accuracy(params, {"x": x, "y": y}, cfg)) > 0.7
 
 
-def test_dryrun_multichip_8():
-    """The driver's multi-chip validation path: full sharded train step
-    (fsdp/sp/tp axes + ring attention) on the 8-device CPU mesh."""
+@pytest.mark.parametrize("label", [
+    "gpt2 fsdp*sp*tp", "gpt2-moe pp*ep*dp", "llama tp*sp*fsdp",
+    "gpt2-125m-shape fsdp*sp*tp"])
+def test_dryrun_multichip_8(label):
+    """The driver's multi-chip validation path (``dryrun_multichip(8)``, a
+    case of it a case here): full sharded train step (fsdp/sp/tp axes + ring
+    attention; pp/ep/dp) on the 8-device CPU mesh."""
     import __graft_entry__ as g
 
-    g.dryrun_multichip(8)
+    cases = g.dryrun_cases(8)
+    assert label in cases and len(cases) == 4  # every case of it runs here
+    model, cfg, sizes, parity_atol = cases[label]
+    g._dryrun_one(model, cfg, sizes, 8, parity_atol=parity_atol, label=label)
 
 
 def test_llama_tiny_forward_and_gqa():

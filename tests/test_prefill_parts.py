@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_harness import engine, prefill_at, run_engine, tiny_model
 
 import ray_tpu.ops.attention  # noqa: F401  (the module, not ops' function)
 from ray_tpu._private import events as events_mod
@@ -18,6 +19,7 @@ from ray_tpu.ops import dsa
 from ray_tpu.serve import llm
 
 attention = sys.modules["ray_tpu.ops.attention"]
+pytestmark = pytest.mark.usefixtures("kept_engine_programs")
 
 CONTINUES = ["gpt2", "exaone_moe", "kimi_k2", "dots3_note"]
 # (tokens a part, the prompt's): parts under both tiny rings (16 positions for
@@ -27,25 +29,17 @@ PARTS = [(4, 23, jnp.float32), (8, 23, jnp.float32), (32, 37, jnp.float32),
          (8, 23, jnp.bfloat16)]
 
 
-def _model(family, dtype):
-    cfg = llm.make_config(family, "tiny", dtype=dtype)
-    return cfg, gen.family_of(cfg).init(cfg, jax.random.PRNGKey(0))
-
-
 def _in_parts(params, cfg, cache, prompt, part, slot, bound):
     """``prompt`` into ``slot`` of ``cache`` a ``part`` at a time; the last
     part's logits."""
     # ONE program for every part, the offset a runtime value (as the engine's)
-    one = jax.jit(lambda toks, lens, cache, at: gen.prefill_at(
-        params, cfg, toks, lens, cache, jnp.asarray([slot]), offsets=at,
-        bound=bound))
     for at in range(0, len(prompt), part):
         row = np.zeros((1, part), np.int32)
         own = prompt[at:at + part]
         row[0, :len(own)] = own
-        logits, cache = one(jnp.asarray(row), jnp.asarray([len(own)]), cache,
-                            jnp.asarray([at]))
-        cache.pop("routed", None)
+        logits, cache, _ = prefill_at(
+            params, cfg, jnp.asarray(row), jnp.asarray([len(own)]), cache,
+            jnp.asarray([slot]), offsets=jnp.asarray([at]), bound=bound)
     return logits, cache
 
 
@@ -56,14 +50,13 @@ def test_a_prompt_in_parts_leaves_what_the_whole_call_leaves(family, part, n, dt
     """The same cache (slab, ring, index keys; of a ring the entries that
     hold a position), the same ``pos``, the same last logits and first token
     as ONE call over the whole prompt, whatever the part."""
-    cfg, params = _model(family, dtype)
+    cfg, params = tiny_model(family, dtype=dtype)
     prompt = np.random.default_rng(1).integers(1, cfg.vocab_size, n).tolist()
     whole = np.zeros((1, 64), np.int32)
     whole[0, :n] = prompt
-    want_logits, want = jax.jit(lambda toks, cache: gen.prefill_at(
-        params, cfg, toks, jnp.asarray([n]), cache, jnp.asarray([1])))(
-            jnp.asarray(whole), gen.init_cache(cfg, 3, 96))
-    want.pop("routed", None)
+    want_logits, want, _ = prefill_at(
+        params, cfg, jnp.asarray(whole), jnp.asarray([n]),
+        gen.init_cache(cfg, 3, 96), jnp.asarray([1]))
     # the static bound: 64 cached positions, whole parts of every size here
     got_logits, got = _in_parts(
         params, cfg, gen.init_cache(cfg, 3, 96), prompt, part, 1, 64)
@@ -89,7 +82,7 @@ def test_a_prompt_in_parts_leaves_what_the_whole_call_leaves(family, part, n, dt
 
 def test_rows_of_one_call_continue_at_their_own_offsets():
     """A call's rows are parts of different prompts, each at its own offset."""
-    cfg, params = _model("exaone_moe", jnp.float32)
+    cfg, params = tiny_model("exaone_moe")
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, cfg.vocab_size, n).tolist() for n in (20, 11)]
     cache = gen.init_cache(cfg, 3, 96)
@@ -100,7 +93,7 @@ def test_rows_of_one_call_continue_at_their_own_offsets():
     _, cache = _in_parts(params, cfg, cache, prompts[1][:8], 8, 1, 32)
     rows = np.zeros((2, 8), np.int32)
     rows[0, :4], rows[1, :3] = prompts[0][16:], prompts[1][8:]
-    logits, cache = gen.prefill_at(
+    logits, cache, _ = prefill_at(
         params, cfg, jnp.asarray(rows), jnp.asarray([4, 3]), cache,
         jnp.asarray([0, 1]), offsets=jnp.asarray([16, 8]), bound=32)
     assert np.asarray(cache["pos"][:2]).tolist() == [20, 11]
@@ -109,9 +102,9 @@ def test_rows_of_one_call_continue_at_their_own_offsets():
 
 
 def test_a_family_with_recurrent_layers_keeps_whole_prompts():
-    cfg, params = _model("granite_hybrid", jnp.float32)
+    cfg, params = tiny_model("granite_hybrid")
     assert not gen.can_continue(cfg)
-    assert all(gen.can_continue(_model(f, jnp.float32)[0]) for f in CONTINUES)
+    assert all(gen.can_continue(tiny_model(f)[0]) for f in CONTINUES)
     with pytest.raises(AssertionError, match="prefilled whole"):
         gen.prefill_at(params, cfg, jnp.ones((1, 8), jnp.int32),
                        jnp.asarray([8]), gen.init_cache(cfg, 2, 32),
@@ -223,19 +216,12 @@ def test_flash_kernel_over_a_band_against_the_blocks(shape):
 
 # -- the engine ---------------------------------------------------------------
 
-FAMILY_KW = {"gpt2": {}, "exaone_moe": {"experts_held": (4, 8)},
-             "kimi_k2": {"experts_held": (4, 8)},
-             "dots3_note": {"experts_held": (4, 8)}}
-
-
 def _engine(family, part, monkeypatch, **kw):
     monkeypatch.setattr(llm, "PREFILL_PART_TOKENS", part)
-    mod = gen.FAMILIES[family]
-    cfg = mod.Config.tiny(dtype=jnp.float32, max_seq_len=256, **FAMILY_KW[family])
-    params = mod.init(cfg, jax.random.PRNGKey(3))
-    kw = {"n_slots": 4, "max_new_tokens": 14, "decode_chunk_steps": 4,
-          "prefill_buckets": (8, 16, 64), **kw}
-    return llm.GenerationEngine(cfg, params, **kw), cfg
+    eng, cfg, _ = engine(family, seed=3, **{
+        "n_slots": 4, "max_new_tokens": 14, "decode_chunk_steps": 4,
+        "prefill_buckets": (8, 16, 64), **kw})
+    return eng, cfg
 
 
 def _log_dispatches(eng):
@@ -262,14 +248,6 @@ def _log_dispatches(eng):
     return log
 
 
-def _step_until(eng, futs):
-    for _ in range(400):
-        if all(f.done() for f in futs):
-            return
-        eng.step()
-    raise AssertionError("the engine did not finish")
-
-
 @pytest.mark.parametrize("family", CONTINUES)
 def test_long_prompts_between_decode_chunks_token_for_token(family, monkeypatch):
     """Two rows decode; a prompt of 50 tokens and one of 33 arrive with a
@@ -288,7 +266,7 @@ def test_long_prompts_between_decode_chunks_token_for_token(family, monkeypatch)
         eng.step()  # the two short prompts are in and decoding
         log = _log_dispatches(eng)
         late = [eng.submit(p, m) for p, (_, m) in zip(prompts[2:], sizes[2:])]
-        _step_until(eng, early + late)
+        run_engine(eng, early + late)
         eng.stop()
         answers[part] = [f.result(1) for f in early + late]
         stats = eng.perf_stats()
@@ -333,7 +311,7 @@ def test_a_lone_long_prompts_parts_go_back_to_back(monkeypatch):
     assert offsets == [8, 16, 24, 32, 40, 48]
     assert eng.perf_stats()["prefill"]["parts"]["calls"] == 7
     assert eng.perf_stats()["prefill"]["8"]["calls"] == 0
-    _step_until(eng, [fut])
+    run_engine(eng, [fut])
     want = fut.result(1)
     eng.stop()
 
@@ -345,7 +323,7 @@ def test_a_lone_long_prompts_parts_go_back_to_back(monkeypatch):
         assert eng._pending.chunk_dev is None and not eng._pending.rows
     assert log == ["part"] * 6 and eng._slots[0].prefilled == 48
     assert eng.stats()["active_slots"] == 1
-    _step_until(eng, [fut])
+    run_engine(eng, [fut])
     assert log == ["part"] * 7 + ["chunk"]
     assert fut.result(1) == want
     ticks = eng.perf_stats()["ticks"]
@@ -414,7 +392,7 @@ def test_the_cache_holds_whole_parts_and_one_program_serves_every_offset(
     assert llm.part_bound(64) == 72
     assert eng._max_len == llm.cache_positions(64, 14, 4) >= 72 + 14 + 4
     futs = [eng.submit(list(range(1, n + 1)), 3) for n in (64, 49, 25)]
-    _step_until(eng, futs)
+    run_engine(eng, futs)
     eng.stop()
     assert eng._part_jit._cache_size() == 1
     assert eng.perf_stats()["prefill"]["parts"]["calls"] == 3 + 3 + 2

@@ -209,8 +209,9 @@ def test_sixteen_host_slice_chaos_recovery(slice_fleet, tmp_path):
     # slice_degraded finding clears (gang_restart remains as the
     # explanation of what happened, which is the point of the recorder)
     closed = diagnose(events)
-    assert "slice_degraded" not in [f["rule"] for f in closed], \
-        [f["rule"] for f in closed]
+    assert "slice_degraded" not in [f["rule"] for f in closed], [
+        (e["message"], e.get("entity_id"), e.get("ts"), e.get("data"))
+        for e in events if e.get("message", "").startswith("slice ")]
 
     # the failure-domain view agrees: only the healthy replacement remains
     rows = state.list_slices()
@@ -271,6 +272,24 @@ def test_slice_degraded_rule_reopens_when_replacement_fails():
     # ...and its success closes the incident for good
     evs.append(_ev("autoscaler", "slice replaced", "s1", 104.0))
     assert "slice_degraded" not in [x["rule"] for x in diagnose(evs)]
+
+
+def test_slice_degraded_rule_ignores_a_late_death_of_a_replaced_slice():
+    """Degraded, replaced, and then a member of the OLD slice reported dead
+    (its death lands late on a loaded head): the slice is gone whole, so
+    nothing re-opens; the replacement's own degradation is an incident of
+    its own."""
+    evs = [
+        _ev("node", "slice degraded", "s1", 100.0, dead_node="h3"),
+        _ev("autoscaler", "slice replacement started", "s1", 101.0),
+        _ev("autoscaler", "slice replaced", "s1", 110.0, replacement="s2"),
+        _ev("node", "slice degraded", "s1", 111.0, dead_node="h7"),
+    ]
+    assert "slice_degraded" not in [x["rule"] for x in diagnose(evs)]
+    evs.append(_ev("node", "slice degraded", "s2", 120.0, dead_node="h20"))
+    found = {x["rule"]: x for x in diagnose(evs)}
+    assert "s2" in found["slice_degraded"]["summary"]
+    assert "s1" not in found["slice_degraded"]["summary"]
 
 
 def test_slice_degraded_rule_silent_on_healthy_events():
