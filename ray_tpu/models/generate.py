@@ -1,16 +1,18 @@
 """KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
-EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note).
+EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note, EvaByte).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
 
-- **Four kinds of cache** (:func:`init_cache`), chosen by what the family's
+- **Kinds of cache** (:func:`init_cache`), chosen by what the family's
   config says of its layers, never by its name; three hold something per
   POSITION (one of them, the latent row, in a slab or in a ring, and with a
-  second tensor beside it where the layer SELECTS what it reads), the fourth
-  per REQUEST:
+  second tensor beside it where the layer SELECTS what it reads: five kinds
+  of cached tensor so far), the fourth per REQUEST; every one of those is
+  written once and keeps its meaning.  The last (a SIXTH kind of cached
+  tensor) is COMPACTED while the request is live:
 
   1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
      KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
@@ -56,6 +58,32 @@ first-class TPU path, designed for XLA:
      over the slots that were active when the chunk began); a cut chunk has
      advanced it ``n`` steps; there is nothing to flush.  The family's other
      layers keep K and V in the slab (1).
+  5. a WINDOW AND ITS SUMMARIES for a layer that compacts
+     (``cfg.summary_cache``: ``(window, chunk)``; :mod:`ray_tpu.models.evabyte`,
+     :mod:`ray_tpu.ops.eva`), two pairs of tensors a layer that trade places
+     as a request grows.  (a) The CURRENT window's exact ``k``, ``v`` ``[L, B,
+     KV, dh, window + slack]`` (:func:`window_positions`), position ``j`` at
+     place ``j % window``, of which a query at ``i`` reads places ``0 .. i %
+     window``: BLOCK-ALIGNED, not a ring: when ``i`` reaches a multiple of the
+     window every place is dead at once (none of :func:`_ring_mask`,
+     :func:`_ring_holds`, :func:`ring_positions` describes it).  (b) The
+     SUMMARIES ``ks``, ``vs`` ``[L, B, KV, dh, rows]`` (:func:`summary_rows`):
+     one pooled key and value a head a ``chunk`` of positions of every window
+     the slot has FILLED, of which a query at ``i`` reads the first ``(i //
+     window) x (window // chunk)`` rows.  The moment a slot's window fills, its
+     positions are pooled (:func:`ray_tpu.ops.eva.pool_chunks`, by the layer's
+     learned vectors), appended to the slab, and the window starts again at
+     place 0: in a prefill call for every window the call's tokens fill
+     (:func:`_keep_compacted`), in decode WITH THE CHUNK'S FLUSH
+     (:func:`_roll_over`), which is why no chunk may straddle a window's end:
+     the caller cuts the chunk there (``n``; the serve engine's second reason
+     for a cut; :func:`generate` does the same on the device).  A decode step
+     reads both through :func:`_cache_scores` (lowered for a TPU the ragged
+     kernel, twice, each over its own live tiles) and merges them
+     (:func:`ray_tpu.ops.eva.merge`) before the chunk-local columns join; a
+     prompt's PART (whole windows, at an offset that is a multiple of the
+     window) attends the cached summaries laid ahead of its own keys
+     (:func:`ray_tpu.ops.eva.windowed_attention`).
 
 - **A prefill that continues** (:func:`prefill_at`'s ``offsets``): a prompt
   need not go into its slot in ONE call.  A call's rows may be PARTS: row
@@ -173,6 +201,7 @@ from jax import lax
 
 from ray_tpu.models import (
     dots3_note,
+    evabyte,
     exaone_moe,
     gpt2,
     granite_hybrid,
@@ -180,7 +209,7 @@ from ray_tpu.models import (
     llama,
 )
 from ray_tpu.models.transformer import _attend
-from ray_tpu.ops import dsa, ssm
+from ray_tpu.ops import dsa, eva, ssm
 from ray_tpu.ops.attention import (
     DECODE_TILE,
     band_attention_after,
@@ -217,10 +246,14 @@ from ray_tpu.ops.attention import (
 # recurrent layers (``params[kind]``, leaves ``[L_kind, ...]``) beside a list
 # of the others, ``cfg.layer_runs`` says which layers follow each other, its
 # ``block`` takes the layer's ``kind`` and the mixer's middle, and the loops
-# roll each run of recurrent layers.
+# roll each run of recurrent layers.  A family whose layers keep an exact
+# window and pooled summaries of the windows before it says so in its config
+# (``summary_cache``: window and chunk), keeps its parameters stacked, and has
+# ``pooling(p)``: a layer's (or the stack's) pooling vectors, which the prefill
+# hands the attention middle and the decode's roll-over pools a full window by.
 FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
             "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid,
-            "dots3_note": dots3_note}
+            "dots3_note": dots3_note, "evabyte": evabyte}
 
 # a layer's entry in :func:`layer_windows` that attends no position at all
 RECURRENT = granite_hybrid.RECURRENT
@@ -275,6 +308,37 @@ def index_cache(cfg) -> Optional[Tuple[int, int]]:
     return getattr(cfg, "index_cache", None)
 
 
+def summary_cache(cfg) -> Optional[Tuple[int, int]]:
+    """For a family whose layers keep an EXACT window and a compressed memory
+    of what came before it (:mod:`ray_tpu.ops.eva`): ``(window, chunk)``: the
+    positions of a block-aligned window, and how many of them one pooled key
+    and value stand for once the window is full (``cfg.summary_cache``).
+    None: a cached position is never rewritten."""
+    return getattr(cfg, "summary_cache", None)
+
+
+# what :func:`init_cache` holds of a compacting layer beside its window's
+# ``k``, ``v``: the pooled keys and values of the windows before it
+SUMMARIES = ("ks", "vs")
+
+
+def window_positions(window: int) -> int:
+    """Places a slot's exact window holds: the window and a tile of slack (the
+    window again where it is shorter than a tile), because a chunk's flush
+    writes all its ``steps`` columns from the slot's place on, those a cut
+    chunk did not run too, and the last may start one place short of the
+    window's end.  A decode chunk needs ``steps`` at most the slack."""
+    return window + min(window, DECODE_TILE)
+
+
+def summary_rows(cfg, max_len: int) -> int:
+    """Summary rows a slot of ``max_len`` positions can come to hold: a row a
+    chunk of every window it can FILL (whole tiles where a window is)."""
+    window, chunk = summary_cache(cfg)
+    rows = max(1, max_len // window) * (window // chunk)
+    return -(-rows // DECODE_TILE) * DECODE_TILE if window % DECODE_TILE == 0 else rows
+
+
 def attention_scale(cfg, window: bool = False) -> Optional[float]:
     """A family's own score scale for one kind of layer (None: ``dh **
     -0.5``)."""
@@ -317,7 +381,12 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     ``c_ring`` ``[L_window, B, 1, row, R]``.  The recurrent layers, for a family that
     has them (:func:`state_cache`): ``ssm`` ``[L_state, B, ...]`` and ``conv``
     ``[L_state, inputs kept, B, width]`` (the slots beside the width, so that
-    the chip pads neither), no positions at all."""
+    the chip pads neither), no positions at all.  A family whose layers
+    COMPACT what they cache (:func:`summary_cache`; the sixth kind of cached
+    tensor): ``k``/``v`` ``[L, B, KV, dh, window + slack]``, the CURRENT
+    window's exact keys and values (:func:`window_positions`), and ``ks``/``vs``
+    ``[L, B, KV, dh, rows]``, one pooled key and value a chunk of every window
+    the slot has filled (:func:`summary_rows` of ``max_len``)."""
     windows = layer_windows(cfg)
     n_full, n_state = windows.count(0), windows.count(RECURRENT)
     n_window = len(windows) - n_full - n_state
@@ -334,6 +403,11 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
         return cache
     slab = lambda layers, length: jnp.zeros(  # noqa: E731
         (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
+    if summary_cache(cfg):
+        held, rows = window_positions(summary_cache(cfg)[0]), summary_rows(cfg, max_len)
+        return {"k": slab(n_full, held), "v": slab(n_full, held),
+                "ks": slab(n_full, rows), "vs": slab(n_full, rows),
+                "pos": jnp.zeros((n_slots,), jnp.int32)}
     cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
              "pos": jnp.zeros((n_slots,), jnp.int32)}
     if n_window:
@@ -623,13 +697,17 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     B, Tp = tokens.shape
     windows = layer_windows(cfg)
     part = offsets is not None
+    compact = summary_cache(cfg)
     if part:
         assert can_continue(cfg), "a recurrent layer's prompt is prefilled whole"
         offsets = offsets.astype(jnp.int32)
         positions = offsets[:, None] + jnp.arange(Tp)         # [B, Tp]
+        if compact:  # a part reads the summaries, a row a chunk of ``bound``
+            bound = bound and bound // compact[1]
         # what the cache holds of the rows' slots, by kind of layer
         ahead = {False: tuple(cache[n][:, slots, :, :, :bound]
-                              for n in cached_tensors(cfg)),
+                              for n in (SUMMARIES if compact
+                                        else cached_tensors(cfg))),
                  True: tuple(cache[n][:, slots]
                              for n in cached_tensors(cfg, True)
                              ) if max(windows) > 0 else ()}
@@ -637,13 +715,22 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
-    def attend(q, k, v, row=None, index=None, window=0, held=None):
+    def attend(q, k, v, row=None, index=None, window=0, held=None, pool=None):
         # the causal (or band) attention of training; kept: this layer's k,
         # v, or the cache row a latent family's block hands over; a layer
         # that selects attends the positions its index puts first, and its
         # index keys are kept beside the row.  ``held``: a PART's layer, what
-        # the cache holds ahead of it, a tensor each of ``kept``
+        # the cache holds ahead of it, a tensor each of ``kept``.  A layer
+        # that compacts (``pool``: its pooling vectors) attends a window at a
+        # time, each with the summaries of the windows before it, and keeps
+        # its k, v and every window's summaries
         scale = attention_scale(cfg, bool(window))
+        if compact:
+            W, c = compact
+            out, pooled = eva.windowed_attention(
+                q, k, v, *pool, window=W, chunk=c, scale=scale, held=held,
+                rows0=offsets // W * (W // c) if part else None)
+            return out, (k, v, *(pooled or ()))
         kept = ((k, v) if row is None else (row,) if index is None
                 else (row, index[2]))
         if held is not None:
@@ -672,8 +759,9 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     if "blocks" in params:  # layers alike, stacked: one rolled loop
         def body(h, p):
             p, *held = p
+            pool = {"pool": fam.pooling(p)} if compact else {}
             h, _, kv = fam.block(
-                h, p, cfg, partial(attend, held=held or None), positions)
+                h, p, cfg, partial(attend, held=held or None, **pool), positions)
             return h, kv
 
         # ks [L, B, KV, Tp, dh]
@@ -707,7 +795,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     rows = lengths.astype(jnp.int32)
     out = {**cache, "pos": cache["pos"].at[slots].set(
         rows + offsets if part else rows)}
-    if part:
+    if compact:
+        out.update(_keep_compacted(cfg, cache, full, slots, rows,
+                                   offsets if part else None))
+    elif part:
         # a row at a time, each at its own offset (a call is a row or a few);
         # a ring keeps the entries the part did not reach: the newest position
         # of an entry (_ring_holds over prefix-and-part) is the part's or older
@@ -755,6 +846,43 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     last = fam.unembed(params, jnp.take_along_axis(
         x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
     return last[:, 0, :], out
+
+
+def _keep_compacted(cfg, cache, kept, slots, lengths, offsets):
+    """What a prefill call leaves of a compacting family's layers (kept: ``k,
+    v [L, B, KV, T, dh]`` and, where the call is whole windows, every window's
+    summaries ``[L, B, KV, dh, windows x rows a window]``): the EXACT window is
+    the one row ``b``'s next position falls in (the call's last where the row
+    ends it: its places are then all dead, ``pos % window == 0``), position
+    ``j`` at place ``j % window``; the summaries go in at the row a window,
+    every window's: those of a window the row did not fill lie beyond what
+    ``pos`` lets a query read, and the roll-over that fills it rewrites them.
+    ``offsets`` (None: whole prompts): the call's rows are PARTS, written a
+    row at a time at their own summary row."""
+    window, chunk = summary_cache(cfg)
+    k, v, *pooled = kept
+    T = k.shape[3]
+    tw = min(T, window)
+    at = jnp.minimum(lengths // tw, T // tw - 1)
+    out = {}
+    for name, t in zip(("k", "v"), (k, v)):
+        held = jnp.take_along_axis(
+            t.reshape(*t.shape[:3], T // tw, tw, t.shape[-1]),
+            at[None, :, None, None, None, None], axis=3)[:, :, :, 0]
+        out[name] = cache[name].at[:, slots, :, :, :tw].set(
+            jnp.swapaxes(held, 3, 4).astype(cache[name].dtype))
+    for name, t in zip(SUMMARIES, pooled):
+        t = t.astype(cache[name].dtype)
+        if offsets is None:
+            n = min(t.shape[-1], cache[name].shape[-1])
+            out[name] = cache[name].at[:, slots, :, :, :n].set(t[..., :n])
+            continue
+        out[name] = cache[name]
+        for b in range(t.shape[1]):
+            out[name] = lax.dynamic_update_slice(
+                out[name], t[:, b:b + 1],
+                (0, slots[b], 0, 0, offsets[b] // chunk))
+    return out
 
 
 def _selection_counts(cfg, **counts) -> Dict[str, jax.Array]:
@@ -863,7 +991,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     window = max(windows)
     names = cached_tensors(cfg)
     ring_names = cached_tensors(cfg, True) if window > 0 else ()
-    latent, index = latent_cache(cfg), index_cache(cfg)
+    latent, index, compact = latent_cache(cfg), index_cache(cfg), summary_cache(cfg)
     old = tuple(cache[name] for name in names)
     rings = tuple(cache[name] for name in ring_names)
     S = old[0].shape[-1]
@@ -873,19 +1001,32 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # pos0 + steps <= S (the engine's bucket + max_new + chunk); a ring's
     # flush, steps <= window + 1 (ring_positions)
     assert steps <= S and (not window or steps <= window + 1), (steps, S, window)
+    assert not compact or steps <= S - compact[0], (steps, S, compact)
     if steps == 0:
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
     pos0 = cache["pos"]
+    # where the chunk's columns go: a slot's position, or its place in the
+    # exact window of a family that compacts (whose caller ends a chunk where
+    # the nearest slot's window ends: no chunk straddles one)
+    place0 = pos0 % compact[0] if compact else pos0
     # what a slot attends of the cache, fixed for the chunk: the positions
     # below where it stood, nothing for a slot that sits the chunk out
-    live = jnp.where(active, pos0, 0)
+    live = jnp.where(active, place0, 0)
     # the kernels' work lists, functions of who is active and where alone:
     # built once here, for every layer, step and tensor
     plan = to_flush = None
     if S % DECODE_TILE == 0 and all(t.shape[3] % 8 == 0 for t in old):
         plan = ragged_decode_plan(live, S // DECODE_TILE)
         if steps <= DECODE_TILE:
-            to_flush = cache_flush_plan(active, pos0, steps, S, written=n)
+            to_flush = cache_flush_plan(active, place0, steps, S, written=n)
+    # a family that compacts: the summaries of the windows before a slot's
+    # own, ``rows a window`` for each it has filled, read as the window is
+    sums = tuple(cache[name] for name in SUMMARIES) if compact else ()
+    far = far_plan = None
+    if compact:
+        far = jnp.where(active, pos0 // compact[0] * (compact[0] // compact[1]), 0)
+        if plan is not None and sums[0].shape[-1] % DECODE_TILE == 0:
+            far_plan = ragged_decode_plan(far, sums[0].shape[-1] // DECODE_TILE)
     # the chunk-local buffers, one a cached tensor: [layers of its kind,
     # steps, B, KV, dh], a layer's own at its place among its kind
     local = tuple(jnp.zeros((t.shape[0], steps, B, *t.shape[2:4]), t.dtype)
@@ -917,6 +1058,13 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
             mine = slice(len(names), None) if w else slice(0, len(names))
 
             def cached(q, keep=None):
+                if compact:
+                    with jax.named_scope("attention.eva_window"):
+                        near = _cache_scores(q, *old, at, live, plan, scale)
+                    with jax.named_scope("attention.eva_summary"):
+                        rest = _cache_scores(q, *sums, at, far, far_plan, scale)
+                    with jax.named_scope("attention.eva_merge"):
+                        return eva.merge(near, rest)
                 if latent and w:
                     return tuple(a[:, None] for a in latent_slab_attention(
                         q[:, 0], rings[0], at, _ring_mask(live, pos, w, ring),
@@ -1053,7 +1201,11 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
 
     out = {**cache, "pos": pos, **dict(zip(("ssm", "conv"), held))}
     for name, big, loc in zip(names, old, locs):
-        out[name] = _flush(big, loc, pos0, to_flush)
+        out[name] = _flush(big, loc, place0, to_flush)
+    if compact:
+        out.update(zip(SUMMARIES, _roll_over(
+            cfg, fam.pooling(params["blocks"]), out["k"], out["v"], sums,
+            pos, pos != pos0)))
     if rings:
         # the rings, once a chunk and whole: column t of slot b goes to entry
         # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other
@@ -1068,6 +1220,34 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         out["routed"] = routed if n is not None else jax.tree.map(
             lambda a: a.sum(0), routed)
     return emitted.T, out, active, key
+
+
+def _roll_over(cfg, pool, k, v, sums, pos, moved):
+    """The compaction of the slots whose window the chunk FILLED (``moved``
+    and now at a multiple of the window): the window's exact ``k, v [L, B, KV,
+    dh, places]`` pooled a chunk at a time (:func:`ray_tpu.ops.eva.pool_chunks`,
+    every layer at once: ``pool``, the stacked layers' vectors) into the rows
+    ``[(w - 1) R, w R)`` of the slot's summaries, in place; the window's
+    places are dead from here on (``pos % window == 0``) and the next chunk's
+    flush starts again at place 0.  A loop over the slots that rolled alone:
+    none, most chunks, and then it costs nothing."""
+    window, chunk = summary_cache(cfg)
+    rolled = moved & (pos % window == 0)
+    order = jnp.argsort(~rolled)  # the slots that rolled first
+
+    def roll(j, sums):
+        b = order[j]
+        with jax.named_scope("attention.eva_pool"):
+            held = (lax.dynamic_slice_in_dim(t, b, 1, 1)[:, 0, :, :, :window]
+                    for t in (k, v))
+            pooled = eva.pool_chunks(
+                *held, *pool, chunk=chunk,
+                scale=attention_scale(cfg) or cfg.head_dim ** -0.5)
+            return tuple(lax.dynamic_update_slice(
+                s, p[:, None], (0, b, 0, 0, (pos[b] - window) // chunk))
+                for s, p in zip(sums, pooled))
+
+    return lax.fori_loop(0, rolled.sum(), roll, tuple(sums))
 
 
 def generate(params, cfg, prompts: jax.Array, lengths: jax.Array, *,
@@ -1091,8 +1271,39 @@ def generate(params, cfg, prompts: jax.Array, lengths: jax.Array, *,
     active = jnp.ones((B,), bool)
     if eos_id is not None:
         active = active & (first != eos_id)
-    rest, _, _, _ = decode_chunk(
-        params, cfg, cache, first, active, key,
-        steps=max_new_tokens - 1, temperature=temperature, top_k=top_k,
-        eos_id=eos_id)
+    sampling = dict(temperature=temperature, top_k=top_k, eos_id=eos_id)
+    if summary_cache(cfg) and max_new_tokens > 1:
+        rest = _decode_to_window_ends(
+            params, cfg, cache, first, active, key, max_new_tokens - 1, sampling)
+    else:
+        rest, _, _, _ = decode_chunk(
+            params, cfg, cache, first, active, key,
+            steps=max_new_tokens - 1, **sampling)
     return jnp.concatenate([first[:, None], rest], axis=1)
+
+
+def _decode_to_window_ends(params, cfg, cache, first, active, key, total: int,
+                           sampling: dict) -> jax.Array:
+    """:func:`generate`'s answer for a family that compacts: no chunk may
+    straddle a slot's window end (:func:`decode_chunk`), so the ``total``
+    steps run as cut chunks, each ending where the nearest active slot's
+    window does (the serve engine's rule, here on the device: one program)."""
+    window = summary_cache(cfg)[0]
+    steps = min(total, window_positions(window) - window)
+
+    def chunk(carry):
+        done, cache, tok, active, key, emitted = carry
+        left = jnp.where(active, window - cache["pos"] % window, steps)
+        n = jnp.minimum(jnp.minimum(steps, total - done), left.min())
+        out, cache, active, key = decode_chunk(
+            params, cfg, cache, tok, active, key, steps=steps, n=n, **sampling)
+        # the columns past ``n`` repeat the last token; the next chunk's
+        # overwrite them
+        return (done + n, cache, out[:, -1], active, key,
+                lax.dynamic_update_slice(emitted, out, (0, done)))
+
+    emitted = jnp.zeros((first.shape[0], total + steps), jnp.int32)
+    *_, emitted = lax.while_loop(
+        lambda carry: carry[0] < total, chunk,
+        (jnp.int32(0), cache, first, active, key, emitted))
+    return emitted[:, :total]

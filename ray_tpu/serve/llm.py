@@ -16,7 +16,12 @@ is part of the framework here:
   iteration.  A chunk ends where the nearest live request ends: when one has
   fewer tokens left than the chunk is long, the CUT chunk runs just those
   steps (the same step body under a runtime bound: one further program
-  whatever the bound), so no answer waits for steps nobody needs.  A prompt
+  whatever the bound), so no answer waits for steps nobody needs.  A family
+  whose cache COMPACTS itself (``generate.summary_cache``: an exact window
+  pooled into summaries when it fills) gives the cut a second reason: a chunk
+  also ends where the nearest live slot's WINDOW ends, because the roll-over
+  (pool, append, restart at place 0) runs with the chunk's flush and no chunk
+  may straddle it.  A prompt
   longer than one PART (:data:`PREFILL_PART_TOKENS`) goes into its slot a
   part at a time, each part attending what the earlier ones left in the cache
   (``generate.prefill_at``'s ``offsets``: one further program whatever the
@@ -24,6 +29,10 @@ is part of the framework here:
   a pasted document stalls no live stream for longer than one part takes.
   Buckets above a part are never called, so their programs are never built; a
   family with recurrent layers keeps whole prompts (``generate.can_continue``).
+  Parts start at position 0 and every part but a prompt's last is WHOLE, so a
+  part's offset is a multiple of the part: a compacting family's windows (a
+  part is one or more whole windows: checked where the engine is built) line
+  up with the parts for free, and only because of that.
 - :func:`llm_deployment` — wraps the engine in a Serve deployment on a
   ``num_tpus`` replica; requests block on a future the engine thread
   resolves, so Serve's threaded replica concurrency (not the engine)
@@ -490,6 +499,14 @@ class GenerationEngine:
         self._part: Optional[int] = PREFILL_PART_TOKENS if (
             gen.can_continue(cfg)
             and self.buckets[-1] > PREFILL_PART_TOKENS) else None
+        # a family whose cache compacts itself: (window, chunk), or None.  Its
+        # chunks end where the nearest live slot's window does (``step``), and
+        # a part is whole windows (parts are whole and start at 0, so their
+        # offsets are multiples of the window: ``generate.prefill_at``)
+        self._compact = gen.summary_cache(cfg)
+        assert not (self._compact and self._part
+                    and self._part % self._compact[0]), (
+            "a part has to be whole windows", self._part, self._compact)
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -513,6 +530,26 @@ class GenerationEngine:
         self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
         self._cache_tiles = {"read_full": 0, "read_window": 0, "padded": 0,
                              "flushed": 0}
+        if self._compact:
+            # a compacting family's own, cumulative like the rest and counted
+            # at dispatch on the host (they ride HERE because a traced
+            # replica of the benchmark reads this key at the trace's two
+            # ends; ``perf_stats()["eva"]`` is the same numbers): tiles of the
+            # exact window and of the summaries the decode steps read (a layer
+            # a step: ``read_full`` is their sum a dispatch; ``tile_steps``:
+            # the same times the steps each dispatch ran, beside the live rows
+            # times those steps, ``row_steps``), the window
+            # places and summary rows those tiles hold and the ones the
+            # queries MAY attend (summed over the steps), the windows the
+            # decode rolled over and the chunks that pooled, the windows the
+            # prefills pooled, the chunks cut for a window's end, and the
+            # steps and dispatches all of it is over
+            self._cache_tiles.update(dict.fromkeys((
+                "eva_window_tiles", "eva_summary_tiles", "eva_tile_steps",
+                "eva_row_steps", "eva_read_positions",
+                "eva_attendable_positions", "eva_rollovers",
+                "eva_chunks_pooled", "eva_prefill_windows_pooled",
+                "eva_window_cuts", "eva_steps", "eva_dispatches"), 0))
         # cumulative, by prefill bucket: calls, the rows and tokens they were
         # wide, and the prompts and prompt tokens among those
         # ... and, under "parts" for an engine that splits prompts, the same
@@ -529,6 +566,12 @@ class GenerationEngine:
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
+        # tiles of the padded slab a full layer a slot (a compacting family:
+        # its window's and its summaries')
+        self._slab_tiles = sum(
+            -(-self.cache[name].shape[-1] // DECODE_TILE)
+            for name in (("k", "ks") if self._compact
+                         else gen.cached_tensors(cfg)[:1]))
         # bytes of one tile of a layer of each kind, from the cache's own
         # shapes (k and v per KV head, or one latent row a position, with its
         # index key where the layer selects), so that no reader of the
@@ -770,6 +813,7 @@ class GenerationEngine:
             moe = jax.tree.map(lambda a: np.asarray(a).tolist(), self._routed)
             state = dict(self._state) if self._state else None
             dsa = self._selection_stats()
+            compacting = self._compaction_stats()
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
             stages = tracing.span_stats(STAGES + PER_GAP)
@@ -820,6 +864,12 @@ class GenerationEngine:
             # they came in.  Counted on the device, beside the routing counts
             # (the same numbers as ``moe[phase]["dsa_*"]``).  Cumulative
             **({"dsa": dsa} if dsa else {}),
+            # a family whose cache compacts itself (ray_tpu.ops.eva): the
+            # window and summary tiles the decode steps read, the positions
+            # in them against those the queries may attend, the roll-overs
+            # and the chunks they pooled, the windows the prefills pooled,
+            # the chunks cut for a window's end (``__init__``).  Cumulative
+            **({"eva": compacting} if compacting else {}),
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -949,6 +999,14 @@ class GenerationEngine:
                 tally["padded_tokens"] += self._rows[b] * b
                 tally["prompts"] += len(batch)
                 tally["live_tokens"] += sum(len(r.tokens) for _, r in batch)
+            if self._compact:  # the windows these calls fill and pool
+                window = self._compact[0]
+                # (first position, tokens) of every row: a part's, a prompt's
+                spans = [batch[2:] if b is None else (0, min(len(r.tokens), b))
+                         for b, batch in calls
+                         for _, r in ([(None, None)] if b is None else batch)]
+                self._cache_tiles["eva_prefill_windows_pooled"] += sum(
+                    (first + n) // window - first // window for first, n in spans)
         return [self._part_call(*batch) if b is None
                 else self._prefill_call(b, batch) for b, batch in calls]
 
@@ -1093,6 +1151,18 @@ class GenerationEngine:
                 # prefill's token, is freed below and counts for nothing)
                 left = [req.max_new - req.scheduled for _, req in rows]
                 n = min([self.chunk] + [m for m in left if m > 0])
+                # a slot stands at prompt + scheduled - 1 (the last sampled
+                # token is not in the cache yet); EOS, which only the
+                # device has seen, can only leave it lower
+                stands = [len(req.tokens) + req.scheduled - 1 for _, req in rows]
+                if self._compact:
+                    # ... and to where the nearest live slot's window ends:
+                    # the roll-over runs with the flush (a row that EOS
+                    # stopped stands lower and is nobody's answer any more)
+                    window = self._compact[0]
+                    ends = min(window - at % window for at in stands)
+                    self._cache_tiles["eva_window_cuts"] += ends < n
+                    n = min(n, ends)
                 args = (self.params, self.cache, self._last_tok_dev,
                         jnp.asarray(active), self._key)
                 (chunk_dev, self.cache, self._last_tok_dev, self._key,
@@ -1109,10 +1179,8 @@ class GenerationEngine:
             # (completion timing is deterministic; EOS only finishes a
             # request EARLIER, confirmed at drain)
             with self._lock:
-                # a slot stands at prompt + scheduled - 1 (the last sampled
-                # token is not in the cache yet); EOS, which only the
-                # device has seen, can only leave it lower
-                stands = [len(req.tokens) + req.scheduled - 1 for _, req in rows]
+                if self._compact:
+                    stands = self._count_compacting(stands, n)
                 self._cache_tiles["read_full"] += sum(
                     -(-at // self._tile) for at in stands)
                 # the chunk's flush writes, a full layer a tensor, the tile
@@ -1125,7 +1193,7 @@ class GenerationEngine:
                     self._cache_tiles["read_window"] += (
                         (self.n_slots + 1) * self._ring_tiles)
                 self._cache_tiles["padded"] += (
-                    (self.n_slots + 1) * self._max_len // self._tile)
+                    (self.n_slots + 1) * self._slab_tiles)
                 for i, req in rows:
                     req.scheduled = min(req.max_new, req.scheduled + n)
                     if req.scheduled >= req.max_new:
@@ -1144,6 +1212,45 @@ class GenerationEngine:
                 t_admitted - t_tick0, t_dispatched - t_admitted,
                 time.perf_counter() - t_dispatched - waited)
         return worked
+
+    def _count_compacting(self, stands, n: int):
+        """A dispatched chunk of ``n`` steps of a compacting family, rows at
+        positions ``stands``: the counters (``__init__``; call under the
+        lock).  Returns where the rows stand IN THEIR WINDOWS, which is what
+        the flush's tiles are counted from."""
+        window, chunk = self._compact
+        tile, tally = self._tile, self._cache_tiles
+        places = [at % window for at in stands]
+        rows = [at // window * (window // chunk) for at in stands]
+        near = sum(-(-p // tile) for p in places)
+        far = sum(-(-r // tile) for r in rows)
+        tally["eva_window_tiles"] += near
+        tally["eva_summary_tiles"] += far
+        tally["eva_tile_steps"] += n * (near + far)
+        tally["eva_row_steps"] += n * len(stands)
+        # a step reads the tiles and the chunk's own columns up to its own;
+        # its query may attend its window up to itself and every summary row
+        own = n * (n + 1) // 2 * len(stands)
+        tally["eva_read_positions"] += n * (near + far) * tile + own
+        tally["eva_attendable_positions"] += (
+            n * (sum(places) + sum(rows)) + own)
+        rolled = sum((p + n) % window == 0 for p in places)
+        tally["eva_rollovers"] += rolled
+        tally["eva_chunks_pooled"] += rolled * (window // chunk)
+        tally["eva_steps"] += n
+        tally["eva_dispatches"] += 1
+        # (a summary tile is a tile of read_full too)
+        tally["read_full"] += far
+        return places
+
+    def _compaction_stats(self) -> Optional[Dict[str, Any]]:
+        """``perf_stats()["eva"]`` (call under the lock); None for a family
+        that compacts nothing."""
+        if not self._compact:
+            return None
+        return {"window": self._compact[0], "chunk": self._compact[1],
+                **{k[4:]: v for k, v in self._cache_tiles.items()
+                   if k.startswith("eva_")}}
 
     def _count_routed(self, phase: str, counts, steps: int = 0,
                       padded: int = 0) -> None:

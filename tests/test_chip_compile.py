@@ -346,6 +346,39 @@ def _lower_dots3_cell(chip):
     ]
 
 
+def _lower_evabyte_cell(chip):
+    """The serve-evabyte-6.5b-pp4-code cell's programs: EvaByte at its
+    published widths, 8 of 32 layers (one of four pipeline stages), 16 slots
+    and the scratch row over the cache that compacts itself: an exact window
+    of 2,048 + 128 places and 1,152 summary rows (9 windows' worth: 16,384 +
+    2,048 + 16 positions) a slot a layer.  The decode chunk reads both through
+    the ragged kernel (twice a layer), flushes the window's two tensors at the
+    slot's place in its window and pools the windows that filled; a prompt of
+    at most one part (2,048 bytes: one window) takes its bucket's program
+    (plain causal attention), every longer one the part program: the flash
+    kernel over the cached summaries laid ahead of the part's own keys."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("evabyte", "6.5b", n_layers=8)
+    n_slots, chunk = 16, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(16384, 2048, chunk)))
+    assert set(cache) == {"k", "v", "ks", "vs", "pos"}
+    assert cache["k"].shape == cache["v"].shape == (8, 17, 32, 128, 2176)
+    assert cache["ks"].shape == cache["vs"].shape == (8, 17, 32, 128, 1152)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(16384))
+    assert llm.call_rows(2048, n_slots) == 1
+    return [
+        _lower_part(chip, part, params, cache),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
+        _lower_prefill(chip, prefill, params, cache, n_slots, 2048),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -412,6 +445,7 @@ PROGRAMS = {
     "serve_engine_kimi_cell": _lower_kimi_cell,
     "serve_engine_granite_cell": _lower_granite_cell,
     "serve_engine_dots3_cell": _lower_dots3_cell,
+    "serve_engine_evabyte_cell": _lower_evabyte_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -527,7 +561,7 @@ def test_program_compiles_for_v5e(compiled, name):
         for program in programs:
             assert program.as_text().count("tpu_custom_call") == 2
     if name in ("serve_engine_exaone_cell", "serve_engine_kimi_cell",
-                "serve_engine_dots3_cell"):
+                "serve_engine_dots3_cell", "serve_engine_evabyte_cell"):
         # ONE program for every part of every prompt above 2,048 tokens, under
         # the name a trace's readers sum the prefill programs by; the flash
         # kernel is in it on every layer that reads a slab
@@ -650,6 +684,34 @@ def test_program_compiles_for_v5e(compiled, name):
             assert not re.search(r"f32\[1,128,\d{4,5},\d{4,5}\]", prefill.as_text())
         assert programs[0].memory_analysis().temp_size_in_bytes < 2.6e9
         assert all(5.5e9 < need < 8.5e9 for need in needs), needs
+    if name == "serve_engine_evabyte_cell":
+        # the ragged kernel twice a layer (the window, then the summaries:
+        # each under its own named scope, which the traced run's ``scope:*``
+        # rows are summed by), the flush kernel over the window's k and v,
+        # and the roll-over's pooling in a loop over the slots that rolled,
+        # writing the summaries where they lie (no copy of a 2.6 GB slab:
+        # the chunk plans 0.85 GB of temporaries).  3.26 GB of weights and
+        # 7.42 GB of cache resident: 62 % of the chip
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            reads = [line for line in text.splitlines()
+                     if re.search(r"%ragged_decode_attention[.\d]* = ", line)]
+            assert len(reads) == 2, len(reads)
+            assert sum("attention.eva_window" in line for line in reads) == 1
+            assert sum("attention.eva_summary" in line for line in reads) == 1
+            flushes = [line for line in text.splitlines()
+                       if re.search(r"%cache_flush[.\d]* = ", line)]
+            assert len(flushes) == 2, flushes
+            assert all("bf16[8,17,32,128,2176]" in line for line in flushes)
+            assert "attention.eva_pool" in text and "attention.eva_merge" in text
+            assert decode.memory_analysis().temp_size_in_bytes < 1.0e9
+        assert "attention.eva_summary" in programs[0].as_text()
+        assert "attention.eva_pool" in programs[0].as_text()
+        assert "flash_attention_fwd" in programs[3].as_text()
+        for prefill in (programs[0], programs[3]):
+            assert not re.search(r"f32\[1,32,2048,\d{4}\]", prefill.as_text())
+            assert prefill.memory_analysis().temp_size_in_bytes < 1.0e9
+        assert all(11.0e9 < need < 12.0e9 for need in needs), needs
     if name.startswith("flash_attention"):
         # must reach the chip's compiler as kernels, not as an XLA fallback:
         # one forward; the backward kernel + the forward it differentiates
