@@ -217,6 +217,7 @@ from ray_tpu.ops.attention import (
     cache_flush_plan,
     continued_attention,
     latent_slab_attention,
+    live_blocks,
     ragged_decode_attention,
     ragged_decode_plan,
     ragged_latent_decode_attention,
@@ -238,9 +239,10 @@ from ray_tpu.ops.attention import (
 # (``index_cache``) the block hands the middle the layer's index queries, head
 # weights and index keys as a fifth, and where its window layers cache latent
 # rows too, their width and scale (``window_latent_cache``,
-# ``window_attention_scale``); its ``block`` also takes ``context``, which maps
-# the call's rows to the rows its keys and values are up-projections of (a
-# prompt's part: what the cache holds ahead of it, then its own).  A family some of whose layers attend NOTHING and
+# ``window_attention_scale``); its ``block`` also takes ``context``, which is
+# handed the call's rows and the layer's up-projection of any rows and gives the
+# call's keys and values (a prompt's part: those of what the cache holds ahead
+# of it, then its own).  A family some of whose layers attend NOTHING and
 # carry a per-request state instead says so in ``sliding_windows``
 # (``RECURRENT``) and ``state_cache``; its parameters are a stack of the
 # recurrent layers (``params[kind]``, leaves ``[L_kind, ...]``) beside a list
@@ -683,16 +685,24 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
       among them (:func:`_placed`), attended by position
       (:func:`ray_tpu.ops.attention.continued_attention`: lowered for a TPU
       the flash kernel with the key length as a prefetched scalar, which
-      neither folds nor fetches a block beyond ``offsets + Tp``);
+      neither folds nor fetches a block beyond ``offsets + Tp``).  What is
+      PREPARED of those positions for the kernel (cached heads repeated to the
+      query heads; a latent slab's up-projection, below) follows the live
+      length too: a block of ``Tp`` positions a trip, the blocks below
+      ``max(offsets) + Tp`` alone
+      (:func:`ray_tpu.ops.attention.live_blocks`; ``bound`` is then whole
+      blocks), so a part near its prompt's start pays for few;
     - a ring: the positions just ahead of the part by their places ``j % ring``
       (:func:`_preceded`), masked by absolute position; the ring then holds the
       last ``ring`` positions of prefix-and-part;
     - a latent slab: the cached rows are up-projected to per-head k and v by
-      the block's own weights, as the part's own are (the block's ``context``);
+      the block's own weights, as the part's own are (the block's ``context``,
+      which hands over the layer's up-projection: the live blocks' rows go
+      through it, a trip each);
     - a latent slab that selects: the part's index queries score the cached
-      index keys below ``offsets`` and the part's own, ONE threshold over both
-      (:func:`ray_tpu.ops.dsa.causal_top_k_mask`): the whole prompt's
-      selection."""
+      index keys below ``offsets`` and the part's own (the live blocks alone),
+      ONE threshold over both (:func:`ray_tpu.ops.dsa.causal_top_k_mask`): the
+      whole prompt's selection."""
     fam = family_of(cfg)
     B, Tp = tokens.shape
     windows = layer_windows(cfg)
@@ -715,6 +725,24 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         positions = jnp.arange(Tp)
     x = fam.embed(params, tokens, cfg, positions)
 
+    def among(window, held, own, prepare=None):
+        # a PART's keys (values, ...) of one layer, a tensor each of ``held``
+        # and ``own``: ``prepare`` (the layer's up-projection of cached rows,
+        # a repeat to the query heads; None: as they are) of what the cache
+        # holds ahead of the part and of the part's own.  A window layer's
+        # are the ring's few positions; a full layer's go by position up to
+        # the static bound, and only the blocks (a part wide) below the
+        # longest row's end are prepared at all: the trips are a runtime
+        # count, the kernel reads no further, and a part near the prompt's
+        # start pays for few
+        if window:
+            rows = tuple(_preceded(h, t, offsets, window)
+                         for h, t in zip(held, own))
+            return prepare(*rows) if prepare else rows
+        rows = tuple(_placed(h, t, offsets) for h, t in zip(held, own))
+        return live_blocks(prepare, rows, offsets.max() + Tp, Tp
+                           ) if prepare else rows
+
     def attend(q, k, v, row=None, index=None, window=0, held=None, pool=None):
         # the causal (or band) attention of training; kept: this layer's k,
         # v, or the cache row a latent family's block hands over; a layer
@@ -734,11 +762,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         kept = ((k, v) if row is None else (row,) if index is None
                 else (row, index[2]))
         if held is not None:
-            among = partial(_preceded, window=window) if window else _placed
             if row is None:  # (a latent layer's k and v cover it already)
-                k, v = (among(h, t, offsets) for h, t in zip(held, (k, v)))
-                k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1)
-                        for t in (k, v))
+                heads = q.shape[1] // k.shape[1]  # query heads a cached one
+                k, v = among(window, held, (k, v), None if heads == 1 else (
+                    lambda *kv: tuple(jnp.repeat(t, heads, axis=1) for t in kv)))
             if window:
                 return band_attention_after(
                     q, k, v, offsets, window=window, scale=scale), kept
@@ -781,9 +808,8 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
             if part:  # this layer's among its kind's, and a latent block's rows
                 held = tuple(t[len(kept.get(bool(w), ()))] for t in ahead[bool(w)])
                 if latent_cache(cfg, bool(w)):
-                    rows_of = {"context": partial(
-                        partial(_preceded, window=w) if w else _placed,
-                        held[0], offsets=offsets)}
+                    rows_of = {"context": lambda row, up, w=w, held=held: among(
+                        w, held[:1], (row,), up)}
             x, counts, kv = fam.block(
                 x, p, cfg, partial(attend, window=w, held=held), positions,
                 window=w, valid=valid, **rows_of)

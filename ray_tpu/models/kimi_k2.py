@@ -259,11 +259,12 @@ def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
     pe]`` against ONE key head, ``k`` the rows themselves and ``v`` their first
     ``rkv`` values.  ``rope(t)`` rotates ``t [B, heads, T, pe]``; ``rescale``:
     factors on the two normed latents (a family that has them).
-    ``context(row) -> [B, 1, Tk, rkv + pe]`` (un-absorbed; None: the rows
-    themselves): the rows the call's keys and values are up-projections of, a
-    prompt's PART handing over what the cache holds before it with its own
-    rows among them (:func:`ray_tpu.models.generate.prefill_at`): ``k`` and
-    ``v`` then have ``Tk`` positions."""
+    ``context(row, up) -> (k, v)`` (un-absorbed; None: ``up(row)``): a
+    prompt's PART, whose keys and values cover what the cache holds before it
+    with its own rows among them; ``up(rows [B, 1, Tk, rkv + pe]) -> (k, v)``
+    is this layer's up-projection of any rows, and the caller decides which
+    positions it is run over
+    (:func:`ray_tpu.models.generate.prefill_at`)."""
     B, T, _ = h.shape
     rkv = p["kv_norm"].shape[0]
     c_q = norm(dense(h, p["w_dq"]), p["q_norm"])
@@ -282,13 +283,20 @@ def latent_projections(h, p, *, heads: int, nope: int, norm, rope,
         q = jnp.concatenate(
             [jnp.einsum("bhtd,hdc->bhtc", q_nope, w_uk), q_pe], axis=-1)
         return q, row, row[..., :rkv], row, c_q
-    held = row if context is None else context(row)
-    c, k_pe = held[:, 0, :, :rkv], held[..., rkv:]
+    split = lambda held: (held[:, 0, :, :rkv], held[..., rkv:])  # noqa: E731
+
+    def up(c, k_pe):  # rows' latents and shared keys -> a key and value a head
+        k = jnp.concatenate([
+            jnp.einsum("btc,hdc->bhtd", c, w_uk),
+            jnp.broadcast_to(k_pe, (B, heads, c.shape[1], pe))], axis=-1)
+        return k, jnp.einsum("btc,hcv->bhtv", c, w_uv)
+
+    # (a whole call's operations in the order they always had: its lowered
+    # text is what a change to the part's path is checked against)
+    own = None if context else split(row)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    k = jnp.concatenate([
-        jnp.einsum("btc,hdc->bhtd", c, w_uk),
-        jnp.broadcast_to(k_pe, (B, heads, held.shape[2], pe))], axis=-1)
-    return q, k, jnp.einsum("btc,hcv->bhtv", c, w_uv), row, c_q
+    k, v = context(row, lambda held: up(*split(held))) if context else up(*own)
+    return q, k, v, row, c_q
 
 
 def block(x, p, cfg: KimiK2Config, attend=None, positions=None,

@@ -43,6 +43,9 @@ Not in the dispatch:
   mask; off the TPU the masked scores a block of queries at a time, which the
   tests hold the kernel to.  :func:`band_attention_after`: the same for a
   window layer, the ring's positions ahead of the part then its own.
+  :func:`live_blocks`: what is prepared FOR that kernel of the cached
+  positions (an up-projection, a repeat to the query heads), the blocks below
+  the live length alone, a runtime trip count.
 - :func:`mha_reference` — naive O(T²) f32 attention; numerical ground
   truth for tests.
 - :func:`ragged_decode_attention` — the decode step's contract, not
@@ -88,6 +91,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 
 NEG_INF = -1e30
 
@@ -381,11 +385,17 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
     static shapes say all three): batch row ``b``'s first query sits at
     position ``bounds[3 b]`` and attends the keys from ``bounds[3 b + 2]`` to
     below ``bounds[3 b + 1]``, runtime values that ride into scalar memory
-    ahead of the grid; causal only.  ``window``: the kernel's (with bounds)."""
+    ahead of the grid; causal only.  ``window``: the kernel's (with bounds).
+    ``kp``, ``vp`` may come IN BLOCKS of positions, ``[blocks, N, positions a
+    block, lanes]`` (:func:`live_blocks`: position ``j`` is row ``j % a`` of
+    block ``j // a``, whole key blocks of the kernel a block): only the index
+    maps differ, the kernel sees the same ``[block_k, lanes]`` tiles."""
     heads, lw, lwv = layout
     n, t_q, w = qp.shape
-    t_k, nb = kp.shape[1], w // lw
+    in_blocks = kp.shape[2] if kp.ndim == 4 else 0  # positions a block
+    t_k, nb = (kp.shape[0] * in_blocks or kp.shape[1]), w // lw
     bq, bk = _flash_blocks(t_q, t_k, block_q, block_k)
+    assert in_blocks % bk == 0, (in_blocks, bk)
     nk, q_offset = t_k // bk, t_k - t_q
     per_bound = 0 if bounds is None else n // (bounds.shape[0] // 3)
     assert bounds is None or causal, "runtime bounds: causal attention only"
@@ -413,6 +423,19 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
 
     by_q = lambda i, hb, qi, ki, *b: (i, qi, hb)  # noqa: E731
     by_k = lambda i, hb, qi, ki, *b: (i, held(ki, i, qi, b), hb)  # noqa: E731
+
+    def key_tile(lanes):
+        if not in_blocks:
+            return pl.BlockSpec((1, bk, lanes), by_k)
+
+        def in_block(*grid):  # (the leading axis squeezed: the same tile)
+            i, at, hb = by_k(*grid)
+            return at // tiles, i, at % tiles, hb
+
+        tiles = in_blocks // bk  # of the kernel, a block of positions
+
+        return pl.BlockSpec((None, 1, bk, lanes), in_block)
+
     masks, mask_specs = (), []
     if keep is not None:
         per_row = n // keep.shape[0]  # packed rows (heads) of one batch row
@@ -424,8 +447,8 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
         grid=(n, nb, t_q // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, lw), by_q),
-            pl.BlockSpec((1, bk, lw), by_k),
-            pl.BlockSpec((1, bk, lwv), by_k),
+            key_tile(lw),
+            key_tile(lwv),
             *mask_specs,
         ],
         out_specs=[
@@ -448,7 +471,7 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
                           window=window),
         **spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n, t_q, vp.shape[2]), qp.dtype),
+            jax.ShapeDtypeStruct((n, t_q, vp.shape[-1]), qp.dtype),
             jax.ShapeDtypeStruct((n, nb, heads, t_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -1513,6 +1536,41 @@ def _attention_by_query_block(q, k, v, keep, first, scale: float):
     return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:2], t, v.shape[-1])
 
 
+def live_blocks(prepare, rows, live: jax.Array, block: int):
+    """What a prompt's PART prepares of a slot's positions for its keys, the
+    positions that are LIVE alone.  ``rows``: a tuple of ``[B, KV, bound, *]``
+    arrays with something a position up to a static bound (whole blocks of
+    ``block``), the cached prefix with the part's own among them;
+    ``prepare(a block of each) -> a tuple of [B, H, block, *]``.  Returns the
+    same for all the bound's positions IN BLOCKS, ``[bound // block, B, H,
+    block, *]``, of which the blocks below ``live`` (int32, a RUNTIME value:
+    the trip count of the one loop) are written and the others are NOT: they
+    hold whatever the buffer held (nobody fills it; off the TPU zeros), and
+    whoever reads the result keeps below ``live``, as
+    :func:`continued_attention`'s kernel does.  In blocks because a trip then
+    writes one entry of the leading axis, which the compiler does where the
+    block is computed (a block stored among ``[B, H, bound, *]`` was a copy of
+    its own, at a quarter of the HBM's rate: PERF.md section 6, PR 50)."""
+    bound = rows[0].shape[2]
+    assert bound % block == 0, (bound, block)
+    take = lambda i: prepare(*(  # noqa: E731
+        lax.dynamic_slice_in_dim(t, i * block, block, 2) for t in rows))
+
+    def trip(i, blocks):
+        # row-major, as a kernel takes its operands: left to itself the
+        # compiler lays the loop's buffers out as ``prepare``'s matmuls like
+        # them and re-lays ALL the bound's positions out after the loop
+        return tuple(lax.dynamic_update_slice_in_dim(
+            with_layout_constraint(
+                t, Layout(major_to_minor=tuple(range(t.ndim)))), new[None], i, 0)
+            for t, new in zip(blocks, take(i)))
+
+    return lax.fori_loop(
+        0, (live + block - 1) // block, trip,
+        tuple(lax.empty((bound // block, *s.shape), s.dtype)
+              for s in jax.eval_shape(take, 0)))
+
+
 def continued_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         first: jax.Array, *, keep: Optional[jax.Array] = None,
                         scale: Optional[float] = None,
@@ -1525,7 +1583,9 @@ def continued_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``first[b] ..``, ``Tk`` a static bound.  Query ``i`` attends ``j <=
     first[b] + i``; ``keep [B, P, Tk]`` int8 (None: all of them): of those, the
     positions a layer that selects lets the row attend, the same for every head
-    (:func:`masked_attention`).
+    (:func:`masked_attention`).  ``k`` and ``v`` may come IN BLOCKS of
+    positions, ``[Tk // a, B, H, a, *]`` (:func:`live_blocks`, which writes
+    only the blocks a live position falls in).
 
     Lowered for a TPU, whole blocks of 512: the Pallas forward kernel with the
     first position and the key length ``first[b] + P`` as prefetched scalars,
@@ -1533,19 +1593,26 @@ def continued_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     parts add up to the whole call's cells whatever ``Tk`` is.  Anywhere else
     the masked scores a block of queries at a time, the reference the kernel is
     tested against.  Forward only."""
-    t_q, t_k = q.shape[2], k.shape[2]
+    in_blocks = k.ndim == 5
+    t_q, t_k = q.shape[2], k.shape[-2] * (k.shape[0] if in_blocks else 1)
+    a_block = k.shape[-2]  # (positions a block; not in blocks: all of them)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     first = first.astype(jnp.int32)
+    by_position = lambda t: t if t.ndim == 4 else jnp.moveaxis(  # noqa: E731
+        t, 0, 2).reshape(*t.shape[1:3], -1, t.shape[-1])
     xla = lambda q, k, v, first, keep: _attention_by_query_block(  # noqa: E731
-        q, k, v, keep, first, scale)
-    block = next((b for b in (1024, 512) if t_q % b == 0 and t_k % b == 0), None)
+        q, by_position(k), by_position(v), keep, first, scale)
+    block = next((b for b in (1024, 512)
+                  if t_q % b == 0 and t_k % b == 0 and a_block % b == 0), None)
     if block is None:
         return xla(q, k, v, first, keep)
 
     def kernel(q, k, v, first, keep):
         pack, unpack, layout = _flash_pack(q, k, v)
+        pack_keys = jax.vmap(pack) if in_blocks else pack  # (a block at a time)
         out, _ = _flash_forward(
-            pack(q), pack(k), pack(v), layout=layout, causal=True, scale=scale,
+            pack(q), pack_keys(k), pack_keys(v), layout=layout, causal=True,
+            scale=scale,
             block_q=block, block_k=block, interpret=interpret, keep=keep,
             bounds=jnp.stack([first, first + t_q, jnp.zeros_like(first)],
                              axis=1).reshape(-1))

@@ -106,17 +106,32 @@ def causal_top_k_mask(q: jax.Array, w: jax.Array, k: jax.Array, top_k: int,
     prompt's PART, whose row ``t`` sits at position ``first[b] + t`` and whose
     keys are a slot's index keys by position, the cached ones below ``first``
     and the part's own from there, up to a static bound ``Tk``: ONE threshold
-    over both, the whole prompt's selection for the same scores."""
+    over both, the whole prompt's selection for the same scores.  A part
+    scores the blocks of ``T`` keys below the longest row's end alone (a
+    RUNTIME trip count; the scores beyond it stay 0 and are never valid): no
+    query may select a position there."""
     B, H, T, d = q.shape
+    Tk = k.shape[1]
     block = min(block, T)
     assert T % block == 0, (T, block)
     n = T // block
-    positions = jnp.arange(k.shape[1])
+    positions = jnp.arange(Tk)
+
+    def live_scores(qb, wb):
+        def trip(i, scores):  # (a last block the bound cuts short starts earlier)
+            at = jnp.minimum(i * T, Tk - T)
+            return lax.dynamic_update_slice_in_dim(scores, index_scores(
+                qb, wb, lax.dynamic_slice_in_dim(k, at, T, 1)), at, 2)
+
+        live = first.max().astype(jnp.int32) + T
+        return lax.fori_loop(0, (live + T - 1) // T, trip,
+                             jnp.zeros((B, block, Tk), jnp.float32))
 
     def rows(args):
         qb, wb, at = args                          # [B, H, block, d], [B, block, H]
-        with jax.named_scope("attention.index_score"):
-            scores = index_scores(qb, wb, k)       # [B, block, Tk]
+        with jax.named_scope("attention.index_score"):                # [B, block, Tk]
+            scores = (index_scores(qb, wb, k) if first is None
+                      else live_scores(qb, wb))
         at = at + jnp.arange(block)
         if first is not None:
             at = first.astype(jnp.int32)[:, None] + at

@@ -310,6 +310,40 @@ def kernel_profile(compiled: Any) -> Dict[str, Dict[str, Any]]:
     return profile
 
 
+_HLO_OPERATION = re.compile(r"^(\(.*?\)|\S+)\s+([\w\-]+)\((.*)$")
+
+
+def operation_profile(compiled: Any, kinds) -> list:
+    """The instructions of a compiled program whose opcode is one of
+    ``kinds`` (``"dot"``, ``"broadcast"``, ``"concatenate"``, ...), those
+    inside fusions among them: a dict each of ``kind``, ``shape`` (the result,
+    as ``"f32[2,72,16]"``), ``operands`` (the text in the call's brackets
+    and the attributes after them), ``op_name`` (the metadata's: the
+    ``jax.named_scope`` path the operation was traced under) and
+    ``runtime_loop``: whether it runs under a ``while`` whose trip count the
+    compiler does NOT know (a ``lax.fori_loop`` / ``while_loop`` with a traced
+    bound; a scanned loop's count is known).  What a program does for every
+    position of a static bound, and what only for those a runtime value says
+    are live, is decided where it is lowered: this is what shows it."""
+    _, loops, rows = _hlo_instructions(compiled)
+    found = [(comp, _HLO_OPERATION.match(rest)) for comp, _, rest in rows]
+    counted = {c.strip().lstrip("%")
+               for _, m in found if m and m.group(2) == "while"
+               and "known_trip_count" in m.group(3)
+               for c in re.findall(r"\bbody=(%?[\w.\-]+)", m.group(3))}
+    profile = []
+    for comp, m in found:
+        if m and m.group(2) in kinds:
+            name = re.search(r'op_name="([^"]*)"', m.group(3))
+            profile.append({
+                "kind": m.group(2),
+                "shape": re.sub(r"\{[^}]*\}", "", m.group(1)),
+                "operands": re.sub(r",? metadata=\{.*", "", m.group(3)),
+                "op_name": name.group(1) if name else "",
+                "runtime_loop": bool(loops[comp] - counted)})
+    return profile
+
+
 def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
     """What a compiled step moves between chips, read from its HLO.
 

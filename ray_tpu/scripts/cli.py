@@ -722,6 +722,21 @@ def cmd_metrics(args) -> None:
     print(json.dumps(result, indent=2))
 
 
+def _parts_line(split: dict) -> str:
+    """The prompts longer than one prefill part, which went in parts between
+    the decode chunks (an engine's ``perf_stats()["prefill"]["parts"]``), and
+    how much of the part program's static bound of cached positions the calls
+    prepared for their keys: the blocks below each part's end."""
+    line = (f"{split['prompts']} prompts prefilled in {split['calls']} parts "
+            f"({split['live_tokens']} prompt tokens in "
+            f"{split['padded_tokens']} padded)")
+    if split.get("blocks_bound"):
+        line += (f", {split['blocks_prepared']} of {split['blocks_bound']} "
+                 f"blocks of cached positions prepared "
+                 f"({split['blocks_prepared'] / split['blocks_bound']:.0%})")
+    return line
+
+
 def cmd_perf(args) -> None:
     """Performance observability report: the step-phase breakdown
     (phases sum exactly to the profiled step wall), live MFU per rank +
@@ -812,14 +827,8 @@ def cmd_perf(args) -> None:
                     f"p50 {m['tpot_p50_s'] * 1e3:.2f}ms "
                     f"p95 {m['tpot_p95_s'] * 1e3:.2f}ms over a decode tick "
                     f"of {(m.get('baseline_s') or 0) * 1e3:.1f}ms")
-            split = m.get("parts") or {}
-            if split.get("calls"):
-                # prompts longer than one prefill part went in parts, between
-                # the decode chunks
-                out.append(
-                    f"  {eid}: {split['prompts']} prompts prefilled in "
-                    f"{split['calls']} parts ({split['live_tokens']} prompt "
-                    f"tokens in {split['padded_tokens']} padded)")
+            if (m.get("parts") or {}).get("calls"):
+                out.append(f"  {eid}: {_parts_line(m['parts'])}")
         states = {eid: m["state"] for eid, m in interference.items()
                   if m.get("state")}
         if states:

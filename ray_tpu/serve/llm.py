@@ -106,11 +106,17 @@ def call_rows(bucket: int, n_slots: int) -> int:
 # 16,384) at 1,024 / 2,048 / 4,096 reads ``tpot_p95_ms`` 12.0 / 13.0 / 19.7 for
 # the whole prompts' 30.3, but at 1,024 the parts' own queue grows (260.2
 # tokens/s for the 280.3 offered, the first token's p95 5.2 s for 2.6 s at
-# 2,048 and 1.7 s at 4,096): a part costs ~40 ms more than a whole call as
-# wide (since PR 46's expert dispatch 90.7 ms at offset 4,096 against 50.4 for
-# the 2,048 bucket's call; before it 110.3 against 70.1): ~24 for the program's
-# static bound whatever the part is wide, the rest for the cached keys its
-# queries attend, so halving it halves nothing.  Kimi-K2's
+# 2,048 and 1.7 s at 4,096): a part costs what a whole call as wide costs and,
+# on top, what the cached positions BEFORE it cost, so halving it halves
+# nothing.  One part alone on the chip (PR 50; the 2,048 bucket's whole call
+# 49.0 ms): 65.3 / 73.3 / 90.0 / 113.8 ms at offsets 2,048 / 4,096 / 8,192 /
+# 14,336, ~8.2 ms a further 2,048 cached positions (the masked kernel over
+# them ~6, their up-projection, concatenation and index scores ~2.2: those
+# are prepared a block of 2,048 a trip, the blocks below the part's end alone,
+# ``ops.attention.live_blocks``), and ~7.5 ms for being a part at all (offset
+# 0: 56.5).  Before PR 50 every part prepared all 16,384 positions of the
+# program's static bound, ~24 ms whatever its offset: 82.0 / 87.9 / 100.0 /
+# 117.0 at the same offsets.  Kimi-K2's
 # cell (prompts 256 - 8,192, half of them split) hardly tells the sizes apart:
 # 9.7 - 10.4 / 9.4 - 9.6 / 9.4 for the whole prompts' 9.15 - 9.20, the first
 # token's p95 1.6 - 2.4 s / 0.95 s / 0.7 s: its whole calls were short enough
@@ -352,7 +358,11 @@ def cache_positions(largest_bucket: int, max_new_tokens: int,
 def part_bound(largest_bucket: int) -> int:
     """The cache positions a prompt's part may attend, itself included: the
     largest bucket in whole parts (the part program's static bound on the
-    keys; 16,384 for dots3's cell, where the buckets are whole parts)."""
+    keys; 16,384 for dots3's cell, where the buckets are whole parts).  A
+    bound on shapes only: the program prepares and reads the positions below
+    ``offset + part``, a part's worth a trip, so what a part costs follows its
+    offset and not this (``perf_stats()["prefill"]["parts"]``:
+    ``blocks_prepared`` of ``blocks_bound``)."""
     part = PREFILL_PART_TOKENS
     return largest_bucket if largest_bucket <= part else (
         -(-largest_bucket // part) * part)
@@ -554,10 +564,14 @@ class GenerationEngine:
         # wide, and the prompts and prompt tokens among those
         # ... and, under "parts" for an engine that splits prompts, the same
         # of those that went in PARTS: the prompts split, the part calls (one
-        # row each), and the tokens they took
+        # row each), and the tokens they took; then the blocks of cached
+        # positions (a part wide) the calls prepared for their keys, those
+        # below each part's end, beside what the program's static bound holds
         self._prefill = {b: {"calls": 0, "rows": 0, "padded_tokens": 0,
                              "prompts": 0, "live_tokens": 0}
                          for b in (*self.buckets, *(("parts",) if self._part else ()))}
+        if self._part:
+            self._prefill["parts"].update(blocks_prepared=0, blocks_bound=0)
         # cumulative routing counts of the dispatches drained so far, where
         # the family's layers count (leaves as the programs return them:
         # stacked over the layers that route); None until a first arrives
@@ -962,8 +976,12 @@ class GenerationEngine:
                     calls.append((None, (slot, req, req.prefilled, n)))
                     req.prefilled += n
                     spent += self._part
-                    for name, more in (("calls", 1), ("rows", 1), ("live_tokens", n),
-                                       ("padded_tokens", self._part)):
+                    for name, more in (
+                            ("calls", 1), ("rows", 1), ("live_tokens", n),
+                            ("padded_tokens", self._part),
+                            ("blocks_prepared", -(-req.prefilled // self._part)),
+                            ("blocks_bound", part_bound(self.buckets[-1])
+                             // self._part)):
                         self._prefill["parts"][name] += more
                 if req.prefilled >= len(req.tokens):
                     self._splitting.remove((slot, req))
@@ -1075,8 +1093,8 @@ class GenerationEngine:
         stands instead of first tokens.  A FIRST part, where a part is one of
         the buckets, is that bucket's own program over the prompt's first
         tokens (a slot from scratch: it reads nothing of the cache, where the
-        part program reads its static bound whatever the offset; measured:
-        the constant's comment)."""
+        part program places its rows among the slot's and goes one trip: 7.5 ms
+        more at offset 0; measured: the constant's comment)."""
         import jax.numpy as jnp
 
         if first == 0 and self._part in self._rows:

@@ -4,6 +4,7 @@ attending what the earlier ones left in the cache: the model's continuation
 kernel under a runtime key length against the masked XLA reference, and the
 engine that schedules the parts between its decode chunks."""
 
+import re
 import sys
 
 import jax
@@ -16,6 +17,7 @@ import ray_tpu.ops.attention  # noqa: F401  (the module, not ops' function)
 from ray_tpu._private import events as events_mod
 from ray_tpu.models import generate as gen
 from ray_tpu.ops import dsa
+from ray_tpu.scripts import cli
 from ray_tpu.serve import llm
 
 attention = sys.modules["ray_tpu.ops.attention"]
@@ -101,6 +103,125 @@ def test_rows_of_one_call_continue_at_their_own_offsets():
         np.testing.assert_allclose(logits[s], want[0], atol=5e-5, rtol=1e-4)
 
 
+# -- what a part prepares of the cached positions -------------------------------
+
+# a part of 8 tokens under a static bound of 72 cached positions: no other axis
+# of a tiny model is 72 long, so a shape that holds it holds every position
+LIVE_PART, LIVE_BOUND = 8, 72
+
+def _opaque_middle(q, k, v, first, keep=None, scale=None):
+    """``continued_attention`` as the chip's compiler sees it: a call it
+    cannot look into (a host callback for the kernel), handed the keys and
+    values by position up to the static bound, which reads none beyond a
+    row's last live position."""
+    def on_host(q, k, v, first, keep):
+        if k.ndim == 5:  # in blocks of positions (``live_blocks``)
+            k, v = (np.moveaxis(t, 0, 2).reshape(*t.shape[1:3], -1, t.shape[-1])
+                    for t in (k, v))
+        out = np.zeros((*q.shape[:3], v.shape[-1]), np.float32)
+        for b, at in enumerate(first):
+            n = int(at) + q.shape[2]
+            s = np.einsum("hqd,hkd->hqk", q[b], k[b, :, :n]) * scale
+            seen = np.arange(n)[None, :] <= int(at) + np.arange(q.shape[2])[:, None]
+            if keep.size:
+                seen = seen & (keep[b, :, :n] != 0)
+            s = np.where(seen[None], s, -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[b] = np.einsum("hqk,hkd->hqd", p / p.sum(-1, keepdims=True),
+                               v[b, :, :n])
+        return out.astype(q.dtype)
+
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    return jax.pure_callback(
+        on_host, jax.ShapeDtypeStruct((*q.shape[:3], v.shape[-1]), q.dtype),
+        q, k, v, first, jnp.zeros((0,), jnp.int8) if keep is None else keep)
+
+
+def _every_position(prepare, rows, live, block):
+    """The static form, the parent's: ``prepare`` over ALL the bound's
+    positions at once, whatever is live (``attention.live_blocks``' contract
+    with nothing left unwritten).  Kept as the reference."""
+    return prepare(*rows)
+
+
+@pytest.fixture(scope="module")
+def part_forms():
+    """``of(family) -> (live, static, operations)``: the part program of the
+    family's tiny model compiled ONCE a module in both forms (one row, a part
+    of 8 under a bound of 72; the offset a runtime argument), the attention
+    middle a call the compiler cannot look into (``_opaque_middle``), so that
+    what is left over the bound is what is prepared FOR it; ``operations``:
+    the live form's matmuls, broadcasts and concatenations
+    (``sharding.operation_profile``)."""
+    from ray_tpu.parallel.sharding import operation_profile
+
+    def compiled(family):
+        cfg, params = tiny_model(family)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        return jax.jit(
+            lambda params, toks, lens, cache, slots, offsets: gen.prefill_at(
+                params, cfg, toks, lens, cache, slots, offsets, LIVE_BOUND)
+        ).lower(params, i32(1, LIVE_PART), i32(1), gen.init_cache(cfg, 3, 96),
+                i32(1), i32(1)).compile()
+
+    kept = {}
+
+    def of(family):
+        if family not in kept:
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(gen, "continued_attention", _opaque_middle)
+                live = compiled(family)
+                patch.setattr(gen, "live_blocks", _every_position)
+                static = compiled(family)
+            kept[family] = (live, static, operation_profile(
+                live, ("dot", "broadcast", "concatenate")))
+        return kept[family]
+
+    return of
+
+
+@pytest.mark.parametrize("offset", [0, LIVE_PART, LIVE_BOUND - LIVE_PART],
+                         ids=["offset0", "one_part", "bound_less_a_part"])
+@pytest.mark.parametrize("family", CONTINUES)
+def test_a_part_prepares_the_live_blocks_alone(family, offset, part_forms):
+    """The lowered part program holds no matmul, broadcast or concatenation
+    over all the bound's cached positions outside a loop whose trip count is a
+    RUNTIME value (the blocks below the part's end): none that gives keys,
+    values or scores (floats; a fill is no preparation, and on the TPU the
+    buffer is not even filled; the exact selection's masks and counts over a
+    block of queries, booleans and integers, stay over the static width:
+    ROADMAP S5(1)(i)).  And at an offset of 0, of one part and of the bound
+    less a part it leaves the logits and the cache the static form leaves."""
+    live, static, operations = part_forms(family)
+    whole = [op for op in operations
+             if re.match(r"(bf16|f32)\[", op["shape"])
+             and str(LIVE_BOUND) in re.findall(r"\d+", op["shape"])
+             and not op["runtime_loop"] and "dimensions={}" not in op["operands"]]
+    assert not whole, whole
+    # ... and the loop is there wherever something is prepared at all (a
+    # family whose cache holds a key and value a query head prepares nothing)
+    cfg, params = tiny_model(family)
+    prepares = gen.latent_cache(cfg) or cfg.n_heads != gen.kv_heads(cfg)
+    assert any(op["runtime_loop"] for op in operations) == bool(prepares)
+
+    prompt = np.random.default_rng(7).integers(1, cfg.vocab_size, LIVE_BOUND)
+    cache = gen.init_cache(cfg, 3, 96)
+    for at in range(0, offset, LIVE_PART):  # what stands before the part
+        _, cache = static(
+            params, jnp.asarray(prompt[None, at:at + LIVE_PART], jnp.int32),
+            jnp.asarray([LIVE_PART]), cache, jnp.asarray([1]), jnp.asarray([at]))
+        cache.pop("routed", None)
+    args = (params, jnp.asarray(prompt[None, offset:offset + LIVE_PART], jnp.int32),
+            jnp.asarray([LIVE_PART - 3]), cache, jnp.asarray([1]),
+            jnp.asarray([offset]))
+    (want_logits, want), (got_logits, got) = static(*args), live(*args)
+    np.testing.assert_allclose(got_logits, want_logits, atol=8e-5, rtol=2e-5)
+    assert int(got_logits.argmax()) == int(want_logits.argmax())
+    for name in set(want) - {"routed"}:
+        np.testing.assert_allclose(got[name], want[name], atol=2e-5, rtol=2e-5,
+                                   err_msg=name)
+
+
 def test_a_family_with_recurrent_layers_keeps_whole_prompts():
     cfg, params = tiny_model("granite_hybrid")
     assert not gen.can_continue(cfg)
@@ -170,6 +291,29 @@ def test_flash_kernel_under_a_runtime_key_length(first, with_keep):
             q, k, v, starts, keep=keep, interpret=True)
         assert not np.isnan(np.asarray(got)).any()
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        # ... and the same tiles where the keys come in blocks of positions
+        # (``live_blocks``: a part a block), through the index maps alone
+        in_blocks = lambda t: jnp.moveaxis(  # noqa: E731
+            t.reshape(B, H, bound // P, P, -1), 2, 0)
+        blocks = attention.continued_attention(
+            q, in_blocks(k), in_blocks(v), starts, keep=keep, interpret=True)
+        np.testing.assert_array_equal(blocks, got)
+
+
+def test_live_blocks_prepares_the_blocks_below_the_live_length():
+    """The blocks below the live length hold what was prepared of them, the
+    others what the buffer held (off the TPU: zeros), a block of positions an
+    entry of the leading axis."""
+    rows = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 64, 8))
+    heads = lambda r: (jnp.repeat(r, 3, axis=1) * 2.0,)  # noqa: E731
+    want = np.asarray(heads(rows)[0])
+    prepared = jax.jit(lambda live: attention.live_blocks(heads, (rows,), live, 16))
+    for live in (1, 16, 17, 48, 64):
+        (got,) = prepared(jnp.int32(live))
+        assert got.shape == (4, 2, 3, 16, 8)
+        for i in range(4):
+            block = want[:, :, i * 16:(i + 1) * 16] if i * 16 < live else 0.0
+            np.testing.assert_array_equal(got[i], block)
 
 
 def test_band_attention_after_reads_the_ring_ahead_of_the_part():
@@ -276,9 +420,13 @@ def test_long_prompts_between_decode_chunks_token_for_token(family, monkeypatch)
             continue
         assert not any(a == b == "part" for a, b in zip(log, log[1:])), log
         assert log.count("part") == 7 + 5 and log.count("whole") == 1
+        # a prompt of k parts prepares 1 + 2 + .. + k blocks of cached
+        # positions for its keys (those below each part's end: 28 and 15)
+        # where the static bound of 64 holds 8 a call
         assert stats["prefill"]["parts"] == {
             "prompts": 2, "calls": 12, "rows": 12, "padded_tokens": 96,
-            "live_tokens": 83}
+            "live_tokens": 83, "blocks_prepared": sum(range(1, 8)) + sum(range(1, 6)),
+            "blocks_bound": 12 * 8}
         assert stats["prefill"]["8"]["prompts"] == 3  # admitted as ever
         assert stats["prefill"]["64"]["calls"] == 0   # never called again
         # every tick that ran a part while rows decoded is interleaved, the
@@ -288,6 +436,8 @@ def test_long_prompts_between_decode_chunks_token_for_token(family, monkeypatch)
         meter = [r for r in events_mod.buffer().since(seq)
                  if r.get("message") == "prefill interference"]
         assert meter[-1]["data"]["parts"] == stats["prefill"]["parts"]
+        assert cli._parts_line(meter[-1]["data"]["parts"]).endswith(
+            "43 of 96 blocks of cached positions prepared (45%)")
     assert answers[8] == answers[10 ** 6]
     assert [len(a) for a in answers[8]] == [m for _, m in sizes]
 
