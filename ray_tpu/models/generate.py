@@ -20,8 +20,9 @@ first-class TPU path, designed for XLA:
      offset: below); a decode chunk flushes its ``steps`` columns to
      ``pos0[b]`` once, both tensors.
   2. a RING for a window layer (``cfg.sliding_windows``): ``k_ring``,
-     ``v_ring`` ``[L_window, B, KV, dh, 2 x window]``, position ``j`` at ``j %
-     ring``, so a slot costs the ring however long its context.  Prefill
+     ``v_ring`` ``[L_window, B, KV, dh, ring]`` (:func:`ring_positions`: twice
+     the window, in whole tiles), position ``j`` at ``j % ring``, so a slot
+     costs the ring however long its context.  Prefill
      keeps each row's last ring; the chunk's flush writes modulo the ring by
      a 0/1 matrix, both tensors.
   3. a LATENT slab (``cfg.latent_cache``; multi-head latent attention): ONE
@@ -43,9 +44,12 @@ first-class TPU path, designed for XLA:
      the latent kernel still reads every live tile and is handed the
      selection as a mask; a cut chunk selects as a whole one does.  (b) Its
      WINDOW layers keep latent rows of their own width in a RING
-     (``cfg.window_latent_cache``): ``c_ring`` ``[L_window, B, 1, row, 2 x
-     window]`` (1,088 values as published), kind 2's ring holding kind 3's
-     rows, read by masked einsums and flushed by the 0/1 matrix.
+     (``cfg.window_latent_cache``): ``c_ring`` ``[L_window, B, 1, row,
+     ring]`` (1,088 values as published), kind 2's ring holding kind 3's
+     rows, read as kind 3's slab is (the latent kernel over the tiles of the
+     entries a live slot's ring holds, the step's window its mask; masked
+     einsums over every row's whole ring anywhere else) and flushed by the
+     0/1 matrix.
   4. a STATE for a recurrent layer (``cfg.state_cache``; a Mamba-2 mixer,
      ``RECURRENT`` in ``cfg.sliding_windows``): ``ssm`` ``[L_state, B, tiles,
      state, heads a tile x head values]`` in float32 and ``conv`` ``[L_state, d_conv - 1, B,
@@ -181,7 +185,7 @@ covered, the next flush starts at ``pos`` again, and prefill overwrites
 ``[0, Tp)`` (a prompt in parts: ``[offset, offset + Tp)``, part after part
 from 0) and resets ``pos`` when the slot is reused.  The columns a CUT
 chunk flushes beyond its ``n`` steps (zeros) land past ``pos`` the same way;
-in a ring they overwrite entries that held positions ``2 x window`` back,
+in a ring they overwrite entries that held positions a whole ring back,
 outside every window still to come.  A slot that
 decodes needs ``pos0 + steps <= S`` (the engine sizes the cache ``bucket +
 max_new + chunk``).  For a slot that sat the chunk out the kernel writes
@@ -360,12 +364,26 @@ def cached_tensors(cfg, window: bool = False) -> Tuple[str, ...]:
 
 
 def ring_positions(window: int) -> int:
-    """Positions a slot's ring holds for a window layer: twice the window
-    (two 128-tiles at the published 128).  A decode chunk needs ``steps <=
+    """Positions a slot's ring holds for a window layer: twice the window,
+    and from one tile up whole 128-position tiles (256 at the published 128,
+    1,152 for 513: what the chip stores either way, lanes being padded to
+    whole tiles, and what a kernel that walks tiles can read), a ring under
+    a tile just twice the window.  A decode chunk needs ``steps <= ring -
     window + 1``: the flush writes all ``steps`` columns, those of a slot
     that stopped mid-chunk too, and what they overwrite has to lie outside
     every window still to come."""
-    return 2 * window
+    ring = 2 * window
+    return ring if ring < DECODE_TILE else -(-ring // DECODE_TILE) * DECODE_TILE
+
+
+def ring_read_by_tile(cache) -> bool:
+    """Whether a decode step reads the window layers' rings of ``cache`` a
+    tile of a live slot at a time (:func:`decode_chunk`): rings of latent
+    rows, in whole tiles, the row whole sublanes.  Otherwise every row's
+    whole ring, masked."""
+    ring = cache.get("c_ring")
+    return (ring is not None and ring.shape[-1] % DECODE_TILE == 0
+            and ring.shape[3] % 8 == 0)
 
 
 def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
@@ -470,7 +488,7 @@ def _cache_scores(q, k_all, v_all, l, n, plan, scale=None):
 
 
 def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int,
-                         keep=None):
+                         keep=None, **named):
     """:func:`_cache_scores` for a latent layer: ``q [B, 1, H, row]``, every
     head against the ONE row a position of layer ``l`` of ``c_all [L, B, 1,
     row, S]``, whose first ``dv`` values are the position's value vector.
@@ -478,7 +496,8 @@ def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int,
     and the masked einsums over the slab are chosen as there.  ``keep [B, S]``
     bool (None: all): of the positions ``j < n[b]`` the ones a layer that
     selects lets slot ``b`` attend; both forms still read what is live and
-    mask."""
+    mask.  A window layer's ring is read the same way
+    (:func:`_latent_ring_scores`; ``named``: the kernel call's own name)."""
     below = lambda n: jnp.arange(c_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
 
     def slab(q, c, l, n, plan=None, keep=None):
@@ -491,7 +510,7 @@ def _latent_cache_scores(q, c_all, l, n, plan, *, scale: float, dv: int,
         out = lax.platform_dependent(
             q[:, 0], c_all, l, n, plan, keep,
             tpu=lambda q, c, l, n, plan, keep: ragged_latent_decode_attention(
-                q, c, l, plan, scale=scale, dv=dv, keep=keep),
+                q, c, l, plan, scale=scale, dv=dv, keep=keep, **named),
             default=slab)
     return tuple(a[:, None] for a in out)
 
@@ -514,6 +533,25 @@ def _ring_mask(live, pos, window: int, ring: int) -> jax.Array:
     return (j >= 0) & (j > pos[:, None] - window)
 
 
+@partial(jax.jit, static_argnames=("window", "scale", "dv"))
+def _latent_ring_scores(q, c_ring, l, live, pos, plan, *, window: int,
+                        scale: float, dv: int):
+    """The cache half of a latent window layer's decode attention, read from
+    its ring: :func:`_latent_cache_scores` over the entries a slot's ring
+    holds, the step's window (:func:`_ring_mask`) its ``keep``, the kernel's
+    call under a name of its own, so that a device trace's rows of the full
+    layers' kernel stay the full layers'.  Jitted: the window layers of a step
+    are alike, so ONE trace and one lowering of the kernel serve all of them,
+    and the cut chunk's program finds the whole chunk's trace (what three
+    further traces a program cost a replica's start: PERF.md section 6,
+    PR 52)."""
+    ring = c_ring.shape[-1]
+    return _latent_cache_scores(
+        q, c_ring, l, jnp.minimum(live, ring), plan, scale=scale, dv=dv,
+        keep=_ring_mask(live, pos, window, ring),
+        name="ragged_latent_ring_attention")
+
+
 def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
                    scale: Optional[float] = None, keep_new=None) -> jax.Array:
     """q ``[B, H, 1, dh]`` of chunk step ``i`` against the keys a slot has:
@@ -522,9 +560,10 @@ def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
     window`` too).  ``cached(q)`` gives the cache half for ``q [B, KV, G,
     dh]``, un-normalised (:func:`_cache_scores` over the positions ``j < n``
     of a full layer, ``n`` where the slot stood when the chunk began and 0
-    for a slot that was inactive then; :func:`_cache_scores_slab` over a
-    window layer's ring).  ONE softmax over both score sets: the halves are
-    merged under the shared max and denominator.  GQA folds the query heads
+    for a slot that was inactive then; :func:`_cache_scores_slab`, or a latent
+    family's :func:`_latent_cache_scores`, over a window layer's ring).  ONE
+    softmax over both score sets: the halves are merged under the shared max
+    and denominator.  GQA folds the query heads
     onto their KV head by reshape (no materialized repeat).  A latent layer
     is the same sums with one KV head, a ``scale`` of its own (None: ``dh **
     -0.5``) and values narrower than keys (``v_new``: the first values of
@@ -1025,8 +1064,9 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # the flush holds a start to S - steps silently (a dynamic_update_slice
     # clamps, and the kernel's plan does as it does): a slot at pos0 needs
     # pos0 + steps <= S (the engine's bucket + max_new + chunk); a ring's
-    # flush, steps <= window + 1 (ring_positions)
-    assert steps <= S and (not window or steps <= window + 1), (steps, S, window)
+    # flush, steps <= ring - window + 1 (ring_positions)
+    assert steps <= S and (not window or steps <= ring - window + 1), (
+        steps, S, window, ring)
     assert not compact or steps <= S - compact[0], (steps, S, compact)
     if steps == 0:
         return jnp.zeros((B, 0), jnp.int32), cache, active, key
@@ -1045,6 +1085,13 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         plan = ragged_decode_plan(live, S // DECODE_TILE)
         if steps <= DECODE_TILE:
             to_flush = cache_flush_plan(active, place0, steps, S, written=n)
+    # a latent family's rings, read as its slabs are: a live slot's ring
+    # holds min(live, ring) entries, whole tiles of them listed, and the step's
+    # window is the kernel's mask (a ring of K and V per head stays a slab
+    # read: that kernel takes no mask)
+    ring_plan = ragged_decode_plan(
+        jnp.minimum(live, ring),
+        ring // DECODE_TILE) if ring_read_by_tile(cache) else None
     # a family that compacts: the summaries of the windows before a slot's
     # own, ``rows a window`` for each it has filled, read as the window is
     sums = tuple(cache[name] for name in SUMMARIES) if compact else ()
@@ -1092,9 +1139,9 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     with jax.named_scope("attention.eva_merge"):
                         return eva.merge(near, rest)
                 if latent and w:
-                    return tuple(a[:, None] for a in latent_slab_attention(
-                        q[:, 0], rings[0], at, _ring_mask(live, pos, w, ring),
-                        scale=scale, dv=latent_cache(cfg, True)[1]))
+                    return _latent_ring_scores(
+                        q, rings[0], at, live, pos, ring_plan, window=w,
+                        scale=scale, dv=latent_cache(cfg, True)[1])
                 if latent:
                     return _latent_cache_scores(
                         q, old[0], at, live, plan, scale=scale, dv=latent[1],

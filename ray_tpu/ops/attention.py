@@ -985,7 +985,8 @@ def _ragged_latent_kernel(layer_ref, plan_ref, q_ref, c_hbm, *rest,
 def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
                                    layer: jax.Array, plan: jax.Array, *,
                                    scale: float, dv: int, keep=None,
-                                   interpret=False):
+                                   interpret=False,
+                                   name="ragged_latent_decode_attention"):
     """The cache half of a decode step's LATENT attention, reading only what
     is live: ``q [B, H, dk]`` (one absorbed query a head a slot) against layer
     ``layer`` of the WHOLE latent cache ``c [L, B, 1, dk, S]`` (one row a
@@ -1001,7 +1002,9 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
     of cache.  Needs ``S % 128 == 0`` and ``dk, dv % 8 == 0``.  ``keep [B, S]``
     (None: every live position): the positions a slot's queries attend, where
     a layer selects them (one mask a slot, shared by its heads); the kernel
-    still reads every live tile and masks.
+    still reads every live tile and masks.  ``name``: the call's own in the
+    compiled program and in a device trace, for a caller whose reads are to be
+    told from another's.
 
     What it shares with :func:`ragged_decode_attention`: the plan, the walk,
     the double buffer, the un-normalised result.  Why it is not that kernel:
@@ -1045,7 +1048,7 @@ def ragged_latent_decode_attention(q: jax.Array, c: jax.Array,
         # pipeline: 33 slots x 64 heads x (576 bf16 + 768 f32) is 18 MB
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
-        name="ragged_latent_decode_attention",
+        name=name,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q.astype(c.dtype), c,
       *masks)

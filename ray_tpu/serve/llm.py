@@ -534,12 +534,14 @@ class GenerationEngine:
         self._layers = {"full": windows.count(0),
                         "window": len(windows) - windows.count(0) - n_state,
                         **({"state": n_state} if n_state else {})}
-        # per layer of the kind: a full layer reads a slot's live tiles, a
+        # per layer of the kind: a full layer reads a slot's live tiles; a
         # window layer every row's whole ring (two tiles at the published
-        # window), whatever the context
+        # window; ``held_window``), whatever the context, or, where the rings
+        # hold latent rows in whole tiles, the tiles of the entries a
+        # dispatched row's ring holds (``generate.decode_chunk``'s ring plan)
         self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
-        self._cache_tiles = {"read_full": 0, "read_window": 0, "padded": 0,
-                             "flushed": 0}
+        self._cache_tiles = {"read_full": 0, "read_window": 0,
+                             "held_window": 0, "padded": 0, "flushed": 0}
         if self._compact:
             # a compacting family's own, cumulative like the rest and counted
             # at dispatch on the host (they ride HERE because a traced
@@ -580,6 +582,7 @@ class GenerationEngine:
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
+        self._ring_by_tile = gen.ring_read_by_tile(self.cache)
         # tiles of the padded slab a full layer a slot (a compacting family:
         # its window's and its summaries')
         self._slab_tiles = sum(
@@ -843,7 +846,8 @@ class GenerationEngine:
             "stages": stages,
             # how much of the padded cache the decode steps read, in tiles a
             # layer of each kind (cumulative; counted at dispatch from prompt
-            # lengths and scheduled tokens), and how much of it a chunk's
+            # lengths and scheduled tokens; ``read_window`` of every row's
+            # ring, ``held_window``), and how much of it a chunk's
             # flush writes (``flushed``: tiles a full layer a tensor, against
             # ``padded``); how many layers of each kind (a latent layer is a
             # full one) and the bytes of one tile of each
@@ -1208,8 +1212,11 @@ class GenerationEngine:
                 self._cache_tiles["flushed"] += sum(
                     1 + (at % self._tile > self._tile - n) for at in stands)
                 if self._layers["window"]:
-                    self._cache_tiles["read_window"] += (
-                        (self.n_slots + 1) * self._ring_tiles)
+                    held = (self.n_slots + 1) * self._ring_tiles
+                    self._cache_tiles["held_window"] += held
+                    self._cache_tiles["read_window"] += sum(
+                        min(-(-at // self._tile), self._ring_tiles)
+                        for at in stands) if self._ring_by_tile else held
                 self._cache_tiles["padded"] += (
                     (self.n_slots + 1) * self._slab_tiles)
                 for i, req in rows:

@@ -312,8 +312,9 @@ def _lower_dots3_cell(chip):
     """The serve-dots3-note-prev-ep32-docs cell's programs: two full latent
     layers that SELECT (a 576-value row and a 128-value index key a position,
     33 rows x 17,536 positions), three sliding latent layers over rings of
-    1,026 rows of 1,088 values, 8 held experts a sparse layer; the decode
-    chunk hands the latent kernel the selection as a mask; a prompt of at
+    1,152 rows of 1,088 values (nine whole tiles), 8 held experts a sparse
+    layer; the decode chunk hands the latent kernel the selection as a mask
+    on a full layer and the step's window on a sliding one; a prompt of at
     most one part (2,048 tokens) through its bucket's program, every longer
     one through the part program: the full layers' cached rows up-projected,
     the selection over cached and own index keys, and the Pallas forward
@@ -331,7 +332,7 @@ def _lower_dots3_cell(chip):
     assert set(cache) == {"c", "idx_k", "c_ring", "pos"}
     assert cache["c"].shape == (2, 33, 1, 576, 17536)
     assert cache["idx_k"].shape == (2, 33, 1, 128, 17536)
-    assert cache["c_ring"].shape == (3, 33, 1, 1088, 1026)
+    assert cache["c_ring"].shape == (3, 33, 1, 1088, 1152)
     prefill, decode, cut, part = llm.engine_programs(
         cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(16384))
     prefill_of = partial(_lower_prefill, chip, prefill, params, cache, n_slots)
@@ -657,8 +658,12 @@ def test_program_compiles_for_v5e(compiled, name):
                 assert made == line or " bf16[9,4096,768]{" not in made, line[:200]
         assert all(12.9e9 < need < 14.0e9 for need in needs), needs
     if name == "serve_engine_dots3_cell":
-        # the latent kernel once a full layer, given the step's selection as
-        # a further operand; the sliding layers' rings are read by einsums;
+        # the latent kernel FIVE times a decode step: once a full layer,
+        # given the step's selection as a further operand, and once a sliding
+        # layer (under a name of its own: a trace's rows of the full layers'
+        # kernel stay theirs), over the tiles of the entries a live row's ring
+        # holds and given the step's window (PR 52: no layer's ring is sliced out of
+        # ``c_ring``, 82.7 MB a layer, for masked einsums over every row);
         # the flush kernel over ``c`` and ``idx_k``; the grouped matmuls of
         # four expert layers.  Every prompt above one part of 2,048 tokens
         # goes through the PART program (the first of the list), which runs
@@ -670,14 +675,23 @@ def test_program_compiles_for_v5e(compiled, name):
         # kernel.  3.64 GB of weights and 1.85 GB of cache resident
         for decode in programs[1:3]:
             text = decode.as_text()
-            kernels = [line for line in text.splitlines()
-                       if re.search(r"%ragged_latent_decode_attention[.\d]* = ", line)]
-            assert len(kernels) == 2, len(kernels)
-            assert all("f32[33,144,128]" in line for line in kernels)  # the mask
+            kernels = [line for line in text.splitlines() if re.search(
+                r"%ragged_latent_(decode|ring)_attention[.\d]* = ", line)]
+            assert sum("%ragged_latent_ring" in line for line in kernels) == 3
+            assert all(("attention.latent_window" in line)
+                       == ("%ragged_latent_ring" in line) for line in kernels)
+            assert len(kernels) == 5, len(kernels)
+            for line in kernels:  # the mask: a slot's tiles, in groups of 8
+                ring = "%ragged_latent_ring" in line
+                assert ("f32[33,16,128]" if ring else "f32[33,144,128]") in line
+            for line in text.splitlines():  # one layer's ring, sliced out
+                made = line.split(" fusion(")[0].split(" copy(")[0]
+                assert made == line or not re.search(
+                    r" bf16\[33,(1,)?1088,\d+\]\{", made), line[:200]
             flushes = [line for line in text.splitlines()
                        if re.search(r"%cache_flush[.\d]* = ", line)]
             assert len(flushes) == 2, flushes
-            assert text.count("tpu_custom_call") >= 2 + 2 + 4 * 3
+            assert text.count("tpu_custom_call") >= 5 + 2 + 4 * 3
             assert decode.memory_analysis().temp_size_in_bytes < 0.6e9
         for prefill in programs[:1] + programs[3:]:
             assert prefill.as_text().count("flash_attention_fwd") >= 2

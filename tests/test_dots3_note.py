@@ -109,15 +109,16 @@ def test_config_is_the_published_one_and_says_what_it_caches():
     assert gen.cached_tensors(cfg) == ("c", "idx_k")
     assert gen.cached_tensors(cfg, True) == ("c_ring",)
     # the cell's cache: 33 rows x 17,536 positions x 2 full layers x (576 +
-    # 128) values, and three rings of 1,026 rows of 1,088
+    # 128) values, and three rings of 1,152 rows of 1,088 (nine whole tiles:
+    # what the chip stores of twice the window's 1,026 either way)
     cell = dataclasses.replace(cfg, n_layers=5)
     cache = jax.eval_shape(lambda: gen.init_cache(cell, 33, 17536))
     assert set(cache) == {"c", "idx_k", "c_ring", "pos"}
     assert cache["c"].shape == (2, 33, 1, 576, 17536)
     assert cache["idx_k"].shape == (2, 33, 1, 128, 17536)
-    assert cache["c_ring"].shape == (3, 33, 1, 1088, 1026)
+    assert cache["c_ring"].shape == (3, 33, 1, 1088, 1152)
     assert (cache["c"].size + cache["idx_k"].size) * 2 == 1_629_585_408
-    assert cache["c_ring"].size * 2 == 221_025_024
+    assert cache["c_ring"].size * 2 == 248_168_448
     with pytest.raises(AssertionError):
         dn.Dots3NoteConfig.tiny(experts_held=(12, 8))  # past the router
 
@@ -348,6 +349,42 @@ def test_engine_serves_a_mixed_batch_and_counts_the_selection(model):
     assert np.asarray(stats["moe"]["decode"]["tokens"]).shape == (4, 8)
 
 
+def test_engine_counts_the_ring_tiles_a_chunk_lists():
+    """A window of 100: rings of 256 entries, two whole tiles, which a chunk
+    reads by tile.  One dispatch with two live rows, at positions 40 and 300:
+    ``read_window`` counts the tiles of the entries each row's ring holds (one
+    of the ring not yet full, both of the one that wrapped; a sliding layer),
+    ``held_window`` every row's whole ring, which is what the parent read."""
+    eng, cfg, _ = engine(
+        "dots3_note", changed=(("sliding_window", 100),), n_slots=3,
+        max_new_tokens=8, decode_chunk_steps=3, prefill_buckets=(64, 512))
+    assert eng.cache["c_ring"].shape[-1] == 256
+    assert gen.ring_read_by_tile(eng.cache)
+    rng = np.random.RandomState(13)
+    futs = [eng.submit(list(rng.randint(0, cfg.vocab_size, n)), 8)
+            for n in (40, 300)]
+    for _ in range(8):
+        before = eng.perf_stats()["cache_tiles"]
+        eng.step()
+        chunk = eng._pending
+        if chunk is not None and chunk.chunk_dev is not None and len(chunk.rows) == 2:
+            break
+    after = eng.perf_stats()["cache_tiles"]
+    # where the two rows stood when the chunk was dispatched
+    stands = sorted(len(req.tokens) + req.scheduled - chunk.steps - 1
+                    for _, req in chunk.rows)
+    assert stands == [40, 300]
+    assert after["read_window"] - before["read_window"] == 1 + 2
+    assert after["held_window"] - before["held_window"] == (3 + 1) * 2
+    run_engine(eng, futs)
+    tiles = eng.perf_stats()["cache_tiles"]
+    assert 0 < tiles["read_window"] < tiles["held_window"]
+    # the tiny preset's rings are under a tile (10 entries): every row's is
+    # read whole, as a ring of K and V per head is
+    assert not gen.ring_read_by_tile(jax.eval_shape(
+        lambda: gen.init_cache(tiny_model("dots3_note")[0], 4, 64)))
+
+
 def test_masked_flash_kernel_against_the_materialised_mask():
     """``masked_attention``: the Pallas forward kernel with the row mask as a
     fourth operand (TPU interpreter), cells that hold none of a row's
@@ -389,6 +426,54 @@ def test_latent_kernel_takes_a_selection():
         interpret=pltpu.InterpretParams())
     for name, g, w in zip(("acc", "m", "d"), got, want):
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+# a sliding layer's ring through the latent kernel: (where the slot stood
+# when the chunk began, the step's position) of slot 0, window 100 in a ring of
+# 256 entries (two tiles); slot 1 beside it, mid-ring, so that the walk crosses
+# slots
+RING_READS = {
+    "shorter_than_the_window": (60, 63),
+    "wrapped": (700, 705),
+    "sits_the_chunk_out": (0, 0),
+    "at_a_tiles_edge": (128, 128),
+    "at_the_rings_edge": (256, 259),
+}
+
+
+@pytest.mark.parametrize("case", list(RING_READS))
+def test_ring_read_through_the_latent_kernel(case, lowered_for_tpu):
+    """``decode_chunk``'s read of a latent ring as a chip runs it
+    (``_latent_cache_scores``: the latent kernel, TPU interpreter, over the
+    tiles of the entries a slot's ring holds, the step's window its ``keep``)
+    against ``latent_slab_attention`` under ``_ring_mask`` alone, which is the
+    parent's read: a ring not yet full, one that has wrapped, a slot that sits
+    the chunk out (``acc = 0, d = 0`` and no tile listed), a ring that ends
+    exactly on a tile's edge and on its own."""
+    window = 100
+    ring = gen.ring_positions(window)
+    assert ring == 256
+    live = jnp.asarray([RING_READS[case][0], 150, 0])
+    pos = jnp.asarray([RING_READS[case][1], 152, 0])
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    B, H, dk, dv = 3, 8, 48, 32
+    c = jax.random.normal(ks[0], (2, B, 1, dk, ring))
+    q = jax.random.normal(ks[1], (B, 1, H, dk))
+    held = jnp.minimum(live, ring)
+    plan = attention.ragged_decode_plan(held, ring // attention.DECODE_TILE)
+    assert int(plan[0]) == sum(-(-int(n) // 128) for n in held)
+    mask = gen._ring_mask(live, pos, window, ring)
+    # what the window lets the slot attend, counted by hand
+    assert int(mask[0].sum()) == max(0, min(
+        int(live[0]), window - 1 - int(pos[0] - live[0])))
+    want = attention.latent_slab_attention(
+        q[:, 0], c, jnp.int32(1), mask, scale=0.2, dv=dv)
+    got = gen._latent_cache_scores(
+        q, c, jnp.int32(1), held, plan, scale=0.2, dv=dv, keep=mask)
+    for name, g, w in zip(("acc", "m", "d"), got, want):
+        np.testing.assert_allclose(g[:, 0], w, rtol=2e-5, atol=2e-5, err_msg=name)
+    if not int(live[0]):
+        assert float(jnp.abs(got[0][0]).max()) == 0.0 == float(got[2][0].max())
 
 
 def test_band_attention_walks_large_bands_a_block_at_a_time(monkeypatch):

@@ -396,6 +396,7 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
     assert tiles["layers"] == {"full": 1, "window": 4}
     dispatches = tiles["padded"] // 5  # 5 rows x 1 tile of 128
     assert tiles["read_window"] == dispatches * 5 and tiles["read_full"] > 0
+    assert tiles["held_window"] == tiles["read_window"]  # K and V per head
     # the routing counts of every drained dispatch, prefill calls (several a
     # tick) and chunks apart
     routed = stats["moe"]

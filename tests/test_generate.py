@@ -163,6 +163,28 @@ def test_chunk_straddles_a_128_position_boundary(family, positions):
     eng.assert_greedy({0: 17, 1: 17})
 
 
+def test_a_latent_ring_of_whole_tiles_is_read_by_tile(positions):
+    """dots3-note with a window of 64: its rings are one whole tile (128
+    entries), so a chunk reads them as it reads the latent slabs: the tiles of
+    the entries a live slot's ring holds (``decode_chunk``'s ring plan; as a
+    chip runs it the latent kernel with the step's window as its mask, on the
+    CPU the masked einsums).  A ring that has wrapped (150 > 128), one shorter
+    than the window, one exactly full, an idle slot and the scratch slot
+    beside them, over two chunks: every token is the full forward's."""
+    eng = Slots("dots3_note", positions(LONG), sliding_window=64)
+    assert eng.cache["c_ring"].shape[-1] == 128
+    assert gen.ring_read_by_tile(eng.cache)
+    rng = np.random.default_rng(1)
+    prompts = {0: 150, 1: 20, 3: 128}
+    for slot, length in prompts.items():
+        eng.admit(slot, [int(t) for t in rng.integers(1, 200, size=length)],
+                  -(-length // 32) * 32)
+    eng.decode(4)
+    eng.decode(4)
+    eng.assert_greedy(dict.fromkeys(prompts, 9))
+    assert [int(p) for p in eng.cache["pos"]][:4] == [158, 28, 0, 136]
+
+
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_chunk_of_one_and_of_none(family):
     """``steps=1`` is the one-step form; ``steps=0`` returns the cache as

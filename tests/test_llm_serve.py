@@ -78,7 +78,8 @@ def test_cache_tiles_counts_what_the_decode_chunks_read():
     eng, cfg, params = engine("gpt2", **TILES)  # never started
     assert eng.cache["k"].shape[-1] == 256  # 160 + 8 + 3 = 171 -> two tiles
     assert eng.perf_stats()["cache_tiles"] == {
-        "read_full": 0, "read_window": 0, "padded": 0, "flushed": 0,
+        "read_full": 0, "read_window": 0, "held_window": 0, "padded": 0,
+        "flushed": 0,
         "layers": {"full": cfg.n_layers, "window": 0},
         # k and v of every head, 128 positions, float32 here
         "tile_bytes": {"full": 2 * cfg.d_model * 128 * 4, "window": 0}}
