@@ -1,5 +1,5 @@
 """KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
-EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note, EvaByte).
+EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note, EvaByte, Phi-4-flash).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
@@ -61,7 +61,11 @@ first-class TPU path, designed for XLA:
      (:func:`ray_tpu.ops.ssm.state_update`: lowered for a TPU a Pallas kernel
      over the slots that were active when the chunk began); a cut chunk has
      advanced it ``n`` steps; there is nothing to flush.  The family's other
-     layers keep K and V in the slab (1).
+     layers keep K and V in the slab (1).  A Mamba-1 state
+     (:mod:`ray_tpu.models.phi4_flash`: a decay per channel AND state
+     element) is ``[L_state, B, N, rows, 128]``, the state index leading and
+     the channels on the lanes, and has an update of its own over the same
+     plan (:func:`ray_tpu.ops.ssm.selective_state_update`).
   5. a WINDOW AND ITS SUMMARIES for a layer that compacts
      (``cfg.summary_cache``: ``(window, chunk)``; :mod:`ray_tpu.models.evabyte`,
      :mod:`ray_tpu.ops.eva`), two pairs of tensors a layer that trade places
@@ -89,6 +93,20 @@ first-class TPU path, designed for XLA:
      window) attends the cached summaries laid ahead of its own keys
      (:func:`ray_tpu.ops.eva.windowed_attention`).
 
+  None of these has to be OWNED by the layer that reads it: a family may say
+  that some layers read ANOTHER layer's slab and that some cache nothing
+  (:func:`layer_windows`'s entries :func:`reads_layer` and ``UNCACHED``;
+  :func:`shared_cache`; :mod:`ray_tpu.models.phi4_flash`: ONE full layer's K
+  and V read by every cross-attention layer above it, gated memory units
+  between them).  ``init_cache`` then makes ONE slab (``k``, ``v`` ``[1, B,
+  ...]``) whatever the readers; a decode step's readers attend the cache below
+  ``live`` and the owner's chunk-local columns (:func:`_decode_attend`), and a
+  prefill runs the layers above the slab for a row's LAST position alone
+  (:func:`prefill_at`'s ``final``).  Such a family's window layers may have
+  their rings read a live slot's tiles at a time
+  (``cfg.window_rings_by_tile``; :func:`_ring_scores`: the ragged kernel with
+  the step's window as its mask).
+
 - **A prefill that continues** (:func:`prefill_at`'s ``offsets``): a prompt
   need not go into its slot in ONE call.  A call's rows may be PARTS: row
   ``b``'s tokens sit at positions ``offsets[b] ..`` of a slot whose earlier
@@ -106,9 +124,11 @@ first-class TPU path, designed for XLA:
   neither folds nor fetches a block beyond it, so a prompt's parts add up to
   the whole call's cells.  Kind 2 reads the positions just ahead of the part
   by their places in the ring and leaves the last ``ring`` positions of
-  prefix-and-part behind.  Kind 4 cannot: a recurrent layer's prefill starts
-  from a zero state, so such a family keeps whole prompts
-  (:func:`can_continue`).  Without ``offsets`` a call is a whole prompt from
+  prefix-and-part behind.  Kind 4 can where the family says so
+  (``cfg.state_carried_in``: Mamba-1's scan takes the slot's state IN and its
+  convolution the slot's last inputs, zeros for a part at offset 0); a family
+  that does not (Granite's Mamba-2: its chunked scan starts from zero) keeps
+  whole prompts (:func:`can_continue`).  Without ``offsets`` a call is a whole prompt from
   position 0, the slot written from scratch.
 - **One block per family**: prefill and decode run the block training
   runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
@@ -211,6 +231,7 @@ from ray_tpu.models import (
     granite_hybrid,
     kimi_k2,
     llama,
+    phi4_flash,
 )
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops import dsa, eva, ssm
@@ -257,12 +278,28 @@ from ray_tpu.ops.attention import (
 # (``summary_cache``: window and chunk), keeps its parameters stacked, and has
 # ``pooling(p)``: a layer's (or the stack's) pooling vectors, which the prefill
 # hands the attention middle and the decode's roll-over pools a full window by.
+# A family whose upper layers read ONE lower layer's slab says so in
+# ``sliding_windows`` (entries ``READS - k`` and ``UNCACHED``) and has
+# ``lower_stack`` (the layers below the slab, rolled, threading the caller's
+# carry through middles ``mamba(at, x, p, carry)`` and ``attend(at, q, k, v,
+# carry)``), ``shared_kv``, ``shared_layer`` and ``upper_stack`` (the layers
+# above, handed the slab's read), ``mamba_whole`` and ``mamba_step``.
 FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
             "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid,
-            "dots3_note": dots3_note, "evabyte": evabyte}
+            "dots3_note": dots3_note, "evabyte": evabyte,
+            "phi4_flash": phi4_flash}
 
 # a layer's entry in :func:`layer_windows` that attends no position at all
 RECURRENT = granite_hybrid.RECURRENT
+# ... that caches nothing and mixes no positions either (a gated memory unit)
+UNCACHED = phi4_flash.UNCACHED
+
+
+def reads_layer(w: int) -> Optional[int]:
+    """The layer whose slab a layer with entry ``w`` of :func:`layer_windows`
+    reads (it caches nothing of its own: cross attention over ANOTHER layer's
+    K and V), or None."""
+    return phi4_flash.READS - w if w <= phi4_flash.READS else None
 
 
 def family_of(cfg):
@@ -281,11 +318,25 @@ def layer_windows(cfg) -> Tuple[int, ...]:
     """Per layer, the positions it attends: 0 is every one (a full layer), a
     window size ``W`` the last ``W`` (a window layer; ``cfg.sliding_windows``
     of a family that mixes them, with one size), ``RECURRENT`` none (a layer
-    that carries a state: :func:`state_cache`)."""
+    that carries a state: :func:`state_cache`), ``UNCACHED`` none and no state
+    either, and an entry :func:`reads_layer` knows the slab of the full layer
+    it names (:func:`shared_cache`)."""
     windows = tuple(getattr(cfg, "sliding_windows", ()) or (0,) * cfg.n_layers)
     assert len(windows) == cfg.n_layers and len(
-        set(windows) - {0, RECURRENT}) <= 1, windows
+        {w for w in windows if w > 0}) <= 1, windows
     return windows
+
+
+def shared_cache(cfg) -> Optional[int]:
+    """For a family whose upper layers read ONE lower layer's K and V (a
+    decoder-hybrid-decoder: :mod:`ray_tpu.models.phi4_flash`): the layer that
+    owns the slab, the family's only full layer.  Such a family's module has
+    ``lower_stack``, ``shared_kv``, ``shared_layer`` and ``upper_stack``, and
+    its prefill runs the layers above the slab for a prompt's LAST position
+    alone.  None: every attention layer owns what it reads."""
+    owners = {reads_layer(w) for w in layer_windows(cfg)} - {None}
+    assert len(owners) <= 1, owners
+    return owners.pop() if owners else None
 
 
 def state_cache(cfg) -> Optional[dict]:
@@ -376,12 +427,15 @@ def ring_positions(window: int) -> int:
     return ring if ring < DECODE_TILE else -(-ring // DECODE_TILE) * DECODE_TILE
 
 
-def ring_read_by_tile(cache) -> bool:
+def ring_read_by_tile(cache, cfg=None) -> bool:
     """Whether a decode step reads the window layers' rings of ``cache`` a
     tile of a live slot at a time (:func:`decode_chunk`): rings of latent
-    rows, in whole tiles, the row whole sublanes.  Otherwise every row's
-    whole ring, masked."""
+    rows, or rings of K and V where the config says so
+    (``cfg.window_rings_by_tile``), in whole tiles, the row whole sublanes.
+    Otherwise every row's whole ring, masked."""
     ring = cache.get("c_ring")
+    if ring is None and getattr(cfg, "window_rings_by_tile", False):
+        ring = cache.get("k_ring")
     return (ring is not None and ring.shape[-1] % DECODE_TILE == 0
             and ring.shape[3] % 8 == 0)
 
@@ -409,7 +463,7 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     the slot has filled (:func:`summary_rows` of ``max_len``)."""
     windows = layer_windows(cfg)
     n_full, n_state = windows.count(0), windows.count(RECURRENT)
-    n_window = len(windows) - n_full - n_state
+    n_window = sum(w > 0 for w in windows)
     if latent_cache(cfg):
         rows = lambda width, layers, length: jnp.zeros(  # noqa: E731
             (layers, n_slots, 1, width, length), cfg.dtype)
@@ -422,7 +476,8 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
                                    ring_positions(max(windows)))
         return cache
     slab = lambda layers, length: jnp.zeros(  # noqa: E731
-        (layers, n_slots, kv_heads(cfg), cfg.head_dim, length), cfg.dtype)
+        (layers, n_slots, kv_heads(cfg),
+         getattr(cfg, "cache_head_dim", cfg.head_dim), length), cfg.dtype)
     if summary_cache(cfg):
         held, rows = window_positions(summary_cache(cfg)[0]), summary_rows(cfg, max_len)
         return {"k": slab(n_full, held), "v": slab(n_full, held),
@@ -469,20 +524,21 @@ def _cache_scores_slab(q, k_all, v_all, l, mask, scale=None):
     return acc, m, e.sum(-1)
 
 
-def _cache_scores(q, k_all, v_all, l, n, plan, scale=None):
+def _cache_scores(q, k_all, v_all, l, n, plan, scale=None, **named):
     """``q [B, KV, G, dh]`` against positions ``j < n[b]`` of layer ``l`` of
     the whole caches ``[L, B, KV, dh, S]``: ``(acc, m, d)``, the softmax
     un-normalised.  Lowered for a TPU, with a cache of whole 128-position
     tiles, the Pallas kernel that copies in only the tiles below ``n[b]``;
     anywhere else the masked einsums over the slab.  Decided by what the
-    program is lowered for and by the cache's shape, never by a flag."""
+    program is lowered for and by the cache's shape, never by a flag.
+    ``named``: the kernel call's own name."""
     below = lambda n: jnp.arange(k_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
     if plan is None:
         return _cache_scores_slab(q, k_all, v_all, l, below(n), scale)
     return lax.platform_dependent(
         q, k_all, v_all, l, n, plan,
         tpu=lambda q, k, v, l, n, plan: ragged_decode_attention(
-            q, k, v, l, plan, scale=scale),
+            q, k, v, l, plan, scale=scale, **named),
         default=lambda q, k, v, l, n, plan: _cache_scores_slab(
             q, k, v, l, below(n), scale))
 
@@ -550,6 +606,25 @@ def _latent_ring_scores(q, c_ring, l, live, pos, plan, *, window: int,
         q, c_ring, l, jnp.minimum(live, ring), plan, scale=scale, dv=dv,
         keep=_ring_mask(live, pos, window, ring),
         name="ragged_latent_ring_attention")
+
+
+def _ring_scores(q, k_ring, v_ring, l, live, pos, plan, *, window: int,
+                 scale: Optional[float]):
+    """The cache half of a window layer's decode attention, read from its
+    rings of K and V a live slot's tiles at a time: :func:`_cache_scores` over
+    the entries a slot's ring holds (``plan``: of ``min(live, ring)``), the
+    step's window (:func:`_ring_mask`) the kernel's ``keep``, the call under a
+    name of its own; anywhere else the masked einsums over every row's ring."""
+    keep = _ring_mask(live, pos, window, k_ring.shape[-1])
+    if plan is None:
+        return _cache_scores_slab(q, k_ring, v_ring, l, keep, scale)
+    return lax.platform_dependent(
+        q, k_ring, v_ring, l, keep, plan,
+        tpu=lambda q, k, v, l, keep, plan: ragged_decode_attention(
+            q, k, v, l, plan, scale=scale, keep=keep,
+            name="ragged_ring_attention"),
+        default=lambda q, k, v, l, keep, plan: _cache_scores_slab(
+            q, k, v, l, keep, scale))
 
 
 def _decode_attend(q, cached, k_new, v_new, i, window: int = 0,
@@ -663,8 +738,11 @@ def can_continue(cfg) -> bool:
     (:func:`prefill_at`'s ``offsets``): every layer caches positions, which a
     later part can read back.  A family with recurrent layers
     (:func:`state_cache`) keeps whole prompts: its prefill starts from a zero
-    state, and a state carried IN is not built."""
-    return state_cache(cfg) is None
+    state, unless its config says that the state a part leaves behind is the
+    state the next part starts from (``cfg.state_carried_in``: the scan takes
+    a state IN, the convolution the inputs before the part)."""
+    return state_cache(cfg) is None or bool(
+        getattr(cfg, "state_carried_in", False))
 
 
 def _placed(held, new, offsets):
@@ -695,7 +773,8 @@ def _preceded(ring, new, offsets, window: int):
 def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                cache: Dict[str, jax.Array], slots: jax.Array,
                offsets: Optional[jax.Array] = None,
-               bound: Optional[int] = None) -> Tuple[jax.Array, Dict]:
+               bound: Optional[int] = None,
+               final: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
     """Run the prompts ``tokens [B, Tp]`` (right-padded; true lengths
     ``lengths [B]``) and write K/V into cache slots ``slots [B]`` (any
     subset — one compiled program admits a whole batch of requests).  Returns
@@ -741,12 +820,25 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     - a latent slab that selects: the part's index queries score the cached
       index keys below ``offsets`` and the part's own (the live blocks alone),
       ONE threshold over both (:func:`ray_tpu.ops.dsa.causal_top_k_mask`): the
-      whole prompt's selection."""
+      whole prompt's selection;
+    - a state (``cfg.state_carried_in``): the scan starts from the state and
+      the convolution from the last inputs the slot holds (zeros for a part at
+      offset 0, whatever the slot's last tenant left), and both are written
+      back as they stand after the part's last real token.
+
+    A family whose upper layers read ONE lower layer's slab
+    (:func:`shared_cache`) runs its lower layers and the slab's K and V at
+    every position, and everything above for each row's LAST position alone,
+    against the slab as this call leaves it.  ``final [B]`` bool (None: every
+    row): the rows whose last position ENDS a prompt; where none does (a part
+    that is not its prompt's last) the upper layers and the head do not run at
+    all and the logits are zeros."""
     fam = family_of(cfg)
     B, Tp = tokens.shape
     windows = layer_windows(cfg)
     part = offsets is not None
     compact = summary_cache(cfg)
+    shared = shared_cache(cfg)
     if part:
         assert can_continue(cfg), "a recurrent layer's prompt is prefilled whole"
         offsets = offsets.astype(jnp.int32)
@@ -754,9 +846,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         if compact:  # a part reads the summaries, a row a chunk of ``bound``
             bound = bound and bound // compact[1]
         # what the cache holds of the rows' slots, by kind of layer
-        ahead = {False: tuple(cache[n][:, slots, :, :, :bound]
-                              for n in (SUMMARIES if compact
-                                        else cached_tensors(cfg))),
+        # (a family that shares one slab reads it for a last position alone)
+        ahead = {False: () if shared is not None else tuple(
+                     cache[n][:, slots, :, :, :bound]
+                     for n in (SUMMARIES if compact else cached_tensors(cfg))),
                  True: tuple(cache[n][:, slots]
                              for n in cached_tensors(cfg, True)
                              ) if max(windows) > 0 else ()}
@@ -834,6 +927,16 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         x, full = lax.scan(body, x, (params["blocks"],
                                      *(ahead[False] if part else ())))
         ringed = states = ()
+    elif shared is not None:  # the lower half, and the shared slab's K and V
+        carried = None
+        if part:  # what the slot holds of the part before; nothing at offset 0
+            carried = tuple(
+                jnp.where((offsets > 0).reshape(1, -1, *(1,) * (t.ndim - 2)), t, 0)
+                for t in (cache["ssm"][:, slots],
+                          jnp.swapaxes(cache["conv"][:, :, slots], 1, 2)))
+        x, memory, full, ringed, states = _prefill_lower(
+            fam, params, cfg, x, attend, lengths, carried,
+            ahead[True] if part else None, max(windows))
     elif state_cache(cfg):  # runs of recurrent layers rolled, the others listed
         x, routed, full, states = _prefill_runs(
             fam, params, cfg, x, attend, positions, lengths)
@@ -908,9 +1011,33 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                   + (rows - top) * index_cache(cfg)[1]).sum()
         out["routed"] = {**out.get("routed", {}), **_selection_counts(
             cfg, scored=pairs, selected=chosen, read=pairs)}
-    last = fam.unembed(params, jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1), cfg)
-    return last[:, 0, :], out
+    at_last = (lengths - 1)[:, None, None].astype(jnp.int32)
+    x = jnp.take_along_axis(x, at_last, axis=1)
+    if shared is None:
+        return fam.unembed(params, x, cfg)[:, 0, :], out
+    # the layers above the slab, for the rows' last positions: the slab as
+    # this call leaves it, below where each row now stands
+    k, v = (out[name][0][slots][None] for name in cached_tensors(cfg))
+    seen = jnp.arange(k.shape[-1])[None, :] < out["pos"][slots][:, None]
+
+    def read(q):  # [B, H, 1, dh]
+        with jax.named_scope("attention.shared_kv"):
+            b, h, _, dh = q.shape
+            acc, _, d = _cache_scores_slab(
+                q.reshape(b, k.shape[2], -1, dh), k, v, 0, seen,
+                attention_scale(cfg))
+            return (acc / d[..., None]).reshape(b, h, 1, dh).astype(cfg.dtype)
+
+    def upper(x, memory):
+        x, _ = fam.shared_layer(params, cfg, x, lambda q, k, v: (read(q), None))
+        return fam.unembed(params, fam.upper_stack(
+            params, cfg, x, memory, read), cfg)[:, 0, :]
+
+    memory = jnp.take_along_axis(memory, at_last, axis=1)
+    if final is None:
+        return upper(x, memory), out
+    return lax.cond(final.any(), upper, lambda x, memory: jnp.zeros(
+        (B, cfg.vocab_size), jnp.float32), x, memory), out
 
 
 def _keep_compacted(cfg, cache, kept, slots, lengths, offsets):
@@ -957,6 +1084,38 @@ def _selection_counts(cfg, **counts) -> Dict[str, jax.Array]:
     n_full = layer_windows(cfg).count(0)
     return {"dsa_" + name: jnp.full((n_full,), value, jnp.int32)
             for name, value in counts.items()}
+
+
+def _prefill_lower(fam, params, cfg, x, attend, lengths, carried, rings,
+                   window: int):
+    """:func:`prefill_at`'s layers for a family that shares one slab
+    (:func:`shared_cache`): the lower half rolled (``fam.lower_stack``), then
+    the K and V of the layer that owns the slab, at every position.
+    ``carried``: a PART's rows' states and last inputs as the slots hold them
+    (``[L_state, B, ...]`` each; None: prompts from their start); ``rings``:
+    what the window layers' rings hold of the rows' slots (``[L_window, B, KV,
+    dh, R]`` each; None: whole prompts).  Returns ``(x, the memory layer's y,
+    the slab's (k, v) [1, B, KV, T, dh], the window layers' stacked (k, v),
+    the recurrent layers' stacked (state, last inputs))``."""
+    at_l = lambda t, at: lax.dynamic_index_in_dim(t, at, 0, keepdims=False)  # noqa: E731
+
+    def mamba(at, xs, p, carry):
+        before = state = None
+        if carried is not None:
+            state, before = (at_l(t, at) for t in carried)
+        y, kept = fam.mamba_whole(xs, p, cfg, lengths, before, state)
+        return y, kept, carry
+
+    def ring(at, q, k, v, carry):
+        held = None if rings is None else tuple(at_l(t, at) for t in rings)
+        with jax.named_scope("attention.diff_window"):
+            out, kept = attend(q, k, v, window=window, held=held)
+        return out, kept, carry
+
+    x, memory, _, states, ringed = fam.lower_stack(
+        params, cfg, x, None, mamba, ring)
+    full = tuple(t[None] for t in fam.shared_kv(params, cfg, x))
+    return x, memory, full, ringed, states
 
 
 def _prefill_runs(fam, params, cfg, x, attend, positions, lengths):
@@ -1057,6 +1216,10 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     names = cached_tensors(cfg)
     ring_names = cached_tensors(cfg, True) if window > 0 else ()
     latent, index, compact = latent_cache(cfg), index_cache(cfg), summary_cache(cfg)
+    shared = shared_cache(cfg)
+    # (a slab that layers other than its owner read: its kernel call has a
+    # name of its own)
+    slab_name = {} if shared is None else {"name": "ragged_shared_kv_attention"}
     old = tuple(cache[name] for name in names)
     rings = tuple(cache[name] for name in ring_names)
     S = old[0].shape[-1]
@@ -1091,7 +1254,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # read: that kernel takes no mask)
     ring_plan = ragged_decode_plan(
         jnp.minimum(live, ring),
-        ring // DECODE_TILE) if ring_read_by_tile(cache) else None
+        ring // DECODE_TILE) if ring_read_by_tile(cache, cfg) else None
     # a family that compacts: the summaries of the windows before a slot's
     # own, ``rows a window`` for each it has filled, read as the window is
     sums = tuple(cache[name] for name in SUMMARIES) if compact else ()
@@ -1110,8 +1273,9 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                if plan is not None else (lambda n: jnp.full_like(n, S)))
     # the recurrent layers' state, and the slots whose state a step moves
     held = tuple(cache[name] for name in ("ssm", "conv") if name in cache)
-    moved = ssm.state_update_plan(active) if held and ssm.kernel_shapes(
-        held[0]) else None
+    moved = ssm.state_update_plan(active) if held and (
+        ssm.kernel_shapes if shared is None else ssm.selective_kernel_shapes)(
+            held[0]) else None
     # a latent family's block in its decode form (the family's docstring)
     form = {"absorbed": True} if latent else {}
 
@@ -1147,9 +1311,13 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                         q, old[0], at, live, plan, scale=scale, dv=latent[1],
                         keep=keep)
                 if not w:
-                    return _cache_scores(q, *old, at, live, plan, scale)
+                    return _cache_scores(q, *old, at, live, plan, scale,
+                                         **slab_name)
+                if ring_plan is not None:
+                    return _ring_scores(q, *rings, at, live, pos, ring_plan,
+                                        window=w, scale=scale)
                 return _cache_scores_slab(
-                    q, *rings, at, _ring_mask(live, pos, w, ring))
+                    q, *rings, at, _ring_mask(live, pos, w, ring), scale)
 
             def attend(q, k, v, row=None, picked=None):  # [B, heads, 1, dh]
                 put = lambda buf, t: lax.dynamic_update_slice(
@@ -1184,6 +1352,61 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     x, p, cfg, attend, positions), carry)[0]
 
             x, *locs = lax.fori_loop(0, cfg.n_layers, rolled, (x, *locs))
+        elif shared is not None:  # two rolled halves around the shared slab
+            def attended(w, at, q, k, v, locs):
+                # layer()'s write into the chunk-local buffer and its read of
+                # cache and buffer, for a middle that is handed q, k, v
+                (a, *locs), _ = layer(
+                    None, w, at, lambda x, attend: (lambda got: (
+                        got[0], None, got[1]))(attend(q, k, v)), (None, *locs))
+                return a, tuple(locs)
+
+            def mamba(at, xs, p, carry):
+                locs, (state, tails) = carry
+                tail = lax.dynamic_index_in_dim(tails, at, 0, keepdims=False)
+                moved_to = []
+
+                def update(*inputs):
+                    with jax.named_scope("ssm.selective_state_update"):
+                        new, y = ssm.selective_state_update(
+                            state, at, *inputs, act, moved)
+                    moved_to.append(new)
+                    return y
+
+                y, last = fam.mamba_step(xs, p, cfg, tail, update)
+                last = jnp.where(act[None, :, None], last, tail)
+                return y, None, (locs, (
+                    moved_to[0],
+                    lax.dynamic_update_index_in_dim(tails, last, at, 0)))
+
+            def ringed(at, q, k, v, carry):
+                with jax.named_scope("attention.diff_window"):
+                    a, locs = attended(window, at, q, k, v, carry[0])
+                return a, None, (locs, carry[1])
+
+            x, memory, (locs, held), _, _ = fam.lower_stack(
+                params, cfg, x, (tuple(locs), tuple(held)), mamba, ringed)
+            now = []  # the buffers with this step's column of the slab in
+
+            def own(q, k, v):
+                with jax.named_scope("attention.shared_kv"):
+                    a, new = attended(0, 0, q, k, v, locs)
+                now.append(new)
+                return a, None
+
+            x, _ = fam.shared_layer(params, cfg, x, own)
+            locs = now[0]
+
+            def read(q):  # a layer that reads the slab and writes nothing
+                with jax.named_scope("attention.shared_kv"):
+                    return _decode_attend(
+                        q, lambda q: _cache_scores(
+                            q, *old, 0, live, plan, attention_scale(cfg),
+                            **slab_name),
+                        locs[0][0], locs[1][0], i, 0,
+                        attention_scale(cfg)).astype(cfg.dtype)
+
+            x = fam.upper_stack(params, cfg, x, memory, read)
         elif held:  # runs of recurrent layers rolled, the others listed
             for kind, first, count, at in cfg.layer_runs:
                 if windows[first] == RECURRENT:

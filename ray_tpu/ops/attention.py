@@ -674,9 +674,8 @@ def ragged_decode_plan(n: jax.Array, n_tiles: int) -> jax.Array:
     return jnp.concatenate([ends[-1:], n, slot, tile]).astype(jnp.int32)
 
 
-def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
-                          k_buf, v_buf, sem, q_wide, acc, m_run, l_run,
-                          *, scale: float, groups: int):
+def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, *rest,
+                          scale: float, groups: int, with_keep: bool = False):
     """One invocation walks the work list: item ``w`` is one 128-position
     tile of one slot, copied from the caches in HBM (double-buffered: item
     ``w + 1`` is in flight while ``w`` is computed, across slots too) and
@@ -697,7 +696,16 @@ def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
     ``out_ref [B, 2 * heads_pad + n_blocks, 128]`` (one copy out a call):
     rows ``[0, heads_pad)`` the max of head ``r`` on every lane, the next
     ``heads_pad`` its denominator still spread over the lanes, then the
-    accumulator as ``n_blocks`` rows of 128 consecutive ``(head, d)``."""
+    accumulator as ``n_blocks`` rows of 128 consecutive ``(head, d)``.
+
+    ``with_keep``: a further operand ``keep [B, tiles, 128]`` float32, whole
+    in VMEM, as :func:`_ragged_latent_kernel` takes it: of the live positions
+    a slot attends only those where it is not 0 (a window layer's ring: the
+    entries inside the step's window).  A slot's first tile may then hold
+    nothing it attends: its lanes keep the empty softmax, which the merge at
+    the last tile weighs 0."""
+    keep_ref, rest = (rest[0], rest[1:]) if with_keep else (None, rest)
+    out_ref, k_buf, v_buf, sem, q_wide, acc, m_run, l_run = rest
     T = DECODE_TILE
     n_slots = q_ref.shape[0]
     kv_heads, dh = k_buf.shape[1], k_buf.shape[2]
@@ -751,6 +759,14 @@ def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
         for c in copies(w, buf):
             c.wait()
         live = t * T + lax.broadcasted_iota(jnp.int32, (1, T), 1) < n
+        if keep_ref is not None:
+            # the slot's mask of tile t: row t of [tiles, T], read as the
+            # aligned group of 8 rows that holds it
+            t8 = pl.multiple_of((t // 8) * 8, 8)
+            rows8 = keep_ref[b, pl.ds(t8, 8), :]
+            mine = lax.broadcasted_iota(jnp.int32, rows8.shape, 0) == t - t8
+            chosen = jnp.sum(jnp.where(mine, rows8, 0.0), axis=0, keepdims=True)
+            live = live & (chosen != 0.0)
 
         def kv_head(kv):
             k = k_buf[buf, kv].astype(jnp.float32)  # [dh, T]
@@ -801,7 +817,8 @@ def _ragged_decode_kernel(layer_ref, plan_ref, q_ref, k_hbm, v_hbm, out_ref,
 
 def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             layer: jax.Array, plan: jax.Array, *,
-                            scale: Optional[float] = None, interpret=False):
+                            scale: Optional[float] = None, interpret=False,
+                            keep=None, name="ragged_decode_attention"):
     """The cache half of a decode step's attention, reading only what is
     live: ``q [B, KV, G, dh]`` (one query a head a slot) against layer
     ``layer`` of the WHOLE caches ``k, v [L, B, KV, dh, S]``, which stay in
@@ -812,7 +829,13 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     other keys: ``acc [B, KV, G, dh]``, running max ``m`` and denominator
     ``d [B, KV, G]``, all f32.  A slot with ``n[b] == 0`` gives ``acc = 0,
     d = 0, m = -1e30`` and moves no byte of cache.  ``scale``: the scores'
-    (None: ``dh ** -0.5``).  Needs ``S % 128 == 0`` and ``dh % 8 == 0``."""
+    (None: ``dh ** -0.5``).  Needs ``S % 128 == 0`` and ``dh % 8 == 0``.
+    ``keep [B, S]`` (None: every live position): the positions a slot's
+    queries attend (one mask a slot, shared by its heads: a window layer's
+    ring read, the step's window over the entries the ring holds); the kernel
+    still reads every live tile and masks; a slot that attends nothing gives
+    ``d = 0``.  ``name``: the call's own in the compiled program and in a
+    device trace, for a caller whose reads are to be told from another's."""
     B, KV, G, dh = q.shape
     S, T = k.shape[-1], DECODE_TILE
     assert S % T == 0 and dh % 8 == 0, (S, dh)
@@ -823,9 +846,15 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      ((0, 0), (0, 0), (0, n_blocks * T - heads * dh)))
     whole = lambda *shape: pl.BlockSpec(  # noqa: E731
         shape, lambda i, *_: (0,) * len(shape))
+    masks, more = (), {}
+    if keep is not None:  # [B, tiles (whole groups of 8), T]
+        tiles = -(-(S // T) // 8) * 8
+        masks = (jnp.pad(keep.astype(jnp.float32).reshape(B, S // T, T),
+                         ((0, 0), (0, tiles - S // T), (0, 0))),)
+        more = {"with_keep": True}
     out = pl.pallas_call(
         functools.partial(_ragged_decode_kernel, groups=G,
-                          scale=dh ** -0.5 if scale is None else scale),
+                          scale=dh ** -0.5 if scale is None else scale, **more),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -833,6 +862,7 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 whole(B, 1, n_blocks * T),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
+                *(whole(*m.shape) for m in masks),
             ],
             out_specs=whole(B, 2 * heads_pad + n_blocks, T),
             scratch_shapes=[
@@ -849,9 +879,9 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct(
             (B, 2 * heads_pad + n_blocks, T), jnp.float32),
-        name="ragged_decode_attention",
+        name=name,
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q_rows, k, v)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan, q_rows, k, v, *masks)
     acc = out[:, 2 * heads_pad:, :].reshape(B, n_blocks * T)[:, :heads * dh]
     return (acc.reshape(B, KV, G, dh),
             out[:, :heads, 0].reshape(B, KV, G),

@@ -29,6 +29,10 @@ the gate and the norm are the family's (:mod:`ray_tpu.models.granite_hybrid`).
   ``jax.numpy`` form over every row with the rows that sit out masked, which
   is also what the tests hold the kernel to.  Chosen by
   ``lax.platform_dependent`` and the shapes; there is no flag.
+- Mamba-1 (a decay per channel AND state element; the module's last
+  section): :func:`selective_scan` for a prompt or a prompt's part with a
+  state carried IN, :func:`selective_state_update` for a decode step over the
+  same plan.
 """
 
 from __future__ import annotations
@@ -71,11 +75,17 @@ def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return jax.nn.silu(out).astype(x.dtype)
 
 
-def conv_tail(x: jax.Array, lengths: jax.Array, k: int) -> jax.Array:
+def conv_tail(x: jax.Array, lengths: jax.Array, k: int,
+              before: Optional[jax.Array] = None) -> jax.Array:
     """The last ``k`` inputs of each row of ``x [B, T, C]`` that are real
     (``lengths [B]``; the rows are right-padded), oldest first, zeros where a
-    row has fewer: ``[B, k, C]``."""
-    xp = jnp.pad(x, ((0, 0), (k, 0), (0, 0)))
+    row has fewer: ``[B, k, C]``.  ``before [B, k, C]`` (None: zeros): the
+    inputs that came before position 0 (a prompt's PART: what the slot kept
+    of the part before it), which a row of fewer than ``k`` still shows."""
+    if before is None:
+        xp = jnp.pad(x, ((0, 0), (k, 0), (0, 0)))
+    else:
+        xp = jnp.concatenate([before.astype(x.dtype), x], axis=1)
     at = lengths.astype(jnp.int32)[:, None] + jnp.arange(k)[None, :]
     return jnp.take_along_axis(xp, at[:, :, None], axis=1)
 
@@ -373,3 +383,288 @@ def state_update(state, layer, decay, dtx, b, c, active, plan=None):
             s, l, da, dx, b, c, plan),
         default=lambda s, l, da, dx, b, c, act, plan: state_update_masked(
             s, l, da, dx, b, c, act))
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1: a decay PER (channel, state) element
+# ---------------------------------------------------------------------------
+#
+# A channel ``c`` of a request carries ``H[c, :] in R^N`` and
+#
+#     H_t[c, n] = exp(dt_t[c] A[c, n]) H_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c] = sum_n H_t[c, n] C_t[n]
+#
+# with ``dt`` a CHANNEL (after its softplus) and ``A [C, N]`` learned: the
+# decay is no scalar a head, so neither :func:`ssd_scan`'s matmul form nor
+# :func:`state_update_kernel`'s ``tile x decay + b x dtx`` computes it.  Both
+# forms below keep the state as ``[N, R, LANES]``: the state index LEADING and
+# the channels as ``R`` rows of 128 lanes (``C = R x 128``; a narrower model:
+# one row of ``C``), so that what a step does to one ``n`` is elementwise on
+# dense ``[R, 128]`` tiles, ``B_t[n]`` and ``C_t[n]`` are scalars of the
+# step, and ``y`` is a plain running sum over ``n``: no reduction over lanes
+# or sublanes anywhere.
+
+
+def channel_tiles(channels: int) -> Tuple[int, int]:
+    """``(rows, lanes)`` of a channel vector in the state's layout."""
+    lanes = min(LANES, channels)
+    assert channels % lanes == 0, channels
+    return channels // lanes, lanes
+
+
+# positions a grid step of :func:`_selective_scan_kernel` walks
+SCAN_CHUNK = 64
+
+
+def selective_scan_steps(dtx, dt, a, b, c, state):
+    """The recurrence a position at a time (``lax.scan``): what every platform
+    can run, and the plain form the kernel is held to.  ``dtx, dt [B, T, C]``
+    float32, ``a [N, C]``, ``b, c [B, T, N]``, ``state [B, N, C]`` -> ``(y [B,
+    T, C], state)``."""
+    def one(h, inputs):
+        dtx_t, dt_t, b_t, c_t = inputs
+        h = (jnp.exp(dt_t[:, None, :] * a) * h
+             + b_t[:, :, None] * dtx_t[:, None, :])
+        return h, (h * c_t[:, :, None]).sum(1)
+
+    state, y = lax.scan(one, state, tuple(
+        jnp.swapaxes(t, 0, 1) for t in (dtx, dt, b, c)))
+    return jnp.swapaxes(y, 0, 1), state
+
+
+def _selective_scan_kernel(dtx_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
+                           y_ref, h_ref):
+    """One grid step is ``SCAN_CHUNK`` positions of one row; the state is the
+    OUTPUT block ``h_ref [N, R, 128]``, resident across the row's grid steps
+    (taken from ``h0_ref`` at the first).  A position updates the state an
+    ``n`` at a time, every operand a dense ``[R, 128]`` tile; ``b_ref``,
+    ``c_ref`` hold ``B_t[n]``, ``C_t[n]`` on all 128 lanes of a row."""
+    N = h_ref.shape[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_ref[...] = h0_ref[...]
+
+    def step(t, _):
+        dt, dtx = dt_ref[t], dtx_ref[t]
+        y = jnp.zeros_like(dt)
+        for n in range(N):
+            h = (h_ref[n] * jnp.exp(dt * a_ref[n])
+                 + b_ref[t, pl.ds(n, 1), :] * dtx)
+            h_ref[n] = h
+            y = y + h * c_ref[t, pl.ds(n, 1), :]
+        y_ref[t] = y
+
+    lax.fori_loop(0, dtx_ref.shape[0], step, None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_scan_kernel(dtx, dt, a, b, c, state, *, interpret=False):
+    """:func:`selective_scan_steps` as a Pallas kernel that walks time with
+    the state resident in VMEM (``[N, R, 128]`` float32: 328 KB at the
+    published 5,120 channels x 16): ``T`` a multiple of ``SCAN_CHUNK``, the
+    channels whole lanes.  ``state [B, N, R, 128]`` in and out (the cache's
+    layout).  ``ssm_selective_scan`` in a traced run."""
+    B, T, C = dtx.shape
+    N = a.shape[0]
+    R, L = channel_tiles(C)
+    assert T % SCAN_CHUNK == 0 and state.shape == (B, N, R, L), (T, state.shape)
+    tiles = lambda t: t.astype(jnp.float32).reshape(B, T, R, L)  # noqa: E731
+    lanes = lambda t: jnp.broadcast_to(  # noqa: E731 — B_t[n] on every lane
+        t.astype(jnp.float32)[..., None], (B, T, N, L))
+    by_time = lambda *tail: pl.BlockSpec(  # noqa: E731
+        (None, SCAN_CHUNK, *tail), lambda i, j: (i, j, 0, 0))
+    a_row = lambda *tail: pl.BlockSpec(  # noqa: E731
+        (None, *tail), lambda i, j: (i, 0, 0, 0))
+    y, state = pl.pallas_call(
+        _selective_scan_kernel,
+        grid=(B, T // SCAN_CHUNK),
+        in_specs=[by_time(R, L), by_time(R, L),
+                  pl.BlockSpec((N, R, L), lambda i, j: (0, 0, 0)),
+                  by_time(N, L), by_time(N, L), a_row(N, R, L)],
+        out_specs=[by_time(R, L), a_row(N, R, L)],
+        out_shape=[jax.ShapeDtypeStruct((B, T, R, L), jnp.float32),
+                   jax.ShapeDtypeStruct((B, N, R, L), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        name="ssm_selective_scan",
+        interpret=interpret,
+    )(tiles(dtx), tiles(dt), a.astype(jnp.float32).reshape(N, R, L),
+      lanes(b), lanes(c), state)
+    return y.reshape(B, T, C), state
+
+
+def selective_scan(x, dt, a, b, c, d, state_in=None, lengths=None):
+    """A prompt, or a prompt's PART, through the recurrence: ``x [B, T, C]``
+    (the convolved inputs), ``dt [B, T, C]`` float32 after its softplus, ``a
+    [N, C]`` float32 (negative), ``b, c [B, T, N]``, ``d [C]`` the skip,
+    ``state_in [B, N, R, 128]`` float32 in the cache's layout (None: zeros, a
+    prompt's start), ``lengths [B]`` the real positions of right-padded rows
+    (None: all).  A padded position changes nothing (its ``dt`` is 0: decay 1,
+    input 0).  Returns ``(y [B, T, C] float32 with the skip, the state after
+    the last REAL position, in the cache's layout)``.  Lowered for a TPU at
+    the kernel's shapes :func:`selective_scan_kernel`, anywhere else
+    :func:`selective_scan_steps`; there is no flag."""
+    B, T, C = x.shape
+    N = a.shape[0]
+    R, L = channel_tiles(C)
+    xf = x.astype(jnp.float32)
+    if lengths is not None:
+        dt = jnp.where((jnp.arange(T)[None, :] < lengths[:, None])[..., None],
+                       dt, 0.0)
+    if state_in is None:
+        state_in = jnp.zeros((B, N, R, L), jnp.float32)
+    dtx = dt * xf
+
+    def steps(dtx, dt, a, b, c, state):
+        y, state = selective_scan_steps(
+            dtx, dt, a, b.astype(jnp.float32), c.astype(jnp.float32),
+            state.reshape(B, N, C))
+        return y, state.reshape(B, N, R, L)
+
+    if L == LANES and T % SCAN_CHUNK == 0:
+        y, state = lax.platform_dependent(
+            dtx, dt, a, b, c, state_in, tpu=selective_scan_kernel, default=steps)
+    else:
+        y, state = steps(dtx, dt, a, b, c, state_in)
+    return y + d.astype(jnp.float32) * xf, state
+
+
+def selective_kernel_shapes(state: jax.Array) -> bool:
+    """Whether :func:`selective_state_update_kernel` takes a cache of this
+    shape ``[L, B, N, R, 128]``: whole lanes, whole sublane tiles, float32."""
+    return (state.shape[-1] == LANES and state.shape[-2] % 8 == 0
+            and state.dtype == jnp.float32)
+
+
+def selective_state_update_masked(state, layer, dt, dtx, a, b, c):
+    """One decode step of layer ``layer`` over EVERY row of ``state [L, B, N,
+    R, 128]``: ``dt, dtx [B, C]`` float32 (0 and 0 for a row that sits the
+    step out: its state is then kept bit for bit), ``a [N, C]``, ``b, c [B,
+    N]``.  What every platform can run, and the reference the kernel is held
+    to.  Returns ``(state, y [B, C])``."""
+    old = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    B, N, R, L = old.shape
+    rows = lambda t: t.reshape(B, 1, R, L)  # noqa: E731
+    new = (old * jnp.exp(rows(dt) * a.reshape(1, N, R, L))
+           + b[:, :, None, None] * rows(dtx))
+    y = (new * c[:, :, None, None]).sum(1)
+    return (lax.dynamic_update_index_in_dim(state, new, layer, 0),
+            y.reshape(B, R * L))
+
+
+def _selective_update_kernel(layer_ref, plan_ref, dt_ref, dtx_ref, a_ref,
+                             b_ref, c_ref, state_hbm, out_hbm, y_ref, buf, sem):
+    """One invocation walks :func:`state_update_plan`'s list: a unit of work
+    is one slot's whole state of the layer, ``[N, R, 128]`` (328 KB as
+    published), copied in, updated an ``n`` at a time on dense tiles and
+    copied back to where it came from (``state_hbm`` and ``out_hbm`` are one
+    buffer), ``UPDATE_BUFFERS`` units in flight."""
+    n_buf, N = buf.shape[0], buf.shape[1]
+    layer, total = layer_ref[0], plan_ref[0]
+
+    def copy(j, back: bool):
+        b, at = plan_ref[1 + j], j % n_buf
+        if back:
+            return pltpu.make_async_copy(buf.at[at], out_hbm.at[layer, b],
+                                         sem.at[1, at])
+        return pltpu.make_async_copy(state_hbm.at[layer, b], buf.at[at],
+                                     sem.at[0, at])
+
+    y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(total > 0)
+    def _():
+        copy(0, False).start()
+
+    def unit(j, _):
+        at = j % n_buf
+
+        @pl.when(j + 1 < total)
+        def _():
+            @pl.when(j + 1 >= n_buf)
+            def _():  # the buffer's last tenant has to be back in the cache
+                copy(j + 1 - n_buf, True).wait()
+
+            copy(j + 1, False).start()
+
+        copy(j, False).wait()
+        b = plan_ref[1 + j]
+        dt, dtx = dt_ref[b], dtx_ref[b]
+        y = jnp.zeros_like(dt)
+        for n in range(N):
+            h = (buf[at, n] * jnp.exp(dt * a_ref[n])
+                 + b_ref[b, pl.ds(n, 1), :] * dtx)
+            buf[at, n] = h
+            y = y + h * c_ref[b, pl.ds(n, 1), :]
+        y_ref[b] = y
+        copy(j, True).start()
+
+    lax.fori_loop(0, total, unit, None)
+
+    def settle(j, _):  # the last units' copies back
+        copy(j, True).wait()
+
+    lax.fori_loop(jnp.maximum(total - n_buf, 0), total, settle, None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_state_update_kernel(state, layer, dt, dtx, a, b, c, plan, *,
+                                  interpret=False):
+    """:func:`selective_state_update_masked` for the slots ``plan`` lists
+    (:func:`state_update_plan`), touching nothing else: ``state [L, B, N, R,
+    128]`` stays in HBM and is updated IN PLACE (the result aliases it).  A
+    slot that is not listed moves no byte and its ``y`` is 0; a listed slot
+    that stopped mid-chunk is frozen by its inputs (``dt`` 0, ``dtx`` 0: the
+    caller's).  ``ssm_selective_state_update`` in a traced run."""
+    L, B, N, R, W = state.shape
+    assert selective_kernel_shapes(state), state.shape
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    lanes = lambda t: jnp.broadcast_to(f32(t)[..., None], (B, N, W))  # noqa: E731
+    whole = lambda *shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, *_: (0,) * len(shape))
+    new, y = pl.pallas_call(
+        _selective_update_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole(B, R, W), whole(B, R, W), whole(N, R, W),
+                      whole(B, N, W), whole(B, N, W),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY), whole(B, R, W)],
+            scratch_shapes=[
+                pltpu.VMEM((UPDATE_BUFFERS, N, R, W), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, UPDATE_BUFFERS)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((B, R, W), jnp.float32)],
+        # operands: layer, plan, dt, dtx, a, b, c, state -> the first result
+        input_output_aliases={7: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 << 20),
+        name="ssm_selective_state_update",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), plan,
+      f32(dt).reshape(B, R, W), f32(dtx).reshape(B, R, W),
+      f32(a).reshape(N, R, W), lanes(b), lanes(c), state)
+    return new, y.reshape(B, R * W)
+
+
+def selective_state_update(state, layer, dt, dtx, a, b, c, active, plan=None):
+    """One Mamba-1 decode step of layer ``layer`` over the cache of states, in
+    place -> ``(state, y [B, C] float32)``, without the skip.  ``active [B]``:
+    the rows that take the step NOW (a row that sits out or stopped mid-chunk
+    is frozen: ``dt`` and ``dtx`` 0).  ``plan`` (:func:`state_update_plan` of
+    the rows active when the chunk began; None: no kernel at these shapes):
+    lowered for a TPU the kernel over those rows, anywhere else the masked
+    form over every row."""
+    args = (state, layer, jnp.where(active[:, None], dt, 0.0),
+            jnp.where(active[:, None], dtx, 0.0), a,
+            b.astype(jnp.float32), c.astype(jnp.float32))
+    if plan is None:
+        return selective_state_update_masked(*args)
+    return lax.platform_dependent(
+        *args, plan,
+        tpu=selective_state_update_kernel,
+        default=lambda *args: selective_state_update_masked(*args[:-1]))

@@ -28,7 +28,12 @@ is part of the framework here:
   offset), at most one part between two decode chunks while any row decodes:
   a pasted document stalls no live stream for longer than one part takes.
   Buckets above a part are never called, so their programs are never built; a
-  family with recurrent layers keeps whole prompts (``generate.can_continue``).
+  family with recurrent layers keeps whole prompts (``generate.can_continue``)
+  unless the state a part leaves in the slot is the state the next part starts
+  from (``cfg.state_carried_in``: Mamba-1, :mod:`ray_tpu.models.phi4_flash`).
+  A family whose upper layers read ONE lower layer's slab
+  (``generate.shared_cache``) is told which rows END a prompt (``final``): a
+  part that ends none never runs the layers above the slab.
   Parts start at position 0 and every part but a prompt's last is WHOLE, so a
   part's offset is a multiple of the part: a compacting family's windows (a
   part is one or more whole windows: checked where the engine is built) line
@@ -532,7 +537,7 @@ class GenerationEngine:
         windows = gen.layer_windows(cfg)
         n_state = windows.count(gen.RECURRENT)
         self._layers = {"full": windows.count(0),
-                        "window": len(windows) - windows.count(0) - n_state,
+                        "window": sum(w > 0 for w in windows),
                         **({"state": n_state} if n_state else {})}
         # per layer of the kind: a full layer reads a slot's live tiles; a
         # window layer every row's whole ring (two tiles at the published
@@ -542,6 +547,24 @@ class GenerationEngine:
         self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
         self._cache_tiles = {"read_full": 0, "read_window": 0,
                              "held_window": 0, "padded": 0, "flushed": 0}
+        # a family whose upper layers read ONE lower layer's slab: the layers
+        # that read it (its owner and the readers), or None
+        self._shared = gen.shared_cache(cfg)
+        if self._shared is not None:
+            # its own, cumulative, counted on the host at dispatch (they ride
+            # HERE for the traced replica's sake, as ``eva_*`` do): over the
+            # steps each dispatch ran, the slab tiles read TIMES the layers
+            # that read them, the ring tiles read times the window layers, the
+            # rows of state moved times the recurrent layers, the live rows;
+            # the prompt positions prefilled and the positions (rows that
+            # ended a prompt) run through the layers above the slab
+            self._slab_readers = 1 + sum(
+                gen.reads_layer(w) is not None for w in windows)
+            self._cache_tiles.update(dict.fromkeys((
+                "yoco_slab_tile_steps", "yoco_ring_tile_steps",
+                "yoco_state_row_steps", "yoco_row_steps", "yoco_steps",
+                "yoco_dispatches", "yoco_prefill_positions",
+                "yoco_upper_positions"), 0))
         if self._compact:
             # a compacting family's own, cumulative like the rest and counted
             # at dispatch on the host (they ride HERE because a traced
@@ -582,7 +605,7 @@ class GenerationEngine:
         # one extra SCRATCH slot (index n_slots): a prefill call is its
         # bucket's fixed rows wide, and the rows no prompt fills park there
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
-        self._ring_by_tile = gen.ring_read_by_tile(self.cache)
+        self._ring_by_tile = gen.ring_read_by_tile(self.cache, cfg)
         # tiles of the padded slab a full layer a slot (a compacting family:
         # its window's and its summaries')
         self._slab_tiles = sum(
@@ -612,8 +635,9 @@ class GenerationEngine:
             from ray_tpu.ops import ssm
 
             held, tails = self.cache["ssm"], self.cache["conv"]
-            self._state_kernel = (jax.devices()[0].platform == "tpu"
-                                  and ssm.kernel_shapes(held))
+            self._state_kernel = jax.devices()[0].platform == "tpu" and (
+                ssm.kernel_shapes if self._shared is None
+                else ssm.selective_kernel_shapes)(held)
             self._state = {
                 "rows_updated": 0, "rows_live": 0, "steps": 0, "dispatches": 0,
                 "layers": n_state,
@@ -1012,6 +1036,11 @@ class GenerationEngine:
                 req = self._queue.pop(0)
                 self._slots[slot] = req
                 calls[-1][1].append((slot, req))
+            if self._shared is not None:  # the prompt positions these calls take
+                self._cache_tiles["yoco_prefill_positions"] += sum(
+                    batch[3] if b is None
+                    else sum(min(len(r.tokens), b) for _, r in batch)
+                    for b, batch in calls)
             for b, batch in calls:
                 if b is None:
                     continue  # (a part: tallied where it was planned)
@@ -1079,7 +1108,7 @@ class GenerationEngine:
         slots_dev = jnp.asarray(slots)
         last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
-            self.cache, slots_dev)
+            self.cache, slots_dev, *self._final(n, batch, not first_part))
         if first_part:
             _to_host_async((last_logits, routed_dev))
             return [], last_logits, routed_dev, n * b
@@ -1087,6 +1116,22 @@ class GenerationEngine:
             last_logits, slots_dev, routed_dev, [req for _, req in batch])
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
         return admissions, firsts_dev, routed_dev, n * b
+
+    def _final(self, rows: int, batch, ends: bool) -> tuple:
+        """A prefill call's last argument for a family that shares one slab
+        (none for any other): which of its ``rows`` END a prompt, i.e. run the
+        layers above the slab (``ends``: the call is no prompt's earlier
+        part).  Counts the call's prompt positions and the positions it runs
+        through those layers."""
+        if self._shared is None:
+            return ()
+        import jax.numpy as jnp
+
+        final = np.zeros((rows,), bool)
+        final[:len(batch)] = ends
+        with self._lock:
+            self._cache_tiles["yoco_upper_positions"] += int(final.sum())
+        return (jnp.asarray(final),)
 
     def _part_call(self, slot: int, req: _Request, first: int, n: int):
         """Dispatch one PART of ``req``'s prompt, its tokens ``[first, first +
@@ -1111,7 +1156,8 @@ class GenerationEngine:
         last_logits, self.cache, routed_dev, stands_dev = self._part_jit(
             self.params, jnp.asarray(toks),
             jnp.asarray(np.array([n], np.int32)), self.cache, slots_dev,
-            jnp.asarray(np.array([first], np.int32)))
+            jnp.asarray(np.array([first], np.int32)),
+            *self._final(1, [(slot, req)], first + n >= len(req.tokens)))
         if first + n < len(req.tokens):
             stands_dev.copy_to_host_async()
             _to_host_async(routed_dev)
@@ -1219,6 +1265,8 @@ class GenerationEngine:
                         for at in stands) if self._ring_by_tile else held
                 self._cache_tiles["padded"] += (
                     (self.n_slots + 1) * self._slab_tiles)
+                if self._shared is not None:
+                    self._count_shared(stands, n)
                 for i, req in rows:
                     req.scheduled = min(req.max_new, req.scheduled + n)
                     if req.scheduled >= req.max_new:
@@ -1237,6 +1285,23 @@ class GenerationEngine:
                 t_admitted - t_tick0, t_dispatched - t_admitted,
                 time.perf_counter() - t_dispatched - waited)
         return worked
+
+    def _count_shared(self, stands, n: int) -> None:
+        """A dispatched chunk of ``n`` steps of a family that shares one slab,
+        rows at positions ``stands``: the ``yoco_*`` counters (``__init__``;
+        call under the lock)."""
+        tally, tile = self._cache_tiles, self._tile
+        tally["yoco_slab_tile_steps"] += n * self._slab_readers * sum(
+            -(-at // tile) for at in stands)
+        tally["yoco_ring_tile_steps"] += n * self._layers["window"] * sum(
+            min(-(-at // tile), self._ring_tiles) for at in stands
+        ) if self._ring_by_tile else n * self._layers["window"] * (
+            self.n_slots + 1) * self._ring_tiles
+        tally["yoco_state_row_steps"] += n * self._layers.get("state", 0) * (
+            len(stands) if self._state_kernel else self.n_slots + 1)
+        tally["yoco_row_steps"] += n * len(stands)
+        tally["yoco_steps"] += n
+        tally["yoco_dispatches"] += 1
 
     def _count_compacting(self, stands, n: int):
         """A dispatched chunk of ``n`` steps of a compacting family, rows at
@@ -1477,6 +1542,14 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
         # that the host can read them (None: the family counts nothing)
         return logits, cache, cache.pop("routed", None)
 
+    if gen.shared_cache(cfg) is not None:
+        # a family that shares one slab: a further argument, the rows that END
+        # a prompt (a first part through a bucket's own program ends none)
+        def llm_prefill(params, toks, lens, cache, slots, final):  # noqa: F811
+            logits, cache = gen.prefill_at(params, cfg, toks, lens, cache,
+                                           slots, final=final)
+            return logits, cache, cache.pop("routed", None)
+
     prefill = jax.jit(
         llm_prefill,
         donate_argnums=(3,),  # scatter into the cache in place
@@ -1508,6 +1581,13 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
         logits, cache = gen.prefill_at(params, cfg, toks, lens, cache, slots,
                                        offsets, part_bound)
         return logits, cache, cache.pop("routed", None), cache["pos"][slots]
+
+    if gen.shared_cache(cfg) is not None:
+        def llm_prefill_part(params, toks, lens, cache, slots, offsets,  # noqa: F811
+                             final):
+            logits, cache = gen.prefill_at(params, cfg, toks, lens, cache,
+                                           slots, offsets, part_bound, final)
+            return logits, cache, cache.pop("routed", None), cache["pos"][slots]
 
     part = None if part_bound is None else jax.jit(
         llm_prefill_part, donate_argnums=(3,))

@@ -39,6 +39,7 @@ TINY = {
     "granite_hybrid": {"experts_held": (4, 8)},
     "dots3_note": {"experts_held": (4, 8)},       # window 5, top-8 positions
     "evabyte": {},                                # window 32, chunks of 4
+    "phi4_flash": {},                             # window 8, one shared slab
 }
 # exaone_moe where a chunk is longer than 9 steps (the one-shot path decodes a
 # whole answer in ONE chunk): a ring's flush needs ``steps <= window + 1``

@@ -380,6 +380,42 @@ def _lower_evabyte_cell(chip):
     ]
 
 
+def _lower_phi4_flash_cell(chip):
+    """The serve-phi-4-mini-flash-reasoning-docs cell's programs:
+    Phi-4-mini-flash at its published sizes, WHOLE (32 layers, 200,064
+    rows), 32 slots and the scratch row: ONE slab of 18,560 positions of K and
+    V pairs (10 heads of 128) that eight layers read, eight rings of 1,024,
+    nine Mamba-1 states ``[16, 40, 128]`` float32.  A prefill program takes a
+    further argument, the rows that END a prompt (the layers above the slab
+    run for those rows' last positions alone, or not at all)."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("phi4_flash", "mini-flash")
+    n_slots, chunk = 32, 16
+    params = jax.eval_shape(lambda: llm._default_init(cfg, 0))
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 3_852_562_944
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(16384, 2048, chunk)))
+    assert cache["k"].shape == cache["v"].shape == (1, 33, 10, 128, 18560)
+    assert cache["k_ring"].shape == (8, 33, 10, 128, 1024)
+    assert cache["ssm"].shape == (9, 33, 16, 40, 128)
+    assert cache["conv"].shape == (9, 3, 33, 5120)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(16384))
+    assert llm.call_rows(2048, n_slots) == 1
+    i32 = lambda *shape: _on(chip, jax.ShapeDtypeStruct(shape, jnp.int32))  # noqa: E731
+    final = _on(chip, jax.ShapeDtypeStruct((1,), jnp.bool_))
+    return [
+        part.lower(_on(chip, params), i32(1, llm.PREFILL_PART_TOKENS), i32(1),
+                   _on(chip, cache), i32(1), i32(1), final),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
+        prefill.lower(_on(chip, params), i32(1, 2048), i32(1), _on(chip, cache),
+                      i32(1), final),
+    ]
+
+
 def _lower_bert(chip):
     """The classifier bench.run_serve_bench serves: BERT-base, one static
     batch of 16 x 128 tokens."""
@@ -447,6 +483,7 @@ PROGRAMS = {
     "serve_engine_granite_cell": _lower_granite_cell,
     "serve_engine_dots3_cell": _lower_dots3_cell,
     "serve_engine_evabyte_cell": _lower_evabyte_cell,
+    "serve_engine_phi4_flash_cell": _lower_phi4_flash_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -562,7 +599,8 @@ def test_program_compiles_for_v5e(compiled, name):
         for program in programs:
             assert program.as_text().count("tpu_custom_call") == 2
     if name in ("serve_engine_exaone_cell", "serve_engine_kimi_cell",
-                "serve_engine_dots3_cell", "serve_engine_evabyte_cell"):
+                "serve_engine_dots3_cell", "serve_engine_evabyte_cell",
+                "serve_engine_phi4_flash_cell"):
         # ONE program for every part of every prompt above 2,048 tokens, under
         # the name a trace's readers sum the prefill programs by; the flash
         # kernel is in it on every layer that reads a slab
@@ -698,6 +736,42 @@ def test_program_compiles_for_v5e(compiled, name):
             assert not re.search(r"f32\[1,128,\d{4,5},\d{4,5}\]", prefill.as_text())
         assert programs[0].memory_analysis().temp_size_in_bytes < 2.6e9
         assert all(5.5e9 < need < 8.5e9 for need in needs), needs
+    if name == "serve_engine_phi4_flash_cell":
+        # ONE slab read by eight layers: the ragged kernel under a name of its
+        # own, once for the owner and once in the rolled (GMU, cross) loop;
+        # the rings read a live row's tiles at a time, under their own name,
+        # with the step's window as the mask (f32[33, 8, 128]: a slot's eight
+        # tiles); the Mamba-1 update over the plan's rows, in the rolled pairs
+        # and in the memory layer; the flush over the slab's k and v.  7.71 GB
+        # of weights and 4.63 GB of cache resident: 12.33 GB of arguments
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            called = lambda kernel: [  # noqa: E731
+                line for line in text.splitlines()
+                if re.search(rf"%{kernel}[.\d]* = ", line)]
+            assert len(called("ragged_shared_kv_attention")) == 2
+            assert all("attention.shared_kv" in line
+                       for line in called("ragged_shared_kv_attention"))
+            rings = called("ragged_ring_attention")
+            assert len(rings) == 1 and "f32[33,8,128]" in rings[0]
+            assert "attention.diff_window" in rings[0]
+            assert len(called("ssm_selective_state_update")) == 2
+            assert not called("ragged_decode_attention")
+            flushes = called("cache_flush")
+            assert len(flushes) == 2, flushes
+            assert all("bf16[1,33,10,128,18560]" in line for line in flushes)
+            assert decode.memory_analysis().temp_size_in_bytes < 0.9e9
+            assert decode.memory_analysis().argument_size_in_bytes > 12.3e9
+        # the part program and the 2,048 bucket's: the scan as the kernel that
+        # walks time (rolled pairs + the memory layer), and the layers above
+        # the slab under a conditional (no row may end a prompt)
+        for prefill in (programs[0], programs[3]):
+            text = prefill.as_text()
+            assert len(re.findall(r"%ssm_selective_scan[.\d]* = ", text)) == 2
+            assert re.search(r"\bconditional\(", text)
+            assert prefill.memory_analysis().temp_size_in_bytes < 0.7e9
+        assert "flash_attention_fwd" in programs[0].as_text()
+        assert all(12.5e9 < need < 13.5e9 for need in needs), needs
     if name == "serve_engine_evabyte_cell":
         # the ragged kernel twice a layer (the window, then the summaries:
         # each under its own named scope, which the traced run's ``scope:*``
