@@ -54,7 +54,10 @@ convention; ASSUMED).
 FFN: layer 0 dense SwiGLU; layers 1..: ``s = sigmoid(h W_r)`` float32, ``sel =
 top_8(s + b)``, ``g_i = s_i / sum_sel s_j``, ``y = E_shared(h) + sum_{i in
 sel, held} g_i E_i(h)`` (:func:`ray_tpu.models.exaone_moe._sparse_ffn` as it
-stands; no group limit).  Final RMSNorm, an output matrix of its own.
+stands; no group limit; :func:`init` makes ``ew_gate ew_up [held, D, F]
+ew_down``, what a reference reads, and an engine serves from ``ew_gate_up
+[held, D, 2F]`` and ``ew_down``: :func:`ray_tpu.models.exaone_moe.
+serving_layout`, once at load).  Final RMSNorm, an output matrix of its own.
 
 Departures from the published model: ``kv_b_proj`` as its two halves a head
 and the rotary pairing ``(2i, 2i + 1)`` in place, as :mod:`kimi_k2`; the index
@@ -73,7 +76,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ray_tpu.models.exaone_moe import _selection_bias, _sparse_ffn, _swiglu
+from ray_tpu.models.exaone_moe import (
+    _selection_bias, _sparse_ffn, _swiglu, serving_layout)
 from ray_tpu.models.kimi_k2 import latent_projections
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.dsa import selected_attention
@@ -388,7 +392,9 @@ def apply(params: Dict[str, Any], tokens: jax.Array, cfg: Dots3NoteConfig,
           *, absorbed: bool = False) -> jax.Array:
     """tokens [B, T] int32 -> logits [B, T, V] f32: the whole forward, no
     cache (the tests hold prefill and decode to it, and the two forms of the
-    attention to each other)."""
+    attention to each other), over :func:`init`'s tree or the served one
+    (:func:`ray_tpu.models.exaone_moe.serving_layout`)."""
+    params = serving_layout(jax.tree.map(lambda a: a, params))
     x = embed(params, tokens, cfg)
     for p, window in zip(params["layers"], cfg.sliding_windows):
         x, _, _ = block(x, p, cfg, window=window, absorbed=absorbed)

@@ -25,7 +25,12 @@ Layer equations (pre-norm residual blocks, ``n`` the RMSNorm):
 - sparse FFN: ``s = sigmoid(h W_r)`` in float32, ``sel = top_k(s + b)``,
   ``g_i = routed_scale * s_i / sum_{j in sel} s_j``, ``y = E_shared(h) +
   sum_{i in sel, held here} g_i E_i(h)``; what the absent experts would add is
-  left out.
+  left out.  A sparse layer's leaves as :func:`init` makes them (and a
+  reference reads them): ``router router_bias ew_gate ew_up [held, D, F]
+  ew_down [held, F, D] sw_gate sw_up sw_down``; as an engine serves them
+  (:func:`serving_layout`, once at load; what :func:`block` reads): ``ew_gate``
+  and ``ew_up`` as ONE leaf ``ew_gate_up [held, D, 2F]``, so that the experts'
+  SwiGLU is two grouped matmuls.
 - head: final RMSNorm, an output matrix of its own (untied).
 
 Multi-token prediction (the published model's one MTP module) is not built:
@@ -44,11 +49,12 @@ from jax.sharding import Mesh
 
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.layers import dense, rmsnorm
-from ray_tpu.ops.moe import held_experts_ffn, route_sigmoid_top_k
+from ray_tpu.ops.moe import (
+    gate_up_side_by_side, held_experts_ffn, route_sigmoid_top_k)
 
 __all__ = [
-    "ExaoneMoeConfig", "init", "init_layer", "apply", "block", "embed",
-    "unembed", "kv_heads", "num_params",
+    "ExaoneMoeConfig", "init", "init_layer", "serving_layout", "apply",
+    "block", "embed", "unembed", "kv_heads", "num_params",
 ]
 
 WINDOW, FULL = "sliding_attention", "full_attention"
@@ -184,6 +190,18 @@ def init(cfg: ExaoneMoeConfig, key: jax.Array) -> Dict[str, Any]:
     }
 
 
+def serving_layout(params: Dict[str, Any]) -> Dict[str, Any]:
+    """:func:`init`'s tree as it is SERVED, what :func:`block` reads: every
+    sparse layer's ``ew_gate`` and ``ew_up`` as ONE leaf ``ew_gate_up``
+    (:func:`ray_tpu.ops.moe.gate_up_side_by_side`), a layer at a time and IN
+    PLACE on the layers' dicts, which the caller owns
+    (:func:`ray_tpu.models.generate.serving_layout`).  Kimi-K2's and dots3's
+    trees are laid out as this family's, so this is their hook too."""
+    for p in params.get("layers", ()):
+        gate_up_side_by_side(p)
+    return params
+
+
 def kv_heads(cfg: ExaoneMoeConfig) -> int:
     """K/V heads a cache holds for a position (the GQA saving)."""
     return cfg.n_kv_heads
@@ -223,7 +241,7 @@ def _sparse_ffn(h, p, cfg: ExaoneMoeConfig, valid):
             cfg.routed_scale)
     with jax.named_scope("moe.expert_ffn"):
         y, tokens = held_experts_ffn(
-            flat, experts, gates, p["ew_gate"], p["ew_up"], p["ew_down"],
+            flat, experts, gates, p["ew_gate_up"], p["ew_down"],
             first_expert=cfg.experts_held[0], valid=valid)
     with jax.named_scope("moe.shared_ffn"):
         shared = _swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"])
@@ -284,7 +302,9 @@ def unembed(params: Dict[str, Any], x: jax.Array, cfg: ExaoneMoeConfig) -> jax.A
 
 def apply(params: Dict[str, Any], tokens: jax.Array, cfg: ExaoneMoeConfig) -> jax.Array:
     """tokens [B, T] int32 -> logits [B, T, V] f32: the whole forward, no
-    cache (the tests hold prefill and decode to it)."""
+    cache (the tests hold prefill and decode to it), over :func:`init`'s tree
+    or the served one."""
+    params = serving_layout(jax.tree.map(lambda a: a, params))
     x = embed(params, tokens, cfg)
     for p, window in zip(params["layers"], cfg.sliding_windows):
         x, _, _ = block(x, p, cfg, window=window)

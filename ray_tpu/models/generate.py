@@ -310,6 +310,20 @@ def family_of(cfg):
     raise TypeError(f"no generation support for config {type(cfg).__name__}")
 
 
+def serving_layout(cfg, params):
+    """The family's ``init`` tree as :func:`prefill_at`, :func:`decode_chunk`
+    and :func:`generate` take it: what a family lays out ONCE when an engine
+    takes its parameters and never in a step (its ``serving_layout`` hook: the
+    held experts' gate and up matrices side by side,
+    :func:`ray_tpu.ops.moe.gate_up_side_by_side`; a family without a hook
+    serves from ``init``'s tree as it is).  IN PLACE on ``params``' dicts and
+    lists, never on a leaf, so that a source is let go before the next copy is
+    made: hand over a tree whose containers are yours (``jax.tree.map(lambda
+    a: a, params)`` makes one; the engine's cast of its parameters is one)."""
+    lay = getattr(family_of(cfg), "serving_layout", None)
+    return lay(params) if lay else params
+
+
 def kv_heads(cfg) -> int:
     return family_of(cfg).kv_heads(cfg)
 

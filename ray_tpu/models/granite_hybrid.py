@@ -36,8 +36,11 @@ Layer equations (``n`` the RMSNorm with a learned scale, eps ``rms_eps``):
   W_up,e x)``; the shared MLP is the same form for every token.  This chip
   holds ``experts_held``; what the absent experts would add is left out.
 
-Departures from the published model: an expert's fused input projection
-(``[gate | up]``) is stored as its two halves (the same numbers, the layout
+Departures from the published model: :func:`init` makes an expert's fused
+input projection (``[gate | up]``) as its two halves, ``ew_gate`` and
+``ew_up`` (the leaves the reference reads); an engine serves from the
+published layout, ONE leaf ``ew_gate_up`` (:func:`serving_layout`, laid out
+once at load: what :func:`block` reads and
 :func:`ray_tpu.ops.moe.held_experts_ffn` takes); ``mamba_n_groups`` is 1 as
 published and no group axis is built.
 """
@@ -56,11 +59,13 @@ from ray_tpu.models.exaone_moe import _swiglu
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops import ssm
 from ray_tpu.ops.layers import dense, rmsnorm
-from ray_tpu.ops.moe import held_experts_ffn, route_softmax_top_k
+from ray_tpu.ops.moe import (
+    gate_up_side_by_side, held_experts_ffn, route_softmax_top_k)
 
 __all__ = [
-    "GraniteHybridConfig", "init", "init_layer", "apply", "block", "embed",
-    "unembed", "kv_heads", "num_params", "mamba_whole", "mamba_step",
+    "GraniteHybridConfig", "init", "init_layer", "serving_layout", "apply",
+    "block", "embed", "unembed", "kv_heads", "num_params", "mamba_whole",
+    "mamba_step",
 ]
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -265,6 +270,18 @@ def init(cfg: GraniteHybridConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def serving_layout(params: Dict[str, Any]) -> Dict[str, Any]:
+    """:func:`init`'s tree as it is SERVED, what :func:`block` reads: the
+    Mamba stack's ``ew_gate`` and ``ew_up`` as ONE leaf ``ew_gate_up`` ``[Mamba
+    layers, held, D, 2F]`` and each attention layer's as one ``[held, D, 2F]``
+    (:func:`ray_tpu.ops.moe.gate_up_side_by_side`), the stack and then a layer
+    at a time, IN PLACE on those dicts, which the caller owns
+    (:func:`ray_tpu.models.generate.serving_layout`)."""
+    for p in (params.get(MAMBA, {}), *params.get(ATTENTION, ())):
+        gate_up_side_by_side(p)
+    return params
+
+
 def kv_heads(cfg: GraniteHybridConfig) -> int:
     """K/V heads a cache holds for a position of an attention layer."""
     return cfg.n_kv_heads
@@ -340,7 +357,7 @@ def _sparse_ffn(h, p, cfg: GraniteHybridConfig, valid):
             flat, p["router"], cfg.experts_per_token)
     with jax.named_scope("moe.expert_ffn"):
         y, tokens = held_experts_ffn(
-            flat, experts, gates, p["ew_gate"], p["ew_up"], p["ew_down"],
+            flat, experts, gates, p["ew_gate_up"], p["ew_down"],
             first_expert=cfg.experts_held[0], valid=valid, layer=p.get("layer"))
     with jax.named_scope("moe.shared_ffn"):
         shared = _swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"])
@@ -409,7 +426,7 @@ def unembed(params: Dict[str, Any], x: jax.Array, cfg: GraniteHybridConfig) -> j
 
 # the leaves of the Mamba stack that are NOT sliced a layer: they feed the
 # grouped-matmul kernel, and a slice feeding a kernel is a copy
-_WHOLE = ("ew_gate", "ew_up", "ew_down")
+_WHOLE = ("ew_gate_up", "ew_down")
 
 
 def layer_of(stack: Dict[str, Any], at) -> Dict[str, Any]:
@@ -434,7 +451,9 @@ def layer_params(params: Dict[str, Any], cfg: GraniteHybridConfig, layer: int):
 
 def apply(params: Dict[str, Any], tokens: jax.Array, cfg: GraniteHybridConfig) -> jax.Array:
     """tokens [B, T] int32 -> logits [B, T, V] f32: the whole forward, no
-    cache, a layer at a time (the tests hold prefill and decode to it)."""
+    cache, a layer at a time (the tests hold prefill and decode to it), over
+    :func:`init`'s tree or the served one."""
+    params = serving_layout(jax.tree.map(lambda a: a, params))
     x = embed(params, tokens, cfg)
     for l, kind in enumerate(cfg.layer_types):
         x, _, _ = block(x, layer_params(params, cfg, l), cfg, kind=kind)

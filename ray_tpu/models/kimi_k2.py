@@ -42,7 +42,10 @@ Layer equations (pre-norm residual blocks, ``n`` RMSNorm with ``rms_eps``
   ``sel = top_8(s + b)``, ``g_i = routed_scale s_i / sum_{sel} s_j``, ``y =
   E_shared(h) + sum_{i in sel, held here} g_i E_i(h)``
   (:mod:`ray_tpu.ops.moe`, the expert layer K-EXAONE runs); what the absent
-  experts would add is left out.
+  experts would add is left out.  :func:`init` makes a sparse layer's
+  ``ew_gate ew_up [held, D, F] ew_down [held, F, D]`` (what a reference
+  reads); an engine serves from ``ew_gate_up [held, D, 2F]`` and ``ew_down``
+  (:func:`ray_tpu.models.exaone_moe.serving_layout`, once at load).
 - head: final RMSNorm, an output matrix of its own (untied).
 
 Departures from the published model: ``kv_b_proj`` is stored as its two
@@ -65,7 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ray_tpu.models.exaone_moe import _selection_bias, _sparse_ffn, _swiglu
+from ray_tpu.models.exaone_moe import (
+    _selection_bias, _sparse_ffn, _swiglu, serving_layout)
 from ray_tpu.models.transformer import _attend
 from ray_tpu.ops.layers import dense, rmsnorm
 
@@ -364,7 +368,9 @@ def apply(params: Dict[str, Any], tokens: jax.Array, cfg: KimiK2Config,
           *, absorbed: bool = False) -> jax.Array:
     """tokens [B, T] int32 -> logits [B, T, V] f32: the whole forward, no
     cache (the tests hold prefill and decode to it, and the two forms of the
-    attention to each other)."""
+    attention to each other), over :func:`init`'s tree or the served one
+    (:func:`ray_tpu.models.exaone_moe.serving_layout`)."""
+    params = serving_layout(jax.tree.map(lambda a: a, params))
     x = embed(params, tokens, cfg)
     for p in params["layers"]:
         x, _, _ = block(x, p, cfg, absorbed=absorbed)

@@ -176,9 +176,11 @@ _ONE_BLOCK_PAIRS = 1024
 # held) whole calls of 256 / 512 / 1,024 / 2,048 tokens: 31.5 / 59.6 / 81.7 /
 # 135.9 as it stood, 25.3 / 41.3 / 72.6 / 146.4 at 256 (ten trips a layer at
 # 2,048 tokens, each three grouped-matmul calls over 180 groups: the one shape
-# that lost), 30.6 / 43.7 / 73.1 / 135.9 at 1,024 in slices; K-EXAONE's 128 x
-# 2 / 256 / 512 / 1,024 / 2,048: 21.9 / 22.9 / 28.1 / 42.9 / 62.3 as it
-# stood, 17.1 / 17.7 / 23.5 / 36.7 / 55.2 at 256
+# that lost; two calls a trip since PR 54, prompts 0.8 of the bucket: 24.0 ->
+# 22.9 / 38.9 -> 37.2 / - / 135.1 -> 132.8), 30.6 / 43.7 / 73.1 / 135.9 at
+# 1,024 in slices; K-EXAONE's 128 x 2 / 256 / 512 / 1,024 / 2,048: 21.9 /
+# 22.9 / 28.1 / 42.9 / 62.3 as it stood, 17.1 / 17.7 / 23.5 / 36.7 / 55.2 at
+# 256
 _TRIP_ROWS = 256
 
 
@@ -223,9 +225,28 @@ def route_softmax_top_k(x: jax.Array, router_w: jax.Array, top_k: int):
     return experts.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
 
 
+def gate_up_side_by_side(p: Dict[str, Any]) -> Dict[str, Any]:
+    """One layer's (or one stack of layers') parameters as they are SERVED:
+    ``ew_gate`` and ``ew_up`` ``[.., n_held, D, F]``, the leaves a family's
+    ``init`` makes (and a reference reads), become ONE leaf ``ew_gate_up``
+    ``[.., n_held, D, 2F]``, gate's columns then up's, which is what
+    :func:`held_experts_ffn` takes.  IN PLACE on the dict ``p`` (a layer
+    without experts, or one laid out already, is left as it is): the two
+    sources are let go here, before the caller makes the next layer's copy,
+    so a model's expert weights are never held twice (a family's
+    ``serving_layout`` walks its layers with this; the caller owns the dicts:
+    :func:`ray_tpu.models.generate.serving_layout`)."""
+    if "ew_gate" in p:
+        # (ready before the next layer's copy is asked for: the sources are
+        # held until the concatenation that reads them has run)
+        p["ew_gate_up"] = jax.block_until_ready(
+            jnp.concatenate([p.pop("ew_gate"), p.pop("ew_up")], axis=-1))
+    return p
+
+
 def held_experts_ffn(
-    x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate: jax.Array,
-    w_up: jax.Array, w_down: jax.Array, *, first_expert: int = 0,
+    x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate_up: jax.Array,
+    w_down: jax.Array, *, first_expert: int = 0,
     valid: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of a top-k SwiGLU expert layer, no token dropped.
@@ -234,13 +255,17 @@ def held_experts_ffn(
         x: ``[N, D]`` tokens (compute dtype).
         experts, gates: ``[N, k]``, each token's chosen experts (numbered over
             ALL experts) and their weights (:func:`route_sigmoid_top_k`).
-        w_gate, w_up: ``[n_held, D, F]``; w_down: ``[n_held, F, D]``: experts
+        w_gate_up: ``[n_held, D, 2F]``, an expert's gate and up matrices side
+            by side on the last axis (:func:`gate_up_side_by_side`: laid out
+            once when an engine takes its parameters, never in a step; a
+            family's ``init`` makes ``ew_gate`` and ``ew_up``, the engine
+            serves from ``ew_gate_up``); w_down: ``[n_held, F, D]``: experts
             ``first_expert .. first_expert + n_held``, without biases.
         valid: ``[N]`` bool, the real tokens.  Padding and the rows of slots
             that sit a step out are routed nowhere: they cost no expert
             matmul and no expert weight read, and their part of ``y`` is 0.
         layer: for weights that hold the experts of SEVERAL layers stacked
-            (``[n_layers, n_held, D, F]``, a family whose layer loop is
+            (``[n_layers, n_held, D, 2F]``, a family whose layer loop is
             rolled), the index (it may be traced) of the layer to use.  The
             grouped matmuls are then given every layer's experts and sizes of
             0 for all but this layer's: a group without rows costs nothing,
@@ -248,12 +273,13 @@ def held_experts_ffn(
             copy of them (170 MB a layer a decode step at Granite's widths).
 
     The ``N * k`` (token, expert) pairs are sorted by held expert (pairs of
-    absent experts last) and the held ones go through grouped matmuls
+    absent experts last) and the held ones go through TWO grouped matmuls
     (``lax.ragged_dot``: on a TPU one kernel over the rows of each group, no
     capacity, and a group without rows costs nothing, not even the read of
-    its weights).  Up to one block of pairs (``_ONE_BLOCK_PAIRS``: a decode
-    step) all ``M`` rows take one trip and a token's ``k`` rows are gathered
-    back and summed; of more pairs (a prefill call) the HELD ones take
+    its weights): gate and up as one call whose result is split into its
+    halves for ``silu(g) * u``, then down.  Up to one block of pairs
+    (``_ONE_BLOCK_PAIRS``: a decode step) all ``M`` rows take one trip and a
+    token's ``k`` rows are gathered back and summed; of more pairs (a prefill call) the HELD ones take
     ``_TRIP_ROWS`` rows a trip, as many trips as they need
     (:func:`dispatch_trips`: a runtime count), each added into the result
     where its rows' tokens are, in float32: what a trip gathers, multiplies
@@ -266,12 +292,13 @@ def held_experts_ffn(
     N, D = x.shape
     widen = lambda sizes: sizes  # noqa: E731 — the groups' sizes as the kernel takes them
     if layer is not None:
-        n_layers, n_held = w_gate.shape[:2]
-        w_gate, w_up, w_down = (
-            w.reshape(n_layers * n_held, *w.shape[2:]) for w in (w_gate, w_up, w_down))
+        n_layers, n_held = w_down.shape[:2]
+        w_gate_up, w_down = (
+            w.reshape(n_layers * n_held, *w.shape[2:]) for w in (w_gate_up, w_down))
         widen = lambda sizes: jax.lax.dynamic_update_slice(  # noqa: E731
             jnp.zeros((n_layers * n_held,), sizes.dtype), sizes, (layer * n_held,))
-    n_held, top_k = w_gate.shape[0] if layer is None else n_held, experts.shape[1]
+    n_held, top_k = w_down.shape[0] if layer is None else n_held, experts.shape[1]
+    F = w_down.shape[-2]
     M = N * top_k
     local = experts.reshape(M) - first_expert
     held = (local >= 0) & (local < n_held)
@@ -287,8 +314,8 @@ def held_experts_ffn(
 
     def experts_of(pairs, sizes):
         rows, sizes = x[pairs // top_k], widen(sizes)
-        h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate.astype(x.dtype), sizes))
-             * jax.lax.ragged_dot(rows, w_up.astype(x.dtype), sizes))
+        gu = jax.lax.ragged_dot(rows, w_gate_up.astype(x.dtype), sizes)
+        h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
         return jax.lax.ragged_dot(h, w_down.astype(x.dtype), sizes,
                                   preferred_element_type=jnp.float32)
 

@@ -490,10 +490,17 @@ class GenerationEngine:
         # has masters (exaone_moe: they would not fit the chip)
         import jax.numpy as jnp
 
-        self.params = jax.tree.map(
+        params = jax.tree.map(
             lambda x: x.astype(cfg.dtype)
             if hasattr(x, "dtype") and x.dtype == jnp.float32 else x,
             params)
+        # ... and laid out as the step programs read them, ONCE and before the
+        # cache exists: a family's held experts' gate and up matrices side by
+        # side (one grouped matmul where there were two), a layer at a time
+        # with its two sources let go (the cast tree's containers are the
+        # engine's own, and ``params`` was rebound so that nothing else holds
+        # them), never in a step
+        self.params = gen.serving_layout(cfg, params)
         self.n_slots = n_slots
         self.max_new_tokens = max_new_tokens
         self.chunk = decode_chunk_steps
@@ -1687,7 +1694,8 @@ def prefill_decode_graph(
 
             self.gen = gen
             self.cfg = make_config(family, size, **ckw)
-            self.params = _default_init(self.cfg, seed)
+            self.params = gen.serving_layout(
+                self.cfg, _default_init(self.cfg, seed))
             self.max_len = prefill_bucket + max_new_tokens + 1
 
         def prefill(self, request):
@@ -1728,7 +1736,8 @@ def prefill_decode_graph(
 
             self.gen = gen
             self.cfg = make_config(family, size, **ckw)
-            self.params = _default_init(self.cfg, seed)
+            self.params = gen.serving_layout(
+                self.cfg, _default_init(self.cfg, seed))
 
         def decode(self, state):
             import jax

@@ -110,10 +110,34 @@ def programs(path):
             "max_new_tokens", "eos_id", "temperature", "top_k")))
 
 
+_SERVED = {}
+
+
+def served(params, cfg):
+    """``params`` as the programs take them (``gen.serving_layout``: what an
+    engine lays out once at load), made once a tree: the cases hold ``init``'s
+    tree (``tiny_model``: what a reference reads and an engine is handed) and
+    every walk into ``gen`` below goes through here.  A tree laid out already
+    (an engine's) comes back as it is."""
+    if id(params) not in _SERVED:  # (the tree is kept, so its id is not reused)
+        _SERVED[id(params)] = params, gen.serving_layout(
+            cfg, jax.tree.map(lambda a: a, params))
+    return _SERVED[id(params)][1]
+
+
+def served_layer(p):
+    """One layer of ``init``'s tree (or a stack) as a family's ``block`` reads
+    it (``moe.gate_up_side_by_side``, on a copy of the dict)."""
+    from ray_tpu.ops import moe
+
+    return moe.gate_up_side_by_side(dict(p))
+
+
 def prefill_at(params, cfg, *args, **kw):
     """``gen.prefill_at`` through the kept program -> the last logits, the
     cache WITHOUT its routing counts, and those counts (None: none)."""
-    logits, cache = programs(PATH).prefill_at(params, cfg, *args, **kw)
+    logits, cache = programs(PATH).prefill_at(
+        served(params, cfg), cfg, *args, **kw)
     return logits, cache, cache.pop("routed", None)
 
 
@@ -123,7 +147,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key=None, *, n=None, **kw):
     and those counts."""
     key = jax.random.PRNGKey(0) if key is None else key
     emitted, cache, active, key = programs(PATH).decode_chunk(
-        params, cfg, cache, tokens, active, key,
+        served(params, cfg), cfg, cache, tokens, active, key,
         n=None if n is None else jnp.int32(n), **kw)
     return emitted, cache, active, key, cache.pop("routed", None)
 
@@ -139,7 +163,7 @@ def one_shot(params, cfg, prompts, n, pad_to=8, eos_id=None):
     for row, prompt in enumerate(prompts):
         batch[row, :len(prompt)] = prompt
     out = np.asarray(programs(PATH).generate(
-        params, cfg, jnp.asarray(batch),
+        served(params, cfg), cfg, jnp.asarray(batch),
         jnp.asarray([len(p) for p in prompts]), max_new_tokens=max(counts),
         eos_id=eos_id))
     return [[int(t) for t in row[:m]] for row, m in zip(out, counts)]
@@ -330,10 +354,11 @@ def shares_add_up(p, h, shares, routed, by_hand, ref_swiglu, n_experts):
     share = jax.jit(moe.held_experts_ffn)
     parts, counted = 0.0, 0
     for chip in range(shares):
-        held = slice(2 * chip, 2 * chip + 2)
+        held = {k: p[k][2 * chip:2 * chip + 2]
+                for k in ("ew_gate", "ew_up", "ew_down")}
         y, tokens = share(
-            flat, experts, gates, p["ew_gate"][held], p["ew_up"][held],
-            p["ew_down"][held], first_expert=2 * chip)
+            flat, experts, gates, served_layer(held)["ew_gate_up"],
+            held["ew_down"], first_expert=2 * chip)
         parts, counted = parts + y, counted + int(tokens.sum())
     f = lambda a: a  # noqa: E731
     shared = ref_swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"], f)

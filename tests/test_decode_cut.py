@@ -15,6 +15,7 @@ from family_harness import (
     engine,
     one_shot,
     run_engine,
+    served,
     tiny_model,
 )
 
@@ -75,7 +76,7 @@ def test_whole_chunk_stays_a_scan_and_the_cut_is_one_loop(family):
     operation of the scan's."""
     cfg, params = tiny_model(family)
     cache = gen.init_cache(cfg, 3, 64)
-    args = (params, cache, jnp.zeros((3,), jnp.int32), jnp.ones((3,), bool),
+    args = (served(params, cfg), cache, jnp.zeros((3,), jnp.int32), jnp.ones((3,), bool),
             jax.random.PRNGKey(0))
     chunk = lambda params, cache, *rest, **kw: gen.decode_chunk(  # noqa: E731
         params, cfg, cache, *rest, steps=8, **kw)

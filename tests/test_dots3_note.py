@@ -28,6 +28,7 @@ from family_harness import (
     padded,
     run_engine,
     serve,
+    served_layer,
     shares_add_up,
     sigmoid_top_k_by_hand,
     tiny_model,
@@ -129,7 +130,8 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(layer), (1, 41, cfg.d_model))
     p = params["layers"][layer]
-    got, routed, _ = _block(x, p, cfg, window=cfg.sliding_windows[layer])
+    got, routed, _ = _block(
+        x, served_layer(p), cfg, window=cfg.sliding_windows[layer])
     with jax.default_matmul_precision("highest"):
         want = ref._layer(x, p, **ref.layer_statics(sizes_of(cfg), layer))
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
@@ -154,7 +156,7 @@ def test_forward_against_the_reference(model, absorbed):
 def test_absorbed_against_unabsorbed(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 33, cfg.d_model))
-    p, w = params["layers"][layer], cfg.sliding_windows[layer]
+    p, w = served_layer(params["layers"][layer]), cfg.sliding_windows[layer]
     plain, _, _ = _block(x, p, cfg, window=w)
     folded, _, _ = _block(x, p, cfg, window=w, absorbed=True)
     assert np.abs(np.asarray(plain) - np.asarray(folded)).max() < F32_TOL
@@ -171,7 +173,7 @@ def test_selected_sets_are_the_references_exactly(model):
     p = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 44, cfg.d_model))
     seen = {}
-    dn.block(x, p, cfg, attend=lambda q, k, v, row, index: (
+    dn.block(x, served_layer(p), cfg, attend=lambda q, k, v, row, index: (
         seen.update(index=index) or (jnp.zeros_like(v), None)))
     qi, w, ki = seen["index"]
     got = np.asarray(dsa.causal_top_k_mask(qi, w, ki[:, 0], cfg.index_topk))[0]

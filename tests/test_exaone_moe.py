@@ -27,6 +27,7 @@ from family_harness import (
     padded,
     run_engine,
     serve,
+    served_layer,
     shares_add_up,
     sigmoid_top_k_by_hand,
     tiny_model,
@@ -86,7 +87,8 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(layer), (2, 21, cfg.d_model))
     p, window = params["layers"][layer], cfg.sliding_windows[layer]
-    got, routed, _ = em.block(x, p, cfg, window=window)
+    # the block reads the served layout, the reference ``init``'s leaves
+    got, routed, _ = em.block(x, served_layer(p), cfg, window=window)
     sizes = {k: v for k, v in sizes_of(cfg).items() if k != "sliding_windows"}
     with jax.default_matmul_precision("highest"):
         want = ref._layer(x, p, window=window, lower=None, **sizes)
@@ -222,8 +224,9 @@ def test_no_token_is_dropped_when_every_token_takes_the_same_experts(
     monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, *a: (
         ran.append(int(hi)), loop(lo, hi, *a))[1])
     y, tokens = moe.held_experts_ffn(
-        x, experts, gates, w_gate, w_up, w_down, valid=valid,
-        layer=None if layer is None else jnp.int32(layer))
+        x, experts, gates, moe.gate_up_side_by_side(
+            {"ew_gate": w_gate, "ew_up": w_up})["ew_gate_up"], w_down,
+        valid=valid, layer=None if layer is None else jnp.int32(layer))
     if layer is not None:
         w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
     took = np.asarray((experts[:, :, None] == jnp.arange(E)) & valid[:, None, None])
@@ -262,18 +265,23 @@ def _eqns(jaxpr, name):
     (33, 8, 264, 0), (49, 10, 496, 0), (128, 8, 1024, 0),
 ])
 def test_the_grouped_matmuls_are_handed_a_trips_rows(n, top_k, rows, loops):
-    """What lowers: above one block of pairs (every prefill call) the three
-    ``ragged_dot`` calls sit in the loop over trips and take ``_TRIP_ROWS``
-    rows each, whatever ``M`` is; up to one block (a decode step) they take
-    the ``M`` pairs at once and a token's rows are gathered back."""
+    """What lowers: above one block of pairs (every prefill call) the TWO
+    ``ragged_dot`` calls (gate and up as one over the side-by-side weights,
+    then down) sit in the loop over trips and take ``_TRIP_ROWS`` rows each,
+    whatever ``M`` is; up to one block (a decode step) they take the ``M``
+    pairs at once and a token's rows are gathered back.  No concatenation of
+    an expert-weight shape is part of the dispatch."""
     assert (moe._ONE_BLOCK_PAIRS, moe._TRIP_ROWS) == (1024, 256)
     f32, i32 = jnp.float32, jnp.int32
     shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
         ((n, 16), f32), ((n, top_k), i32), ((n, top_k), f32),
-        ((4, 16, 8), f32), ((4, 16, 8), f32), ((4, 8, 16), f32))]
+        ((4, 16, 16), f32), ((4, 8, 16), f32))]
     jaxpr = jax.make_jaxpr(moe.held_experts_ffn)(*shapes).jaxpr
     dots = _eqns(jaxpr, "ragged_dot") or _eqns(jaxpr, "ragged_dot_general")
-    assert [eqn.invars[0].aval.shape[0] for eqn in dots] == [rows] * 3
+    assert [eqn.invars[0].aval.shape[0] for eqn in dots] == [rows] * 2
+    assert [eqn.invars[1].aval.shape for eqn in dots] == [(4, 16, 16), (4, 8, 16)]
+    assert not any(len(eqn.outvars[0].aval.shape) == 3
+                   for eqn in _eqns(jaxpr, "concatenate"))
     assert len(_eqns(jaxpr, "while")) == loops
     assert len(_eqns(jaxpr, "scatter-add")) == loops
     assert moe.dispatch_trips(n * top_k, 0)[0] == rows

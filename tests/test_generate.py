@@ -18,6 +18,7 @@ from family_harness import (
     decode_chunk,
     greedy_reference,
     one_shot,
+    served,
     tiny_model,
 )
 
@@ -196,7 +197,7 @@ def test_chunk_of_one_and_of_none(family):
         eng.decode(1)
     eng.assert_greedy({0: 4})
     none, cache, _, _ = gen.decode_chunk(
-        eng.params, eng.cfg, eng.cache, eng.tok, jnp.asarray(eng.active),
+        served(eng.params, eng.cfg), eng.cfg, eng.cache, eng.tok, jnp.asarray(eng.active),
         eng.key, steps=0)
     assert none.shape == (eng.n, 0) and cache is eng.cache
     assert one_shot(eng.params, eng.cfg, [prompt], 1) == [eng.out[0][:1]]

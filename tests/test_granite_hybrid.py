@@ -33,6 +33,7 @@ from family_harness import (
     prefill,
     run_engine,
     serve,
+    served_layer,
     shares_add_up,
     tiny_model,
     worst_gap,
@@ -129,7 +130,8 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
     x = jax.random.normal(jax.random.PRNGKey(layer), (1, 64, cfg.d_model))
     p = gh.layer_params(params, cfg, layer)
     got, routed, _ = jax.jit(
-        lambda x, p: gh.block(x, p, cfg, kind=cfg.layer_types[layer]))(x, p)
+        lambda x, p: gh.block(x, p, cfg, kind=cfg.layer_types[layer]))(
+            x, served_layer(p))
     with jax.default_matmul_precision("highest"):
         want = ref._layer(x, p, kind=cfg.layer_types[layer],
                           **ref.layer_statics(sizes_of(cfg)))

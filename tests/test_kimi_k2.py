@@ -28,6 +28,7 @@ from family_harness import (
     padded,
     run_engine,
     serve,
+    served_layer,
     shares_add_up,
     sigmoid_top_k_by_hand,
     tiny_model,
@@ -128,7 +129,7 @@ def test_one_block_of_each_kind_against_the_reference(model, layer):
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(layer), (1, 21, cfg.d_model))
     p = params["layers"][layer]
-    got, routed, _ = kk.block(x, p, cfg)
+    got, routed, _ = kk.block(x, served_layer(p), cfg)
     with jax.default_matmul_precision("highest"):
         want = ref._layer(x, p, **ref.layer_statics(sizes_of(cfg)))
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < F32_TOL
@@ -154,7 +155,7 @@ def test_absorbed_against_unabsorbed(model):
     float8 latent row (1e-2) or a scale without ``m * m`` (1e-1) breaks."""
     cfg, params = model
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 33, cfg.d_model))
-    for p in params["layers"][:2]:
+    for p in map(served_layer, params["layers"][:2]):
         plain, _, _ = kk.block(x, p, cfg)
         folded, _, _ = kk.block(x, p, cfg, absorbed=True)
         assert np.abs(np.asarray(plain) - np.asarray(folded)).max() < F32_TOL

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from family_harness import engine, prefill_at, run_engine, tiny_model
+from family_harness import engine, prefill_at, run_engine, served, tiny_model
 
 import ray_tpu.ops.attention  # noqa: F401  (the module, not ops' function)
 from ray_tpu._private import events as events_mod
@@ -161,7 +161,7 @@ def part_forms():
         return jax.jit(
             lambda params, toks, lens, cache, slots, offsets: gen.prefill_at(
                 params, cfg, toks, lens, cache, slots, offsets, LIVE_BOUND)
-        ).lower(params, i32(1, LIVE_PART), i32(1), gen.init_cache(cfg, 3, 96),
+        ).lower(served(params, cfg), i32(1, LIVE_PART), i32(1), gen.init_cache(cfg, 3, 96),
                 i32(1), i32(1)).compile()
 
     kept = {}
@@ -201,6 +201,7 @@ def test_a_part_prepares_the_live_blocks_alone(family, offset, part_forms):
     # ... and the loop is there wherever something is prepared at all (a
     # family whose cache holds a key and value a query head prepares nothing)
     cfg, params = tiny_model(family)
+    params = served(params, cfg)
     prepares = gen.latent_cache(cfg) or cfg.n_heads != gen.kv_heads(cfg)
     assert any(op["runtime_loop"] for op in operations) == bool(prepares)
 
@@ -227,7 +228,7 @@ def test_a_family_with_recurrent_layers_keeps_whole_prompts():
     assert not gen.can_continue(cfg)
     assert all(gen.can_continue(tiny_model(f)[0]) for f in CONTINUES)
     with pytest.raises(AssertionError, match="prefilled whole"):
-        gen.prefill_at(params, cfg, jnp.ones((1, 8), jnp.int32),
+        gen.prefill_at(served(params, cfg), cfg, jnp.ones((1, 8), jnp.int32),
                        jnp.asarray([8]), gen.init_cache(cfg, 2, 32),
                        jnp.asarray([0]), offsets=jnp.asarray([0]))
     # ... and its engine never splits one, whatever the part
