@@ -5183,6 +5183,8 @@ class Node:
         last_mfu: Dict[str, float] = {}
         compiles: Dict[tuple, dict] = {}
         interference: Dict[str, dict] = {}
+        host_first: Dict[str, dict] = {}  # an engine's oldest host summary
+        slow: Dict[str, list] = {}
         for r in rows:
             d = r.get("data") or {}
             msg = r.get("message")
@@ -5216,6 +5218,28 @@ class Node:
                 if prev is None or float(r.get("ts") or 0.0) >= float(
                         prev.get("ts") or 0.0):
                     interference[eid] = r
+                first = host_first.get(eid)
+                if d.get("host") and (first is None
+                                      or d["host"]["t"] < first["t"]):
+                    host_first[eid] = d["host"]
+            elif msg == "slow tick":
+                eid = f"{r.get('origin') or 'head'}:{r.get('entity_id')}"
+                slow.setdefault(eid, []).append(d)
+        # the thread's time by kind between an engine's oldest and newest
+        # meter event on record, and the newest slow periods' records (an
+        # engine's ticks, a train loop's steps)
+        from ray_tpu.util import tracing as _tracing
+
+        host = {}
+        for eid, r in interference.items():
+            last = (r.get("data") or {}).get("host")
+            first = host_first.get(eid)
+            if last and first and last["t"] > first["t"]:
+                host[eid] = {"interval_s": round(last["t"] - first["t"], 3),
+                             "ticks": last["count"] - first["count"],
+                             **_tracing.host_readings(first, last)}
+        slow = {eid: sorted(rs, key=lambda d: d.get("t") or 0.0)[-8:]
+                for eid, rs in sorted(slow.items())}
         merged = self._merged_metrics_snapshot()
 
         def counter_by_origin_fn(name: str) -> Dict[tuple, float]:
@@ -5270,6 +5294,7 @@ class Node:
                 "interference": {eid: dict(r.get("data") or {})
                                  for eid, r in sorted(interference.items())},
             },
+            "host": {"readings": host, "slow": slow},
         }
 
     def _state_snapshot(self) -> dict:

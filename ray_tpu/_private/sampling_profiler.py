@@ -176,6 +176,37 @@ def continuous_enabled() -> bool:
         "0", "false", "no")
 
 
+# --- overdue host phases -----------------------------------------------------
+# A thread whose recurring period has host phases (the serve engine's tick:
+# ``util.tracing.StallRecorder``) publishes the phase it is in, and the
+# process's ONE sampler looks at it between its bursts: a phase that has lasted
+# as long as a slow period gets one burst at once, while whatever holds the
+# process is still holding it (the period itself is known to be slow only at
+# its end, when nothing is left to sample).  Only in a process where somebody
+# registered: every other process sleeps as it did.
+_WATCHED: List = []
+# how often the sampler looks while anything is registered.  A phase is
+# overdue from its 10th ms at the earliest, so a 45 ms one is caught whenever a
+# look falls in the 35 ms after that: always, at 20 ms; a 2 s one always.  Not
+# the 10 ms ISSUE 55 named: on the chip machine (a sandboxed kernel, where a
+# timed wait is a timer of the sandbox's own) 100 looks a second cost the
+# replica ~10 % of a core and read ~1 % in XL's `tpot_p95_ms` (PERF.md section
+# 6, PR 55); on Linux proper a look is ~27 us of CPU
+WATCH_EVERY_S = 0.020
+
+
+def watch(recorder) -> None:
+    """``recorder.now`` (``(phase, began)`` or None), ``.threshold_s`` and
+    ``.sampled`` are read, ``.caught`` is appended to, by the sampler."""
+    if recorder not in _WATCHED:
+        _WATCHED.append(recorder)
+
+
+def unwatch(recorder) -> None:
+    if recorder in _WATCHED:
+        _WATCHED.remove(recorder)
+
+
 class ContinuousProfiler:
     """Low-duty-cycle burst sampler with adaptive backoff.
 
@@ -272,8 +303,12 @@ class ContinuousProfiler:
                     severity="DEBUG", entity_id=self.origin)
 
     # -- sampling ----------------------------------------------------------
-    def _burst(self) -> None:
-        """One sampling burst; also refreshes the GIL-lateness estimate."""
+    def _burst(self, tag: Optional[str] = None,
+               live: Optional[Callable[[], bool]] = None):
+        """One sampling burst; also refreshes the GIL-lateness estimate.
+        ``tag``: a root frame for the burst's stacks (an overdue phase's);
+        ``live``: the burst ends when it turns false.  Returns the stacks it
+        folded."""
         exclude = frozenset((threading.get_ident(),))
         counter: "collections.Counter[str]" = collections.Counter()
         t0 = time.perf_counter()
@@ -285,6 +320,8 @@ class ContinuousProfiler:
                 busy_ticks += 1
             ticks += 1
             self._stop.wait(self.period_s)
+            if live is not None and not live():
+                break
         elapsed = time.perf_counter() - t0
         if ticks:
             # expected wall for the burst is ticks * period (+ sample
@@ -294,17 +331,21 @@ class ContinuousProfiler:
             self.lateness_frac = max(
                 0.0, min(1.0, (elapsed - expected) / max(elapsed, 1e-9)))
         if not counter:
-            return
+            return counter
+        folded = counter if tag is None else collections.Counter(
+            {f"[{tag}]|{stack}": n for stack, n in counter.items()})
         bucket = (time.time() // self.bucket_s) * self.bucket_s
         with self._lock:
             cur = self._buckets.setdefault(bucket, collections.Counter())
-            cur.update(counter)
+            cur.update(folded)
             bt = self._bucket_ticks.setdefault(bucket, [0.0, 0.0])
             bt[0] += ticks
             bt[1] += busy_ticks
             self._ticks += ticks
-        self._adapt(counter)
+        if tag is None:  # an overdue phase's burst says nothing of idleness
+            self._adapt(counter)
         self._publish_gauges()
+        return counter
 
     def _adapt(self, counter) -> None:
         """Interval backoff: static stacks across bursts double the
@@ -350,8 +391,52 @@ class ContinuousProfiler:
 
         locks.publish_lock_metrics()
 
+    def _sleep(self) -> bool:
+        """Wait out the interval between two bursts (True: stopped), looking
+        at the watched phases every ``WATCH_EVERY_S`` where there are any."""
+        if not _WATCHED:
+            return self._stop.wait(self._cur_interval)
+        deadline = time.monotonic() + self._cur_interval
+        while True:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                return self._stop.is_set()
+            if self._stop.wait(min(left, WATCH_EVERY_S)):
+                return True
+            self._sample_overdue()
+
+    def _sample_overdue(self) -> None:
+        """ONE burst for each watched phase that is older than its
+        recorder's slow threshold (and than two sampling periods: a burst
+        shorter than that is no burst), over every thread but this one, for
+        as long as the phase lasts and a burst at most; the top five stacks
+        and the burst's own lateness (how long this thread, runnable, waited
+        for the GIL meanwhile) go to the recorder."""
+        from ray_tpu.util.profile_store import classify_stack
+
+        now = time.perf_counter()
+        for rec in list(_WATCHED):
+            cur = rec.now
+            if cur is None or cur is rec.sampled:
+                continue
+            phase, began = cur
+            if now - began < max(rec.threshold_s, 2 * self.period_s):
+                continue
+            rec.sampled = cur
+            try:
+                counter = self._burst(
+                    tag=f"overdue {phase}", live=lambda: rec.now is cur)
+            except Exception:  # noqa: BLE001 — never the host process's end
+                continue
+            # the top five by samples, the threads caught off a blocking
+            # wait first (a replica parks a dozen threads in polls)
+            top = sorted(counter.items(), key=lambda kv: (
+                classify_stack(kv[0]) == "idle", -kv[1]))[:5]
+            rec.caught.append((began, phase, [list(kv) for kv in top],
+                               round(self.lateness_frac, 4)))
+
     def _loop(self) -> None:
-        while not self._stop.wait(self._cur_interval):
+        while not self._sleep():
             if self._closed is not None and self._closed():
                 return
             try:

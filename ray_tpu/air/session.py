@@ -55,23 +55,33 @@ class _Session:
         self.dataset_shards = dataset_shards or {}
         self._report_fn = report_fn  # callable(metrics, checkpoint)
         self.stop_event = stop_event
-        self._last_report_t: Optional[float] = None
+        # the loop thread's periods between two reports, by kind of time,
+        # and the records of the slow ones: the recorder an engine's ticks
+        # report to (``perf`` / ``slow tick`` events; ``ray_tpu perf``, the
+        # doctor's ``host_stall``).  The loop thread WAITS for the device by
+        # design, so a period's off-core share says nothing by itself
+        from ray_tpu.util import tracing
+
+        tracing.listen_gc()
+        self.steps = tracing.StallRecorder(
+            f"train-rank{world_rank}", ("step",), what="train step")
+        self._clocks: Optional[tuple] = None  # at the previous report
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
         from ray_tpu._private import events as _events
+        from ray_tpu.util import tracing
 
         if _events.ENABLED:
             # report() runs once per step in the canonical train loop, so
             # the inter-report gap IS the step time (ingest wait included;
             # the ingest-wait counter isolates that share)
-            import time as _time
-
-            now = _time.perf_counter()
-            if self._last_report_t is not None:
+            now = tracing.thread_clocks()
+            if self._clocks is not None:
+                period = tracing.clocks_between(self._clocks, now)
+                self.steps.add([period], self._clocks[0], now[1])
                 _step_time_hist().observe(
-                    now - self._last_report_t,
-                    tags={"rank": str(self.world_rank)})
-            self._last_report_t = now
+                    period[0], tags={"rank": str(self.world_rank)})
+            self._clocks = now
         if self._report_fn is not None:
             self._report_fn(metrics, checkpoint)
 

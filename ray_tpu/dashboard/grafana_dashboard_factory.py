@@ -33,7 +33,6 @@ CORE_METRICS: Dict[str, tuple] = {
     "ray_tpu_streaming_stall_s_total": ("counter", "pump backpressure stall seconds"),
     "ray_tpu_serve_admission_latency_s": ("histogram", "serve admission latency"),
     "ray_tpu_serve_router_queue_len": ("gauge", "router queue length"),
-    "ray_tpu_llm_generated_tokens_total": ("counter", "LLM tokens generated"),
     "ray_tpu_llm_slot_admission_latency_s": ("histogram", "decode-slot admission latency"),
     "ray_tpu_train_step_time_s": ("histogram", "train step time"),
     "ray_tpu_data_ingest_wait_s_total": ("counter", "train ingest-wait seconds"),
@@ -44,8 +43,6 @@ CORE_METRICS: Dict[str, tuple] = {
     "ray_tpu_hbm_bytes_in_use": ("gauge", "device memory in use"),
     "ray_tpu_llm_ttft_s": ("histogram", "LLM time-to-first-token"),
     "ray_tpu_llm_itl_s": ("histogram", "LLM inter-token latency"),
-    "ray_tpu_llm_prefill_interference_s_total":
-        ("counter", "decode-tick seconds billed to prefill"),
     # continuous-profiling plane (PR 17: sampling_profiler + locks)
     "ray_tpu_profiler_duty_frac": ("gauge", "profiler duty cycle fraction"),
     "ray_tpu_gil_lateness_frac": ("gauge", "GIL pressure (tick lateness)"),

@@ -846,7 +846,39 @@ def cmd_perf(args) -> None:
                     f"{st_['dispatches']:>7} {live:>10} "
                     f"{st_['rows_updated']:>10} "
                     f"{100.0 * st_['rows_updated'] / live if live else 0.0:>6.1f}%")
-    if not (st["count"] or comp or hbm or ttft or itl or interference):
+    host = s.get("host") or {}
+    if host.get("readings") or host.get("slow"):
+        # what a tick cost the engine THREAD by kind of time (PERF.md
+        # section 3), and the slow periods with the cause their record names
+        out.append("")
+        out.append(f"{'HOST':<28} {'SECONDS':>8} {'TICKS':>7} {'MAX-MS':>7} "
+                   f"{'SLOW-S':>7} {'OFFCORE':>8} {'PREEMPT/S':>9} "
+                   f"{'GC':>6} {'OTHER-CPU':>9}")
+        fmt = lambda v, spec: "n/a" if v is None else format(v, spec)  # noqa: E731
+        for eid, h in host.get("readings", {}).items():
+            out.append(
+                f"{eid[:27]:<28} {h['interval_s']:>8.1f} {h['ticks']:>7} "
+                f"{fmt(h['tick_host_max_ms'], '.0f'):>7} "
+                f"{fmt(h['slow_ticks_s'], '.3f'):>7} "
+                f"{fmt(h['thread_offcore_pct'], '.1f'):>7}% "
+                f"{fmt(h['thread_preempted_per_s'], '.1f'):>9} "
+                f"{fmt(h['gc_pause_pct'], '.2f'):>5}% "
+                f"{fmt(h['other_threads_cpu_pct'], '.1f'):>8}%")
+        for eid, records in host.get("slow", {}).items():
+            for r in records:
+                wall = sum(r["wall_s"].values())
+                worst = max(r["wall_s"], key=r["wall_s"].get)
+                tops = [st_["top"][0][0] for st_ in r.get("stacks") or ()
+                        if st_.get("top")]
+                out.append(
+                    f"  {eid[:27]}: slow {r.get('what', 'tick')} "
+                    f"{wall * 1e3:.0f}ms ({worst}) at "
+                    f"{time.strftime('%H:%M:%S', time.localtime(r['t']))}: "
+                    f"{r['cause']}"
+                    + (" <- " + "|".join(tops[0].split("|")[-2:])
+                       if tops else ""))
+    if not (st["count"] or comp or hbm or ttft or itl or interference
+            or host.get("slow")):
         out.append("(no perf data recorded — run a StepProfiler-"
                    "instrumented train loop or serve LLM traffic; see "
                    "README 'Performance observability')")

@@ -46,7 +46,9 @@ is part of the framework here:
 
 from __future__ import annotations
 
+import contextlib
 import math
+import operator
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -56,6 +58,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private import events as _events
+from ray_tpu._private import sampling_profiler
 from ray_tpu.util import compile_cache, tracing
 
 # the stages of one served request as phases of the span aggregate
@@ -133,20 +136,15 @@ PREFILL_PART_TOKENS = 2048
 def _llm_metrics():
     global _LLM_METRICS
     if _LLM_METRICS is None:
-        from ray_tpu.util.metrics import Counter, Histogram
+        from ray_tpu.util.metrics import Histogram
 
         _LLM_METRICS = {
             "admission": Histogram(
                 "ray_tpu_llm_slot_admission_latency_s",
                 "request submit -> decode-slot admission latency (s)",
                 boundaries=[0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5, 30]),
-            "tokens": Counter(
-                "ray_tpu_llm_generated_tokens_total",
-                "tokens generated by the continuous-batching engine"),
-            # decode-tail attribution (the gpt2 p99/p50=1.39x chase):
-            # TTFT + inter-token latency per request, and the decode-tick
-            # seconds billed to co-scheduled prefill chunks — all
-            # TSDB-exported via the push path
+            # decode-tail attribution: TTFT + inter-token latency per
+            # request, TSDB-exported via the push path
             "ttft": Histogram(
                 "ray_tpu_llm_ttft_s",
                 "request submit -> first generated token (s)",
@@ -156,10 +154,6 @@ def _llm_metrics():
                 "inter-token latency (per-drain mean per request, s)",
                 boundaries=[0.0005, 0.001, 0.005, 0.01, 0.025, 0.05,
                             0.1, 0.5, 1]),
-            "interference": Counter(
-                "ray_tpu_llm_prefill_interference_s_total",
-                "seconds of the periods between two chunk landings that "
-                "went to prefill calls while other slots were decoding"),
         }
     return _LLM_METRICS
 
@@ -181,13 +175,19 @@ class _TickMeter:
     interference*: what the requests that were decoding waited for other
     requests' prompts.
 
-    Also summed here: the engine thread's own time a tick (``host_s``) and,
+    Also summed here: what a tick cost the engine thread, by kind of time
+    (``host``, a :class:`ray_tpu.util.tracing.StallRecorder` over the phases
+    ``HOST_PHASES``: wall and CPU seconds, context switches, GC pauses, a
+    histogram of the ticks' host time and the records of the slow ones), and,
     over finished requests, what their decode spans held (``decode``).
 
     Owned by the engine thread (no locking needed on the hot path);
     ``snapshot()`` reads are torn-tolerant counters."""
 
     EMIT_EVERY = 32  # interleaved ticks per flight-recorder event
+    # a tick's host phases: admission (the prefill calls' dispatch), the
+    # chunk's dispatch, the drain less the time blocked in its reads
+    HOST_PHASES = ("admit", "dispatch", "drain_book")
 
     def __init__(self, entity_id: str):
         self.entity_id = entity_id
@@ -200,14 +200,14 @@ class _TickMeter:
         # span differences them between its first and its last token
         self.prefill_s = 0.0
         self.prefill_calls = 0
-        self.host_s = {"admit": 0.0, "dispatch": 0.0, "drain_book": 0.0}
-        self.ticks_live = 0
+        self.host = tracing.StallRecorder(entity_id, self.HOST_PHASES)
         self.decode = {"requests": 0, "gaps": 0, "chunk_steps_paid": 0,
                        "span_s": 0.0, "prefill_s": 0.0}
         self._landed: Optional[float] = None  # the previous landing
         self._period_s = 0.0    # of the tick being drained, so far
         self._counted = False   # it has a previous landing to start from
         self._calls = 0         # its prefill calls that have landed
+        self._mode: Optional[str] = None  # its class, None if left out
         self._since_emit = 0
         # the engine's recurrent-state counters (its own dict, or None): they
         # ride this meter's event to `ray_tpu perf`; so does the engine's
@@ -242,6 +242,7 @@ class _TickMeter:
         if self._landed is not None:
             self._period_s += t - self._landed
         self._landed = t
+        self._mode = None
         if not self._counted:
             return
         if not (self._calls or n_admitted):
@@ -250,6 +251,7 @@ class _TickMeter:
             mode = "interleaved"
         else:
             mode = "prefill_only"
+        self._mode = mode
         self.ticks[mode] += 1
         self.tick_s[mode] += self._period_s
         if sum(self.ticks.values()) % 256 == 1:
@@ -261,20 +263,26 @@ class _TickMeter:
             _perf.publish_device_memory()
         if mode == "interleaved":
             self.interference_s += prefill_part
-            _llm_metrics()["interference"].inc(prefill_part)
             self._since_emit += 1
             if self._since_emit >= self.EMIT_EVERY:
                 self.emit_event()
 
-    def tick_host(self, admit: float, dispatch: float,
-                  drain_book: float) -> None:
+    def tick_host(self, admit, dispatch, drain_book, began: float = 0.0,
+                  cpu_now: float = 0.0, **facts) -> Optional[dict]:
         """What one tick that dispatched or drained anything cost the
-        engine thread, apart from blocking in the drain's reads."""
-        host = self.host_s
-        host["admit"] += admit
-        host["dispatch"] += dispatch
-        host["drain_book"] += drain_book
-        self.ticks_live += 1
+        engine thread, apart from blocking in the drain's reads: a phase's
+        wall seconds, or its ``tracing.clocks_between`` tuple (wall, CPU,
+        switches of both kinds, GC seconds).  ``began``, ``cpu_now``: the
+        tick's first wall clock read and the thread's CPU clock at its last;
+        ``facts``: what it dispatched.  A slow tick's record
+        (returned, kept in ``host.slow`` and emitted once) also says what the
+        tick it DRAINED was: its class and device period."""
+        deltas = [d if isinstance(d, tuple) else (d, 0.0, 0, 0, 0.0)
+                  for d in (admit, dispatch, drain_book)]
+        return self.host.add(deltas, began, cpu_now,
+                             drained_class=self._mode,
+                             drained_period_s=round(self._period_s, 6),
+                             **facts)
 
     def request_done(self, tokens: int, chunk_steps: int, span_s: float,
                      prefill_s: float) -> None:
@@ -319,8 +327,12 @@ class _TickMeter:
                 if excess > self.interference_s
                 else 1.0 if baseline is not None and self.interference_s > 0
                 else None,
-            "host_s": dict(self.host_s),
-            "ticks_live": self.ticks_live,
+            # the engine thread's time a tick by phase and kind of time,
+            # the ticks' host time by bucket, the slow ticks' records
+            # (``host_s``, ``host_cpu_s``, ``host_switches``, ``host_gc_s``,
+            # ``host_hist``, ``slow_ticks``: StallRecorder.snapshot)
+            **self.host.snapshot(),
+            "ticks_live": self.host.count,
             "decode": dict(self.decode),
         }
 
@@ -342,6 +354,7 @@ class _TickMeter:
             decode_only_ticks=self.ticks["decode_only"],
             baseline_s=snap["decode_tick_baseline_s"],
             tpot_p50_s=tpot.get("p50_s"), tpot_p95_s=tpot.get("p95_s"),
+            host=self.host.summary(),
             **({"state": dict(self.state)} if self.state else {}),
             **({"parts": dict(self.parts)} if self.parts else {}))
 
@@ -473,6 +486,7 @@ class GenerationEngine:
         # before the first program is built: every executable this process
         # compiles or loads from now on is counted (perf_stats()["compiles"])
         compile_cache.listen()
+        tracing.listen_gc()  # ... and every collection (["process"]["gc"])
         # host phases of the engine thread on the profiler's clock: they
         # land in the same trace as the device's ops, so an idle gap there
         # can be named by what this thread was doing (free when no trace
@@ -716,6 +730,9 @@ class GenerationEngine:
             f"engine-{_os.getpid()}-{next(_ENGINE_SEQ)}")
         self._ticks.state = self._state
         self._ticks.parts = self._prefill.get("parts")
+        # the process's sampler looks at the tick's phases between its bursts
+        # (where the continuous profiler is off nobody looks: no stacks)
+        sampling_profiler.watch(self._ticks.host)
         self._ttft_samples: "_deque[float]" = _deque(maxlen=4096)
         self._itl_samples: "_deque[float]" = _deque(maxlen=4096)
 
@@ -805,6 +822,7 @@ class GenerationEngine:
             self._thread.join(timeout=5.0)
             self._thread = None
         self._decode_cut.exception()  # a build still running ends first
+        sampling_profiler.unwatch(self._ticks.host)
         # flush the final interference numbers so a short engine run
         # still leaves the doctor/`ray_tpu perf` its last meter state
         self._ticks.emit_event()
@@ -867,9 +885,17 @@ class GenerationEngine:
             stages = tracing.span_stats(STAGES + PER_GAP)
             stages["clock_skew"] = tracing.clock_skew()
         return {
+            # the wall clock of this read: every difference of two calls has
+            # its denominator, and a slow tick's ``t`` its place in it
+            "t": time.time(),
             "ttft": self._pctiles(ttft),
             "itl": self._pctiles(itl),
             **self._ticks.snapshot(),
+            # what the PROCESS did meanwhile, read here and never on the
+            # engine thread's path (``tracing.process_stats``): every
+            # thread's CPU seconds, the engine thread's own, collections and
+            # their pauses, CPU seconds by thread name
+            "process": tracing.process_stats(self._thread),
             # the request's stages from ingress to the last reply, as this
             # process closed them (cumulative: difference two calls for a
             # window, and take that many off the end of ``recent`` for the
@@ -933,6 +959,7 @@ class GenerationEngine:
                 # silently kill the engine thread and wedge the replica
                 import jax.numpy as jnp
 
+                self._ticks.host.now = None  # no phase is on any more
                 with self._lock:
                     victims = [s for s in self._slots if s is not None]
                     victims += self._queue
@@ -1200,14 +1227,36 @@ class GenerationEngine:
         not the time blocked in its reads) and, from the drain, the record of
         tick N-1: the period between two landings is that tick's device
         time (:class:`_TickMeter`)."""
+        with self._annotate("engine.tick"):
+            return self._tick(self._ticks if _events.ENABLED else None)
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """``with self._lock`` on the engine thread, the WAIT for it under a
+        name of its own in a device trace (submitters and ``perf_stats()``
+        take the same lock)."""
+        with self._annotate("engine.lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
+    def _tick(self, meter: Optional[_TickMeter]) -> bool:
         import jax.numpy as jnp
 
-        meter = self._ticks if _events.ENABLED else None
-        t_tick0 = time.perf_counter() if meter else 0.0
+        # with the meter: the thread's clocks by kind of time where a host
+        # phase begins (``tracing.thread_clocks``), and the phase itself
+        # published for the sampler (``StallRecorder.now``)
+        if meter:
+            c_tick0 = tracing.thread_clocks()
+            meter.host.now = ("admit", c_tick0[0])
         with self._annotate("engine.admit"):
             prefills = self._admit()
-        t_admitted = time.perf_counter() if meter else 0.0
-        with self._lock:
+        if meter:
+            c_admitted = tracing.thread_clocks()
+            meter.host.now = ("dispatch", c_admitted[0])
+        with self._locked():
             # (a slot-holder with nothing scheduled is mid-prefill)
             rows = [(i, s) for i, s in enumerate(self._slots)
                     if s is not None and s.scheduled]
@@ -1253,7 +1302,7 @@ class GenerationEngine:
             # NEXT admission even though their token values haven't landed
             # (completion timing is deterministic; EOS only finishes a
             # request EARLIER, confirmed at drain)
-            with self._lock:
+            with self._locked():
                 if self._compact:
                     stands = self._count_compacting(stands, n)
                 self._cache_tiles["read_full"] += sum(
@@ -1278,19 +1327,29 @@ class GenerationEngine:
                     req.scheduled = min(req.max_new, req.scheduled + n)
                     if req.scheduled >= req.max_new:
                         self._slots[i] = None
-        t_dispatched = time.perf_counter() if meter else 0.0
+        if meter:
+            c_dispatched = tracing.thread_clocks()
+            meter.host.now = ("drain_book", c_dispatched[0])
         prev, self._pending = self._pending, dispatched
-        waited = 0.0
+        waited = tracing.NO_CLOCKS
         if prev is not None:
             self._draining = prev  # visible to _loop's error recovery
             with self._annotate("engine.drain"):
                 waited = self._drain(prev, meter)
             self._draining = None
         worked = dispatched is not None or prev is not None
-        if meter is not None and worked:
-            meter.tick_host(
-                t_admitted - t_tick0, t_dispatched - t_admitted,
-                time.perf_counter() - t_dispatched - waited)
+        if meter:
+            meter.host.now = None
+            if worked:
+                between, c_end = tracing.clocks_between, tracing.thread_clocks()
+                drain = between(c_dispatched, c_end)
+                meter.tick_host(
+                    between(c_tick0, c_admitted),
+                    between(c_admitted, c_dispatched),
+                    tuple(d - w for d, w in zip(drain, waited)),
+                    began=c_tick0[0], cpu_now=c_end[1], rows=len(rows),
+                    steps=dispatched.steps if dispatched else 0,
+                    prefill_calls=len(prefills))
         return worked
 
     def _count_shared(self, stands, n: int) -> None:
@@ -1391,30 +1450,37 @@ class GenerationEngine:
         out["decode"]["steps"] = self._routed["decode_steps"]
         return out
 
-    def _read_back(self, dev) -> tuple:
+    def _read_back(self, dev, meter: Optional[_TickMeter]) -> tuple:
         """``np.asarray(dev)`` (blocks until the device has produced it), the
-        instant it returned, and the seconds it blocked; the wait carries
-        its own annotation, so that in a device trace a gap under
-        ``engine.drain`` and not under it is the drain's bookkeeping."""
-        t = time.perf_counter()
+        instant it returned, and what the thread's clocks moved by while it
+        blocked (``tracing.clocks_between``: the wait is by design, so its
+        seconds and its voluntary switch stay out of the tick's host phases,
+        and the sampler is told no phase is on); the wait carries its own
+        annotation, so that in a device trace a gap under ``engine.drain`` and
+        not under it is the drain's bookkeeping."""
+        before = tracing.thread_clocks()
+        if meter:
+            meter.host.now = None
         with self._annotate("engine.drain_wait"):
             host = np.asarray(dev)
-        landed = time.perf_counter()
-        return host, landed, landed - t
+        after = tracing.thread_clocks()
+        if meter:
+            meter.host.now = ("drain_book", after[0])
+        return host, after[0], tracing.clocks_between(before, after)
 
     def _drain(self, pending: _PendingChunk,
-               meter: Optional[_TickMeter] = None) -> float:
+               meter: Optional[_TickMeter] = None) -> tuple:
         """Materialize one landed chunk: route first tokens + chunk rows to
         their requests, resolve futures, confirm EOS slot frees.  With a
         ``meter`` (the observability layer is on) every stamp is the instant
         the tokens it times were on the host, and the tick's record is made;
-        returns the seconds blocked in the reads."""
-        waited = 0.0
+        returns what the thread's clocks moved by while blocked in the reads."""
+        waited = tracing.NO_CLOCKS
         if meter is not None:
             meter.begin(pending.chained)
         for admissions, firsts_dev, routed_dev, padded in pending.prefills:
-            firsts, landed, blocked = self._read_back(firsts_dev)
-            waited += blocked
+            firsts, landed, blocked = self._read_back(firsts_dev, meter)
+            waited = tuple(map(operator.add, waited, blocked))
             if meter is not None:
                 meter.call_landed(landed)
             for j, slot, req in admissions:
@@ -1440,8 +1506,8 @@ class GenerationEngine:
                 meter.chunk_landed(landed, 0, 0)
             return waited
         # the transfer is already in flight
-        chunk, landed, blocked = self._read_back(pending.chunk_dev)
-        waited += blocked
+        chunk, landed, blocked = self._read_back(pending.chunk_dev, meter)
+        waited = tuple(map(operator.add, waited, blocked))
         if meter is not None:
             meter.chunk_landed(
                 landed, sum(len(adm) for adm, *_ in pending.prefills),
@@ -1499,7 +1565,6 @@ class GenerationEngine:
         the submitter's trace) and, under it, ``engine.decode``: first token
         on the host -> last token on the host."""
         n = len(req.emitted)
-        _llm_metrics()["tokens"].inc(n)
         now = time.perf_counter()
         if meter is not None and req.first_host_t is not None:
             span = req.last_host_t - req.first_host_t

@@ -62,6 +62,11 @@ INGEST_FRACTION = 0.30       # ingest-wait share of step wall to flag
 INGEST_MIN_STEPS = 5         # profiled steps before the share is trusted
 PREFILL_INTERFERENCE_FRAC = 0.20  # interference share of decode tick time
 PREFILL_MIN_TICKS = 20       # interleaved ticks before the share is trusted
+HOST_STALL_TOTAL_S = 1.0     # seconds of slow periods one thread must have
+HOST_STALL_WINDOW_S = 120.0  # on record in the newest two minutes of them: a
+                             # single 2 s stall and a run of 45 ms ticks both
+                             # reach it, a healthy replica's few slow ticks
+                             # (20 - 60 ms in all a minute) do not
 MFU_DROP_FRAC = 0.10         # trailing-window MFU drop vs the earlier mean
 MFU_MIN_LEVEL = 0.02         # earlier-mean floor (CPU dev noise guard)
 TENANT_REAP_STUCK_S = 10.0   # death with no reap for this long = wedged
@@ -620,6 +625,60 @@ def _rule_prefill_interference(events, tasks):
         "(serve.llm.prefill_decode_graph)")
 
 
+# what to do about a stalled host phase, by the cause its record names
+# (``util.tracing.stall_cause``)
+_HOST_STALL_REMEDY = {
+    "gc": "a garbage collection held every thread of the process: freeze "
+          "the long-lived heap once it is built (gc.freeze() after warm-up) "
+          "or raise the oldest generation's threshold",
+    "cpu": "the thread ran that long on its core: the record's stacks say "
+           "in what; move that work off the recurring path or shrink it",
+    "preempted": "the scheduler took the thread's core (involuntary "
+                 "switches): run fewer busy processes than the host has "
+                 "cores, or give the replica a core of its own",
+    "waiting": "the thread blocked on the GIL or on a lock: the record's "
+               "stacks show the thread that ran meanwhile (a high "
+               "lateness_frac: the GIL); move that thread's work to another "
+               "process, or shorten the lock's hold",
+}
+
+
+def _rule_host_stall(events, tasks):
+    """A recurring host period (a serve engine's tick, a train loop's step)
+    took several times its median, for long enough in all, within two
+    minutes, to show in a tail:
+    each such period left a ``slow tick`` event with the thread's time by
+    kind and, where it lasted, the stacks that held the process
+    (``util.tracing.StallRecorder``).  The finding names the cause."""
+    seconds = lambda r: float(r.get("span_dur") or 0.0)  # noqa: E731
+    by_thread: Dict[tuple, list] = {}
+    for r in _rows(events, "perf", "slow tick"):
+        by_thread.setdefault((str(r.get("origin") or "head"),
+                              str(r.get("entity_id"))), []).append(r)
+    flagged, by_cause = [], {}
+    for rows in by_thread.values():
+        newest = max(float(r.get("ts") or 0.0) for r in rows)
+        rows = [r for r in rows
+                if float(r.get("ts") or 0.0) >= newest - HOST_STALL_WINDOW_S]
+        if sum(map(seconds, rows)) < HOST_STALL_TOTAL_S:
+            continue
+        flagged += rows
+        for r in rows:
+            cause = (r.get("data") or {}).get("cause", "waiting")
+            by_cause[cause] = by_cause.get(cause, 0.0) + seconds(r)
+    if not flagged:
+        return None
+    cause = max(by_cause, key=by_cause.get)
+    flagged.sort(key=seconds, reverse=True)
+    return _finding(
+        "host_stall", "WARNING",
+        f"{len(flagged)} slow host period(s), {sum(by_cause.values()):.2f}s "
+        f"in all (the longest {seconds(flagged[0]):.2f}s on "
+        f"{flagged[0].get('entity_id')}); cause: {cause} "
+        f"({by_cause[cause]:.2f}s of them)",
+        flagged, _HOST_STALL_REMEDY[cause])
+
+
 # ---------------------------------------------------------------------------
 # trend rules (each: series_map -> finding | None).  series_map is
 # {metric_name: [{"tags": {...}, "points": [[ts, value], ...]}, ...]} —
@@ -948,6 +1007,7 @@ RULES = (
     _rule_recompile_storm,
     _rule_ingest_bound,
     _rule_prefill_interference,
+    _rule_host_stall,
 )
 
 
