@@ -472,7 +472,11 @@ class ServeController:
 
     def _start_replica(self, state: _DeploymentState) -> None:
         import ray_tpu
-        from ray_tpu.serve._private.replica import ServeReplica
+        from ray_tpu.serve._private.replica import (
+            STREAM_GROUP,
+            STREAM_GROUP_CONCURRENCY,
+            ServeReplica,
+        )
 
         goal = state.goal
         tag = f"{state.name}#{uuid.uuid4().hex[:8]}"
@@ -487,6 +491,11 @@ class ServeController:
         # and with it every health probe.
         groups = dict(options.get("concurrency_groups") or {})
         groups.setdefault("control", 2)
+        # ... and the proxies' ``next_chunks`` pulls in a third lane: a pull
+        # may park on an idle stream (a task of the worker's event loop, no
+        # thread), and neither the head's dispatch window nor the worker's
+        # bound on running coroutines may then be the requests' own
+        groups.setdefault(STREAM_GROUP, STREAM_GROUP_CONCURRENCY)
         options["concurrency_groups"] = groups
         # replicas are serve infrastructure managed (and explicitly
         # killed) by the detached controller: the tenant-disconnect reap
@@ -501,6 +510,9 @@ class ServeController:
             options.setdefault(
                 "max_concurrency", goal["config"].max_concurrent_queries
             )
+        # said, not defaulted: a class with a coroutine method
+        # (``next_chunks``) would default to an async actor's 1000
+        options.setdefault("max_concurrency", 1)
         handle = ray_tpu.remote(ServeReplica).options(**options).remote(
             state.name,
             tag,

@@ -55,6 +55,7 @@ from ray_tpu.serve._private.http_util import (
     encode_response,
     parse_http_head,
 )
+from ray_tpu.serve._private.replica import PULL_WAIT_S, STREAM_GROUP
 from ray_tpu.serve._private.router import Router
 from ray_tpu.serve.config import (
     INGRESS_DEFAULT_TIMEOUT_S,
@@ -521,6 +522,13 @@ class _AsyncIngress:
             max_workers=num_exec_threads, thread_name_prefix="serve-exec")
         self._max_inflight = max_inflight
         self._inflight = 0
+        # stream pulls that may park on their replica ride a pool thread
+        # for as long as they do: at most half of the pool (loop-confined,
+        # like ``_inflight``), so unary requests and 503s keep their threads
+        # under a storm of streams; a stream over the share pulls without
+        # parking and paces itself
+        self._park_share = num_exec_threads // 2
+        self._parking = 0
         self._shedding = False
         self._loop = asyncio.new_event_loop()
         self._startup_error: Optional[BaseException] = None
@@ -670,25 +678,25 @@ class _AsyncIngress:
     async def _stream_response(self, writer: asyncio.StreamWriter,
                                reply: _Reply) -> bool:
         """Chunked-transfer delivery of a StreamingResponse: blocking
-        next_chunks pulls ride the executor, writes stay on the loop.
-        Returns False when the connection is no longer reusable (producer
-        error truncates the body so the client sees an aborted stream,
-        not a clean end)."""
-        import ray_tpu
-
+        next_chunks pulls ride the executor (parked on the replica while
+        the stream is idle), writes stay on the loop.  Returns False when
+        the connection is no longer reusable (producer error truncates the
+        body so the client sees an aborted stream, not a clean end)."""
         replica, meta = reply.stream
         sid = meta["__serve_stream__"]
-
-        def pull():
-            return ray_tpu.get(replica.next_chunks.remote(sid, 16),
-                               timeout=120.0)
-
         try:
             writer.write(
                 (f"HTTP/1.1 200 OK\r\nContent-Type: {reply.ctype}\r\n"
                  "Transfer-Encoding: chunked\r\n\r\n").encode("latin-1"))
             while True:
-                out = await self._loop.run_in_executor(self._pool, pull)
+                may_park = self._parking < self._park_share
+                self._parking += may_park
+                try:
+                    out = await self._loop.run_in_executor(
+                        self._pool, _pull_chunks, replica, sid,
+                        PULL_WAIT_S if may_park else 0.0)
+                finally:
+                    self._parking -= may_park
                 buf = b"".join(
                     f"{len(c):x}\r\n".encode() + c + b"\r\n"
                     for c in out["chunks"] if c)
@@ -701,7 +709,9 @@ class _AsyncIngress:
                     writer.write(b"0\r\n\r\n")
                     await writer.drain()
                     return True
-                if not out["chunks"]:
+                if not out["chunks"] and not out["parked"]:
+                    # not allowed to park (this proxy's share or the
+                    # replica's was taken): pace the pull, don't spin
                     await asyncio.sleep(0.02)
         except Exception:  # noqa: BLE001 — client disconnect or replica
             # death; either way the stream (and connection) is done
@@ -775,13 +785,22 @@ def _threaded_respond(h: BaseHTTPRequestHandler, code: int, body: bytes,
         h._headers_buffer = []
 
 
+def _pull_chunks(replica, sid: str, wait_s: float) -> Dict:
+    """One ``next_chunks`` pull of a stream, in the replica's lane for them;
+    with ``wait_s`` it may park there until the stream has something."""
+    import ray_tpu
+
+    return ray_tpu.get(
+        replica.next_chunks.options(concurrency_group=STREAM_GROUP).remote(
+            sid, 16, wait_s),
+        timeout=120.0)
+
+
 def _threaded_stream(h: BaseHTTPRequestHandler, replica, meta: Dict) -> None:
     """Chunked delivery on the connection thread.  NEVER raises: once the
     200 + chunked headers are on the wire, a second response would corrupt
     the stream — any failure just ends the body and closes the (no longer
     reusable) connection."""
-    import ray_tpu
-
     sid = meta["__serve_stream__"]
     try:
         h.send_response(200)
@@ -789,10 +808,9 @@ def _threaded_stream(h: BaseHTTPRequestHandler, replica, meta: Dict) -> None:
         h.send_header("Transfer-Encoding", "chunked")
         h.end_headers()
         while True:
-            # non-blocking drain replica-side; an empty reply means the
-            # producer hasn't caught up — pace the poll, don't spin
-            out = ray_tpu.get(replica.next_chunks.remote(sid, 16),
-                              timeout=120.0)
+            # parked replica-side while the producer has nothing (the
+            # connection's own thread waits with it: no shared pool here)
+            out = _pull_chunks(replica, sid, PULL_WAIT_S)
             for c in out["chunks"]:
                 if c:  # a zero-length chunk would terminate the stream
                     h.wfile.write(f"{len(c):x}\r\n".encode() + c + b"\r\n")
@@ -806,8 +824,8 @@ def _threaded_stream(h: BaseHTTPRequestHandler, replica, meta: Dict) -> None:
                     return
                 h.wfile.write(b"0\r\n\r\n")
                 return
-            if not out["chunks"]:
-                time.sleep(0.02)
+            if not out["chunks"] and not out["parked"]:
+                time.sleep(0.02)  # the replica's share of parks was taken
     except Exception:  # noqa: BLE001 — includes client disconnects and
         # replica death; the connection is unusable either way
         h.close_connection = True

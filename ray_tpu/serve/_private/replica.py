@@ -9,6 +9,7 @@ so the scheduler pins a chip before the model loads.
 
 from __future__ import annotations
 
+import asyncio
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -16,6 +17,32 @@ import cloudpickle
 
 from ray_tpu.serve.exceptions import ReplicaDrainingError
 from ray_tpu.util import tracing
+
+# The longest a ``next_chunks`` pull parks on an idle stream before it
+# answers empty and the proxy asks again: a safety net under the wake-ups
+# (a put, the producer's end, a cancel), far below the 120 s the proxy gives
+# a pull's ``get``.  Nothing a deployment would tune: it is only ever read by
+# a stream whose producer is silent for longer.
+PULL_WAIT_S = 1.0
+# ``next_chunks`` runs in a concurrency group of its own (the controller
+# declares it on every replica, as it does "control"): the head's dispatch
+# window and the worker's bound on running coroutines are a group's, so
+# parked pulls take nothing from the lane ``handle_request`` runs in.  At
+# most half of the group's calls may be PARKED; a pull past that answers at
+# once (``parked: False``) and the proxy paces it, so a pull is never queued
+# behind sleepers.
+STREAM_GROUP = "stream"
+STREAM_GROUP_CONCURRENCY = 256
+MAX_PARKED_PULLS = STREAM_GROUP_CONCURRENCY // 2
+
+
+def _wake(state: Dict[str, Any]) -> None:
+    """Wake the pull parked on this stream, if one is (any thread).  The
+    parker publishes ``waker`` BEFORE it looks at the queue again and the
+    producer looks for it AFTER its put, so one of them sees the other."""
+    waker = state["waker"]
+    if waker is not None:
+        waker()
 
 
 class ServeReplica:
@@ -54,6 +81,10 @@ class ServeReplica:
         # batches of chunks with next_chunks until exhausted)
         self._streams: Dict[str, Any] = {}
         self._streams_lock = threading.Lock()
+        # pulls parked right now, and every reply ``next_chunks`` made by
+        # kind; both touched on the worker's event loop alone
+        self._parked = 0
+        self._stream_pulls = {"woken": 0, "timed_out": 0, "unparked": 0}
 
     def handle_request(self, method_name: str, args: Tuple, kwargs: Dict) -> Any:
         """Run one request (``replica.py:250`` handle_request analog).
@@ -118,9 +149,12 @@ class ServeReplica:
 
     def _register_stream(self, result) -> Dict[str, Any]:
         """Drain the generator on a dedicated thread into a bounded queue
-        so follow-up ``next_chunks`` polls never BLOCK a replica executor
+        so follow-up ``next_chunks`` pulls never BLOCK a replica executor
         thread between chunks (N slow streams would otherwise pin N
-        threads and exhaust max_concurrency)."""
+        threads and exhaust max_concurrency).  A pull that finds the queue
+        empty PARKS, but as a task of the worker's event loop
+        (``next_chunks`` is a coroutine): this thread wakes it after a put
+        and at its end, and no executor thread waits with it."""
         import queue as queue_mod
         import threading
         import uuid
@@ -141,7 +175,8 @@ class ServeReplica:
                  "error": None, "stop": threading.Event(),
                  "stage_ctx": ctx if ctx and "t_root" in ctx else None,
                  "first_put_t": None, "ended_t": None,
-                 "first_data_t": None, "last_data_t": None, "n_chunks": 0}
+                 "first_data_t": None, "last_data_t": None, "n_chunks": 0,
+                 "waker": None}
 
         def drain(it=iter(result.iterable)):
             token = tracing.adopt(ctx)
@@ -149,12 +184,13 @@ class ServeReplica:
                 for chunk in it:
                     data = encode_chunk(chunk)
                     if state["first_put_t"] is None:
-                        # stamped BEFORE the put: a poll that finds the
+                        # stamped BEFORE the put: a pull that finds the
                         # chunk must find its time too
                         state["first_put_t"] = time.perf_counter()
                     while not state["stop"].is_set():
                         try:
                             state["q"].put(data, timeout=0.2)
+                            _wake(state)
                             break
                         except queue_mod.Full:
                             continue
@@ -167,6 +203,7 @@ class ServeReplica:
             finally:
                 state["ended_t"] = time.perf_counter()  # before ``done``
                 state["done"] = True
+                _wake(state)
                 tracing.restore(token)
 
         threading.Thread(target=drain, daemon=True,
@@ -175,15 +212,32 @@ class ServeReplica:
             self._streams[sid] = state
         return {"__serve_stream__": sid, "content_type": result.content_type}
 
-    def next_chunks(self, sid: str, max_n: int = 16) -> Dict[str, Any]:
-        """Non-blocking drain of up to ``max_n`` buffered chunks; ``done``
-        unregisters the stream, ``error`` carries a producer failure."""
+    async def next_chunks(self, sid: str, max_n: int = 16,
+                          wait_s: float = 0.0) -> Dict[str, Any]:
+        """Up to ``max_n`` buffered chunks; ``done`` unregisters the stream,
+        ``error`` carries a producer failure.  With ``wait_s`` a pull that
+        finds the queue empty and the producer alive PARKS until a chunk is
+        put, the producer ends, the stream is cancelled or ``wait_s`` runs
+        out, and says so (``parked``; an empty reply without it asks the
+        proxy to pace itself).  Parked, it is a task of the worker's event
+        loop awaiting a future that the stream's thread resolves through
+        ``call_soon_threadsafe``: it pins no executor thread, and the group
+        it runs in (``STREAM_GROUP``) keeps it out of the requests' lane."""
         import queue as queue_mod
 
         with self._streams_lock:
             state = self._streams.get(sid)
         if state is None:
-            return {"chunks": [], "done": True}
+            self._stream_pulls["unparked"] += 1
+            return {"chunks": [], "done": True, "parked": False}
+        kind = "unparked"
+        if (wait_s > 0 and state["waker"] is None
+                and self._parked < MAX_PARKED_PULLS):
+            kind = await self._park(state, wait_s)
+        self._stream_pulls[kind] += 1
+        parked = kind != "unparked"
+        if state["stop"].is_set():  # cancelled: the producer is winding up
+            return {"chunks": [], "done": True, "parked": parked}
         chunks = []
         for _ in range(max_n):
             try:
@@ -198,9 +252,9 @@ class ServeReplica:
             now = time.perf_counter()
             if state["first_data_t"] is None:
                 # the first reply that carries data: how long the first
-                # chunk lay in the queue waiting for this poll, and, on its
-                # own two clock reads, the whole way from ingress to here
-                # (the stage the others must add up to)
+                # chunk lay in the queue before this pull returned it, and,
+                # on its own two clock reads, the whole way from ingress to
+                # here (the stage the others must add up to)
                 state["first_data_t"] = now
                 tracing.emit_stage(
                     "serve.pickup", now - state["first_put_t"], ctx)
@@ -213,13 +267,42 @@ class ServeReplica:
             if state["first_data_t"] is not None:
                 self._emit_stream_stages(state, ctx)
         return {"chunks": chunks, "done": finished,
-                "error": state["error"] if finished else None}
+                "error": state["error"] if finished else None,
+                "parked": parked}
+
+    async def _park(self, state: Dict[str, Any], wait_s: float) -> str:
+        """Wait, as a task of the running loop, until the stream has
+        something to say.  The kind of pull this made: ``"woken"`` by the
+        producer (a put, its end) or a cancel, ``"timed_out"`` after
+        ``wait_s``, ``"unparked"`` if there was something by the time the
+        waker was published (nothing was waited for)."""
+        loop = asyncio.get_running_loop()
+        woke = loop.create_future()
+
+        def wake(kind: str) -> None:
+            if not woke.done():
+                woke.set_result(kind)
+
+        state["waker"] = lambda: loop.call_soon_threadsafe(wake, "woken")
+        self._parked += 1
+        try:
+            if (not state["q"].empty() or state["done"]
+                    or state["stop"].is_set()):
+                return "unparked"
+            timer = loop.call_later(wait_s, wake, "timed_out")
+            try:
+                return await woke
+            finally:
+                timer.cancel()
+        finally:
+            self._parked -= 1
+            state["waker"] = None
 
     @staticmethod
     def _emit_stream_stages(state: Dict[str, Any], ctx) -> None:
         """The finishing reply of a stream that delivered data.  Both stages
         end at the LAST reply that carried data (this one may be empty, a
-        poll later): how long the last chunk lay in the queue, and, on its
+        pull later): how long the last chunk lay in the queue, and, on its
         own two clock reads, first data reply -> last data reply, which the
         request's other decode stages must add up to (``serve.llm.STAGES``);
         the same over the gaps between the chunks delivered is folded as
@@ -240,6 +323,7 @@ class ServeReplica:
             state = self._streams.pop(sid, None)
         if state is not None:
             state["stop"].set()
+            _wake(state)
         return state is not None
 
     def reconfigure(self, user_config: Any) -> bool:
@@ -268,6 +352,10 @@ class ServeReplica:
             "draining": draining,
             "pid": os.getpid(),
             "uptime_s": time.time() - self._start_time,
+            # ``next_chunks`` replies by kind: after a park that a put, the
+            # producer's end or a cancel ended / after one that ran out /
+            # without one (data at once, or not allowed to park)
+            "stream_pulls": dict(self._stream_pulls),
         }
 
     # -- graceful draining ---------------------------------------------

@@ -713,6 +713,11 @@ class GenerationEngine:
         self._lock = threading.Lock()
         self._work = threading.Event()
         self._stop = threading.Event()
+        # what ``stream`` waits on: notified by a drain after each prefill
+        # call's first tokens' appends and after its chunk rows' and the
+        # futures it resolved (a call or a chunk, never a token), by the
+        # failure path and by ``stop``; its lock guards nothing else
+        self._landed = threading.Condition()
         self._thread: Optional[threading.Thread] = None
         # serving metrics (Serve data-plane observability)
         self.total_generated = 0
@@ -770,32 +775,40 @@ class GenerationEngine:
     def stream(self, tokens: List[int], max_new: Optional[int] = None,
                timeout: float = 300.0):
         """Yield token ids AS THE ENGINE EMITS THEM (token streaming for
-        serve's chunked responses).  Raises the request's error, if any."""
+        serve's chunked responses).  Raises the request's error, if any.
+        Between two looks it waits on the drain's signal (``_landed``), the
+        ``timeout`` its only clock."""
         req = self._submit_req(tokens, max_new)
         n = 0
-        yielded_t = None  # when the newest token of a poll was handed over
+        yielded_t = None  # when the newest token of a look was handed over
         deadline = time.perf_counter() + timeout
         while True:
-            # read BEFORE the snapshot: the engine resolves the future
-            # after its last append, so a done request's tokens are all in
-            done = req.future.done()
-            emitted = req.emitted  # list append is atomic; len-snapshot safe
-            m = len(emitted)
+            with self._landed:
+                # read BEFORE the snapshot: the engine resolves the future
+                # after its last append, so a done request's tokens are all in
+                done = req.future.done()
+                emitted = req.emitted  # list append is atomic
+                m = len(emitted)
+                if m == n and not done:  # nothing new: wait for a drain
+                    self._landed.wait(
+                        max(0.0, deadline - time.perf_counter()))
+                    done = req.future.done()
+                    m = len(emitted)
             while n < m:
                 if n == 0 and req.first_host_t is not None:
-                    # what this loop's poll adds to the first token
+                    # what the wake-up adds to the first token
                     tracing.emit_stage(
                         "engine.stream_yield",
                         time.perf_counter() - req.first_host_t,
                         req.trace_ctx)
                 if n == m - 1 and req.first_host_t is not None:
-                    yielded_t = time.perf_counter()  # one read a poll
+                    yielded_t = time.perf_counter()  # one read a look
                 yield emitted[n]
                 n += 1
             if done:
                 if yielded_t is not None:
                     # ... and what it added to the last: emitted here, where
-                    # the request's stamps are final, whichever poll saw the
+                    # the request's stamps are final, whichever look saw the
                     # last token (the engine resolves the future a little
                     # after appending it)
                     tracing.emit_stage(
@@ -806,7 +819,10 @@ class GenerationEngine:
                 return
             if time.perf_counter() > deadline:
                 raise TimeoutError("token stream timed out")
-            time.sleep(0.02)
+
+    def _wake_streams(self) -> None:
+        with self._landed:
+            self._landed.notify_all()
 
     def start(self) -> "GenerationEngine":
         if self._thread is None:
@@ -818,6 +834,7 @@ class GenerationEngine:
     def stop(self) -> None:
         self._stop.set()
         self._work.set()
+        self._wake_streams()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
@@ -978,6 +995,7 @@ class GenerationEngine:
                 for req in dict.fromkeys(victims):
                     if not req.future.done():
                         req.future.set_exception(e)
+                self._wake_streams()  # each waiting ``stream`` raises it
                 # the donated cache lineage may be poisoned mid-pipeline;
                 # restart from a fresh one so the engine survives
                 self.cache = self._gen.init_cache(
@@ -1500,6 +1518,11 @@ class GenerationEngine:
                 # after the stamps: the stream thread reads them the
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
+            if admissions:
+                # a call's first tokens' wake, BEFORE the next read blocks:
+                # they were on the host a call, or a whole chunk, ahead of
+                # what the drain reads next
+                self._wake_streams()
             self._count_routed("prefill", routed_dev, padded=padded)
         if pending.chunk_dev is None:  # a tick of parts alone: no chunk
             if meter is not None:
@@ -1556,6 +1579,7 @@ class GenerationEngine:
                 if _events.ENABLED:
                     self._emit_done(i, req, meter)
                 req.future.set_result(req.emitted)
+        self._wake_streams()  # the chunk rows' wake, after the futures
         return waited
 
     def _emit_done(self, slot: int, req: _Request,

@@ -362,17 +362,18 @@ def test_engine_spans_do_not_depend_on_the_ingress():
                    for r in rows)
 
 
-def test_last_yield_is_emitted_when_the_future_resolves_a_poll_late(
+def test_last_yield_is_emitted_when_the_future_resolves_a_wake_late(
         monkeypatch):
     """The engine appends a request's last token, emits what it emits once a
-    request, and only then resolves the future: a poll of ``stream`` that
-    lands in between yields every token and sees the request done a poll
-    later.  The stage is still emitted, once, timed at the yield."""
+    request, and only then resolves the future.  An answer of ONE token is
+    its prefill's: the drain's first wake hands it to ``stream``, which sees
+    the request done a wake later (after the chunk's read and the rows'
+    bookkeeping).  The stage is still emitted, once, timed at the yield."""
     eng = tiny_engine()
     emit_done = eng._emit_done
 
     def slow_emit_done(*a):
-        time.sleep(0.08)  # four polls of the stream thread
+        time.sleep(0.08)  # between the last append and the resolution
         emit_done(*a)
 
     try:
@@ -380,17 +381,98 @@ def test_last_yield_is_emitted_when_the_future_resolves_a_poll_late(
         monkeypatch.setattr(eng, "_emit_done", slow_emit_done)
         seq = events_mod.buffer().last_seq()
         t0 = time.perf_counter()
-        for n, _ in enumerate(eng.stream([3, 5, 7], 6), 1):
-            if n == 6:
-                got_last = time.perf_counter() - t0
+        for n, _ in enumerate(eng.stream([3, 5, 7], 1), 1):
+            got_last = time.perf_counter() - t0
         took = time.perf_counter() - t0
     finally:
         eng.stop()
+    assert n == 1
     assert took - got_last > 0.03  # the last token came before ``done``
     found = [r for r in events_mod.buffer().since(seq)
              if (r.get("data") or {}).get("phase") == "engine.last_yield"]
     assert len(found) == 1
-    assert 0 <= found[0]["span_dur"] < 0.06  # a poll, not the wait for done
+    assert 0 <= found[0]["span_dur"] < 0.06  # a wake, not the wait for done
+
+
+def _stage_durations(seq, phase):
+    return [r["span_dur"] for r in events_mod.buffer().since(seq)
+            if (r.get("data") or {}).get("phase") == phase]
+
+
+def test_stream_yields_at_the_drains_wake_not_at_a_poll():
+    """A token the drain appended is handed over by ``stream`` at the
+    drain's signal: the wake-up's latency, not a share of a 20 ms sleep (a
+    uniform 0 - 20 ms wait has a median of 10), and every token of a chunk
+    comes with the same wake."""
+    import inspect
+
+    assert "sleep" not in inspect.getsource(GenerationEngine.stream)
+    eng = tiny_engine()
+    try:
+        eng.generate([3, 5, 7], 6)  # build the programs
+        seq = events_mod.buffer().last_seq()
+        stamps = []
+        for i in range(8):
+            stamps.append([time.perf_counter()
+                           for _ in eng.stream([3, 5, 7 + i], 6)])
+    finally:
+        eng.stop()
+    first = sorted(_stage_durations(seq, "engine.stream_yield"))
+    last = sorted(_stage_durations(seq, "engine.last_yield"))
+    assert len(first) == len(last) == 8
+    assert first[4] < 0.008 and last[4] < 0.008, (first, last)
+    for row in stamps:
+        # 1 token of the prefill, then chunks of 3 + 2 (cut at the cap):
+        # a chunk's tokens are yielded together, no sleep between them
+        assert len(row) == 6
+        assert row[3] - row[1] < 0.005 and row[5] - row[4] < 0.005, row
+
+
+def test_engine_failure_wakes_every_waiting_stream_with_the_error(
+        monkeypatch):
+    """``_loop``'s failure path fails every victim and wakes the streams
+    that wait for them: each raises the engine's error at once, the ones
+    that held slots and the ones still queued alike."""
+    eng = tiny_engine()
+    caught = []
+
+    def consume(i):
+        t0 = time.perf_counter()
+        try:
+            list(eng.stream([3, 5, 7 + i], 6, timeout=60.0))
+        except Exception as e:  # noqa: BLE001 — the error IS the result
+            caught.append((type(e), str(e), time.perf_counter() - t0))
+
+    def broken_tick(meter):
+        raise RuntimeError("device on fire")
+
+    try:
+        eng.generate([3, 5, 7], 6)
+        monkeypatch.setattr(eng, "_tick", broken_tick)
+        with ThreadPoolExecutor(4) as pool:  # 2 slots: two of them queue
+            list(pool.map(consume, range(4)))
+    finally:
+        eng.stop()
+    assert [(c, m) for c, m, _ in caught] == [
+        (RuntimeError, "device on fire")] * 4
+    assert max(t for *_, t in caught) < 5.0  # not the stream's timeout
+
+
+def test_stream_timeout_still_raises_and_stop_wakes_the_waiters():
+    """Nothing drains a never-started engine: ``stream`` waits on the
+    condition and its ``timeout`` is the only clock.  ``stop()`` notifies,
+    and a waiter that finds nothing new goes back to waiting."""
+    cfg = make_config("gpt2", "tiny", dtype=jnp.float32)
+    eng = GenerationEngine(cfg, n_slots=2, max_new_tokens=6,
+                           decode_chunk_steps=3, prefill_buckets=(8,))
+    t0 = time.perf_counter()
+    with ThreadPoolExecutor(1) as pool:
+        fut = pool.submit(lambda: list(eng.stream([3, 5, 7], 6, timeout=0.6)))
+        time.sleep(0.2)
+        eng.stop()  # wakes it; nothing landed, so it waits the rest out
+        with pytest.raises(TimeoutError):
+            fut.result(timeout=30)
+    assert 0.55 < time.perf_counter() - t0 < 5.0
 
 
 def test_events_off_means_no_stage_and_no_span(monkeypatch):
