@@ -39,6 +39,25 @@ def _step_time_hist():
     return _STEP_TIME_HIST
 
 
+_EXPERT_LOAD_GAUGE = None
+
+
+def _expert_load_gauge():
+    """What a sparse model's train loop reports beside its loss each step
+    (``expert_chip_load_max_over_mean``: the fullest chip's routed pairs over
+    the mean chip's, the worst layer: the straggler the experts' exchange
+    waits for), as a series beside the step time."""
+    global _EXPERT_LOAD_GAUGE
+    if _EXPERT_LOAD_GAUGE is None:
+        from ray_tpu.util.metrics import Gauge
+
+        _EXPERT_LOAD_GAUGE = Gauge(
+            "ray_tpu_train_expert_chip_load_max_over_mean",
+            "routed (token, expert) pairs: the fullest chip over the mean chip",
+            tag_keys=("rank",))
+    return _EXPERT_LOAD_GAUGE
+
+
 class _Session:
     def __init__(
         self, *, world_size: int = 1, world_rank: int = 0, local_rank: int = 0,
@@ -82,6 +101,10 @@ class _Session:
                 _step_time_hist().observe(
                     period[0], tags={"rank": str(self.world_rank)})
             self._clocks = now
+            if "expert_chip_load_max_over_mean" in metrics:
+                _expert_load_gauge().set(
+                    metrics["expert_chip_load_max_over_mean"],
+                    tags={"rank": str(self.world_rank)})
         if self._report_fn is not None:
             self._report_fn(metrics, checkpoint)
 

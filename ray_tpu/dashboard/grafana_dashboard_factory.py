@@ -35,6 +35,8 @@ CORE_METRICS: Dict[str, tuple] = {
     "ray_tpu_serve_router_queue_len": ("gauge", "router queue length"),
     "ray_tpu_llm_slot_admission_latency_s": ("histogram", "decode-slot admission latency"),
     "ray_tpu_train_step_time_s": ("histogram", "train step time"),
+    "ray_tpu_train_expert_chip_load_max_over_mean": (
+        "gauge", "routed pairs, fullest chip over mean chip"),
     "ray_tpu_data_ingest_wait_s_total": ("counter", "train ingest-wait seconds"),
     # perf observability (util/perf.py + serve/llm.py decode attribution)
     "ray_tpu_train_phase_seconds": ("histogram", "step-phase wall seconds"),

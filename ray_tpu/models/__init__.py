@@ -11,6 +11,15 @@ rules in :mod:`ray_tpu.parallel.sharding` apply mechanically.  Families:
 - :mod:`ray_tpu.models.llama` — Llama-family decoder (RMSNorm/RoPE/
   SwiGLU/grouped-query attention; long-context + GQA KV savings).
 - :mod:`ray_tpu.models.mlp` — MNIST-class MLP (BASELINE config 2).
+- :mod:`ray_tpu.models.smallthinker` — a sparse decoder TRAINED (SmallThinker-
+  21B-A3B): ReGLU experts in every layer, routed from the layer's input, a
+  per-layer choice of rotary and window from two layouts; a loss with the
+  load-balance term, a train step, logical axes, the experts spread over the
+  chips and brought to each chip's tokens
+  (:func:`ray_tpu.ops.moe.experts_ffn_train`).
+  Trained, not served: it is not in ``generate.FAMILIES``.  Reference:
+  ``benchmark/reference/smallthinker_ref.py``; cell:
+  ``train-smallthinker-21b-a3b-ep4-8k``.
 
 A transformer family writes its block once; training, prefill and decode
 share it through one seam, the attention middle the block takes as an

@@ -138,12 +138,21 @@ def block_logical_axes(n_experts: int = 0) -> Dict[str, Tuple]:
 
 
 def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = None,
-                              rules: Optional[ShardingRules] = None):
+                              rules: Optional[ShardingRules] = None, *,
+                              counters=None, name: Optional[str] = None):
     """Shared train-step recipe for every model family: value_and_grad of
     ``loss_fn(params, batch, cfg, mesh)`` + optimizer update.  One place to
     fix donation/metrics for all models.  ``rules``: the table the caller
     placed the parameters with, when it is not ``rules_for_mesh(mesh)``
-    (handed on as ``loss_fn(..., rules=rules)``)."""
+    (handed on as ``loss_fn(..., rules=rules)``).
+
+    ``counters`` (a family with more to report than its loss):
+    ``counters(grads) -> dict``, and ``loss_fn`` then returns ``(loss, dict)``;
+    both dicts ride out in the step's metrics beside ``loss`` and ``step``
+    (what a family counts on the device: routed pairs, the loss's terms, the
+    norms of named gradients).  ``name``: the step function's, and so the
+    compiled program's (``jit_<name>``: what a device trace lists it under);
+    default ``train_step``."""
     import optax
 
     if rules is not None:
@@ -151,12 +160,21 @@ def make_train_step_from_loss(loss_fn, cfg, optimizer, mesh: Optional[Mesh] = No
 
     def train_step(state, batch):
         params, opt_state, step = state["params"], state["opt_state"], state["step"]
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg, mesh)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        if counters is None:
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg, mesh)
+            counted = {}
+        else:
+            (loss, counted), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch, cfg, mesh)
+            counted = {**counted, **counters(grads)}
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return ({"params": params, "opt_state": opt_state, "step": step + 1},
-                {"loss": loss, "step": step + 1})
+                {"loss": loss, "step": step + 1, **counted})
 
+    if name:
+        train_step.__name__ = train_step.__qualname__ = name
     return train_step
 
 

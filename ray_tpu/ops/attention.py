@@ -485,7 +485,7 @@ def _flash_forward(qp, kp, vp, *, layout, causal: bool, scale: float,
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, *, heads: int,
                       scale: float, causal: bool, block_q: int, block_k: int,
-                      q_offset: int):
+                      q_offset: int, window: int = 0):
     """dQ, dK and dV in ONE kernel (five matmuls and one exp a cell, where a
     ``dq`` and a ``dk, dv`` kernel took seven and two): grid (batch, lane
     blocks of heads, n_k_blocks); a row's queries, dO, logsumexp and Δ stay in
@@ -500,7 +500,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     used as the rows (positions on the lanes) they are stored as.  Of a lane
     block of several heads, a head's cell contracts ITS lanes of K and V (the
     others zeroed once a key block), so dS K lands on its own lanes of dQ, and
-    dK and dV keep their own lanes at the end."""
+    dK and dV keep their own lanes at the end.  ``window`` (0: none; causal):
+    a query attends the ``window`` keys up to its own position only, so the
+    walk ends at the last chunk whose band still reaches this key block, and a
+    chunk the band's lower edge crosses is masked like one the diagonal
+    crosses."""
     ki, nk = pl.program_id(2), pl.num_programs(2)
     nq = q_ref.shape[1] // block_q
     first_k = ki * block_k
@@ -509,12 +513,18 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    whole = 0
+    whole, inside, end = 0, nq, nq
     if causal:
         # the first chunk of queries whose last row sees this key block, and
         # the first whose FIRST row sees its last key
         seen = jnp.clip((first_k - q_offset) // block_q, 0, nq)
         whole = jnp.clip(-((q_offset - first_k - block_k + 1) // block_q), 0, nq)
+    if window:
+        # the chunks whose LAST row's band still holds this block's first key,
+        # and those whose first row's band holds its last
+        inside = jnp.clip((first_k + window - q_offset) // block_q, whole, nq)
+        end = jnp.clip(
+            (first_k + block_k + window - 2 - q_offset) // block_q + 1, inside, nq)
     dk_all = jnp.zeros(k_ref.shape[1:], jnp.float32)
     dv_all = jnp.zeros(v_ref.shape[1:], jnp.float32)
     for i in range(heads):
@@ -527,8 +537,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, do = q_ref[0, rows, :], do_ref[0, rows, :]
             s = _nt(k, q) * scale                               # [bk, bq]
             if masked:
-                s = jnp.where(_causal_mask(q_offset + j * block_q, first_k,
-                                           s.shape, keys_first=True), s, NEG_INF)
+                mask = _causal_mask(q_offset + j * block_q, first_k,
+                                    s.shape, keys_first=True)
+                if window:
+                    mask = mask & (
+                        first_k + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                        > q_offset + j * block_q - window
+                        + lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                s = jnp.where(mask, s, NEG_INF)
             p = jnp.exp(s - lse_ref[0, 0, stat, :])
             dv = dv + _nn(p.astype(do.dtype), do)
             ds = (p * (_nt(v, do) - delta_ref[0, 0, stat, :])).astype(q.dtype)
@@ -542,7 +558,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             carry = lax.fori_loop(
                 seen, whole, functools.partial(chunk, masked=True), carry)
         dk, dv = lax.fori_loop(
-            whole, nq, functools.partial(chunk, masked=False), carry)
+            whole, inside, functools.partial(chunk, masked=False), carry)
+        if window:
+            dk, dv = lax.fori_loop(
+                inside, end, functools.partial(chunk, masked=True), (dk, dv))
         dk_all = dk_all + _own_lanes(dk, i, heads)
         dv_all = dv_all + _own_lanes(dv, i, heads)
     dk_ref[0] = (dk_all * scale).astype(dk_ref.dtype)
@@ -554,7 +573,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(qp, kp, vp, outp, lse, gp, *, layout, causal, scale,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, window: int = 0):
     """Packed operands, result and cotangent in; packed ``(dq, dk, dv)``."""
     heads, lw, lwv = layout
     n, t_q, w = qp.shape
@@ -577,7 +596,7 @@ def _flash_backward(qp, kp, vp, outp, lse, gp, *, layout, causal, scale,
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, heads=heads, scale=scale,
                           causal=causal, block_q=bq, block_k=bk,
-                          q_offset=t_k - t_q),
+                          q_offset=t_k - t_q, window=window),
         grid=(n, nb, t_k // bk),
         in_specs=[row_of_q(lw), by_k(lw), by_k(lwv), row_of_q(lwv), stat, stat],
         out_specs=[row_of_q(lw), by_k(lw), by_k(lwv)],
@@ -601,12 +620,13 @@ _FLASH_BWD_BLOCK = 512
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
 def flash_attention_tpu(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = False, scale: Optional[float] = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """Pallas flash attention: an MXU-tiled forward kernel and ONE backward
     kernel.  The backward is recompute-free: P is rebuilt from the forward's
@@ -614,23 +634,34 @@ def flash_attention_tpu(
     flash backward, dq and dk/dv fused); it keeps a row's queries and dO in
     VMEM (``flash_plan`` bounds the length).  ``block_q``, ``block_k``: the
     forward's; the backward's are these up to 512.  Heads of 64 or 128 are
-    read and written where the projections leave them (:func:`_flash_pack`)."""
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)[0]
+    read and written where the projections leave them (:func:`_flash_pack`).
+    ``window`` (0: none; causal self-attention): position ``i`` attends ``i -
+    window < j <= i``; both kernels leave out the cells wholly below the band
+    as they do those above the diagonal."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=0):
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     pack, unpack, layout = _flash_pack(q, k, v)
     qp, kp, vp = pack(q), pack(k), pack(v)
+    band = {}
+    if window:
+        # the forward kernel takes a band beside runtime bounds: every row's
+        # first query at position 0, all of the keys, none before the first
+        assert causal and q.shape[-2] == k.shape[-2], (causal, q.shape, k.shape)
+        band = dict(window=window, bounds=jnp.tile(
+            jnp.array([0, k.shape[-2], 0], jnp.int32), q.shape[0]))
     outp, lse = _flash_forward(
         qp, kp, vp, layout=layout, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, block_k=block_k, interpret=interpret, **band,
     )
     outp, lse = map(checkpoint_name, (outp, lse), FLASH_RESIDUALS)
     return unpack(outp), (q, k, v, outp, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     q, k, v, outp, lse = res
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     pack, unpack, layout = _flash_pack(q, k, v)
@@ -638,6 +669,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
         pack(q), pack(k), pack(v), outp, lse, pack(g), layout=layout,
         causal=causal, scale=scale, block_q=min(block_q, _FLASH_BWD_BLOCK),
         block_k=min(block_k, _FLASH_BWD_BLOCK), interpret=interpret,
+        window=window,
     )
     return tuple(map(unpack, grads))
 
@@ -1271,10 +1303,16 @@ def flash_plan(q_shape, k_shape, v_shape=None, *, causal: bool, window: int = 0,
     1,024, else 512, where the lengths allow (the chip's sweep: a grid step's
     fixed cost outweighs what a finer causal staircase skips; PERF.md section
     6, PR 43); no plan where a row's queries, dO and float32 dQ would not sit
-    in the backward kernel's VMEM together (T = 32k with heads of 64)."""
+    in the backward kernel's VMEM together (T = 32k with heads of 64).  A
+    window layer's band goes to the pair from ``FLASH_MIN_T`` positions a
+    window up (a training step at 8k under a 4,096 band, whose masked scores a
+    block of queries would be gigabytes); the served families' narrower bands
+    (128 - 513) keep :func:`band_attention`."""
     t_q, t_k = q_shape[-2], k_shape[-2]
     square = causal and t_q == t_k and t_q >= FLASH_MIN_T
-    if window or len(q_shape) != 4 or not (square or t_k >= 8192):
+    if window and not (square and window >= FLASH_MIN_T):
+        return None
+    if len(q_shape) != 4 or not (square or t_k >= 8192):
         return None
     wide = lambda b, t: max(b, next(  # noqa: E731
         (w for w in (1024, 512) if t % w == 0), b))
@@ -1297,7 +1335,8 @@ def attention(
     runs which).  Single entry point used by the model zoo.
 
     - a window layer (``window > 0``: causal, and position ``i`` attends
-      ``i - window < j <= i``) → :func:`band_attention`
+      ``i - window < j <= i``) → :func:`band_attention`, or the Pallas pair
+      under its band where :func:`flash_plan` says so (wide windows)
     - lowered for a TPU, block-divisible: causal self-attention from
       ``FLASH_MIN_T`` (1,024) positions up, and anything from 8k keys up →
       :func:`flash_attention_tpu` (pallas fwd + recompute-free bwd kernels;
@@ -1308,11 +1347,11 @@ def attention(
       length; ring attention covers sharded-T)
     """
     t_q, t_k = q.shape[-2], k.shape[-2]
-    if window:
-        assert causal and t_q == t_k, (causal, t_q, t_k)
-        return band_attention(q, k, v, window=window, scale=scale)
+    assert not window or (causal and t_q == t_k), (window, causal, t_q, t_k)
 
     def xla(q, k, v):
+        if window:
+            return band_attention(q, k, v, window=window, scale=scale)
         if t_q <= _MAX_MATERIALIZED_T and t_k <= _MAX_MATERIALIZED_T:
             if causal and t_q == t_k and t_q % 256 == 0 and t_q >= 512:
                 return causal_skip_attention(q, k, v, scale=scale, block=256)
@@ -1320,14 +1359,14 @@ def attention(
         return blockwise_attention(
             q, k, v, causal=causal, scale=scale, block_k=block_k)
 
-    plan = flash_plan(q.shape, k.shape, v.shape, causal=causal,
+    plan = flash_plan(q.shape, k.shape, v.shape, causal=causal, window=window,
                       block_q=block_q, block_k=block_k)
     if plan is None:
         return xla(q, k, v)
     return lax.platform_dependent(
         q, k, v,
         tpu=lambda q, k, v: flash_attention_tpu(
-            q, k, v, causal, scale, *plan, False),
+            q, k, v, causal, scale, *plan, False, window),
         default=xla)
 
 

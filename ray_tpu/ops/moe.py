@@ -1,4 +1,23 @@
-"""Mixture-of-Experts FFN with expert parallelism over the ``ep`` axis.
+"""Mixture-of-Experts FFNs.  Three layers, by age:
+
+- :func:`moe_ffn` (below): the Switch layer, top-1 with a capacity that DROPS
+  (kept for the LayerNorm block's ``n_experts`` option and the pipeline's
+  ``pp x ep x dp`` dry run: GELU experts with biases, which the other two do
+  not have);
+- :func:`held_experts_ffn`: top-k gated experts as they are SERVED, the layer
+  told which experts this chip holds, no token dropped, a runtime number of
+  trips over the held pairs;
+- :func:`experts_ffn_train`: the same layer TRAINED: differentiable (no loop:
+  a runtime trip count has no transpose; every pair of the tokens given, so
+  every shape is static), ReGLU beside SwiGLU, and the experts spread over a
+  mesh axis with their exchange (the experts' matrices gathered to each
+  chip's own tokens, their gradients reduce-scattered home: a chip's work does
+  not follow the routing).
+
+The two dropless layers share the sort of the pairs (:func:`_sorted_pairs`)
+and the two grouped matmuls (:func:`_experts_block`).
+
+The Switch layer: Mixture-of-Experts FFN with expert parallelism over the ``ep`` axis.
 
 The reference has no MoE/expert-parallel code (SURVEY §2.5 row EP:
 "Absent"); this is the TPU-native build target — "expert-axis sharding +
@@ -23,6 +42,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.layers import dense
@@ -244,6 +264,44 @@ def gate_up_side_by_side(p: Dict[str, Any]) -> Dict[str, Any]:
     return p
 
 
+def _sorted_pairs(experts: jax.Array, first_expert, n_held: int,
+                  valid: Optional[jax.Array] = None):
+    """What both expert layers below do before any matmul: of the ``M = N *
+    k`` (token, expert) pairs of ``experts [N, k]``, which are HELD here
+    (experts ``first_expert .. first_expert + n_held``, of valid tokens),
+    ``order [M]``: the pairs sorted by held expert, pairs of absent experts
+    last, and ``bounds [n_held + 1]``: where each held expert's rows begin in
+    that list (``bounds[n_held]``: the held pairs)."""
+    M = experts.size
+    local = experts.reshape(M) - first_expert
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held = held & jnp.repeat(valid, experts.shape[1])
+    key = jnp.where(held, local, n_held)
+    order = jnp.argsort(key)                      # held pairs first, by expert
+    bounds = jnp.searchsorted(
+        key[order], jnp.arange(n_held + 1)).astype(jnp.int32)
+    return held, order, bounds
+
+
+def _experts_block(rows: jax.Array, w_gate_up: jax.Array, w_down: jax.Array,
+                   sizes: jax.Array, activation):
+    """``rows [R, D]`` sorted by expert, ``sizes`` of them each (rows past
+    their sum belong to no group, and what comes out for them is not meant to
+    be read) -> ``[R, D]`` float32: TWO grouped matmuls (``lax.ragged_dot``,
+    served and trained: the Pallas grouped matmul that ships with JAX gave the
+    training layer the same time to 3 % on the chip, PERF.md section 6, PR 57,
+    the permutations around the matmuls being what costs), gate and up as one
+    call whose result is split into its halves for ``activation(g) * u``
+    (``silu``: SwiGLU; ``relu``: ReGLU), then down.  The weights are used in
+    the rows' dtype."""
+    F = w_down.shape[-2]
+    gu = jax.lax.ragged_dot(rows, w_gate_up.astype(rows.dtype), sizes)
+    h = activation(gu[:, :F]) * gu[:, F:]
+    return jax.lax.ragged_dot(h, w_down.astype(rows.dtype), sizes,
+                              preferred_element_type=jnp.float32)
+
+
 def held_experts_ffn(
     x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate_up: jax.Array,
     w_down: jax.Array, *, first_expert: int = 0,
@@ -298,26 +356,14 @@ def held_experts_ffn(
         widen = lambda sizes: jax.lax.dynamic_update_slice(  # noqa: E731
             jnp.zeros((n_layers * n_held,), sizes.dtype), sizes, (layer * n_held,))
     n_held, top_k = w_down.shape[0] if layer is None else n_held, experts.shape[1]
-    F = w_down.shape[-2]
     M = N * top_k
-    local = experts.reshape(M) - first_expert
-    held = (local >= 0) & (local < n_held)
-    if valid is not None:
-        held = held & jnp.repeat(valid, top_k)
-    key = jnp.where(held, local, n_held)
-    order = jnp.argsort(key)                      # held pairs first, by expert
-    # where each held expert's rows begin in the sorted list
-    bounds = jnp.searchsorted(
-        key[order], jnp.arange(n_held + 1)).astype(jnp.int32)
+    held, order, bounds = _sorted_pairs(experts, first_expert, n_held, valid)
     tokens = bounds[1:] - bounds[:-1]
     gate_of = jnp.where(held, gates.reshape(M), 0.0)
 
     def experts_of(pairs, sizes):
-        rows, sizes = x[pairs // top_k], widen(sizes)
-        gu = jax.lax.ragged_dot(rows, w_gate_up.astype(x.dtype), sizes)
-        h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
-        return jax.lax.ragged_dot(h, w_down.astype(x.dtype), sizes,
-                                  preferred_element_type=jnp.float32)
+        return _experts_block(x[pairs // top_k], w_gate_up, w_down,
+                              widen(sizes), jax.nn.silu)
 
     if M <= _ONE_BLOCK_PAIRS:
         block, _ = dispatch_trips(M, 0)
@@ -351,3 +397,154 @@ def held_experts_ffn(
 
     return jax.lax.fori_loop(
         0, trips, trip, jnp.zeros((N, D), jnp.float32)), tokens
+
+
+# -- the same layer TRAINED: differentiable, its experts spread over the chips --
+#
+# ``held_experts_ffn`` walks its held pairs with ``lax.fori_loop(0, trips,
+# ...)`` under a TRACED trip count (what a skewed router costs is trips, never
+# tokens).  Reverse-mode differentiation cannot take that loop: it lowers to a
+# ``while`` whose transpose would have to keep one set of residuals a trip, for
+# a number of trips no shape says.  The training form has no loop and nothing
+# that follows the routing: it computes ALL the ``N * k`` pairs of the tokens
+# it is given against ALL the experts, so every shape is static (``N * k``
+# rows through the grouped matmuls however a router skews them: no capacity, no
+# dropped token at any imbalance) and plain reverse mode takes it.  Under a
+# mesh the chips divide the TOKENS and each chip is brought the experts it
+# does not hold (below): a chip's work is its own tokens' pairs, the same
+# rows whatever the routing.  The form that sent the tokens to the chip that
+# holds their experts (an all-gather of the tokens, each chip its own experts'
+# part in 65,536-row trips under a runtime count, a reduce-scatter of the
+# partial sums) was built first and measured on the four chips (PERF.md
+# section 6, PR 57): every chip waited for the one the routers loaded most
+# (1.41 - 1.78 x the mean over seeds), and the step's time followed the seed
+# by 2 - 5 %.
+#
+# What costs on the v5e is less the matmuls than the two permutations around
+# them (one chip, 98 k pairs of 2,560 values: gathering the rows 4.8 ms,
+# ADDING them back into a float32 result 15.2 ms where the grouped matmuls are
+# ~8), so both permutations and both their transposes are GATHERS here: a
+# token has exactly ``k`` pairs, all on this chip, so "add a token's pairs" is
+# a gather by the inverse permutation and a sum over ``k``.
+
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# what a remat policy may keep of a layer's experts (``save_only_these_names``):
+# the layer's result, a chip's own tokens.  The backward pass then gathers the
+# experts again and recomputes the gate-and-up matmul, not the down one
+EXPERTS_OUT = "experts_out"
+
+
+@jax.custom_vjp
+def _rows_of_pairs(x, order, rank):
+    """``x [N, D]`` -> ``[M, D]``: row ``i`` is the token of pair ``order[i]``
+    (pair ``p`` is token ``p // k``'s).  Transposed: a token's ``k`` rows
+    gathered by ``rank`` (``order``'s inverse) and summed, no scatter."""
+    return x[order // (order.size // x.shape[0])]
+
+
+def _rows_of_pairs_bwd(kept, ct):
+    rank, n = kept
+    with jax.named_scope("moe.expert_ffn"):  # (a rule of its own has no scope)
+        dx = ct[rank].reshape(n, rank.size // n, -1)
+        return dx.astype(jnp.float32).sum(1).astype(ct.dtype), None, None
+
+
+_rows_of_pairs.defvjp(
+    lambda x, order, rank: (_rows_of_pairs(x, order, rank), (rank, x.shape[0])),
+    _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _pairs_of_rows(y, order, rank):
+    """``y [M, D]`` sorted by expert -> ``[M, D]`` in the pairs' own order
+    (token-major).  Transposed: the gather by ``order``."""
+    return y[rank]
+
+
+def _pairs_of_rows_bwd(order, ct):
+    with jax.named_scope("moe.expert_ffn"):
+        return ct[order], None, None
+
+
+_pairs_of_rows.defvjp(lambda y, order, rank: (y[rank], order), _pairs_of_rows_bwd)
+
+
+def _experts_train(x, gates, w_gate_up, w_down, experts, activation: str):
+    """``x [N, D]``, ``experts``, ``gates [N, k]`` over ALL the experts of
+    ``w_gate_up [E, D, 2F]`` / ``w_down [E, F, D]`` -> ``[N, D]`` float32: the
+    ``N * k`` pairs sorted by expert, two grouped matmuls over all of them
+    (:func:`_experts_block`), a token's ``k`` rows gathered back and summed
+    under its gates."""
+    (N, D), top_k = x.shape, experts.shape[1]
+    M = N * top_k
+    _, order, bounds = _sorted_pairs(experts, 0, w_down.shape[0])
+    rank = jnp.argsort(order)
+    rows = _rows_of_pairs(x, order, rank)
+    # the TPU's grouped-matmul kernel takes whole sublane tiles of rows (a
+    # list of another length is computed densely); rows past the groups
+    # belong to no group and are not read
+    rows = jnp.pad(rows, ((0, -M % 8), (0, 0)))
+    y = _experts_block(rows, w_gate_up.astype(x.dtype), w_down.astype(x.dtype),
+                       bounds[1:] - bounds[:-1], ACTIVATIONS[activation])[:M]
+    y = _pairs_of_rows(y, order, rank).reshape(N, top_k, D)
+    return (y * gates[..., None]).sum(1)
+
+
+def experts_ffn_train(
+    x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate_up: jax.Array,
+    w_down: jax.Array, *, activation: str = "silu",
+    mesh: Optional[Mesh] = None, axis: Optional[str] = None,
+) -> jax.Array:
+    """A top-k gated expert layer that a train step differentiates, no token
+    dropped, its experts spread over the chips of mesh axis ``axis``.
+
+    ``x [N, D]`` tokens, ``experts``, ``gates [N, k]`` (numbered over ALL
+    experts, a router's: :func:`route_softmax_top_k`), ``w_gate_up [E, D, 2F]``
+    (gate's columns then up's), ``w_down [E, F, D]``, ``activation``: ``silu``
+    (SwiGLU) or ``relu`` (ReGLU).  Returns ``y [N, D]`` in ``x``'s dtype: the
+    gate-weighted sum of each token's ``k`` experts.  Gradients reach ``x``,
+    ``gates`` and both weights by plain reverse mode (module comment above).
+
+    With a mesh, tokens AND experts are divided over ``axis`` (``N`` and ``E``
+    both in ``mesh.shape[axis]`` contiguous blocks: a chip HOLDS its block of
+    the experts, their masters and their optimizer state).  The exchange
+    brings the EXPERTS to the tokens: an all-gather of the experts' matrices
+    in ``x``'s dtype before a chip computes its own tokens' pairs, and, in the
+    backward pass, the same gather again and a float32 reduce-scatter of the
+    matrices' gradients to the chips that hold them (never a sum of bf16
+    partials: :func:`ray_tpu.parallel.sharding.gather_for_compute`'s rule).
+    Both are static shapes that no routing changes.  At 16,384 tokens a chip
+    and 64 experts of 2,560 x 768 on the v5e's 2x2 they take 32 ms a layer
+    (the gather's 566 MB a chip twice, 1.13 GB of float32 gradients out), none
+    of it under other work yet though neither waits for a value of the layer
+    (PERF.md section 6, PR 57).  Scopes: ``moe.exchange`` the collectives,
+    ``moe.expert_ffn`` the rest."""
+    if mesh is None:
+        with jax.named_scope("moe.expert_ffn"):
+            return checkpoint_name(_experts_train(
+                x, gates, w_gate_up, w_down, experts, activation
+            ).astype(x.dtype), EXPERTS_OUT)
+
+    @jax.custom_vjp
+    def brought(w):
+        return jax.lax.all_gather(w.astype(x.dtype), axis, tiled=True)
+
+    def sent_home(w, ct):
+        with jax.named_scope("moe.exchange"):
+            return (jax.lax.psum_scatter(
+                ct.astype(jnp.float32), axis, scatter_dimension=0, tiled=True
+            ).astype(w.dtype),)
+
+    # (the residual is the held block itself: only its dtype is read)
+    brought.defvjp(lambda w: (brought(w), w), sent_home)
+
+    def on_chip(x, experts, gates, w_gate_up, w_down):
+        with jax.named_scope("moe.exchange"):
+            w_gate_up, w_down = brought(w_gate_up), brought(w_down)
+        with jax.named_scope("moe.expert_ffn"):
+            return _experts_train(
+                x, gates, w_gate_up, w_down, experts, activation).astype(x.dtype)
+
+    return checkpoint_name(jax.shard_map(
+        on_chip, mesh=mesh, in_specs=(P(axis),) * 5, out_specs=P(axis),
+        check_vma=False)(x, experts, gates, w_gate_up, w_down), EXPERTS_OUT)

@@ -44,9 +44,14 @@ def topo():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    _FOUR_CHIPS[:] = topo.devices
     yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+# the described 2x2's devices, for a lowering over all four (set by ``topo``)
+_FOUR_CHIPS: list = []
 
 
 @pytest.fixture(scope="module")
@@ -456,6 +461,43 @@ def _lower_flash(chip, direction):
     return [jax.jit(attend).lower(qkv, qkv, qkv)]
 
 
+def _lower_sparse_train_parts(chip):
+    """What the four-chip sparse train cell (SmallThinker-21B-A3B, 8 x 8,192
+    tokens a step) gave the compiler that no program had: the dropless expert
+    layer differentiated and spread over the 2x2 (64 ReGLU experts of 2,560 x
+    768 top-6, 16 held a chip, all of them gathered to a chip's own 16,384
+    tokens, whose 98,304 pairs go through ``lax.ragged_dot`` and its
+    transposes with no loop; the matrices' gradients reduce-scattered home),
+    and ``attention()`` forward and backward under a 4,096 band at a chip's
+    share ``[2, 28, 8192, 128]``.  (The WHOLE step's compile, ~60 s here,
+    sized the cell's batch in the builder's scratch run and is too long for
+    this file: PERF.md section 6, PR 57.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops import attention, moe
+
+    mesh = Mesh(np.array(_FOUR_CHIPS).reshape(4), ("fsdp",))
+    over = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=NamedSharding(mesh, P("fsdp")))
+    n, d, f, e, k = 65536, 2560, 768, 64, 6
+
+    def experts(x, gates, w_gate_up, w_down, chosen):
+        return moe.experts_ffn_train(
+            x, chosen, gates, w_gate_up, w_down, activation="relu", mesh=mesh,
+            axis="fsdp").astype(jnp.float32).sum()
+
+    qkv = _on(chip, jax.ShapeDtypeStruct((2, 28, 8192, 128), jnp.bfloat16))
+    return [
+        jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3))).lower(
+            over((n, d), jnp.bfloat16), over((n, k), jnp.float32),
+            over((e, d, 2 * f), jnp.float32), over((e, f, d), jnp.float32),
+            over((n, k), jnp.int32)),
+        jax.jit(jax.grad(
+            lambda q, k, v: attention(q, k, v, causal=True, window=4096)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(qkv, qkv, qkv)]
+
+
 def _lower_attention_dispatch(chip):
     """``attention()`` itself, forward and backward, at the two train cells'
     shapes (``[8, 16, 1024, 64]``; a chip's share of fsdp4 ``[4, 25, 1024,
@@ -498,6 +540,7 @@ PROGRAMS = {
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
+    "sparse_train_experts_and_band": _lower_sparse_train_parts,
 }
 
 
@@ -663,6 +706,23 @@ def test_program_compiles_for_v5e(compiled, name):
     if name == "attention_dispatch_train_and_prefill":
         for program in programs:
             assert program.as_text().count("tpu_custom_call") == 2
+    if name == "sparse_train_experts_and_band":
+        experts, band = (p.as_text() for p in programs)
+        # the exchange (the experts brought whole, their gradients sent home
+        # in float32), the grouped matmuls (``lax.ragged_dot`` and its
+        # transposes in both operands) over a chip's own 98,304 pairs, and
+        # nothing that follows the routing: no loop, and no scatter but the
+        # sort's own
+        for mark in ("bf16[64,768,2560]{2,1,0:T(8,128)(2,1)} all-gather",
+                     "ragged-dot", "f32[16,768,2560]{2,1,0:T(8,128)} reduce-scatter"):
+            assert mark in experts, mark
+        assert not re.search(r"= \S+ while\(", experts)
+        assert "f32[98304,2560]" in experts and "scatter-add" not in experts
+        assert needs[0] < 6 * 2**30, needs
+        # both kernels of the pair under the band, no masked scores in HBM
+        assert band.count("tpu_custom_call") == 2
+        # (q, k, v, their gradients and the result are 0.8 GB)
+        assert "flash_attention_bwd" in band and needs[1] < 2 * 2**30, needs
     if name in ("serve_engine_exaone_cell", "serve_engine_kimi_cell",
                 "serve_engine_dots3_cell", "serve_engine_evabyte_cell",
                 "serve_engine_phi4_flash_cell"):
