@@ -36,7 +36,8 @@ Under a mesh (:func:`make_train_step`), ONE axis carries the batch, the
 non-expert parameters (``embed -> fsdp``: gathered a layer at a time,
 :func:`ray_tpu.parallel.sharding.gather_for_compute`) and the experts
 (``expert -> fsdp``: a chip holds 1 / fsdp of a layer's experts and is
-brought the others for its own tokens, a layer at a time,
+brought the others for its own tokens, a layer at a time; their gradients go
+home a chip's block at a time under the layer's backward matmuls,
 :func:`ray_tpu.ops.moe.experts_ffn_train`).
 """
 

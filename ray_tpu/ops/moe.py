@@ -11,8 +11,8 @@
   a runtime trip count has no transpose; every pair of the tokens given, so
   every shape is static), ReGLU beside SwiGLU, and the experts spread over a
   mesh axis with their exchange (the experts' matrices gathered to each
-  chip's own tokens, their gradients reduce-scattered home: a chip's work does
-  not follow the routing).
+  chip's own tokens, their gradients sent home a chip's block at a time under
+  the backward pass's own matmuls: a chip's work does not follow the routing).
 
 The two dropless layers share the sort of the pairs (:func:`_sorted_pairs`)
 and the two grouped matmuls (:func:`_experts_block`).
@@ -507,39 +507,63 @@ def experts_ffn_train(
 
     With a mesh, tokens AND experts are divided over ``axis`` (``N`` and ``E``
     both in ``mesh.shape[axis]`` contiguous blocks: a chip HOLDS its block of
-    the experts, their masters and their optimizer state).  The exchange
-    brings the EXPERTS to the tokens: an all-gather of the experts' matrices
-    in ``x``'s dtype before a chip computes its own tokens' pairs, and, in the
-    backward pass, the same gather again and a float32 reduce-scatter of the
-    matrices' gradients to the chips that hold them (never a sum of bf16
-    partials: :func:`ray_tpu.parallel.sharding.gather_for_compute`'s rule).
-    Both are static shapes that no routing changes.  At 16,384 tokens a chip
-    and 64 experts of 2,560 x 768 on the v5e's 2x2 they take 32 ms a layer
-    (the gather's 566 MB a chip twice, 1.13 GB of float32 gradients out), none
-    of it under other work yet though neither waits for a value of the layer
-    (PERF.md section 6, PR 57).  Scopes: ``moe.exchange`` the collectives,
-    ``moe.expert_ffn`` the rest."""
-    if mesh is None:
+    the experts, their masters and their optimizer state; an axis of one chip
+    is the plain path).  The exchange brings the EXPERTS to the tokens: an
+    all-gather of the experts' matrices in ``x``'s dtype before a chip
+    computes its own tokens' pairs and, in the backward pass, the same gather
+    again and the matrices' gradients home in float32: each chip sends every
+    other chip that chip's block of its partial gradient, ``n - 1``
+    independent shifts (``ppermute``) that the receiver adds to its own in a
+    fixed order (never a sum of bf16 partials:
+    :func:`ray_tpu.parallel.sharding.gather_for_compute`'s rule; the same
+    bytes as a reduce-scatter moves).  Both are static shapes that no routing
+    changes.  A shift is a start and a done with work between, where the
+    reduce-scatter it replaced was ONE instruction that held the chip (12 ms
+    a layer for the gate-and-up gradients on the v5e's 2x2): the gradients
+    fly under the backward pass's grouped matmuls.  The exchange is tied to
+    the layer (``optimization_barrier`` with the layer's tokens, and so with
+    their cotangent): a transfer still in flight makes any other collective
+    issued meanwhile wait for it, so one left to fly under the attention
+    costs the FSDP parameters' gathers what it saves here.  What still
+    stands on the line is the gather, 5 ms a layer forward and again in the
+    backward pass (PERF.md section 6, PR 58).  Scopes: ``moe.exchange`` the
+    collectives, the casts and the sum; ``moe.expert_ffn`` the rest."""
+    if mesh is None or mesh.shape[axis] == 1:
         with jax.named_scope("moe.expert_ffn"):
             return checkpoint_name(_experts_train(
                 x, gates, w_gate_up, w_down, experts, activation
             ).astype(x.dtype), EXPERTS_OUT)
+
+    n = mesh.shape[axis]
 
     @jax.custom_vjp
     def brought(w):
         return jax.lax.all_gather(w.astype(x.dtype), axis, tiled=True)
 
     def sent_home(w, ct):
+        """A chip's partial gradient of ALL the experts -> the sum over the
+        chips of the block it holds, float32."""
         with jax.named_scope("moe.exchange"):
-            return (jax.lax.psum_scatter(
-                ct.astype(jnp.float32), axis, scatter_dimension=0, tiled=True
-            ).astype(w.dtype),)
+            me = jax.lax.axis_index(axis)
+            blocks = ct.reshape((n, ct.shape[0] // n) + ct.shape[1:])
+            block_of = lambda chip: jax.lax.dynamic_index_in_dim(  # noqa: E731
+                blocks, chip % n, keepdims=False).astype(jnp.float32)
+            home = block_of(me)
+            for by in range(1, n):  # a fixed order of summation
+                home = home + jax.lax.ppermute(
+                    block_of(me + by), axis, [(c, (c + by) % n) for c in range(n)])
+            return (home.astype(w.dtype),)
 
     # (the residual is the held block itself: only its dtype is read)
     brought.defvjp(lambda w: (brought(w), w), sent_home)
 
     def on_chip(x, experts, gates, w_gate_up, w_down):
         with jax.named_scope("moe.exchange"):
+            # the exchange stays INSIDE the layer: no gather before the
+            # tokens are here; transposed, no cotangent out before the
+            # gradients are home (docstring)
+            x, w_gate_up, w_down = jax.lax.optimization_barrier(
+                (x, w_gate_up, w_down))
             w_gate_up, w_down = brought(w_gate_up), brought(w_down)
         with jax.named_scope("moe.expert_ffn"):
             return _experts_train(

@@ -244,7 +244,10 @@ _HLO_CALLEES = re.compile(
     r"\b(calls|to_apply|body|condition|branch_computations)="
     r"(?:\{([^}]*)\}|(%?[\w.\-]+))")
 _HLO_COLLECTIVE = re.compile(
-    r"^(.*?)\s(%s)(?:-start)?\((.*?)\)(?:,|$)(.*)$" % "|".join(_COLLECTIVES))
+    r"^(.*?)\s(%s)(-start)?\((.*?)\)(?:,|$)(.*)$" % "|".join(_COLLECTIVES))
+# the TPU compiler's own asynchronous form: a pair of fusions by these names,
+# the collective cloned into the computations they call
+_HLO_ASYNC_FUSION = re.compile(r"^async-collective-(?:start|done)((?:\.\d+)?)$")
 _HLO_SHAPE = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
 
 
@@ -352,38 +355,82 @@ def collective_profile(compiled: Any) -> Dict[str, Dict[str, Dict[str, Any]]]:
     ``all-gather``, ``reduce-scatter``, ``all-to-all``,
     ``collective-permute``) and per place (``"in_loop"``: in a ``while``
     body or anything it calls, i.e. the scanned layers; ``"outside"``),
-    ``{"count", "max_operand_bytes", "shapes"}``.  ``shapes`` lists every
-    distinct per-device array the collectives of that kind take or give,
-    as ``"f32[4,256,768]"``.  The FSDP mechanism above is decided at
-    compile time, so this is the counter that says it engaged.
+    ``{"count", "max_operand_bytes", "shapes", "synchronous",
+    "start_to_done"}``.  ``shapes`` lists every distinct per-device array
+    the collectives of that kind take or give, as ``"f32[4,256,768]"``.
+    ``synchronous`` counts those that stand in a computation's schedule as
+    ONE instruction (``reduce-scatter(...)``, whatever the instruction is
+    called: ``jax.lax.psum_scatter`` names it ``%reduce_scatter.28``): nothing
+    else runs on the chip meanwhile.  ``start_to_done`` lists, for each that is
+    a start/done pair (``collective-permute-start`` / ``-done``, or the TPU
+    compiler's ``async-collective-start`` / ``-done`` fusions), how many
+    instructions are scheduled between the two (the text of a compiled
+    program is its schedule); the rest of ``count`` are fused into the
+    operation that produces their operand (``all-reduce-scatter``).  The
+    FSDP mechanism above and the experts' exchange
+    (:func:`ray_tpu.ops.moe.experts_ffn_train`) are decided at compile time,
+    so this is the counter that says they engaged, and how.
     """
     comps, loops, rows = _hlo_instructions(compiled)
     in_loop = {n for n, bodies in loops.items() if bodies}
-    found = []  # (computation, kind, result shape, operand names, attributes)
+    position: Dict[str, Dict[str, int]] = {}  # computation -> instruction -> line
+    fused_into = {}  # a fusion's computation -> (where the fusion stands, its name)
+    done_of = {}     # (computation, a start) -> the instruction that waits for it
     for comp, instr, rest in rows:
-        c = _HLO_COLLECTIVE.match(rest)
-        if c:
-            found.append((comp, c.group(2), c.group(1),
-                          re.findall(r"%([\w.\-]+)", c.group(3)),
-                          instr + c.group(4)))
+        at = position.setdefault(comp, {})
+        at[instr] = len(at)
+        op = _HLO_OPERATION.match(rest)
+        if op and op.group(2) == "fusion":
+            for callee in re.findall(r"\bcalls=%?([\w.\-]+)", rest):
+                fused_into[callee] = (comp, instr)
+        elif op and op.group(2).endswith("-done"):
+            for operand in re.findall(r"%([\w.\-]+)", op.group(3).split(")")[0]):
+                done_of[comp, operand] = instr
 
-    profile = {kind: {place: {"count": 0, "max_operand_bytes": 0, "shapes": []}
+    def between(comp, start, done):
+        at = position[comp]
+        return at[done] - at[start] - 1 if start in at and done in at else None
+
+    profile = {kind: {place: {"count": 0, "max_operand_bytes": 0, "shapes": [],
+                              "synchronous": 0, "start_to_done": []}
                       for place in ("in_loop", "outside")}
                for kind in _COLLECTIVES}
     seen = set()
-    for comp, kind, result, operands, attrs in found:
+    for comp, instr, rest in rows:
+        c = _HLO_COLLECTIVE.match(rest)
+        if not c:
+            continue
+        result, kind, start, operands, attrs = c.groups()
+        operands, attrs = re.findall(r"%([\w.\-]+)", operands), instr + attrs
         # the TPU compiler writes a reduce-scatter as a fusion of an
         # all-reduce and a slice, and clones an asynchronous collective
-        # into each fusion that continues it (same channel)
+        # into each fusion that continues it (same channel; the collectives
+        # a ``shard_map`` body writes by hand all share channel 1, and stand
+        # in no fusion)
         if kind == "all-reduce" and comp.startswith("all-reduce-scatter"):
             kind = "reduce-scatter"
         channel = re.search(r"channel_id=(\d+)", attrs)
-        key = (kind, channel.group(1) if channel else (comp, attrs))
+        key = (kind, channel.group(1) if channel and comp in fused_into
+               else (comp, attrs))
         if key in seen:
             continue
         seen.add(key)
+        # None: one instruction; False: inside another operation's fusion;
+        # else the instructions between its start and its done
+        pair = None
+        if start:
+            pair = between(comp, instr, done_of.get((comp, instr)))
+        elif comp in fused_into:
+            caller, fusion = fused_into[comp]
+            named = _HLO_ASYNC_FUSION.match(fusion)
+            pair = between(caller, "async-collective-start" + named.group(1),
+                           "async-collective-done" + named.group(1)) if named else False
         entry = profile[kind]["in_loop" if comp in in_loop else "outside"]
         entry["count"] += 1
+        if pair is None:
+            entry["synchronous"] += 1
+        elif pair is not False:
+            entry["start_to_done"].append(pair)
         taken = [s for o in operands
                  for s in _HLO_SHAPE.findall(comps[comp].get(o, ""))]
         for dt, dims in taken + _HLO_SHAPE.findall(result):

@@ -467,8 +467,8 @@ def _lower_sparse_train_parts(chip):
     layer differentiated and spread over the 2x2 (64 ReGLU experts of 2,560 x
     768 top-6, 16 held a chip, all of them gathered to a chip's own 16,384
     tokens, whose 98,304 pairs go through ``lax.ragged_dot`` and its
-    transposes with no loop; the matrices' gradients reduce-scattered home),
-    and ``attention()`` forward and backward under a 4,096 band at a chip's
+    transposes with no loop; the matrices' gradients sent home a block at a
+    time), and ``attention()`` forward and backward under a 4,096 band at a chip's
     share ``[2, 28, 8192, 128]``.  (The WHOLE step's compile, ~60 s here,
     sized the cell's batch in the builder's scratch run and is too long for
     this file: PERF.md section 6, PR 57.)"""
@@ -708,17 +708,36 @@ def test_program_compiles_for_v5e(compiled, name):
             assert program.as_text().count("tpu_custom_call") == 2
     if name == "sparse_train_experts_and_band":
         experts, band = (p.as_text() for p in programs)
-        # the exchange (the experts brought whole, their gradients sent home
-        # in float32), the grouped matmuls (``lax.ragged_dot`` and its
-        # transposes in both operands) over a chip's own 98,304 pairs, and
-        # nothing that follows the routing: no loop, and no scatter but the
-        # sort's own
-        for mark in ("bf16[64,768,2560]{2,1,0:T(8,128)(2,1)} all-gather",
-                     "ragged-dot", "f32[16,768,2560]{2,1,0:T(8,128)} reduce-scatter"):
-            assert mark in experts, mark
+        # the exchange: the experts gathered whole in bf16 (the compiler's
+        # own all-gather), the gradients' blocks sent home in float32 by
+        # three shifts a matrix, every one a start/done pair with the
+        # backward's grouped matmuls scheduled between: under ``moe.exchange``
+        # no sum across the chips is ONE instruction that holds the chip
+        # (PR 58: the four reduce-scatters a layer were, 12 ms each)
+        from ray_tpu.parallel.sharding import collective_profile
+
+        moved = collective_profile(experts)
+        shifted = moved["collective-permute"]["outside"]
+        assert shifted["count"] == 2 * 3 and shifted["synchronous"] == 0
+        assert len(shifted["start_to_done"]) == 6 and min(shifted["start_to_done"]) > 0
+        assert {"f32[16,768,2560]", "f32[16,2560,1536]"} <= set(shifted["shapes"])
+        assert shifted["max_operand_bytes"] == 16 * 2560 * 1536 * 4
+        assert {"bf16[64,768,2560]", "bf16[64,2560,1536]"} <= set(
+            moved["all-gather"]["outside"]["shapes"])
+        alone = re.compile(r" (reduce-scatter|all-reduce|all-to-all"
+                           r"|collective-permute)\(")
+        assert not [line[:200] for line in experts.splitlines()
+                    if "moe.exchange" in line and alone.search(line)]
+        # the grouped matmuls (``lax.ragged_dot`` and its transposes in both
+        # operands) over a chip's own 98,304 pairs, and nothing that follows
+        # the routing: no loop, and no scatter but the sort's own
+        assert "ragged-dot" in experts and "moe.expert_ffn" in experts
         assert not re.search(r"= \S+ while\(", experts)
         assert "f32[98304,2560]" in experts and "scatter-add" not in experts
-        assert needs[0] < 6 * 2**30, needs
+        # (4.2 GiB while one reduce-scatter took the gradients home: a
+        # matrix's float32 blocks are in flight, three sent and three
+        # received, while the backward's matmuls still hold their operands)
+        assert needs[0] < 7 * 2**30, needs
         # both kernels of the pair under the band, no masked scores in HBM
         assert band.count("tpu_custom_call") == 2
         # (q, k, v, their gradients and the result are 0.8 GB)

@@ -124,6 +124,76 @@ def test_grad_sync_pmean():
     np.testing.assert_allclose(out["b"], np.ones(8))
 
 
+# a compiled program's text is its schedule: three forms of a collective as the
+# TPU compiler writes them, and the one it fuses into the producer of its operand
+_SCHEDULED = """
+HloModule jit_step, is_scheduled=true
+
+%add (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %sum = f32[] add(%a, %b)
+}
+
+%fused_computation.7 (p0: bf16[2,8]) -> (bf16[2,8], bf16[8,8]) {
+  %p0 = bf16[2,8]{1,0} parameter(0)
+  ROOT %all-gather.3 = bf16[8,8]{1,0} all-gather(%p0), channel_id=9, replica_groups=[1,4]<=[4], dimensions={0}, use_global_device_ids=true
+}
+
+%fused_computation.8 (p1: bf16[2,8]) -> bf16[8,8] {
+  %p1 = bf16[2,8]{1,0} parameter(0)
+  ROOT %all-gather.4 = bf16[8,8]{1,0} all-gather(%p1), channel_id=9, replica_groups=[1,4]<=[4], dimensions={0}, use_global_device_ids=true
+}
+
+%all-reduce-scatter.2 (p2: f32[8,8]) -> f32[2,8] {
+  %p2 = f32[8,8]{1,0} parameter(0)
+  %all-reduce.5 = f32[8,8]{1,0} all-reduce(%p2), channel_id=11, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add
+  ROOT %slice.1 = f32[2,8]{1,0} slice(%all-reduce.5), slice={[0:2], [0:8]}
+}
+
+ENTRY %main (w: f32[2,8], g: f32[8,8]) -> f32[2,8] {
+  %w = f32[2,8]{1,0} parameter(0)
+  %g = f32[8,8]{1,0} parameter(1)
+  %low = bf16[2,8]{1,0} convert(%w)
+  %async-collective-start.1 = (bf16[2,8]{1,0}, bf16[8,8]{1,0}) fusion(%low), kind=kCustom, calls=%fused_computation.7
+  %collective-permute-start.2 = (f32[2,8]{1,0}, f32[2,8]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%w), channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, metadata={op_name="jit(step)/moe.exchange/ppermute"}
+  %square = f32[8,8]{1,0} multiply(%g, %g)
+  %root = f32[8,8]{1,0} sqrt(%square)
+  %async-collective-done.1 = bf16[8,8]{1,0} fusion(%async-collective-start.1), kind=kCustom, calls=%fused_computation.8
+  %half = f32[8,8]{1,0} multiply(%root, %root)
+  %collective-permute-done.2 = f32[2,8]{1,0} collective-permute-done(%collective-permute-start.2), metadata={op_name="jit(step)/moe.exchange/ppermute"}
+  %reduce_scatter.28 = f32[2,8]{1,0} reduce-scatter(%half), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={0}, to_apply=%add, metadata={op_name="jit(step)/moe.exchange/reduce_scatter"}
+  %reduce_scatter.29 = f32[2,8]{1,0} reduce-scatter(%g), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={0}, to_apply=%add
+  %fusion.4 = f32[2,8]{1,0} fusion(%g), kind=kCustom, calls=%all-reduce-scatter.2
+  ROOT %out = f32[2,8]{1,0} add(%reduce_scatter.28, %collective-permute-done.2)
+}
+"""
+
+
+def test_collective_profile_tells_a_pair_from_one_instruction():
+    """Which collectives stand alone in the schedule and how far a start is
+    from its done, in each spelling: ``-start`` / ``-done`` instructions, the
+    TPU's ``async-collective-*`` pair of fusions (the collective cloned into
+    both, counted once), the ``reduce-scatter`` that ``jax.lax.psum_scatter``
+    names ``%reduce_scatter.N`` (two by hand on one channel are two), and the
+    one fused into its producer, which is neither."""
+    from ray_tpu.parallel.sharding import collective_profile
+
+    profile = collective_profile(_SCHEDULED)
+    assert not any(places["in_loop"]["count"] for places in profile.values())
+    outside = {kind: places["outside"] for kind, places in profile.items()}
+    counted = {kind: (e["count"], e["synchronous"], e["start_to_done"])
+               for kind, e in outside.items() if e["count"]}
+    assert counted == {
+        "all-gather": (1, 0, [3]),
+        "collective-permute": (1, 0, [4]),
+        "reduce-scatter": (3, 2, []),
+    }, counted
+    assert outside["reduce-scatter"]["max_operand_bytes"] == 8 * 8 * 4
+    assert "f32[2,8]" in outside["collective-permute"]["shapes"]
+    assert outside["all-gather"]["shapes"] == ["bf16[2,8]", "bf16[8,8]"]
+
+
 @pytest.mark.parametrize(
     "sizes,own_rules",
     [({"fsdp": 4}, None), ({"fsdp": 2, "tp": 2}, None), ({"dp": 2, "fsdp": 2}, None),

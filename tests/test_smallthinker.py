@@ -21,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import smallthinker_ref as ref  # noqa: E402
 from ray_tpu.models import smallthinker as st  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
+from ray_tpu.parallel.sharding import collective_profile  # noqa: E402
 
 B, T = 2, 32
 
@@ -110,10 +111,19 @@ def test_the_four_way_step_is_the_one_device_step(mesh):
     text = jax.jit(jax.grad(lambda p, b: st.loss_fn(p, b, cfg, mesh)[0])).lower(
         jax.device_put(params, st.param_shardings(mesh, None, cfg)),
         batch).compile().as_text()
-    # the experts are brought whole to a chip's tokens, their gradients home
+    # the experts are brought whole to a chip's tokens (one gather a matrix);
+    # their gradients go home a chip's block at a time, in float32, by three
+    # shifts a matrix, and no sum across chips takes a layer's experts whole
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_expert
-    assert f"f32[{e},{f},{d}]{{2,1,0}} all-gather" in text
-    assert f"f32[{e // 4},{f},{d}]{{2,1,0}} reduce-scatter" in text
+    profile = collective_profile(text)
+    whole = {f"f32[{e},{f},{d}]", f"f32[{e},{d},{2 * f}]"}
+    blocks = {f"f32[{e // 4},{f},{d}]", f"f32[{e // 4},{d},{2 * f}]"}
+    assert whole <= set(profile["all-gather"]["outside"]["shapes"])
+    shifted = profile["collective-permute"]["outside"]
+    assert blocks <= set(shifted["shapes"]) and not whole & set(shifted["shapes"])
+    assert shifted["count"] == cfg.n_layers * 2 * 3  # layers x matrices x shifts
+    assert not any(whole & set(profile[kind]["outside"]["shapes"])
+                   for kind in ("reduce-scatter", "all-reduce", "all-to-all"))
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(mesh):
@@ -137,6 +147,37 @@ def test_the_four_shares_add_up_to_the_uncut_layer(mesh):
         h, experts, gates, p["ew_gate_up"], p["ew_down"], activation="relu",
         mesh=mesh, axis="fsdp"))()
     assert rel(spread, whole) < 1e-5
+
+
+@pytest.mark.parametrize("chips,abroad", [(4, False), (2, False), (1, False), (4, True)],
+                         ids=["four", "two", "one", "four-a-chip-routes-abroad"])
+def test_the_spread_layer_differentiates_as_the_one_device_layer(chips, abroad):
+    """The layer and its gradients in ``x``, the gates and both matrices, the
+    experts over four chips (three shifts), two (one) and one (no region),
+    against the layer on one device; ``abroad``: every token of chip 0
+    chooses experts that other chips hold."""
+    cfg = st.SmallThinkerConfig.tiny()
+    p = st.init(cfg, jax.random.PRNGKey(9))["layers"][1]
+    keys = jax.random.split(jax.random.PRNGKey(10), 2)
+    h, weight = (jax.random.normal(k, (48, cfg.d_model)) for k in keys)
+    experts, gates = moe.route_softmax_top_k(h, p["router"], 2)
+    if abroad:  # chip 0 has tokens 0-11 and experts 0, 1
+        far = 2 + 2 * (jnp.arange(12) % 3)
+        experts = experts.at[:12].set(jnp.stack([far, far + 1], 1))
+    mesh = Mesh(np.array(jax.devices()[:chips]), ("fsdp",))
+    assert cfg.n_experts % chips == 0
+
+    def layer(mesh):
+        return jax.jit(jax.value_and_grad(lambda x, g, w_gu, w_d: (
+            moe.experts_ffn_train(
+                x, experts, g, w_gu, w_d, activation="relu", mesh=mesh,
+                axis=mesh and "fsdp") * weight).sum(), (0, 1, 2, 3)))(
+            h, gates, p["ew_gate_up"], p["ew_down"])
+
+    (wanted, wanted_grads), (got, got_grads) = layer(None), layer(mesh)
+    assert float(got) == pytest.approx(float(wanted), rel=1e-5)
+    assert all(float(jnp.abs(g).max()) > 0 for g in wanted_grads)
+    assert max(rel(a, b) for a, b in zip(got_grads, wanted_grads)) < 1e-5
 
 
 def test_one_expert_takes_every_token_and_none_is_lost(mesh):
