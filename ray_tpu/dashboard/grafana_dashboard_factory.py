@@ -45,6 +45,11 @@ CORE_METRICS: Dict[str, tuple] = {
     "ray_tpu_hbm_bytes_in_use": ("gauge", "device memory in use"),
     "ray_tpu_llm_ttft_s": ("histogram", "LLM time-to-first-token"),
     "ray_tpu_llm_itl_s": ("histogram", "LLM inter-token latency"),
+    # a request that is not token ids alone (serve/llm.py: the vision tower)
+    "ray_tpu_llm_vision_frames_total": ("counter", "video frames the tower encoded"),
+    "ray_tpu_llm_vision_patches_total": ("counter", "patches the tower encoded"),
+    "ray_tpu_llm_requests_refused_total":
+        ("counter", "LLM requests refused at submission, by reason"),
     # continuous-profiling plane (PR 17: sampling_profiler + locks)
     "ray_tpu_profiler_duty_frac": ("gauge", "profiler duty cycle fraction"),
     "ray_tpu_gil_lateness_frac": ("gauge", "GIL pressure (tick lateness)"),

@@ -1,5 +1,6 @@
 """KV-cache autoregressive generation for the decoder LMs (GPT-2, Llama,
-EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note, EvaByte, Phi-4-flash).
+EXAONE-MoE, Kimi-K2, Granite-hybrid, dots3-note, EvaByte, Phi-4-flash,
+Keye-VL).
 
 The reference snapshot has no inference engine at all — serving wraps a
 plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
@@ -229,6 +230,7 @@ from ray_tpu.models import (
     exaone_moe,
     gpt2,
     granite_hybrid,
+    keye_vl,
     kimi_k2,
     llama,
     phi4_flash,
@@ -283,11 +285,18 @@ from ray_tpu.ops.attention import (
 # ``lower_stack`` (the layers below the slab, rolled, threading the caller's
 # carry through middles ``mamba(at, x, p, carry)`` and ``attend(at, q, k, v,
 # carry)``), ``shared_kv``, ``shared_layer`` and ``upper_stack`` (the layers
-# above, handed the slab's read), ``mamba_whole`` and ``mamba_step``.
+# above, handed the slab's read), ``mamba_whole`` and ``mamba_step``.  A family
+# whose requests are not token ids alone (a vision tower in front of the text
+# path: :mod:`ray_tpu.models.keye_vl`) says that a slot's rotary position is
+# its cached length plus an offset of its own (``rope_delta_cache``), its
+# ``embed`` takes ``visual`` (the tower's rows and where they stand) and its
+# ``block`` positions ``[B, 3, T]``; where such a family SELECTS over a slab of
+# K and V per head (``index_cache`` without ``latent_cache``) its block hands
+# the middle ``None`` for the row and the index as a fifth argument.
 FAMILIES = {"gpt2": gpt2, "llama": llama, "exaone_moe": exaone_moe,
             "kimi_k2": kimi_k2, "granite_hybrid": granite_hybrid,
             "dots3_note": dots3_note, "evabyte": evabyte,
-            "phi4_flash": phi4_flash}
+            "phi4_flash": phi4_flash, "keye_vl": keye_vl}
 
 # a layer's entry in :func:`layer_windows` that attends no position at all
 RECURRENT = granite_hybrid.RECURRENT
@@ -379,6 +388,16 @@ def index_cache(cfg) -> Optional[Tuple[int, int]]:
     return getattr(cfg, "index_cache", None)
 
 
+def rope_offset(cfg) -> bool:
+    """Whether a slot's ROTARY position is its cached length plus an offset a
+    slot (``cfg.rope_delta_cache``: positions on three axes, where a video's
+    tokens advance the position by less than their count:
+    :func:`ray_tpu.models.keye_vl.rope_index`).  The cache then holds
+    ``rope_delta``; causality, the selection and every write stay on cache
+    positions."""
+    return bool(getattr(cfg, "rope_delta_cache", False))
+
+
 def summary_cache(cfg) -> Optional[Tuple[int, int]]:
     """For a family whose layers keep an EXACT window and a compressed memory
     of what came before it (:mod:`ray_tpu.ops.eva`): ``(window, chunk)``: the
@@ -419,12 +438,13 @@ def attention_scale(cfg, window: bool = False) -> Optional[float]:
 
 def cached_tensors(cfg, window: bool = False) -> Tuple[str, ...]:
     """The names of what :func:`init_cache` holds a position of a full layer
-    (K and V per head; the one latent row; the row and the index key of a
-    layer that selects) or, ``window``, of a window layer's ring."""
+    (K and V per head, and the index key where the layer selects; the one
+    latent row; the row and the index key of a layer that selects) or,
+    ``window``, of a window layer's ring."""
     if window:
         return ("c_ring",) if latent_cache(cfg, True) else ("k_ring", "v_ring")
     if not latent_cache(cfg):
-        return ("k", "v")
+        return ("k", "v", "idx_k") if index_cache(cfg) else ("k", "v")
     return ("c", "idx_k") if index_cache(cfg) else ("c",)
 
 
@@ -474,7 +494,11 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
     tensor): ``k``/``v`` ``[L, B, KV, dh, window + slack]``, the CURRENT
     window's exact keys and values (:func:`window_positions`), and ``ks``/``vs``
     ``[L, B, KV, dh, rows]``, one pooled key and value a chunk of every window
-    the slot has filled (:func:`summary_rows` of ``max_len``)."""
+    the slot has filled (:func:`summary_rows` of ``max_len``).  A family of K
+    and V per head whose layers select: ``idx_k`` beside ``k`` and ``v``; one
+    whose rotary positions are not cache positions (:func:`rope_offset`):
+    ``rope_delta [B]`` int32, what a slot's rotary position is ahead of its
+    cached length (0 or less)."""
     windows = layer_windows(cfg)
     n_full, n_state = windows.count(0), windows.count(RECURRENT)
     n_window = sum(w > 0 for w in windows)
@@ -499,6 +523,11 @@ def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
                 "pos": jnp.zeros((n_slots,), jnp.int32)}
     cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
              "pos": jnp.zeros((n_slots,), jnp.int32)}
+    if index_cache(cfg):  # the first and the fifth kinds side by side
+        cache["idx_k"] = jnp.zeros(
+            (n_full, n_slots, 1, index_cache(cfg)[0], max_len), cfg.dtype)
+    if rope_offset(cfg):
+        cache["rope_delta"] = jnp.zeros((n_slots,), jnp.int32)
     if n_window:
         ring = ring_positions(max(windows))
         cache.update(k_ring=slab(n_window, ring), v_ring=slab(n_window, ring))
@@ -538,15 +567,26 @@ def _cache_scores_slab(q, k_all, v_all, l, mask, scale=None):
     return acc, m, e.sum(-1)
 
 
-def _cache_scores(q, k_all, v_all, l, n, plan, scale=None, **named):
+def _cache_scores(q, k_all, v_all, l, n, plan, scale=None, keep=None, **named):
     """``q [B, KV, G, dh]`` against positions ``j < n[b]`` of layer ``l`` of
     the whole caches ``[L, B, KV, dh, S]``: ``(acc, m, d)``, the softmax
     un-normalised.  Lowered for a TPU, with a cache of whole 128-position
     tiles, the Pallas kernel that copies in only the tiles below ``n[b]``;
     anywhere else the masked einsums over the slab.  Decided by what the
     program is lowered for and by the cache's shape, never by a flag.
-    ``named``: the kernel call's own name."""
+    ``named``: the kernel call's own name.  ``keep [B, S]`` bool (None: all):
+    of the positions ``j < n[b]`` the ones a layer that selects lets slot ``b``
+    attend; both forms still read what is live and mask."""
     below = lambda n: jnp.arange(k_all.shape[-1])[None, :] < n[:, None]  # noqa: E731
+    if keep is not None:
+        if plan is None:
+            return _cache_scores_slab(q, k_all, v_all, l, below(n) & keep, scale)
+        return lax.platform_dependent(
+            q, k_all, v_all, l, n, plan, keep,
+            tpu=lambda q, k, v, l, n, plan, keep: ragged_decode_attention(
+                q, k, v, l, plan, scale=scale, keep=keep, **named),
+            default=lambda q, k, v, l, n, plan, keep: _cache_scores_slab(
+                q, k, v, l, below(n) & keep, scale))
     if plan is None:
         return _cache_scores_slab(q, k_all, v_all, l, below(n), scale)
     return lax.platform_dependent(
@@ -788,7 +828,8 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                cache: Dict[str, jax.Array], slots: jax.Array,
                offsets: Optional[jax.Array] = None,
                bound: Optional[int] = None,
-               final: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
+               final: Optional[jax.Array] = None,
+               visual: Optional[Dict] = None) -> Tuple[jax.Array, Dict]:
     """Run the prompts ``tokens [B, Tp]`` (right-padded; true lengths
     ``lengths [B]``) and write K/V into cache slots ``slots [B]`` (any
     subset — one compiled program admits a whole batch of requests).  Returns
@@ -846,7 +887,16 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     against the slab as this call leaves it.  ``final [B]`` bool (None: every
     row): the rows whose last position ENDS a prompt; where none does (a part
     that is not its prompt's last) the upper layers and the head do not run at
-    all and the logits are zeros."""
+    all and the logits are zeros.
+
+    ``visual`` (None: the rows are token ids alone, at rotary positions that
+    are their cache positions): for a family with a tower in front
+    (:func:`rope_offset`), ``{"rows": the tower's results for the frames this
+    call's tokens stand for, "index" [B, Tp] int32: the row of those a token's
+    input is (-1: its embedding), "positions" [B, 3, Tp] int32: every token's
+    rotary position, "delta" [B] int32: what the rows' slots decode ahead of
+    their cached lengths afterwards}``.  The slots' ``rope_delta`` is written
+    either way (0 for a text request: a reused slot is clean)."""
     fam = family_of(cfg)
     B, Tp = tokens.shape
     windows = layer_windows(cfg)
@@ -869,7 +919,13 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                              ) if max(windows) > 0 else ()}
     else:
         positions = jnp.arange(Tp)
-    x = fam.embed(params, tokens, cfg, positions)
+    if visual is not None:
+        # rotary positions apart from cache positions: the blocks rotate by
+        # these; offsets, masks and writes above and below stay the cache's
+        positions = visual["positions"]
+        x = fam.embed(params, tokens, cfg, positions, visual)
+    else:
+        x = fam.embed(params, tokens, cfg, positions)
 
     def among(window, held, own, prepare=None):
         # a PART's keys (values, ...) of one layer, a tensor each of ``held``
@@ -905,7 +961,8 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                 q, k, v, *pool, window=W, chunk=c, scale=scale, held=held,
                 rows0=offsets // W * (W // c) if part else None)
             return out, (k, v, *(pooled or ()))
-        kept = ((k, v) if row is None else (row,) if index is None
+        kept = (((k, v) if index is None else (k, v, index[2]))
+                if row is None else (row,) if index is None
                 else (row, index[2]))
         if held is not None:
             if row is None:  # (a latent layer's k and v cover it already)
@@ -916,7 +973,7 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
                 return band_attention_after(
                     q, k, v, offsets, window=window, scale=scale), kept
             keep = None if index is None else dsa.causal_top_k_mask(
-                index[0], index[1], _placed(held[1], index[2], offsets)[:, 0],
+                index[0], index[1], _placed(held[-1], index[2], offsets)[:, 0],
                 index_cache(cfg)[1], first=offsets)
             return continued_attention(
                 q, k, v, offsets, keep=keep, scale=scale), kept
@@ -977,6 +1034,9 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     rows = lengths.astype(jnp.int32)
     out = {**cache, "pos": cache["pos"].at[slots].set(
         rows + offsets if part else rows)}
+    if "rope_delta" in cache:
+        out["rope_delta"] = cache["rope_delta"].at[slots].set(
+            0 if visual is None else visual["delta"].astype(jnp.int32))
     if compact:
         out.update(_keep_compacted(cfg, cache, full, slots, rows,
                                    offsets if part else None))
@@ -1297,6 +1357,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         locs, held, pos, toks, act, rng = carry
         rng, sub = jax.random.split(rng)
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
+        if "rope_delta" in cache:  # rotary positions ahead of the cache's
+            positions = positions + cache["rope_delta"][:, None]
         x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
 
         def layer(l, w, at, block, carry):
@@ -1324,6 +1386,10 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     return _latent_cache_scores(
                         q, old[0], at, live, plan, scale=scale, dv=latent[1],
                         keep=keep)
+                if not w and index:  # a slab of k and v under a selection
+                    return _cache_scores(
+                        q, old[0], old[1], at, live, plan, scale, keep=keep,
+                        name="ragged_sparse_gqa_attention")
                 if not w:
                     return _cache_scores(q, *old, at, live, plan, scale,
                                          **slab_name)
@@ -1337,7 +1403,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                 put = lambda buf, t: lax.dynamic_update_slice(
                     buf, t[None, None, :, :, 0, :].astype(buf.dtype),
                     (at, i, 0, 0, 0))
-                cols = ((k, v) if row is None else (row,) if picked is None
+                cols = (((k, v) if picked is None else (k, v, picked[2]))
+                        if row is None else (row,) if picked is None
                         else (row, picked[2]))
                 new = tuple(put(buf, t) for buf, t in zip(locs[mine], cols))
                 k_new = at_l(new[0])
@@ -1347,8 +1414,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
                     # the layer's selection for this step: over the slab's
                     # index keys below ``live`` AND the chunk's own up to i
                     keep, keep_new = _select(
-                        picked[0][:, :, 0], picked[1][:, 0], old[1], at,
-                        at_l(new[1])[:, :, 0], live, i, index[1])
+                        picked[0][:, :, 0], picked[1][:, 0], old[-1], at,
+                        at_l(new[-1])[:, :, 0], live, i, index[1])
                 out = _decode_attend(q, partial(cached, keep=keep), k_new,
                                      v_new, i, w, scale, keep_new)
                 locs[mine] = new

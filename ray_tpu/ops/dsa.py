@@ -149,13 +149,15 @@ def causal_top_k_mask(q: jax.Array, w: jax.Array, k: jax.Array, top_k: int,
 def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array, index,
                        top_k: int, *, scale: float) -> jax.Array:
     """A whole prompt's attention on a layer that selects: ``q [B, H, T, dk]``,
-    ``k [B, H or 1, T, dk]``, ``v [B, H or 1, T, dv]``, ``index = (index
+    ``k [B, H or KV or 1, T, dk]``, ``v [B, H or KV or 1, T, dv]``, ``index = (index
     queries [B, Hi, T, d], head weights [B, T, Hi], index keys [B, 1, T,
     d])``.  Query ``t`` attends the ``top_k`` positions ``s <= t`` its index
     scores put first; a prompt of at most ``top_k`` positions selects all of
     them, and is plain causal attention (decided by the shape)."""
-    if k.shape[1] != q.shape[1]:  # one key head for every query head
+    if k.shape[1] == 1 != q.shape[1]:  # one key head for every query head
         k, v = (jnp.broadcast_to(t, (*q.shape[:3], t.shape[-1])) for t in (k, v))
+    elif k.shape[1] != q.shape[1]:  # grouped-query heads: a key head a group
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1) for t in (k, v))
     if q.shape[2] <= top_k:
         return attention(q, k, v, causal=True, scale=scale)
     qi, w, ki = index

@@ -137,6 +137,37 @@ def rope(
     return out.astype(x.dtype)
 
 
+def mrope(x: jax.Array, positions: jax.Array, sections, *,
+          base: float = 10000.0) -> jax.Array:
+    """Rotary embedding on THREE position axes (M-RoPE: Qwen2-VL's, what a
+    config's ``rope_scaling.mrope_section`` declares).  ``x [B, heads, T, d]``;
+    ``sections``: how many of the ``d / 2`` frequencies (``base ** (-2i / d)``)
+    turn by the temporal, the row and the column component of a position, in
+    that order; ``positions [B, 3, T]`` one ``(t, h, w)`` a token, or ``[T]`` /
+    ``[B, T]``: every axis the same, which is plain rotary at that position (a
+    text token's).  The rotate-half pairing (value ``i`` with ``i + d / 2``)."""
+    d = x.shape[-1]
+    assert sum(sections) * 2 == d, (sections, d)
+    inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    at = positions.astype(jnp.float32)
+    if positions.ndim == 3:  # each frequency's own axis: [B, T, d / 2]
+        axis = jnp.repeat(jnp.arange(3), jnp.asarray(sections),
+                          total_repeat_length=d // 2)
+        at = jnp.take_along_axis(
+            jnp.swapaxes(at, 1, 2), jnp.broadcast_to(
+                axis, (at.shape[0], at.shape[2], d // 2)), axis=2)
+        angles = (at * inv_freq)[:, None]
+    else:
+        angles = at[..., None] * inv_freq
+        if positions.ndim == 2:
+            angles = angles[:, None]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
 def cross_entropy_loss(
     logits: jax.Array, labels: jax.Array, *, ignore_index: int = -100,
     z_loss: float = 0.0,

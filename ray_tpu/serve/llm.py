@@ -38,6 +38,18 @@ is part of the framework here:
   part's offset is a multiple of the part: a compacting family's windows (a
   part is one or more whole windows: checked where the engine is built) line
   up with the parts for free, and only because of that.
+  A family with a VISION TOWER in front of its text path
+  (:mod:`ray_tpu.models.keye_vl`) is the first whose requests are not token
+  ids alone: a request may carry a video's patches, the tower is a program of
+  its own (``jit_llm_vision_encode``: :data:`VISION_CALL_PATCHES` patches a
+  call, one program a frame grid) run for the frames a prompt's NEXT prefill
+  call needs, in the tick that dispatches that call, its rows handed over on
+  the device (no buffer a slot, no pixels on the host once the last frame is
+  dispatched; a frame whose rows straddle a part's end is encoded once: the
+  tower's last result rides to the next part), and a slot's rotary position
+  is its cached length plus the offset its prompt left (``rope_delta``).
+  Tower calls count against the tick's token budget by their patches, and the
+  tick meter books their time as prefill.
 - :func:`llm_deployment` — wraps the engine in a Serve deployment on a
   ``num_tpus`` replica; requests block on a future the engine thread
   resolves, so Serve's threaded replica concurrency (not the engine)
@@ -76,6 +88,9 @@ FIRST_REPLY_STAGES = (
 DECODE_STAGES = ("engine.decode", "engine.last_yield", "serve.last_pickup",
                  "serve.stream")
 STAGES = FIRST_REPLY_STAGES + DECODE_STAGES
+# ... and, of a request that carries a video, the device seconds its tower
+# calls took (a part of ``engine.first_token``: dispatch -> first token)
+VISION_STAGES = ("engine.vision_encode",)
 # folded only (no span in any tree): a request's ``engine.decode`` over its
 # tokens - 1 and its ``serve.stream`` over its chunks - 1, the client's
 # per-request pace after the first token taken at two depths inside the
@@ -132,13 +147,42 @@ def call_rows(bucket: int, n_slots: int) -> int:
 # bucket's own program: ``_part_call``.)
 PREFILL_PART_TOKENS = 2048
 
+# Patches one call of a vision tower takes (frames a call: this many over the
+# patches of a frame, at least one; 16 frames of 16 x 16 patches): frames are
+# independent, so a call is a batch, and at 4,096 patches the tower's matmuls
+# are far past the chip's ridge while a call stays ~a part's own time, which
+# is what a live stream waits between two chunks.
+VISION_CALL_PATCHES = 4096
+
+
+class RequestRefused(ValueError):
+    """A request the engine refuses at submission, in the caller's thread: a
+    client's error, named (``reason``: the key it is counted under in
+    ``stats()["refused"]``)."""
+
+    def __init__(self, reason: str, message: str):
+        super().__init__(message)
+        self.reason = reason
+
 
 def _llm_metrics():
     global _LLM_METRICS
     if _LLM_METRICS is None:
-        from ray_tpu.util.metrics import Histogram
+        from ray_tpu.util.metrics import Counter, Histogram
 
         _LLM_METRICS = {
+            # a family with a vision tower: what its calls encoded, and the
+            # requests refused at submission (any family), by reason
+            "vision_patches": Counter(
+                "ray_tpu_llm_vision_patches_total",
+                "patches the vision tower's calls encoded (padding apart)"),
+            "vision_frames": Counter(
+                "ray_tpu_llm_vision_frames_total",
+                "video frames the vision tower's calls encoded"),
+            "refused": Counter(
+                "ray_tpu_llm_requests_refused_total",
+                "requests refused at submission (a client's error)",
+                tag_keys=("reason",)),
             "admission": Histogram(
                 "ray_tpu_llm_slot_admission_latency_s",
                 "request submit -> decode-slot admission latency (s)",
@@ -214,6 +258,12 @@ class _TickMeter:
         # tally of the prompts that went in parts (its own dict too)
         self.state: Optional[Dict[str, int]] = None
         self.parts: Optional[Dict[str, int]] = None
+        # an engine with a vision tower: seconds and count of the tower calls
+        # that landed behind another landing, and the part of them that fell
+        # in interleaved periods (booked as prefill everywhere above); None:
+        # no tower
+        self.vision: Optional[Dict[str, float]] = None
+        self._vision_period_s = 0.0
 
     def begin(self, chained: bool) -> None:
         """The drain of one tick begins; ``chained``: it was dispatched
@@ -222,7 +272,23 @@ class _TickMeter:
         if not self._counted:
             self._landed = None
         self._period_s = 0.0
+        self._vision_period_s = 0.0
         self._calls = 0
+
+    def vision_landed(self, t: float) -> float:
+        """One of the tick's TOWER calls landed at ``t`` (ahead of the prefill
+        call its rows feed): prefill time like that call's own.  Returns the
+        seconds it is booked."""
+        took = 0.0
+        if self._landed is not None:
+            took = t - self._landed
+            self._period_s += took
+            self.prefill_s += took
+            self._vision_period_s += took
+            self.vision["tower_s"] += took
+            self.vision["tower_calls_timed"] += 1
+        self._landed = t
+        return took
 
     def call_landed(self, t: float) -> None:
         """One of the tick's prefill calls landed at ``t``."""
@@ -263,6 +329,8 @@ class _TickMeter:
             _perf.publish_device_memory()
         if mode == "interleaved":
             self.interference_s += prefill_part
+            if self.vision is not None:
+                self.vision["tower_interference_s"] += self._vision_period_s
             self._since_emit += 1
             if self._since_emit >= self.EMIT_EVERY:
                 self.emit_event()
@@ -334,6 +402,8 @@ class _TickMeter:
             **self.host.snapshot(),
             "ticks_live": self.host.count,
             "decode": dict(self.decode),
+            **({"vision_ticks": {k: round(v, 6) for k, v in self.vision.items()}}
+               if self.vision is not None else {}),
         }
 
     def emit_event(self) -> None:
@@ -390,10 +460,17 @@ class _Request:
     __slots__ = ("tokens", "max_new", "future", "emitted", "scheduled",
                  "prefilled", "submitted_at", "trace_ctx", "dispatched_at",
                  "first_host_t", "last_host_t", "chunks", "chunk_steps",
-                 "prefill_mark")
+                 "prefill_mark", "video", "vision_s")
 
-    def __init__(self, tokens: List[int], max_new: int):
+    def __init__(self, tokens: List[int], max_new: int, video=None):
         self.tokens = list(tokens)
+        # a request that carries a video (``GenerationEngine._checked``): its
+        # grid, its patches on the host until the last frame is dispatched,
+        # where its rows stand among the tokens, its rotary positions and the
+        # offset its slot decodes at; and the device seconds its tower calls
+        # took
+        self.video: Optional[Dict[str, Any]] = video
+        self.vision_s = 0.0
         self.max_new = int(max_new)
         self.future: Future = Future()
         self.emitted: List[int] = []
@@ -446,7 +523,8 @@ class _PendingChunk:
         # this iteration's prefill calls, in dispatch order: (admissions
         # [(row_j, slot, _Request)], first tokens [rows] device, the call's
         # routing counts: device, None where the family counts nothing; the
-        # padded tokens it was wide).  A
+        # padded tokens it was wide; the tower calls dispatched ahead of it,
+        # [(a value of the call's result, its _Request)]).  A
         # part that completes no prompt admits nobody, and in the first
         # tokens' place has where its row stands now (read for its landing)
         self.prefills = prefills
@@ -665,6 +743,25 @@ class GenerationEngine:
                 "row_bytes": (held.nbytes // math.prod(held.shape[:2])
                               + tails.nbytes // (n_state * tails.shape[2]))}
         self._key = jax.random.PRNGKey(seed)
+        # requests refused at submission, by reason (``stats()["refused"]``)
+        self._refused: Dict[str, int] = {}
+        # a family with a vision tower in front (``vision_program``; None:
+        # requests are token ids alone): the tower's jitted call, and,
+        # cumulative and counted on the host at dispatch, the frames and
+        # patches the calls encoded, the patches of the frames a call was
+        # padded with past a video's end, the rows handed to prefill calls
+        # (``visual_tokens``), the calls, the requests with a video; they ride
+        # ``cache_tiles`` too (``vision_*``), where a traced replica of the
+        # benchmark reads its counters at the trace's two ends
+        self._vision_jit = vision_program(cfg)
+        self._vision: Optional[Dict[str, int]] = None
+        self._vision_zeros: Dict[tuple, Any] = {}
+        if self._vision_jit is not None:
+            self._vision = dict.fromkeys(
+                ("frames", "patches", "padded_patches", "visual_tokens",
+                 "calls", "requests"), 0)
+            self._cache_tiles.update(
+                {"vision_" + k: 0 for k in self._vision})
 
         (self._prefill_jit, self._decode_jit, decode_cut_jit,
          self._part_jit) = engine_programs(
@@ -735,6 +832,9 @@ class GenerationEngine:
             f"engine-{_os.getpid()}-{next(_ENGINE_SEQ)}")
         self._ticks.state = self._state
         self._ticks.parts = self._prefill.get("parts")
+        if self._vision is not None:
+            self._ticks.vision = {"tower_s": 0.0, "tower_calls_timed": 0,
+                                  "tower_interference_s": 0.0}
         # the process's sampler looks at the tick's phases between its bursts
         # (where the continuous profiler is off nobody looks: no stacks)
         sampling_profiler.watch(self._ticks.host)
@@ -742,16 +842,109 @@ class GenerationEngine:
         self._itl_samples: "_deque[float]" = _deque(maxlen=4096)
 
     # -- public API ----------------------------------------------------
-    def _submit_req(self, tokens: List[int], max_new: Optional[int]) -> _Request:
-        """Validate + enqueue (shared by submit and stream)."""
+    def checked(self, tokens, max_new=None, video=None) -> tuple:
+        """What a request may carry, checked in the CALLER's thread: ``(tokens,
+        max_new, video)`` as ``_Request`` takes them, or :class:`RequestRefused`
+        (a ``ValueError`` that names the fault; counted by reason in
+        ``stats()["refused"]``; nothing refused is ever admitted).
+
+        - ``tokens``: a non-empty list of ints, at most the largest prefill
+          bucket long;
+        - ``max_new``: None or an int >= 0 (None and 0: the engine's cap; held
+          to the cap);
+        - ``video`` (None: a text request; only a family with a vision tower
+          takes one): ``{"grid": [F, gh, gw], "patches": uint8 [F x gh x gw,
+          patch values] (an array, or its bytes in base64)}``: ``F`` frames of
+          ``gh x gw`` patches, both even, row-major in a frame.  The tower's
+          ``F x gh/2 x gw/2`` rows stand where ``tokens`` hold the family's
+          video placeholder id, which has to be ONE run of exactly that many
+          (a text request holds none)."""
+        try:
+            return self._checked(tokens, max_new, video)
+        except RequestRefused as e:
+            with self._lock:
+                self._refused[e.reason] = self._refused.get(e.reason, 0) + 1
+            _llm_metrics()["refused"].inc(1, {"reason": e.reason})
+            raise
+
+    def _checked(self, tokens, max_new, video) -> tuple:
+        if not isinstance(tokens, (list, tuple)) or not all(
+                isinstance(t, (int, np.integer)) and not isinstance(t, bool)
+                for t in tokens):
+            raise RequestRefused("tokens", "tokens: not a list of ints")
         if not tokens:
-            raise ValueError("empty prompt")
+            raise RequestRefused("tokens", "empty prompt")
         if len(tokens) > self.buckets[-1]:
-            raise ValueError(
-                f"prompt length {len(tokens)} exceeds the largest prefill "
-                f"bucket {self.buckets[-1]}")
-        req = _Request(tokens, min(max_new or self.max_new_tokens,
-                                   self.max_new_tokens))
+            raise RequestRefused(
+                "tokens", f"prompt length {len(tokens)} exceeds the largest "
+                f"prefill bucket {self.buckets[-1]}")
+        if max_new is not None and (
+                isinstance(max_new, bool)
+                or not isinstance(max_new, (int, np.integer)) or max_new < 0):
+            raise RequestRefused(
+                "max_new_tokens", f"max_new_tokens: {max_new!r} is no int >= 0")
+        max_new = min(int(max_new) or self.max_new_tokens
+                      if max_new is not None else self.max_new_tokens,
+                      self.max_new_tokens)
+        placeholder = getattr(self.cfg, "video_token_id", None)
+        held = [i for i, t in enumerate(tokens) if t == placeholder
+                ] if placeholder is not None else []
+        if video is None:
+            if held:
+                raise RequestRefused(
+                    "video", f"{len(held)} video placeholders and no video")
+            return list(tokens), max_new, None
+        if self._vision is None:
+            raise RequestRefused("video", "this model takes token ids alone")
+        if not isinstance(video, dict) or "grid" not in video:
+            raise RequestRefused("video", "video: no grid")
+        grid = video["grid"]
+        if not (isinstance(grid, (list, tuple)) and len(grid) == 3 and all(
+                isinstance(g, (int, np.integer)) and g > 0 for g in grid)):
+            raise RequestRefused(
+                "video", "video.grid: not [frames, rows, columns] of patches")
+        F, gh, gw = (int(g) for g in grid)
+        if gh % 2 or gw % 2:
+            raise RequestRefused(
+                "video", f"video.grid: {gh} x {gw} patches, an odd side")
+        tpf = (gh // 2) * (gw // 2)
+        one_run = bool(held) and held[-1] - held[0] == len(held) - 1
+        if len(held) != F * tpf or not one_run:
+            raise RequestRefused(
+                "video", f"{len(held)} video placeholders (one run: {one_run}) "
+                f"for {F} x {gh // 2} x {gw // 2} = {F * tpf} rows")
+        patches = video.get("patches")
+        if isinstance(patches, (str, bytes, memoryview)):
+            try:
+                patches = _base64_in_pieces(patches)
+            except ValueError:  # (binascii.Error is one)
+                raise RequestRefused(
+                    "video", "video.patches: not base64") from None
+        values = self._gen.family_of(self.cfg).vision_config(
+            self.cfg).patch_values
+        if not isinstance(patches, np.ndarray) or patches.dtype != np.uint8 \
+                or patches.size != F * gh * gw * values:
+            raise RequestRefused(
+                "video", f"video.patches: not {F * gh * gw} x {values} bytes")
+        positions, delta = self._gen.family_of(self.cfg).rope_index(
+            len(tokens), held[0], (F, gh // 2, gw // 2))
+        return list(tokens), max_new, {
+            "grid": (F, gh, gw), "patches": patches.reshape(F, gh * gw, values),
+            "first": held[0], "tpf": tpf, "n_vis": F * tpf,
+            "positions": positions, "delta": delta,
+            # frames planned into tower calls so far; the calls due with each
+            # of the request's planned prefill calls (their first frames, a
+            # list a call); the last tower result on the device and the frame
+            # it starts at
+            "planned": 0, "due": [], "last": None}
+
+    def _submit_req(self, tokens: List[int], max_new: Optional[int],
+                    video=None, ready: bool = False) -> _Request:
+        """Validate + enqueue (shared by submit and stream).  ``ready``: the
+        three are what :meth:`checked` returned (a caller that refused in its
+        own thread first)."""
+        req = _Request(*((tokens, max_new, video) if ready
+                         else self.checked(tokens, max_new, video)))
         ctx = tracing.current_context()
         if ctx is not None and "t_exec" in ctx:
             # from where the worker's task.dispatch ended to the request
@@ -762,23 +955,34 @@ class GenerationEngine:
         with self._lock:
             self._queue.append(req)
             self.total_requests += 1
+            if req.video is not None:
+                self._count_vision(requests=1)
         self._work.set()
         return req
 
-    def submit(self, tokens: List[int], max_new: Optional[int] = None) -> Future:
-        return self._submit_req(tokens, max_new).future
+    def submit(self, tokens: List[int], max_new: Optional[int] = None,
+               video=None, ready: bool = False) -> Future:
+        """Enqueue one request -> a Future of its generated token ids.  What a
+        request may carry, and what is refused here, in the caller's thread:
+        :meth:`checked` (``video``: a family with a vision tower only;
+        ``ready``: the arguments are what :meth:`checked` returned)."""
+        return self._submit_req(tokens, max_new, video, ready).future
 
     def generate(self, tokens: List[int], max_new: Optional[int] = None,
-                 timeout: float = 300.0) -> List[int]:
-        return self.submit(tokens, max_new).result(timeout)
+                 timeout: float = 300.0, video=None,
+                 ready: bool = False) -> List[int]:
+        return self.submit(tokens, max_new, video, ready).result(timeout)
 
     def stream(self, tokens: List[int], max_new: Optional[int] = None,
-               timeout: float = 300.0):
+               timeout: float = 300.0, video=None, ready: bool = False):
         """Yield token ids AS THE ENGINE EMITS THEM (token streaming for
         serve's chunked responses).  Raises the request's error, if any.
         Between two looks it waits on the drain's signal (``_landed``), the
-        ``timeout`` its only clock."""
-        req = self._submit_req(tokens, max_new)
+        ``timeout`` its only clock.  What a request may carry: :meth:`checked`
+        (a generator: the check runs at its first ``next``; a caller that
+        wants the refusal before it streams calls :meth:`checked` itself and
+        hands over what it returned, ``ready``, as ``llm_deployment`` does)."""
+        req = self._submit_req(tokens, max_new, video, ready)
         n = 0
         yielded_t = None  # when the newest token of a look was handed over
         deadline = time.perf_counter() + timeout
@@ -861,6 +1065,8 @@ class GenerationEngine:
                 "queued": len(self._queue),
                 "total_requests": self.total_requests,
                 "total_generated_tokens": self.total_generated,
+                # refused at submission, by what was wrong (never admitted)
+                "refused": dict(self._refused),
             }
 
     @staticmethod
@@ -897,9 +1103,13 @@ class GenerationEngine:
             state = dict(self._state) if self._state else None
             dsa = self._selection_stats()
             compacting = self._compaction_stats()
+            vision = None if self._vision is None else {
+                **self._vision,
+                "requests_refused": self._refused.get("video", 0)}
         stages: Dict[str, Any] = {}
         if _events.ENABLED:
-            stages = tracing.span_stats(STAGES + PER_GAP)
+            stages = tracing.span_stats(STAGES + PER_GAP + (
+                VISION_STAGES if vision is not None else ()))
             stages["clock_skew"] = tracing.clock_skew()
         return {
             # the wall clock of this read: every difference of two calls has
@@ -962,6 +1172,12 @@ class GenerationEngine:
             # and the chunks they pooled, the windows the prefills pooled,
             # the chunks cut for a window's end (``__init__``).  Cumulative
             **({"eva": compacting} if compacting else {}),
+            # a family with a vision tower: the frames and patches its calls
+            # encoded, the patches they were padded with, the rows handed to
+            # prefill calls, the calls, the requests with a video and those
+            # refused for theirs (cumulative, counted at dispatch; the calls'
+            # device seconds: the tick meter's ``vision_ticks`` above)
+            **({"vision": vision} if vision is not None else {}),
             "compiles": compile_cache.counts(),
             "device": _device_facts(),
         }
@@ -1054,8 +1270,10 @@ class GenerationEngine:
                                       <= self._tick_tokens)):
                     n = min(self._part, len(req.tokens) - req.prefilled)
                     calls.append((None, (slot, req, req.prefilled, n)))
+                    # (the part's tower calls, by their patches)
+                    spent += self._part + self._plan_frames(
+                        req, req.prefilled, n)
                     req.prefilled += n
-                    spent += self._part
                     for name, more in (
                             ("calls", 1), ("rows", 1), ("live_tokens", n),
                             ("padded_tokens", self._part),
@@ -1088,6 +1306,7 @@ class GenerationEngine:
                 req = self._queue.pop(0)
                 self._slots[slot] = req
                 calls[-1][1].append((slot, req))
+                spent += self._plan_frames(req, 0, len(req.tokens))
             if self._shared is not None:  # the prompt positions these calls take
                 self._cache_tiles["yoco_prefill_positions"] += sum(
                     batch[3] if b is None
@@ -1158,16 +1377,150 @@ class GenerationEngine:
             slots[j] = slot
         self._stamp_dispatch(req for _, req in batch)
         slots_dev = jnp.asarray(slots)
+        visual, towers = self._visual(
+            [(j, req, 0, int(lens[j])) for j, (_, req) in enumerate(batch)],
+            n, b)
         last_logits, self.cache, routed_dev = self._prefill_jit(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
-            self.cache, slots_dev, *self._final(n, batch, not first_part))
+            self.cache, slots_dev, *self._final(n, batch, not first_part),
+            *visual)
         if first_part:
             _to_host_async((last_logits, routed_dev))
-            return [], last_logits, routed_dev, n * b
+            return [], last_logits, routed_dev, n * b, towers
         firsts_dev = self._first_tokens(
             last_logits, slots_dev, routed_dev, [req for _, req in batch])
         admissions = [(j, slot, req) for j, (slot, req) in enumerate(batch)]
-        return admissions, firsts_dev, routed_dev, n * b
+        return admissions, firsts_dev, routed_dev, n * b, towers
+
+    def _count_vision(self, **more) -> None:
+        """Add to the tower's counters, both places (call under the lock)."""
+        for name, n in more.items():
+            self._vision[name] += n
+            self._cache_tiles["vision_" + name] += n
+
+    @staticmethod
+    def _video_rows(video, first: int, n: int) -> tuple:
+        """``(lo, hi)``: which of the video's rows (counted from its first)
+        stand among the prompt's tokens ``[first, first + n)``; ``hi <= lo``:
+        none."""
+        return (max(first, video["first"]) - video["first"],
+                min(first + n, video["first"] + video["n_vis"]) - video["first"])
+
+    def _frames_a_call(self, video) -> int:
+        _, gh, gw = video["grid"]
+        return max(1, VISION_CALL_PATCHES // (gh * gw))
+
+    def _plan_frames(self, req: _Request, first: int, n: int) -> int:
+        """Plan the tower calls that the prefill call over ``req``'s tokens
+        ``[first, first + n)`` needs (an entry of ``video["due"]``, one a
+        planned call in call order: the tower calls' first frames):
+        the frames its rows stand for that no earlier call encoded, whole
+        calls of real frames (a call encodes ahead of the need rather than
+        pad; only a video's end is padded).  Returns the patches those calls
+        are wide, for the tick's budget.  Call under the lock."""
+        video = req.video
+        if video is None:
+            return 0
+        due: List[int] = []
+        video["due"].append(due)  # one entry a prefill call, in call order
+        lo, hi = self._video_rows(video, first, n)
+        if hi <= lo:
+            return 0
+        per, frames = self._frames_a_call(video), video["grid"][0]
+        size = video["grid"][1] * video["grid"][2]
+        need = -(-hi // video["tpf"])  # frames [0, need) have to be encoded
+        real = 0
+        while video["planned"] < need:
+            due.append(video["planned"])
+            real += min(per, frames - video["planned"])
+            video["planned"] += per
+        self._count_vision(
+            frames=real, patches=real * size, calls=len(due),
+            padded_patches=(len(due) * per - real) * size,
+            visual_tokens=hi - lo)
+        _llm_metrics()["vision_frames"].inc(real)
+        _llm_metrics()["vision_patches"].inc(real * size)
+        return len(due) * per * size
+
+    def _visual(self, spans, rows: int, width: int) -> tuple:
+        """A prefill call's ``visual`` argument and the tower calls dispatched
+        for it.  ``spans``: ``(row, _Request, first token, tokens)`` of the
+        call's real rows; the call is ``rows x width``.  Returns ``((visual,),
+        [(a value of a tower call's result, its request)])``; ``((), [])`` for
+        a family without a tower; ``((None,), [])`` where no row carries a
+        video (the program for token ids alone).
+
+        For every row with a video: its planned tower calls are dispatched
+        (``video["due"]``), each over :func:`_frames_a_call` frames from the
+        request's host patches, and the rows of the frames this call's tokens
+        stand for are named by ``index`` into the results laid side by side:
+        the request's LAST result first where an earlier call encoded some of
+        them (a frame that straddles a part's end; frames encoded ahead), then
+        the new ones; the tuple is filled up with zeros to the count a call of
+        this width can need, so that one program serves every part."""
+        if self._vision_jit is None:
+            return (), []
+        if not any(req.video is not None for _, req, _, _ in spans):
+            return (None,), []
+        import jax.numpy as jnp
+
+        index = np.full((rows, width), -1, np.int32)
+        positions = np.broadcast_to(
+            np.arange(width, dtype=np.int32), (rows, 3, width)).copy()
+        delta = np.zeros((rows,), np.int32)
+        results, towers = [], []   # [(first frame, device rows [per, tpf, D])]
+        most = 0
+        for j, req, first, n in spans:
+            video = req.video
+            if video is None:
+                continue
+            positions[j, :, :n] = video["positions"][:, first:first + n]
+            delta[j] = video["delta"]
+            per, tpf = self._frames_a_call(video), video["tpf"]
+            grid = video["grid"][1:]
+            most = max(most, 1 + -(-(width // tpf + 1) // per))
+            mine = [video["last"]] if video["last"] is not None else []
+            for base in video["due"].pop(0):
+                chunk = video["patches"][base:base + per]
+                if len(chunk) < per:  # past the video's end
+                    chunk = np.concatenate([chunk, np.zeros(
+                        (per - len(chunk), *chunk.shape[1:]), np.uint8)])
+                out, mark = self._vision_jit(
+                    self.params, jnp.asarray(chunk), grid=grid)
+                mark.copy_to_host_async()
+                towers.append((mark, req))
+                mine.append((base, out))
+            if mine:
+                video["last"] = mine[-1]
+            if video["planned"] >= video["grid"][0] and not any(video["due"]):
+                video["patches"] = None  # every frame is on the device
+            shape = (per, tpf, self.cfg.d_model)
+            lo, hi = self._video_rows(video, first, n)
+            if hi <= lo:  # (a part of text after the video's last row)
+                continue
+            frame, within = np.divmod(np.arange(lo, hi), tpf)
+            mine_index = index[j, video["first"] + lo - first:
+                               video["first"] + hi - first]
+            for base, out in mine:
+                here = (frame >= base) & (frame < base + per)
+                mine_index[here] = (len(results) * per * tpf
+                                    + (frame[here] - base) * tpf + within[here])
+                results.append(out)
+            assert (mine_index >= 0).all(), (
+                "a frame no tower call encoded", first, n)
+        while len(results) < max(most, 1):
+            results.append(self._tower_zeros(shape))
+        return ({"rows": tuple(results), "index": jnp.asarray(index),
+                 "positions": jnp.asarray(positions),
+                 "delta": jnp.asarray(delta)},), towers
+
+    def _tower_zeros(self, shape: tuple):
+        """A tower result's worth of zeros on the device (kept a shape)."""
+        import jax.numpy as jnp
+
+        if shape not in self._vision_zeros:
+            self._vision_zeros[shape] = jnp.zeros(shape, self.cfg.dtype)
+        return self._vision_zeros[shape]
 
     def _final(self, rows: int, batch, ends: bool) -> tuple:
         """A prefill call's last argument for a family that shares one slab
@@ -1205,18 +1558,20 @@ class GenerationEngine:
         if first == 0:
             self._stamp_dispatch([req])
         slots_dev = jnp.asarray(np.array([slot], np.int32))
+        visual, towers = self._visual([(0, req, first, n)], 1, self._part)
         last_logits, self.cache, routed_dev, stands_dev = self._part_jit(
             self.params, jnp.asarray(toks),
             jnp.asarray(np.array([n], np.int32)), self.cache, slots_dev,
             jnp.asarray(np.array([first], np.int32)),
-            *self._final(1, [(slot, req)], first + n >= len(req.tokens)))
+            *self._final(1, [(slot, req)], first + n >= len(req.tokens)),
+            *visual)
         if first + n < len(req.tokens):
             stands_dev.copy_to_host_async()
             _to_host_async(routed_dev)
-            return [], stands_dev, routed_dev, self._part
+            return [], stands_dev, routed_dev, self._part, towers
         firsts_dev = self._first_tokens(
             last_logits, slots_dev, routed_dev, [req])
-        return [(0, slot, req)], firsts_dev, routed_dev, self._part
+        return [(0, slot, req)], firsts_dev, routed_dev, self._part, towers
 
     def step(self) -> bool:
         """One engine iteration, software-pipelined against the device:
@@ -1496,7 +1851,12 @@ class GenerationEngine:
         waited = tracing.NO_CLOCKS
         if meter is not None:
             meter.begin(pending.chained)
-        for admissions, firsts_dev, routed_dev, padded in pending.prefills:
+        for admissions, firsts_dev, routed_dev, padded, towers in pending.prefills:
+            for mark, req in towers:  # the tower calls ahead of this call
+                _, landed, blocked = self._read_back(mark, meter)
+                waited = tuple(map(operator.add, waited, blocked))
+                if meter is not None:
+                    req.vision_s += meter.vision_landed(landed)
             firsts, landed, blocked = self._read_back(firsts_dev, meter)
             waited = tuple(map(operator.add, waited, blocked))
             if meter is not None:
@@ -1515,6 +1875,10 @@ class GenerationEngine:
                         tracing.emit_stage(
                             "engine.first_token",
                             landed - req.dispatched_at, req.trace_ctx)
+                        if req.video is not None:
+                            tracing.emit_stage(
+                                "engine.vision_encode", req.vision_s,
+                                req.trace_ctx)
                 # after the stamps: the stream thread reads them the
                 # moment it sees the token
                 req.emitted.append(int(firsts[j]))
@@ -1646,6 +2010,15 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
                                            slots, final=final)
             return logits, cache, cache.pop("routed", None)
 
+    if gen.rope_offset(cfg):
+        # a family with a tower in front: a further argument, ``visual`` (the
+        # tower's rows, where they stand, every token's rotary position, the
+        # slots' offsets; None: token ids alone, which is another program)
+        def llm_prefill(params, toks, lens, cache, slots, visual):  # noqa: F811
+            logits, cache = gen.prefill_at(params, cfg, toks, lens, cache,
+                                           slots, visual=visual)
+            return logits, cache, cache.pop("routed", None)
+
     prefill = jax.jit(
         llm_prefill,
         donate_argnums=(3,),  # scatter into the cache in place
@@ -1685,9 +2058,82 @@ def engine_programs(cfg, *, decode_chunk_steps: int, temperature: float = 0.0,
                                            slots, offsets, part_bound, final)
             return logits, cache, cache.pop("routed", None), cache["pos"][slots]
 
+    if gen.rope_offset(cfg):
+        def llm_prefill_part(params, toks, lens, cache, slots, offsets,  # noqa: F811
+                             visual):
+            logits, cache = gen.prefill_at(params, cfg, toks, lens, cache,
+                                           slots, offsets, part_bound,
+                                           visual=visual)
+            return logits, cache, cache.pop("routed", None), cache["pos"][slots]
+
     part = None if part_bound is None else jax.jit(
         llm_prefill_part, donate_argnums=(3,))
     return prefill, decode, jax.jit(llm_decode_cut, donate_argnums=(1,)), part
+
+
+def vision_program(cfg):
+    """The tower call of a family with a vision tower in front of its text
+    path (its module has ``encode_video``; None for any other family):
+    ``jit_llm_vision_encode(params, patches [frames, patches a frame, values]
+    uint8, grid=(rows, columns))`` -> ``(rows [frames, rows a frame, D], one
+    value of them)``: one program a frame grid and frames a call; the single
+    value is what the drain reads for the call's landing.  A function of the
+    config alone, as :func:`engine_programs`."""
+    import jax
+
+    from ray_tpu.models import generate as gen
+
+    fam = gen.family_of(cfg)
+    if not hasattr(fam, "encode_video"):
+        return None
+
+    def llm_vision_encode(params, patches, grid):
+        rows = fam.encode_video(params, cfg, patches, grid)
+        return rows, rows[0, 0, 0].astype("float32")
+
+    return jax.jit(llm_vision_encode, static_argnames=("grid",))
+
+
+def _base64_in_pieces(data, piece: int = 1 << 18) -> np.ndarray:
+    """``data`` (base64 as str, bytes or a memoryview of them) -> its bytes as
+    uint8, decoded ``piece`` characters at a time.  A video's patches are tens
+    of megabytes, and ONE ``a2b_base64`` over them holds the interpreter lock
+    for a tenth of a second and more, which the engine thread, in the same
+    process, then waits out between two decode chunks (every live stream's
+    pace); between two pieces the lock can change hands."""
+    import binascii
+
+    if isinstance(data, str):
+        data = data.encode("ascii")
+    out = bytearray(len(data) // 4 * 3 + 3)
+    n = 0
+    for lo in range(0, len(data), piece):
+        part = binascii.a2b_base64(data[lo:lo + piece], strict_mode=True)
+        out[n:n + len(part)] = part
+        n += len(part)
+    return np.frombuffer(out, np.uint8, n)
+
+
+def _json_beside_patches(body: bytes):
+    """A request's JSON body -> ``(the request, parsed)`` with the VALUE of
+    its ``"patches"`` key (a string of base64: no quote, no escape inside)
+    left out of the parse and put back as a memoryview of the body's own
+    bytes: ``json.loads`` over a 48 MB string is another tenth of a second
+    under the interpreter lock (:func:`_base64_in_pieces`).  A body without
+    the key, or one this cannot split, is parsed whole."""
+    import json
+
+    key = body.find(b'"patches"')
+    first = body.find(b'"', body.find(b":", key + 9) + 1) if key >= 0 else -1
+    last = body.find(b'"', first + 1) if first >= 0 else -1
+    if last < 0:
+        return json.loads(body or b"null")
+    request = json.loads(body[:first + 1] + body[last:])
+    if isinstance(request, dict) and isinstance(request.get("video"), dict) \
+            and request["video"].get("patches") == "":
+        request["video"]["patches"] = memoryview(body)[first + 1:last]
+        return request
+    return json.loads(body)
 
 
 def _decode_chunk_wrapper(gen, cfg, params, cache, tokens, active, key, *,
@@ -1873,7 +2319,8 @@ def llm_deployment(
 ):
     """Build a Serve deployment serving token generation with continuous
     batching (the ``num_tpus=1`` replica shape of BASELINE config 5, with
-    the engine replacing the plain forward)."""
+    the engine replacing the plain forward).  What a request may carry:
+    ``LLMServer.__call__``."""
     from ray_tpu import serve
 
     ekw = dict(engine_kwargs or {})
@@ -1895,26 +2342,49 @@ def llm_deployment(
 
         def __call__(self, request):
             """request: {"tokens": [int, ...], "max_new_tokens": int,
-            "stream": bool} -> {"tokens": generated ids}, or a token-per-
-            line StreamingResponse when ``stream`` is set.  Blocks this
-            replica thread; the engine interleaves all in-flight requests
-            between chunks."""
+            "stream": bool, "video": {"grid": [F, gh, gw], "patches":
+            base64 of uint8 [F x gh x gw, patch values]}} -> {"tokens":
+            generated ids}, or a token-per-line StreamingResponse when
+            ``stream`` is set.  ``video``: a family with a vision tower only;
+            its ``F x gh/2 x gw/2`` rows stand where ``tokens`` hold the video
+            placeholder id; without it a request is token ids alone.  Unknown
+            keys are ignored.  A malformed body (``tokens`` no list of ints, a
+            negative ``max_new_tokens``, a ``video`` without ``grid``, a
+            placeholder count that is not the grid's, an odd grid, ...) is
+            refused HERE, in the caller's thread, before anything is queued:
+            over HTTP a 400 whose ``error`` names the fault, through a handle
+            a ``ValueError`` (``GenerationEngine.checked``; counted in
+            ``stats()["refused"]``).  Blocks this replica thread; the engine
+            interleaves all in-flight requests between chunks."""
             from ray_tpu.serve._private.http_util import Request as _HttpReq
+            from ray_tpu.serve._private.http_util import Response as _HttpRes
 
-            if isinstance(request, _HttpReq):
-                request = request.json()
-            if isinstance(request, (list, tuple)):
-                request = {"tokens": list(request)}
+            over_http = isinstance(request, _HttpReq)
+            try:
+                if over_http:
+                    try:
+                        request = _json_beside_patches(request.body or b"")
+                    except ValueError:
+                        raise RequestRefused("body", "the body is no JSON") from None
+                if isinstance(request, (list, tuple)):
+                    request = {"tokens": list(request)}
+                if not isinstance(request, dict):
+                    raise RequestRefused("body", "the request is no object")
+                args = self.engine.checked(
+                    request.get("tokens"), request.get("max_new_tokens"),
+                    request.get("video"))
+            except RequestRefused as e:
+                if over_http:
+                    return _HttpRes({"error": str(e)}, status_code=400)
+                raise
             if request.get("stream"):
                 from ray_tpu import serve as _serve
 
-                gen = self.engine.stream(
-                    request["tokens"], request.get("max_new_tokens"))
+                gen = self.engine.stream(*args[:2], video=args[2], ready=True)
                 return _serve.StreamingResponse(
                     (f"{t}\n" for t in gen), content_type="text/plain")
-            toks = self.engine.generate(
-                request["tokens"], request.get("max_new_tokens"))
-            return {"tokens": toks}
+            return {"tokens": self.engine.generate(
+                *args[:2], video=args[2], ready=True)}
 
         def stats(self):
             return self.engine.stats()

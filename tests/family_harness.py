@@ -40,6 +40,9 @@ TINY = {
     "dots3_note": {"experts_held": (4, 8)},       # window 5, top-8 positions
     "evabyte": {},                                # window 32, chunks of 4
     "phi4_flash": {},                             # window 8, one shared slab
+    # top-8 positions over k, v slabs, 8 experts top 2 (all held), a tower of
+    # 2 blocks over frames of 4 x 4 patches
+    "keye_vl": {},
 }
 # exaone_moe where a chunk is longer than 9 steps (the one-shot path decodes a
 # whole answer in ONE chunk): a ring's flush needs ``steps <= window + 1``

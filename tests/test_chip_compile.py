@@ -363,6 +363,53 @@ def _lower_dots3_cell(chip):
     ]
 
 
+def _lower_keye_cell(chip):
+    """The serve-keye-vl-2.0-30b-a3b-pp8-video cell's programs: Keye-VL-2.0 at
+    its published widths, 6 of 48 layers (the first of eight pipeline stages)
+    with all 128 experts of each, the whole vocabulary, the tower and the
+    merger; 12 slots and the scratch row over k, v and a 64-value index key a
+    position (17,536 positions) and an offset a slot.  The decode chunk hands
+    the ragged kernel the step's selection as its mask on every layer and
+    flushes three tensors; every prompt (all are above one part) goes through
+    the 2,048 bucket's program for its first part and the part program after
+    it, both with the ``visual`` argument (four tower results of 16 frames x
+    64 rows, an index, three-axis positions, the slots' offsets); the tower
+    call takes 16 frames of 16 x 16 patches."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.serve import llm
+
+    cfg = llm.make_config("keye_vl", "2.0-30b-a3b", n_layers=6)
+    n_slots, chunk, part_tokens = 12, 16, llm.PREFILL_PART_TOKENS
+    params = _served_shapes(cfg)
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(params))
+    cache = jax.eval_shape(lambda: gen.init_cache(
+        cfg, n_slots + 1, llm.cache_positions(16384, 1024, chunk)))
+    assert set(cache) == {"k", "v", "idx_k", "rope_delta", "pos"}
+    assert cache["k"].shape == (6, 13, 4, 128, 17536)
+    assert cache["idx_k"].shape == (6, 13, 1, 64, 17536)
+    prefill, decode, cut, part = llm.engine_programs(
+        cfg, decode_chunk_steps=chunk, part_bound=llm.part_bound(16384))
+    i32 = lambda *shape: _on(chip, jax.ShapeDtypeStruct(shape, jnp.int32))  # noqa: E731
+    # frames a tower call, rows a frame, tower results a call of one part
+    per, rows = llm.VISION_CALL_PATCHES // 256, 64
+    results = 1 + -(-(part_tokens // rows + 1) // per)
+    assert (per, results) == (16, 4)
+    visual = {"rows": tuple(_on(chip, jax.ShapeDtypeStruct(
+                  (per, rows, cfg.d_model), cfg.dtype)) for _ in range(results)),
+              "index": i32(1, part_tokens), "positions": i32(1, 3, part_tokens),
+              "delta": i32(1)}
+    return [
+        part.lower(_on(chip, params), i32(1, part_tokens), i32(1),
+                   _on(chip, cache), i32(1), i32(1), visual),
+        *_lower_decodes(chip, decode, cut, params, cache, n_slots),
+        prefill.lower(_on(chip, params), i32(1, part_tokens), i32(1),
+                      _on(chip, cache), i32(1), visual),
+        llm.vision_program(cfg).lower(
+            _on(chip, params), _on(chip, jax.ShapeDtypeStruct(
+                (per, 256, 588), jnp.uint8)), grid=(16, 16)),
+    ]
+
+
 def _lower_evabyte_cell(chip):
     """The serve-evabyte-6.5b-pp4-code cell's programs: EvaByte at its
     published widths, 8 of 32 layers (one of four pipeline stages), 16 slots
@@ -537,6 +584,7 @@ PROGRAMS = {
     "serve_engine_dots3_cell": _lower_dots3_cell,
     "serve_engine_evabyte_cell": _lower_evabyte_cell,
     "serve_engine_phi4_flash_cell": _lower_phi4_flash_cell,
+    "serve_engine_keye_cell": _lower_keye_cell,
     "bert_base_forward": _lower_bert,
     "flash_attention_forward": lambda chip: _lower_flash(chip, "forward"),
     "flash_attention_backward": lambda chip: _lower_flash(chip, "backward"),
@@ -744,7 +792,7 @@ def test_program_compiles_for_v5e(compiled, name):
         assert "flash_attention_bwd" in band and needs[1] < 2 * 2**30, needs
     if name in ("serve_engine_exaone_cell", "serve_engine_kimi_cell",
                 "serve_engine_dots3_cell", "serve_engine_evabyte_cell",
-                "serve_engine_phi4_flash_cell"):
+                "serve_engine_phi4_flash_cell", "serve_engine_keye_cell"):
         # ONE program for every part of every prompt above 2,048 tokens, under
         # the name a trace's readers sum the prefill programs by; the flash
         # kernel is in it on every layer that reads a slab
@@ -768,6 +816,10 @@ def test_program_compiles_for_v5e(compiled, name):
             # ragged kernel, for G = 1 (GPT-2) and G > 1 (Llama) alike
             assert text.count("tpu_custom_call") >= 1
             n_params = len(jax.tree.leaves(decode.args_info[0][0]))
+            if name == "serve_engine_keye_cell":
+                # (the tower's leaves are no argument of a decode program:
+                # the compiled program's numbering skips them)
+                n_params -= len(jax.tree.leaves(decode.args_info[0][0]["vision"]))
             n_cache = len(jax.tree.leaves(decode.args_info[0][1]))
             aliased = re.search(
                 r"input_output_alias=\{(.*?) \}, entry", text).group(1)
@@ -884,6 +936,50 @@ def test_program_compiles_for_v5e(compiled, name):
             assert not re.search(r"f32\[1,128,\d{4,5},\d{4,5}\]", prefill.as_text())
         assert programs[0].memory_analysis().temp_size_in_bytes < 2.6e9
         assert all(5.5e9 < need < 8.5e9 for need in needs), needs
+    if name == "serve_engine_keye_cell":
+        # the evidence for 12 slots (ISSUE 59's arithmetic: 9.64 GB of weights,
+        # 2.98 GB of cache, 12.6 GB resident): the decode chunk, whole and cut,
+        # plans 11.73 GB of arguments (the tower's 0.89 GB are no argument of
+        # it) and 0.18 GB of temporaries; a 2,048-token part over a static
+        # 16,384 positions 0.99 GB of temporaries (12.74 GB in all: the
+        # largest program); the 2,048 bucket 0.21; the tower call 0.89 GB of
+        # arguments and 0.03 of temporaries.  The ragged kernel SIX times a
+        # decode step, under a name of its own and handed the step's selection
+        # (f32[13, 144, 128]: a slot's 137 tiles in groups of 8), the flush
+        # kernel over k, v AND idx_k, the grouped matmuls over 128 experts a
+        # layer read where they lie; the flash kernel on every layer of both
+        # prefill programs (the part's with the selection as its operand; no
+        # [32, 2048, 16384] score tensor exists)
+        for decode in programs[1:3]:
+            text = decode.as_text()
+            kernels = [line for line in text.splitlines() if re.search(
+                r"%ragged_sparse_gqa_attention[.\d]* = ", line)]
+            assert len(kernels) == 6 and all(
+                "f32[13,144,128]" in line and "attention.gqa_sparse" in line
+                for line in kernels), len(kernels)
+            assert not re.search(r"%ragged_decode_attention[.\d]* = ", text)
+            flushes = [line for line in text.splitlines()
+                       if re.search(r"%cache_flush[.\d]* = ", line)]
+            assert len(flushes) == 3, flushes
+            assert sum("bf16[6,13,4,128,17536]" in line for line in flushes) == 2
+            assert sum("bf16[6,13,1,64,17536]" in line for line in flushes) == 1
+            assert text.count("tpu_custom_call") >= 6 + 3 + 6 * 3
+            _expert_weights_are_read_where_they_lie(decode, 6)
+            assert decode.memory_analysis().temp_size_in_bytes < 0.3e9
+            assert 11.6e9 < decode.memory_analysis().argument_size_in_bytes < 11.9e9
+        for prefill in (programs[0], programs[3]):
+            assert prefill.as_text().count("flash_attention_fwd") >= 6
+            assert not re.search(r"f32\[1,32,2048,\d{4,5}\]", prefill.as_text())
+            _expert_weights_are_read_where_they_lie(prefill, 6)
+        assert programs[0].memory_analysis().temp_size_in_bytes < 1.2e9
+        assert programs[3].memory_analysis().temp_size_in_bytes < 0.4e9
+        tower = programs[4]
+        assert tower.as_text().startswith("HloModule jit_llm_vision_encode")
+        assert all(scope in tower.as_text() for scope in (
+            "vision.patch_embed", "vision.attention", "vision.mlp", "vision.merge"))
+        assert tower.memory_analysis().temp_size_in_bytes < 0.2e9
+        assert 0.85e9 < tower.memory_analysis().argument_size_in_bytes < 0.95e9
+        assert all(11.7e9 < need < 13.0e9 for need in needs[:4]), needs
     if name == "serve_engine_phi4_flash_cell":
         # ONE slab read by eight layers: the ragged kernel under a name of its
         # own, once for the owner and once in the rolled (GMU, cross) loop;

@@ -489,8 +489,11 @@ def test_sigkill_worker_stderr_retrievable_after_death(fast_ship):
         grep="final-stderr-needle", errors=True)["records"])
     stream = rows[0]["stream"]
     # the stream is retired (its worker is dead) but its tail still serves
+    # (retirement follows the death by a reaper's beat: wait for it, as for
+    # the records above; under a loaded suite the stream was once listed first)
     meta = _wait_for(lambda: [
-        s for s in state.list_logs() if s["stream"] == stream])[0]
+        s for s in state.list_logs()
+        if s["stream"] == stream and s["retired"]])[0]
     assert meta["retired"]
     tail = state.tail_log(stream, n=50, errors=True)
     assert any("final-stderr-needle" in ln for ln in tail)

@@ -34,6 +34,8 @@ EXPERT_FAMILIES = {
     "dots3_note": (lambda t: t["layers"], lambda t: t["layers"][1], _sigmoid),
     "granite_hybrid": (lambda t: [t["mamba"], *t["attention"]],
                        lambda t: t["attention"][0], _softmax),
+    # (every expert held, no shared expert)
+    "keye_vl": (lambda t: t["layers"], lambda t: t["layers"][1], _softmax),
 }
 
 
@@ -87,7 +89,8 @@ def test_an_engine_serves_from_gate_and_up_side_by_side(family):
         lambda h, p: sparse_ffn(h, p, cfg, None))(h, served_layer(p))
     sel, gates = route(h, p, cfg)
     first, n_held = cfg.experts_held
-    want = _swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"])
+    want = _swiglu(h, p["sw_gate"], p["sw_up"], p["sw_down"]
+                   ) if "sw_gate" in p else jnp.zeros_like(h)
     for e in range(n_held):
         g = jnp.where(sel == first + e, gates, 0.0).sum(-1)
         want = want + g[..., None] * _swiglu(
