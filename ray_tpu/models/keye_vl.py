@@ -35,7 +35,10 @@ learned scale; no bias on a projection):
   8, renormalised over the 8 (the same gates as the softmax over the chosen
   logits: :func:`ray_tpu.ops.moe.route_softmax_top_k`); ``x += sum_i g_i
   W_down_i(silu(W_gate_i h) * W_up_i h)`` at width 768, no shared expert
-  (:func:`ray_tpu.ops.moe.held_experts_ffn` with every expert held).
+  (:func:`ray_tpu.ops.moe.held_experts_ffn` with every expert held, and told
+  so: a prefill call's 16,384 pairs go through the two grouped matmuls as ONE
+  block and a token's 8 rows are gathered back and summed, no loop over trips
+  of 256 rows; a stage handed a SHARE of the experts keeps the trips).
 - final RMSNorm, an untied head.
 
 Positions (Qwen2-VL's ``get_rope_index``, ASSUMED): a text token has ``t = h =
@@ -126,6 +129,14 @@ class KeyeVLConfig:
 
     # a slot's rotary position is its cached length plus an offset of its own
     rope_delta_cache = True
+
+    @property
+    def all_experts_held(self) -> bool:
+        """Every expert the router chooses from is on this chip (a pipeline
+        stage holds whole layers): the expert layer's dispatch and the
+        engine's counter of it both read this
+        (:func:`ray_tpu.ops.moe.dispatch_trips`)."""
+        return self.experts_held == (0, self.n_experts)
 
     @staticmethod
     def keye_vl_2_30b(**kw) -> "KeyeVLConfig":
@@ -303,7 +314,8 @@ def _sparse_ffn(h, p, cfg: KeyeVLConfig, valid):
     with jax.named_scope("moe.expert_ffn"):
         y, tokens = held_experts_ffn(
             flat, experts, gates, p["ew_gate_up"], p["ew_down"],
-            first_expert=cfg.experts_held[0], valid=valid)
+            first_expert=cfg.experts_held[0], valid=valid,
+            all_held=cfg.all_experts_held)
     return y.reshape(B, T, D).astype(h.dtype), {
         "tokens": tokens, "touched": (tokens > 0).sum().astype(jnp.int32)}
 

@@ -200,18 +200,32 @@ _ONE_BLOCK_PAIRS = 1024
 # 22.9 / 38.9 -> 37.2 / - / 135.1 -> 132.8), 30.6 / 43.7 / 73.1 / 135.9 at
 # 1,024 in slices; K-EXAONE's 128 x 2 / 256 / 512 / 1,024 / 2,048: 21.9 /
 # 22.9 / 28.1 / 42.9 / 62.3 as it stood, 17.1 / 17.7 / 23.5 / 36.7 / 55.2 at
-# 256
+# 256.  All of that is a chip that holds a SHARE.  A stage that holds EVERY
+# expert of its layers (Keye-VL's: 128 of 128, a 2,048-token part 16,384 pairs,
+# all held) would walk 64 trips a layer, ~0.12 - 0.13 ms each (two grouped
+# matmuls over 128 groups of which two or three have rows 57 us, the loop's
+# copies 37, the gather, the gates and the scatter-add the rest), and needs no
+# trip: every pair of a token is here, so nothing is ADDED into the result
+# (``all_held``: one block whatever the pairs).  The chip, one part, ms a layer
+# in ``moe.expert_ffn`` (PERF.md section 6, PR 60): a first part 8.6 -> 5.4, a
+# part at offset 4,096 7.5 -> 3.9 (the programs 63.3 -> 43.0 and 98.9 -> 75.4);
+# the layer alone 9.2 -> 6.5.  What is left is the grouped matmuls themselves
+# (2.7 - 4.3 ms a layer where the experts' bytes need 1.5: the compiler tiles
+# 512 rows for groups of ~128)
 _TRIP_ROWS = 256
 
 
-def dispatch_trips(pairs: int, held):
+def dispatch_trips(pairs: int, held, all_held: bool = False):
     """``(block, trips)`` of one dispatch of ``pairs`` (token, expert) pairs of
     which ``held`` are this chip's: the rows one trip hands the grouped
     matmuls, and the trips it takes (``block * trips`` rows computed).
     ``held`` may be a count, an array of counts (a layer each) or a traced
     value: the device's loop and the host's counter
-    (``perf_stats()["moe"]["prefill"]["rows_computed"]``) both ask here."""
-    if pairs <= _ONE_BLOCK_PAIRS:
+    (``perf_stats()["moe"]["prefill"]["rows_computed"]`` and ``["trips"]``)
+    both ask here.  ``all_held`` (static): the chip holds EVERY expert of the
+    layer, so the pairs go as one block whatever their number
+    (:func:`held_experts_ffn`); a block that is all the pairs runs no loop."""
+    if all_held or pairs <= _ONE_BLOCK_PAIRS:
         return pairs + -pairs % 8, 1
     return _TRIP_ROWS, -(-held // _TRIP_ROWS)
 
@@ -306,6 +320,7 @@ def held_experts_ffn(
     x: jax.Array, experts: jax.Array, gates: jax.Array, w_gate_up: jax.Array,
     w_down: jax.Array, *, first_expert: int = 0,
     valid: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
+    all_held: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of a top-k SwiGLU expert layer, no token dropped.
 
@@ -329,16 +344,25 @@ def held_experts_ffn(
             0 for all but this layer's: a group without rows costs nothing,
             where a layer's weights sliced out to feed the kernel would be a
             copy of them (170 MB a layer a decode step at Granite's widths).
+        all_held: a static fact of the caller's deployment: ``first_expert``
+            is 0 and the ``n_held`` experts are ALL the router chooses from
+            (a pipeline stage that holds whole layers).  It picks the way
+            back to the tokens (below), never the result.
 
     The ``N * k`` (token, expert) pairs are sorted by held expert (pairs of
     absent experts last) and the held ones go through TWO grouped matmuls
     (``lax.ragged_dot``: on a TPU one kernel over the rows of each group, no
     capacity, and a group without rows costs nothing, not even the read of
     its weights): gate and up as one call whose result is split into its
-    halves for ``silu(g) * u``, then down.  Up to one block of pairs
-    (``_ONE_BLOCK_PAIRS``: a decode step) all ``M`` rows take one trip and a
-    token's ``k`` rows are gathered back and summed; of more pairs (a prefill call) the HELD ones take
-    ``_TRIP_ROWS`` rows a trip, as many trips as they need
+    halves for ``silu(g) * u``, then down.  Two ways back to the tokens.
+    ONE BLOCK: all ``M`` rows go through the two grouped matmuls once and a
+    token's ``k`` rows are gathered back by the inverse permutation and summed
+    under its gates in float32 (no loop, nothing added into anything): taken
+    up to one block of pairs (``_ONE_BLOCK_PAIRS``: a decode step), and by a
+    dispatch of ANY size when ``all_held``: every pair is then this chip's,
+    so the block computes no row a loop would have skipped.  TRIPS: of more
+    pairs (a prefill call) on a chip that holds a SHARE of the experts, the
+    HELD ones take ``_TRIP_ROWS`` rows a trip, as many trips as they need
     (:func:`dispatch_trips`: a runtime count), each added into the result
     where its rows' tokens are, in float32: what a trip gathers, multiplies
     and adds back follows what this chip holds (a 32nd of the pairs where 32
@@ -365,8 +389,8 @@ def held_experts_ffn(
         return _experts_block(x[pairs // top_k], w_gate_up, w_down,
                               widen(sizes), jax.nn.silu)
 
-    if M <= _ONE_BLOCK_PAIRS:
-        block, _ = dispatch_trips(M, 0)
+    if all_held or M <= _ONE_BLOCK_PAIRS:
+        block, _ = dispatch_trips(M, 0, all_held)
         # the TPU's grouped-matmul kernel takes whole sublane tiles of rows
         # (a list of another length, 49 rows x 10, is computed densely:
         # every row against every held expert); rows past the groups' sizes

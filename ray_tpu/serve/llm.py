@@ -1150,7 +1150,10 @@ class GenerationEngine:
             # ``decode_dispatches`` chunks, whole or cut; ``prefill`` also
             # ``rows_computed``: the rows the calls' grouped matmuls were
             # handed, summed over the layers, so that ``tokens`` summed over
-            # it is how full the prefill dispatches were).
+            # it is how full the prefill dispatches were, and ``trips``: the
+            # loop trips those calls ran, a layer each; 0 for a dispatch that
+            # went as one block: a narrow call's, and any call's of a stage
+            # that holds every expert of its layers).
             # Cumulative, like cache_tiles
             "moe": moe,
             # a family with recurrent layers: the rows of state the decode
@@ -1788,8 +1791,9 @@ class GenerationEngine:
         None where the family counts nothing) to the totals.  A prefill call
         also counts the rows its grouped matmuls were handed
         (``rows_computed``; the held pairs, ``tokens``, over it is how full
-        the dispatch was): host arithmetic over what the call returned, by the
-        function the device's loop takes its trips from."""
+        the dispatch was) and the loop trips they took (``trips``): host
+        arithmetic over what the call returned, by the function the device's
+        loop takes its trips from."""
         if counts is None:
             return
         counts = {k: np.asarray(v, np.int64) for k, v in counts.items()}
@@ -1797,10 +1801,14 @@ class GenerationEngine:
             from ray_tpu.ops.moe import dispatch_trips
 
             held = counts["tokens"].sum(-1)  # a sparse layer each
+            pairs = padded * self.cfg.experts_per_token
             block, trips = dispatch_trips(
-                padded * self.cfg.experts_per_token, held)
+                pairs, held, getattr(self.cfg, "all_experts_held", False))
             counts["rows_computed"] = np.broadcast_to(
                 block * trips, held.shape).sum()
+            # (a block that is all the pairs went at once: no loop ran)
+            counts["trips"] = np.broadcast_to(
+                trips if block < pairs else 0, held.shape).sum()
         with self._lock:
             had = self._routed[phase]
             self._routed[phase] = counts if had is None else {

@@ -971,8 +971,16 @@ def test_program_compiles_for_v5e(compiled, name):
             assert prefill.as_text().count("flash_attention_fwd") >= 6
             assert not re.search(r"f32\[1,32,2048,\d{4,5}\]", prefill.as_text())
             _expert_weights_are_read_where_they_lie(prefill, 6)
+            # every expert is held: a part's 16,384 pairs go through the
+            # grouped matmuls as ONE block (``held_experts_ffn(all_held=)``)
+            # and a token's rows are gathered back, nothing is added into
+            # the result trip by trip (the parent's six scatter-adds)
+            assert "bf16[16384,2048]" in prefill.as_text()
+            assert not re.search(r"\bscatter\(", prefill.as_text())
+        # (the block's temporaries: 0.21 -> 0.46 GB in the 2,048 bucket's
+        # program; the part's largest are elsewhere: 0.99 -> 0.97 GB)
         assert programs[0].memory_analysis().temp_size_in_bytes < 1.2e9
-        assert programs[3].memory_analysis().temp_size_in_bytes < 0.4e9
+        assert programs[3].memory_analysis().temp_size_in_bytes < 0.6e9
         tower = programs[4]
         assert tower.as_text().startswith("HloModule jit_llm_vision_encode")
         assert all(scope in tower.as_text() for scope in (

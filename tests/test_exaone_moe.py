@@ -158,6 +158,15 @@ def _a_32nd_held():
     return dict(experts=experts.astype(np.int32))
 
 
+def _all_six(even: bool):
+    """90 tokens x top-3 (270 pairs) over the six experts the dispatch test
+    holds: each token three of the six, or expert 2 and two of 0, 4 and 5."""
+    rng = np.random.RandomState(60)
+    pick = (lambda: rng.choice(6, 3, replace=False)) if even else (
+        lambda: np.append(2, rng.choice([0, 4, 5], 2, replace=False)))
+    return dict(experts=np.stack([pick() for _ in range(90)]).astype(np.int32))
+
+
 # (token, expert) pairs through ``held_experts_ffn``: the routing, the rows of
 # a trip and the pairs of one block (None: the module's), and the trips the
 # dispatch has to take
@@ -194,6 +203,18 @@ DISPATCHES = {
     # a pair list that is no multiple of 8 rows (a decode step's 49 x 10)
     "one_block_filled_to_whole_tiles": dict(
         experts=np.tile(np.array([1, 3, 8], np.int32), (13, 1)), trips=1),
+    # a stage that holds EVERY expert (``all_held``): more pairs than a block
+    # go as ONE block, under an even router ...
+    "every_expert_held_even": dict(
+        **_all_six(even=True), one_block=100, trip_rows=64, all_held=True, trips=1),
+    # ... one that leaves experts 1 and 3 without a row and gives 2 every token
+    "every_expert_held_some_without_a_row_one_with_most": dict(
+        **_all_six(even=False), one_block=100, trip_rows=64, all_held=True,
+        trips=1),
+    # ... and with padding rows among the tokens
+    "every_expert_held_padding_rows": dict(
+        **_all_six(even=True), valid=(np.arange(90) % 5 != 0) & (np.arange(90) < 79),
+        one_block=100, trip_rows=64, all_held=True, trips=1),
 }
 
 
@@ -223,10 +244,12 @@ def test_no_token_is_dropped_when_every_token_takes_the_same_experts(
     loop = jax.lax.fori_loop
     monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, *a: (
         ran.append(int(hi)), loop(lo, hi, *a))[1])
-    y, tokens = moe.held_experts_ffn(
-        x, experts, gates, moe.gate_up_side_by_side(
+    all_held = want.get("all_held", False)
+    layer_ffn = partial(
+        moe.held_experts_ffn, x, experts, gates, moe.gate_up_side_by_side(
             {"ew_gate": w_gate, "ew_up": w_up})["ew_gate_up"], w_down,
         valid=valid, layer=None if layer is None else jnp.int32(layer))
+    y, tokens = layer_ffn(all_held=all_held)
     if layer is not None:
         w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
     took = np.asarray((experts[:, :, None] == jnp.arange(E)) & valid[:, None, None])
@@ -236,12 +259,21 @@ def test_no_token_is_dropped_when_every_token_takes_the_same_experts(
         * ((jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e])
         for e in range(E))
     assert np.abs(np.asarray(y) - np.asarray(dense)).max() < 1e-5
-    block, trips = moe.dispatch_trips(N * top_k, int(took.sum()))
+    block, trips = moe.dispatch_trips(N * top_k, int(took.sum()), all_held)
     assert trips == want["trips"]
-    if N * top_k > moe._ONE_BLOCK_PAIRS:
+    if N * top_k > moe._ONE_BLOCK_PAIRS and not all_held:
         assert ran == [trips] and block == moe._TRIP_ROWS
     else:
         assert ran == [] and block == N * top_k + -(N * top_k) % 8
+    if all_held:
+        # the argument left at its default walks the same pairs in trips:
+        # the same result and counts; a padding row's part of ``y`` is 0
+        assert N * top_k > moe._ONE_BLOCK_PAIRS
+        y_loop, tokens_loop = layer_ffn()
+        assert ran == [moe.dispatch_trips(N * top_k, int(took.sum()))[1]]
+        assert tokens_loop.tolist() == tokens.tolist()
+        assert np.abs(np.asarray(y) - np.asarray(y_loop)).max() < 1e-5
+        assert not np.asarray(y)[~np.asarray(valid)].any()
 
 
 def _eqns(jaxpr, name):
@@ -299,6 +331,41 @@ def test_dispatch_trips(pairs, held, want):
     assert np.broadcast_to(block * trips, (2,)).tolist() == [want[0] * want[1]] * 2
     assert int(jax.jit(lambda h: jnp.asarray(
         moe.dispatch_trips(pairs, h)[1]))(held)) == want[1]
+
+
+@pytest.mark.parametrize("pairs,held,want", [
+    # a 2,048-token part with top-8 on a stage that holds every expert: all
+    # its pairs at once, whatever came back as held (padding rows are not)
+    (16384, 16384, (16384, 1)), (16384, 16000, (16384, 1)), (16384, 0, (16384, 1)),
+    # filled up to whole sublane tiles, as a narrow dispatch is
+    (2050, 2050, (2056, 1)), (1025, 7, (1032, 1)),
+    # a decode step: one block with or without the fact
+    (264, 264, (264, 1)), (490, 490, (496, 1)),
+])
+def test_dispatch_trips_when_every_expert_is_held(pairs, held, want):
+    assert moe.dispatch_trips(pairs, held, all_held=True) == want
+    # a layer each, as the engine's counter asks
+    block, trips = moe.dispatch_trips(pairs, np.array([held, held]), True)
+    assert np.broadcast_to(block * trips, (2,)).tolist() == [want[0]] * 2
+
+
+@pytest.mark.parametrize("n,top_k,rows", [(2048, 8, 16384), (257, 8, 2056)])
+def test_every_expert_held_lowers_without_a_loop(n, top_k, rows):
+    """What lowers for a prefill call on a stage that holds every expert: NO
+    ``while`` and no scatter-add, exactly two ``ragged_dot`` over all the
+    call's pairs (filled to whole sublane tiles); the same shapes without the
+    fact keep their loop (the case above this one's neighbour)."""
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((n, 16), f32), ((n, top_k), i32), ((n, top_k), f32),
+        ((4, 16, 16), f32), ((4, 8, 16), f32))]
+    jaxpr = jax.make_jaxpr(partial(moe.held_experts_ffn, all_held=True))(
+        *shapes).jaxpr
+    dots = _eqns(jaxpr, "ragged_dot") or _eqns(jaxpr, "ragged_dot_general")
+    assert [eqn.invars[0].aval.shape[0] for eqn in dots] == [rows] * 2
+    assert not _eqns(jaxpr, "while") and not _eqns(jaxpr, "scatter-add")
+    assert len(_eqns(jax.make_jaxpr(moe.held_experts_ffn)(*shapes).jaxpr,
+                     "while")) == 1
 
 
 @pytest.mark.parametrize("t,window", [(256, 128), (384, 100), (48, 8), (128, 128)])
@@ -433,7 +500,14 @@ def test_engine_admits_under_a_token_budget_in_order(model, monkeypatch, case):
         4 * 64 if padded == 16 else int((-(-held // 16) * 16).sum())
         for held, padded in calls)
     assert np.sum(routed["prefill"]["tokens"]) <= routed["prefill"]["rows_computed"]
+    # ``trips``: the loop trips of those calls, a layer each (a chip that
+    # holds a share: the calls of more than one block, as their held pairs
+    # need; one block runs no loop)
+    assert routed["prefill"]["trips"] == sum(
+        0 if padded == 16 else int((-(-held // 16)).sum())
+        for held, padded in calls)
     assert "rows_computed" not in routed["decode"]
+    assert "trips" not in routed["decode"]
 
 
 @pytest.mark.parametrize("n_slots,buckets,rows", [

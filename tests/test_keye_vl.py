@@ -26,6 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+import family_harness
 import pytest
 from family_harness import (
     TINY,
@@ -42,6 +43,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import keye_vl_ref as ref  # noqa: E402
 from ray_tpu.models import generate as gen  # noqa: E402
 from ray_tpu.models import keye_vl as kv  # noqa: E402
+from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.serve import llm  # noqa: E402
 from ray_tpu.serve.llm import RequestRefused, make_config  # noqa: E402
 
@@ -407,6 +409,58 @@ def test_parts_planned_in_one_tick_keep_their_own_tower_calls(model, small_parts
     assert worst_gap(partial(ref_logits, model, (video, GRID)),
                      [prompt], [fut.result(0)]) < F32_TOL
     assert eng.perf_stats()["vision"]["calls"] == 5
+    eng.stop()
+
+
+@pytest.mark.parametrize("held", [(0, 8), (0, 4)],
+                         ids=["every_expert_held", "a_share_held"])
+def test_the_engine_counts_a_prefill_calls_rows_and_trips(
+        model, small_parts, monkeypatch, held):
+    """``perf_stats()["moe"]["prefill"]``: a stage that holds EVERY expert
+    sends a call of more than one block of pairs (made 16 here; a part of 16
+    tokens is 32) through the grouped matmuls at once: ``trips`` 0 and the
+    call's pairs as ``rows_computed``, a layer each, and the answer is still
+    the reference's.  The same family handed a SHARE walks its held pairs in
+    trips (of 8 rows here), and the counters say what the loop was handed."""
+    monkeypatch.setattr(moe, "_ONE_BLOCK_PAIRS", 16)
+    monkeypatch.setattr(moe, "_TRIP_ROWS", 8)
+    # the block's size is read at trace time: programs of a path of their own
+    monkeypatch.setattr(family_harness, "PATH", "one_block_is_16_pairs")
+    eng, cfg, _ = engine("keye_vl", changed=(("experts_held", held),),
+                         n_slots=3, max_new_tokens=6, decode_chunk_steps=4,
+                         prefill_buckets=(16, 32, 64), prefill_token_budget=64)
+    assert cfg.all_experts_held == (held == (0, 8))
+    landed, count = [], eng._count_routed
+    monkeypatch.setattr(eng, "_count_routed", lambda phase, counts, *a, **kw: (
+        landed.append((phase, counts, kw.get("padded"))),
+        count(phase, counts, *a, **kw))[1])
+    prompt, video = a_prompt(cfg, 5, 9, 7, 1), a_video(cfg, 9, 1)
+    fut = eng.submit(prompt, 6, video={
+        "grid": [9, *GRID], "patches": video.reshape(-1, video.shape[-1])})
+    for _ in range(50):
+        if fut.done():
+            break
+        eng.step()
+    routed = eng.perf_stats()["moe"]["prefill"]
+    calls = [(np.asarray(c["tokens"]).sum(-1), padded)
+             for phase, c, padded in landed if phase == "prefill"]
+    # 48 tokens in parts of 16: the bucket's call, three rows wide, then two
+    wide = [padded for _, padded in calls]
+    assert wide == [3 * 16, 16, 16]
+    pairs = cfg.n_layers * cfg.experts_per_token * sum(wide)
+    assert all(counted.shape == (cfg.n_layers,) for counted, _ in calls)
+    if cfg.all_experts_held:
+        assert worst_gap(partial(ref_logits, model, (video, GRID)),
+                         [prompt], [fut.result(0)]) < F32_TOL
+        assert routed["trips"] == 0
+        assert routed["rows_computed"] == pairs
+        # (the bucket's two unfilled rows are 1-token dummies, routed too)
+        assert np.sum(routed["tokens"]) == (len(prompt) + 2) * 2 * cfg.n_layers
+    else:
+        trips = sum(int((-(-counted // 8)).sum()) for counted, _ in calls)
+        assert 0 < routed["trips"] == trips
+        assert routed["rows_computed"] == 8 * trips < pairs
+    assert "trips" not in eng.perf_stats()["moe"]["decode"]
     eng.stop()
 
 
