@@ -24,7 +24,8 @@ evabyte_ref.py`` is the same reading written plainly):
   ROADMAP R18); :func:`apply` gives every head's logits on request.
 
 What it asks of :mod:`ray_tpu.models.generate`: a cache that is COMPACTED
-while a request is live (``cfg.summary_cache``: the sixth kind there).  The
+while a request is live (``cfg.summary_cache``: WINDOW and SUMMARY rows of
+``cache_layout`` there).  The
 parameters are stacked (``params["blocks"]``, leaves ``[L, ...]``) and made in
 ``cfg.dtype``, a leaf at a time: the served model never exists in float32.
 """

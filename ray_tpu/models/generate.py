@@ -7,90 +7,53 @@ plain forward (``python/ray/serve/_private/replica.py:250`` calls the user
 callable); generation/KV-cache is delegated to user code.  Here decode is a
 first-class TPU path, designed for XLA:
 
-- **Kinds of cache** (:func:`init_cache`), chosen by what the family's
-  config says of its layers, never by its name; three hold something per
-  POSITION (one of them, the latent row, in a slab or in a ring, and with a
-  second tensor beside it where the layer SELECTS what it reads: five kinds
-  of cached tensor so far), the fourth per REQUEST; every one of those is
-  written once and keeps its meaning.  The last (a SIXTH kind of cached
-  tensor) is COMPACTED while the request is live:
+- **What a config's cache holds is ONE table** (:func:`cache_layout`: a row
+  a cached tensor, with whose it is, how it is arranged and what a position
+  of it holds; its docstring lists the rows).  :func:`init_cache`, a prompt's
+  writes (:func:`_write_prompt`), a decode chunk's (:func:`decode_chunk`'s
+  tail) and the serve engine's byte counts walk it, and no other line spells
+  a tensor's name.  What the table cannot say is how each arrangement is
+  READ, which is where the kernels are chosen:
 
-  1. a SLAB for a full layer of K and V per KV head: ``k``, ``v`` ``[L, B,
-     KV, dh, S]``, a position holds ``2 x KV x dh`` values, every position
-     kept.  Prefill writes a prompt's columns whole (or a PART's at its
-     offset: below); a decode chunk flushes its ``steps`` columns to
-     ``pos0[b]`` once, both tensors.
-  2. a RING for a window layer (``cfg.sliding_windows``): ``k_ring``,
-     ``v_ring`` ``[L_window, B, KV, dh, ring]`` (:func:`ring_positions`: twice
-     the window, in whole tiles), position ``j`` at ``j % ring``, so a slot
-     costs the ring however long its context.  Prefill
-     keeps each row's last ring; the chunk's flush writes modulo the ring by
-     a 0/1 matrix, both tensors.
-  3. a LATENT slab (``cfg.latent_cache``; multi-head latent attention): ONE
-     tensor ``c`` ``[L, B, 1, row, S]``, a position holds one row (the normed
-     latent, then the rotated key all heads share: 512 + 64 values as
-     published) and there is NO ``v``: the row is every head's key and its
-     first values are the position's value vector (the absorbed decode form
-     of :mod:`ray_tpu.models.kimi_k2`).  Prefill (un-absorbed, k and v a head
-     for the call only) keeps the rows its block hands over; the chunk's
-     flush writes ONE tensor.  Two things a family may add
-     (:mod:`ray_tpu.models.dots3_note`).  (a) A full layer that SELECTS the
-     positions a query attends (``cfg.index_cache``; :mod:`ray_tpu.ops.dsa`)
-     caches an INDEX KEY beside each row: ``idx_k`` ``[L_full, B, 1, key,
-     S]`` (128 values as published), a fifth cached tensor, written by
-     prefill and flushed as ``c`` is.  A decode step scores the slab's index
-     keys below the slot's live length AND the chunk-local ones of the steps
-     up to its own, keeps the ``top-k`` under ONE exact threshold
-     (:func:`_select`), and its attention's softmax runs over those alone:
-     the latent kernel still reads every live tile and is handed the
-     selection as a mask; a cut chunk selects as a whole one does.  (b) Its
-     WINDOW layers keep latent rows of their own width in a RING
-     (``cfg.window_latent_cache``): ``c_ring`` ``[L_window, B, 1, row,
-     ring]`` (1,088 values as published), kind 2's ring holding kind 3's
-     rows, read as kind 3's slab is (the latent kernel over the tiles of the
-     entries a live slot's ring holds, the step's window its mask; masked
-     einsums over every row's whole ring anywhere else) and flushed by the
-     0/1 matrix.
-  4. a STATE for a recurrent layer (``cfg.state_cache``; a Mamba-2 mixer,
-     ``RECURRENT`` in ``cfg.sliding_windows``): ``ssm`` ``[L_state, B, tiles,
-     state, heads a tile x head values]`` in float32 and ``conv`` ``[L_state, d_conv - 1, B,
-     width]``, the layer's last inputs.  NOTHING here grows with the
-     position: a slot costs the same at position 10 and at 100,000.  Prefill
-     writes a slot's state WHOLE, as it stands after the prompt's last real
-     token (which is what makes a reused slot clean); a decode step
-     overwrites it in place, and only for the rows that take the step
-     (:func:`ray_tpu.ops.ssm.state_update`: lowered for a TPU a Pallas kernel
-     over the slots that were active when the chunk began); a cut chunk has
-     advanced it ``n`` steps; there is nothing to flush.  The family's other
-     layers keep K and V in the slab (1).  A Mamba-1 state
-     (:mod:`ray_tpu.models.phi4_flash`: a decay per channel AND state
-     element) is ``[L_state, B, N, rows, 128]``, the state index leading and
-     the channels on the lanes, and has an update of its own over the same
-     plan (:func:`ray_tpu.ops.ssm.selective_state_update`).
-  5. a WINDOW AND ITS SUMMARIES for a layer that compacts
-     (``cfg.summary_cache``: ``(window, chunk)``; :mod:`ray_tpu.models.evabyte`,
-     :mod:`ray_tpu.ops.eva`), two pairs of tensors a layer that trade places
-     as a request grows.  (a) The CURRENT window's exact ``k``, ``v`` ``[L, B,
-     KV, dh, window + slack]`` (:func:`window_positions`), position ``j`` at
-     place ``j % window``, of which a query at ``i`` reads places ``0 .. i %
-     window``: BLOCK-ALIGNED, not a ring: when ``i`` reaches a multiple of the
-     window every place is dead at once (none of :func:`_ring_mask`,
-     :func:`_ring_holds`, :func:`ring_positions` describes it).  (b) The
-     SUMMARIES ``ks``, ``vs`` ``[L, B, KV, dh, rows]`` (:func:`summary_rows`):
-     one pooled key and value a head a ``chunk`` of positions of every window
-     the slot has FILLED, of which a query at ``i`` reads the first ``(i //
-     window) x (window // chunk)`` rows.  The moment a slot's window fills, its
-     positions are pooled (:func:`ray_tpu.ops.eva.pool_chunks`, by the layer's
-     learned vectors), appended to the slab, and the window starts again at
-     place 0: in a prefill call for every window the call's tokens fill
-     (:func:`_keep_compacted`), in decode WITH THE CHUNK'S FLUSH
-     (:func:`_roll_over`), which is why no chunk may straddle a window's end:
-     the caller cuts the chunk there (``n``; the serve engine's second reason
-     for a cut; :func:`generate` does the same on the device).  A decode step
-     reads both through :func:`_cache_scores` (lowered for a TPU the ragged
-     kernel, twice, each over its own live tiles) and merges them
-     (:func:`ray_tpu.ops.eva.merge`) before the chunk-local columns join; a
-     prompt's PART (whole windows, at an offset that is a multiple of the
+  1. a SLAB of K and V per KV head is read below a slot's live length
+     (:func:`_cache_scores`); a slab of latent rows (multi-head latent
+     attention; :mod:`ray_tpu.models.kimi_k2`) the same way by the latent
+     kernel, absorbed: the row is every head's key and its first values are
+     the position's value vector; prefill runs un-absorbed, k and v a head for
+     the call only, and keeps the rows its block hands over.
+  2. a full layer that SELECTS (:mod:`ray_tpu.ops.dsa`;
+     :mod:`ray_tpu.models.dots3_note`, :mod:`ray_tpu.models.keye_vl`) scores
+     the slab's index keys below the slot's live length AND the chunk-local
+     ones of the steps up to its own, keeps the ``top-k`` under ONE exact
+     threshold (:func:`_select`), and its attention's softmax runs over those
+     alone: the kernel still reads every live tile and is handed the selection
+     as a mask; a cut chunk selects as a whole one does.
+  3. a RING is read by every row, whole and masked by the step's window
+     (:func:`_ring_mask`), or, where it holds latent rows (or the config says
+     so: ``cfg.window_rings_by_tile``) in whole tiles, the tiles of the
+     entries a live slot's ring holds (:func:`ring_read_by_tile`;
+     :func:`_latent_ring_scores`, :func:`_ring_scores`).
+  4. a STATE (a Mamba-2 mixer, :mod:`ray_tpu.models.granite_hybrid`; Mamba-1,
+     a decay per channel AND state element, :mod:`ray_tpu.models.phi4_flash`)
+     is overwritten in place by a decode step, and only for the rows that
+     take the step (:func:`ray_tpu.ops.ssm.state_update`,
+     :func:`ray_tpu.ops.ssm.selective_state_update`: lowered for a TPU a
+     Pallas kernel over the slots that were active when the chunk began); a
+     cut chunk has advanced it ``n`` steps; there is nothing to flush.
+  5. a WINDOW AND ITS SUMMARIES (:mod:`ray_tpu.models.evabyte`,
+     :mod:`ray_tpu.ops.eva`) trade places as a request grows: a query at
+     ``i`` reads places ``0 .. i % window`` of the window and the first ``(i
+     // window) x (window // chunk)`` summary rows, each through
+     :func:`_cache_scores` over its own live tiles, merged
+     (:func:`ray_tpu.ops.eva.merge`) before the chunk-local columns join.  The
+     moment a slot's window fills, its positions are pooled
+     (:func:`ray_tpu.ops.eva.pool_chunks`, by the layer's learned vectors),
+     appended to the summaries, and the window starts again at place 0: in a
+     prefill call for every window the call's tokens fill, in decode WITH THE
+     CHUNK'S FLUSH (:func:`_roll_over`), which is why no chunk may straddle a
+     window's end: the caller cuts the chunk there (``n``; the serve engine's
+     second reason for a cut; :func:`generate` does the same on the device).
+     A prompt's PART (whole windows, at an offset that is a multiple of the
      window) attends the cached summaries laid ahead of its own keys
      (:func:`ray_tpu.ops.eva.windowed_attention`).
 
@@ -99,14 +62,11 @@ first-class TPU path, designed for XLA:
   (:func:`layer_windows`'s entries :func:`reads_layer` and ``UNCACHED``;
   :func:`shared_cache`; :mod:`ray_tpu.models.phi4_flash`: ONE full layer's K
   and V read by every cross-attention layer above it, gated memory units
-  between them).  ``init_cache`` then makes ONE slab (``k``, ``v`` ``[1, B,
-  ...]``) whatever the readers; a decode step's readers attend the cache below
+  between them).  The table then has ONE slab a tensor (``[1, B, ...]``)
+  whatever the readers; a decode step's readers attend the cache below
   ``live`` and the owner's chunk-local columns (:func:`_decode_attend`), and a
   prefill runs the layers above the slab for a row's LAST position alone
-  (:func:`prefill_at`'s ``final``).  Such a family's window layers may have
-  their rings read a live slot's tiles at a time
-  (``cfg.window_rings_by_tile``; :func:`_ring_scores`: the ragged kernel with
-  the step's window as its mask).
+  (:func:`prefill_at`'s ``final``).
 
 - **A prefill that continues** (:func:`prefill_at`'s ``offsets``): a prompt
   need not go into its slot in ONE call.  A call's rows may be PARTS: row
@@ -116,21 +76,21 @@ first-class TPU path, designed for XLA:
   at ``offsets[b]``, and ``pos`` becomes ``offsets + lengths``.  The offsets
   are runtime VALUES (one program whatever they are; the serve engine
   interleaves the parts of a long prompt with its decode chunks:
-  :mod:`ray_tpu.serve.llm`).  By kind of cache: kinds 1 and 3 read the slot's
+  :mod:`ray_tpu.serve.llm`).  By arrangement: a slab's layer reads the slot's
   cached positions up to a static bound with the part's own placed among them,
   a latent layer's rows up-projected to per-head k and v by the block's own
   weights as the part's are, and a layer that selects scores cached and own
   index keys under ONE threshold (the whole prompt's selection); lowered for a
   TPU the flash kernel takes the key length as a prefetched scalar and
   neither folds nor fetches a block beyond it, so a prompt's parts add up to
-  the whole call's cells.  Kind 2 reads the positions just ahead of the part
+  the whole call's cells.  A ring's reads the positions just ahead of the part
   by their places in the ring and leaves the last ``ring`` positions of
-  prefix-and-part behind.  Kind 4 can where the family says so
+  prefix-and-part behind.  A state's can where the family says so
   (``cfg.state_carried_in``: Mamba-1's scan takes the slot's state IN and its
   convolution the slot's last inputs, zeros for a part at offset 0); a family
   that does not (Granite's Mamba-2: its chunked scan starts from zero) keeps
-  whole prompts (:func:`can_continue`).  Without ``offsets`` a call is a whole prompt from
-  position 0, the slot written from scratch.
+  whole prompts (:func:`can_continue`).  Without ``offsets`` a call is a whole
+  prompt from position 0, the slot written from scratch.
 - **One block per family**: prefill and decode run the block training
   runs (``gpt2.block``, ``llama.block``) and hand it their attention middle
   (:mod:`ray_tpu.models.transformer`): prefill the full causal attention,
@@ -217,8 +177,10 @@ onto the slot's own dead columns, which is as harmless.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -407,11 +369,6 @@ def summary_cache(cfg) -> Optional[Tuple[int, int]]:
     return getattr(cfg, "summary_cache", None)
 
 
-# what :func:`init_cache` holds of a compacting layer beside its window's
-# ``k``, ``v``: the pooled keys and values of the windows before it
-SUMMARIES = ("ks", "vs")
-
-
 def window_positions(window: int) -> int:
     """Places a slot's exact window holds: the window and a tile of slack (the
     window again where it is shorter than a tile), because a chunk's flush
@@ -424,7 +381,10 @@ def window_positions(window: int) -> int:
 def summary_rows(cfg, max_len: int) -> int:
     """Summary rows a slot of ``max_len`` positions can come to hold: a row a
     chunk of every window it can FILL (whole tiles where a window is)."""
-    window, chunk = summary_cache(cfg)
+    return _summary_rows(*summary_cache(cfg), max_len)
+
+
+def _summary_rows(window: int, chunk: int, max_len: int) -> int:
     rows = max(1, max_len // window) * (window // chunk)
     return -(-rows // DECODE_TILE) * DECODE_TILE if window % DECODE_TILE == 0 else rows
 
@@ -434,18 +394,6 @@ def attention_scale(cfg, window: bool = False) -> Optional[float]:
     -0.5``)."""
     return getattr(
         cfg, "window_attention_scale" if window else "attention_scale", None)
-
-
-def cached_tensors(cfg, window: bool = False) -> Tuple[str, ...]:
-    """The names of what :func:`init_cache` holds a position of a full layer
-    (K and V per head, and the index key where the layer selects; the one
-    latent row; the row and the index key of a layer that selects) or,
-    ``window``, of a window layer's ring."""
-    if window:
-        return ("c_ring",) if latent_cache(cfg, True) else ("k_ring", "v_ring")
-    if not latent_cache(cfg):
-        return ("k", "v", "idx_k") if index_cache(cfg) else ("k", "v")
-    return ("c", "idx_k") if index_cache(cfg) else ("c",)
 
 
 def ring_positions(window: int) -> int:
@@ -461,82 +409,188 @@ def ring_positions(window: int) -> int:
     return ring if ring < DECODE_TILE else -(-ring // DECODE_TILE) * DECODE_TILE
 
 
-def ring_read_by_tile(cache, cfg=None) -> bool:
+# whose a cached tensor is (``Cached.layers``): the layers that attend every
+# position, those that attend a window, those that carry a state, or no
+# layer's: the slot's own
+FULL_LAYERS, WINDOW_LAYERS, STATE_LAYERS, THE_SLOT = (
+    "full", "window", "state", "slot")
+# how a cached tensor is arranged (``Cached.arrangement``):
+# - SLAB ``[L, B, *row, max_len]``: positions last, every position kept;
+# - RING ``[L, B, *row, ring_positions(window)]``: position ``j`` at ``j %
+#   ring``, so a slot costs the ring however long its context;
+# - WINDOW ``[L, B, *row, window_positions(window)]``: the CURRENT window's
+#   positions, ``j`` at ``j % window``, BLOCK-ALIGNED: when a slot reaches a
+#   multiple of the window every place is dead at once (no ring: none of
+#   :func:`_ring_mask`, :func:`_ring_holds` describes it);
+# - SUMMARY ``[L, B, *row, summary_rows]``: one pooled row a ``chunk`` of
+#   positions of every window the slot has FILLED, of the WINDOW tensor the
+#   row names (``pools``);
+# - STATE ``[L, ...]`` with the slots at ``slot_axis``: nothing by position,
+#   a slot costs the same at position 10 and at 100,000;
+# - SLOT ``[B]``: a scalar a slot.
+SLAB, RING, WINDOW, SUMMARY, STATE, SLOT = (
+    "slab", "ring", "window", "summary", "state", "slot")
+
+
+@dataclasses.dataclass(frozen=True)
+class Cached:
+    """One cached tensor of a config: a row of :func:`cache_layout`."""
+    name: str                    # its key in the cache
+    layers: str                  # whose: FULL_LAYERS, ..., THE_SLOT
+    count: int                   # ... and how many of them (0: the slot's)
+    arrangement: str             # SLAB, RING, WINDOW, SUMMARY, STATE, SLOT
+    row: Tuple[int, ...]         # what one position (STATE: one slot) holds
+    dtype: Any
+    window: int = 0              # RING, WINDOW, SUMMARY: the layer's window
+    chunk: int = 0               # SUMMARY: the positions a row stands for
+    pools: Optional[str] = None  # SUMMARY: the WINDOW tensor it pools
+    slot_axis: int = 1           # STATE: where the slots stand
+
+    def positions(self, max_len: int) -> int:
+        """Positions (SUMMARY: rows) a slot of ``max_len`` holds here."""
+        if self.arrangement == RING:
+            return ring_positions(self.window)
+        if self.arrangement == WINDOW:
+            return window_positions(self.window)
+        if self.arrangement == SUMMARY:
+            return _summary_rows(self.window, self.chunk, max_len)
+        assert self.arrangement == SLAB, self
+        return max_len
+
+    def shape(self, n_slots: int, max_len: int) -> Tuple[int, ...]:
+        if self.arrangement == SLOT:
+            return (n_slots,)
+        if self.arrangement == STATE:
+            shape = [self.count, *self.row]
+            shape.insert(self.slot_axis, n_slots)
+            return tuple(shape)
+        return (self.count, n_slots, *self.row, self.positions(max_len))
+
+    def row_bytes(self) -> int:
+        """Bytes one position (STATE: one slot) of one layer holds."""
+        return math.prod(self.row) * jnp.dtype(self.dtype).itemsize
+
+
+def cache_layout(cfg) -> Tuple[Cached, ...]:
+    """WHAT A CONFIG'S CACHE HOLDS, a row a tensor, decided here and nowhere
+    else: :func:`init_cache` makes the rows, a prompt's writes
+    (:func:`_write_prompt`), a chunk's (:func:`decode_chunk`'s tail) and the
+    serve engine's byte counts walk them, and a new family's cache is rows of
+    this table plus what is new about how it is READ.  Chosen by what the
+    config says of its layers (:func:`layer_windows`, an owner of a shared
+    slab counting once; the predicates above), never by the family's name.
+    The order is the order a prompt's writes are emitted in: the slot's own,
+    then the full layers' by position, their summaries, the window layers',
+    the recurrent layers'.  (``pos [B]`` is the cache's own, ``routed`` a
+    program's result: neither is a row.)
+
+    - a full layer of K and V per KV head: ``k``, ``v``, a position ``2 x KV
+      x dh`` values (``cfg.cache_head_dim`` where what is cached is not
+      ``head_dim`` wide);
+    - a full latent layer (:func:`latent_cache`): ONE tensor ``c``, a position
+      one row (the normed latent, then the rotated key all heads share: 512 +
+      64 values as published) and NO ``v``: the row is every head's key and
+      its first values are the position's value vector;
+    - a full layer that SELECTS what it reads (:func:`index_cache`): an index
+      key ``idx_k`` beside each position (128 values as published), of a
+      latent row or of K and V per head;
+    - a layer that compacts (:func:`summary_cache`): ``k``, ``v`` are the
+      exact WINDOW, and ``ks``, ``vs`` the summaries that pool them;
+    - a window layer: rings ``k_ring``, ``v_ring``, or one of latent rows of
+      their own width, ``c_ring`` (``cfg.window_latent_cache``: 1,088 values
+      as published);
+    - a recurrent layer (:func:`state_cache`): ``ssm`` in float32 and
+      ``conv``, the layer's last inputs, which keeps its slots third (beside
+      the width, so that the chip pads neither);
+    - a family whose rotary positions are not cache positions
+      (:func:`rope_offset`): ``rope_delta``, int32, what a slot's rotary
+      position is ahead of its cached length (0 or less).
+
+    A combination the bodies below do not serve is refused here, not left out
+    of the cache in silence."""
+    windows = layer_windows(cfg)
+    n_full, n_state = windows.count(0), windows.count(RECURRENT)
+    n_window = sum(w > 0 for w in windows)
+    latent, index, compact = latent_cache(cfg), index_cache(cfg), summary_cache(cfg)
+    assert not (latent and n_state), "no body runs latent layers between runs"
+    assert not (compact and (latent or index or n_window or n_state)), (
+        "a family that compacts has layers alike, stacked", windows)
+    # K and V per KV head (a latent family has no such row)
+    heads = None if latent else (
+        kv_heads(cfg), getattr(cfg, "cache_head_dim", cfg.head_dim))
+    full = partial(Cached, layers=FULL_LAYERS, count=n_full, dtype=cfg.dtype)
+    ring = partial(Cached, layers=WINDOW_LAYERS, count=n_window,
+                   arrangement=RING, dtype=cfg.dtype, window=max(windows))
+    rows = []
+    if rope_offset(cfg):
+        rows.append(Cached("rope_delta", THE_SLOT, 0, SLOT, (), jnp.int32))
+    if latent:
+        rows.append(full("c", arrangement=SLAB, row=(1, latent[0])))
+    elif compact:
+        rows += [full(name, arrangement=WINDOW, row=heads, window=compact[0])
+                 for name in ("k", "v")]
+    else:
+        rows += [full(name, arrangement=SLAB, row=heads) for name in ("k", "v")]
+    if index:
+        rows.append(full("idx_k", arrangement=SLAB, row=(1, index[0])))
+    if compact:
+        rows += [full(name, arrangement=SUMMARY, row=heads, window=compact[0],
+                      chunk=compact[1], pools=pooled)
+                 for name, pooled in (("ks", "k"), ("vs", "v"))]
+    if n_window and latent:
+        rows.append(ring("c_ring", row=(1, latent_cache(cfg, True)[0])))
+    elif n_window:
+        rows += [ring(name, row=heads) for name in ("k_ring", "v_ring")]
+    if n_state:
+        for name, axis in (("ssm", 1), ("conv", 2)):
+            shape, dtype = state_cache(cfg)[name]
+            rows.append(Cached(name, STATE_LAYERS, n_state, STATE, tuple(shape),
+                               dtype, slot_axis=axis))
+    return tuple(rows)
+
+
+def cache_rows(cfg, *arrangements: str,
+               layers: Optional[str] = None) -> Tuple[Cached, ...]:
+    """The rows of :func:`cache_layout` arranged so (any, where none is
+    named) and, ``layers``, owned so."""
+    return tuple(r for r in cache_layout(cfg)
+                 if (not arrangements or r.arrangement in arrangements)
+                 and layers in (None, r.layers))
+
+
+def cached_tensors(cfg, window: bool = False) -> Tuple[str, ...]:
+    """The names of what :func:`init_cache` holds a position of a full layer
+    (K and V per head, and the index key where the layer selects; the one
+    latent row; the row and the index key of a layer that selects) or,
+    ``window``, of a window layer's ring: a filter over :func:`cache_layout`."""
+    return tuple(r.name for r in (
+        cache_rows(cfg, RING) if window
+        else cache_rows(cfg, SLAB, WINDOW, layers=FULL_LAYERS)))
+
+
+def ring_read_by_tile(cache, cfg) -> bool:
     """Whether a decode step reads the window layers' rings of ``cache`` a
     tile of a live slot at a time (:func:`decode_chunk`): rings of latent
     rows, or rings of K and V where the config says so
     (``cfg.window_rings_by_tile``), in whole tiles, the row whole sublanes.
     Otherwise every row's whole ring, masked."""
-    ring = cache.get("c_ring")
-    if ring is None and getattr(cfg, "window_rings_by_tile", False):
-        ring = cache.get("k_ring")
-    return (ring is not None and ring.shape[-1] % DECODE_TILE == 0
-            and ring.shape[3] % 8 == 0)
+    rings = cache_rows(cfg, RING)
+    if not rings or not (latent_cache(cfg)
+                         or getattr(cfg, "window_rings_by_tile", False)):
+        return False
+    ring = cache[rings[0].name]
+    return ring.shape[-1] % DECODE_TILE == 0 and ring.shape[3] % 8 == 0
 
 
 def init_cache(cfg, n_slots: int, max_len: int) -> Dict[str, jax.Array]:
-    """Fixed-size KV cache, by kind of layer, plus per-slot ``pos``.  The
-    full layers: ``k``/``v`` ``[L_full, B, KV, dh, S]`` (positions LAST, so
-    the scores of a decode step come out with S on the lanes and the chip
-    stores the cache unpadded), every position kept.  The window layers, for
-    a family that has them: ``k_ring``/``v_ring`` ``[L_window, B, KV, dh,
-    R]``, a ring: position ``j`` lives at ``j % R`` (``ring_positions``), so
-    a slot costs ``R`` positions however long its context.  A family of
-    latent layers (:func:`latent_cache`): ``c`` ``[L_full, B, 1, row, S]``, one
-    row a position and NO second tensor; where its full layers select
-    (:func:`index_cache`) their index keys beside it, ``idx_k`` ``[L_full, B,
-    1, key, S]``; its window layers a ring of rows of their own width,
-    ``c_ring`` ``[L_window, B, 1, row, R]``.  The recurrent layers, for a family that
-    has them (:func:`state_cache`): ``ssm`` ``[L_state, B, ...]`` and ``conv``
-    ``[L_state, inputs kept, B, width]`` (the slots beside the width, so that
-    the chip pads neither), no positions at all.  A family whose layers
-    COMPACT what they cache (:func:`summary_cache`; the sixth kind of cached
-    tensor): ``k``/``v`` ``[L, B, KV, dh, window + slack]``, the CURRENT
-    window's exact keys and values (:func:`window_positions`), and ``ks``/``vs``
-    ``[L, B, KV, dh, rows]``, one pooled key and value a chunk of every window
-    the slot has filled (:func:`summary_rows` of ``max_len``).  A family of K
-    and V per head whose layers select: ``idx_k`` beside ``k`` and ``v``; one
-    whose rotary positions are not cache positions (:func:`rope_offset`):
-    ``rope_delta [B]`` int32, what a slot's rotary position is ahead of its
-    cached length (0 or less)."""
-    windows = layer_windows(cfg)
-    n_full, n_state = windows.count(0), windows.count(RECURRENT)
-    n_window = sum(w > 0 for w in windows)
-    if latent_cache(cfg):
-        rows = lambda width, layers, length: jnp.zeros(  # noqa: E731
-            (layers, n_slots, 1, width, length), cfg.dtype)
-        cache = {"c": rows(latent_cache(cfg)[0], n_full, max_len),
-                 "pos": jnp.zeros((n_slots,), jnp.int32)}
-        if index_cache(cfg):
-            cache["idx_k"] = rows(index_cache(cfg)[0], n_full, max_len)
-        if n_window:
-            cache["c_ring"] = rows(latent_cache(cfg, True)[0], n_window,
-                                   ring_positions(max(windows)))
-        return cache
-    slab = lambda layers, length: jnp.zeros(  # noqa: E731
-        (layers, n_slots, kv_heads(cfg),
-         getattr(cfg, "cache_head_dim", cfg.head_dim), length), cfg.dtype)
-    if summary_cache(cfg):
-        held, rows = window_positions(summary_cache(cfg)[0]), summary_rows(cfg, max_len)
-        return {"k": slab(n_full, held), "v": slab(n_full, held),
-                "ks": slab(n_full, rows), "vs": slab(n_full, rows),
-                "pos": jnp.zeros((n_slots,), jnp.int32)}
-    cache = {"k": slab(n_full, max_len), "v": slab(n_full, max_len),
-             "pos": jnp.zeros((n_slots,), jnp.int32)}
-    if index_cache(cfg):  # the first and the fifth kinds side by side
-        cache["idx_k"] = jnp.zeros(
-            (n_full, n_slots, 1, index_cache(cfg)[0], max_len), cfg.dtype)
-    if rope_offset(cfg):
-        cache["rope_delta"] = jnp.zeros((n_slots,), jnp.int32)
-    if n_window:
-        ring = ring_positions(max(windows))
-        cache.update(k_ring=slab(n_window, ring), v_ring=slab(n_window, ring))
-    if n_state:
-        (shape, dtype), ((kept, width), conv_dtype) = (
-            state_cache(cfg)[name] for name in ("ssm", "conv"))
-        cache.update(
-            ssm=jnp.zeros((n_state, n_slots, *shape), dtype),
-            conv=jnp.zeros((n_state, kept, n_slots, width), conv_dtype))
+    """The fixed-size cache of ``n_slots`` slots of ``max_len`` positions:
+    every row of :func:`cache_layout`, zeros of its ``shape`` and ``dtype``
+    under its name, plus per-slot ``pos``.  (Positions LAST, so the scores of
+    a decode step come out with them on the lanes and the chip stores the
+    cache unpadded.)"""
+    cache = {row.name: jnp.zeros(row.shape(n_slots, max_len), row.dtype)
+             for row in cache_layout(cfg)}
+    cache["pos"] = jnp.zeros((n_slots,), jnp.int32)
     return cache
 
 
@@ -839,7 +893,8 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
     a window layer the last ``ring`` of each row (:func:`_ring_of`), a
     recurrent layer its state as it stands after each row's last REAL token
     and that row's last real inputs, written whole over the slot (a padded
-    position changes neither).  Where the family's layers count what they
+    position changes neither): :func:`_write_prompt`, a row of
+    :func:`cache_layout` at a time.  Where the family's layers count what they
     routed, the dispatch's counts come back as ``cache["routed"]`` (leaves
     stacked over the layers that route).
 
@@ -912,11 +967,10 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         # what the cache holds of the rows' slots, by kind of layer
         # (a family that shares one slab reads it for a last position alone)
         ahead = {False: () if shared is not None else tuple(
-                     cache[n][:, slots, :, :, :bound]
-                     for n in (SUMMARIES if compact else cached_tensors(cfg))),
-                 True: tuple(cache[n][:, slots]
-                             for n in cached_tensors(cfg, True)
-                             ) if max(windows) > 0 else ()}
+                     cache[row.name][:, slots, :, :, :bound]
+                     for row in cache_rows(cfg, SLAB, SUMMARY, layers=FULL_LAYERS)),
+                 True: tuple(cache[row.name][:, slots]
+                             for row in cache_rows(cfg, RING))}
     else:
         positions = jnp.arange(Tp)
     if visual is not None:
@@ -997,80 +1051,47 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         # ks [L, B, KV, Tp, dh]
         x, full = lax.scan(body, x, (params["blocks"],
                                      *(ahead[False] if part else ())))
-        ringed = states = ()
+        kept = _kept(cfg, full)
     elif shared is not None:  # the lower half, and the shared slab's K and V
         carried = None
         if part:  # what the slot holds of the part before; nothing at offset 0
+            held = tuple(jnp.moveaxis(cache[row.name][_of_slots(row, slots)],
+                                      row.slot_axis, 1)
+                         for row in cache_rows(cfg, STATE))
             carried = tuple(
                 jnp.where((offsets > 0).reshape(1, -1, *(1,) * (t.ndim - 2)), t, 0)
-                for t in (cache["ssm"][:, slots],
-                          jnp.swapaxes(cache["conv"][:, :, slots], 1, 2)))
-        x, memory, full, ringed, states = _prefill_lower(
+                for t in held)
+        x, memory, kept = _prefill_lower(
             fam, params, cfg, x, attend, lengths, carried,
             ahead[True] if part else None, max(windows))
     elif state_cache(cfg):  # runs of recurrent layers rolled, the others listed
-        x, routed, full, states = _prefill_runs(
+        x, routed, kept = _prefill_runs(
             fam, params, cfg, x, attend, positions, lengths)
-        ringed = ()
     else:  # kinds of layer mixed, listed: unrolled, each kind's k, v apart
-        states = ()
         valid = jnp.arange(Tp)[None, :] < lengths[:, None]
-        kept = {}
+        done = {}  # what the layers so far kept, by kind of layer
         for p, w in zip(params["layers"], windows):
             held, rows_of = None, {}
             if part:  # this layer's among its kind's, and a latent block's rows
-                held = tuple(t[len(kept.get(bool(w), ()))] for t in ahead[bool(w)])
+                held = tuple(t[len(done.get(bool(w), ()))] for t in ahead[bool(w)])
                 if latent_cache(cfg, bool(w)):
                     rows_of = {"context": lambda row, up, w=w, held=held: among(
                         w, held[:1], (row,), up)}
             x, counts, kv = fam.block(
                 x, p, cfg, partial(attend, window=w, held=held), positions,
                 window=w, valid=valid, **rows_of)
-            kept.setdefault(bool(w), []).append(kv)
+            done.setdefault(bool(w), []).append(kv)
             routed += [] if counts is None else [counts]
-        full, ringed = (
-            tuple(jnp.stack(t) for t in zip(*kept.get(kind, ())))
-            for kind in (False, True))
+        kept = _kept(cfg, *(
+            tuple(jnp.stack(t) for t in zip(*done.get(kind, ())))
+            for kind in (False, True)))
     rows = lengths.astype(jnp.int32)
     out = {**cache, "pos": cache["pos"].at[slots].set(
         rows + offsets if part else rows)}
-    if "rope_delta" in cache:
-        out["rope_delta"] = cache["rope_delta"].at[slots].set(
-            0 if visual is None else visual["delta"].astype(jnp.int32))
-    if compact:
-        out.update(_keep_compacted(cfg, cache, full, slots, rows,
-                                   offsets if part else None))
-    elif part:
-        # a row at a time, each at its own offset (a call is a row or a few);
-        # a ring keeps the entries the part did not reach: the newest position
-        # of an entry (_ring_holds over prefix-and-part) is the part's or older
-        for name, t in zip(cached_tensors(cfg), full):
-            cols = jnp.swapaxes(t, 3, 4).astype(cache[name].dtype)
-            for b in range(B):
-                out[name] = lax.dynamic_update_slice(
-                    out[name], cols[:, b:b + 1], (0, slots[b], 0, 0, offsets[b]))
-        for name, t in zip(cached_tensors(cfg, True), ringed):
-            j = _ring_holds(offsets + rows, cache[name].shape[-1]) - offsets[:, None]
-            new = jnp.take_along_axis(
-                t, jnp.clip(j, 0, Tp - 1)[None, :, None, :, None], axis=3)
-            out[name] = cache[name].at[:, slots].set(jnp.where(
-                (j >= 0)[None, :, None, None, :],
-                jnp.swapaxes(new, 3, 4).astype(cache[name].dtype),
-                cache[name][:, slots]))
-    else:
-        # single advanced index keeps its axis position: one scatter per tensor
-        # (over whole slots, once a prompt; decode never scatters)
-        to_cache = lambda t, c: c.at[:, slots, :, :, :Tp].set(
-            jnp.swapaxes(t, 3, 4).astype(c.dtype))
-        for t, name in zip(full, cached_tensors(cfg)):
-            out[name] = to_cache(t, cache[name])
-        for t, name in zip(ringed, cached_tensors(cfg, True)):
-            out[name] = cache[name].at[:, slots].set(_ring_of(
-                t, lengths, cache[name].shape[-1]).astype(cache[name].dtype))
-    if states:  # [L_state, B, ...] and [L_state, B, kept, width]: whole slots
-        out["ssm"] = cache["ssm"].at[:, slots].set(states[0])
-        out["conv"] = cache["conv"].at[:, :, slots].set(
-            jnp.swapaxes(states[1], 1, 2).astype(cache["conv"].dtype))
+    for row in cache_rows(cfg, SLOT):  # (0 for a text: a reused slot is clean)
+        kept[row.name] = 0 if visual is None else visual["delta"].astype(jnp.int32)
+    out.update(_write_prompt(cfg, cache, kept, slots, rows,
+                             offsets if part else None))
     if routed:
         out["routed"] = routed if isinstance(routed, dict) else jax.tree.map(
             lambda *a: jnp.stack(a), *routed)
@@ -1114,40 +1135,100 @@ def prefill_at(params, cfg, tokens: jax.Array, lengths: jax.Array,
         (B, cfg.vocab_size), jnp.float32), x, memory), out
 
 
-def _keep_compacted(cfg, cache, kept, slots, lengths, offsets):
-    """What a prefill call leaves of a compacting family's layers (kept: ``k,
-    v [L, B, KV, T, dh]`` and, where the call is whole windows, every window's
-    summaries ``[L, B, KV, dh, windows x rows a window]``): the EXACT window is
-    the one row ``b``'s next position falls in (the call's last where the row
-    ends it: its places are then all dead, ``pos % window == 0``), position
-    ``j`` at place ``j % window``; the summaries go in at the row a window,
-    every window's: those of a window the row did not fill lie beyond what
-    ``pos`` lets a query read, and the roll-over that fills it rewrites them.
-    ``offsets`` (None: whole prompts): the call's rows are PARTS, written a
-    row at a time at their own summary row."""
-    window, chunk = summary_cache(cfg)
-    k, v, *pooled = kept
-    T = k.shape[3]
-    tw = min(T, window)
-    at = jnp.minimum(lengths // tw, T // tw - 1)
-    out = {}
-    for name, t in zip(("k", "v"), (k, v)):
-        held = jnp.take_along_axis(
-            t.reshape(*t.shape[:3], T // tw, tw, t.shape[-1]),
-            at[None, :, None, None, None, None], axis=3)[:, :, :, 0]
-        out[name] = cache[name].at[:, slots, :, :, :tw].set(
-            jnp.swapaxes(held, 3, 4).astype(cache[name].dtype))
-    for name, t in zip(SUMMARIES, pooled):
-        t = t.astype(cache[name].dtype)
-        if offsets is None:
-            n = min(t.shape[-1], cache[name].shape[-1])
-            out[name] = cache[name].at[:, slots, :, :, :n].set(t[..., :n])
+def _kept(cfg, full=(), ringed=(), states=()) -> Dict[str, jax.Array]:
+    """What a prefill call's layers kept, BY ROW of :func:`cache_layout`: the
+    full layers' tensors (stacked over them, ``[L, B, KV, Tp, dh]``, a
+    compacting family's summaries ``[L, B, KV, dh, rows]`` after them), the
+    window layers' and the recurrent layers' (``[L_state, B, ...]``), each
+    kind's in the table's order, under the names :func:`_write_prompt` finds
+    them by.  Fewer tensors than rows: the call did not make the last ones (a
+    compacting family's call that is no whole window pools nothing)."""
+    return {row.name: t
+            for layers, tensors in ((FULL_LAYERS, full), (WINDOW_LAYERS, ringed),
+                                    (STATE_LAYERS, states))
+            for row, t in zip(cache_rows(cfg, layers=layers), tensors)}
+
+
+def _of_slots(row: Cached, slots) -> tuple:
+    """The index of ``slots`` in a STATE row's tensor."""
+    return (slice(None),) * row.slot_axis + (slots,)
+
+
+def _write_prompt(cfg, cache, kept, slots, lengths, offsets=None):
+    """What a prefill call leaves in its rows' slots, a row of
+    :func:`cache_layout` at a time: ``kept[name]``, what the call's layers
+    kept for the row of that name (:func:`_kept`; a row they kept nothing for
+    stays as it was), of prompts of ``lengths [B]`` int32.  ``offsets`` (None:
+    WHOLE prompts, the slots written from position 0): the call's rows are
+    PARTS, each written at its own offset, a row at a time (a call is a row
+    or a few).  By arrangement:
+
+    - SLOT: the scalar, whole or part;
+    - SLAB: the prompt's columns at ``[0, Tp)`` in one scatter over whole
+      slots (a single advanced index keeps its axis position; decode never
+      scatters), a part's at ``offsets[b]``;
+    - RING: each row's last ``ring`` positions (:func:`_ring_of`); of a part,
+      the entries whose newest position (:func:`_ring_holds` over
+      prefix-and-part) is the part's own, the others kept;
+    - WINDOW: the EXACT window row ``b``'s next position falls in (the call's
+      last where the row ends it: its places are then all dead, ``pos %
+      window == 0``), position ``j`` at place ``j % window``;
+    - SUMMARY: every window's summaries at the row a window (those of a
+      window the row did not fill lie beyond what ``pos`` lets a query read,
+      and the roll-over that fills it rewrites them), a part's at its own
+      summary row;
+    - STATE: the state as it stands after each row's last REAL token, whole
+      over the slot (which is what makes a reused slot clean)."""
+    out, which = {}, None
+    for row in cache_layout(cfg):
+        if row.name not in kept:
             continue
-        out[name] = cache[name]
-        for b in range(t.shape[1]):
-            out[name] = lax.dynamic_update_slice(
-                out[name], t[:, b:b + 1],
-                (0, slots[b], 0, 0, offsets[b] // chunk))
+        old, t = cache[row.name], kept[row.name]
+        if row.arrangement == SLOT:
+            out[row.name] = old.at[slots].set(t)
+        elif row.arrangement == STATE:
+            out[row.name] = old.at[_of_slots(row, slots)].set(
+                jnp.moveaxis(t, 1, row.slot_axis).astype(old.dtype))
+        elif row.arrangement == SLAB and offsets is None:
+            out[row.name] = old.at[:, slots, :, :, :t.shape[3]].set(
+                jnp.swapaxes(t, 3, 4).astype(old.dtype))
+        elif row.arrangement == SLAB:
+            cols = jnp.swapaxes(t, 3, 4).astype(old.dtype)
+            for b in range(cols.shape[1]):
+                old = lax.dynamic_update_slice(
+                    old, cols[:, b:b + 1], (0, slots[b], 0, 0, offsets[b]))
+            out[row.name] = old
+        elif row.arrangement == RING and offsets is None:
+            out[row.name] = old.at[:, slots].set(
+                _ring_of(t, lengths, old.shape[-1]).astype(old.dtype))
+        elif row.arrangement == RING:
+            j = _ring_holds(offsets + lengths, old.shape[-1]) - offsets[:, None]
+            new = jnp.take_along_axis(
+                t, jnp.clip(j, 0, t.shape[3] - 1)[None, :, None, :, None], axis=3)
+            out[row.name] = old.at[:, slots].set(jnp.where(
+                (j >= 0)[None, :, None, None, :],
+                jnp.swapaxes(new, 3, 4).astype(old.dtype), old[:, slots]))
+        elif row.arrangement == WINDOW:
+            T = t.shape[3]
+            tw = min(T, row.window)
+            if which is None:  # the window of the call's that each row keeps
+                which = jnp.minimum(lengths // tw, T // tw - 1)
+            held = jnp.take_along_axis(
+                t.reshape(*t.shape[:3], T // tw, tw, t.shape[-1]),
+                which[None, :, None, None, None, None], axis=3)[:, :, :, 0]
+            out[row.name] = old.at[:, slots, :, :, :tw].set(
+                jnp.swapaxes(held, 3, 4).astype(old.dtype))
+        elif offsets is None:  # SUMMARY
+            n = min(t.shape[-1], old.shape[-1])
+            out[row.name] = old.at[:, slots, :, :, :n].set(
+                t.astype(old.dtype)[..., :n])
+        else:
+            t = t.astype(old.dtype)
+            for b in range(t.shape[1]):
+                old = lax.dynamic_update_slice(
+                    old, t[:, b:b + 1],
+                    (0, slots[b], 0, 0, offsets[b] // row.chunk))
+            out[row.name] = old
     return out
 
 
@@ -1169,8 +1250,9 @@ def _prefill_lower(fam, params, cfg, x, attend, lengths, carried, rings,
     (``[L_state, B, ...]`` each; None: prompts from their start); ``rings``:
     what the window layers' rings hold of the rows' slots (``[L_window, B, KV,
     dh, R]`` each; None: whole prompts).  Returns ``(x, the memory layer's y,
-    the slab's (k, v) [1, B, KV, T, dh], the window layers' stacked (k, v),
-    the recurrent layers' stacked (state, last inputs))``."""
+    what was kept by row`` (:func:`_kept`): the slab's k, v ``[1, B, KV, T,
+    dh]``, the window layers' stacked k, v, the recurrent layers' stacked
+    state and last inputs``)``."""
     at_l = lambda t, at: lax.dynamic_index_in_dim(t, at, 0, keepdims=False)  # noqa: E731
 
     def mamba(at, xs, p, carry):
@@ -1189,16 +1271,16 @@ def _prefill_lower(fam, params, cfg, x, attend, lengths, carried, rings,
     x, memory, _, states, ringed = fam.lower_stack(
         params, cfg, x, None, mamba, ring)
     full = tuple(t[None] for t in fam.shared_kv(params, cfg, x))
-    return x, memory, full, ringed, states
+    return x, memory, _kept(cfg, full, ringed, states)
 
 
 def _prefill_runs(fam, params, cfg, x, attend, positions, lengths):
     """:func:`prefill_at`'s layers for a family with recurrent layers: every
     run of them (``cfg.layer_runs``) one rolled loop over the kind's stacked
     parameters, the attention layers between them listed.  Returns ``(x, the
-    layers' routing counts with their leaves stacked over the layers, the
-    attention layers' stacked k and v, the recurrent layers' stacked (state,
-    last inputs))``."""
+    layers' routing counts with their leaves stacked over the layers, what
+    was kept by row`` (:func:`_kept`): the attention layers' stacked k and v,
+    the recurrent layers' stacked state and last inputs``)``."""
     valid = positions[None, :] < lengths[:, None]
     recur = lambda xbc, dt, p: fam.mamba_whole(  # noqa: E731
         xbc, dt, p, cfg, lengths)
@@ -1225,7 +1307,8 @@ def _prefill_runs(fam, params, cfg, x, attend, positions, lengths):
     full, states = (tuple(jnp.concatenate(t) for t in zip(*kept[kind]))
                     for kind in (False, True))
     # leaves stacked over the layers, in layer order
-    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *routed), full, states
+    return (x, jax.tree.map(lambda *a: jnp.concatenate(a), *routed),
+            _kept(cfg, full, states=states))
 
 
 def prefill(params, cfg, tokens: jax.Array, lengths: jax.Array,
@@ -1287,15 +1370,20 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     # first values are the position's value vector (absorbed attention)
     windows = layer_windows(cfg)
     window = max(windows)
-    names = cached_tensors(cfg)
-    ring_names = cached_tensors(cfg, True) if window > 0 else ()
+    # the table's rows by what a chunk does with each: columns flushed at the
+    # slots' places, columns flushed modulo the ring, summaries a full window
+    # rolls over into, a state the steps carry
+    flushed = cache_rows(cfg, SLAB, WINDOW, layers=FULL_LAYERS)
+    wrapped, pooled, carried = (
+        cache_rows(cfg, kind) for kind in (RING, SUMMARY, STATE))
+    names = tuple(row.name for row in flushed)
     latent, index, compact = latent_cache(cfg), index_cache(cfg), summary_cache(cfg)
     shared = shared_cache(cfg)
     # (a slab that layers other than its owner read: its kernel call has a
     # name of its own)
     slab_name = {} if shared is None else {"name": "ragged_shared_kv_attention"}
     old = tuple(cache[name] for name in names)
-    rings = tuple(cache[name] for name in ring_names)
+    rings = tuple(cache[row.name] for row in wrapped)
     S = old[0].shape[-1]
     ring = rings[0].shape[-1] if rings else S
     # the flush holds a start to S - steps silently (a dynamic_update_slice
@@ -1331,7 +1419,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         ring // DECODE_TILE) if ring_read_by_tile(cache, cfg) else None
     # a family that compacts: the summaries of the windows before a slot's
     # own, ``rows a window`` for each it has filled, read as the window is
-    sums = tuple(cache[name] for name in SUMMARIES) if compact else ()
+    sums = tuple(cache[row.name] for row in pooled)
     far = far_plan = None
     if compact:
         far = jnp.where(active, pos0 // compact[0] * (compact[0] // compact[1]), 0)
@@ -1346,7 +1434,7 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
     read_of = ((lambda n: -(-n // DECODE_TILE) * DECODE_TILE)
                if plan is not None else (lambda n: jnp.full_like(n, S)))
     # the recurrent layers' state, and the slots whose state a step moves
-    held = tuple(cache[name] for name in ("ssm", "conv") if name in cache)
+    held = tuple(cache[row.name] for row in carried)
     moved = ssm.state_update_plan(active) if held and (
         ssm.kernel_shapes if shared is None else ssm.selective_kernel_shapes)(
             held[0]) else None
@@ -1357,8 +1445,8 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
         locs, held, pos, toks, act, rng = carry
         rng, sub = jax.random.split(rng)
         positions = pos[:, None]  # [B, 1] per-slot offsets (wpe / rope)
-        if "rope_delta" in cache:  # rotary positions ahead of the cache's
-            positions = positions + cache["rope_delta"][:, None]
+        for row in cache_rows(cfg, SLOT):  # rotary positions ahead of the cache's
+            positions = positions + cache[row.name][:, None]
         x = fam.embed(params, toks[:, None], cfg, positions)  # [B, 1, D]
 
         def layer(l, w, at, block, carry):
@@ -1576,23 +1664,27 @@ def decode_chunk(params, cfg, cache, tokens, active, key, *, steps: int,
              jax.tree.map(jnp.zeros_like, counted)))
         emitted = jnp.where(jnp.arange(steps)[:, None] < n, emitted, last)
 
-    out = {**cache, "pos": pos, **dict(zip(("ssm", "conv"), held))}
+    # what the chunk leaves of every row it writes: the state as the last step
+    # carried it, the columns flushed, a filled window rolled over into its
+    # summaries, the rings' columns modulo the ring
+    out = {**cache, "pos": pos,
+           **{row.name: t for row, t in zip(carried, held)}}
     for name, big, loc in zip(names, old, locs):
         out[name] = _flush(big, loc, place0, to_flush)
-    if compact:
-        out.update(zip(SUMMARIES, _roll_over(
-            cfg, fam.pooling(params["blocks"]), out["k"], out["v"], sums,
-            pos, pos != pos0)))
-    if rings:
-        # the rings, once a chunk and whole: column t of slot b goes to entry
+    if pooled:
+        out.update(zip((row.name for row in pooled), _roll_over(
+            cfg, fam.pooling(params["blocks"]),
+            *(out[row.pools] for row in pooled), sums, pos, pos != pos0)))
+    if wrapped:
+        # once a chunk and whole: column t of slot b goes to entry
         # (pos0[b] + t) % ring, chosen by a 0/1 matrix (exact), every other
         # entry stays; no scatter, and a wrap is nothing special
         hit = ((pos0[:, None, None] + jnp.arange(steps)[None, :, None]) % ring
                == jnp.arange(ring)[None, None, :])          # [B, steps, ring]
-        for name, loc in zip(ring_names, locs[len(names):]):
+        for row, loc in zip(wrapped, locs[len(names):]):
             new = jnp.einsum("ltbkd,btr->lbkdr", loc, hit.astype(loc.dtype))
-            out[name] = jnp.where(hit.any(1)[None, :, None, None, :],
-                                  new, cache[name])
+            out[row.name] = jnp.where(hit.any(1)[None, :, None, None, :],
+                                      new, cache[row.name])
     if routed is not None:  # the chunk's routing counts: summed over its steps
         out["routed"] = routed if n is not None else jax.tree.map(
             lambda a: a.sum(0), routed)

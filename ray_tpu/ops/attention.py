@@ -56,8 +56,8 @@ Not in the dispatch:
   Arithmetic on the VPU (one query a head is a matrix-VECTOR product).
   ``models/generate.py`` calls it where a decode program is lowered for a
   TPU with a cache of whole tiles: the ``serve-gpt2-xl-chat`` cell, whose
-  masked einsums over the padded slab it replaces (6.2 ms of a 13.16 ms
-  step: ledger, PR 29).
+  masked einsums over the padded slab it replaces (the cell's
+  ``model.decode_step_ms``: PERF.md section 6, PR 30).
 - :func:`ragged_latent_decode_attention` — the decode step of multi-head
   latent attention: 64 absorbed queries a slot against a cache of ONE latent
   row a position, ``[L, B, 1, 576, S]``.  The tile walk is

@@ -59,7 +59,6 @@ is part of the framework here:
 from __future__ import annotations
 
 import contextlib
-import math
 import operator
 import threading
 import time
@@ -443,6 +442,42 @@ def cache_positions(largest_bucket: int, max_new_tokens: int,
     return -(-need // DECODE_TILE) * DECODE_TILE
 
 
+def cache_counts(cfg, max_len: int) -> Dict[str, Any]:
+    """What an engine's counters need to know of its cache, summed over the
+    rows of ``generate.cache_layout(cfg)`` for slots of ``max_len`` positions:
+    ``layers`` (how many a kind; no ``state`` key where there is none),
+    ``ring_tiles`` (tiles of one window layer's ring a slot), ``slab_tiles``
+    (tiles of a full layer's padded slab a slot: of the first of its tensors,
+    and of the summaries that pool it), ``tile_bytes`` (bytes of one tile of
+    a layer, every tensor that holds its positions, by kind), ``state`` (the
+    tensor that holds the recurrent state, slots second; None) and
+    ``state_row_bytes`` (what a slot holds a recurrent layer)."""
+    from ray_tpu.models import generate as gen
+    from ray_tpu.ops.attention import DECODE_TILE
+
+    tiles = lambda row: -(-row.positions(max_len) // DECODE_TILE)  # noqa: E731
+    by_position = {
+        gen.FULL_LAYERS: gen.cache_rows(
+            cfg, gen.SLAB, gen.WINDOW, layers=gen.FULL_LAYERS),
+        gen.WINDOW_LAYERS: gen.cache_rows(cfg, gen.RING)}
+    first = by_position[gen.FULL_LAYERS][0]
+    states = gen.cache_rows(cfg, gen.STATE)
+    layers = {kind: rows[0].count if rows else 0
+              for kind, rows in by_position.items()}
+    if states:
+        layers[gen.STATE_LAYERS] = states[0].count
+    return {
+        "layers": layers,
+        "ring_tiles": sum(map(tiles, by_position[gen.WINDOW_LAYERS][:1])),
+        "slab_tiles": tiles(first) + sum(
+            tiles(r) for r in gen.cache_rows(cfg, gen.SUMMARY)
+            if r.pools == first.name),
+        "tile_bytes": {kind: DECODE_TILE * sum(r.row_bytes() for r in rows)
+                       for kind, rows in by_position.items()},
+        "state": next((r.name for r in states if r.slot_axis == 1), None),
+        "state_row_bytes": sum(r.row_bytes() for r in states)}
+
+
 def part_bound(largest_bucket: int) -> int:
     """The cache positions a prompt's part may attend, itself included: the
     largest bucket in whole parts (the part program's static bound on the
@@ -634,16 +669,14 @@ class GenerationEngine:
 
         self._tile = DECODE_TILE
         windows = gen.layer_windows(cfg)
-        n_state = windows.count(gen.RECURRENT)
-        self._layers = {"full": windows.count(0),
-                        "window": sum(w > 0 for w in windows),
-                        **({"state": n_state} if n_state else {})}
         # per layer of the kind: a full layer reads a slot's live tiles; a
         # window layer every row's whole ring (two tiles at the published
         # window; ``held_window``), whatever the context, or, where the rings
         # hold latent rows in whole tiles, the tiles of the entries a
         # dispatched row's ring holds (``generate.decode_chunk``'s ring plan)
-        self._ring_tiles = -(-gen.ring_positions(max(windows)) // DECODE_TILE)
+        counts = cache_counts(cfg, self._max_len)
+        self._layers = counts["layers"]
+        self._ring_tiles = counts["ring_tiles"]
         self._cache_tiles = {"read_full": 0, "read_window": 0,
                              "held_window": 0, "padded": 0, "flushed": 0}
         # a family whose upper layers read ONE lower layer's slab: the layers
@@ -706,21 +739,11 @@ class GenerationEngine:
         self.cache = gen.init_cache(cfg, n_slots + 1, self._max_len)
         self._ring_by_tile = gen.ring_read_by_tile(self.cache, cfg)
         # tiles of the padded slab a full layer a slot (a compacting family:
-        # its window's and its summaries')
-        self._slab_tiles = sum(
-            -(-self.cache[name].shape[-1] // DECODE_TILE)
-            for name in (("k", "ks") if self._compact
-                         else gen.cached_tensors(cfg)[:1]))
-        # bytes of one tile of a layer of each kind, from the cache's own
-        # shapes (k and v per KV head, or one latent row a position, with its
-        # index key where the layer selects), so that no reader of the
+        # its window's and its summaries'), and bytes of one tile of a layer
+        # of each kind, from the table's own rows, so that no reader of the
         # counters guesses a row's width
-        row_bytes = lambda *names: sum(  # noqa: E731
-            math.prod(self.cache[n].shape[2:4]) * self.cache[n].dtype.itemsize
-            for n in names if n in self.cache)
-        self._tile_bytes = {
-            "full": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg)),
-            "window": DECODE_TILE * row_bytes(*gen.cached_tensors(cfg, True))}
+        self._slab_tiles = counts["slab_tiles"]
+        self._tile_bytes = counts["tile_bytes"]
         # a family with recurrent layers: cumulative, over the steps the
         # drained chunks really ran (host arithmetic, no device read): the
         # rows whose state a step HAD to move (``rows_live``: the chunk's
@@ -728,20 +751,18 @@ class GenerationEngine:
         # same where the decode program was lowered with the kernel that
         # walks them, every row of the cache where it runs the masked form);
         # the layers with a state, and the bytes a row holds a layer (state
-        # and last inputs), from the cache's own shapes.  None: no such layer
+        # and last inputs).  None: no such layer
         self._state: Optional[Dict[str, int]] = None
-        if n_state:
+        if counts["state"]:
             from ray_tpu.ops import ssm
 
-            held, tails = self.cache["ssm"], self.cache["conv"]
             self._state_kernel = jax.devices()[0].platform == "tpu" and (
                 ssm.kernel_shapes if self._shared is None
-                else ssm.selective_kernel_shapes)(held)
+                else ssm.selective_kernel_shapes)(self.cache[counts["state"]])
             self._state = {
                 "rows_updated": 0, "rows_live": 0, "steps": 0, "dispatches": 0,
-                "layers": n_state,
-                "row_bytes": (held.nbytes // math.prod(held.shape[:2])
-                              + tails.nbytes // (n_state * tails.shape[2]))}
+                "layers": self._layers[gen.STATE_LAYERS],
+                "row_bytes": counts["state_row_bytes"]}
         self._key = jax.random.PRNGKey(seed)
         # requests refused at submission, by reason (``stats()["refused"]``)
         self._refused: Dict[str, int] = {}

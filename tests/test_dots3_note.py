@@ -361,7 +361,7 @@ def test_engine_counts_the_ring_tiles_a_chunk_lists():
         "dots3_note", changed=(("sliding_window", 100),), n_slots=3,
         max_new_tokens=8, decode_chunk_steps=3, prefill_buckets=(64, 512))
     assert eng.cache["c_ring"].shape[-1] == 256
-    assert gen.ring_read_by_tile(eng.cache)
+    assert gen.ring_read_by_tile(eng.cache, cfg)
     rng = np.random.RandomState(13)
     futs = [eng.submit(list(rng.randint(0, cfg.vocab_size, n)), 8)
             for n in (40, 300)]
@@ -383,8 +383,9 @@ def test_engine_counts_the_ring_tiles_a_chunk_lists():
     assert 0 < tiles["read_window"] < tiles["held_window"]
     # the tiny preset's rings are under a tile (10 entries): every row's is
     # read whole, as a ring of K and V per head is
+    tiny = tiny_model("dots3_note")[0]
     assert not gen.ring_read_by_tile(jax.eval_shape(
-        lambda: gen.init_cache(tiny_model("dots3_note")[0], 4, 64)))
+        lambda: gen.init_cache(tiny, 4, 64)), tiny)
 
 
 def test_masked_flash_kernel_against_the_materialised_mask():

@@ -202,7 +202,8 @@ def test_a_window_pooled_at_roll_over_is_the_prefills():
     assert int(slots.cache["pos"][0]) >= 64
     seq = (prompt + slots.out[0])[:64]
     _, want, _ = _whole(slots.params, slots.cfg, seq, 64)
-    for name in gen.SUMMARIES:
+    for name in (row.name for row in gen.cache_layout(slots.cfg)
+                 if row.arrangement == gen.SUMMARY):
         got = np.asarray(slots.cache[name][:, 0, :, :, :2 * ROWS])
         # (values of size ~20 under ``block_scale=8``: float32's 1e-5 of them)
         assert np.allclose(got, np.asarray(want[name][:, 1, :, :, :2 * ROWS]),

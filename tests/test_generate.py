@@ -174,7 +174,7 @@ def test_a_latent_ring_of_whole_tiles_is_read_by_tile(positions):
     beside them, over two chunks: every token is the full forward's."""
     eng = Slots("dots3_note", positions(LONG), sliding_window=64)
     assert eng.cache["c_ring"].shape[-1] == 128
-    assert gen.ring_read_by_tile(eng.cache)
+    assert gen.ring_read_by_tile(eng.cache, eng.cfg)
     rng = np.random.default_rng(1)
     prompts = {0: 150, 1: 20, 3: 128}
     for slot, length in prompts.items():

@@ -505,6 +505,10 @@ def test_refresh_failure_keeps_stale_table_with_backoff(serve_instance):
         assert router._refresh_failures == 1
         assert router._next_refresh_attempt > time.monotonic() - 1
         assert router._replicas, "stale replica set was dropped"
+        # the window pinned open, far ahead (as it is pinned shut below): what
+        # is asserted is that a pull INSIDE it is not retried, not that a
+        # request and a second refresh fit into 0.2 s on a busy host
+        router._next_refresh_attempt = time.monotonic() + 3600
         # requests still route on the stale table
         assert ray_tpu.get(handle.remote(), timeout=60) == "steady"
         # inside the backoff window the failing pull is NOT retried

@@ -1,7 +1,11 @@
 """The slice failure domain, end-to-end (ROADMAP item 3 / VERDICT Weak #8).
 
-Headline scenario: 16 emulated hosts form one TPU slice and hold a
-STRICT_PACK training gang mid-run; chaos SIGKILLs one host.  The runtime
+Headline scenario: four emulated hosts form one TPU slice and hold a
+STRICT_PACK training gang mid-run; chaos SIGKILLs one host.  (Four is what
+the assertions need: a gang spread over several hosts, ONE of which dies, and
+a slice replaced WHOLE.  It was sixteen, plus the sixteen of the replacement
+and a worker each, beside five other xdist workers on eight cores: the waits
+below then ran out on a loaded box, one tier-1 run in three.)  The runtime
 must detect the death (mesh + control EOF), declare the slice degraded,
 restart the WHOLE gang from the latest checkpoint, and heal the fleet by
 replacing the slice atomically (create-before-terminate) — with
@@ -25,7 +29,7 @@ from ray_tpu.autoscaler.local_node_provider import LocalNodeProvider
 from ray_tpu.devtools.chaos import ChaosMonkey, Injection
 from ray_tpu.util.doctor import diagnose
 
-SLICE_HOSTS = 16
+SLICE_HOSTS = 4
 STEPS = 40
 
 
@@ -83,7 +87,7 @@ def slice_fleet():
         ray_tpu.shutdown()
 
 
-def test_sixteen_host_slice_chaos_recovery(slice_fleet, tmp_path):
+def test_four_host_slice_chaos_recovery(slice_fleet, tmp_path):
     node, provider, _ = slice_fleet
     from ray_tpu.air import FailureConfig, RunConfig, ScalingConfig
     from ray_tpu.train.trainer import DataParallelTrainer
@@ -98,7 +102,7 @@ def test_sixteen_host_slice_chaos_recovery(slice_fleet, tmp_path):
     assert len(members) == SLICE_HOSTS
     _wait(lambda: all(m in node.nodes and node.nodes[m].alive
                       for m in members),
-          120, "all 16 slice hosts to register")
+          120, "all the slice's hosts to register")
 
     progress = tmp_path / "progress"
     trainer = DataParallelTrainer(
@@ -125,7 +129,7 @@ def test_sixteen_host_slice_chaos_recovery(slice_fleet, tmp_path):
 
     # mid-train: rank 0 has taken (and checkpointed) a few steps.  The
     # progress file is written BEFORE the step's report, and the driver books
-    # a checkpoint only once all 16 ranks' reports are in, so on a loaded box
+    # a checkpoint only once every rank's report is in, so on a loaded box
     # step 3 can be reached with nothing booked yet: wait for the third
     # checkpoint (step 2) itself, which is what ``resumed_from >= 3`` needs
     _wait(lambda: progress.exists() and int(progress.read_text() or 0) >= 3
