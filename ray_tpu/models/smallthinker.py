@@ -14,7 +14,7 @@ runs; and a layer's attention is chosen by TWO per-layer layouts:
 position).  The published pattern pairs them (a NoPE global layer, then three
 rotary window layers), the block takes each on its own.  The layers are a
 LIST (``params["layers"]``), run unrolled, each recomputed in the backward
-pass (``remat``) but for its attention's and its experts' results.
+pass (``remat``) but for its attention's result and its experts' sort.
 
 Layer ``l`` with input ``x`` (``cfg.dtype`` stream, float32 accumulation;
 router, softmax and loss in float32)::
@@ -56,7 +56,7 @@ from ray_tpu.models.gpt2 import make_optimizer  # noqa: F401 — the trained fam
 from ray_tpu.models.transformer import _attend, make_train_step_from_loss
 from ray_tpu.ops.attention import FLASH_RESIDUALS
 from ray_tpu.ops.layers import cross_entropy_loss, dense, rmsnorm
-from ray_tpu.ops.moe import EXPERTS_OUT, experts_ffn_train
+from ray_tpu.ops.moe import EXPERTS_SORT, experts_ffn_train
 from ray_tpu.parallel.sharding import (
     ShardingRules,
     fsdp_engaged,
@@ -101,8 +101,10 @@ class SmallThinkerConfig:
     dtype: Any = jnp.bfloat16
     # a layer's backward pass recomputes the layer, but for what costs most to
     # redo and little to hold: the flash pair's result and logsumexp and the
-    # experts' result (a chip's own tokens) are kept, so it runs neither the
-    # forward kernel nor the experts' down matmul and combine again
+    # sort of the experts' pairs are kept, so it runs neither the forward
+    # kernel nor the sorts again (nor the experts' down matmul and the way
+    # back to the tokens: their backward pass needs the result of neither,
+    # ``ops.moe._experts_block_train``)
     remat: bool = True
 
     def __post_init__(self):
@@ -255,6 +257,13 @@ def block(x, p, cfg: SmallThinkerConfig, *, rope: bool, window: int,
     return x + y.reshape(B, T, D), {"pairs": pairs, "aux": aux}
 
 
+def remat_policy():
+    """What a replayed layer keeps (``cfg.remat``): the flash pair's result
+    and logsumexp, and the sort of the experts' pairs."""
+    return jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUALS, EXPERTS_SORT)
+
+
 def embed(params: Dict[str, Any], tokens: jax.Array, cfg: SmallThinkerConfig,
           mesh: Optional[Mesh] = None,
           rules: Optional[ShardingRules] = None) -> jax.Array:
@@ -284,8 +293,7 @@ def apply(params: Dict[str, Any], tokens: jax.Array, cfg: SmallThinkerConfig,
     also ``{"pairs" [L, E], "aux" [L]}``."""
     rules = rules or (sharding_rules(mesh) if mesh is not None else None)
     x = embed(params, tokens, cfg, mesh, rules)
-    policy = jax.checkpoint_policies.save_only_these_names(
-        *FLASH_RESIDUALS, EXPERTS_OUT)
+    policy = remat_policy()
     routed = []
     for p, rope, window in zip(
             params["layers"], cfg.rope_layout, cfg.sliding_windows):

@@ -14,8 +14,10 @@
   chip's own tokens, their gradients sent home a chip's block at a time under
   the backward pass's own matmuls: a chip's work does not follow the routing).
 
-The two dropless layers share the sort of the pairs (:func:`_sorted_pairs`)
-and the two grouped matmuls (:func:`_experts_block`).
+The two dropless layers share the sort of the pairs (:func:`_sorted_pairs`).
+The served one's two grouped matmuls are :func:`_experts_block`; the trained
+one has a block of its own (:func:`_experts_block_train`: a pair's gate goes
+in before the down matmul, and the backward pass is written by hand).
 
 The Switch layer: Mixture-of-Experts FFN with expert parallelism over the ``ep`` axis.
 
@@ -38,6 +40,7 @@ eq. (4): ``E * sum_e f_e * P_e``, minimized at uniform routing.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -303,12 +306,12 @@ def _experts_block(rows: jax.Array, w_gate_up: jax.Array, w_down: jax.Array,
     """``rows [R, D]`` sorted by expert, ``sizes`` of them each (rows past
     their sum belong to no group, and what comes out for them is not meant to
     be read) -> ``[R, D]`` float32: TWO grouped matmuls (``lax.ragged_dot``,
-    served and trained: the Pallas grouped matmul that ships with JAX gave the
-    training layer the same time to 3 % on the chip, PERF.md section 6, PR 57,
-    the permutations around the matmuls being what costs), gate and up as one
-    call whose result is split into its halves for ``activation(g) * u``
-    (``silu``: SwiGLU; ``relu``: ReGLU), then down.  The weights are used in
-    the rows' dtype."""
+    as the trained block's are: the Pallas grouped matmul that ships with JAX
+    gave the training layer the same time to 3 % on the chip, PERF.md section
+    6, PR 57, the permutations around the matmuls being what costs), gate and
+    up as one call whose result is split into its halves for ``activation(g)
+    * u`` (``silu``: SwiGLU; ``relu``: ReGLU), then down.  The weights are
+    used in the rows' dtype."""
     F = w_down.shape[-2]
     gu = jax.lax.ragged_dot(rows, w_gate_up.astype(rows.dtype), sizes)
     h = activation(gu[:, :F]) * gu[:, F:]
@@ -450,68 +453,131 @@ def held_experts_ffn(
 # ~8), so both permutations and both their transposes are GATHERS here: a
 # token has exactly ``k`` pairs, all on this chip, so "add a token's pairs" is
 # a gather by the inverse permutation and a sum over ``k``.
+#
+# And the block has ONE backward pass of its own (:func:`_experts_block_train`,
+# PR 62), because plain reverse mode of "down matmul, gather back, times the
+# gates, sum" needs every pair's down result for the gates' gradient: under
+# the model's remat the backward pass ran the down matmul a second time and
+# gathered its float32 ``[pairs, D]`` result (1 GB at the cell's sizes) again,
+# and the cotangent was a float32 ``ct x gate`` product written whole and then
+# gathered.  The gate goes in BEFORE the down matmul instead.  What the
+# backward pass keeps: the sorted rows, the gate-and-up result, the sorted
+# gates and the sort itself, all upstream of the down matmul; what a replay
+# recomputes: one row gather and the gate-and-up matmul (the sort too, unless
+# a policy keeps ``EXPERTS_SORT``); what it computes: the cotangent's rows
+# gathered once in the dtype they arrive in, four grouped matmuls, the gates'
+# gradient from ``dh`` over ``[pairs, F]``.  Seven grouped matmuls a layer
+# where there were eight, one float32 ``[pairs, D]`` gather where there were
+# three; the layer alone on one chip, forward + replay + backward, 108.6 ->
+# 74.4 ms (PERF.md section 6, PR 62).
 
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # what a remat policy may keep of a layer's experts (``save_only_these_names``):
-# the layer's result, a chip's own tokens.  The backward pass then gathers the
-# experts again and recomputes the gate-and-up matmul, not the down one
-EXPERTS_OUT = "experts_out"
+# the pairs' sort (``order``, ``rank``, the groups' sizes: integers, 0.8 MB a
+# layer at 98 k pairs), so that a replay sorts nothing again.  (The layer's
+# RESULT had a name here until PR 62, and keeping it kept nothing: only the
+# residual sum reads it, and a sum's backward pass needs no operand.)
+EXPERTS_SORT = "experts_sort"
+
+# ``lhs [R, A]``, ``rhs [R, B]``, ``sizes`` -> ``[E, A, B]``: the rows of each
+# group contracted (a grouped matmul's transpose in its weights)
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-@jax.custom_vjp
-def _rows_of_pairs(x, order, rank):
-    """``x [N, D]`` -> ``[M, D]``: row ``i`` is the token of pair ``order[i]``
-    (pair ``p`` is token ``p // k``'s).  Transposed: a token's ``k`` rows
-    gathered by ``rank`` (``order``'s inverse) and summed, no scatter."""
-    return x[order // (order.size // x.shape[0])]
+def _halves(gu):
+    """``gu [R, 2F]`` -> gate's columns and up's, float32."""
+    F = gu.shape[-1] // 2
+    return gu[:, :F].astype(jnp.float32), gu[:, F:].astype(jnp.float32)
 
 
-def _rows_of_pairs_bwd(kept, ct):
-    rank, n = kept
+@partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _experts_block_train(x, gates, w_gate_up, w_down, order, rank, sizes,
+                         activation: str):
+    """The TRAINED block, tokens in to tokens out, with ONE backward pass of
+    its own.  ``x [N, D]``, ``gates [N, k]`` float32, the weights in ``x``'s
+    dtype; ``order [R]``: the pairs sorted by expert (pair ``p`` is token ``p
+    // k``'s; ``R``: ``N * k`` and up to 7 rows more that belong to no group
+    and are never read back), ``rank [k, N]`` its inverse (pair ``(n, j)`` sits
+    at row ``rank[j, n]``: a token's ``k`` rows gathered as ``k`` blocks of
+    ``[N, D]`` and the blocks added, where ``[N, k, D]`` would be laid out
+    again before its sum, ``k`` not being a whole tile of rows), ``sizes [E]``
+    the rows of each expert.  Returns ``[N, D]`` in ``x``'s dtype.
+
+    A pair's gate goes in BEFORE the down matmul (``sum_j g_j (h_j W[e_j]) =
+    sum_j (g_j h_j) W[e_j]``): it scales ``act(g) * u`` where that is formed,
+    and the way back to the tokens is a plain sum of a token's ``k`` rows.
+    Nothing in the backward pass then needs the down matmul's result: the
+    gates' gradient is ``sum_F dh * (act(g) * u)`` over ``[R, F]`` with ``dh =
+    ct W_down^T``, which the pass computes anyway.  Its residuals all lie
+    UPSTREAM of the down matmul (the sorted rows, ``gu``, the sorted gates),
+    so a replay under ``jax.checkpoint`` runs the sort (unless kept:
+    ``EXPERTS_SORT``), one row gather and the gate-and-up matmul, and the down
+    matmul, its float32 ``[R, D]`` result and that result's gather are dead
+    code there.  Every grouped matmul has operands in ``x``'s dtype and
+    float32 accumulation; sums over ``k`` are float32."""
+    return _experts_block_train_fwd(
+        x, gates, w_gate_up, w_down, order, rank, sizes, activation)[0]
+
+
+def _experts_block_train_fwd(x, gates, w_gate_up, w_down, order, rank, sizes,
+                             activation):
+    rows = x[order // gates.shape[1]]
+    gate_of_row = gates.reshape(-1)[order]
+    gu = jax.lax.ragged_dot(rows, w_gate_up, sizes)
+    # formed in float32 and rounded once, gate and all
+    g, u = _halves(gu)
+    h = (ACTIVATIONS[activation](g) * u * gate_of_row[:, None]).astype(gu.dtype)
+    y = jax.lax.ragged_dot(h, w_down, sizes, preferred_element_type=jnp.float32)
+    out = y[rank].sum(0).astype(x.dtype)
+    return out, (rows, gu, gate_of_row, w_gate_up, w_down, order, rank, sizes)
+
+
+def _experts_block_train_bwd(activation, kept, ct):
+    rows, gu, gate_of_row, w_gate_up, w_down, order, rank, sizes = kept
+    grouped = partial(jax.lax.ragged_dot_general, group_sizes=sizes,
+                      ragged_dot_dimension_numbers=_ROWS_CONTRACTED)
     with jax.named_scope("moe.expert_ffn"):  # (a rule of its own has no scope)
-        dx = ct[rank].reshape(n, rank.size // n, -1)
-        return dx.astype(jnp.float32).sum(1).astype(ct.dtype), None, None
+        # the cotangent's rows once, in the dtype it arrives in; the weights'
+        # gradients before what follows from them, so that their way home
+        # (``experts_ffn_train``'s shifts) has the rest to fly under
+        hu, pull = jax.vjp(
+            lambda g, u: ACTIVATIONS[activation](g) * u, *_halves(gu))
+        h = (hu * gate_of_row[:, None]).astype(gu.dtype)
+        ct_rows = ct[order // rank.shape[0]]
+        d_w_down = grouped(h, ct_rows, preferred_element_type=jnp.float32)
+        dh = jax.lax.ragged_dot(ct_rows, jnp.swapaxes(w_down, 1, 2), sizes,
+                                preferred_element_type=jnp.float32)
+        d_gate_of_row = (dh * hu).sum(-1)
+        dgu = jnp.concatenate(
+            pull(dh * gate_of_row[:, None]), -1).astype(gu.dtype)
+        d_w_gate_up = grouped(rows, dgu)
+        drows = jax.lax.ragged_dot(dgu, jnp.swapaxes(w_gate_up, 1, 2), sizes)
+        dx = drows[rank].astype(jnp.float32).sum(0)
+        return (dx.astype(ct.dtype), d_gate_of_row[rank].T,
+                d_w_gate_up, d_w_down.astype(w_down.dtype), None, None, None)
 
 
-_rows_of_pairs.defvjp(
-    lambda x, order, rank: (_rows_of_pairs(x, order, rank), (rank, x.shape[0])),
-    _rows_of_pairs_bwd)
-
-
-@jax.custom_vjp
-def _pairs_of_rows(y, order, rank):
-    """``y [M, D]`` sorted by expert -> ``[M, D]`` in the pairs' own order
-    (token-major).  Transposed: the gather by ``order``."""
-    return y[rank]
-
-
-def _pairs_of_rows_bwd(order, ct):
-    with jax.named_scope("moe.expert_ffn"):
-        return ct[order], None, None
-
-
-_pairs_of_rows.defvjp(lambda y, order, rank: (y[rank], order), _pairs_of_rows_bwd)
+_experts_block_train.defvjp(_experts_block_train_fwd, _experts_block_train_bwd)
 
 
 def _experts_train(x, gates, w_gate_up, w_down, experts, activation: str):
     """``x [N, D]``, ``experts``, ``gates [N, k]`` over ALL the experts of
-    ``w_gate_up [E, D, 2F]`` / ``w_down [E, F, D]`` -> ``[N, D]`` float32: the
-    ``N * k`` pairs sorted by expert, two grouped matmuls over all of them
-    (:func:`_experts_block`), a token's ``k`` rows gathered back and summed
-    under its gates."""
-    (N, D), top_k = x.shape, experts.shape[1]
-    M = N * top_k
+    ``w_gate_up [E, D, 2F]`` / ``w_down [E, F, D]`` -> ``[N, D]`` in ``x``'s
+    dtype: the ``N * k`` pairs sorted by expert, and all of them through
+    :func:`_experts_block_train`."""
     _, order, bounds = _sorted_pairs(experts, 0, w_down.shape[0])
-    rank = jnp.argsort(order)
-    rows = _rows_of_pairs(x, order, rank)
     # the TPU's grouped-matmul kernel takes whole sublane tiles of rows (a
     # list of another length is computed densely); rows past the groups
     # belong to no group and are not read
-    rows = jnp.pad(rows, ((0, -M % 8), (0, 0)))
-    y = _experts_block(rows, w_gate_up.astype(x.dtype), w_down.astype(x.dtype),
-                       bounds[1:] - bounds[:-1], ACTIVATIONS[activation])[:M]
-    y = _pairs_of_rows(y, order, rank).reshape(N, top_k, D)
-    return (y * gates[..., None]).sum(1)
+    rank = jnp.argsort(order).reshape(experts.shape).T
+    order = jnp.pad(order, (0, -experts.size % 8))
+    order, rank, sizes = checkpoint_name(
+        (order, rank, bounds[1:] - bounds[:-1]), EXPERTS_SORT)
+    return _experts_block_train(
+        x, gates.astype(jnp.float32), w_gate_up.astype(x.dtype),
+        w_down.astype(x.dtype), order, rank, sizes, activation)
 
 
 def experts_ffn_train(
@@ -527,7 +593,10 @@ def experts_ffn_train(
     (gate's columns then up's), ``w_down [E, F, D]``, ``activation``: ``silu``
     (SwiGLU) or ``relu`` (ReGLU).  Returns ``y [N, D]`` in ``x``'s dtype: the
     gate-weighted sum of each token's ``k`` experts.  Gradients reach ``x``,
-    ``gates`` and both weights by plain reverse mode (module comment above).
+    ``gates`` and both weights through the block's own backward pass
+    (:func:`_experts_block_train`: it keeps what lies upstream of the down
+    matmul and the sort, a replay under ``jax.checkpoint`` recomputes one row
+    gather and the gate-and-up matmul, never the down matmul).
 
     With a mesh, tokens AND experts are divided over ``axis`` (``N`` and ``E``
     both in ``mesh.shape[axis]`` contiguous blocks: a chip HOLDS its block of
@@ -554,9 +623,8 @@ def experts_ffn_train(
     collectives, the casts and the sum; ``moe.expert_ffn`` the rest."""
     if mesh is None or mesh.shape[axis] == 1:
         with jax.named_scope("moe.expert_ffn"):
-            return checkpoint_name(_experts_train(
-                x, gates, w_gate_up, w_down, experts, activation
-            ).astype(x.dtype), EXPERTS_OUT)
+            return _experts_train(
+                x, gates, w_gate_up, w_down, experts, activation)
 
     n = mesh.shape[axis]
 
@@ -591,8 +659,8 @@ def experts_ffn_train(
             w_gate_up, w_down = brought(w_gate_up), brought(w_down)
         with jax.named_scope("moe.expert_ffn"):
             return _experts_train(
-                x, gates, w_gate_up, w_down, experts, activation).astype(x.dtype)
+                x, gates, w_gate_up, w_down, experts, activation)
 
-    return checkpoint_name(jax.shard_map(
+    return jax.shard_map(
         on_chip, mesh=mesh, in_specs=(P(axis),) * 5, out_specs=P(axis),
-        check_vma=False)(x, experts, gates, w_gate_up, w_down), EXPERTS_OUT)
+        check_vma=False)(x, experts, gates, w_gate_up, w_down)

@@ -515,13 +515,16 @@ def _lower_sparse_train_parts(chip):
     768 top-6, 16 held a chip, all of them gathered to a chip's own 16,384
     tokens, whose 98,304 pairs go through ``lax.ragged_dot`` and its
     transposes with no loop; the matrices' gradients sent home a block at a
-    time), and ``attention()`` forward and backward under a 4,096 band at a chip's
+    time; value and gradients under the model's remat policy, so the program
+    holds the forward pass, its replay and the backward pass as a step does),
+    and ``attention()`` forward and backward under a 4,096 band at a chip's
     share ``[2, 28, 8192, 128]``.  (The WHOLE step's compile, ~60 s here,
     sized the cell's batch in the builder's scratch run and is too long for
     this file: PERF.md section 6, PR 57.)"""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from ray_tpu.models import smallthinker
     from ray_tpu.ops import attention, moe
 
     mesh = Mesh(np.array(_FOUR_CHIPS).reshape(4), ("fsdp",))
@@ -536,7 +539,9 @@ def _lower_sparse_train_parts(chip):
 
     qkv = _on(chip, jax.ShapeDtypeStruct((2, 28, 8192, 128), jnp.bfloat16))
     return [
-        jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3))).lower(
+        jax.jit(jax.value_and_grad(
+            jax.checkpoint(experts, policy=smallthinker.remat_policy()),
+            argnums=(0, 1, 2, 3))).lower(
             over((n, d), jnp.bfloat16), over((n, k), jnp.float32),
             over((e, d, 2 * f), jnp.float32), over((e, f, d), jnp.float32),
             over((n, k), jnp.int32)),
@@ -779,13 +784,31 @@ def test_program_compiles_for_v5e(compiled, name):
         # the grouped matmuls (``lax.ragged_dot`` and its transposes in both
         # operands) over a chip's own 98,304 pairs, and nothing that follows
         # the routing: no loop, and no scatter but the sort's own
-        assert "ragged-dot" in experts and "moe.expert_ffn" in experts
+        assert "moe.expert_ffn" in experts
         assert not re.search(r"= \S+ while\(", experts)
-        assert "f32[98304,2560]" in experts and "scatter-add" not in experts
+        assert "scatter-add" not in experts
+        # SEVEN grouped matmuls: two forward, gate-and-up again in the replay,
+        # four backward.  The gate goes in before the down matmul, so the
+        # backward pass needs nothing of that matmul's result: the replay
+        # leaves it out (eight before PR 62), and of the float32 [pairs, D]
+        # blocks gathered only the forward's way back to the tokens is left
+        # (three: the replay's, and the weighted cotangent's).  The rows, the
+        # replay's rows and the cotangent's rows are bf16 gathers; the two
+        # ways back to the tokens (the result's, the rows' gradient's) gather
+        # k blocks of [N, D] that are added with no layout change between
+        assert len(re.findall(r"^\s*%ragged-dot[\w\-.]* = \S+ custom-call\(",
+                              experts, flags=re.M)) == 7
+        gathered = lambda shape: sorted(re.findall(  # noqa: E731
+            r" = (\w+)\[%s\]\S* gather\(" % shape, experts))
+        assert gathered("98304,2560") == ["bf16"] * 3
+        assert gathered("6,16384,2560") == ["bf16", "f32"]
+        assert not re.search(r"\[16384,6,2560\]", experts)
         # (4.2 GiB while one reduce-scatter took the gradients home: a
         # matrix's float32 blocks are in flight, three sent and three
-        # received, while the backward's matmuls still hold their operands)
-        assert needs[0] < 7 * 2**30, needs
+        # received, while the backward's matmuls still hold their operands;
+        # 5.95 GiB with the forward pass and the replay in the program, 6.57
+        # for the backward pass alone while it held float32 [pairs, D] blocks)
+        assert needs[0] < 6.5 * 2**30, needs
         # both kernels of the pair under the band, no masked scores in HBM
         assert band.count("tpu_custom_call") == 2
         # (q, k, v, their gradients and the result are 0.8 GB)

@@ -74,10 +74,15 @@ def value_and_grads(cfg, params, batch, mesh=None):
         lambda p, b: st.loss_fn(p, b, cfg, mesh), has_aux=True))(params, batch)
 
 
-@pytest.mark.parametrize("activation", ["relu", "silu"])
-def test_loss_and_every_gradient_match_the_reference(activation):
-    """ReGLU and SwiGLU through the same grouped matmuls, the whole tree."""
-    cfg = st.SmallThinkerConfig.tiny(activation=activation)
+@pytest.mark.parametrize("activation,remat", [
+    ("relu", False), ("silu", False), ("relu", True)],
+    ids=["relu", "silu", "relu-layers-replayed"])
+def test_loss_and_every_gradient_match_the_reference(activation, remat):
+    """ReGLU and SwiGLU through the same grouped matmuls, the whole tree; and
+    with every layer replayed in the backward pass under the model's policy
+    (the expert block's own backward rule fed by a replay of its forward
+    one)."""
+    cfg = st.SmallThinkerConfig.tiny(activation=activation, remat=remat)
     params, batch = st.init(cfg, jax.random.PRNGKey(1)), batch_of(cfg)
     (loss, counted), grads = value_and_grads(cfg, params, batch)
     leaves, kept = every_leaf(params), {}
@@ -247,6 +252,101 @@ def test_the_expert_layer_differentiates_past_one_block():
     got = jax.jit(jax.value_and_grad(program, (0, 1, 2, 3)))(x, w_r, w_gu, w_d)
     assert float(got[0]) == pytest.approx(float(wanted[0]), rel=1e-5)
     assert max(rel(a, b) for a, b in zip(got[1], wanted[1])) < 2e-5
+
+
+def operations_of(jaxpr, inside=()):
+    """``[(primitive, the enclosing equations' primitives, result's aval)]`` of
+    every equation of a jaxpr and of the jaxprs its equations hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        found.append((name, inside, eqn.outvars[0].aval if eqn.outvars else None))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += operations_of(sub, inside + (name,))
+    return found
+
+
+@pytest.mark.parametrize("replayed", [False, True], ids=["kept", "replayed"])
+@pytest.mark.parametrize("starved", [False, True], ids=["routed", "an-expert-without-rows"])
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_the_trained_block_against_plain_reverse_mode(activation, starved, replayed):
+    """The block's own backward pass (the gate before the down matmul, the
+    gates' gradient from ``dh``) against plain reverse mode of the layer as
+    the reference writes it (every expert dense over every token, the gate
+    AFTER the down matmul): the loss and the gradient in x, in the GATES and in
+    both weights, past one block of pairs, a number of pairs that is no whole
+    tile of rows; with an expert no token chose (its gradients exactly 0); and
+    under the model's remat policy, the backward rule fed by a replay."""
+    n, d, f, e, k = 601, 16, 12, 8, 2
+    assert n * k > moe._ONE_BLOCK_PAIRS and n * k % 8
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    x, target = (jax.random.normal(kk, (n, d)) for kk in keys[:2])
+    w_gu = jax.random.normal(keys[3], (e, d, 2 * f)) * 0.3
+    w_d = jax.random.normal(keys[4], (e, f, d)) * 0.3
+    experts, gates = moe.route_softmax_top_k(
+        x, jax.random.normal(keys[2], (d, e)), k)
+    if starved:  # expert 5's pairs go to expert 6 (or 7, where 6 is the other)
+        other = experts[:, ::-1]
+        experts = jnp.where(experts == 5, jnp.where(other == 6, 7, 6), experts)
+        assert not bool((experts == 5).any())
+    gates = gates * jax.random.uniform(keys[5], gates.shape, minval=0.5, maxval=1.5)
+    act = moe.ACTIVATIONS[activation]
+
+    def dense(x, gates, w_gu, w_d):
+        y = 0.0
+        for j in range(e):
+            gu = x @ w_gu[j]
+            weight = jnp.where(experts == j, gates, 0.0).sum(-1)
+            y = y + (act(gu[:, :f]) * gu[:, f:]) @ w_d[j] * weight[:, None]
+        return jnp.square(y - target).sum()
+
+    def program(x, gates, w_gu, w_d):
+        y = moe.experts_ffn_train(x, experts, gates, w_gu, w_d, activation=activation)
+        return jnp.square(y - target).sum()
+
+    if replayed:
+        program = jax.checkpoint(program, policy=st.remat_policy())
+    wanted = jax.value_and_grad(dense, (0, 1, 2, 3))(x, gates, w_gu, w_d)
+    got = jax.jit(jax.value_and_grad(program, (0, 1, 2, 3)))(x, gates, w_gu, w_d)
+    assert float(got[0]) == pytest.approx(float(wanted[0]), rel=1e-5)
+    assert all(float(jnp.abs(g).max()) > 0 for g in wanted[1])
+    assert max(rel(a, b) for a, b in zip(got[1], wanted[1])) < 2e-5
+    if starved:
+        assert all(float(jnp.abs(g[5]).max()) == 0 for g in got[1][2:])
+        assert all(float(jnp.abs(g[6]).max()) > 0 for g in got[1][2:])
+
+
+def test_a_replayed_layer_runs_seven_grouped_matmuls():
+    """One layer of the model (bf16, as the cell trains it) under the model's
+    remat policy, its ``jax.grad`` read as a jaxpr: SEVEN grouped matmuls (two
+    forward, the gate-and-up one again in the replay, four backward: the down
+    matmul is not run a second time), ONE gather whose result is a float32
+    block of ``pairs x D`` (the forward's way back to the tokens, ``k`` blocks
+    of ``[N, D]``; the rows, the replay's rows, the cotangent's rows and the
+    rows' gradient are bf16 gathers), and no sort in the backward pass (the
+    forward's two are kept by name)."""
+    cfg = st.SmallThinkerConfig.tiny(n_layers=1, remat=True, dtype=jnp.bfloat16)
+    params, batch = st.init(cfg, jax.random.PRNGKey(12)), batch_of(cfg)
+    k, d = cfg.experts_per_token, cfg.d_model
+    operations = operations_of(jax.make_jaxpr(jax.grad(
+        lambda p: st.loss_fn(p, batch, cfg)[0]))(params).jaxpr)
+    matmuls = [inside for name, inside, _ in operations if name == "ragged_dot_general"]
+    assert len(matmuls) == 7
+    # (the backward pass is the ``remat2`` equation: the replay and the
+    # transposes; what stands outside it is the forward pass)
+    assert sum("remat2" in inside for inside in matmuls) == 5
+    blocks = sorted(
+        (str(aval.dtype), aval.shape, "remat2" in inside)
+        for name, inside, aval in operations
+        if name == "gather" and aval.shape in ((B * T * k, d), (k, B * T, d)))
+    assert blocks == [
+        ("bfloat16", (k, B * T, d), True),    # the rows' gradient, back to the tokens
+        ("bfloat16", (B * T * k, d), False),  # the rows
+        ("bfloat16", (B * T * k, d), True),   # the rows again, the cotangent's rows
+        ("bfloat16", (B * T * k, d), True),
+        ("float32", (k, B * T, d), False)]    # the result, back to the tokens
+    sorts = ["remat2" in inside for name, inside, _ in operations if name == "sort"]
+    assert sorts == [False, False]
 
 
 def test_ten_steps_lower_the_loss(mesh):
